@@ -18,8 +18,10 @@ y = ((X[:, 0] > 0).astype(np.float32)
 # keeps the fitted binner for serving. On a multi-process job, pass
 # ``comm=`` and the binner fits DISTRIBUTED (each rank sketches its
 # own shard, one allgather merges — check/checkdist.py runs that).
+# 32 bins are too few for the compiled Pallas kernel (n_bins % 128 == 0),
+# so the XLA matmul strategy is chosen explicitly
 cfg = GBDTConfig(n_features=F, n_bins=B, depth=4, n_trees=5,
-                 learning_rate=0.3)
+                 learning_rate=0.3, hist_mode="matmul")
 trainer = GBDTTrainer(cfg)  # all available devices, data-parallel
 trees, train_preds = trainer.train_raw(X, y)
 

@@ -1,6 +1,6 @@
 """FFM consumer: field-aware factorization machine with the sparse
 embedding-gradient allreduce (the Criteo-shaped workload of
-BASELINE.md configs[4]); train, persist, and serve."""
+BASELINE.json configs[4]); train, persist, and serve."""
 import numpy as np
 
 from ytk_mp4j_tpu.models.fm import FMConfig, FMTrainer

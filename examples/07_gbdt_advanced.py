@@ -17,9 +17,12 @@ binner = QuantileBinner(B).fit(X[: N - 5000])
 bins_tr = binner.transform(X[: N - 5000])
 bins_va = binner.transform(X[N - 5000:])
 
+# 64 bins are too few for the compiled Pallas kernel (n_bins % 128 == 0),
+# so the XLA matmul strategy is chosen explicitly
 cfg = GBDTConfig(n_features=F, n_bins=B, depth=4, n_trees=30,
                  learning_rate=0.3, loss="softmax", n_classes=C,
-                 subsample=0.9, colsample=0.9, min_split_gain=1e-6)
+                 subsample=0.9, colsample=0.9, min_split_gain=1e-6,
+                 hist_mode="matmul")
 trainer = GBDTTrainer(cfg)
 trees, _ = trainer.train(
     bins_tr, y[: N - 5000], sample_weight=w[: N - 5000],
@@ -63,7 +66,8 @@ mbins = np.array(mbinner.transform(Xm))       # writable copy
 mbins[:, 9] = codes + 1                       # codes -> bins [1, 6]
 mcfg = GBDTConfig(n_features=F, n_bins=B, depth=4, n_trees=20,
                   learning_rate=0.3, loss="logistic",
-                  missing_bin=True, categorical_features=(9,))
+                  missing_bin=True, categorical_features=(9,),
+                  hist_mode="matmul")
 mtr = GBDTTrainer(mcfg)
 mtrees, _ = mtr.train(mbins, ym)
 macc = float(((mtr.predict(mbins, mtrees, proba=True) > 0.5) == ym).mean())

@@ -41,6 +41,8 @@ for algo in ("xla", "ring", "rdma"):
 # -- functional layer: the same schedules inside YOUR jit -------------
 mesh = make_mesh(n)
 on_tpu = mesh.devices.flat[0].platform == "tpu"
+print("rdma kernels:", "compiled (Mosaic)" if on_tpu
+      else f"interpreted ({mesh.devices.flat[0].platform} mesh)")
 data = np.tile(np.arange(n, dtype=np.float32)[:, None], (1, 16 * n))
 
 

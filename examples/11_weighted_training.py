@@ -31,7 +31,8 @@ print("weighted bins == duplicated-row bins")
 # (b) one-call weighted GBDT: the weights reach the sketch AND the
 # boosting gradients; the fitted binner rides save_model
 cfg = GBDTConfig(n_features=F, n_bins=16, depth=4, n_trees=5,
-                 loss="logistic", learning_rate=0.3)
+                 loss="logistic", learning_rate=0.3,
+                 hist_mode="matmul")   # 16 bins: below the Pallas kernel's 128
 tr = GBDTTrainer(cfg)
 trees, _ = tr.train_raw(X, y, sample_weight=w)
 proba = tr.predict_raw(X, trees, proba=True)

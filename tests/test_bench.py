@@ -173,3 +173,44 @@ def test_bench_socket_tuner_act_smoke():
     assert out["decisions"] and all(
         st is not None and st["mode"] == "act"
         for st in out["decisions"].values())
+
+
+def test_socket_job_refuses_to_fork_under_an_accelerator(monkeypatch):
+    """One process for each chip: once this process holds an
+    accelerator backend the socket legs refuse to fork (no master, no
+    child is started). A CPU-only backend holds no chip and passes —
+    the smokes above fork after CPU device tests in this very process."""
+    import jax
+    import pytest
+
+    jax.devices()                           # a backend is up
+    bench._refuse_fork_with_live_accelerator()          # cpu: passes
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for job in (
+            lambda: bench._run_socket_job(2, lambda s, r: r, False),
+            lambda: bench._run_elastic_job(2, lambda s, r: r, "", "off")):
+        with pytest.raises(RuntimeError, match="refusing to fork"):
+            job()
+
+
+def test_device_figures_need_a_tpu_and_a_known_peak():
+    """A measurement path that finds no chip fails, and a chip with no
+    published peak is an error, not a v5e default."""
+    import pytest
+
+    with pytest.raises(RuntimeError, match="platform 'cpu'"):
+        bench.require_tpu()
+    assert bench.peak_bf16_flops("TPU v5 lite") == 197e12
+    with pytest.raises(RuntimeError, match="no published peak"):
+        bench.peak_bf16_flops("cpu")
+
+
+def test_aot_compile_lets_a_compile_error_out():
+    import jax
+    import pytest
+
+    def bad(x):
+        raise ValueError("does not trace")
+
+    with pytest.raises(ValueError, match="does not trace"):
+        bench._aot_compile(jax.jit(bad), np.zeros(2, np.float32))

@@ -459,8 +459,7 @@ def test_tie_mass_rides_the_merge(rng):
     """90% of the mass in ONE tied value: every internal quantile sits
     strictly inside the jump, so all merged edges must equal the tied
     value exactly — matching fit() — instead of smearing toward the
-    tail (the pre-round-4 grid-CDF merge smeared; VERDICT round 3
-    item 4)."""
+    tail (the pre-round-4 grid-CDF merge smeared)."""
     B, R, N = 8, 3, 9_000
     col = np.where(rng.random(N) < 0.9, 0.0,
                    rng.uniform(1.0, 2.0, N)).astype(np.float32)
@@ -497,7 +496,7 @@ def _tied_shard_sets(draw):
 @settings(max_examples=25, deadline=None)
 @given(_tied_shard_sets(), st.integers(4, 16))
 def test_heavy_ties_position_bound(shards, B):
-    """VERDICT round-3 item 4's acceptance: under 90%-mass-in-5-values
+    """Under 90%-mass-in-5-values
     the merged edges must land within 2/Q of the target quantiles in
     POOLED-CDF position (tie-aware: a q inside a jump an edge sits on
     costs 0) — the same documented bound as the continuous case, which

@@ -41,118 +41,23 @@ def test_single_process_fallback():
         assert d == {"a": 1.0}
 
 
-def test_device_reduce_verdict_agreed_job_wide(monkeypatch):
-    """If the local MAX/MIN probe verdicts differ across ranks (TTL
-    timing, per-host env overrides), every rank must still pick the SAME
-    path: verdicts are exchanged once over the always-safe path and
-    AND-ed, then cached on the comm (ADVICE round 3, medium)."""
-    import numpy as np
-
-    import jax
-    from jax.sharding import Mesh
-
-    from ytk_mp4j_tpu.operators import Operators
-    from ytk_mp4j_tpu.ops import collectives as coll
-
-    comm = DistributedComm.__new__(DistributedComm)
-    comm._rank, comm._n, comm._closed = 0, 3, False
-    comm._djits, comm._agreed_native = {}, {}
-    comm._pmesh = Mesh(np.asarray(jax.devices()[:1]), ("proc",))
-
-    monkeypatch.setattr(coll, "resolve_native_reduce",
-                        lambda operator, devices=None: True)
-    definitive = {"v": True}
-    monkeypatch.setattr(coll, "native_reduce_definitive",
-                        lambda kind, devices=None: definitive["v"])
-    exchanges = []
-
-    def fake_exchange(obj):
-        exchanges.append(obj)
-        return [obj, (False, True), (True, True)]  # rank 1 disagrees
-
-    comm._exchange_obj = fake_exchange
-
-    # local probe said True, but the job-wide AND must win
-    assert comm._device_reduce_ok(Operators.MAX) is False
-    assert exchanges == [(True, True)]
-    # all ranks definitive: pinned, no second exchange
-    assert comm._device_reduce_ok(Operators.MAX) is False
-    assert exchanges == [(True, True)]
-    # SUM needs no probe and never exchanges
-    assert comm._device_reduce_ok(Operators.SUM) is True
-    assert exchanges == [(True, True)]
-    # PROD has no device reducer at all
-    assert comm._device_reduce_ok(Operators.PROD) is False
-
-
-def test_device_reduce_transient_verdict_not_pinned(monkeypatch):
-    """A transient probe verdict (optimistic True, not definitive) must
-    NOT be pinned job-wide: each call re-exchanges until every rank's
-    verdict is definitive, so a backend whose first probes hit infra
-    errors can still fall back to the host path later."""
-    import numpy as np
-
-    import jax
-    from jax.sharding import Mesh
-
-    from ytk_mp4j_tpu.operators import Operators
-    from ytk_mp4j_tpu.ops import collectives as coll
-
-    comm = DistributedComm.__new__(DistributedComm)
-    comm._rank, comm._n, comm._closed = 0, 2, False
-    comm._djits, comm._agreed_native = {}, {}
-    comm._pmesh = Mesh(np.asarray(jax.devices()[:1]), ("proc",))
-
-    state = {"verdict": True, "definitive": False}
-    monkeypatch.setattr(coll, "resolve_native_reduce",
-                        lambda operator, devices=None: state["verdict"])
-    monkeypatch.setattr(coll, "native_reduce_definitive",
-                        lambda kind, devices=None: state["definitive"])
-    exchanges = []
-
-    def fake_exchange(obj):
-        exchanges.append(obj)
-        return [obj, obj]  # peer agrees with us
-
-    comm._exchange_obj = fake_exchange
-
-    assert comm._device_reduce_ok(Operators.MIN) is True
-    assert comm._device_reduce_ok(Operators.MIN) is True
-    assert len(exchanges) == 2          # transient: re-exchanged
-    assert comm._agreed_native == {}    # and never pinned
-    # probe finally lands a definitive rejection -> pinned False
-    state.update(verdict=False, definitive=True)
-    assert comm._device_reduce_ok(Operators.MIN) is False
-    assert comm._agreed_native == {"pmin": False}
-    assert comm._device_reduce_ok(Operators.MIN) is False
-    assert len(exchanges) == 3
-
-
 def test_device_reduce_rejects_shadowing_custom_operator():
-    """A custom operator NAMED "MAX"/"SUM" must never take the native
-    device-reduce path — even after the builtin pinned its verdict
-    (ADVICE round 4, medium: the gate and the pin were keyed by
-    operator.name, so the custom inherited lax.pmax)."""
+    """SUM / MAX / MIN ride the device collective, PROD has no device
+    reducer, and a custom operator NAMED "MAX"/"SUM" must never take the
+    device path: the gate is operator identity, not operator.name (a
+    name-keyed gate once handed a custom "MAX" lax.pmax)."""
     import numpy as np
-
-    import jax
-    from jax.sharding import Mesh
 
     from ytk_mp4j_tpu.operators import Operator, Operators
 
-    comm = DistributedComm.__new__(DistributedComm)
-    comm._rank, comm._n, comm._closed = 0, 2, False
-    comm._djits = {}
-    # builtin MAX already pinned native job-wide
-    comm._agreed_native = {"pmax": True}
-    comm._pmesh = Mesh(np.asarray(jax.devices()[:1]), ("proc",))
-
+    ok = DistributedComm._device_reduce_ok
+    assert ok(Operators.SUM) and ok(Operators.MAX) and ok(Operators.MIN)
+    assert ok(Operators.PROD) is False
     absmax = Operator.custom(
         "MAX", lambda a, b: np.where(np.abs(a) >= np.abs(b), a, b), 0.0)
-    assert comm._device_reduce_ok(Operators.MAX) is True
-    assert comm._device_reduce_ok(absmax) is False
+    assert ok(absmax) is False
     fake_sum = Operator.custom("SUM", lambda a, b: a, 0.0)
-    assert comm._device_reduce_ok(fake_sum) is False
+    assert ok(fake_sum) is False
 
 
 def test_reduce_scatter_shadowing_custom_sum_goes_host_path():
@@ -166,7 +71,7 @@ def test_reduce_scatter_shadowing_custom_sum_goes_host_path():
 
     comm = DistributedComm.__new__(DistributedComm)
     comm._rank, comm._n, comm._closed = 0, 2, False
-    comm._djits, comm._agreed_native = {}, {}
+    comm._djits = {}
 
     device_calls = []
     comm._device_rows_collective = (
@@ -190,19 +95,6 @@ def test_reduce_scatter_shadowing_custom_sum_goes_host_path():
 @pytest.mark.slow
 @pytest.mark.parametrize("procs", [2, 3])
 def test_checkdist_multiprocess(procs):
-    # feature-detect (ISSUE 7 satellite): checkdist's subprocess needs
-    # a jax whose CPU backend runs MULTIPROCESS computations. The
-    # `jax_num_cpu_devices` config arrived alongside that support —
-    # on older jax (this image) the XLA flag equivalent yields local
-    # devices but cross-process CPU collectives still raise
-    # "Multiprocess computations aren't implemented on the CPU
-    # backend", so the whole flow must skip, not fail.
-    import jax
-
-    if not hasattr(jax.config, "jax_num_cpu_devices"):
-        pytest.skip("this jax lacks jax_num_cpu_devices / multiprocess "
-                    "CPU computations; checkdist multiprocess needs a "
-                    "newer jax")
     port = _free_port()
     workers = [
         subprocess.Popen(

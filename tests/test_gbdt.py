@@ -699,7 +699,7 @@ def test_scanned_predict_matches_unrolled(rng):
     """predict scans over the stacked ensemble (one-tree program size);
     it must match the unrolled-loop formulation to 1 ulp (FMA fusion
     differs between program shapes, so exact bit-identity across XLA
-    programs is not attainable — BASELINE.md round-3 note)."""
+    programs is not attainable)."""
     import jax
 
     cfg = GBDTConfig(n_features=7, n_bins=16, depth=4)
@@ -771,7 +771,7 @@ def _raw_problem(rng, n=400, f=6):
 
 def test_train_raw_matches_manual_wiring(rng):
     """train_raw == QuantileBinner.fit + transform + train with the
-    same seed (the parity VERDICT round 4 asked for), and the fitted
+    same seed, and the fitted
     binner is retained for predict_raw."""
     from ytk_mp4j_tpu.models.binning import QuantileBinner
 

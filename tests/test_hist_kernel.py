@@ -94,6 +94,29 @@ def test_supported_gate():
     assert not pallas_hist_supported(8, 5)      # B not lane-aligned
     # depth-6 trees (32 nodes) fit the VMEM accumulator budget...
     assert pallas_hist_supported(256, 28, n_nodes=32)
-    # ...but depth-8 (128 nodes -> ~14.7 MB accumulator) must fall back
-    # to the matmul strategy instead of failing Mosaic VMEM allocation
+    # ...but depth-8 (128 nodes -> ~14.7 MB accumulator) does not
     assert not pallas_hist_supported(256, 28, n_nodes=128)
+
+
+@pytest.mark.parametrize("F,B,n_nodes", [(3, 16, 1), (28, 256, 128)])
+def test_pallas_mode_raises_on_unsupported_compiled_shape(rng, F, B,
+                                                          n_nodes):
+    """On a non-interpreted call hist_mode="pallas" compiles the kernel
+    or raises, naming the constraint and the explicit alternative — it
+    never hands over to the matmul strategy without a word."""
+    from ytk_mp4j_tpu.exceptions import Mp4jError
+    from ytk_mp4j_tpu.models.gbdt import GBDTConfig, build_histograms
+
+    N = 64
+    bins = jnp.array(rng.integers(0, B, (N, F)).astype(np.int32))
+    g = jnp.ones(N, jnp.float32)
+    nid = jnp.zeros(N, jnp.int32)
+    cfg = GBDTConfig(n_features=F, n_bins=B)
+    with pytest.raises(Mp4jError, match="hist_mode='matmul'") as e:
+        build_histograms(bins, g, g, nid, n_nodes, cfg, interpret=False)
+    assert f"n_bins={B}" in str(e.value)
+    # the explicit choice serves the same shape
+    explicit = GBDTConfig(n_features=F, n_bins=B, hist_mode="matmul")
+    hg, _ = build_histograms(bins, g, g, nid, n_nodes, explicit,
+                             interpret=False)
+    assert hg.shape == (n_nodes, F, B)

@@ -37,8 +37,6 @@ from ytk_mp4j_tpu.resilience.faults import FaultKill
 from ytk_mp4j_tpu.utils import stats as stats_mod
 from ytk_mp4j_tpu.utils import tuning
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 
 # ----------------------------------------------------------------------
 # histogram bucket math — property sweeps
@@ -572,14 +570,29 @@ def test_postmortem_dir_empty_means_disabled(tmp_path, monkeypatch):
 # ----------------------------------------------------------------------
 # bench-diff — the perf regression gate
 # ----------------------------------------------------------------------
-def test_bench_diff_on_checked_in_bench_files(capsys):
-    """Tier-1 seed of perf regression gating: the two checked-in BENCH
-    rounds compare clean (r05 did not regress r04), through the real
-    CLI."""
-    old = os.path.join(REPO, "BENCH_r04.json")
-    new = os.path.join(REPO, "BENCH_r05.json")
-    assert os.path.exists(old) and os.path.exists(new)
-    assert scope_main(["bench-diff", old, new]) == 0
+def test_bench_diff_clean_pair_both_envelopes(tmp_path, capsys):
+    """Tier-1 seed of perf regression gating: two bench documents that
+    did not regress compare clean through the real CLI — one in the
+    driver's ``{"parsed": ...}`` envelope, one bare (both input shapes
+    bench-diff accepts)."""
+    old = tmp_path / "round_a.json"
+    new = tmp_path / "round_b.json"
+    old.write_text(json.dumps({
+        "n": 4, "cmd": "python bench.py", "rc": 0, "tail": "...",
+        "parsed": {"metric": "gbdt-histogram-allreduce GB/s/chip",
+                   "value": 3.0, "unit": "GB/s/chip",
+                   "extra": {"trees_per_sec": 14.0,
+                             "socket_baseline_gbs": 0.10,
+                             "socket_collective_gbs": 0.040,
+                             "only_in_round_a": 1.0}}}))
+    new.write_text(json.dumps({
+        "metric": "gbdt-histogram-allreduce GB/s/chip",
+        "value": 3.1, "unit": "GB/s/chip",
+        "extra": {"trees_per_sec": 14.1,
+                  "socket_baseline_gbs": 0.09,      # -10%: inside 25%
+                  "socket_collective_gbs": 0.041,
+                  "only_in_round_b": 2.0}}))
+    assert scope_main(["bench-diff", str(old), str(new)]) == 0
     out = capsys.readouterr().out
     assert "socket_collective_gbs" in out
     assert "within budget" in out

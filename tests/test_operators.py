@@ -1,3 +1,5 @@
+import os
+
 import numpy as np
 import pytest
 
@@ -27,7 +29,7 @@ def test_identity(op, operand):
 
 @pytest.mark.parametrize("op", ALL_OPS, ids=lambda o: o.name)
 @pytest.mark.parametrize("operand", Operands.NUMERIC, ids=lambda o: o.name)
-def test_native_reduce_matches_numpy(op, operand, rng):
+def test_reduce_into_matches_numpy(op, operand, rng):
     if operand.dtype.kind == "f":
         a = rng.standard_normal(257).astype(operand.dtype)
         b = rng.standard_normal(257).astype(operand.dtype)
@@ -44,6 +46,30 @@ def test_native_backend_is_active():
     # The image has g++; the C++ hot loop must actually be in use.
     native._load()
     assert native.HAVE_NATIVE
+
+
+def test_native_library_keyed_on_sources_flags_and_cpu(tmp_path,
+                                                       monkeypatch):
+    """A library built from other sources, with other flags or on
+    another CPU is never loaded: its file name does not match."""
+    srcs = []
+    for name in ("a.cpp", "b.cpp"):
+        f = tmp_path / name
+        f.write_text(f"// {name}\n")
+        srcs.append(str(f))
+    monkeypatch.setattr(native, "_SRCS", srcs)
+    base = native._so_path()
+    assert base == native._so_path()                    # stable
+    assert os.path.dirname(base) == native._BUILD_DIR
+    (tmp_path / "b.cpp").write_text("// b.cpp, edited\n")
+    os.utime(srcs[1], (0, 0))       # contents decide, not the mtime
+    edited = native._so_path()
+    assert edited != base
+    monkeypatch.setattr(native, "_FLAGS", native._FLAGS + ["-DX"])
+    flagged = native._so_path()
+    assert flagged not in (base, edited)
+    monkeypatch.setattr(native, "_cpu_features", lambda: "flags : other")
+    assert native._so_path() not in (base, edited, flagged)
 
 
 def test_custom_operator():

@@ -7,7 +7,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from ytk_mp4j_tpu.utils.compat import shard_map  # jax-version compat import
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ytk_mp4j_tpu.operators import Operator, Operators

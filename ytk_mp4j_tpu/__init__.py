@@ -23,10 +23,6 @@ paragraph 0); the API surface below is built from the capability list in
 SURVEY.md section 2 and BASELINE.json, with naming chosen idiomatically.
 """
 
-from ytk_mp4j_tpu.utils import compat as _compat
-
-_compat.install()   # backfill jax.shard_map on jax < 0.6
-
 from ytk_mp4j_tpu.exceptions import Mp4jError
 from ytk_mp4j_tpu.operators import Operator, Operators
 from ytk_mp4j_tpu.operands import Operand, Operands

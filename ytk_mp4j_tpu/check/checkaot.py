@@ -37,6 +37,7 @@ from ytk_mp4j_tpu.ops import collectives as coll
 from ytk_mp4j_tpu.ops import ring
 from ytk_mp4j_tpu.ops import ring_kernel
 from ytk_mp4j_tpu.ops import sparse as sparse_ops
+from ytk_mp4j_tpu.utils.compile_cache import enable_compilation_cache
 
 AXIS = "mp4j"
 
@@ -232,11 +233,11 @@ def _rooted_scatter_sequential(x, n: int, root: int = 0):
 
 def check_rooted_lowerings(results: dict, mesh: Mesh, n: int,
                            L: int = 1 << 20):
-    """VERDICT round-2 #5: turn the rooted-collective docstring
+    """Turn the rooted-collective docstring
     arithmetic (ops/collectives.py reduce/gather/scatter) into compiler
     artifacts — the current allreduce/allgather/broadcast lowerings
     side by side with faithful hand-built rooted variants, so the cost
-    analysis is on record next to the prose (table in BASELINE.md)."""
+    analysis is on record next to the prose."""
     progs = {
         "rooted/reduce_current_allreduce":
             lambda x: coll.reduce(x[0], Operators.SUM, 0, AXIS)[None],
@@ -262,7 +263,7 @@ def check_hier_reduce_scatter(results: dict, devices, n: int,
                               L: int = 1 << 20):
     """Round-3 measured decision: tuple-axis reduce_scatter stays
     allreduce+slice because XLA's tuple psum is already hierarchical.
-    These three programs keep the evidence on record (BASELINE.md):
+    These three programs keep the evidence on record:
     the current lowering vs the two hand-staged psum_scatter cascades
     (outer-first needs no permute; inner-first shrinks the buffer
     before the DCN stage but pays a block permutation)."""
@@ -362,7 +363,7 @@ def check_gbdt(results: dict, devices, n: int, per: int = 8192):
 
 
 def check_ffm(results: dict, devices, n: int, per: int = 1024):
-    """The FFM sparse-gradient step (BASELINE.md configs[4] shape):
+    """The FFM sparse-gradient step (BASELINE.json configs[4] shape):
     score + grads + device-native sparse allreduce + update."""
     from ytk_mp4j_tpu.models.fm import FMConfig, FMTrainer
 
@@ -403,6 +404,7 @@ def main(argv=None) -> int:
                     help="TPU topology name (PJRT C-API spelling)")
     ap.add_argument("--out", default=None, help="write JSON artifact here")
     args = ap.parse_args(argv)
+    enable_compilation_cache()
 
     from jax.experimental import topologies
     topo = topologies.get_topology_desc(topology_name=args.topology,

@@ -434,8 +434,8 @@ def check_binning_dist(comm) -> int:
 def check_dense_plane_timing(comm, elems: int = 1 << 20) -> int:
     """A/B the dense data plane: device psum vs the host
     allgather+loop formulation on the same buffer. Correctness is
-    asserted; the timing is logged (loopback CPU timings are noisy —
-    the recorded numbers live in BASELINE.md)."""
+    asserted; the timing is logged only (loopback CPU timings are
+    noisy and say nothing about a device)."""
     import time
 
     from ytk_mp4j_tpu.operands import Operands
@@ -486,27 +486,16 @@ def main(argv=None) -> int:
 
     # CPU multi-process job: each process contributes --local-devices
     # virtual devices (the "multi-node without a cluster" pattern,
-    # SURVEY.md section 4). The device-count config is version-gated:
-    # `jax_num_cpu_devices` only exists on newer jax; older versions
-    # (this image ships one without it) take the XLA flag instead —
-    # which must be in the environment BEFORE jax initializes any
-    # backend, hence the env check ahead of the import.
-    import os
-
-    if "xla_force_host_platform_device_count" not in \
-            os.environ.get("XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "") +
-            f" --xla_force_host_platform_device_count="
-            f"{args.local_devices}").strip()
-
+    # SURVEY.md section 4). Workers are pinned to the CPU on purpose:
+    # a chip belongs to one process, and N workers that each tried to
+    # take it would fail or hang.
     import jax
 
+    from ytk_mp4j_tpu.utils.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", args.local_devices)
-    except AttributeError:
-        pass    # older jax: the XLA flag above already did the job
+    jax.config.update("jax_num_cpu_devices", args.local_devices)
     # DOUBLE/LONG operands round-trip through the devices; without x64
     # they would be silently downcast (the backend raises instead)
     jax.config.update("jax_enable_x64", True)

@@ -4,8 +4,8 @@ The reference's sparse ``Map<K, V>`` path re-serializes whole maps with
 Kryo every call (SURVEY.md section 3c). Round 2's TPU packing did the
 host half of that work per call too: ``sorted(set().union(*maps))`` over
 the full key union plus a per-entry Python pack loop — measured as the
-reason the device map path LOST to the socket dict loop at configs[2]
-(BASELINE.md round-3 A/B: 122k vs 169k keys/sec). A real sparse-gradient
+reason the device map path LOST to the socket dict loop at configs[2].
+A real sparse-gradient
 stream has a near-persistent vocabulary, so none of that work is
 per-call: these codecs assign each distinct key a stable int32 code ONCE
 (grow-only) and translate whole maps with vectorized numpy.

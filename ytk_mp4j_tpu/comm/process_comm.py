@@ -28,7 +28,7 @@ serialize phase counters (``utils.stats``).
 
 This path is also the semantic oracle the TPU path is differentially
 tested against, and the baseline the >=10x TPU bandwidth claim is
-measured against (BASELINE.md).
+measured against.
 """
 
 from __future__ import annotations

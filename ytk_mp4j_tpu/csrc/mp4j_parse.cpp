@@ -2,7 +2,7 @@
 // path (configs[4]: ytk-learn streams 1TB of libsvm text; SURVEY.md
 // section 1 flagship consumer). The Python per-token parser measured
 // ~100k rows/s on the bench host and numpy string->number casts are no
-// faster than Python's (~95 ns/item both ways, BASELINE.md round 5);
+// faster than Python's (~95 ns/item both ways);
 // this kernel parses the raw chunk bytes in one pass with hand-rolled
 // int/float scanners and no intermediate strings.
 //

@@ -19,8 +19,8 @@ TPU-first rebuild. Instances are padded to ``max_nnz`` static slots
   each, then a single identity-dropping scatter-add into the table,
   which merges duplicate rows natively (the device-native analogue of
   the reference's key-wise map merge; the map API's sort + segment
-  pack would be pure overhead here — round-3 A/B in BASELINE.md,
-  64.2 -> 38.1 ms/step). Bandwidth ~nnz instead of ~|V|: the TPU
+  pack would be pure overhead here — 64.2 -> 38.1 ms/step, v5e,
+  2026-07). Bandwidth ~nnz instead of ~|V|: the TPU
   translation of the reference's sparse map path.
 
 Model scores (order-2, sigmoid/logloss for classification):
@@ -236,8 +236,8 @@ def train_step_sparse(params, batch, cfg: FMConfig, capacity: int,
         # is a scatter-add, which merges duplicate rows natively — the
         # pack would be pure overhead (measured ~17 ms at the 524288-
         # row union shape: sort ~2 ms + segment reduce ~15 ms; the
-        # scatter costs the same either way, round-3 A/B in
-        # BASELINE.md). Gather every shard's slots and scatter them all.
+        # scatter costs the same either way). Gather every shard's
+        # slots and scatter them all.
         oi = lax.all_gather(li, axis_name, axis=0, tiled=True)
         ov = lax.all_gather(lv, axis_name, axis=0, tiled=True)
     else:
@@ -281,8 +281,8 @@ def train_step_sparse_sharded(params, batch, cfg: FMConfig, n: int,
 
     The replicated sparse step's serial floor is the per-chip
     scatter-add of ALL members' gradient rows (n*S descriptors into a
-    full replica; BASELINE.md prices it at 69.2 of 74.6 costed GB,
-    ~80 ns/row). Sharding changes both sides:
+    full replica; XLA's cost analysis prices it at 69.2 of 74.6
+    costed GB, ~80 ns/row). Sharding changes both sides:
 
     - forward: slot row-ids ride one (tiny, int32) all_gather; each
       member gathers the requested rows IT OWNS from its shard (row
@@ -640,8 +640,8 @@ class FMTrainer(DataParallelTrainer):
         At most ``max_in_flight`` steps stay in flight, bounding device
         memory at ~max_in_flight staged batches. ``max_in_flight=0``
         reproduces the fully serialized round-4 behavior (the A/B
-        baseline in bench.py; overlap measured 1.24-1.69x per trial on
-        the streaming bench, BASELINE.md round 5)."""
+        baseline in bench.py; the overlap's gain is not resolved
+        above noise, see ROADMAP S6)."""
         if params is None:
             params = self.init_params(seed)
         state = [self._place_params(params)]

@@ -72,11 +72,13 @@ class GBDTConfig:
     reg_lambda: float = 1.0
     n_trees: int = 10
     # "pallas": fused one-hot MXU matmul in VMEM (default; ~25% over
-    # "matmul", see ops/hist_kernel.py); "matmul": XLA one-hot MXU
-    # matmul (~5x the scatter strategies on v5e — see the performance
-    # note below; also the fallback when the pallas constraints don't
-    # hold); "pair": feature-pair joint scatter histograms (exact in
-    # f32, the differential oracle); "flat": one scatter per feature
+    # "matmul", see ops/hist_kernel.py; compiled on TPU it needs
+    # n_bins % 128 == 0 and raises otherwise); "matmul": XLA one-hot
+    # MXU matmul (~5x the scatter strategies on v5e — see the
+    # performance note below; the explicit choice where the pallas
+    # constraints don't hold); "pair": feature-pair joint scatter
+    # histograms (exact in f32, the differential oracle); "flat": one
+    # scatter per feature
     hist_mode: str = "pallas"
     # Missing-value handling (ytk-learn routes missing by a learned
     # per-split default direction): when True, bin 0 is the RESERVED
@@ -162,7 +164,7 @@ class GBDTConfig:
 # zero (measured: identical error to plain bf16).
 #
 # The per-level full-N scan is the measured optimum, not an oversight
-# (round-2 pricing on v5e at N=1M, see BASELINE.md): active-sample
+# (round-2 pricing on v5e at N=1M): active-sample
 # compaction (scan only the ~N/2 left-child rows below the root) costs
 # argsort 25 ms + row/vector gathers 62/46 ms per level on the serial
 # unit against ~21 ms of histogram saved; leaf-wise growth needs the
@@ -186,11 +188,12 @@ def build_histograms(bins, g, h, node_ids, n_nodes: int, cfg: GBDTConfig,
     Returns (hist_g, hist_h): [n_nodes, F, B] f32.
 
     Strategy "pallas" (default): the fused VMEM one-hot MXU kernel
-    (ops/hist_kernel.py); falls back to "matmul" when the kernel's
-    lane-alignment constraints don't hold on a compiled backend.
+    (ops/hist_kernel.py). Compiled, it either fits the kernel's
+    constraints or raises — there is no hand-over to another strategy;
+    ``hist_mode="matmul"`` is the explicit choice for other shapes.
     ``interpret`` selects the kernel's interpret mode (None: interpret
-    unless running on TPU — the CPU test suite and the driver's virtual
-    CPU meshes take the interpreted path). Strategy "matmul": XLA
+    unless running on TPU — the CPU test suite and the virtual CPU
+    meshes take the interpreted path). Strategy "matmul": XLA
     one-hot MXU matmul per tile (see the performance note). Strategy
     "pair" (when F is even and the joint table fits): one scatter of
     N*F/2 elements into per-feature-PAIR joint (B x B) histograms, then
@@ -199,20 +202,28 @@ def build_histograms(bins, g, h, node_ids, n_nodes: int, cfg: GBDTConfig,
     """
     F, B = cfg.n_features, cfg.n_bins
     if cfg.hist_mode == "pallas":
-        from ytk_mp4j_tpu.ops.hist_kernel import (pallas_hist_supported,
+        from ytk_mp4j_tpu.ops.hist_kernel import (PALLAS_HIST_CONSTRAINT,
+                                                  pallas_hist_supported,
                                                   pallas_histograms)
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
-        # the pallas HLO interpreter is not vma-aware, so interpreting
-        # inside shard_map trips check_vma; the matmul strategy is the
-        # semantically identical stand-in there (CPU test meshes)
-        under_shard_map = bool(getattr(jax.typeof(g), "vma", None))
-        if interpret and not under_shard_map:
+        if interpret:
+            # the pallas HLO interpreter is not vma-aware, so
+            # interpreting inside shard_map trips check_vma; the matmul
+            # strategy is the semantically identical stand-in there
+            # (CPU test meshes)
+            if getattr(jax.typeof(g), "vma", None):
+                return _build_histograms_matmul(bins, g, h, node_ids,
+                                                n_nodes, cfg)
             return pallas_histograms(bins, g, h, node_ids, n_nodes, F, B,
                                      interpret=True)
-        if not interpret and pallas_hist_supported(B, F, n_nodes):
-            return pallas_histograms(bins, g, h, node_ids, n_nodes, F, B)
-        return _build_histograms_matmul(bins, g, h, node_ids, n_nodes, cfg)
+        if not pallas_hist_supported(B, F, n_nodes):
+            raise Mp4jError(
+                f"hist_mode='pallas' cannot compile n_bins={B}, "
+                f"n_features={F}, n_nodes={n_nodes}: "
+                f"{PALLAS_HIST_CONSTRAINT}; choose hist_mode='matmul' "
+                f"explicitly for this shape")
+        return pallas_histograms(bins, g, h, node_ids, n_nodes, F, B)
     if cfg.hist_mode == "matmul":
         return _build_histograms_matmul(bins, g, h, node_ids, n_nodes, cfg)
     joint_mb = n_nodes * (F // 2) * B * B * 4 * 2 / 1e6
@@ -947,7 +958,7 @@ class GBDTTrainer(DataParallelTrainer):
                 # lax.scan over the stacked ensemble: program size is
                 # one tree regardless of T (the unrolled loop compiled
                 # O(T) programs — a compile-time cliff at ytk-learn-
-                # scale ensembles; round-3 measurement in BASELINE.md)
+                # scale ensembles)
                 def body(out, tree):
                     if softmax:
                         delta = jnp.stack(

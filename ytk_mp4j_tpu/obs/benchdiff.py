@@ -6,7 +6,7 @@ verdict per metric — the seed of perf regression gating for every
 future PR: drop two BENCH files in, get a nonzero exit when a tracked
 figure regressed past its budget.
 
-Accepted input shapes (both appear in the repo):
+Accepted input shapes:
 
 - the raw one-line bench output: ``{"metric", "value", "extra": {...}}``;
 - the driver wrapper: ``{"n", "cmd", "rc", "tail", "parsed": {...}}``
@@ -24,8 +24,8 @@ from __future__ import annotations
 import json
 
 # metric -> max tolerated fractional drop (new >= old * (1 - thr)).
-# Grounded in BENCH_r01..r05 run-to-run spread; tighten as the bench
-# host stabilizes. "value" is the headline GB/s/chip figure.
+# Grounded in the run-to-run spread of five earlier bench rounds on a
+# shared 1-core host; tighten as the bench host stabilizes. "value" is the headline GB/s/chip figure.
 THRESHOLDS: dict[str, float] = {
     "value": 0.10,
     "trees_per_sec": 0.10,

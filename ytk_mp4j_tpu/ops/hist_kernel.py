@@ -58,6 +58,13 @@ def split_bf16(a):
     return hi.astype(jnp.bfloat16), (a - hi).astype(jnp.bfloat16)
 
 
+# what pallas_hist_supported checks, for error messages
+PALLAS_HIST_CONSTRAINT = (
+    "the compiled kernel needs n_bins % 128 == 0 and a "
+    "[4*n_nodes, n_features*n_bins] f32 accumulator of at most "
+    f"{_MAX_ACC_BYTES // 2 ** 20} MiB")
+
+
 def pallas_hist_supported(n_bins: int, n_features: int,
                           n_nodes: int = 1) -> bool:
     """Compiled-path constraints: lane-aligned bin rows (static lane

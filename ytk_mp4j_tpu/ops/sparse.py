@@ -60,7 +60,7 @@ def sort_by_key(idx, val):
     180.5 GB bytes-accessed (AOT_r02) for 16 MB of live data — the
     gather's multi-chip lowering is pathological. The multi-operand
     sort carries each payload column through the sort comparators
-    instead (see BASELINE.md round-3 A/B for the measured delta).
+    instead.
 
     ``val`` may be [L] or [L, ...]; trailing dims ride as extra static
     payload columns. Beyond ``_MAX_SORT_PAYLOAD_COLS`` columns the

@@ -1,7 +1,8 @@
 """ctypes loader/builder for the native C++ hot loops.
 
-Compiles ``csrc/mp4j_native.cpp`` with g++ on first use (cached by source
-mtime) and exposes
+Compiles ``csrc/mp4j_native.cpp`` with g++ on first use (cached under a
+name keyed on the source contents, the flags and the host's CPU
+features) and exposes
 
 - :func:`reduce_into` — ``acc = op(acc, src)`` element-wise, the socket
   path's merge hot loop,
@@ -17,7 +18,9 @@ unavailable; the active backend is reported by :data:`HAVE_NATIVE`.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 
@@ -30,7 +33,7 @@ _SRC = os.path.join(_CSRC, "mp4j_native.cpp")
 _SRCS = [_SRC, os.path.join(_CSRC, "mp4j_transport.cpp"),
          os.path.join(_CSRC, "mp4j_parse.cpp")]
 _BUILD_DIR = os.path.join(_CSRC, "build")
-_SO = os.path.join(_BUILD_DIR, "libmp4j_native.so")
+_FLAGS = ["-O3", "-shared", "-fPIC", "-std=c++17", "-march=native"]
 
 # Must match csrc/mp4j_native.cpp DType.
 _DTYPE_CODES = {
@@ -49,18 +52,44 @@ _lib = None
 HAVE_NATIVE: bool | None = None
 
 
+def _cpu_features() -> str:
+    """What ``-march=native`` compiles for: the host's CPU feature line
+    (the machine name where /proc/cpuinfo is absent)."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return line
+    except OSError:
+        pass
+    return platform.machine()
+
+
+def _so_path() -> str:
+    """The cached library's path. Its name carries a hash of the source
+    CONTENTS, the compiler flags and the CPU features, so a build tree
+    copied from another machine (SIGILL under ``-march=native``) or
+    left over from other sources (an mtime-preserving sync) is never
+    loaded: the name does not match and the library is rebuilt."""
+    key = hashlib.sha256()
+    for src in _SRCS:
+        with open(src, "rb") as f:
+            key.update(f.read())
+    key.update(" ".join(_FLAGS).encode())
+    key.update(_cpu_features().encode())
+    return os.path.join(_BUILD_DIR,
+                        f"libmp4j_native-{key.hexdigest()[:16]}.so")
+
+
 def _build() -> str:
+    so = _so_path()
+    if os.path.exists(so):
+        return so
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    newest_src = max(os.path.getmtime(s) for s in _SRCS)
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= newest_src:
-        return _SO
-    cmd = [
-        "g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-march=native",
-        *_SRCS, "-o", _SO + ".tmp",
-    ]
-    subprocess.run(cmd, check=True, capture_output=True)
-    os.replace(_SO + ".tmp", _SO)
-    return _SO
+    subprocess.run(["g++", *_FLAGS, *_SRCS, "-o", so + ".tmp"],
+                   check=True, capture_output=True)
+    os.replace(so + ".tmp", so)
+    return so
 
 
 def _load():
@@ -72,9 +101,7 @@ def _load():
             return _lib
         try:
             lib = ctypes.CDLL(_build())
-            # probe the NEWEST symbol: a stale cached .so (an
-            # mtime-preserving sync of newer sources over an old build
-            # tree) would lack it, and missing symbols must mean
+            # probe the NEWEST symbol: missing symbols must mean
             # "native unavailable", never an AttributeError crash in
             # every consumer
             lib.mp4j_progress_multi
