@@ -103,11 +103,21 @@ def test_main_prints_contract_json(monkeypatch, capsys):
     assert summary["machine"]["scalar_round_trip_secs"] > 0
 
 
+_CACHE_OPTIONS = ("jax_compilation_cache_dir",
+                  "jax_compilation_cache_include_metadata_in_key",
+                  "jax_persistent_cache_min_compile_time_secs",
+                  "jax_persistent_cache_min_entry_size_bytes")
+
+
 @pytest.fixture
 def cache_config():
-    before = jax.config.jax_compilation_cache_dir
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = {name: getattr(jax.config, name) for name in _CACHE_OPTIONS}
     yield
-    jax.config.update("jax_compilation_cache_dir", before)
+    for name, value in before.items():
+        jax.config.update(name, value)
+    compilation_cache.reset_cache()
 
 
 def test_cache_helper_sets_nothing_when_variable_set(cache_config,
@@ -119,7 +129,8 @@ def test_cache_helper_sets_nothing_when_variable_set(cache_config,
     monkeypatch.setattr(jax.config, "update",
                         lambda *a, **k: calls.append(a))
     compile_cache.enable_compilation_cache()
-    assert calls == []
+    assert [a[0] for a in calls] == [
+        "jax_compilation_cache_include_metadata_in_key"]
 
 
 def test_cache_helper_default_is_fixed_checkout_path(cache_config,
@@ -129,3 +140,38 @@ def test_cache_helper_default_is_fixed_checkout_path(cache_config,
     second = compile_cache.enable_compilation_cache()
     assert first == second == os.path.join(REPO, ".jax_cache")
     assert jax.config.jax_compilation_cache_dir == first
+
+
+
+def test_a_named_scope_is_part_of_the_cache_key(cache_config, monkeypatch,
+                                                tmp_path):
+    """The device trace is read by the program's scope names, so a
+    program must not be read from an entry compiled under other names:
+    two programs that differ in a ``jax.named_scope`` alone are two
+    entries once the helper has run, and one entry by jax's default."""
+    from jax.experimental.compilation_cache import compilation_cache
+    import jax.numpy as jnp
+
+    def program(scope):
+        def f(x):
+            with jax.named_scope(scope):
+                return x * 2 + 1
+        return jax.jit(f)
+
+    def entries(where, with_helper):
+        compilation_cache.reset_cache()
+        jax.config.update("jax_compilation_cache_dir", str(where))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          False)
+        if with_helper:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(where))
+            assert compile_cache.enable_compilation_cache() == str(where)
+        for scope in ("mp4j.one", "mp4j.other"):
+            program(scope)(jnp.arange(7.0)).block_until_ready()
+        return len([f for f in os.listdir(where) if f.startswith("jit_f-")
+                    and f.endswith("-cache")])
+
+    assert entries(tmp_path / "default", with_helper=False) == 1
+    assert entries(tmp_path / "helper", with_helper=True) == 2

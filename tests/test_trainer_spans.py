@@ -1,0 +1,355 @@
+"""Trainer spans and device scopes (ISSUE 24): ``train()`` and
+``fit_stream()`` leave exactly the ``mp4j.*`` host spans PERF.md section 3
+lists in the span ring, with the shared ``job`` / ``chunk`` argument and
+children inside parents; the ring switched off leaves none and changes no
+result; the lowered steps carry every named scope, and the scopes change
+no instruction of the optimised program."""
+
+import contextlib
+import glob
+import json
+import re
+from functools import partial
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ytk_mp4j_tpu.models import fm as fm_mod
+from ytk_mp4j_tpu.models.fm import FMConfig, FMTrainer
+from ytk_mp4j_tpu.models.gbdt import GBDTConfig, GBDTTrainer
+from ytk_mp4j_tpu.models.linear import LinearConfig, LinearTrainer
+from ytk_mp4j_tpu.obs import spans
+from ytk_mp4j_tpu.operators import Operators
+from ytk_mp4j_tpu.ops import collectives
+from ytk_mp4j_tpu.ops.hist_kernel import pallas_histograms
+from ytk_mp4j_tpu.parallel.mesh import make_mesh
+from ytk_mp4j_tpu.utils import trace, tuning
+
+N_SHARDS = 4
+
+
+@pytest.fixture
+def ring():
+    """A fresh, private-sized ring; the job's own size afterwards."""
+    spans.configure(4096)
+    try:
+        yield
+    finally:
+        spans.configure(tuning.span_ring_capacity())
+
+
+def _trainer_spans():
+    return [s for s in spans.snapshot() if s[1] == "trainer"]
+
+
+def _named(recorded, name):
+    return [s for s in recorded if s[0] == name]
+
+
+def _inside(child, parent) -> bool:
+    return (parent[2] <= child[2]
+            and child[2] + child[3] <= parent[2] + parent[3])
+
+
+def _gbdt(rng):
+    N, F, B = 512, 4, 16
+    bins = rng.integers(0, B, (N, F)).astype(np.int32)
+    y = (bins[:, 0] > B // 2).astype(np.float32)
+    cfg = GBDTConfig(n_features=F, n_bins=B, depth=3, n_trees=3,
+                     loss="logistic")
+    return GBDTTrainer(cfg, mesh=make_mesh(N_SHARDS)), bins, y
+
+
+def _ffm(rng, n_chunks, **kw):
+    cfg = FMConfig(n_features=32, n_fields=4, k=4, max_nnz=4, model="ffm",
+                   learning_rate=0.1)
+    chunks = []
+    for _ in range(n_chunks):
+        feats = rng.integers(0, 32, (16, 4)).astype(np.int32)
+        fields = np.tile(np.arange(4, dtype=np.int32), (16, 1))
+        vals = rng.random((16, 4)).astype(np.float32) + 0.1
+        chunks.append((feats, fields, vals,
+                       rng.integers(0, 2, 16).astype(np.float32)))
+    return FMTrainer(cfg, mesh=make_mesh(N_SHARDS), sparse_grads=True,
+                     **kw), chunks
+
+
+def _linear(rng, n_chunks):
+    cfg = LinearConfig(n_features=5, learning_rate=0.1)
+    chunks = [(rng.standard_normal((16, 5)).astype(np.float32),
+               rng.standard_normal(16).astype(np.float32))
+              for _ in range(n_chunks)]
+    return LinearTrainer(cfg, mesh=make_mesh(N_SHARDS)), chunks
+
+
+# ------------------------------------------------------------- host spans
+def test_gbdt_train_leaves_exactly_its_spans(rng, ring):
+    tr, bins, y = _gbdt(rng)
+    tr.train(bins, y)
+    got = _trainer_spans()
+    assert sorted({s[0] for s in got}) == [
+        "mp4j.gbdt.dispatch", "mp4j.gbdt.fetch", "mp4j.gbdt.stage",
+        "mp4j.put_sharded", "mp4j.step.build"]
+    (stage,), (fetch,) = (_named(got, "mp4j.gbdt.stage"),
+                          _named(got, "mp4j.gbdt.fetch"))
+    dispatch = _named(got, "mp4j.gbdt.dispatch")
+    assert stage[6] == {"job": 0} and fetch[6] == {"job": 0}
+    assert [s[6] for s in dispatch] == [{"job": 0, "tree": i}
+                                        for i in range(3)]
+    puts = _named(got, "mp4j.put_sharded")
+    assert len(puts) == 4                       # bins, y, preds, weights
+    assert all(_inside(p, stage) for p in puts)
+    assert puts[0][6] == {"bytes": bins.nbytes}
+    # in order on the host: build, stage, every tree, fetch
+    order = [s[0] for s in got if s[0] != "mp4j.put_sharded"]
+    assert order == ["mp4j.step.build", "mp4j.gbdt.stage"] \
+        + ["mp4j.gbdt.dispatch"] * 3 + ["mp4j.gbdt.fetch"]
+    assert all(a[2] + a[3] <= b[2] for a, b in zip(
+        [stage] + dispatch, dispatch + [fetch]))
+
+    # the next job: a new ``job``, and the step is not built again
+    tr.train(bins, y, n_trees=1)
+    got = _trainer_spans()
+    assert len(_named(got, "mp4j.step.build")) == 1
+    assert [s[6]["job"] for s in _named(got, "mp4j.gbdt.stage")] == [0, 1]
+    assert _named(got, "mp4j.gbdt.dispatch")[-1][6] == {"job": 1, "tree": 0}
+
+
+@pytest.mark.parametrize("max_in_flight", [0, 2])
+@pytest.mark.parametrize("family", ["ffm", "linear"])
+def test_fit_stream_leaves_exactly_its_spans(rng, ring, family,
+                                             max_in_flight):
+    n = 5
+    tr, chunks = (_ffm if family == "ffm" else _linear)(rng, n)
+    tr.fit_stream(iter(chunks), max_in_flight=max_in_flight)
+    got = _trainer_spans()
+    stream = ["mp4j.stream.dispatch", "mp4j.stream.fetch",
+              "mp4j.stream.stage"]
+    if max_in_flight < n - 1:
+        stream.append("mp4j.stream.throttle")
+    # the linear step is built outside the loop and is not a span
+    built = ["mp4j.step.build"] if family == "ffm" else []
+    assert sorted({s[0] for s in got}) == sorted(
+        stream + ["mp4j.put_sharded"] + built)
+
+    stage = _named(got, "mp4j.stream.stage")
+    dispatch = _named(got, "mp4j.stream.dispatch")
+    assert [s[6] for s in stage] == [{"chunk": k} for k in range(n)]
+    assert [s[6] for s in dispatch] == [{"chunk": k} for k in range(n)]
+    # the throttle names the chunk it waits for: after chunk k is
+    # launched, the one ``max_in_flight`` before it
+    throttle = _named(got, "mp4j.stream.throttle")
+    assert [s[6]["chunk"] for s in throttle] == list(
+        range(n - 1 - max_in_flight))
+    (fetch,) = _named(got, "mp4j.stream.fetch")
+    assert fetch[6] == {"chunks": n}
+    # chunk k is staged while step k - 1 runs: after its dispatch
+    assert all(dispatch[k - 1][2] + dispatch[k - 1][3] <= stage[k][2]
+               for k in range(1, n))
+    puts = _named(got, "mp4j.put_sharded")
+    assert len(puts) % n == 0 and all(
+        any(_inside(p, s) for s in stage) for p in puts)
+    if built:
+        (build,) = _named(got, "mp4j.step.build")
+        assert _inside(build, dispatch[0])
+        assert build[6] == {"key": (16 // N_SHARDS) * 4}
+
+
+def test_a_new_padded_shape_is_a_second_build_span(rng, ring):
+    tr, chunks = _ffm(rng, 2)
+    tr.fit_stream(iter(chunks), batch_rows=16)
+    tr.fit_stream(iter(chunks), batch_rows=16)      # same step again
+    tr.fit_stream(iter(chunks), batch_rows=32)      # padded shape changed
+    keys = [s[6]["key"] for s in _named(_trainer_spans(),
+                                        "mp4j.step.build")]
+    assert keys == [16, 32]
+
+
+@pytest.mark.parametrize("family", ["gbdt", "ffm"])
+def test_ring_off_leaves_no_span_and_the_same_bits(rng, family):
+    def run():
+        r = np.random.default_rng(7)
+        if family == "gbdt":
+            tr, bins, y = _gbdt(r)
+            trees, margins = tr.train(bins, y)
+            return [np.asarray(a) for t in trees for a in t] + [margins]
+        tr, chunks = _ffm(r, 3)
+        params, losses = tr.fit_stream(iter(chunks))
+        return [np.asarray(p) for p in params] + [losses]
+
+    try:
+        spans.configure(4096)
+        on = run()
+        assert _trainer_spans()
+        spans.configure(0)
+        off = run()
+        assert not spans.enabled() and spans.snapshot() == []
+    finally:
+        spans.configure(tuning.span_ring_capacity())
+    assert len(on) == len(off)
+    for a, b in zip(on, off):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_span_records_through_an_exception_and_passes_it_on(ring):
+    with pytest.raises(KeyError):
+        with spans.span("mp4j.test.raises", chunk=3):
+            raise KeyError("x")
+    (s,) = _named(spans.snapshot(), "mp4j.test.raises")
+    assert s[1] == "trainer" and s[3] >= 0 and s[6] == {"chunk": 3}
+    with spans.span("mp4j.test.bare", cat="other"):
+        pass
+    (s,) = _named(spans.snapshot(), "mp4j.test.bare")
+    assert s[1] == "other" and s[6] is None
+
+
+def test_span_is_on_the_profilers_clock_under_a_profile(tmp_path, ring):
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with spans.span("mp4j.test.outer", job=4):
+            with spans.span("mp4j.test.inner", chunk=9):
+                jnp.ones(8).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    found = {e.name: (e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+             for plane in data.planes for line in plane.lines
+             for e in line.events if e.name.startswith("mp4j.test.")}
+    outer, inner = found["mp4j.test.outer"], found["mp4j.test.inner"]
+    assert outer[2] == {"job": 4} and inner[2] == {"chunk": 9}
+    assert outer[0] <= inner[0] and inner[1] <= outer[1]
+    # and in the ring as ever
+    assert [s[0] for s in _trainer_spans()] == ["mp4j.test.inner",
+                                                "mp4j.test.outer"]
+
+
+def test_chrome_trace_holds_trainer_events_with_monotone_ts(rng, ring,
+                                                            tmp_path):
+    tr, chunks = _ffm(rng, 3)
+    tr.fit_stream(iter(chunks))
+    path = str(tmp_path / "trace.json")
+    n = spans.export_chrome_trace(path)
+    with open(path) as f:
+        events = json.load(f)["traceEvents"]
+    trainer = [e for e in events if e["cat"] == "trainer"]
+    assert n == len(events) and len(trainer) == len(_trainer_spans())
+    assert {"mp4j.stream.stage", "mp4j.stream.dispatch",
+            "mp4j.stream.fetch"} <= {e["name"] for e in trainer}
+    assert all(e["ph"] == "X" and e["dur"] >= 0 for e in trainer)
+    ts = [e["ts"] for e in trainer]
+    assert ts == sorted(ts)
+    stage = next(e for e in trainer if e["name"] == "mp4j.stream.stage")
+    assert stage["args"] == {"chunk": 0}
+
+
+def test_trace_collectives_profiles_like_the_benchmark(monkeypatch,
+                                                       tmp_path):
+    """``profile_dir=`` starts the profiler with the python tracer off,
+    as ``benchmark/run.py`` does: an operator's trace and the
+    benchmark's are the same kind."""
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d, **kw: calls.append((d, kw)))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    with trace.trace_collectives(profile_dir=str(tmp_path)):
+        pass
+    ((where, kw),) = calls
+    assert where == str(tmp_path)
+    options = kw["profiler_options"]
+    assert options.python_tracer_level == 0
+    assert options.host_tracer_level == 2
+
+
+# ----------------------------------------------------------- device scopes
+def _batch_avals(tr, rows):
+    slots = (tr.n_shards, rows // tr.n_shards, tr.cfg.max_nnz)
+    i32, f32 = (jax.ShapeDtypeStruct(slots, jnp.int32),
+                jax.ShapeDtypeStruct(slots, jnp.float32))
+    row = jax.ShapeDtypeStruct(slots[:2], jnp.float32)
+    return i32, i32, f32, f32, row, row
+
+
+def _lower_ffm(rng, **kw):
+    tr, _ = _ffm(rng, 0, **kw)
+    params = tr._place_params(tr.init_params(0))
+    step = tr._build_step((16 // tr.n_shards) * tr.cfg.max_nnz)
+    return step.lower(params, *_batch_avals(tr, 16))
+
+
+def _lower_gbdt(rng):
+    tr, bins, y = _gbdt(rng)
+    data = tr.shard_data(bins, y)
+    kd = jax.random.key_data(jax.random.key(0))
+    return tr._build_step().lower(*data, kd)
+
+
+def _lower_collectives(rng):
+    mesh = make_mesh(N_SHARDS)
+
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("mp4j"),
+             out_specs=P("mp4j"), check_vma=False)
+    def every(x):
+        s = collectives.allreduce(x, Operators.SUM, "mp4j")
+        r = collectives.reduce_scatter(s, Operators.SUM, "mp4j")
+        return collectives.allgather(r, "mp4j") + s
+
+    return jax.jit(every).lower(jnp.ones((N_SHARDS * 8, 2), jnp.float32))
+
+
+@pytest.mark.parametrize("lower,scopes", [
+    (_lower_gbdt, ["gbdt.hist", "gbdt.route", "gbdt.best_splits",
+                   "gbdt.leaf"]),
+    (_lower_ffm, ["ffm.table_gather", "ffm.table_update"]),
+    (partial(_lower_ffm, sparse_capacity=8),
+     ["ffm.table_gather", "ffm.table_update", "sparse.sort_by_key",
+      "sparse.segment_reduce"]),
+    (partial(_lower_ffm, table_sharding="sharded"),
+     ["ffm.table_gather", "ffm.table_update", "sparse.sort_by_key",
+      "sparse.segment_reduce"]),
+    (_lower_collectives, ["mp4j.allreduce", "mp4j.reduce_scatter",
+                          "mp4j.allgather"]),
+], ids=["gbdt", "ffm", "ffm-dedupe", "ffm-sharded", "collectives"])
+def test_lowered_step_names_every_scope(rng, lower, scopes):
+    text = lower(rng).as_text(debug_info=True)
+    for scope in scopes:
+        # a name stack, not a file's path: the scope ends a component
+        assert re.search(rf'loc\("(?:[^"]*/)?{re.escape(scope)}[/"]', text), \
+            scope
+    assert "jit(step)" in text or "jit(every)" in text
+
+
+def test_histogram_kernel_has_a_name(rng):
+    bins = rng.integers(0, 16, (64, 4)).astype(np.int32)
+    g = rng.standard_normal(64).astype(np.float32)
+    jaxpr = jax.make_jaxpr(partial(pallas_histograms, n_nodes=2, F=4, B=16,
+                                   interpret=True))(
+        bins, g, g, np.zeros(64, np.int32))
+    assert "mp4j_hist" in str(jaxpr)
+
+
+def test_scopes_change_no_instruction_of_the_ffm_step(rng, monkeypatch):
+    """Metadata only: the optimised HLO of the sparse step has the same
+    instructions with the scopes and without them."""
+    def instructions(text):
+        return [re.sub(r", metadata=\{[^}]*\}", "", ln).strip()
+                for ln in text.splitlines() if " = " in ln]
+
+    with_scopes = _lower_ffm(np.random.default_rng(0)).compile().as_text()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    assert fm_mod.jax.named_scope("x").__class__ is contextlib.nullcontext
+    without = _lower_ffm(np.random.default_rng(0)).compile().as_text()
+    assert "ffm.table_update" in with_scopes
+    assert "ffm.table_" not in without
+    a, b = instructions(with_scopes), instructions(without)
+    assert len(a) == len(b) > 20
+    assert a == b

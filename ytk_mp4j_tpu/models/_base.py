@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ytk_mp4j_tpu.obs import spans
 from ytk_mp4j_tpu.parallel.mesh import make_mesh
 
 
@@ -266,19 +267,32 @@ class DataParallelTrainer:
             batch_rows = -(-batch_rows // self.n_shards) * self.n_shards
         pending: list = []
         staged = None
-        for chunk in batches:
-            if staged is not None:  # overlap: device runs step k-1
+
+        def launch():
+            with spans.span("mp4j.stream.dispatch", chunk=len(pending)):
                 pending.append(dispatch(staged))
+
+        # every host phase is a span carrying its chunk's index; the
+        # time between spans is the caller's iterator (reader, parser)
+        for k, chunk in enumerate(batches):
+            if staged is not None:  # overlap: device runs step k-1
+                launch()
                 if len(pending) > max_in_flight:
                     # bounds device memory AND queued programs (jax has
-                    # no "wait for queue depth" primitive)
-                    jax.block_until_ready(pending[-1 - max_in_flight])
-            staged, batch_rows = stage_chunk(chunk, batch_rows)
+                    # no "wait for queue depth" primitive); about a step
+                    # long when the device sets the pace, about nothing
+                    # when the host does
+                    waited = len(pending) - 1 - max_in_flight
+                    with spans.span("mp4j.stream.throttle", chunk=waited):
+                        jax.block_until_ready(pending[waited])
+            with spans.span("mp4j.stream.stage", chunk=k):
+                staged, batch_rows = stage_chunk(chunk, batch_rows)
         if staged is not None:
-            pending.append(dispatch(staged))
+            launch()
         if not pending:
             return np.zeros(0, np.float32)
-        return np.asarray(jax.device_get(pending))
+        with spans.span("mp4j.stream.fetch", chunks=len(pending)):
+            return np.asarray(jax.device_get(pending))
 
     def _pad_stream_rows(self, arrays, batch_rows: int):
         """Pad dim 0 of each chunk array up to ``batch_rows`` (raising
@@ -335,9 +349,10 @@ class DataParallelTrainer:
         this one (fully-REPLICATED placements of host inputs are fine —
         see ``_place_replicated``); the callback path is identical to
         device_put on single-process meshes."""
-        a = a.reshape((self.n_shards, per) + a.shape[1:])
-        return jax.make_array_from_callback(
-            a.shape, self._row_sharding(), lambda idx: a[idx])
+        with spans.span("mp4j.put_sharded", bytes=a.nbytes):
+            a = a.reshape((self.n_shards, per) + a.shape[1:])
+            return jax.make_array_from_callback(
+                a.shape, self._row_sharding(), lambda idx: a[idx])
 
     def save_params(self, path: str, params) -> None:
         """Persist a flat tuple of parameter arrays + the trainer config

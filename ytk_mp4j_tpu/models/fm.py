@@ -47,6 +47,7 @@ from ytk_mp4j_tpu.exceptions import Mp4jError
 from ytk_mp4j_tpu.models._base import (DataParallelTrainer,
                                        EarlyStopper, StepStatsExchanger,
                                        per_example_loss)
+from ytk_mp4j_tpu.obs import spans
 from ytk_mp4j_tpu.operators import Operators
 from ytk_mp4j_tpu.ops import sparse as sparse_ops
 
@@ -80,7 +81,8 @@ def _gather_slots(V, rows):
 
     rows come from :func:`_slot_rows` — [N, K] (fm) or [N, K, K]
     (ffm); result appends the latent dim k."""
-    return V[rows]
+    with jax.named_scope("ffm.table_gather"):
+        return V[rows]
 
 
 def _score_from_slots(w0, w, E, feats, xv, cfg: FMConfig):
@@ -250,7 +252,8 @@ def train_step_sparse(params, batch, cfg: FMConfig, capacity: int,
     if cfg.l2:
         V = V * (1.0 - lr * cfg.l2)     # decay all rows, like the dense
     safe = jnp.where(oi == sparse_ops.SENTINEL, V.shape[0], oi)
-    V = V.at[safe].add(-(lr / denom) * ov, mode="drop")
+    with jax.named_scope("ffm.table_update"):
+        V = V.at[safe].add(-(lr / denom) * ov, mode="drop")
     return (w0, w, V), loss
 
 
@@ -267,7 +270,8 @@ def _fetch_rows_sharded(Vs, flat_rows, me, axis_name):
                         tiled=False)            # [n, S] all requests
     owner = gi // B
     local = jnp.where(owner == me, gi - me * B, 0)
-    contrib = Vs[local]                         # [n, S, k] row gather
+    with jax.named_scope("ffm.table_gather"):
+        contrib = Vs[local]                     # [n, S, k] row gather
     contrib = jnp.where((owner == me)[..., None], contrib, 0.0)
     recv = lax.all_to_all(contrib, axis_name, split_axis=0,
                           concat_axis=0, tiled=False)   # [n, S, k]
@@ -347,7 +351,8 @@ def train_step_sparse_sharded(params, batch, cfg: FMConfig, n: int,
     if cfg.l2:
         Vs = Vs * (1.0 - lr * cfg.l2)
     safe = jnp.where(li == sparse_ops.SENTINEL, B, li)
-    Vs = Vs.at[safe].add(-(lr / denom) * lv, mode="drop")
+    with jax.named_scope("ffm.table_update"):
+        Vs = Vs.at[safe].add(-(lr / denom) * lv, mode="drop")
     return (w0, w, Vs), loss
 
 
@@ -486,7 +491,8 @@ class FMTrainer(DataParallelTrainer):
                          sw[0])
                 return step_fn(params, batch)
 
-            return jax.jit(step)
+            with spans.span("mp4j.step.build", key=per_shard_slots):
+                return jax.jit(step)
         if self.sparse_grads:
             cap = self.sparse_capacity
             if cap is None:
@@ -515,7 +521,8 @@ class FMTrainer(DataParallelTrainer):
             batch = (feats[0], fields[0], vals[0], mask[0], y[0], sw[0])
             return step_fn(params, batch)
 
-        return jax.jit(step)
+        with spans.span("mp4j.step.build", key=per_shard_slots):
+            return jax.jit(step)
 
     def _check_instances(self, feats: np.ndarray, fields: np.ndarray):
         """Shared id-range validation for fit and predict inputs (JAX

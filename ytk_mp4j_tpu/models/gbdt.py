@@ -39,6 +39,7 @@ from ytk_mp4j_tpu.models._base import (DataParallelTrainer, EarlyStopper,
                                        per_example_loss,
                                        stage_softmax_labels)
 from ytk_mp4j_tpu.exceptions import Mp4jError
+from ytk_mp4j_tpu.obs import spans
 from ytk_mp4j_tpu.ops.hist_kernel import split_bf16
 
 
@@ -175,6 +176,7 @@ class GBDTConfig:
 _MATMUL_TILE = 1024  # contraction tile; OH tile = tile*F*B*2 bytes in VMEM
 
 
+@jax.named_scope("gbdt.hist")
 def build_histograms(bins, g, h, node_ids, n_nodes: int, cfg: GBDTConfig,
                      interpret: bool | None = None):
     """Per-(node, feature, bin) gradient/hessian sums.
@@ -356,6 +358,7 @@ def _onehot_segment_sum2(val_a, val_b, seg_ids, n_segments: int):
     return out[0] + out[1], out[2] + out[3]         # [n_segments] f32 x2
 
 
+@jax.named_scope("gbdt.route")
 def _route_samples(bins, node_ids, feat, bin_, n_nodes: int, dir_=None,
                    cat_mask=None, missing_bin: bool = False,
                    n_bins: int | None = None):
@@ -387,6 +390,7 @@ def _route_samples(bins, node_ids, feat, bin_, n_nodes: int, dir_=None,
     return node_ids * 2 + go_right.astype(jnp.int32)
 
 
+@jax.named_scope("gbdt.best_splits")
 def best_splits(hist_g, hist_h, reg_lambda: float, feat_mask=None,
                 min_child_hessian: float = 0.0, cat_mask=None,
                 missing_bin: bool = False):
@@ -546,13 +550,14 @@ def _build_tree(bins, g, h, cfg: GBDTConfig, axis_name, interpret,
 
     # leaf values from (all-reduced) leaf G/H
     n_leaves = 2 ** cfg.depth
-    leaf_g, leaf_h = _onehot_segment_sum2(g, h, node_ids, n_leaves)
-    if axis_name is not None:
-        leaf_g = lax.psum(leaf_g, axis_name)
-        leaf_h = lax.psum(leaf_h, axis_name)
-    leaf_val = -leaf_g / (leaf_h + cfg.reg_lambda)
-    delta = cfg.learning_rate * _onehot_select(leaf_val, node_ids,
-                                               n_leaves)
+    with jax.named_scope("gbdt.leaf"):
+        leaf_g, leaf_h = _onehot_segment_sum2(g, h, node_ids, n_leaves)
+        if axis_name is not None:
+            leaf_g = lax.psum(leaf_g, axis_name)
+            leaf_h = lax.psum(leaf_h, axis_name)
+        leaf_val = -leaf_g / (leaf_h + cfg.reg_lambda)
+        delta = cfg.learning_rate * _onehot_select(leaf_val, node_ids,
+                                                   n_leaves)
     return delta, (tree_feat, tree_bin, tree_dir, leaf_val)
 
 
@@ -628,7 +633,8 @@ def train_tree_shard(bins, y, preds, cfg: GBDTConfig, axis_name=None,
                                       interpret, feat_mask)
             deltas.append(delta)
             trees.append(tree)
-        return preds + jnp.stack(deltas, axis=1), tuple(trees)
+        with jax.named_scope("gbdt.leaf"):
+            return preds + jnp.stack(deltas, axis=1), tuple(trees)
 
     # gradient/hessian of the scalar objective at the current margin
     if cfg.loss == "logistic":
@@ -643,7 +649,8 @@ def train_tree_shard(bins, y, preds, cfg: GBDTConfig, axis_name=None,
         h = h * weights
     delta, tree = _build_tree(bins, g, h, cfg, axis_name, interpret,
                               feat_mask)
-    return preds + delta, tree
+    with jax.named_scope("gbdt.leaf"):
+        return preds + delta, tree
 
 
 def predict_tree(bins, tree, cfg: GBDTConfig):
@@ -676,6 +683,7 @@ class GBDTTrainer(DataParallelTrainer):
         super().__init__(mesh=mesh, n_devices=n_devices)
         self.cfg = cfg
         self._step = None
+        self._jobs = 0         # train() calls so far: the spans' ``job``
         self._predict = None
         self._margin_step = None
         self._stacked_trees = None
@@ -703,7 +711,8 @@ class GBDTTrainer(DataParallelTrainer):
                 interpret=interpret, rng_key=rng_key)
             return new_preds[None], tree
 
-        return jax.jit(step)
+        with spans.span("mp4j.step.build"):
+            return jax.jit(step)
 
     def shard_data(self, bins: np.ndarray, y: np.ndarray,
                    sample_weight: np.ndarray | None = None):
@@ -764,8 +773,10 @@ class GBDTTrainer(DataParallelTrainer):
             y = stage_softmax_labels(y, self.cfg.n_classes)
         else:
             y = np.asarray(y, np.float32)
-        dbins, dy, dpreds, dw = self.shard_data(
-            np.asarray(bins, np.int32), y, sample_weight=sample_weight)
+        job, self._jobs = self._jobs, self._jobs + 1
+        with spans.span("mp4j.gbdt.stage", job=job):
+            dbins, dy, dpreds, dw = self.shard_data(
+                np.asarray(bins, np.int32), y, sample_weight=sample_weight)
 
         if early_stopping_rounds is not None and eval_set is None:
             raise Mp4jError("early_stopping_rounds requires an eval_set")
@@ -785,8 +796,9 @@ class GBDTTrainer(DataParallelTrainer):
         trees = []
         for i in range(n_trees if n_trees is not None
                        else self.cfg.n_trees):
-            kd = jax.random.key_data(jax.random.fold_in(base_key, i))
-            dpreds, tree = self._step(dbins, dy, dpreds, dw, kd)
+            with spans.span("mp4j.gbdt.dispatch", job=job, tree=i):
+                kd = jax.random.key_data(jax.random.fold_in(base_key, i))
+                dpreds, tree = self._step(dbins, dy, dpreds, dw, kd)
             trees.append(tree)
             metric = None
             if va is not None:
@@ -807,7 +819,8 @@ class GBDTTrainer(DataParallelTrainer):
                     break
         exchanger.drain()
         self.sync_round_history_ = exchanger.mean_map_history()
-        preds = self._to_host(dpreds)
+        with spans.span("mp4j.gbdt.fetch", job=job):
+            preds = self._to_host(dpreds)
         if self.cfg.loss == "softmax":
             return trees, preds.reshape(-1, self.cfg.n_classes)
         return trees, preds.reshape(-1)

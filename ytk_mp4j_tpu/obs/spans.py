@@ -15,6 +15,11 @@ O(1) ``deque.append`` — cheap enough to stay default-on.
 per-process thread id), loadable in ``chrome://tracing`` or Perfetto.
 Multi-process jobs export one file per rank; ``mp4j-scope merge``
 combines them into a single timeline (ranks keep distinct pids).
+
+:class:`span` is the one primitive the trainers (``models/``) time
+their host work with: the same ring record, and a
+``jax.profiler.TraceAnnotation`` of the same name, so that under a
+profile the span sits on the profiler's clock beside the device.
 """
 
 from __future__ import annotations
@@ -177,6 +182,47 @@ def collective(name: str, t0: float, dur: float, pid: int | None,
         return
     _append((name, "collective", t0, dur, pid or 0, _tid(),
              {"seq": seq}))
+
+
+# jax.profiler.TraceAnnotation, imported by the first span(): the socket
+# plane imports this module in processes that never import jax
+_annotation = None
+
+
+class span:
+    """Time the body as one span: ``with span("mp4j.stream.stage",
+    chunk=k): ...``.
+
+    The span is appended to the ring through :func:`record` (category
+    ``cat``, ``args`` as given: the identifier the spans of one unit
+    share, ``job=`` or ``chunk=``), and the body runs inside a
+    ``jax.profiler.TraceAnnotation(name, **args)``: while a profile is
+    being taken the same span is a host event on the profiler's clock;
+    otherwise the annotation is one check of a flag. Never entered
+    inside a jitted function (the body would be timed once, at trace
+    time)."""
+
+    __slots__ = ("name", "cat", "args", "_t0", "_ann")
+
+    def __init__(self, name: str, cat: str = "trainer", **args: Any):
+        self.name, self.cat, self.args = name, cat, args
+
+    def __enter__(self) -> "span":
+        global _annotation
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation
+
+            _annotation = TraceAnnotation
+        self._ann = _annotation(self.name, **self.args)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        dur = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        record(self.name, self.cat, self._t0, dur, None, self.args or None)
+        return False
 
 
 def snapshot() -> list[tuple]:

@@ -28,6 +28,7 @@ control flow, static axis sizes.
 
 from __future__ import annotations
 
+import jax
 from jax import lax
 import jax.numpy as jnp
 
@@ -79,6 +80,7 @@ def _tree_reduce_gathered(x, operator: Operator, axis_name):
     return parts[0]
 
 
+@jax.named_scope("mp4j.allreduce")
 def allreduce(x, operator: Operator = Operators.SUM, axis_name="mp4j"):
     """Element-wise reduce across the axis; every member gets the result.
 
@@ -123,6 +125,7 @@ def broadcast(x, root: int = 0, axis_name="mp4j"):
     return lax.psum(contrib, axis_name)
 
 
+@jax.named_scope("mp4j.allgather")
 def allgather(x, axis_name="mp4j", tiled: bool = True):
     """Concatenate every member's ``x`` along dim 0 (``tiled=True``), or
     stack on a new leading axis (``tiled=False``)."""
@@ -165,6 +168,7 @@ def scatter(x, root: int = 0, axis_name="mp4j"):
     return lax.dynamic_slice_in_dim(full, idx * block, block, axis=0)
 
 
+@jax.named_scope("mp4j.reduce_scatter")
 def reduce_scatter(x, operator: Operator = Operators.SUM, axis_name="mp4j"):
     """Element-wise reduce then split: member i receives block i of the
     reduction (i = :func:`flat_index`, row-major over tuple axes).

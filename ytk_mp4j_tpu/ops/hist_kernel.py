@@ -15,12 +15,15 @@ per-tile pipeline in VMEM:
   3. accumulate the [4*n_nodes, F*B] f32 output across grid steps
      (constant out index_map -> the accumulator stays resident in VMEM).
 
-Measured on v5e (N=1M, F=28, B=256, amortized over 30 dispatches):
-14.5 / 16.0 / 20.2 ms per level at n_nodes = 1 / 8 / 32, vs
-19.2 / 20.3 / 25.4 ms for the XLA matmul mode — ~25% faster, close to
-the VPU floor of the one-hot generation itself (~15 ms: compare +
-select over N*F*B lanes at ~1e12 lane-ops/s; element throughput is
-dtype-independent, so the remaining cost is algorithmic, not layout).
+Measured on TPU v5 lite, F=28, B=256, inside the train step, from the
+device trace (PERF.md section 6, PR 22): 8.6-8.8 ms a level at N=1M,
+95.6 ms a level at N=11M (573.6 ms a tree of six levels), 17.9% of the
+MXU roofline: the one-hot GENERATION on the VPU (compare + select over
+N*F*B lanes) is the floor, not the matmul; element throughput is
+dtype-independent, so the remaining cost is algorithmic, not layout.
+(The 2026-07 figures of the previous installation, 14.5-20.2 ms a
+level at 1M against 19.2-25.4 ms for the XLA matmul mode, no longer
+hold.)
 
 Constraints (checked by ``pallas_hist_supported``): B and F*B must be
 lane-aligned (multiples of 128) for the compiled path; any shape works
@@ -162,6 +165,7 @@ def pallas_histograms(bins, g, h, node_ids, n_nodes: int, F: int, B: int,
                                memory_space=pltpu.VMEM),
         out_shape=out_shape,
         interpret=interpret,
+        name="mp4j_hist",
     )(bins, g, h, node_ids)
     out = out.reshape(2, 2, n_nodes, F, B)      # [g/h, hi/lo, n, F, B]
     return out[0, 0] + out[0, 1], out[1, 0] + out[1, 1]

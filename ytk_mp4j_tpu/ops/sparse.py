@@ -49,6 +49,7 @@ _SEGMENT_REDUCERS = {
 }
 
 
+@jax.named_scope("sparse.sort_by_key")
 def sort_by_key(idx, val):
     """Jointly sort ``(idx, val)`` ascending by ``idx`` with ONE
     multi-operand ``lax.sort`` — key and payload ride the same sort
@@ -103,6 +104,7 @@ def pad_to(idx, val, capacity: int, operator: Operator = Operators.SUM):
             jnp.concatenate([val, pad_v]))
 
 
+@jax.named_scope("sparse.segment_reduce")
 def segment_reduce_sorted(idx, val, capacity: int,
                           operator: Operator = Operators.SUM):
     """Reduce runs of equal index in an idx-sorted stream into at most

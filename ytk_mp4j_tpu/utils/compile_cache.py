@@ -4,6 +4,8 @@ The directory is part of the cache key, so it must not move between
 runs: where ``JAX_COMPILATION_CACHE_DIR`` is set jax reads it itself
 and this module sets nothing; where it is unset the cache lives at
 ``<checkout>/.jax_cache``, derived from this file's location alone.
+Op metadata (source lines, ``jax.named_scope`` stacks) is part of the key
+here, so that a profile never shows another commit's names.
 
 Called from entry points (``chip_smoke.py``, ``bench.py`` and the
 ``check`` programs' ``main()``), never from ``import ytk_mp4j_tpu``: a
@@ -26,4 +28,11 @@ def enable_compilation_cache() -> str:
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir",
                           os.path.join(_CHECKOUT, ".jax_cache"))
+    # jax leaves op metadata out of the cache key by default, so a
+    # program read from an entry that another commit wrote carries THAT
+    # commit's name stacks (measured, PERF.md section 6, PR 24: the
+    # parent's FFM step reported this tree's ``ffm.table_*`` scopes).
+    # The scopes are what the device trace is read by: a stale name is a
+    # wrong measurement, a miss is only a compile.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return jax.config.jax_compilation_cache_dir

@@ -9,8 +9,10 @@ backend collective (socket, thread, device) records a
 count / time / bytes / effective GB/s per collective.
 
 Optionally forwards to the JAX profiler: pass ``profile_dir`` to wrap
-the traced region in ``jax.profiler.start_trace`` so device-path
-collectives appear on the XLA timeline (TensorBoard-loadable).
+the traced region in ``jax.profiler.start_trace`` (python tracer off, as
+the benchmark traces) so device-path collectives and the trainers'
+``obs.spans.span`` host spans appear on the XLA timeline
+(TensorBoard-loadable).
 
 Usage::
 
@@ -199,7 +201,13 @@ class trace_collectives:
             try:
                 import jax
 
-                jax.profiler.start_trace(self.profile_dir)
+                # host TraceMe events only, as benchmark/run.py traces:
+                # the python tracer slows the host it is measuring
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                options.host_tracer_level = 2
+                jax.profiler.start_trace(self.profile_dir,
+                                         profiler_options=options)
             except BaseException:
                 with _lock:
                     trace_collectives._profiler_owner = None
