@@ -1,0 +1,150 @@
+"""The GBDT step compiled for a described v5e (no chip, nothing runs):
+the binned table must stay the way it rests on the chip.
+
+A [N, 28] int32 table rests feature-major on the TPU (N on the lanes,
+``{1,0,2:T(1,128)}``, unpadded). Until PR 25 the histogram kernel asked
+for row-major [tile, F] blocks, so every tree paid a ``copy`` and a
+``pad`` of the table at 28 of 128 lanes (two temporaries of 4.57x the
+table each) and routing read the padded copy six times. These compiles
+pin that this cannot come back silently. All topology work happens
+inside fixtures, in this one file (one process may load libtpu).
+"""
+
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from ytk_mp4j_tpu.models.gbdt import GBDTConfig, GBDTTrainer
+from ytk_mp4j_tpu.ops.hist_kernel import (pallas_hist_supported,
+                                          pallas_histograms)
+
+ROWS, F, B, DEPTH = 1_000_000, 28, 256, 6
+TABLE_BYTES = ROWS * F * 4
+
+
+@pytest.fixture(scope="module")
+def topo_devices():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+def _compile_step(devices, chips, depth=DEPTH):
+    mesh = Mesh(np.asarray(devices[:chips]), ("mp4j",))
+    trainer = GBDTTrainer(GBDTConfig(n_features=F, n_bins=B, depth=depth,
+                                     loss="logistic"), mesh=mesh)
+    rows = NamedSharding(mesh, P("mp4j"))
+    per = ROWS // chips
+    kd = jax.eval_shape(lambda: jax.random.key_data(jax.random.key(0)))
+
+    def aval(shape, dtype, sharding=rows):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return trainer._build_step().lower(
+        aval((chips, per, F), jnp.int32), aval((chips, per), jnp.float32),
+        aval((chips, per), jnp.float32), aval((chips, per), jnp.float32),
+        aval(kd.shape, kd.dtype, NamedSharding(mesh, P()))).compile()
+
+
+@pytest.fixture(scope="module")
+def step_one_chip(topo_devices):
+    return _compile_step(topo_devices, 1)
+
+
+# an instruction whose result is an int32 array with F as its last
+# dimension: group 1 the dimensions, group 2 the most-minor dimension
+_TABLE = re.compile(r"= s32\[((?:\d+,)+%d)\]\{(\d+)" % F)
+
+
+def _row_major_tables(text, min_rows):
+    found = []
+    for line in text.splitlines():
+        m = _TABLE.search(line)
+        if not m:
+            continue
+        dims = [int(d) for d in m.group(1).split(",")]
+        if max(dims[:-1]) >= min_rows and int(m.group(2)) == len(dims) - 1:
+            found.append(line.strip()[:200])
+    return found
+
+
+def test_row_major_table_detector():
+    """The detector sees what PR 24's step held, and not the layouts
+    the step holds now (lines from the compiled texts)."""
+    old = """
+  %copy.1 = s32[1,1000000,28]{2,1,0:T(8,128)} copy(%param_0.1)
+  %pad.2 = s32[1000448,28]{1,0:T(8,128)} pad(%bitcast.3, %constant.4), padding=0_448x0_0
+"""
+    new = """
+  %bins.1 = s32[1,1000000,28]{1,0,2:T(1,128)} parameter(0)
+  %copy.612 = s32[1,1000000,28]{1,2,0:T(8,128)} copy(%param_0.45)
+  %bitcast.133 = s32[1000000,28]{0,1:T(8,128)} bitcast(%copy.627)
+  %copy_bitcast_fusion = s32[28,1000000]{1,0:T(8,128)} fusion(%bins.1)
+"""
+    assert len(_row_major_tables(old, ROWS)) == 2
+    assert _row_major_tables(new, ROWS) == []
+
+
+def test_step_holds_no_row_major_table(step_one_chip):
+    text = step_one_chip.as_text()
+    # the table comes in as it rests: N minor, no padding
+    assert "s32[1,%d,%d]{1,0,2:T(1,128)} parameter(0)" % (ROWS, F) in text
+    assert _row_major_tables(text, ROWS // 2) == []
+    # and nothing pads a table-sized array at all
+    assert not re.search(r"= s32\[[\d,]*\d{6,}[\d,]*\]\S* pad\(", text)
+
+
+def test_step_temporaries_under_twice_the_table(step_one_chip):
+    """PR 24's step held 9.1x the table in temporaries (two lane-padded
+    copies); one sublane-tiled copy (32/28 of the table) may remain."""
+    temp = step_one_chip.memory_analysis().temp_size_in_bytes
+    assert temp < 2 * TABLE_BYTES, temp / TABLE_BYTES
+
+
+def test_step_runs_six_kernels(step_one_chip):
+    assert step_one_chip.as_text().count("tpu_custom_call") == DEPTH
+
+
+def test_four_chip_step_compiles_with_the_kernel(topo_devices):
+    """Under shard_map the kernel's out_shape carries the shards' vma;
+    each level's histograms are all-reduced."""
+    step = _compile_step(topo_devices, 4, depth=3)
+    text = step.as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert " all-reduce(" in text or " all-reduce-start(" in text
+    assert _row_major_tables(text, ROWS // 8) == []
+    temp = step.memory_analysis().temp_size_in_bytes
+    assert temp < 2 * TABLE_BYTES // 4, temp
+
+
+@pytest.mark.parametrize("B,n_feat,n_nodes", [
+    (256, 28, 64),     # the Higgs width at the accumulator's limit
+    (4096, 2, 32),     # tallest one-hot: [B, tile]
+])
+def test_kernel_compiles_where_the_gate_says_so(topo_devices, B, n_feat,
+                                                n_nodes):
+    """What pallas_hist_supported admits, Mosaic compiles at the
+    module's tile (VMEM is the limit that interpret mode cannot see)."""
+    from jax.sharding import SingleDeviceSharding
+
+    assert pallas_hist_supported(B, n_feat, n_nodes)
+    one_chip = SingleDeviceSharding(topo_devices[0])
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n = 100_000
+    jax.jit(lambda b, g, h, i: pallas_histograms(
+        b, g, h, i, n_nodes, n_feat, B)).lower(
+        aval((n, n_feat), jnp.int32), aval((n,), jnp.float32),
+        aval((n,), jnp.float32), aval((n,), jnp.int32)).compile()

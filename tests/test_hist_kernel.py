@@ -1,28 +1,44 @@
 """Pallas histogram kernel (ops/hist_kernel.py): differential checks
 against a numpy oracle, in interpret mode on the CPU test rig."""
 
+from functools import partial
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
+from jax import lax
+from jax.sharding import Mesh, PartitionSpec as P
 
 from ytk_mp4j_tpu.ops.hist_kernel import (pallas_hist_supported,
                                           pallas_histograms)
 
 
-def np_hist(bins, g, node_ids, n_nodes, F, B):
+def bincount_hist(bins, v, node_ids, n_nodes, F, B):
+    """float64 reference: ids outside [0, n_nodes) count for nothing."""
+    keep = (node_ids >= 0) & (node_ids < n_nodes)
     out = np.zeros((n_nodes, F, B), np.float64)
-    for i in range(bins.shape[0]):
-        for f in range(F):
-            out[node_ids[i], f, bins[i, f]] += g[i]
+    for f in range(F):
+        flat = node_ids[keep].astype(np.int64) * B + bins[keep, f]
+        out[:, f, :] = np.bincount(flat, weights=v[keep].astype(np.float64),
+                                   minlength=n_nodes * B
+                                   ).reshape(n_nodes, B)
     return out
+
+
+def assert_matches_bincount(hg, hh, bins, g, h, nid, n_nodes, F, B):
+    for got, v in ((hg, g), (hh, h)):
+        np.testing.assert_allclose(
+            np.asarray(got), bincount_hist(bins, v, nid, n_nodes, F, B),
+            rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("n_nodes", [1, 4])
 @pytest.mark.parametrize("N", [64, 77, 300])
 def test_matches_numpy(rng, n_nodes, N):
-    """Odd N exercises the single-step sublane-rounding path (N < tile)
-    and the zero-padded rows."""
+    """Odd N exercises the single-step lane-rounding path (N < tile)
+    and the masked lanes past the table's end."""
     F, B = 3, 16
     bins = rng.integers(0, B, (N, F)).astype(np.int32)
     g = rng.standard_normal(N).astype(np.float32)
@@ -31,16 +47,81 @@ def test_matches_numpy(rng, n_nodes, N):
     hg, hh = pallas_histograms(
         jnp.array(bins), jnp.array(g), jnp.array(h), jnp.array(nid),
         n_nodes, F, B, interpret=True)
-    np.testing.assert_allclose(np.asarray(hg),
-                               np_hist(bins, g, nid, n_nodes, F, B),
-                               rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(hh),
-                               np_hist(bins, h, nid, n_nodes, F, B),
-                               rtol=1e-4, atol=1e-4)
+    assert_matches_bincount(hg, hh, bins, g, h, nid, n_nodes, F, B)
+
+
+# samples lie on the lanes: what is delicate is where N falls against
+# the tile (a multiple of 128 lanes) and what rests past the table's end
+_LANE_CASES = [
+    # (N, tile, n_nodes)
+    (50, 128, 1),        # N < 128: one step, most lanes masked
+    (127, 128, 16),      # tile - 1
+    (128, 128, 1),       # exact: no mask compiled in
+    (129, 128, 16),      # tile + 1: a second step with one live lane
+    (300, 128, 4),       # N not a multiple of the tile, three steps
+    (300, 1024, 16),     # default tile, rounded down to 384 lanes
+    (1025, 1024, 1),     # default tile + 1
+]
+
+
+@pytest.mark.parametrize("N,tile,n_nodes", _LANE_CASES)
+def test_lane_layout_matches_bincount(rng, N, tile, n_nodes):
+    """Ragged tails, sentinel ids (n_nodes and negative: the sibling
+    subtraction's right children) and zero-weight rows (shard padding)
+    against a float64 bincount, to the hi/lo bf16 split's ~2^-17 a
+    summand."""
+    F, B = 5, 16
+    bins = rng.integers(0, B, (N, F)).astype(np.int32)
+    g = rng.standard_normal(N).astype(np.float32)
+    h = rng.random(N).astype(np.float32)
+    nid = rng.integers(-1, n_nodes + 1, N).astype(np.int32)
+    g[::7] = 0.0
+    h[::7] = 0.0                                  # weight-0 rows
+    hg, hh = pallas_histograms(
+        jnp.array(bins), jnp.array(g), jnp.array(h), jnp.array(nid),
+        n_nodes, F, B, tile=tile, interpret=True)
+    assert hg.shape == hh.shape == (n_nodes, F, B)
+    assert_matches_bincount(hg, hh, bins, g, h, nid, n_nodes, F, B)
+
+
+def test_all_sentinel_ids_leave_exact_zeros(rng):
+    N, F, B, n_nodes = 200, 3, 8, 2
+    bins = jnp.array(rng.integers(0, B, (N, F)).astype(np.int32))
+    g = jnp.array(rng.standard_normal(N).astype(np.float32))
+    nid = jnp.array(np.where(np.arange(N) % 2, n_nodes, -1).astype(np.int32))
+    hg, hh = pallas_histograms(bins, g, g, nid, n_nodes, F, B, tile=128,
+                               interpret=True)
+    assert np.all(np.asarray(hg) == 0) and np.all(np.asarray(hh) == 0)
+
+
+def test_kernel_under_shard_map(rng):
+    """Each shard of a CPU mesh runs the kernel on its rows (ragged
+    against the tile) and the psum is the whole table's histogram.
+    check_vma is off because the Pallas interpreter is not vma-aware;
+    the compiled kernel's vma out_shape is exercised by
+    tests/test_gbdt_aot.py on a described four-chip mesh."""
+    n_dev, per, F, B, n_nodes = 4, 150, 3, 16, 2
+    mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("mp4j",))
+    bins = rng.integers(0, B, (n_dev, per, F)).astype(np.int32)
+    g = rng.standard_normal((n_dev, per)).astype(np.float32)
+    h = rng.random((n_dev, per)).astype(np.float32)
+    nid = rng.integers(0, n_nodes + 1, (n_dev, per)).astype(np.int32)
+
+    @jax.jit
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P("mp4j"),) * 4,
+             out_specs=P(), check_vma=False)
+    def hist(b, g, h, i):
+        a, c = pallas_histograms(b[0], g[0], h[0], i[0], n_nodes, F, B,
+                                 tile=128, interpret=True)
+        return lax.psum(a, "mp4j"), lax.psum(c, "mp4j")
+
+    hg, hh = hist(bins, g, h, nid)
+    assert_matches_bincount(hg, hh, bins.reshape(-1, F), g.reshape(-1),
+                            h.reshape(-1), nid.reshape(-1), n_nodes, F, B)
 
 
 def test_multi_tile_grid(rng):
-    """N > tile: accumulation across grid steps, plus pad-row zeroing."""
+    """N > tile: accumulation across grid steps, plus the masked tail."""
     N, F, B = 100, 2, 8
     bins = rng.integers(0, B, (N, F)).astype(np.int32)
     g = rng.standard_normal(N).astype(np.float32)
@@ -50,7 +131,7 @@ def test_multi_tile_grid(rng):
         jnp.array(bins), jnp.array(g), jnp.array(h), jnp.array(nid),
         1, F, B, tile=32, interpret=True)
     np.testing.assert_allclose(np.asarray(hg),
-                               np_hist(bins, g, nid, 1, F, B),
+                               bincount_hist(bins, g, nid, 1, F, B),
                                rtol=1e-4, atol=1e-4)
     assert float(np.asarray(hh).sum()) == pytest.approx(N * F, rel=1e-4)
 
@@ -82,7 +163,7 @@ def test_hi_lo_split_precision(rng):
     hg, _ = pallas_histograms(
         jnp.array(bins), jnp.array(g), jnp.array(h), jnp.array(nid),
         1, F, B, interpret=True)
-    want = np_hist(bins, g.astype(np.float64), nid, 1, F, B)
+    want = bincount_hist(bins, g.astype(np.float64), nid, 1, F, B)
     rel = np.abs(np.asarray(hg, np.float64) - want).max() / want.max()
     assert rel < 1e-5
 

@@ -5,25 +5,44 @@ Higgs 11Mx28, 256 bins"). The XLA "matmul" strategy in models/gbdt.py
 routes the histogram onto the MXU via a one-hot matmul, but XLA
 materializes the per-tile one-hot and the hi/lo-split A operand through
 HBM between the compare and the dot. This kernel fuses the whole
-per-tile pipeline in VMEM:
+per-tile pipeline in VMEM, with the SAMPLES ON THE LANES:
 
-  1. build A = [g_hi | g_lo | h_hi | h_lo] x node-one-hot, a
-     [tile, 4*n_nodes] bf16 operand, from g/h/node_ids tiles
-     (hi/lo mantissa bit-split for near-f32 accuracy);
-  2. for each feature, generate the [tile, B] bin one-hot in VMEM and
-     feed the MXU directly (contraction over the tile axis);
+  1. build A^T = [g_hi | g_lo | h_hi | h_lo] x node-one-hot, a
+     [4*n_nodes, tile] bf16 operand, from [1, tile] rows of g / h /
+     node_ids broadcast along sublanes (hi/lo mantissa bit-split for
+     near-f32 accuracy);
+  2. for each feature, generate the [B, tile] bin one-hot in VMEM (one
+     row of the bins block against a sublane iota) and feed the MXU
+     directly (contraction over the lane axis of both operands);
   3. accumulate the [4*n_nodes, F*B] f32 output across grid steps
      (constant out index_map -> the accumulator stays resident in VMEM).
 
-Measured on TPU v5 lite, F=28, B=256, inside the train step, from the
-device trace (PERF.md section 6, PR 22): 8.6-8.8 ms a level at N=1M,
-95.6 ms a level at N=11M (573.6 ms a tree of six levels), 17.9% of the
-MXU roofline: the one-hot GENERATION on the VPU (compare + select over
-N*F*B lanes) is the floor, not the matmul; element throughput is
-dtype-independent, so the remaining cost is algorithmic, not layout.
-(The 2026-07 figures of the previous installation, 14.5-20.2 ms a
-level at 1M against 19.2-25.4 ms for the XLA matmul mode, no longer
-hold.)
+Why feature-major (PR 25): on the TPU a [N, 28] int32 table rests with
+N on the lanes (``s32[1,N,28]{1,0,2:T(1,128)}``, unpadded). The kernel
+takes it as [F, 1, N] in blocks (F, 1, tile), which is that very
+layout, so the step holds a bitcast of its parameter and no copy, and
+the ragged last tile is masked in the kernel instead of padded. The
+row-major kernel it replaced ([tile, F] blocks, samples on sublanes)
+made XLA copy and pad the table at 28 of 128 lanes in every tree (two
+temporaries of 5.63 GB at 11M rows, which routing then read six times)
+and spent half its own bundles on layout: 128 lane broadcasts of
+``ball[:, f]`` a feature a tile, a lane-sparse A and a transposition
+of A inside the dot. Final bundles a 1,024-sample tile at n_nodes
+1/2/4/8/16 (``--xla_jf_dump_to`` for v5e, libtpu 0.0.34, no chip):
+11,704/11,705/11,730/12,067/12,687 then, 5,617/5,546/5,826/6,164/7,267
+now.
+
+Measured on TPU v5 lite, F=28, B=256 (my chip runs, PR 25): 4.85-4.92
+ms a level at N=1M and 52.3-53.1 ms at N=11M standalone; inside the
+train step, from the device trace, 313.9 ms a tree of six levels at
+N=11M (573.6 for the row-major kernel), 32.6% of the MXU roofline
+(17.9%). The tile: 512 / 1024 / 2048 / 4096 samples gave 54.8 / 53.1 /
+52.3 / 51.9 ms a level at n_nodes=1 and 2.834 / 2.875 / 2.896 trees/s
+in the step for the last three; 2048 is taken because 4096 runs out of
+VMEM at shapes ``pallas_hist_supported`` admits (F=512: the bins block
+is F*tile*4 bytes, twice buffered) and 8192 slows down at n_nodes=32.
+What remains is the one-hot itself: 7,168 int32 compares and 3,584
+mask packs a 1,024-sample tile on the VPU against 3,584 MXU pushes.
 
 Constraints (checked by ``pallas_hist_supported``): B and F*B must be
 lane-aligned (multiples of 128) for the compiled path; any shape works
@@ -40,7 +59,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_TILE = 1024  # contraction tile (samples per grid step)
+_TILE = 2048  # samples (lanes) per grid step; see the docstring
 
 # The [4*n_nodes, F*B] f32 accumulator stays pinned in VMEM for the
 # whole grid (constant out index_map); leave headroom for the input
@@ -77,38 +96,49 @@ def pallas_hist_supported(n_bins: int, n_features: int,
     return n_bins % 128 == 0 and acc_bytes <= _MAX_ACC_BYTES
 
 
-def _hist_kernel(bins_ref, g_ref, h_ref, nid_ref, out_ref, *, tile, F, B,
-                 n_nodes):
-    @pl.when(pl.program_id(0) == 0)
+def _hist_kernel(bins_ref, g_ref, h_ref, nid_ref, out_ref, *, tile, N, F,
+                 B, n_nodes):
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
     def _():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    # A: [tile, 4*n_nodes] bf16 = [g_hi | g_lo | h_hi | h_lo] per node
-    nid = nid_ref[:]                                      # [tile] i32
-    iota_n = lax.broadcasted_iota(jnp.int32, (tile, n_nodes), 1)
-    noh = nid[:, None] == iota_n                          # [tile, n]
+    # A^T: [4*n_nodes, tile] bf16, rows [g_hi | g_lo | h_hi | h_lo] x
+    # node, samples on lanes. Row r holds quantity r // n_nodes of node
+    # r % n_nodes (worked out on a [C, 1] column: lax.div / lax.rem on
+    # int32, since jnp's // and % do not lower in Mosaic under x64);
+    # the [1, tile] rows of g / h / node_ids broadcast along sublanes.
+    C = 4 * n_nodes
+    row = lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+    quantity = lax.div(row, jnp.int32(n_nodes))           # 0..3
+    live = nid_ref[:] == lax.rem(row, jnp.int32(n_nodes))  # [C, tile]
+    if N % tile:
+        # the last grid step reads past the table's end: whatever rests
+        # there (any bin, any NaN) is selected away, never multiplied
+        lane = lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+        live &= i * tile + lane < N
+    v = jnp.where(live, jnp.where(quantity < 2, g_ref[:], h_ref[:]), 0.0)
+    hi, lo = split_bf16(v)
+    At = jnp.where(lax.rem(quantity, jnp.int32(2)) == 0, hi, lo)
 
-    def hilo(v):
-        return split_bf16(jnp.where(noh, v[:, None], 0.0))
+    # The one-hot of feature f is [B, tile]: row f of the bins block
+    # ([F, 1, tile]) broadcast along sublanes against a sublane iota.
+    # The int32 compare + select is the measured best formulation. The
+    # dead ends below were measured ON THE ROW-MAJOR KERNEL that PR 25
+    # replaced (round-2 pricing on v5e, B=256, N=1M) and have not been
+    # tried again in this layout: a bf16 arithmetic one-hot
+    # (relu(1 - |b - i|), exact for integers <= 256) was 9% faster
+    # STANDALONE (17.6 vs 19.3 ms) but ~20% slower in the fused train
+    # step (11.2-11.5 vs 14.1-14.2 trees/sec, alternating A/B); direct
+    # bf16/int16 == compares crashed the Mosaic compiler outright; tile
+    # 1024 beat 2048/4096 there (here 2048 beats 1024, see the module
+    # docstring).
+    iota_b = lax.broadcasted_iota(jnp.int32, (B, tile), 0)
 
-    g_hi, g_lo = hilo(g_ref[:])
-    h_hi, h_lo = hilo(h_ref[:])
-    A = jnp.concatenate([g_hi, g_lo, h_hi, h_lo], axis=1)  # [tile, 4n]
-
-    # The int32 compare+select below is the measured best formulation
-    # of the one-hot (round-2 pricing on v5e, B=256, N=1M): a bf16
-    # arithmetic one-hot (relu(1 - |b - i|), exact for integers <= 256)
-    # was 9% faster STANDALONE (17.6 vs 19.3 ms) but ~20% slower in the
-    # fused train step (11.2-11.5 vs 14.1-14.2 trees/sec, alternating
-    # A/B) — the 16-bit intermediates interact badly with the unrolled
-    # multi-level program; direct bf16/int16 == compares crash the
-    # Mosaic compiler outright. Tile 1024 beat 2048/4096.
-    iota_b = lax.broadcasted_iota(jnp.int32, (tile, B), 1)
-    ball = bins_ref[:]                                    # [tile, F]
-
-    for f in range(F):  # static unroll: lane slices must be static
-        oh = (ball[:, f:f + 1] == iota_b).astype(jnp.bfloat16)
-        part = lax.dot_general(A, oh, (((0,), (0,)), ((), ())),
+    for f in range(F):  # static unroll: one sublane row a feature
+        oh = (bins_ref[f] == iota_b).astype(jnp.bfloat16)
+        part = lax.dot_general(At, oh, (((1,), (1,)), ((), ())),
                                preferred_element_type=jnp.float32)
         out_ref[:, f * B:(f + 1) * B] += part
 
@@ -119,24 +149,22 @@ def pallas_histograms(bins, g, h, node_ids, n_nodes: int, F: int, B: int,
 
     bins: [N, F] int32 in [0, B); g, h: [N] f32; node_ids: [N] int32 —
     ids outside [0, n_nodes) contribute exactly nothing (the one-hot
-    matches no column; the GBDT sibling-subtraction path relies on this
-    to exclude right-child samples via a sentinel id). Returns
+    matches no row of A^T; the GBDT sibling-subtraction path relies on
+    this to exclude right-child samples via a sentinel id). Returns
     (hist_g, hist_h): [n_nodes, F, B] f32. Rows with g == h == 0
     (shard padding) contribute exactly nothing.
+
+    The kernel reads the table as [F, 1, N]: on the TPU a [N, 28] int32
+    table already rests that way (N on the lanes), so inside a jitted
+    step the transposition is a bitcast of the parameter. Nothing is
+    padded: the ragged last tile is masked inside the kernel.
     """
     N = bins.shape[0]
     if N == 0:
         z = jnp.zeros((n_nodes, F, B), jnp.float32)
         return z, z
     if N < tile:
-        tile = -(-N // 8) * 8          # single step, sublane-aligned
-    T = -(-N // tile)
-    pad = T * tile - N
-    if pad:  # zero g/h rows contribute exact-zero products
-        bins = jnp.pad(bins, ((0, pad), (0, 0)))
-        g = jnp.pad(g, (0, pad))
-        h = jnp.pad(h, (0, pad))
-        node_ids = jnp.pad(node_ids, (0, pad))
+        tile = -(-N // 128) * 128      # single step, lane-aligned
     C = 4 * n_nodes
     # under shard_map with check_vma, the out_shape must carry the
     # union of the inputs' varying-across-mesh-axes sets
@@ -147,25 +175,27 @@ def pallas_histograms(bins, g, h, node_ids, n_nodes: int, F: int, B: int,
         out_shape = jax.ShapeDtypeStruct((C, F * B), jnp.float32, vma=vma)
     else:
         out_shape = jax.ShapeDtypeStruct((C, F * B), jnp.float32)
+
+    def lanes(*lead):
+        """``tile`` samples on the lanes, the leading dimensions whole.
+        The index map returns int32 whatever jax_enable_x64 says:
+        Mosaic cannot legalize the i64 a bare 0 becomes under x64."""
+        return pl.BlockSpec(
+            lead + (tile,),
+            lambda i: (jnp.int32(0),) * len(lead) + (i,),
+            memory_space=pltpu.VMEM)
+
     out = pl.pallas_call(
-        functools.partial(_hist_kernel, tile=tile, F=F, B=B,
+        functools.partial(_hist_kernel, tile=tile, N=N, F=F, B=B,
                           n_nodes=n_nodes),
-        grid=(T,),
-        in_specs=[
-            pl.BlockSpec((tile, F), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((tile,), lambda i: (i,),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((tile,), lambda i: (i,),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((tile,), lambda i: (i,),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((C, F * B), lambda i: (0, 0),
+        grid=(-(-N // tile),),
+        in_specs=[lanes(F, 1), lanes(1), lanes(1), lanes(1)],
+        out_specs=pl.BlockSpec((C, F * B), lambda i: (jnp.int32(0),) * 2,
                                memory_space=pltpu.VMEM),
         out_shape=out_shape,
         interpret=interpret,
         name="mp4j_hist",
-    )(bins, g, h, node_ids)
+    )(jnp.transpose(bins[:, None, :], (2, 1, 0)), g.reshape(1, N),
+      h.reshape(1, N), node_ids.reshape(1, N))
     out = out.reshape(2, 2, n_nodes, F, B)      # [g/h, hi/lo, n, F, B]
     return out[0, 0] + out[0, 1], out[1, 0] + out[1, 1]
