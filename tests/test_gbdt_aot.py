@@ -6,8 +6,11 @@ A [N, 28] int32 table rests feature-major on the TPU (N on the lanes,
 for row-major [tile, F] blocks, so every tree paid a ``copy`` and a
 ``pad`` of the table at 28 of 128 lanes (two temporaries of 4.57x the
 table each) and routing read the padded copy six times. These compiles
-pin that this cannot come back silently. All topology work happens
-inside fixtures, in this one file (one process may load libtpu).
+pin that this cannot come back silently. Since PR 26 the same holds of
+a wide table: 1,183,747 x 968 rests as [F, N] in (8, 128) tiles
+(``{1,2,0:T(8,128)}``), the kernel takes it in feature blocks as it
+rests, and the step holds no copy of its 4.58 GB. All topology work
+happens inside fixtures, in this one file (one process may load libtpu).
 """
 
 import re
@@ -20,7 +23,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ytk_mp4j_tpu.models.gbdt import GBDTConfig, GBDTTrainer
-from ytk_mp4j_tpu.ops.hist_kernel import (pallas_hist_supported,
+from ytk_mp4j_tpu.ops.hist_kernel import (_rests_tiled, feature_blocks,
+                                          pallas_hist_supported,
                                           pallas_histograms)
 
 ROWS, F, B, DEPTH = 1_000_000, 28, 256, 6
@@ -39,12 +43,14 @@ def topo_devices():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
-def _compile_step(devices, chips, depth=DEPTH):
+def _compile_step(devices, chips, depth=DEPTH, n_rows=ROWS, n_features=F,
+                  **cfg):
     mesh = Mesh(np.asarray(devices[:chips]), ("mp4j",))
-    trainer = GBDTTrainer(GBDTConfig(n_features=F, n_bins=B, depth=depth,
-                                     loss="logistic"), mesh=mesh)
+    trainer = GBDTTrainer(GBDTConfig(n_features=n_features, n_bins=B,
+                                     depth=depth, loss="logistic", **cfg),
+                          mesh=mesh)
     rows = NamedSharding(mesh, P("mp4j"))
-    per = ROWS // chips
+    per, F = n_rows // chips, n_features
     kd = jax.eval_shape(lambda: jax.random.key_data(jax.random.key(0)))
 
     def aval(shape, dtype, sharding=rows):
@@ -61,15 +67,14 @@ def step_one_chip(topo_devices):
     return _compile_step(topo_devices, 1)
 
 
-# an instruction whose result is an int32 array with F as its last
-# dimension: group 1 the dimensions, group 2 the most-minor dimension
-_TABLE = re.compile(r"= s32\[((?:\d+,)+%d)\]\{(\d+)" % F)
-
-
-def _row_major_tables(text, min_rows):
+def _row_major_tables(text, min_rows, n_features=F):
+    # an instruction whose result is an int32 array with the features as
+    # its last dimension: group 1 the dimensions, group 2 the most-minor
+    # dimension
+    table = re.compile(r"= s32\[((?:\d+,)+%d)\]\{(\d+)" % n_features)
     found = []
     for line in text.splitlines():
-        m = _TABLE.search(line)
+        m = table.search(line)
         if not m:
             continue
         dims = [int(d) for d in m.group(1).split(",")]
@@ -100,6 +105,9 @@ def test_step_holds_no_row_major_table(step_one_chip):
     # the table comes in as it rests: N minor, no padding
     assert "s32[1,%d,%d]{1,0,2:T(1,128)} parameter(0)" % (ROWS, F) in text
     assert _row_major_tables(text, ROWS // 2) == []
+    # the kernel's operand is a bitcast of that parameter, not a copy
+    assert re.search(r"= s32\[%d,1,%d\]\{2,1,0:T\(1,128\)\} bitcast\(%%bins"
+                     % (F, ROWS), text)
     # and nothing pads a table-sized array at all
     assert not re.search(r"= s32\[[\d,]*\d{6,}[\d,]*\]\S* pad\(", text)
 
@@ -127,9 +135,78 @@ def test_four_chip_step_compiles_with_the_kernel(topo_devices):
     assert temp < 2 * TABLE_BYTES // 4, temp
 
 
+WIDE_ROWS, WIDE_F = 1_183_747, 968      # benchmark/configs/gbdt-bosch-968
+WIDE_TABLE_BYTES = WIDE_ROWS * WIDE_F * 4
+
+
+@pytest.fixture(scope="module")
+def wide_step(topo_devices):
+    return _compile_step(topo_devices, 1, n_rows=WIDE_ROWS,
+                         n_features=WIDE_F, missing_bin=True)
+
+
+def test_wide_step_reads_the_table_as_it_rests(wide_step):
+    text = wide_step.as_text()
+    assert "s32[1,%d,%d]{1,2,0:T(8,128)} parameter(0)" % (
+        WIDE_ROWS, WIDE_F) in text
+    assert re.search(r"= s32\[%d,%d\]\{1,0:T\(8,128\)\} bitcast\(%%bins"
+                     % (WIDE_F, WIDE_ROWS), text)
+    assert _row_major_tables(text, WIDE_ROWS // 2, WIDE_F) == []
+    # no copy, pad or transpose of anything with a table-sized dimension
+    assert not re.search(
+        r"= s32\[[\d,]*\d{6,},[\d,]*\d{3,}[\d,]*\]\S* (copy|pad|transpose)\(",
+        text)
+    assert text.count("tpu_custom_call") == DEPTH
+
+
+def test_wide_step_temporaries_far_below_the_table(wide_step):
+    """The six kernel outputs (up to 63 MB) and the split search's
+    [32, 968, 256] arrays: 0.108 GB when this was written, of 4.58."""
+    temp = wide_step.memory_analysis().temp_size_in_bytes
+    assert temp < WIDE_TABLE_BYTES // 16, temp / WIDE_TABLE_BYTES
+
+
+@pytest.mark.parametrize("n_feat,rests", [
+    (28, "{1,0,2:T(1,128)}"),       # F rows of N lanes: Higgs
+    (250, "{1,0,2:T(1,128)}"),
+    (700, "{1,0,2:T(1,128)}"),
+    (8, "{1,2,0:T(8,128)}"),        # [F, N] in (8, 128) tiles
+    (136, "{1,2,0:T(8,128)}"),
+    (968, "{1,2,0:T(8,128)}"),      # Bosch
+    (2000, "{1,2,0:T(8,128)}"),
+    (1024, "{2,"),                  # F minor, row-major: a transposing
+                                    # copy a step in either operand form
+])
+def test_where_a_table_rests_is_what_the_kernel_assumes(topo_devices, n_feat,
+                                                        rests):
+    """``_rests_tiled`` writes the runtime's choice of a parameter's
+    layout, made from the shape alone, into the kernel's choice of its
+    operand. A runtime that chooses otherwise would bring a copy of the
+    table back into every tree without any error: this lowering of the
+    parameter alone would then fail first."""
+    from jax.sharding import SingleDeviceSharding
+
+    table = jax.ShapeDtypeStruct(
+        (1, WIDE_ROWS, n_feat), jnp.int32,
+        sharding=SingleDeviceSharding(topo_devices[0]))
+    text = jax.jit(lambda b: (b[0] > 3).sum(1)).lower(table).compile() \
+        .as_text()
+    layout = re.search(r"entry_computation_layout=\{\(s32\[[\d,]+\](\S+?)\)->",
+                       text).group(1)
+    assert layout.startswith(rests), layout
+    if n_feat % 128:
+        assert _rests_tiled(n_feat) == rests.startswith("{1,2,0")
+
+
 @pytest.mark.parametrize("B,n_feat,n_nodes", [
-    (256, 28, 64),     # the Higgs width at the accumulator's limit
+    (256, 28, 64),     # one block of the Higgs width near the limit
     (4096, 2, 32),     # tallest one-hot: [B, tile]
+    (256, 968, 1),     # Bosch's root: 11 blocks of 88, (8, 128)-tiled
+    (256, 968, 16),    # ... and its deepest level
+    (256, 1024, 16),   # 8 blocks whose accumulators are 8 MiB each: the
+                       # out block must be single-buffered to fit
+    (256, 250, 16),    # rows of (1, 128): two blocks of 125
+    (256, 28, 128),    # depth 8 at the Higgs width: two blocks of 14
 ])
 def test_kernel_compiles_where_the_gate_says_so(topo_devices, B, n_feat,
                                                 n_nodes):
@@ -144,6 +221,8 @@ def test_kernel_compiles_where_the_gate_says_so(topo_devices, B, n_feat,
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     n = 100_000
+    assert feature_blocks(n_feat, B, n_nodes)[0] * 4 * n_nodes * B * 4 \
+        <= 8 * 2 ** 20
     jax.jit(lambda b, g, h, i: pallas_histograms(
         b, g, h, i, n_nodes, n_feat, B)).lower(
         aval((n, n_feat), jnp.int32), aval((n,), jnp.float32),

@@ -11,7 +11,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ytk_mp4j_tpu.ops.hist_kernel import (pallas_hist_supported,
+from ytk_mp4j_tpu.ops.hist_kernel import (_MAX_ACC_BYTES, feature_blocks,
+                                          pallas_hist_supported,
                                           pallas_histograms)
 
 
@@ -168,18 +169,80 @@ def test_hi_lo_split_precision(rng):
     assert rel < 1e-5
 
 
-def test_supported_gate():
-    assert pallas_hist_supported(256, 28)
-    assert pallas_hist_supported(128, 4)
-    assert not pallas_hist_supported(100, 28)   # B not lane-aligned
-    assert not pallas_hist_supported(8, 5)      # B not lane-aligned
-    # depth-6 trees (32 nodes) fit the VMEM accumulator budget...
-    assert pallas_hist_supported(256, 28, n_nodes=32)
-    # ...but depth-8 (128 nodes -> ~14.7 MB accumulator) does not
-    assert not pallas_hist_supported(256, 28, n_nodes=128)
+@pytest.mark.parametrize("B,F,n_nodes,ok", [
+    (256, 28, 1, True),
+    (128, 4, 1, True),
+    (100, 28, 1, False),        # B not lane-aligned
+    (8, 5, 1, False),           # B not lane-aligned
+    (256, 28, 32, True),        # depth 6, the Higgs width
+    # the number of features bounds nothing (until PR 26 the whole
+    # [4*n_nodes, F*B] accumulator had to fit: F <= 128 at 16 nodes)
+    (256, 28, 128, True),       # depth 8: two blocks of 14
+    (256, 968, 16, True),       # Bosch: 11 blocks of 88
+    (256, 2000, 32, True),      # Epsilon
+    (256, 28, 2048, True),      # one feature's accumulator: exactly 8 MiB
+    (256, 28, 4096, False),     # ... and 16 MiB: no block can hold it
+    (4096, 2, 128, True),
+    (4096, 2, 256, False),
+])
+def test_supported_gate(B, F, n_nodes, ok):
+    assert pallas_hist_supported(B, F, n_nodes) is ok
 
 
-@pytest.mark.parametrize("F,B,n_nodes", [(3, 16, 1), (28, 256, 128)])
+@pytest.mark.parametrize("F,B,n_nodes,want", [
+    (28, 256, 1, (28, 1)),          # Higgs: one block at every level
+    (28, 256, 16, (28, 1)),
+    (28, 256, 128, (14, 2)),        # depth 8: the accumulator bounds it
+    (968, 256, 1, (88, 11)),        # Bosch rests (8, 128)-tiled: whole
+    (968, 256, 16, (88, 11)),       # sublane tiles, none idle
+    (130, 256, 16, (65, 2)),
+    (131, 256, 16, (66, 2)),        # ragged: the last block holds 65
+    (250, 128, 16, (125, 2)),
+    (257, 128, 16, (86, 3)),        # ragged: 86 + 86 + 85
+    (136, 256, 16, (72, 2)),        # tiled and ragged: 72 + 64
+    (2000, 256, 16, (80, 25)),      # Epsilon: 25 x 80, none idle
+    (8, 4096, 128, (1, 8)),         # under a sublane tile a block
+])
+def test_feature_blocks_rule(F, B, n_nodes, want):
+    blk, n_blocks = feature_blocks(F, B, n_nodes)
+    assert (blk, n_blocks) == want
+    assert (n_blocks - 1) * blk < F <= n_blocks * blk
+    assert 4 * n_nodes * blk * B * 4 <= _MAX_ACC_BYTES
+
+
+# several feature blocks, ragged last blocks in both operand forms
+# ([F, 1, N] rows and [F, N] in sublane tiles), N against the tile and
+# sentinel ids, at the blocks the compiled kernel would take
+_BLOCK_CASES = [
+    # (F, B, N, tile, n_nodes)
+    (28, 128, 300, 128, 16),     # one block, the Higgs width
+    (130, 128, 300, 128, 16),    # two blocks of 65
+    (131, 128, 200, 128, 8),     # ragged rows: 66 + 65
+    (250, 128, 260, 256, 16),    # two blocks of 125, N one past the tile
+    (257, 128, 130, 128, 1),     # three blocks, ragged: 86 + 86 + 85
+    (136, 128, 300, 128, 16),    # sublane tiles, ragged: 72 + 64
+    (264, 128, 100, 128, 2),     # sublane tiles: 3 x 88
+]
+
+
+@pytest.mark.parametrize("F,B,N,tile,n_nodes", _BLOCK_CASES)
+def test_feature_blocks_match_bincount(rng, F, B, N, tile, n_nodes):
+    bins = rng.integers(0, B, (N, F)).astype(np.int32)
+    bins[rng.random((N, F)) < 0.8] = 0           # mostly the missing bucket
+    g = rng.standard_normal(N).astype(np.float32)
+    h = rng.random(N).astype(np.float32)
+    nid = rng.integers(-1, n_nodes + 1, N).astype(np.int32)
+    hg, hh = pallas_histograms(
+        jnp.array(bins), jnp.array(g), jnp.array(h), jnp.array(nid),
+        n_nodes, F, B, tile=tile, interpret=True)
+    assert hg.shape == hh.shape == (n_nodes, F, B)
+    assert_matches_bincount(hg, hh, bins, g, h, nid, n_nodes, F, B)
+
+
+@pytest.mark.parametrize("F,B,n_nodes", [
+    (3, 16, 1),           # bins not lane-aligned
+    (2, 4096, 256),       # one feature's accumulator is 16 MiB
+])
 def test_pallas_mode_raises_on_unsupported_compiled_shape(rng, F, B,
                                                           n_nodes):
     """On a non-interpreted call hist_mode="pallas" compiles the kernel

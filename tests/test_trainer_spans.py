@@ -119,6 +119,47 @@ def test_gbdt_train_leaves_exactly_its_spans(rng, ring):
     assert _named(got, "mp4j.gbdt.dispatch")[-1][6] == {"job": 1, "tree": 0}
 
 
+@pytest.mark.parametrize("F,depth,hist_mode,want", [
+    (4, 3, "pallas", {"hist_feature_block": 4, "hist_feature_blocks": 1}),
+    # the deepest level builds 2**(depth-2) left children: at 16 nodes
+    # and 256 bins a block holds at most 128 features, 968 go in 11 x 88
+    (968, 6, "pallas", {"hist_feature_block": 88,
+                        "hist_feature_blocks": 11}),
+    (968, 6, "matmul", None),
+])
+def test_step_build_span_says_which_histogram_grid_runs(ring, F, depth,
+                                                        hist_mode, want):
+    cfg = GBDTConfig(n_features=F, n_bins=256, depth=depth,
+                     hist_mode=hist_mode)
+    GBDTTrainer(cfg, mesh=make_mesh(1))._build_step()
+    (build,) = _named(_trainer_spans(), "mp4j.step.build")
+    assert build[6] == want
+
+
+@pytest.mark.parametrize("depth,want", [
+    (0, []), (1, [1]), (2, [1, 1]), (6, [1, 1, 2, 4, 8, 16])])
+def test_levels_build_the_root_then_the_left_children(depth, want):
+    """What ``_build_tree`` loops over and the build span reports the
+    last of."""
+    from ytk_mp4j_tpu.models.gbdt import hist_level_nodes
+    assert hist_level_nodes(depth) == want
+
+
+def test_row_placer_build_is_a_step_build_span(rng, ring):
+    """The program that places row chunks of an oversized shard is
+    built once a (table, chunk) shape, inside a ``mp4j.step.build`` span
+    of its own: a job that built it anew would show, as a rebuilt step
+    does."""
+    tr, bins, y = _gbdt(rng)
+    tr._ONE_TRANSFER_BYTES, tr._CHUNK_BYTES = bins.nbytes // N_SHARDS, 1024
+    for _ in range(2):
+        tr.train(bins, y, n_trees=1)
+    builds = [s[6] for s in _named(_trainer_spans(), "mp4j.step.build")]
+    assert len(builds) == 2 and "hist_feature_block" in builds[0]
+    assert builds[1] == {"key": "row_placer",
+                         "rows": 1024 // (bins.shape[1] * 4)}
+
+
 @pytest.mark.parametrize("max_in_flight", [0, 2])
 @pytest.mark.parametrize("family", ["ffm", "linear"])
 def test_fit_stream_leaves_exactly_its_spans(rng, ring, family,

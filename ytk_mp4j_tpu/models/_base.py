@@ -208,6 +208,7 @@ class DataParallelTrainer:
         self.axes = (self.mesh.axis_names[0]
                      if len(self.mesh.axis_names) == 1
                      else tuple(self.mesh.axis_names))
+        self._row_placers = {}      # _put_in_row_chunks' programs
 
     @property
     def n_shards(self) -> int:
@@ -348,11 +349,86 @@ class DataParallelTrainer:
         target non-addressable devices for ROW-SHARDED placements like
         this one (fully-REPLICATED placements of host inputs are fine —
         see ``_place_replicated``); the callback path is identical to
-        device_put on single-process meshes."""
+        device_put on single-process meshes.
+
+        A shard of ``_ONE_TRANSFER_BYTES`` or more crosses in row chunks
+        instead (``_put_in_row_chunks``)."""
         with spans.span("mp4j.put_sharded", bytes=a.nbytes):
             a = a.reshape((self.n_shards, per) + a.shape[1:])
+            if a.nbytes // self.n_shards >= self._ONE_TRANSFER_BYTES:
+                return self._put_in_row_chunks(a)
             return jax.make_array_from_callback(
                 a.shape, self._row_sharding(), lambda idx: a[idx])
+
+    # One host-to-device transfer of 2**32 bytes or more falls off a
+    # cliff in this runtime (my chip runs, PR 26, v5e host, int32
+    # [1, rows, 968]: 4.07 GB in 0.40 s = 10.1 GB/s, 4.30 GB in 23.3 s =
+    # 0.185 GB/s, the idle time named ``MapDmaBuffer``; 4.58 GB as one
+    # flat array, as four rows of a [4, ., 968] array or at another
+    # width: 21-25 s all the same). A shard that large goes in chunks of
+    # ``_CHUNK_BYTES`` (in their own shape 256 MiB and 1 GiB chunks both
+    # took 0.47 s for 4.58 GB, 64 MiB with nine in flight 0.40 s at
+    # three times the spread; the smaller holds less in flight).
+    _ONE_TRANSFER_BYTES = 2 ** 32
+    _CHUNK_BYTES = 256 * 2 ** 20
+
+    def _put_in_row_chunks(self, a: np.ndarray):
+        """``a`` [n_shards, per, ...] onto the mesh, rows sharded, a chunk
+        of rows at a time: a ``dynamic_update_slice`` places each chunk
+        in the donated table while the next is on its way. At most three
+        chunks are in flight; the table is never held twice.
+
+        A chunk crosses as [n_shards, M, 128], which rests on the device
+        in the order the host holds it, so the host's runtime has nothing
+        to transpose and the device puts the chunk into the table's
+        layout while the next one crosses. My chip runs, PR 26, the
+        4.58 GB Bosch table: 0.425 s (0.4243-0.4253 over four) against
+        0.470 s (0.466-0.482 over ten) when each chunk crossed in its
+        own shape and the host's threads tiled it; whole jobs of 8
+        trees, six each in one process, 10.014 s with a quartile
+        distance of 0.023 against 10.081 s and 0.093. A chunk whose
+        elements do not fill rows of 128 crosses in its own shape."""
+        n, per = a.shape[:2]
+        row = int(np.prod(a.shape[2:]))         # elements a row
+        rows = max(1, min(per, self._CHUNK_BYTES // (row * a.itemsize)))
+        if rows >= 128:
+            rows -= rows % 128                  # whole rows of 128 lanes
+        shape = (n, rows) + a.shape[2:]
+        wire = ((n, rows * row // 128, 128) if rows * row % 128 == 0
+                else shape)
+        sharding = self._row_sharding()
+        # one program a (table, chunk) shape, kept with the trainer: a
+        # job after the first builds nothing
+        key = (a.shape, a.dtype.str, rows)
+        place = self._row_placers.get(key)
+        if place is None:
+            def place(table, chunk, start):
+                at = [jnp.zeros((), start.dtype)] * table.ndim
+                at[1] = start
+                return (jax.lax.dynamic_update_slice(
+                    table, chunk.reshape(shape), at), chunk.reshape(-1)[0])
+
+            with spans.span("mp4j.step.build", key="row_placer",
+                            rows=rows):
+                place = self._row_placers[key] = jax.jit(
+                    place, donate_argnums=0,
+                    out_shardings=(sharding, None))
+        table = jnp.zeros(a.shape, a.dtype, device=sharding)
+        placed = []
+        for start in range(0, per, rows):
+            # the last chunk is as long as the others: it starts early
+            # and rewrites rows the chunk before it already placed
+            start = min(start, per - rows)
+            chunk = a[:, start:start + rows]
+            dchunk = jax.make_array_from_callback(
+                wire, sharding,
+                lambda idx, chunk=chunk: chunk[idx[0]].reshape(
+                    (-1,) + wire[1:]))
+            table, done = place(table, dchunk, np.int32(start))
+            placed.append(done)
+            if len(placed) > 2:
+                jax.block_until_ready(placed.pop(0))
+        return table
 
     def save_params(self, path: str, params) -> None:
         """Persist a flat tuple of parameter arrays + the trainer config

@@ -74,7 +74,9 @@ class GBDTConfig:
     n_trees: int = 10
     # "pallas": fused one-hot MXU matmul in VMEM (default; ~25% over
     # "matmul", see ops/hist_kernel.py; compiled on TPU it needs
-    # n_bins % 128 == 0 and raises otherwise); "matmul": XLA one-hot
+    # n_bins % 128 == 0 and one feature's [4*n_nodes, n_bins] f32
+    # accumulator within 8 MiB, and raises otherwise; any number of
+    # features, taken in blocks); "matmul": XLA one-hot
     # MXU matmul (~5x the scatter strategies on v5e — see the
     # performance note below; the explicit choice where the pallas
     # constraints don't hold); "pair": feature-pair joint scatter
@@ -190,8 +192,9 @@ def build_histograms(bins, g, h, node_ids, n_nodes: int, cfg: GBDTConfig,
     Returns (hist_g, hist_h): [n_nodes, F, B] f32.
 
     Strategy "pallas" (default): the fused VMEM one-hot MXU kernel
-    (ops/hist_kernel.py). Compiled, it either fits the kernel's
-    constraints or raises — there is no hand-over to another strategy;
+    (ops/hist_kernel.py), features in blocks, so any width. Compiled,
+    it either fits the kernel's constraints (n_bins, n_nodes) or
+    raises — there is no hand-over to another strategy;
     ``hist_mode="matmul"`` is the explicit choice for other shapes.
     ``interpret`` selects the kernel's interpret mode (None: interpret
     unless running on TPU — the CPU test suite and the virtual CPU
@@ -471,6 +474,13 @@ def best_splits(hist_g, hist_h, reg_lambda: float, feat_mask=None,
 # ----------------------------------------------------------------------
 # one boosting round (tree build) — per-shard body
 # ----------------------------------------------------------------------
+def hist_level_nodes(depth: int) -> list[int]:
+    """The nodes whose histograms each level of a tree builds from the
+    rows: the root, then the left children of the level above (a right
+    child's is its parent's less its sibling's)."""
+    return ([1] + [2 ** (d - 1) for d in range(1, depth)])[:depth]
+
+
 def _build_tree(bins, g, h, cfg: GBDTConfig, axis_name, interpret,
                 feat_mask=None):
     """Grow one tree from per-sample gradients/hessians; the per-level
@@ -496,7 +506,8 @@ def _build_tree(bins, g, h, cfg: GBDTConfig, axis_name, interpret,
 
     level_start = 0
     prev_hg = prev_hh = None
-    for d in range(cfg.depth):          # depth static -> unrolled
+    # depth static -> unrolled
+    for d, n_half in enumerate(hist_level_nodes(cfg.depth)):
         n_nodes = 2 ** d
         if d == 0:
             hg, hh = reduced_histograms(node_ids, n_nodes)
@@ -515,7 +526,6 @@ def _build_tree(bins, g, h, cfg: GBDTConfig, axis_name, interpret,
             # below keeps that noise from producing negative hessian
             # sums (which could cross H + reg_lambda through zero in
             # best_splits and crown a garbage split).
-            n_half = n_nodes // 2
             left_ids = jnp.where(node_ids % 2 == 0, node_ids // 2,
                                  n_half)
             hl_g, hl_h = reduced_histograms(left_ids, n_half)
@@ -711,7 +721,17 @@ class GBDTTrainer(DataParallelTrainer):
                 interpret=interpret, rng_key=rng_key)
             return new_preds[None], tree
 
-        with spans.span("mp4j.step.build"):
+        grid = {}
+        if cfg.hist_mode == "pallas":
+            # which grid the deepest level's kernel runs (it builds the
+            # left children of the last split level)
+            from ytk_mp4j_tpu.ops.hist_kernel import feature_blocks
+            block, blocks = feature_blocks(
+                cfg.n_features, cfg.n_bins,
+                max(hist_level_nodes(cfg.depth), default=1))
+            grid = {"hist_feature_block": block,
+                    "hist_feature_blocks": blocks}
+        with spans.span("mp4j.step.build", **grid):
             return jax.jit(step)
 
     def shard_data(self, bins: np.ndarray, y: np.ndarray,
