@@ -1686,6 +1686,8 @@ def bench_ffm_tpu(n=8192, n_features=100_000, n_fields=8, k=8,
     tr = FMTrainer(cfg, sparse_grads=True)
     params, _ = tr.fit(feats, fields, vals, y, n_steps=1)  # builds _step
     sharded = tr.shard_data(feats, fields, vals, y)
+    # the step carries (and donates) its own state: the table by feature
+    params = tr._enter(params)
     step, flops = _aot_compile(tr._step, params, *sharded)
     # warm with the SAME arrays the timed loop uses — a fresh
     # shard_data product can trigger a silent recompile that would

@@ -1,0 +1,334 @@
+"""The replicated sparse FM/FFM step in block form: the table held by
+feature, one gather and one scatter descriptor a (sample, feature), the
+field vectors picked out of the block on the device.
+
+Everything goes through the public surface (``fit`` / ``fit_stream`` on
+the [n_rows, k] table) and is held to a float64 numpy SGD step written
+here from the model's definition, slot pair by slot pair on the public
+table's rows."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from ytk_mp4j_tpu.models import fm as fm_mod
+from ytk_mp4j_tpu.models.fm import FMConfig, FMTrainer
+from ytk_mp4j_tpu.parallel import make_mesh
+
+NFEAT, NFIELDS, KDIM, NNZ = 40, 5, 3, 6
+LR = 0.2
+
+
+def _np_step(cfg, params, feats, fields, vals, y, sw):
+    """One float64 SGD step of the weighted mean logloss; FM or FFM."""
+    w0, w, V = (np.asarray(p, np.float64) for p in params)
+    nf = cfg.n_fields
+    N, K = feats.shape
+    sw = np.ones(N) if sw is None else np.asarray(sw, np.float64)
+    denom = max(sw.sum(), 1.0)
+    g0, gw, gV = 0.0, np.zeros_like(w), np.zeros_like(V)
+    loss = 0.0
+    for n in range(N):
+        f, fl, x = feats[n], fields[n], vals[n].astype(np.float64)
+        z = w0 + np.sum(w[f] * x)
+        pairs = []
+        for a in range(K):
+            for b in range(a + 1, K):
+                if cfg.model == "ffm":
+                    ra, rb = f[a] * nf + fl[b], f[b] * nf + fl[a]
+                else:
+                    ra, rb = f[a], f[b]
+                z += V[ra] @ V[rb] * x[a] * x[b]
+                pairs.append((ra, rb, x[a] * x[b]))
+        loss += sw[n] * (max(z, 0) - z * y[n] + np.log1p(np.exp(-abs(z))))
+        dz = sw[n] * (1.0 / (1.0 + np.exp(-z)) - y[n]) / denom
+        g0 += dz
+        np.add.at(gw, f, dz * x)
+        for ra, rb, xx in pairs:
+            gV[ra] += dz * xx * V[rb]
+            gV[rb] += dz * xx * V[ra]
+    lr, l2 = cfg.learning_rate, cfg.l2
+    return (loss / denom,
+            (w0 - lr * g0, w - lr * (gw + l2 * w), V - lr * (gV + l2 * V)))
+
+
+def _instances(rng, case, n=24):
+    """(feats, fields, vals, sample_weight) for one named shape of
+    traffic; fields are drawn so that each case has what its name says."""
+    K = NNZ
+    feats = rng.integers(0, NFEAT, (n, K)).astype(np.int32)
+    fields = np.stack([rng.permutation(NFIELDS)[:K] if K <= NFIELDS
+                       else rng.integers(0, NFIELDS, K)
+                       for _ in range(n)]).astype(np.int32)
+    vals = (rng.random((n, K)) + 0.5).astype(np.float32)
+    sw = None
+    if case == "field_twice":
+        fields[:, 1] = fields[:, 0]         # two slots of a row, one field
+        fields[:, 4] = fields[:, 0]
+    elif case == "fields_absent":
+        fields = rng.choice([0, 3], (n, K)).astype(np.int32)
+    elif case == "padded_slots":
+        vals[:, 3:] = 0.0                   # padding: value 0, any id
+        vals[::3, 1] = 0.0
+    elif case == "one_feature_many_rows":
+        feats[:, 0] = 7
+        feats[::2, 2] = 7                   # twice in one row as well
+    elif case == "k_below_n_fields":
+        feats, fields, vals = feats[:, :2], fields[:, :2], vals[:, :2]
+    elif case == "sample_weight":
+        sw = rng.integers(0, 4, n).astype(np.float32)
+        sw[0] = 2.0
+    elif case != "plain":
+        raise AssertionError(case)
+    return feats, fields, vals, sw
+
+
+CASES = ["plain", "field_twice", "fields_absent", "padded_slots",
+         "one_feature_many_rows", "k_below_n_fields", "sample_weight"]
+
+
+def _cfg(model, **kw):
+    args = dict(n_features=NFEAT, n_fields=NFIELDS, k=KDIM, max_nnz=NNZ,
+                model=model, learning_rate=LR, init_scale=0.3)
+    args.update(kw)
+    return FMConfig(**args)
+
+
+def _start(cfg, rng):
+    n_rows = NFEAT * (cfg.n_fields if cfg.model == "ffm" else 1)
+    return (np.float32(0.1),
+            (0.1 * rng.standard_normal(NFEAT)).astype(np.float32),
+            (0.3 * rng.standard_normal((n_rows, cfg.k))).astype(np.float32))
+
+
+@pytest.mark.parametrize("n_shards", [1, 2])
+@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("model", ["ffm", "fm"])
+def test_block_step_matches_a_float64_step(rng, model, case, n_shards):
+    cfg = _cfg(model)
+    feats, fields, vals, sw = _instances(rng, case)
+    y = rng.integers(0, 2, feats.shape[0]).astype(np.float32)
+    start = _start(cfg, rng)
+    tr = FMTrainer(cfg, mesh=make_mesh(n_shards), sparse_grads=True)
+    got, losses = tr.fit(feats, fields, vals, y, n_steps=1, params=start,
+                         sample_weight=sw)
+    want_loss, want = _np_step(cfg, start, feats, fields, vals, y, sw)
+    np.testing.assert_allclose(losses[0], want_loss, rtol=2e-6)
+    assert got[2].shape == start[2].shape
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), w, rtol=2e-5, atol=2e-7)
+
+
+@pytest.mark.parametrize("l2", [0.0, 1e-2])
+@pytest.mark.parametrize("capacity", [None, NFEAT, 17])
+def test_three_block_steps_with_decay_and_dedupe(rng, l2, capacity):
+    """Several steps, with the multiplicative l2 decay and with the
+    local merge of duplicate features before the all_gather: a capacity
+    in FEATURES (17 holds each shard's distinct features here)."""
+    cfg = _cfg("ffm", l2=l2)
+    feats, fields, vals, _ = _instances(rng, "one_feature_many_rows")
+    feats = feats % 16                      # at most 16 distinct features
+    y = rng.integers(0, 2, feats.shape[0]).astype(np.float32)
+    start = _start(cfg, rng)
+    tr = FMTrainer(cfg, mesh=make_mesh(2), sparse_grads=True,
+                   sparse_capacity=capacity)
+    got, losses = tr.fit(feats, fields, vals, y, n_steps=3, params=start)
+    want, want_losses = start, []
+    for _ in range(3):
+        loss, want = _np_step(cfg, want, feats, fields, vals, y, None)
+        want_losses.append(loss)
+    np.testing.assert_allclose(losses, want_losses, rtol=5e-6)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), w, rtol=5e-5, atol=5e-7)
+
+
+@pytest.mark.parametrize("n_shards", [1, 2])
+def test_a_field_no_row_has_keeps_its_vectors_bit_for_bit(rng, n_shards):
+    """A feature's block is scattered whole; the fields its rows lack
+    get a gradient of exactly 0.0, so their vectors do not move at all.
+    Fields 1, 2 and 4 appear in no row here."""
+    cfg = _cfg("ffm")
+    feats, fields, vals, _ = _instances(rng, "fields_absent")
+    y = rng.integers(0, 2, feats.shape[0]).astype(np.float32)
+    start = _start(cfg, rng)
+    tr = FMTrainer(cfg, mesh=make_mesh(n_shards), sparse_grads=True)
+    got, _ = tr.fit(feats, fields, vals, y, n_steps=3, params=start)
+    before = start[2].reshape(NFEAT, NFIELDS, KDIM)
+    after = np.asarray(got[2]).reshape(NFEAT, NFIELDS, KDIM)
+    absent, present = [1, 2, 4], [0, 3]
+    np.testing.assert_array_equal(after[:, absent].view(np.uint32),
+                                  before[:, absent].view(np.uint32))
+    touched = np.unique(feats)
+    assert (after[touched][:, present] != before[touched][:, present]).any()
+    # and a feature no row holds keeps its whole block
+    untouched = np.setdiff1d(np.arange(NFEAT), touched)
+    assert untouched.size
+    np.testing.assert_array_equal(after[untouched].view(np.uint32),
+                                  before[untouched].view(np.uint32))
+
+
+@pytest.mark.parametrize("fields", [
+    [[0, 1, 2]], [[2, 2, 0]], [[4, 4, 4]], [[3, 0, 3], [1, 1, 2]]],
+    ids=["distinct", "twice", "all_one", "two_rows"])
+def test_field_select_is_exact_and_its_transpose_adds(rng, fields):
+    """``E[n, a, b]`` is bit for bit ``blk[n, a, fields[n, b]]``, and the
+    gradient comes back summed over the slots of a field, 0.0 elsewhere."""
+    cfg = _cfg("ffm")
+    fields = np.asarray(fields, np.int32)
+    N, K = fields.shape
+    width = fm_mod._block_width(cfg)
+    assert width == 128
+    blk = rng.standard_normal((N, K, width)).astype(np.float32)
+    E, back = jax.vjp(lambda b: fm_mod._select_fields(b, fields, cfg),
+                      jnp.asarray(blk))
+    stride = fm_mod._block_stride(cfg)
+
+    def by_field(a):            # [N, K, width] -> [N, K, n_fields, k]
+        return a[:, :, :KDIM * stride].reshape(N, K, KDIM, stride)[
+            ..., :NFIELDS].transpose(0, 1, 3, 2)
+
+    b4 = by_field(blk)
+    want = np.stack([b4[n][:, fields[n]] for n in range(N)])
+    np.testing.assert_array_equal(np.asarray(E).view(np.uint32),
+                                  want.view(np.uint32))
+    gE = rng.standard_normal(E.shape).astype(np.float32)
+    (gblk,) = back(jnp.asarray(gE))
+    want_g = np.zeros((N, K, NFIELDS, KDIM), np.float64)
+    for n in range(N):
+        for b in range(K):
+            want_g[n, :, fields[n, b]] += gE[n, :, b]
+    gblk = np.asarray(gblk)
+    nonzero = np.count_nonzero(gblk)
+    gblk = by_field(gblk)
+    assert np.count_nonzero(gblk) == nonzero    # the padding got 0.0
+    np.testing.assert_allclose(gblk, want_g, rtol=1e-6, atol=0)
+    lacking = np.ones((N, NFIELDS), bool)
+    lacking[np.arange(N)[:, None], fields] = False
+    assert (gblk.transpose(0, 2, 1, 3)[lacking] == 0.0).all()
+
+
+@pytest.mark.parametrize("model", ["ffm", "fm"])
+@pytest.mark.parametrize("n_shards", [1, 2])
+def test_fit_stream_leaves_the_callers_table_and_returns_its_shape(
+        rng, model, n_shards):
+    """The step donates its own state, never what the caller passed in:
+    every array given to ``fit_stream`` is still readable afterwards and
+    unchanged, and what comes back is the public [n_rows, k] table."""
+    cfg = _cfg(model)
+    feats, fields, vals, _ = _instances(rng, "plain")
+    y = rng.integers(0, 2, feats.shape[0]).astype(np.float32)
+    tr = FMTrainer(cfg, mesh=make_mesh(n_shards), sparse_grads=True)
+    start = _start(cfg, rng)
+    given = tr._place_params(tuple(jnp.asarray(p) for p in start))
+    out, losses = tr.fit_stream(
+        ((feats, fields, vals, y) for _ in range(3)), params=given)
+    assert losses.shape == (3,)
+    for g, s in zip(given, start):
+        assert not g.is_deleted()
+        np.testing.assert_array_equal(np.asarray(g), s)
+    assert out[2].shape == (tr.n_rows, cfg.k) == start[2].shape
+    assert all(o is not g for o, g in zip(out, given))
+    # the returned table serves and saves as it always did
+    assert tr.full_table(out).shape == start[2].shape
+    assert np.isfinite(tr.predict(out, feats, fields, vals)).all()
+    # and trains on: a second stream from what the first returned
+    again, _ = tr.fit_stream(iter([(feats, fields, vals, y)]), params=out)
+    assert not out[2].is_deleted() and again[2].shape == out[2].shape
+
+
+@pytest.mark.parametrize("model", ["ffm", "fm"])
+def test_one_chunk_fed_e_times_is_fit_of_e_steps(rng, model):
+    cfg = _cfg(model)
+    feats, fields, vals, _ = _instances(rng, "field_twice", n=37)
+    y = rng.integers(0, 2, 37).astype(np.float32)
+    E = 4
+    a = FMTrainer(cfg, mesh=make_mesh(2), sparse_grads=True)
+    p_fit, l_fit = a.fit(feats, fields, vals, y, n_steps=E, seed=5)
+    b = FMTrainer(cfg, mesh=make_mesh(2), sparse_grads=True)
+    p_st, l_st = b.fit_stream(((feats, fields, vals, y) for _ in range(E)),
+                              seed=5)
+    np.testing.assert_array_equal(l_st, l_fit)
+    for x, z in zip(p_fit, p_st):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(z))
+
+
+def test_table_round_trip_through_the_block_form_is_exact(rng):
+    """Converting in and out moves no bit, with a last conversion block
+    that starts early (77 features in blocks of 32)."""
+    cfg = _cfg("ffm", n_features=77)
+    tr = FMTrainer(cfg, mesh=make_mesh(2), sparse_grads=True)
+    tr._CONVERT_ROWS = 32 * NFIELDS
+    V = rng.standard_normal((77 * NFIELDS, KDIM)).astype(np.float32)
+    w = rng.standard_normal(77).astype(np.float32)
+    state = tr._enter((np.float32(0.5), w, V))
+    # a block is padded with zeros to whole 128-lane words; entry j of
+    # the vector against field fl is column j * 42 + fl
+    assert state[2].shape == (77, 128)
+    assert fm_mod._block_stride(cfg) == 42
+    T = np.asarray(state[2])
+    np.testing.assert_array_equal(
+        T[:, :126].reshape(77, KDIM, 42)[:, :, :NFIELDS],
+        V.reshape(77, NFIELDS, KDIM).transpose(0, 2, 1))
+    assert not T[:, :126].reshape(77, KDIM, 42)[:, :, NFIELDS:].any()
+    assert not T[:, 126:].any()
+    back = tr._leave(state)
+    np.testing.assert_array_equal(np.asarray(back[2]), V)
+    np.testing.assert_array_equal(np.asarray(back[1]), w)
+    assert float(back[0]) == 0.5
+
+
+def test_early_stopping_returns_the_best_rounds_public_params(rng):
+    """The step donates its state, so the best round's params are taken
+    out when the round is seen to be the best."""
+    cfg = _cfg("ffm", learning_rate=0.5)
+    feats, fields, vals, _ = _instances(rng, "plain", n=64)
+    y = rng.integers(0, 2, 64).astype(np.float32)       # noise
+    va = (feats[:16], fields[:16], vals[:16],
+          rng.integers(0, 2, 16).astype(np.float32))
+    tr = FMTrainer(cfg, mesh=make_mesh(2), sparse_grads=True)
+    params, losses = tr.fit(feats, fields, vals, y, n_steps=40, seed=1,
+                            eval_set=va, early_stopping_rounds=2)
+    assert len(losses) < 40
+    best = int(np.argmin(tr.eval_history_))
+    assert len(losses) == best + 1
+    assert params[2].shape == (tr.n_rows, cfg.k)
+    assert tr._eval_loss(params, tr._prep_eval(*va)) == pytest.approx(
+        min(tr.eval_history_), rel=1e-6)
+    # the same job with the dense step stops at the same round
+    dense = FMTrainer(cfg, mesh=make_mesh(2))
+    pd, ld = dense.fit(feats, fields, vals, y, n_steps=40, seed=1,
+                       eval_set=va, early_stopping_rounds=2)
+    assert len(ld) == len(losses)
+    np.testing.assert_allclose(np.asarray(params[2]), np.asarray(pd[2]),
+                               rtol=1e-4, atol=1e-6)
+
+
+def test_a_table_of_another_shape_is_refused(rng):
+    from ytk_mp4j_tpu.exceptions import Mp4jError
+    cfg = _cfg("ffm")
+    feats, fields, vals, _ = _instances(rng, "plain")
+    y = np.zeros(feats.shape[0], np.float32)
+    tr = FMTrainer(cfg, mesh=make_mesh(1), sparse_grads=True)
+    w0, w, V = _start(cfg, rng)
+    with pytest.raises(Mp4jError, match="n_rows"):
+        tr.fit(feats, fields, vals, y, n_steps=1,
+               params=(w0, w, V.reshape(NFEAT, NFIELDS * KDIM)))
+
+
+def test_the_step_refuses_the_public_table(rng):
+    """[n_rows, k] would index and compile as n_rows features of one
+    k-wide block each: another model, silently."""
+    from ytk_mp4j_tpu.exceptions import Mp4jError
+    cfg = _cfg("ffm")
+    tr = FMTrainer(cfg, mesh=make_mesh(1), sparse_grads=True)
+    step = tr._build_step(8 * NNZ)
+    slots = jax.ShapeDtypeStruct((1, 8, NNZ), jnp.float32)
+    ids = jax.ShapeDtypeStruct((1, 8, NNZ), jnp.int32)
+    row = jax.ShapeDtypeStruct((1, 8), jnp.float32)
+    step.lower(tr._state_avals(), ids, ids, slots, slots, row, row)
+    public = tr._state_avals()[:2] + (
+        jax.ShapeDtypeStruct((tr.n_rows, cfg.k), jnp.float32),)
+    with pytest.raises(Mp4jError, match="by feature"):
+        step.lower(public, ids, ids, slots, slots, row, row)

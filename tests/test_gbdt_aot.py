@@ -9,8 +9,11 @@ table each) and routing read the padded copy six times. These compiles
 pin that this cannot come back silently. Since PR 26 the same holds of
 a wide table: 1,183,747 x 968 rests as [F, N] in (8, 128) tiles
 (``{1,2,0:T(8,128)}``), the kernel takes it in feature blocks as it
-rests, and the step holds no copy of its 4.58 GB. All topology work
-happens inside fixtures, in this one file (one process may load libtpu).
+rests, and the step holds no copy of its 4.58 GB. Since PR 27 the FFM
+cell's sparse step is pinned here too (one file, because one process may
+load libtpu): the table by feature in rows of whole 128-lane words,
+which rests row-major by XLA's own choice, donated, gathered and
+scattered where it rests. All topology work happens inside fixtures.
 """
 
 import re
@@ -227,3 +230,120 @@ def test_kernel_compiles_where_the_gate_says_so(topo_devices, B, n_feat,
         b, g, h, i, n_nodes, n_feat, B)).lower(
         aval((n, n_feat), jnp.int32), aval((n,), jnp.float32),
         aval((n,), jnp.float32), aval((n,), jnp.int32)).compile()
+
+
+# ------------------------------------------------ the FFM cell's sparse step
+def _ffm_cell():
+    import json
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parent.parent / "benchmark"
+    return (json.loads((root / "configs" / "ffm-criteo.json").read_text()),
+            json.loads((root / "traffic" / "stream-zipf.json").read_text()))
+
+
+@pytest.fixture(scope="module")
+def ffm_programs(topo_devices):
+    """The step and both conversions of ``ffm-criteo.stream-zipf`` at the
+    cell's own size, compiled for one described chip."""
+    from ytk_mp4j_tpu.models.fm import FMConfig, FMTrainer
+
+    c, t = _ffm_cell()
+    mesh = Mesh(np.asarray(topo_devices[:1]), ("mp4j",))
+    trainer = FMTrainer(FMConfig(
+        model=c["model"], n_features=c["n_features"], n_fields=c["n_fields"],
+        k=c["k"], max_nnz=c["max_nnz"], learning_rate=c["learning_rate"]),
+        mesh=mesh, sparse_grads=c["sparse_grads"],
+        table_sharding=c["table_sharding"])
+    rows, rep = NamedSharding(mesh, P("mp4j")), NamedSharding(mesh, P())
+
+    def aval(shape, dtype, sharding=rows):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    slots = (1, t["rows_per_chunk"], c["max_nnz"])
+    batch = (aval(slots, jnp.int32), aval(slots, jnp.int32),
+             aval(slots, jnp.float32), aval(slots, jnp.float32),
+             aval(slots[:2], jnp.float32), aval(slots[:2], jnp.float32))
+    state = trainer._state_avals()
+    public = (aval((), jnp.float32, rep),
+              aval((c["n_features"],), jnp.float32, rep),
+              aval((trainer.n_rows, c["k"]), jnp.float32, rep))
+    widen, narrow = trainer._build_converters()
+    return {
+        "config": c,
+        "descriptors": t["rows_per_chunk"] * c["max_nnz"],
+        "step": trainer._build_step(t["rows_per_chunk"] * c["max_nnz"])
+        .lower(state, *batch).compile(),
+        "widen": widen.lower(public).compile(),
+        "narrow": narrow.lower(state).compile(),
+    }
+
+
+def _table_sized(text, opcode, elements):
+    """Instructions of ``opcode`` whose result holds ``elements`` values
+    or more."""
+    found = []
+    for line in text.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* %s\(" % opcode, line)
+        if m and np.prod([int(d) for d in m.group(1).split(",")],
+                         dtype=np.int64) >= elements:
+            found.append(line.strip()[:200])
+    return found
+
+
+def test_table_sized_detector():
+    text = """
+  %copy.1 = f32[163577856,4]{1,0:T(8,128)} copy(%params_2_.1)
+  %copy.18 = f32[4194304]{0:T(1024)} copy(%copy-done)
+  %while.2 = (s32[], f32[4194304,156]{1,0:T(8,128)}) while(%tuple.24)
+"""
+    assert len(_table_sized(text, "copy", 10 ** 8)) == 1
+    assert _table_sized(text, "copy", 10 ** 9) == []
+
+
+def test_ffm_step_scatters_into_the_table_where_it_rests(ffm_programs):
+    c, step = ffm_programs["config"], ffm_programs["step"]
+    text = step.as_text()
+    # 39 fields x 4 floats in two 128-lane words
+    F, width = c["n_features"], 256
+    assert c["n_fields"] * c["k"] == 156
+    table = r"f32\[%d,%d\]\{1,0:T\(8,128\)\}" % (F, width)
+    # the table comes in row-major by feature, which no layout is pinned
+    # for: a width that is not whole 128-lane words rests with the
+    # features on the lanes and is copied whole twice a step
+    assert re.search(table + r" parameter\(2\)", text)
+    assert re.search(r"input_output_alias=\{.*\{2\}: \(2, \{\}, may-alias\)",
+                     text)
+    # one native gather and one native scatter of N x K descriptors, on
+    # the parameter itself: no loop of slices, no copy of the table
+    d = ffm_programs["descriptors"]
+    assert re.search(
+        r"= f32\[%d,%d\]\S* fusion\(%%params_2_\S*, [^)]*\), kind=kCustom"
+        r".*ffm\.table_gather" % (d, width), text)
+    assert re.search(
+        r"= " + table + r" fusion\(%params_2_\S*, [^)]*\), kind=kCustom"
+        r".*ffm\.table_update", text)
+    assert " while(" not in text
+    assert _table_sized(text, "copy", F * width // 2) == []
+    assert _table_sized(text, "transpose", F * width // 2) == []
+
+
+def test_ffm_step_holds_one_table_and_small_temporaries(ffm_programs):
+    c = ffm_programs["config"]
+    m = ffm_programs["step"].memory_analysis()
+    # 156 floats in 256: 4.29 GB for 2.62 GB of values
+    padded = c["n_features"] * 256 * 4
+    assert padded <= m.alias_size_in_bytes < padded + 2 ** 25
+    assert m.temp_size_in_bytes < 0.5e9, m.temp_size_in_bytes
+    assert m.output_size_in_bytes - m.alias_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("which", ["widen", "narrow"])
+def test_ffm_conversions_go_a_block_at_a_time(ffm_programs, which):
+    """2.62 GB in and 4.29 GB out (or the reverse) with a third of a GB
+    between them: a whole-table relayout would need 83.7 GB."""
+    m = ffm_programs[which].memory_analysis()
+    assert m.temp_size_in_bytes < 0.5e9, m.temp_size_in_bytes
+    assert m.alias_size_in_bytes == 0       # the caller's table is kept
+    sizes = sorted([m.argument_size_in_bytes, m.output_size_in_bytes])
+    assert 2.6e9 < sizes[0] < 2.7e9 and 4.29e9 < sizes[1] < 4.35e9

@@ -172,8 +172,10 @@ def test_fit_stream_leaves_exactly_its_spans(rng, ring, family,
               "mp4j.stream.stage"]
     if max_in_flight < n - 1:
         stream.append("mp4j.stream.throttle")
-    # the linear step is built outside the loop and is not a span
-    built = ["mp4j.step.build"] if family == "ffm" else []
+    # the linear step is built outside the loop and is not a span; the
+    # FFM stream converts its table on the way in and on the way out
+    built = (["mp4j.step.build", "mp4j.stream.widen", "mp4j.stream.narrow"]
+             if family == "ffm" else [])
     assert sorted({s[0] for s in got}) == sorted(
         stream + ["mp4j.put_sharded"] + built)
 
@@ -195,9 +197,21 @@ def test_fit_stream_leaves_exactly_its_spans(rng, ring, family,
     assert len(puts) % n == 0 and all(
         any(_inside(p, s) for s in stage) for p in puts)
     if built:
-        (build,) = _named(got, "mp4j.step.build")
+        converters, build = _named(got, "mp4j.step.build")
+        (widen,) = _named(got, "mp4j.stream.widen")
+        (narrow,) = _named(got, "mp4j.stream.narrow")
+        assert _inside(converters, widen)
+        assert converters[6] == {"key": "table_converters",
+                                 "block_features": 32}
         assert _inside(build, dispatch[0])
-        assert build[6] == {"key": (16 // N_SHARDS) * 4}
+        # one gather and one scatter descriptor a (sample, feature)
+        assert build[6] == {"key": (16 // N_SHARDS) * 4,
+                            "table_form": "blocks",
+                            "descriptors": (16 // N_SHARDS) * 4}
+        # the table is converted before the first chunk is staged and
+        # after the last loss is fetched
+        assert widen[2] + widen[3] <= stage[0][2]
+        assert fetch[2] + fetch[3] <= narrow[2]
 
 
 def test_a_new_padded_shape_is_a_second_build_span(rng, ring):
@@ -207,7 +221,25 @@ def test_a_new_padded_shape_is_a_second_build_span(rng, ring):
     tr.fit_stream(iter(chunks), batch_rows=32)      # padded shape changed
     keys = [s[6]["key"] for s in _named(_trainer_spans(),
                                         "mp4j.step.build")]
-    assert keys == [16, 32]
+    # the converters are built once, the step once a padded shape
+    assert keys == ["table_converters", 16, 32]
+
+
+@pytest.mark.parametrize("kw,carries", [
+    ({}, True), ({"sparse_capacity": 8}, True),
+    ({"table_sharding": "sharded"}, False)],
+    ids=["replicated", "dedupe", "sharded"])
+def test_step_build_span_says_the_table_form(rng, ring, kw, carries):
+    """The replicated sparse step indexes by feature (N x K descriptors
+    of a shard); the sharded step still indexes a row a slot pair."""
+    tr, chunks = _ffm(rng, 1, **kw)
+    tr.fit_stream(iter(chunks))
+    build = [s[6] for s in _named(_trainer_spans(), "mp4j.step.build")
+             if s[6]["key"] == 16]
+    want = {"key": 16}
+    if carries:
+        want.update(table_form="blocks", descriptors=16)
+    assert build == [want]
 
 
 @pytest.mark.parametrize("family", ["gbdt", "ffm"])
@@ -321,9 +353,11 @@ def _batch_avals(tr, rows):
 
 def _lower_ffm(rng, **kw):
     tr, _ = _ffm(rng, 0, **kw)
-    params = tr._place_params(tr.init_params(0))
+    # the replicated sparse step takes its own state; the sharded step
+    # the placed public params
+    state = tr._enter(tr.init_params(0))
     step = tr._build_step((16 // tr.n_shards) * tr.cfg.max_nnz)
-    return step.lower(params, *_batch_avals(tr, 16))
+    return step.lower(state, *_batch_avals(tr, 16))
 
 
 def _lower_gbdt(rng):

@@ -371,13 +371,13 @@ def check_ffm(results: dict, devices, n: int, per: int = 1024):
                    max_nnz=8, learning_rate=0.05)
     mesh = Mesh(np.asarray(devices[:n]), (AXIS,))
     tr = FMTrainer(cfg, mesh=mesh, sparse_grads=True)
-    params_avals = jax.eval_shape(lambda: tr.init_params(0))
     batch_avals = (_i32(n, per, cfg.max_nnz), _i32(n, per, cfg.max_nnz),
                    _f32(n, per, cfg.max_nnz), _f32(n, per, cfg.max_nnz),
                    _f32(n, per), _f32(n, per))
+    # the replicated sparse step takes the table by feature, in blocks
     _compile("ffm/sparse_train_step", results,
              tr._build_step(per * cfg.max_nnz),
-             params_avals, *batch_avals)
+             tr._state_avals(), *batch_avals)
     # round-4 A/B: mesh-sharded table (owner-routed rows over
     # all_to_all + compacted per-shard scatter) vs the replicated path
     trs = FMTrainer(cfg, mesh=mesh, sparse_grads=True,

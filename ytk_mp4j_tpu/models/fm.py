@@ -14,14 +14,25 @@ TPU-first rebuild. Instances are padded to ``max_nnz`` static slots
 - **dense mode** (default): the full embedding-table gradient rides one
   ``lax.psum`` — bandwidth ~|V| but maximally MXU/HBM friendly; right
   whenever the vocabulary fits comfortably on-chip.
-- **sparse mode** (``sparse_grads=True``): per-slot gradient rows ride
-  as static-shape ``(row_index, grad_row)`` buffers — ONE all_gather
-  each, then a single identity-dropping scatter-add into the table,
-  which merges duplicate rows natively (the device-native analogue of
+- **sparse mode** (``sparse_grads=True``): per-slot gradients ride as
+  static-shape ``(feature, grad_block)`` buffers — ONE all_gather each,
+  then a single identity-dropping scatter-add into the table, which
+  merges duplicate features natively (the device-native analogue of
   the reference's key-wise map merge; the map API's sort + segment
-  pack would be pure overhead here — 64.2 -> 38.1 ms/step, v5e,
-  2026-07). Bandwidth ~nnz instead of ~|V|: the TPU
-  translation of the reference's sparse map path.
+  pack would be pure overhead here: 64.2 -> 38.1 ms/step on the
+  previous installation, 2026-07, not measured on this chip).
+  Bandwidth ~nnz instead of ~|V|: the TPU translation of the
+  reference's sparse map path. The replicated step holds the table by
+  FEATURE, ``[n_features, block]`` with a feature's ``n_fields``
+  vectors side by side in a row of whole 128-lane words, and touches
+  them as one block: a (sample, feature) is one gather and one scatter
+  descriptor, where a row a slot PAIR would be ``max_nnz`` times as
+  many. The serial unit charges by the descriptor, not by its bytes
+  (TPU v5 lite, PR 27, 79,872 descriptors: scatter-add 89.3 ns at
+  1 KB against 87.0 ns at 16 B, gather 13.0 against 17.3). The step
+  donates that table and updates it in place; ``fit`` / ``fit_stream``
+  convert the public ``[n_rows, k]`` table once on the way in and once
+  on the way out.
 
 Model scores (order-2, sigmoid/logloss for classification):
 
@@ -41,7 +52,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ytk_mp4j_tpu.exceptions import Mp4jError
 from ytk_mp4j_tpu.models._base import (DataParallelTrainer,
@@ -77,12 +88,80 @@ class FMConfig:
 
 
 def _gather_slots(V, rows):
-    """The one embedding gather: per-slot rows of the (flat) table.
+    """The embedding gather of the public table: per-slot rows of the
+    (flat) [n_rows, k] table.
 
     rows come from :func:`_slot_rows` — [N, K] (fm) or [N, K, K]
     (ffm); result appends the latent dim k."""
     with jax.named_scope("ffm.table_gather"):
         return V[rows]
+
+
+def _block_width(cfg: FMConfig) -> int:
+    """Floats in a row of the step's table. An FFM block is padded with
+    zeros to whole 128-lane words (39 fields x 4: 156 floats in 256), so
+    that the table rests row-major on the TPU, a feature's block one
+    contiguous run: XLA keeps a [n_features, 156] parameter with the
+    features on the lanes, and both the gather and the scatter then copy
+    the whole table every step (AOT for v5e, ISSUE 27: 4.30 GB of
+    temporaries). Pinning the layout of the unpadded shape
+    (``jax.experimental.layout``) rests the same bytes the same way, but
+    an executable read back from the persistent compilation cache
+    reports its output in the default layout and the next call refuses
+    it (my chip run, PR 27, jax 0.9.0). An FM row is its one vector."""
+    if cfg.model == "fm":
+        return cfg.k
+    return -(-cfg.n_fields * cfg.k // 128) * 128
+
+
+def _block_stride(cfg: FMConfig) -> int:
+    """Where a block keeps what: entry j of the vector against field
+    ``fl`` is column ``j * stride + fl``, the k components one after the
+    other, each a run of the fields. The public table keeps a vector's
+    k entries on the sublanes and its rows on the lanes, so a
+    component's run of a feature's fields is a run of lanes there too,
+    and a conversion moves whole runs (TPU v5 lite, PR 27, the 2.62 GB
+    table: 0.057 s a conversion; with the fields outermost, column
+    ``fl * k + j``, every entry changes lane and it takes 0.266 s)."""
+    return _block_width(cfg) // cfg.k
+
+
+def _gather_blocks(T, feats):
+    """The embedding gather of the step's table ``T``
+    [n_features, block]: one descriptor a (sample, feature) brings the
+    feature's vectors against every field; [N, K, block]."""
+    with jax.named_scope("ffm.table_gather"):
+        return T[feats]
+
+
+def _select_fields(blk, fields, cfg: FMConfig):
+    """``E[n, a, b] = v_{feat_a, field_b}`` picked out of the gathered
+    blocks ``blk`` [N, K, block] -> [N, K, K, k] (FM: the block is the
+    vector, [N, K, k]).
+
+    A one-hot contraction over the block's columns (column
+    ``j * stride + field_b`` of feature a's block is entry j of
+    E[n, a, b], :func:`_block_stride`; the padding columns are never
+    selected and get a gradient of 0.0), exact in f32: each output is
+    one block entry times 1.0 plus zeros (``HIGHEST``: the f32 value
+    crosses the MXU as three bf16 pieces whose sum is the value). Not
+    a second indexed gather, which would
+    bring back a descriptor a slot pair. Its transpose is what carries
+    the gradient back into the block: two slots of a row in one field
+    add, a field the row lacks gets exactly 0.0. Contracting the
+    columns as they lie ([N, K, block] x [N, block, K * k]) took 3.1 ms
+    of a 2,048 x 39-slot step on TPU v5 lite (PR 27); contracting a
+    [N, K, n_fields, k] view over the fields 5.4, a compare-and-sum
+    5.8."""
+    if cfg.model == "fm":
+        return blk
+    N, K, width = blk.shape
+    cols = (fields[:, :, None] + _block_stride(cfg)
+            * jnp.arange(cfg.k, dtype=fields.dtype)).reshape(N, K * cfg.k)
+    sel = jax.nn.one_hot(cols, width, dtype=blk.dtype, axis=1)
+    return jnp.einsum("nac,ncm->nam", blk, sel,
+                      precision=lax.Precision.HIGHEST).reshape(
+                          N, K, K, cfg.k)
 
 
 def _score_from_slots(w0, w, E, feats, xv, cfg: FMConfig):
@@ -109,7 +188,8 @@ def _score_from_slots(w0, w, E, feats, xv, cfg: FMConfig):
 
 
 def _score(params, feats, fields, vals, mask, cfg: FMConfig):
-    """Model score for a batch of padded sparse instances.
+    """Model score for a batch of padded sparse instances, from the
+    public [n_rows, k] table.
 
     feats/fields: [N, K] int32; vals/mask: [N, K] f32.
     """
@@ -119,12 +199,21 @@ def _score(params, feats, fields, vals, mask, cfg: FMConfig):
     return _score_from_slots(w0, w, E, feats, xv, cfg)
 
 
+def _score_blocks(state, feats, fields, vals, mask, cfg: FMConfig):
+    """:func:`_score` from the step's [n_features, block] table."""
+    w0, w, T = state
+    E = _select_fields(_gather_blocks(T, feats), fields, cfg)
+    return _score_from_slots(w0, w, E, feats, vals * mask, cfg)
+
+
 def _slot_rows(feats, fields, cfg: FMConfig):
-    """Embedding-table row index touched by each gradient slot.
+    """Row of the public [n_rows, k] table touched by each slot.
 
     FM touches row ``feat`` per slot ([N, K]); FFM touches row
     ``feat * n_fields + field_b`` per slot PAIR ([N, K, K]) — matching
-    the [N, K(, K), k] slot-gradient layout of ``_score``'s gathers.
+    the [N, K(, K), k] layout of ``_score``'s gathers. The dense step,
+    the sharded step and ``predict`` index this way; the replicated
+    sparse step indexes by feature (:func:`_gather_blocks`).
     """
     if cfg.model == "fm":
         return feats
@@ -186,75 +275,82 @@ def train_step_sparse(params, batch, cfg: FMConfig, capacity: int,
                       axis_name="mp4j"):
     """One step; embedding gradients ride the SPARSE path.
 
-    Instead of psum'ing the dense [rows, k] gradient table, each shard
-    ships its touched (row, grad_row) slots over ONE all_gather each
-    and the merged update is a single identity-dropping scatter-add
-    into V, which sums duplicate rows natively (bandwidth
-    ~touched-slots, not ~|V|). ``capacity`` is the static slot bound
-    the optional local dedupe packs into (it shrinks the all_gather
-    payload when capacity < S; nothing is ever dropped by the
-    scatter).
+    ``params`` is ``(w0, w, T)`` with the table in the step's form,
+    ``T`` [n_features, block]: a feature's vectors against every field
+    side by side (:func:`_block_width`). Instead of
+    psum'ing the dense gradient table, each shard ships its touched
+    ``(feature, grad_block)`` slots over ONE all_gather each and the
+    merged update is a single identity-dropping scatter-add into T,
+    which sums duplicate features natively (bandwidth ~touched slots,
+    not ~|V|). A (sample, feature) is ONE gather and ONE scatter
+    descriptor whatever ``n_fields``: the serial unit charges by the
+    descriptor (PERF.md section 5). ``capacity`` is the static bound,
+    in features, that the optional local dedupe packs into (it shrinks
+    the all_gather payload when capacity < S; nothing is ever dropped
+    by the scatter).
 
-    The embedding table enters autodiff only through the GATHERED
-    per-slot rows (``_score_from_slots``), so the backward yields the
-    per-slot gradient rows [S, k] directly — differentiating through
-    the gather would scatter-add a dense |V|-row gradient table on the
-    serial scatter unit and immediately re-gather its touched rows
-    (measured 1.8x the step time at |V|-rows = 8M single-chip).
-    Duplicate local rows merge by sort + segmented reduction, and the
-    update is one identity-dropping scatter into V.
+    The table enters autodiff only through the GATHERED blocks
+    (``_select_fields`` + ``_score_from_slots``), so the backward
+    yields the per-slot gradient blocks [S, block] directly —
+    differentiating through the gather would scatter-add a dense
+    |V|-row gradient table on the serial scatter unit and immediately
+    re-gather its touched rows (1.8x the step time at 8M rows on the
+    previous installation; not measured on this chip).
     """
     feats, fields, vals, mask, y, sw = batch
-    w0, w, V = _pcast_params(params, axis_name)
-    rows = _slot_rows(feats, fields, cfg)       # [N, K] / [N, K, K]
-    E = _gather_slots(V, rows)
+    if params[2].shape != (cfg.n_features, _block_width(cfg)):
+        # the public [n_rows, k] table would index and compile too, as
+        # n_rows features of one k-wide block: another model
+        raise Mp4jError(
+            "the sparse step takes the table by feature, "
+            f"[{cfg.n_features}, {_block_width(cfg)}] "
+            f"(FMTrainer._enter converts it), got {params[2].shape}")
+    w0, w, T = _pcast_params(params, axis_name)
+    blk = _gather_blocks(T, feats)              # [N, K, block]
     xv = vals * mask
-    loss, (g0, gw, gE), denom = _weighted_mean_grads(
-        (w0, w, E),
-        lambda p: _score_from_slots(p[0], p[1], p[2], feats, xv, cfg),
+    loss, (g0, gw, gblk), denom = _weighted_mean_grads(
+        (w0, w, blk),
+        lambda p: _score_from_slots(
+            p[0], p[1], _select_fields(p[2], fields, cfg), feats, xv, cfg),
         y, sw, cfg, axis_name)
     if axis_name is not None:
         g0 = lax.psum(g0, axis_name)
         gw = lax.psum(gw, axis_name)     # linear part stays dense (small)
 
-    # Local duplicate-row merge (sort + segmented reduction) runs ONLY
-    # when it shrinks the all_gather payload (capacity < S): the final
-    # scatter-add merges duplicates natively, so with capacity >= S
-    # the local sort would buy nothing (its round-2 incarnation
-    # measured ~35 ms of pure overhead at S = 512k single-chip).
-    S = rows.size
-    k = V.shape[1]
-    flat_rows = rows.reshape(-1)
-    flat_g = gE.reshape(S, k)
+    # Local duplicate-feature merge (sort + segmented reduction) runs
+    # ONLY when it shrinks the all_gather payload (capacity < S): the
+    # final scatter-add merges duplicates natively, so with
+    # capacity >= S the local sort would buy nothing (about 35 ms of
+    # pure overhead at S = 512k on the previous installation; not
+    # measured on this chip).
+    S = feats.size
+    flat_feats = feats.reshape(-1)
+    flat_g = gblk.reshape(S, -1)
     if capacity < S:
-        si, sv = sparse_ops.sort_by_key(flat_rows, flat_g)
+        si, sv = sparse_ops.sort_by_key(flat_feats, flat_g)
         li, lv = sparse_ops.segment_reduce_sorted(
             si, sv, capacity, Operators.SUM)
     else:
-        li, lv = flat_rows.astype(jnp.int32), flat_g
+        li, lv = flat_feats.astype(jnp.int32), flat_g
     if axis_name is not None:
         # NOT sparse_allreduce: its post-gather sort + segment reduce
         # packs unique keys for the map API, but the table update below
-        # is a scatter-add, which merges duplicate rows natively — the
-        # pack would be pure overhead (measured ~17 ms at the 524288-
-        # row union shape: sort ~2 ms + segment reduce ~15 ms; the
-        # scatter costs the same either way). Gather every shard's
-        # slots and scatter them all.
+        # is a scatter-add, which merges duplicates natively — the pack
+        # would be pure overhead. Gather every shard's slots and
+        # scatter them all.
         oi = lax.all_gather(li, axis_name, axis=0, tiled=True)
         ov = lax.all_gather(lv, axis_name, axis=0, tiled=True)
     else:
-        # no collective: the identity-dropping scatter-add below sums
-        # duplicate rows natively, no dedupe needed
         oi, ov = li, lv
     lr = cfg.learning_rate
     w0 = w0 - lr * (g0 / denom)
     w = w - lr * (gw / denom + cfg.l2 * w)
     if cfg.l2:
-        V = V * (1.0 - lr * cfg.l2)     # decay all rows, like the dense
-    safe = jnp.where(oi == sparse_ops.SENTINEL, V.shape[0], oi)
+        T = T * (1.0 - lr * cfg.l2)     # decay all rows, like the dense
+    safe = jnp.where(oi == sparse_ops.SENTINEL, T.shape[0], oi)
     with jax.named_scope("ffm.table_update"):
-        V = V.at[safe].add(-(lr / denom) * ov, mode="drop")
-    return (w0, w, V), loss
+        T = T.at[safe].add(-(lr / denom) * ov, mode="drop")
+    return (w0, w, T), loss
 
 
 def _fetch_rows_sharded(Vs, flat_rows, me, axis_name):
@@ -284,26 +380,31 @@ def train_step_sparse_sharded(params, batch, cfg: FMConfig, n: int,
     m owns rows ``[m*B, (m+1)*B)`` of the (padded) table, B = rows/n.
 
     The replicated sparse step's serial floor is the per-chip
-    scatter-add of ALL members' gradient rows (n*S descriptors into a
-    full replica; XLA's cost analysis prices it at 69.2 of 74.6
-    costed GB, ~80 ns/row). Sharding changes both sides:
+    scatter-add of ALL members' gradient slots (n*S descriptors into a
+    full replica; TPU v5 lite, PR 22: 85.8 ns a scattered 16-byte row,
+    15.5 ns a gathered one). This step still indexes the public
+    [rows, k] table a slot PAIR (``_slot_rows``); the block form of
+    the replicated step is not taken here (a shard boundary would have
+    to fall on a feature's block; ROADMAP S3). Sharding changes both
+    sides:
 
     - forward: slot row-ids ride one (tiny, int32) all_gather; each
-      member gathers the requested rows IT OWNS from its shard (row
-      gathers pipeline at ~4 ns/row) and one ``all_to_all`` delivers
-      them — wire n*S*k, the same order as the replicated path's
-      gradient all_gather;
+      member gathers the requested rows IT OWNS from its shard and one
+      ``all_to_all`` delivers them — wire n*S*k, the same order as the
+      replicated path's gradient all_gather;
     - backward: gradient rows route to their owners by ``all_to_all``
       (replacing the all_gather), then each member merges its received
       rows by sort + segmented reduction into at most
       ``C = min(n*S, B)`` slots — C is bounded by the SHARD SIZE, so
       no overflow is possible — and scatter-adds C descriptors into
-      its [B, k] shard. Round-4 chip measurement: drop-mode scatters
-      pay the serial unit per DESCRIPTOR, not per applied row (7/8
-      sentinel rows save only 3%), so the compaction is what converts
-      ownership into a real 1/n serial-floor cut; the set-scatter
-      inside the segmented reduction is the cheaper scatter form
-      (round-3: 15 vs 42 ms at 524288 rows).
+      its [B, k] shard. Drop-mode scatters pay the serial unit per
+      DESCRIPTOR, not per applied row (previous installation, round 4:
+      7/8 sentinel rows saved only 3%), so the compaction is what
+      converts ownership into a real 1/n serial-floor cut; the
+      set-scatter inside the segmented reduction is the cheaper
+      scatter form (previous installation, round 3: 15 vs 42 ms at
+      524288 rows). None of this step was measured on this chip: no
+      cell runs it.
 
     Table memory per chip is V/n rows — the piece that makes
     configs[4]'s Criteo-scale vocabulary fit a pod at all.
@@ -390,7 +491,8 @@ class FMTrainer(DataParallelTrainer):
                 "path; pass sparse_grads=True")
         if sparse_capacity is not None and (
                 table_sharding == "sharded" or not sparse_grads):
-            # only the replicated sparse step consumes it; anywhere
+            # only the replicated sparse step consumes it (a bound on
+            # the distinct FEATURES a shard's batch touches); anywhere
             # else a tuned capacity would be silently dropped
             raise Mp4jError(
                 "sparse_capacity applies to the replicated sparse path "
@@ -399,8 +501,12 @@ class FMTrainer(DataParallelTrainer):
                 "C = min(n_shards * batch_slots, table_rows) and the "
                 "dense step has no capacity at all")
         self.table_sharding = table_sharding
+        # the replicated sparse step keeps the table by feature, in
+        # blocks, and updates it in place
+        self._blocks = sparse_grads and table_sharding == "replicated"
         self._step = None
         self._step_key = None
+        self._converters = None   # (widen, narrow), built on first use
         self._eval_fn = None
         self._pred_fn = None      # sharded serve (jit retraces by shape)
         self.eval_history_: list[float] = []
@@ -465,6 +571,104 @@ class FMTrainer(DataParallelTrainer):
             V.shape, self._row_sharding(), lambda idx: V[idx])
         return (jnp.asarray(params[0]), jnp.asarray(params[1]), Vg)
 
+    # A conversion moves this many public rows at a time, so that what it
+    # holds beside the two tables does not grow with them (AOT for v5e at
+    # 2.62 GB: no temporaries; 0.33 GB a block with the fields outermost
+    # in a block; the whole table reshaped at once: 83.7 GB, ISSUE 27).
+    _CONVERT_ROWS = 5 * 2 ** 17
+
+    def _state_avals(self):
+        """Shapes of the step's ``(w0, w, T)``, replicated (compile
+        proofs: check/checkaot.py, the AOT tests)."""
+        cfg = self.cfg
+        rep = NamedSharding(self.mesh, P())
+        return tuple(
+            jax.ShapeDtypeStruct(shape, jnp.float32, sharding=rep)
+            for shape in ((), (cfg.n_features,),
+                          (cfg.n_features, _block_width(cfg))))
+
+    def _build_converters(self):
+        """``(widen, narrow)``: public params -> the step's own state and
+        back, for the replicated sparse step. Both return new buffers
+        (the step donates its state, never the caller's arrays). The
+        FFM table goes ``_CONVERT_ROWS`` public rows at a time, the last
+        block starting early and rewriting rows the one before placed."""
+        cfg = self.cfg
+        k, F = cfg.k, cfg.n_features
+        nf = self.n_rows // F       # vectors a block: n_fields, or FM's one
+        width, stride = _block_width(cfg), _block_stride(cfg)
+        B = max(1, min(F, self._CONVERT_ROWS // nf))
+        if B >= 128:
+            B -= B % 128        # starts on whole lane tiles of the public table
+        n_blocks = -(-F // B)
+
+        def blockwise(src, out_shape, move):
+            def body(i, out):
+                return move(src, out, jnp.minimum(i * B, F - B))
+            return lax.fori_loop(0, n_blocks, body,
+                                 jnp.zeros(out_shape, src.dtype))
+
+        def to_blocks(V, T, f0):
+            rows = lax.dynamic_slice(V, (f0 * nf, 0), (B * nf, k))
+            runs = jnp.pad(rows.T.reshape(k, B, nf),
+                           ((0, 0), (0, 0), (0, stride - nf)))
+            blk = jnp.pad(runs.transpose(1, 0, 2).reshape(B, k * stride),
+                          ((0, 0), (0, width - k * stride)))
+            return lax.dynamic_update_slice(T, blk, (f0, 0))
+
+        def to_rows(T, V, f0):
+            blk = lax.dynamic_slice(T, (f0, 0), (B, k * stride))
+            runs = blk.reshape(B, k, stride)[:, :, :nf]
+            return lax.dynamic_update_slice(
+                V, runs.transpose(1, 0, 2).reshape(k, B * nf).T,
+                (f0 * nf, 0))
+
+        def widen(params):
+            w0, w, V = params
+            T = (jnp.copy(V) if nf == 1
+                 else blockwise(V, (F, width), to_blocks))
+            return jnp.copy(w0), jnp.copy(w), T
+
+        def narrow(state):
+            w0, w, T = state
+            V = (jnp.copy(T) if nf == 1
+                 else blockwise(T, (F * nf, k), to_rows))
+            return jnp.copy(w0), jnp.copy(w), V
+
+        # committed like the placed params, so that the first step call
+        # compiles the program every later one runs
+        rep = NamedSharding(self.mesh, P())
+        with spans.span("mp4j.step.build", key="table_converters",
+                        block_features=B):
+            return (jax.jit(widen, out_shardings=rep),
+                    jax.jit(narrow, out_shardings=rep))
+
+    def _enter(self, params):
+        """Public params -> the state the step carries. The replicated
+        sparse step gets its own ``(w0, w, T)`` (``widen``); every other
+        step takes the placed params as they are."""
+        with spans.span("mp4j.stream.widen"):
+            if (self.table_sharding != "sharded"
+                    and params[2].shape != (self.n_rows, self.cfg.k)):
+                raise Mp4jError(
+                    f"the embedding table must be [n_rows={self.n_rows}, "
+                    f"k={self.cfg.k}], got {params[2].shape}")
+            params = self._place_params(params)
+            if not self._blocks:
+                return params
+            if self._converters is None:
+                self._converters = self._build_converters()
+            return jax.block_until_ready(self._converters[0](params))
+
+    def _leave(self, state):
+        """The step's state -> public params, in new buffers (``state``
+        stays valid: the snapshot of an early-stopping round is taken
+        this way too)."""
+        with spans.span("mp4j.stream.narrow"):
+            if not self._blocks:
+                return state
+            return jax.block_until_ready(self._converters[1](state))
+
     def save_params(self, path: str, params) -> None:
         """Persist with the table in its portable [n_rows, k] shape
         (a sharded table is gathered + unpadded first, so the file is
@@ -493,17 +697,21 @@ class FMTrainer(DataParallelTrainer):
 
             with spans.span("mp4j.step.build", key=per_shard_slots):
                 return jax.jit(step)
+        build_args = {}
+        jit_args = {}
         if self.sparse_grads:
             cap = self.sparse_capacity
             if cap is None:
-                # global unique touched rows can't exceed total slots
-                # this step, nor the table size
-                bound = per_shard_slots * self.n_shards
-                if cfg.model == "ffm":
-                    bound *= cfg.max_nnz
-                cap = min(self.n_rows, bound)
+                # global unique touched features can't exceed total
+                # slots this step, nor the vocabulary
+                cap = min(cfg.n_features, per_shard_slots * self.n_shards)
             step_fn = partial(train_step_sparse, cfg=cfg, capacity=cap,
                               axis_name=axes)
+            # the state is the trainer's own (``_enter``): donated, the
+            # table is scattered into where it rests
+            jit_args = dict(donate_argnums=0)
+            build_args = dict(table_form="blocks",
+                              descriptors=per_shard_slots)
             # params are pcast to varying but returned under replicated
             # P() out_specs (every shard computes the identical update
             # from the all-gathered slots + psum'd scalars), which VMA
@@ -521,8 +729,9 @@ class FMTrainer(DataParallelTrainer):
             batch = (feats[0], fields[0], vals[0], mask[0], y[0], sw[0])
             return step_fn(params, batch)
 
-        with spans.span("mp4j.step.build", key=per_shard_slots):
-            return jax.jit(step)
+        with spans.span("mp4j.step.build", key=per_shard_slots,
+                        **build_args):
+            return jax.jit(step, **jit_args)
 
     def _check_instances(self, feats: np.ndarray, fields: np.ndarray):
         """Shared id-range validation for fit and predict inputs (JAX
@@ -592,7 +801,8 @@ class FMTrainer(DataParallelTrainer):
             self._step_key = per_shard_slots
         if params is None:
             params = self.init_params(seed)
-        params = self._place_params(params)
+        state = self._enter(params)
+        del params
         va = None
         if eval_set is not None:
             va = self._prep_eval(*eval_set)
@@ -600,24 +810,34 @@ class FMTrainer(DataParallelTrainer):
         self.eval_history_ = stopper.history
         exchanger = StepStatsExchanger(comm)
         losses = []
+        best = None             # early stopping: the best round's params
+        stopped = False
         for i in range(n_steps):
-            params, loss = self._step(params, *sharded)
+            state, loss = self._step(state, *sharded)
             # bound in-flight programs; see models/linear.py fit()
             loss = jax.block_until_ready(loss)
             # step k's host-stats exchange: blocking, or (MP4J_OVERLAP=1)
             # in flight while step k+1 runs the device
             exchanger.submit(np.array([float(loss)], np.float64))
             losses.append(loss)
-            if va is not None and stopper.update(
-                    self._eval_loss(params, va), i, state=params):
-                if stopper.best_state is not None:
-                    params = stopper.best_state
-                    losses = losses[:stopper.best_round + 1]
+            if va is None:
+                continue
+            stopped = stopper.update(self._eval_loss(
+                state, va, _score_blocks if self._blocks else _score), i)
+            if early_stopping_rounds is not None and stopper.best_round == i:
+                # the next step may donate ``state``: the best round's
+                # params are taken out now, in buffers of their own
+                best = self._leave(state)
+            if stopped:
                 break
         exchanger.drain()
         hist = exchanger.mean_history()
         self.sync_loss_history_ = (hist[:, 0] if hist.size
                                    else np.zeros(0, np.float64))
+        if stopped and best is not None:
+            params, losses = best, losses[:stopper.best_round + 1]
+        else:
+            params = self._leave(state)
         return params, np.asarray(jax.device_get(losses))
 
     def fit_stream(self, batches, params=None, seed: int = 0,
@@ -645,13 +865,20 @@ class FMTrainer(DataParallelTrainer):
         dispatched asynchronously and chunk k+1 is parsed/padded/staged
         while the device runs it; losses are fetched once at the end.
         At most ``max_in_flight`` steps stay in flight, bounding device
-        memory at ~max_in_flight staged batches. ``max_in_flight=0``
+        memory at ~max_in_flight staged batches. With
+        ``sparse_grads=True`` on a replicated table the step carries
+        the table by feature and updates it in place: it is converted
+        once here (``mp4j.stream.widen``) and once before the return
+        (``mp4j.stream.narrow``); the table passed in is left as it
+        was, and the one returned is [n_rows, k]. ``max_in_flight=0``
         reproduces the fully serialized round-4 behavior (the A/B
         baseline in bench.py; the overlap's gain is not resolved
         above noise, see ROADMAP S6)."""
         if params is None:
             params = self.init_params(seed)
-        state = [self._place_params(params)]
+        state = [self._enter(params)]
+        # the caller's table is theirs: not donated, and not kept here
+        del params
 
         def dispatch(staged):
             sharded, per_shard_slots = staged
@@ -665,7 +892,7 @@ class FMTrainer(DataParallelTrainer):
 
         losses = self._stream_fit(batches, self._stage_stream_chunk,
                                   dispatch, batch_rows, max_in_flight)
-        return state[0], losses
+        return self._leave(state.pop()), losses
 
     def _stage_stream_chunk(self, chunk, batch_rows: int | None):
         """Host half of one stream step: validate, pad to ``batch_rows``
@@ -713,19 +940,21 @@ class FMTrainer(DataParallelTrainer):
                 jnp.asarray(vals), jnp.asarray(mask),
                 jnp.asarray(np.asarray(y, np.float32)))
 
-    def _eval_loss(self, params, va) -> float:
+    def _eval_loss(self, params, va, score=_score) -> float:
+        """Held-out loss of public ``params``, or with
+        ``score=_score_blocks`` of the replicated sparse step's state."""
         if self._eval_fn is None:
             cfg = self.cfg
 
-            @jax.jit
-            def run(params, feats, fields, vals, mask, y):
-                z = _score(params, feats, fields, vals, mask, cfg)
+            @partial(jax.jit, static_argnums=0)
+            def run(score, params, feats, fields, vals, mask, y):
+                z = score(params, feats, fields, vals, mask, cfg)
                 return jnp.mean(per_example_loss(z, y, cfg.loss))
 
             self._eval_fn = run
         # params may span non-addressable devices on multi-process
         # meshes; a plain local jit cannot consume those directly
-        return float(self._eval_fn(self._local_values(params), *va))
+        return float(self._eval_fn(score, self._local_values(params), *va))
 
     def _build_sharded_predict(self):
         """Serve-side shard_map program: owner-routed row fetch from
