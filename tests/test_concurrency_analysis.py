@@ -883,25 +883,6 @@ def test_analysis_package_is_self_clean():
                for f in files)
 
 
-def test_lint_runtime_extra_within_budget():
-    """ISSUE 14 satellite: the whole-program pass rides the tier-1
-    gate, so its cost is tracked — and budgeted at <= 2x the per-file
-    pass on this repo. ISSUE 16 tightens the marginal cost of the v3
-    passes (R23 lockset + R24/R25 resources): <= 1.5x the v2 run,
-    because they reuse v2's parsed index, call graph and lock
-    summaries instead of re-walking the tree. min-of-2 reps: the
-    legs run sequentially, so a load spike landing on one leg of a
-    single rep skews the ratio; the min per leg absorbs it."""
-    import bench
-
-    doc = bench.bench_lint_runtime(reps=2)
-    assert doc["lint_runtime_secs"] > 0
-    assert doc["lint_perfile_secs"] > 0
-    assert doc["lint_wholeprogram_ratio"] <= 2.0, doc
-    assert doc["lint_v2_secs"] > 0
-    assert doc["lint_v3_over_v2_ratio"] <= 1.5, doc
-
-
 def test_ensure_loaded_matches_have_native():
     from ytk_mp4j_tpu.utils import native
     from ytk_mp4j_tpu.operators import Operators

@@ -1,4 +1,4 @@
-"""Live metrics plane + flight recorder + bench gate (ISSUE 6).
+"""Live metrics plane + flight recorder (ISSUE 6).
 
 Property tests for the log2-bucket histogram math (bucket placement
 invariants over seeded sweeps including exact edges; quantile
@@ -8,8 +8,7 @@ heartbeat rides on, the rate windows, the Prometheus renderer's line
 grammar, the master's live HTTP endpoint during a real 4-rank socket
 workload (acceptance criterion), the postmortem chaos case (a killed
 rank leaves complete bundles on every survivor and the merged report
-names the dead rank), the ``bench-diff`` regression gate on the two
-checked-in BENCH files, and the new knob validation.
+names the dead rank), and the new knob validation.
 """
 
 import io
@@ -29,7 +28,7 @@ from test_resilience import run_chaos
 from ytk_mp4j_tpu.comm.master import Master
 from ytk_mp4j_tpu.comm.process_comm import ProcessCommSlave
 from ytk_mp4j_tpu.exceptions import Mp4jError, Mp4jFatalError
-from ytk_mp4j_tpu.obs import benchdiff, metrics, postmortem, telemetry
+from ytk_mp4j_tpu.obs import metrics, postmortem, telemetry
 from ytk_mp4j_tpu.obs.cli import main as scope_main
 from ytk_mp4j_tpu.operands import Operands
 from ytk_mp4j_tpu.operators import Operators
@@ -565,92 +564,6 @@ def test_postmortem_dir_empty_means_disabled(tmp_path, monkeypatch):
     monkeypatch.setenv("MP4J_POSTMORTEM_DIR", str(f))
     with pytest.raises(Mp4jError):
         tuning.postmortem_dir()
-
-
-# ----------------------------------------------------------------------
-# bench-diff — the perf regression gate
-# ----------------------------------------------------------------------
-def test_bench_diff_clean_pair_both_envelopes(tmp_path, capsys):
-    """Tier-1 seed of perf regression gating: two bench documents that
-    did not regress compare clean through the real CLI — one in the
-    driver's ``{"parsed": ...}`` envelope, one bare (both input shapes
-    bench-diff accepts)."""
-    old = tmp_path / "round_a.json"
-    new = tmp_path / "round_b.json"
-    old.write_text(json.dumps({
-        "n": 4, "cmd": "python bench.py", "rc": 0, "tail": "...",
-        "parsed": {"metric": "gbdt-histogram-allreduce GB/s/chip",
-                   "value": 3.0, "unit": "GB/s/chip",
-                   "extra": {"trees_per_sec": 14.0,
-                             "socket_baseline_gbs": 0.10,
-                             "socket_collective_gbs": 0.040,
-                             "only_in_round_a": 1.0}}}))
-    new.write_text(json.dumps({
-        "metric": "gbdt-histogram-allreduce GB/s/chip",
-        "value": 3.1, "unit": "GB/s/chip",
-        "extra": {"trees_per_sec": 14.1,
-                  "socket_baseline_gbs": 0.09,      # -10%: inside 25%
-                  "socket_collective_gbs": 0.041,
-                  "only_in_round_b": 2.0}}))
-    assert scope_main(["bench-diff", str(old), str(new)]) == 0
-    out = capsys.readouterr().out
-    assert "socket_collective_gbs" in out
-    assert "within budget" in out
-    assert "REGRESSED" not in out
-
-
-def test_bench_diff_flags_regression(tmp_path, capsys):
-    old = tmp_path / "old.json"
-    new = tmp_path / "new.json"
-    old.write_text(json.dumps({
-        "metric": "x", "value": 10.0,
-        "extra": {"socket_collective_gbs": 2.0, "not_tracked": 1.0}}))
-    new.write_text(json.dumps({
-        "parsed": {"metric": "x", "value": 9.7,
-                   "extra": {"socket_collective_gbs": 1.0}}}))
-    # socket leg halved -> regression past its 20% budget; exit 1
-    assert scope_main(["bench-diff", str(old), str(new)]) == 1
-    out = capsys.readouterr().out
-    assert "REGRESSION" in out and "socket_collective_gbs" in out
-    # headline within its 10% budget
-    assert re.search(r"value\s+.*\bok\b", out)
-    # a blanket threshold override rescues it
-    assert scope_main(["bench-diff", str(old), str(new),
-                       "--threshold", "60"]) == 0
-
-
-def test_bench_diff_gates_lint_v3_ratio_growth(tmp_path, capsys):
-    """ISSUE 16: the lint v3-over-v2 runtime ratio is a tracked
-    LOWER_IS_BETTER row — growth past its budget between bench rounds
-    is a regression (the absolute <= 1.5x budget is a tier-1 assert)."""
-    old = tmp_path / "old.json"
-    new = tmp_path / "new.json"
-    old.write_text(json.dumps({
-        "metric": "x", "value": 10.0,
-        "extra": {"lint_v3_over_v2_ratio": 1.2}}))
-    new.write_text(json.dumps({
-        "metric": "x", "value": 10.0,
-        "extra": {"lint_v3_over_v2_ratio": 2.5}}))
-    assert scope_main(["bench-diff", str(old), str(new)]) == 1
-    out = capsys.readouterr().out
-    assert "REGRESSION" in out and "lint_v3_over_v2_ratio" in out
-    # same ratio both rounds: within budget
-    assert scope_main(["bench-diff", str(old), str(old)]) == 0
-
-
-def test_bench_diff_rejects_non_bench_document(tmp_path):
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps({"whatever": 1}))
-    with pytest.raises(ValueError):
-        benchdiff.load_bench(str(bad))
-    assert scope_main(["bench-diff", str(bad), str(bad)]) == 2
-
-
-def test_bench_diff_missing_metrics_are_skipped_not_errors():
-    rows = benchdiff.compare({"value": 1.0},
-                             {"value": 1.0, "trees_per_sec": 5.0})
-    assert [r["metric"] for r in rows] == ["value"]
-    assert rows[0]["verdict"] == "ok"
 
 
 # ----------------------------------------------------------------------

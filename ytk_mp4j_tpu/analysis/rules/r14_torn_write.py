@@ -4,8 +4,8 @@ Postmortem bundles, audit dumps, trace exports and sink manifests are
 read by OTHER processes, possibly while the writer is dying: a plain
 ``open(path, "w")`` + ``json.dump`` torn by a crash leaves a
 syntactically truncated file at the REAL path, and a reader (the
-``mp4j-scope`` report, the bench-diff gate) either crashes on it or —
-worse — silently trusts a half-written document. The discipline is
+``mp4j-scope`` report) either crashes on it or — worse — silently
+trusts a half-written document. The discipline is
 tmp-file + ``os.replace``: the visible path only ever holds a
 complete artifact (see ``obs.postmortem._dump``). Append-only streams
 are the one exception — the durable sink's crc-framed segments

@@ -1,9 +1,8 @@
 """R26 — an in-loop ``i*`` submit awaited with no compute between.
 
-The whole point of the nonblocking API (ISSUE 11) — and of the trainer
-overlap loops built on it (ISSUE 17) — is that the exchange runs WHILE
-the caller computes something independent. A loop body that submits a
-nonblocking collective and immediately awaits it::
+The whole point of the nonblocking API (ISSUE 11) is that the
+exchange runs WHILE the caller computes something independent. A loop
+body that submits a nonblocking collective and immediately awaits it::
 
     for g in grads:
         f = comm.iallreduce(g)

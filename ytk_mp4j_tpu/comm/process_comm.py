@@ -162,8 +162,8 @@ class ProcessCommSlave(CommSlave):
         otherwise. It is a JOB-wide wire-protocol choice: every slave in
         a job must pass the same value (the raw/framed decision must
         match on both ends of every exchange). False keeps the fully
-        framed Python path — the frozen reference baseline bench.py
-        measures against.
+        framed Python path, the reference the raw plane was measured
+        against (loopback, previous installation, 2026-07).
 
         ``shm`` (None reads ``MP4J_SHM``, default on) lets rendezvous
         negotiate the intra-host shared-memory transport (ISSUE 7): a
@@ -2797,8 +2797,9 @@ class ProcessCommSlave(CommSlave):
     # (History: an earlier in-line note here measured a packed merge as
     # a LOSS at 20k-200k int keys — but that variant re-paid a full
     # per-call sorted-union + Python pack, exactly the work the
-    # grow-only codec amortizes away. The honest re-run is bench.py's
-    # socket_map_allreduce_sweep columnar-vs-pickle A/B, BENCH extra.)
+    # grow-only codec amortizes away. The honest re-run was a
+    # columnar-vs-pickle A/B sweep of the socket map allreduce, 1k-500k
+    # keys, loopback, previous installation, 2026-07.)
     #
     # In-place semantics on every plane: the caller's dict is mutated.
     # ------------------------------------------------------------------
@@ -3796,7 +3797,8 @@ class ProcessCommSlave(CommSlave):
 # dict grows between receives) snapshots its input so a retry starts
 # from the caller's original bytes. Keeping this set tight is a PERF
 # decision: the snapshot memcpy is the resilience layer's only
-# steady-state cost (bench.py socket_recovery steady_state).
+# steady-state cost (measured on loopback, previous installation,
+# 2026-07).
 _SNAPSHOT_FREE = frozenset({
     "broadcast_array", "gather_array", "scatter_array",
     "allgather_array", "reduce_array", "reduce_map", "broadcast_map",

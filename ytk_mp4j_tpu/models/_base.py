@@ -80,94 +80,6 @@ def load_npz(path: str, config_cls):
     return cfg, arrays
 
 
-class StepStatsExchanger:
-    """Per-step host-collective statistics exchange for the epoch
-    loops (ISSUE 17 trainer integration).
-
-    When a trainer is handed an mp4j ``comm`` every step's scalar
-    statistics (training loss, eval metric) are summed across the
-    comm's ranks so each rank's history reflects the whole job —
-    ytk-learn's aggregated progress/metric reporting. Two modes,
-    selected by ``MP4J_OVERLAP`` (``utils.tuning.overlap_enabled``):
-
-    - blocking (default): ``submit``/``submit_map`` run
-      ``allreduce_array``/``allreduce_map`` inline — step k's exchange
-      completes before step k+1's compute dispatches (today's loops
-      bit-for-bit).
-    - overlap (``MP4J_OVERLAP=1``): they post ``iallreduce``/
-      ``iallreduce_map`` and return immediately; the comm's
-      progression thread drives the wire while the device runs the
-      NEXT step, and ``drain()`` at the epoch boundary blocks on
-      ``wait_all()``.
-
-    The exchanged stats are OBSERVATIONAL (synced histories), never
-    control flow — early stopping keeps reading the local metric — so
-    deferring the wait is legal, and on == off is bit-exact by
-    construction: identical collectives in identical submit order on
-    every rank, only the wait point moves. Values a ``submit`` call
-    returned are defined only after the next ``drain()``.
-    """
-
-    def __init__(self, comm, overlap: bool | None = None):
-        from ytk_mp4j_tpu.utils import tuning
-
-        self.comm = comm
-        self.overlap = (tuning.overlap_enabled()
-                        if overlap is None else bool(overlap))
-        self._arrays: list[np.ndarray] = []
-        self._maps: list[dict] = []
-
-    @property
-    def active(self) -> bool:
-        return self.comm is not None and self.comm.slave_num > 1
-
-    def submit(self, stats: np.ndarray) -> np.ndarray:
-        """Sum ``stats`` (float64 [K]) over the comm's ranks, in
-        place; the array's values are defined after ``drain()``."""
-        stats = np.ascontiguousarray(stats, np.float64)
-        if self.active:
-            from ytk_mp4j_tpu.operands import Operands
-
-            if self.overlap:
-                self.comm.iallreduce(stats, Operands.DOUBLE)
-            else:
-                self.comm.allreduce_array(stats, Operands.DOUBLE)
-        self._arrays.append(stats)
-        return stats
-
-    def submit_map(self, d: dict) -> dict:
-        """Map-plane twin of :meth:`submit` (GBDT's per-round named
-        metrics ride ``iallreduce_map`` so tiny rounds coalesce)."""
-        if self.active:
-            from ytk_mp4j_tpu.operands import Operands
-
-            if self.overlap:
-                self.comm.iallreduce_map(d, Operands.DOUBLE)
-            else:
-                self.comm.allreduce_map(d, Operands.DOUBLE)
-        self._maps.append(d)
-        return d
-
-    def drain(self) -> None:
-        """The step/epoch-boundary drain: every submitted exchange is
-        complete (and its values defined) after this returns."""
-        if self.active and self.overlap:
-            self.comm.wait_all()
-
-    def mean_history(self) -> np.ndarray:
-        """[n_steps, K] job-wide MEAN of every array submitted so far
-        (sum / rank count). Call after :meth:`drain`."""
-        if not self._arrays:
-            return np.zeros((0, 0), np.float64)
-        n = self.comm.slave_num if self.active else 1
-        return np.stack(self._arrays) / float(n)
-
-    def mean_map_history(self) -> list[dict]:
-        """Per-round job-wide mean of every map submitted so far."""
-        n = float(self.comm.slave_num if self.active else 1)
-        return [{k: v / n for k, v in d.items()} for d in self._maps]
-
-
 class EarlyStopper:
     """The shared early-stopping state machine (GBDT/linear/FM fits).
 

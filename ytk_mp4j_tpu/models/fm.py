@@ -56,8 +56,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ytk_mp4j_tpu.exceptions import Mp4jError
 from ytk_mp4j_tpu.models._base import (DataParallelTrainer,
-                                       EarlyStopper, StepStatsExchanger,
-                                       per_example_loss)
+                                       EarlyStopper, per_example_loss)
 from ytk_mp4j_tpu.obs import spans
 from ytk_mp4j_tpu.operators import Operators
 from ytk_mp4j_tpu.ops import sparse as sparse_ops
@@ -771,7 +770,7 @@ class FMTrainer(DataParallelTrainer):
     def fit(self, feats, fields, vals, y, n_steps: int = 100, params=None,
             seed: int = 0, eval_set=None,
             early_stopping_rounds: int | None = None,
-            sample_weight=None, comm=None):
+            sample_weight=None):
         """Full-batch training; returns (params, losses).
 
         ``eval_set=(feats_va, fields_va, vals_va, y_va)`` evaluates the
@@ -780,13 +779,6 @@ class FMTrainer(DataParallelTrainer):
         k non-improving steps and returns the best round's params;
         ``sample_weight`` ([N]) weights each example's loss/gradient
         (integer weights == row duplication).
-
-        ``comm`` (an mp4j comm; every rank calls ``fit`` together)
-        syncs each step's training loss across the job into
-        ``self.sync_loss_history_`` — under ``MP4J_OVERLAP=1`` the
-        exchange is submitted nonblocking and overlaps the next step's
-        device compute (bit-identical results; see
-        ``models._base.StepStatsExchanger``).
         """
         if early_stopping_rounds is not None and eval_set is None:
             raise Mp4jError("early_stopping_rounds requires an eval_set")
@@ -808,7 +800,6 @@ class FMTrainer(DataParallelTrainer):
             va = self._prep_eval(*eval_set)
         stopper = EarlyStopper(early_stopping_rounds)
         self.eval_history_ = stopper.history
-        exchanger = StepStatsExchanger(comm)
         losses = []
         best = None             # early stopping: the best round's params
         stopped = False
@@ -816,9 +807,6 @@ class FMTrainer(DataParallelTrainer):
             state, loss = self._step(state, *sharded)
             # bound in-flight programs; see models/linear.py fit()
             loss = jax.block_until_ready(loss)
-            # step k's host-stats exchange: blocking, or (MP4J_OVERLAP=1)
-            # in flight while step k+1 runs the device
-            exchanger.submit(np.array([float(loss)], np.float64))
             losses.append(loss)
             if va is None:
                 continue
@@ -830,10 +818,6 @@ class FMTrainer(DataParallelTrainer):
                 best = self._leave(state)
             if stopped:
                 break
-        exchanger.drain()
-        hist = exchanger.mean_history()
-        self.sync_loss_history_ = (hist[:, 0] if hist.size
-                                   else np.zeros(0, np.float64))
         if stopped and best is not None:
             params, losses = best, losses[:stopper.best_round + 1]
         else:
@@ -871,9 +855,9 @@ class FMTrainer(DataParallelTrainer):
         once here (``mp4j.stream.widen``) and once before the return
         (``mp4j.stream.narrow``); the table passed in is left as it
         was, and the one returned is [n_rows, k]. ``max_in_flight=0``
-        reproduces the fully serialized round-4 behavior (the A/B
-        baseline in bench.py; the overlap's gain is not resolved
-        above noise, see ROADMAP S6)."""
+        reproduces the fully serialized round-4 behavior (the
+        overlap's gain was not resolved above noise on the previous
+        installation, 2026-07; see ROADMAP S6)."""
         if params is None:
             params = self.init_params(seed)
         state = [self._enter(params)]

@@ -35,7 +35,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ytk_mp4j_tpu.models._base import (DataParallelTrainer, EarlyStopper,
-                                       StepStatsExchanger,
                                        per_example_loss,
                                        stage_softmax_labels)
 from ytk_mp4j_tpu.exceptions import Mp4jError
@@ -761,8 +760,7 @@ class GBDTTrainer(DataParallelTrainer):
     def train(self, bins: np.ndarray, y: np.ndarray,
               n_trees: int | None = None, seed: int = 0,
               sample_weight: np.ndarray | None = None,
-              eval_set=None, early_stopping_rounds: int | None = None,
-              comm=None):
+              eval_set=None, early_stopping_rounds: int | None = None):
         """Full boosting run; returns (trees, final margins [padded] —
         [N] for scalar objectives, [N, n_classes] for softmax).
         ``seed`` drives the per-tree stochastic-boosting masks when
@@ -776,16 +774,6 @@ class GBDTTrainer(DataParallelTrainer):
         without improvement and the returned ensemble is truncated to
         the best round. The per-round metric history is available as
         ``self.eval_history_`` afterwards.
-
-        ``comm`` (an mp4j comm; every rank calls ``train`` together)
-        syncs each round's statistics across the job on the map plane
-        (round count + eval metric when an ``eval_set`` is given) —
-        the per-round job-wide means land in
-        ``self.sync_round_history_``. Under ``MP4J_OVERLAP=1`` round
-        k's exchange rides ``iallreduce_map`` and overlaps round
-        k+1's device compute, drained at the boosting-loop boundary
-        (bit-identical trees — the exchanged stats are observational;
-        see ``models._base.StepStatsExchanger``).
         """
         if self._step is None:
             self._step = self._build_step()
@@ -812,7 +800,6 @@ class GBDTTrainer(DataParallelTrainer):
         self.eval_history_ = stopper.history
 
         base_key = jax.random.key(seed)
-        exchanger = StepStatsExchanger(comm)
         trees = []
         for i in range(n_trees if n_trees is not None
                        else self.cfg.n_trees):
@@ -820,25 +807,15 @@ class GBDTTrainer(DataParallelTrainer):
                 kd = jax.random.key_data(jax.random.fold_in(base_key, i))
                 dpreds, tree = self._step(dbins, dy, dpreds, dw, kd)
             trees.append(tree)
-            metric = None
             if va is not None:
                 va_margins = self._update_margins(va[0], tree, va_margins)
                 metric = self._eval_metric(np.asarray(va_margins), va[1])
-            # round k's job-wide stats ride the map plane: blocking, or
-            # (MP4J_OVERLAP=1) in flight while round k+1 grows its tree
-            stats = {"trees": np.float64(1.0)}
-            if metric is not None:
-                stats["metric"] = np.float64(metric)
-            exchanger.submit_map(stats)
-            if metric is not None:
                 # state: the margin snapshot matching the kept ensemble
                 if stopper.update(metric, i, state=dpreds):
                     if stopper.best_state is not None:
                         trees = trees[:stopper.best_round + 1]
                         dpreds = stopper.best_state
                     break
-        exchanger.drain()
-        self.sync_round_history_ = exchanger.mean_map_history()
         with spans.span("mp4j.gbdt.fetch", job=job):
             preds = self._to_host(dpreds)
         if self.cfg.loss == "softmax":
@@ -911,7 +888,7 @@ class GBDTTrainer(DataParallelTrainer):
         return self.train(
             binner.transform(X), y, n_trees=n_trees, seed=seed,
             sample_weight=sample_weight, eval_set=eval_set,
-            early_stopping_rounds=early_stopping_rounds, comm=comm)
+            early_stopping_rounds=early_stopping_rounds)
 
     def predict_raw(self, X, trees, proba: bool = False):
         """Serve RAW continuous features through the binner fitted by

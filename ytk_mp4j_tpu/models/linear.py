@@ -35,7 +35,6 @@ from jax.sharding import PartitionSpec as P
 
 from ytk_mp4j_tpu.exceptions import Mp4jError
 from ytk_mp4j_tpu.models._base import (DataParallelTrainer, EarlyStopper,
-                                       StepStatsExchanger,
                                        per_example_loss,
                                        stage_softmax_labels)
 
@@ -183,7 +182,7 @@ class LinearTrainer(DataParallelTrainer):
     def fit(self, x: np.ndarray, y: np.ndarray, n_steps: int = 100,
             params=None, eval_set=None,
             early_stopping_rounds: int | None = None,
-            sample_weight=None, comm=None):
+            sample_weight=None):
         """Run ``n_steps`` full-batch steps; returns (params, losses).
 
         ``eval_set=(x_va, y_va)`` tracks held-out loss per step (history
@@ -191,15 +190,6 @@ class LinearTrainer(DataParallelTrainer):
         after k non-improving steps and returns the best round's
         params; ``sample_weight`` weights examples (see
         :meth:`shard_data`).
-
-        ``comm`` (an mp4j comm; every rank calls ``fit`` together)
-        syncs each step's training loss across the job — the mean
-        history lands in ``self.sync_loss_history_`` ([n_steps]).
-        Under ``MP4J_OVERLAP=1`` step k's exchange is submitted
-        nonblocking and overlaps step k+1's device compute, drained at
-        the loop boundary (bit-identical results — submit order is the
-        collective order either way; see
-        ``models._base.StepStatsExchanger``).
         """
         if early_stopping_rounds is not None and eval_set is None:
             raise Mp4jError("early_stopping_rounds requires an eval_set")
@@ -226,7 +216,6 @@ class LinearTrainer(DataParallelTrainer):
             va = (jnp.asarray(x_va), jnp.asarray(y_va))
         stopper = EarlyStopper(early_stopping_rounds)
         self.eval_history_ = stopper.history
-        exchanger = StepStatsExchanger(comm)
         losses = []
         for i in range(n_steps):
             params, vel, loss = self._step(params, vel, dx, dy, dsw)
@@ -237,9 +226,6 @@ class LinearTrainer(DataParallelTrainer):
             # at a time costs nothing here (steps are data-dependent
             # anyway) and keeps the thread demand bounded.
             loss = jax.block_until_ready(loss)
-            # step k's host-stats exchange: blocking here, or (under
-            # MP4J_OVERLAP=1) in flight while step k+1 runs the device
-            exchanger.submit(np.array([float(loss)], np.float64))
             losses.append(loss)
             if va is not None and stopper.update(
                     self._eval_loss(params, va), i, state=(params, vel)):
@@ -247,10 +233,6 @@ class LinearTrainer(DataParallelTrainer):
                     params, vel = stopper.best_state
                     losses = losses[:stopper.best_round + 1]
                 break
-        exchanger.drain()
-        hist = exchanger.mean_history()
-        self.sync_loss_history_ = (hist[:, 0] if hist.size
-                                   else np.zeros(0, np.float64))
         return params, np.asarray(jax.device_get(losses))
 
     def fit_stream(self, batches, params=None,

@@ -20,9 +20,6 @@ Layers on top of the PR-2 measurement substrate:
   per-rank crash bundles on any terminal abort
   (``MP4J_POSTMORTEM_DIR``), the master manifest, and the merged
   report behind ``mp4j-scope postmortem``.
-- :mod:`ytk_mp4j_tpu.obs.benchdiff` — the perf regression gate behind
-  ``mp4j-scope bench-diff`` (ISSUE 6): per-metric budgets over
-  ``bench.py`` JSON outputs.
 - :mod:`ytk_mp4j_tpu.obs.sink` — mp4j-trail (ISSUE 9): the durable
   streaming telemetry sink draining the span/metrics/audit/recovery
   rings into crc-framed rotating segment files (``MP4J_SINK_DIR``,
@@ -41,6 +38,5 @@ Layers on top of the PR-2 measurement substrate:
 - :mod:`ytk_mp4j_tpu.obs.cli` — the ``mp4j-scope`` CLI: merge per-rank
   Chrome-trace files into one timeline; render the cross-rank skew
   table from per-rank ``comm.stats()`` JSON dumps; ``live`` /
-  ``postmortem`` / ``replay`` / ``analyze`` / ``tail`` / ``health`` /
-  ``bench-diff``.
+  ``postmortem`` / ``replay`` / ``analyze`` / ``tail`` / ``health``.
 """

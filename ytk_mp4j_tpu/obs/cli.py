@@ -13,7 +13,6 @@ Usage::
     mp4j-scope tail /path/to/MP4J_SINK_DIR [--interval 1.0] [--once]
     mp4j-scope fleet URL [URL ...] [--interval 2.0] [--once] [--sink DIR]
     mp4j-scope fleet-report /path/to/FLEET_SINK_DIR
-    mp4j-scope bench-diff BENCH_rA.json BENCH_rB.json [--threshold PCT]
     python -m ytk_mp4j_tpu.obs report ...
 
 ``merge`` combines per-rank Chrome-trace exports
@@ -77,12 +76,8 @@ merged fleet event timeline (job up/stale/gone/restart, health
 transitions, autoscaler actions, contention episodes) offline from
 such a directory.
 
-``bench-diff`` compares two ``bench.py`` JSON outputs against
-per-metric regression budgets (``obs.benchdiff``); exit 1 on a
-regression — the perf gate.
-
-Exit codes: 0 ok, 1 bench-diff regression / replay divergence, 2 bad
-invocation / unreadable input.
+Exit codes: 0 ok, 1 replay divergence, 2 bad invocation / unreadable
+input.
 """
 
 from __future__ import annotations
@@ -95,8 +90,7 @@ import time
 import urllib.error
 import urllib.request
 
-from ytk_mp4j_tpu.obs import (audit, benchdiff, critpath,
-                              fleet as fleet_mod,
+from ytk_mp4j_tpu.obs import (audit, critpath, fleet as fleet_mod,
                               health as health_mod, postmortem,
                               sink as sink_mod, spans, telemetry)
 from ytk_mp4j_tpu.utils import tuning
@@ -107,7 +101,7 @@ def _build_parser() -> argparse.ArgumentParser:
         prog="mp4j-scope",
         description="cluster-wide mp4j telemetry: timeline merge, "
                     "cross-rank skew report, live metrics view, "
-                    "postmortem merge, bench regression gate")
+                    "postmortem merge")
     sub = ap.add_subparsers(dest="cmd", required=True)
 
     mg = sub.add_parser("merge", help="merge per-rank Chrome-trace "
@@ -203,15 +197,6 @@ def _build_parser() -> argparse.ArgumentParser:
     fr.add_argument("--json", action="store_true",
                     help="emit the raw reconstruction as JSON")
 
-    bd = sub.add_parser("bench-diff",
-                        help="compare two bench.py JSON outputs "
-                             "against per-metric regression budgets")
-    bd.add_argument("old", help="baseline BENCH file")
-    bd.add_argument("new", help="candidate BENCH file")
-    bd.add_argument("--threshold", type=float, default=None,
-                    metavar="PCT",
-                    help="override every per-metric budget with this "
-                         "max tolerated drop, in percent (e.g. 10)")
     return ap
 
 
@@ -475,12 +460,6 @@ def main(argv=None) -> int:
             return _fleet(args)
         if args.cmd == "fleet-report":
             return _fleet_report(args)
-        if args.cmd == "bench-diff":
-            thr = (None if args.threshold is None
-                   else args.threshold / 100.0)
-            text, regressed = benchdiff.run(args.old, args.new, thr)
-            print(text)
-            return 1 if regressed else 0
         skew = telemetry.cluster_skew(_load_rank_stats(args.stats))
         if args.json:
             print(json.dumps(skew, sort_keys=True))

@@ -58,10 +58,10 @@ def sort_by_key(idx, val):
     The previous formulation (``order = argsort(idx); idx[order],
     val[order]``) routed the payload through a fancy-index row gather;
     the v5e-8 AOT compile of the FFM sparse step costed that program at
-    180.5 GB bytes-accessed (AOT_r02) for 16 MB of live data — the
-    gather's multi-chip lowering is pathological. The multi-operand
-    sort carries each payload column through the sort comparators
-    instead.
+    180.5 GB bytes-accessed (previous installation, 2026-07) for 16 MB
+    of live data — the gather's multi-chip lowering is pathological.
+    The multi-operand sort carries each payload column through the
+    sort comparators instead.
 
     ``val`` may be [L] or [L, ...]; trailing dims ride as extra static
     payload columns. Beyond ``_MAX_SORT_PAYLOAD_COLS`` columns the

@@ -7,8 +7,8 @@ and this module sets nothing; where it is unset the cache lives at
 Op metadata (source lines, ``jax.named_scope`` stacks) is part of the key
 here, so that a profile never shows another commit's names.
 
-Called from entry points (``chip_smoke.py``, ``bench.py`` and the
-``check`` programs' ``main()``), never from ``import ytk_mp4j_tpu``: a
+Called from entry points (``chip_smoke.py`` and the ``check``
+programs' ``main()``), never from ``import ytk_mp4j_tpu``: a
 library import must not decide where a user's process writes files.
 """
 

@@ -22,8 +22,8 @@ Knobs (all validated where they are consumed; garbage raises
   ``algo="auto"`` thresholds: payloads <= small take the binomial tree
   (latency-bound regime), payloads >= large take the pipelined ring
   (bandwidth-bound regime), in between recursive halving/doubling.
-  Defaults are grounded in ``bench.py``'s ``socket_allreduce_sweep``
-  (see BENCH JSON ``extra``).
+  Defaults are grounded in a loopback sweep of the socket allreduce
+  on the previous installation (2026-07).
 - ``MP4J_SO_SNDBUF`` / ``MP4J_SO_RCVBUF`` — socket buffer sizes applied
   at channel setup (``transport/tcp.py``); unset keeps the kernel
   defaults.
@@ -159,17 +159,6 @@ Knobs (all validated where they are consumed; garbage raises
   call uses the count-negotiating multi protocol or the classic one
   must match on every rank (the negotiated batch size then absorbs
   ragged coalescing depth).
-- ``MP4J_OVERLAP`` — trainer-loop compute/communication overlap
-  (ISSUE 17; ``models/_base.py``): ``1`` submits each step's
-  host-statistics exchange as nonblocking ``iallreduce`` /
-  ``iallreduce_map`` futures and drains them at the NEXT step
-  boundary (``wait_all``), so the progression thread drives the wire
-  while the device runs step k+1; ``0`` (default) keeps today's
-  blocking per-step exchange bit-for-bit. A LOCAL execution-strategy
-  knob like ``MP4J_ASYNC``: submit order equals collective order on
-  every rank either way, only the wait point moves, so ranks need
-  not agree. Frozen bench legs pin it off (the shm/audit/sink/
-  health/autoscale/tuner precedent).
 - ``MP4J_MAX_OUTSTANDING`` — how many nonblocking collectives may be
   queued + in flight per slave before ``i*`` submission blocks
   (backpressure); also caps the engine batch and the coalescing
@@ -253,8 +242,8 @@ import os
 from ytk_mp4j_tpu.exceptions import Mp4jError
 
 DEFAULT_CHUNK_BYTES = 1024 * 1024
-# Sweep-grounded (bench.py socket_allreduce_sweep on the bench host,
-# BENCH JSON extra): the binomial tree wins the latency-bound regime up
+# Sweep-grounded (a loopback sweep of the socket allreduce on the
+# previous installation, 2026-07): the binomial tree wins the latency-bound regime up
 # to ~256 KiB (~1.5x over RHD at 64 KiB); RHD wins the middle; from
 # ~4 MiB the pipelined ring's uniform per-step segments edge out RHD's
 # large first-round exchange (~1.15x at 8 MiB). Hosts with different
@@ -734,21 +723,6 @@ def coalesce_usecs() -> int:
     between the classic and the count-negotiating multi map protocol,
     so every rank must agree."""
     return env_int("MP4J_COALESCE_USECS", 0, minimum=0)
-
-
-def overlap_enabled() -> bool:
-    """Whether the trainer epoch loops overlap each step's host
-    statistics exchange with the next step's compute
-    (``MP4J_OVERLAP``); ``0``/unset keeps the blocking per-step
-    exchange. Local wait-point strategy — wire-identical either
-    way (submit order == collective order on every rank)."""
-    raw = os.environ.get("MP4J_OVERLAP")
-    if raw is None or raw.strip() == "":
-        return False
-    val = raw.strip()
-    if val not in ("0", "1"):
-        raise Mp4jError(f"MP4J_OVERLAP={raw!r} must be 0 or 1")
-    return val == "1"
 
 
 def max_outstanding() -> int:
