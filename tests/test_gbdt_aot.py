@@ -26,8 +26,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ytk_mp4j_tpu.models.gbdt import GBDTConfig, GBDTTrainer
-from ytk_mp4j_tpu.ops.hist_kernel import (_rests_tiled, feature_blocks,
-                                          pallas_hist_supported,
+from ytk_mp4j_tpu.ops.hist_kernel import (_acc_bytes_a_feature,
+                                          _rests_tiled, feature_blocks,
+                                          hist_radix, pallas_hist_supported,
                                           pallas_histograms)
 
 ROWS, F, B, DEPTH = 1_000_000, 28, 256, 6
@@ -201,31 +202,46 @@ def test_where_a_table_rests_is_what_the_kernel_assumes(topo_devices, n_feat,
         assert _rests_tiled(n_feat) == rests.startswith("{1,2,0")
 
 
-@pytest.mark.parametrize("B,n_feat,n_nodes", [
-    (256, 28, 64),     # one block of the Higgs width near the limit
-    (4096, 2, 32),     # tallest one-hot: [B, tile]
-    (256, 968, 1),     # Bosch's root: 11 blocks of 88, (8, 128)-tiled
-    (256, 968, 16),    # ... and its deepest level
-    (256, 1024, 16),   # 8 blocks whose accumulators are 8 MiB each: the
-                       # out block must be single-buffered to fit
-    (256, 250, 16),    # rows of (1, 128): two blocks of 125
-    (256, 28, 128),    # depth 8 at the Higgs width: two blocks of 14
+@pytest.mark.parametrize("B,n_feat,n_nodes,radix", [
+    (256, 28, 64, 1),    # one block of the Higgs width near the limit
+    (4096, 2, 32, 1),    # tallest one-hot: [B, tile]
+    (256, 968, 1, 4),    # Bosch's root: 11 blocks of 88, (8, 128)-tiled
+    (256, 968, 16, 1),   # ... and its deepest level
+    (256, 1024, 16, 1),  # 8 blocks whose accumulators are 8 MiB each:
+                         # the out block must be single-buffered to fit
+    (256, 250, 16, 1),   # rows of (1, 128): two blocks of 125
+    (256, 28, 128, 1),   # depth 8 at the Higgs width: two blocks of 14
+    # the shallow levels of both cells, where a bin is split: every
+    # (radix, operand form, out-block form) the two tables meet
+    (256, 28, 1, 4),     # rows of (1, 128); 16 rows, a block [16, 64]
+    (256, 28, 2, 4),     # a feature
+    (256, 28, 4, 2),     # 32 and 64 rows against 128 low digits side
+    (256, 28, 8, 2),     # by side
+    (256, 968, 2, 4),    # the same in sublane tiles
+    (256, 968, 4, 2),
+    (256, 968, 8, 2),
+    (256, 250, 1, 4),    # 125 rows of (1, 128), 64 low digits
+    (256, 1024, 1, 4),   # 8 blocks of 128 in sublane tiles
+    (128, 28, 1, 4),     # 32 low digits: a quarter of a lane word
+    (4096, 2, 1, 16),    # 64 rows against 256 low digits
 ])
 def test_kernel_compiles_where_the_gate_says_so(topo_devices, B, n_feat,
-                                                n_nodes):
+                                                n_nodes, radix):
     """What pallas_hist_supported admits, Mosaic compiles at the
-    module's tile (VMEM is the limit that interpret mode cannot see)."""
+    module's tile (VMEM is the limit that interpret mode cannot see),
+    at the radix the level takes."""
     from jax.sharding import SingleDeviceSharding
 
     assert pallas_hist_supported(B, n_feat, n_nodes)
+    assert hist_radix(n_nodes, B) == radix
     one_chip = SingleDeviceSharding(topo_devices[0])
 
     def aval(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     n = 100_000
-    assert feature_blocks(n_feat, B, n_nodes)[0] * 4 * n_nodes * B * 4 \
-        <= 8 * 2 ** 20
+    assert feature_blocks(n_feat, B, n_nodes)[0] \
+        * _acc_bytes_a_feature(B, n_nodes) <= 8 * 2 ** 20
     jax.jit(lambda b, g, h, i: pallas_histograms(
         b, g, h, i, n_nodes, n_feat, B)).lower(
         aval((n, n_feat), jnp.int32), aval((n,), jnp.float32),

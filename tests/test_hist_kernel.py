@@ -11,7 +11,10 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ytk_mp4j_tpu.ops.hist_kernel import (_MAX_ACC_BYTES, feature_blocks,
+import ytk_mp4j_tpu.ops.hist_kernel as hist_kernel
+from ytk_mp4j_tpu.ops.hist_kernel import (_MAX_ACC_BYTES,
+                                          _acc_bytes_a_feature,
+                                          feature_blocks, hist_radix,
                                           pallas_hist_supported,
                                           pallas_histograms)
 
@@ -152,11 +155,12 @@ def test_zero_weight_rows_contribute_nothing(rng):
     assert np.all(np.asarray(hh) == 0)
 
 
-def test_hi_lo_split_precision(rng):
+@pytest.mark.parametrize("B", [8, 128, 256])     # radix 1, 4, 4
+def test_hi_lo_split_precision(rng, B):
     """The bf16 hi/lo split must beat plain-bf16 rounding by orders of
     magnitude: values near 1 with tiny perturbations accumulate to ~1e-7
     relative error, where a single bf16 cast alone rounds at ~4e-3."""
-    N, F, B = 4096, 1, 8
+    N, F = 4096, 1
     bins = rng.integers(0, B, (N, F)).astype(np.int32)
     g = (1.0 + 1e-3 * rng.standard_normal(N)).astype(np.float32)
     h = np.ones(N, np.float32)
@@ -207,7 +211,7 @@ def test_feature_blocks_rule(F, B, n_nodes, want):
     blk, n_blocks = feature_blocks(F, B, n_nodes)
     assert (blk, n_blocks) == want
     assert (n_blocks - 1) * blk < F <= n_blocks * blk
-    assert 4 * n_nodes * blk * B * 4 <= _MAX_ACC_BYTES
+    assert blk * _acc_bytes_a_feature(B, n_nodes) <= _MAX_ACC_BYTES
 
 
 # several feature blocks, ragged last blocks in both operand forms
@@ -237,6 +241,115 @@ def test_feature_blocks_match_bincount(rng, F, B, N, tile, n_nodes):
         n_nodes, F, B, tile=tile, interpret=True)
     assert hg.shape == hh.shape == (n_nodes, F, B)
     assert_matches_bincount(hg, hh, bins, g, h, nid, n_nodes, F, B)
+
+
+@pytest.mark.parametrize("n_nodes,B,want", [
+    # 256 bins, a tree's levels: 16 rows against 64 low digits at the
+    # root, 32 against 64, 32 and 64 against 128, then the undivided
+    # kernel, whose 64 rows already keep the MXU as long as its pushes
+    (1, 256, 4), (2, 256, 4), (4, 256, 2), (8, 256, 2), (16, 256, 1),
+    (32, 256, 1), (128, 256, 1),
+    (3, 256, 4),                     # 48 rows: three matmuls, four pushes
+    (1, 128, 4), (2, 128, 2), (4, 128, 2), (8, 128, 1),
+    (1, 1024, 8), (2, 1024, 8), (4, 1024, 4), (8, 1024, 2),
+    (1, 4096, 16), (2, 4096, 8), (32, 4096, 1),
+    (1, 16, 1), (1, 8, 1),           # one push either way
+    (1, 100, 1), (4, 96, 1), (1, 255, 1),   # no power of two: no digits
+])
+def test_hist_radix_rule(n_nodes, B, want):
+    R = hist_radix(n_nodes, B)
+    assert R == want
+    assert B % R == 0 and (R == 1 or B // R >= 16)
+
+
+@pytest.mark.parametrize("B", [128, 256, 4096])
+def test_hist_radix_never_pays_for_a_split(B):
+    """Deeper levels never take a larger radix, and the accumulator's
+    bytes, lane padding included, are what ``feature_blocks`` reckons
+    with."""
+    radices = [hist_radix(2 ** d, B) for d in range(9)]
+    assert radices == sorted(radices, reverse=True) and radices[-1] == 1
+    for d, R in enumerate(radices):
+        assert _acc_bytes_a_feature(B, 2 ** d) \
+            == R * 4 * 2 ** d * max(B // R, 128) * 4
+
+
+# every radix the rule can choose, in both operand forms ([F, 1, N] rows
+# at 28 features, sublane tiles with a ragged last block at 136: 72 +
+# 64) and both forms of the out block (low digits in whole lane words
+# side by side; fewer, a block [R*C, BL] a feature), N ragged against
+# the tile, sentinel ids and zero-weight rows
+_RADIX_SHAPES = [
+    # (F, B, N, tile)
+    (28, 256, 300, 128),
+    (136, 256, 200, 128),
+    (28, 128, 300, 256),
+    (3, 4096, 150, 128),
+]
+_RADIX_NODES = [1, 2, 4, 8, 16]
+
+
+def _radix_case(rng, F, B, N, n_nodes):
+    bins = rng.integers(0, B, (N, F)).astype(np.int32)
+    bins[rng.random((N, F)) < 0.3] = 0           # the missing bucket
+    bins[0], bins[-1] = B - 1, B - 1             # the last bin, both ends
+    g = rng.standard_normal(N).astype(np.float32)
+    h = rng.random(N).astype(np.float32)
+    nid = rng.integers(-1, n_nodes + 1, N).astype(np.int32)
+    g[::7] = 0.0
+    h[::7] = 0.0                                  # weight-0 rows
+    return bins, g, h, nid
+
+
+@pytest.mark.parametrize("n_nodes", _RADIX_NODES)
+@pytest.mark.parametrize("F,B,N,tile", _RADIX_SHAPES)
+def test_every_radix_matches_bincount(rng, F, B, N, tile, n_nodes):
+    bins, g, h, nid = _radix_case(rng, F, B, N, n_nodes)
+    hg, hh = pallas_histograms(
+        jnp.array(bins), jnp.array(g), jnp.array(h), jnp.array(nid),
+        n_nodes, F, B, tile=tile, interpret=True)
+    assert hg.shape == hh.shape == (n_nodes, F, B)
+    assert_matches_bincount(hg, hh, bins, g, h, nid, n_nodes, F, B)
+
+
+@pytest.mark.parametrize("n_nodes", _RADIX_NODES)
+@pytest.mark.parametrize("F,B,N,tile", _RADIX_SHAPES)
+def test_every_radix_matches_the_matmul_strategy(rng, F, B, N, tile,
+                                                 n_nodes):
+    """The XLA one-hot matmul takes the same hi / lo halves and sums
+    them in f32 too (chip_smoke.py holds the compiled kernel to it on
+    the chip)."""
+    from ytk_mp4j_tpu.models.gbdt import (GBDTConfig,
+                                          _build_histograms_matmul)
+
+    bins, g, h, nid = (jnp.array(a) for a in
+                       _radix_case(rng, F, B, N, n_nodes))
+    got = pallas_histograms(bins, g, h, nid, n_nodes, F, B, tile=tile,
+                            interpret=True)
+    want = _build_histograms_matmul(
+        bins, g, h, nid, n_nodes, GBDTConfig(n_features=F, n_bins=B))
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("F,B,n_nodes", [
+    (28, 256, 1), (28, 256, 8), (136, 256, 2), (28, 128, 1), (3, 4096, 2)])
+def test_the_split_changes_no_sum(rng, monkeypatch, F, B, n_nodes):
+    """The same products into the same f32 sums, the masked rows adding
+    exact zeros: the undivided kernel (radix 1) gives the same
+    histogram."""
+    N = 300
+    args = [jnp.array(a) for a in _radix_case(rng, F, B, N, n_nodes)]
+    assert hist_radix(n_nodes, B) > 1
+    split = pallas_histograms(*args, n_nodes, F, B, tile=128,
+                              interpret=True)
+    monkeypatch.setattr(hist_kernel, "hist_radix", lambda n_nodes, B: 1)
+    whole = pallas_histograms(*args, n_nodes, F, B, tile=128,
+                              interpret=True)
+    for a, b in zip(split, whole):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-6)
 
 
 @pytest.mark.parametrize("F,B,n_nodes", [

@@ -120,11 +120,17 @@ def test_gbdt_train_leaves_exactly_its_spans(rng, ring):
 
 
 @pytest.mark.parametrize("F,depth,hist_mode,want", [
-    (4, 3, "pallas", {"hist_feature_block": 4, "hist_feature_blocks": 1}),
+    # hist_radix: the high digits a bin is split into, level by level
+    # (1, 1, 2 nodes at depth 3)
+    (4, 3, "pallas", {"hist_feature_block": 4, "hist_feature_blocks": 1,
+                      "hist_radix": "4,4,4"}),
     # the deepest level builds 2**(depth-2) left children: at 16 nodes
     # and 256 bins a block holds at most 128 features, 968 go in 11 x 88
     (968, 6, "pallas", {"hist_feature_block": 88,
-                        "hist_feature_blocks": 11}),
+                        "hist_feature_blocks": 11,
+                        "hist_radix": "4,4,4,2,2,1"}),
+    (28, 8, "pallas", {"hist_feature_block": 28, "hist_feature_blocks": 1,
+                       "hist_radix": "4,4,4,2,2,1,1,1"}),
     (968, 6, "matmul", None),
 ])
 def test_step_build_span_says_which_histogram_grid_runs(ring, F, depth,

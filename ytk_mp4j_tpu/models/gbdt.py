@@ -723,13 +723,17 @@ class GBDTTrainer(DataParallelTrainer):
         grid = {}
         if cfg.hist_mode == "pallas":
             # which grid the deepest level's kernel runs (it builds the
-            # left children of the last split level)
-            from ytk_mp4j_tpu.ops.hist_kernel import feature_blocks
+            # left children of the last split level), and into how many
+            # high digits each level's kernel splits a bin
+            from ytk_mp4j_tpu.ops.hist_kernel import (feature_blocks,
+                                                      hist_radix)
+            levels = hist_level_nodes(cfg.depth)
             block, blocks = feature_blocks(
-                cfg.n_features, cfg.n_bins,
-                max(hist_level_nodes(cfg.depth), default=1))
+                cfg.n_features, cfg.n_bins, max(levels, default=1))
             grid = {"hist_feature_block": block,
-                    "hist_feature_blocks": blocks}
+                    "hist_feature_blocks": blocks,
+                    "hist_radix": ",".join(
+                        str(hist_radix(n, cfg.n_bins)) for n in levels)}
         with spans.span("mp4j.step.build", **grid):
             return jax.jit(step)
 
