@@ -170,6 +170,78 @@ def test_wide_step_temporaries_far_below_the_table(wide_step):
     assert temp < WIDE_TABLE_BYTES // 16, temp / WIDE_TABLE_BYTES
 
 
+SCORE_ROWS, SCORE_TREES = 1_183_748, 500   # configs/gbdt-bosch-score-500
+# rows of a staging chunk of this table where every chunk is scored as
+# it crosses (_put_in_row_chunks with ``each``)
+SCORE_CHUNK_ROWS = (GBDTTrainer._EACH_CHUNK_BYTES // (WIDE_F * 4)
+                    // 128 * 128)
+
+
+@pytest.fixture(scope="module")
+def score_program(topo_devices):
+    """``GBDTTrainer.predict``'s scoring program at the Bosch scoring
+    cell's size, a staging chunk's rows a call, and its build span."""
+    from ytk_mp4j_tpu.models.gbdt import score_group_size
+    from ytk_mp4j_tpu.obs import spans
+
+    mesh = Mesh(np.asarray(topo_devices[:1]), ("mp4j",))
+    trainer = GBDTTrainer(GBDTConfig(n_features=WIDE_F, n_bins=B,
+                                     depth=DEPTH, loss="logistic",
+                                     missing_bin=True), mesh=mesh)
+    rows, whole = NamedSharding(mesh, P("mp4j")), NamedSharding(mesh, P())
+    group = score_group_size(SCORE_TREES)
+    shape = (-(-SCORE_TREES // group), 2 ** DEPTH, group, 1)
+    stacked = tuple(jax.ShapeDtypeStruct(shape, d, sharding=whole)
+                    for d in (jnp.int32, jnp.int32, jnp.int32, jnp.float32))
+    spans.clear()
+    program = trainer._build_score((1, SCORE_ROWS, WIDE_F), SCORE_CHUNK_ROWS,
+                                   SCORE_TREES)
+    built = [s[-1] for s in spans.snapshot() if s[0] == "mp4j.step.build"]
+    compiled = program.lower(
+        jax.ShapeDtypeStruct((1, SCORE_ROWS, WIDE_F), jnp.int32,
+                             sharding=rows), stacked,
+        jax.ShapeDtypeStruct((1, 1, SCORE_ROWS), jnp.float32, sharding=rows),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=whole)).compile()
+    return compiled, built
+
+
+def test_scoring_program_fits_and_passes_the_table_few_times(score_program):
+    compiled, built = score_program
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes >= SCORE_ROWS * WIDE_F * 4
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 14e9
+    # the temporaries are a chunk's bf16 copy and one group's decisions
+    assert m.temp_size_in_bytes < 0.5e9, m.temp_size_in_bytes
+    assert len(built) == 1 and built[0]["key"] == "gbdt_score"
+    assert built[0]["rows"] == SCORE_CHUNK_ROWS == 34_560
+    assert built[0]["row_chunks"] == 1
+    # a chunk's bf16 copy is read once a group of trees: at most 64 times
+    assert -(-SCORE_TREES // built[0]["group"]) <= 64
+
+
+def test_scoring_program_reads_the_table_as_it_rests(score_program):
+    """The [N, 968] table rests as [F, N]; the program takes a chunk's
+    rows from it as a [968, rows] bf16 array without a transposition,
+    selects by one bf16 matmul a group and decides in its output."""
+    text = score_program[0].as_text()
+    assert "s32[1,%d,%d]{1,2,0:T(8,128)} parameter(0)" % (
+        SCORE_ROWS, WIDE_F) in text
+    assert _row_major_tables(text, SCORE_CHUNK_ROWS // 2, WIDE_F) == []
+    assert not re.search(
+        r"= s32\[[\d,]*\d{6,},[\d,]*\d{3,}[\d,]*\]\S* (copy|pad|transpose)\(",
+        text)
+    group = score_program[1][0]["group"]
+    assert re.search(r"= bf16\[%d,%d\]\{1,0:T\(8,128\)\(2,1\)\} fusion\("
+                     % (WIDE_F, SCORE_CHUNK_ROWS), text)
+    assert re.search(r"= pred\[%d,%d\]\S* fusion\(.*kind=kOutput"
+                     % (group * 2 ** DEPTH, SCORE_CHUNK_ROWS), text)
+    assert "gbdt.score.select/dot_general" in text
+    assert "gbdt.score.walk" in text and "gbdt.route" not in text
+    assert " gather(" not in text and "tpu_custom_call" not in text
+    # the margins are updated where they rest
+    assert "input_output_alias" in text
+
+
 @pytest.mark.parametrize("n_feat,rests", [
     (28, "{1,0,2:T(1,128)}"),       # F rows of N lanes: Higgs
     (250, "{1,0,2:T(1,128)}"),

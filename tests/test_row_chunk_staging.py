@@ -50,8 +50,9 @@ def test_which_arrays_go_in_row_chunks(rng, monkeypatch, n_shards, shape,
     t._ONE_TRANSFER_BYTES, t._CHUNK_BYTES = limit, 4096
     calls = []
     inner = t._put_in_row_chunks
-    monkeypatch.setattr(t, "_put_in_row_chunks",
-                        lambda a: calls.append(a.shape) or inner(a))
+    monkeypatch.setattr(
+        t, "_put_in_row_chunks",
+        lambda a, each=None: calls.append(a.shape) or inner(a, each))
     a = rng.integers(0, 256, shape).astype(np.int32)
     got = t._put_sharded(a, shape[0] // n_shards)
     assert bool(calls) is chunked
@@ -84,7 +85,7 @@ def test_train_is_the_same_whichever_way_the_table_went(rng, monkeypatch,
             inner = tr._put_in_row_chunks
             monkeypatch.setattr(
                 tr, "_put_in_row_chunks",
-                lambda a: calls.append(a.shape) or inner(a))
+                lambda a, each=None: calls.append(a.shape) or inner(a, each))
         trees, margins = tr.train(bins, y, n_trees=2)
         return trees, margins, calls
 

@@ -252,7 +252,7 @@ class DataParallelTrainer:
                 "sample_weight sums to zero: nothing to train on")
         return sw
 
-    def _put_sharded(self, a: np.ndarray, per: int):
+    def _put_sharded(self, a: np.ndarray, per: int, each=None):
         """Reshape [n*per, ...] -> [n, per, ...] and place on the mesh.
 
         ``make_array_from_callback`` (each process materializes only its
@@ -264,13 +264,22 @@ class DataParallelTrainer:
         device_put on single-process meshes.
 
         A shard of ``_ONE_TRANSFER_BYTES`` or more crosses in row chunks
-        instead (``_put_in_row_chunks``)."""
+        instead (``_put_in_row_chunks``).
+
+        ``each(table, start, stop)``, where given, is called as soon as
+        rows [start, stop) of every shard are on their way into
+        ``table`` (the array that holds them; it is only good until the
+        next call): after every chunk, or once for the whole shard. What
+        it dispatches on those rows runs while the rest crosses."""
         with spans.span("mp4j.put_sharded", bytes=a.nbytes):
             a = a.reshape((self.n_shards, per) + a.shape[1:])
             if a.nbytes // self.n_shards >= self._ONE_TRANSFER_BYTES:
-                return self._put_in_row_chunks(a)
-            return jax.make_array_from_callback(
+                return self._put_in_row_chunks(a, each)
+            table = jax.make_array_from_callback(
                 a.shape, self._row_sharding(), lambda idx: a[idx])
+            if each is not None:
+                each(table, 0, per)
+            return table
 
     # One host-to-device transfer of 2**32 bytes or more falls off a
     # cliff in this runtime (my chip runs, PR 26, v5e host, int32
@@ -283,12 +292,42 @@ class DataParallelTrainer:
     # three times the spread; the smaller holds less in flight).
     _ONE_TRANSFER_BYTES = 2 ** 32
     _CHUNK_BYTES = 256 * 2 ** 20
+    # Where work is dispatched on every chunk (``each``) the device sets
+    # the pace, and what the host has to do is keep the link ahead of it.
+    # This host stops for 110 ms at a time, several times a minute, every
+    # process at once; the device goes on through such a stop only with
+    # the chunks that have crossed. Waiting for the device three chunks
+    # back, as staging alone does, starts three transfers at once (the
+    # first chunk is there after 73 ms, not 25) and then holds the link to
+    # the device's pace, so the device runs out of rows as soon as the
+    # host stops. So with ``each`` the host waits for a chunk to have
+    # crossed before it sends the one after the next, and for the device
+    # only when ``_CHUNKS_AHEAD`` chunks that have crossed wait for their
+    # turn (1.5 GB). Two in flight cross at 13.5 GB/s; one at a time
+    # leaves the link idle between chunks (9.8 GB/s), three share it to no
+    # gain. The chunks are half as long: the device starts when the first
+    # pair has crossed, and how long that takes is all that differs from
+    # one job to the next. My chip runs, PR 30, 4.58 GB scored by 500
+    # trees as it crosses, a job in s (quartile distance in ms):
+    # staging's own wait 0.669 (5); 256 MiB, two crossing 0.649 (5);
+    # 128 MiB 0.615 (1.7); 64 MiB 0.611 (0.3), where the host's loop is
+    # what holds the link (10.3 GB/s). A stop 150 ms or more into a job
+    # costs it nothing at 128 MiB, where it cost 70-95 ms before.
+    _EACH_CHUNK_BYTES = 128 * 2 ** 20
+    _CHUNKS_CROSSING = 2
+    _CHUNKS_AHEAD = 12
 
-    def _put_in_row_chunks(self, a: np.ndarray):
+    def _put_in_row_chunks(self, a: np.ndarray, each=None):
         """``a`` [n_shards, per, ...] onto the mesh, rows sharded, a chunk
         of rows at a time: a ``dynamic_update_slice`` places each chunk
         in the donated table while the next is on its way. At most three
         chunks are in flight; the table is never held twice.
+
+        With ``each`` the device has work on every chunk, and the host
+        keeps the link ahead of it instead (``_EACH_CHUNK_BYTES``,
+        ``_CHUNKS_CROSSING``, ``_CHUNKS_AHEAD`` above): chunks half as
+        long, two crossing at a time, and up to twelve that have crossed
+        waiting on the device for their turn.
 
         A chunk crosses as [n_shards, M, 128], which rests on the device
         in the order the host holds it, so the host's runtime has nothing
@@ -302,7 +341,9 @@ class DataParallelTrainer:
         elements do not fill rows of 128 crosses in its own shape."""
         n, per = a.shape[:2]
         row = int(np.prod(a.shape[2:]))         # elements a row
-        rows = max(1, min(per, self._CHUNK_BYTES // (row * a.itemsize)))
+        chunk_bytes = (self._CHUNK_BYTES if each is None
+                       else self._EACH_CHUNK_BYTES)
+        rows = max(1, min(per, chunk_bytes // (row * a.itemsize)))
         if rows >= 128:
             rows -= rows % 128                  # whole rows of 128 lanes
         shape = (n, rows) + a.shape[2:]
@@ -326,7 +367,8 @@ class DataParallelTrainer:
                     place, donate_argnums=0,
                     out_shardings=(sharding, None))
         table = jnp.zeros(a.shape, a.dtype, device=sharding)
-        placed = []
+        placed, crossing = [], []
+        ahead = 2 if each is None else self._CHUNKS_AHEAD
         for start in range(0, per, rows):
             # the last chunk is as long as the others: it starts early
             # and rewrites rows the chunk before it already placed
@@ -338,7 +380,12 @@ class DataParallelTrainer:
                     (-1,) + wire[1:]))
             table, done = place(table, dchunk, np.int32(start))
             placed.append(done)
-            if len(placed) > 2:
+            if each is not None:
+                each(table, start, start + rows)
+                crossing.append(dchunk)
+                if len(crossing) >= self._CHUNKS_CROSSING:
+                    jax.block_until_ready(crossing.pop(0))
+            if len(placed) > ahead:
                 jax.block_until_ready(placed.pop(0))
         return table
 

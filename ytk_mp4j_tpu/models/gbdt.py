@@ -683,6 +683,142 @@ def predict_tree(bins, tree, cfg: GBDTConfig):
 
 
 # ----------------------------------------------------------------------
+# batch scoring: all trees are known, so the order is free
+#
+# Training routes a level at a time because tree t+1 waits for tree t,
+# and every level reads the whole table (``_route_samples``). Scoring an
+# ensemble that way moves trees x depth x table through HBM: 13.75 TB for
+# 500 trees of depth 6 on 1,183,748 x 968 (40.5 s a job with the
+# transfer, my chip run, PR 30). Here the rows are taken in chunks, and
+# for a chunk the trees in groups: one MXU matmul of the chunk against
+# the group's one-hot of split features selects the bins every node of
+# every tree of the group asks for (exact: one term of a sum is nonzero,
+# and a bin digit 0..255 is a bf16 number), every node is decided at
+# once in the matmul's output, and the leaf is found from the decisions
+# by selects from the deepest level up. The table is read once a job, a
+# chunk's bf16 copy once a group; nothing is gathered. Rows rest on the
+# lanes throughout, as the table itself does on the chip ([F, N]), so
+# nothing is transposed either (0.61 s a job, staging included).
+# ----------------------------------------------------------------------
+_SCORE_TREE_GROUP = 16      # trees a matmul selects for (x 2**depth rows)
+_SCORE_ROW_CHUNK = 2 ** 17  # rows a chunk: bounds the [nodes, rows] select
+
+
+def score_group_size(n_trees: int, n_classes: int = 1) -> int:
+    """Rounds a group of the scoring program holds: the ensemble in the
+    fewest groups of at most ``_SCORE_TREE_GROUP`` trees (a softmax
+    round is ``n_classes`` trees), evened out so that the last group is
+    not mostly padding."""
+    most = max(1, _SCORE_TREE_GROUP // n_classes)
+    groups = max(1, -(-n_trees // most))
+    return max(1, -(-n_trees // groups))
+
+
+def score_row_chunks(n_rows: int) -> tuple[int, int]:
+    """(rows a chunk, chunks) the scoring program walks ``n_rows`` rows
+    of a shard in: the fewest chunks of at most ``_SCORE_ROW_CHUNK``
+    rows, of equal length in whole 128-lane words. The last chunk
+    starts early rather than run over the end, and rescoring the few
+    rows it shares with the one before gives them the same margins."""
+    chunks = max(1, -(-n_rows // _SCORE_ROW_CHUNK))
+    if chunks == 1:
+        return n_rows, 1
+    rows = -(-n_rows // chunks)
+    return min(n_rows, -(-rows // 128) * 128), chunks
+
+
+def _bin_digits(n_bins: int) -> int:
+    """Base-256 digits a bin id takes: each is exact in bf16."""
+    return max(1, -(-(int(n_bins) - 1).bit_length() // 8))
+
+
+def _score_group(digits, group, out, cfg: GBDTConfig):
+    """Add one group's trees to ``out`` ([C, R] f32 margins of a chunk),
+    in tree order. ``digits``: the chunk's bins as bf16 [F, R] arrays,
+    least significant first; ``group``: (feat, bin, dir, leaf), each
+    [2**depth, G, C]: node (level order, one unused slot) or leaf, then
+    round, then class. Rows rest on the lanes and nodes are the major
+    axis throughout, so a level is a run of whole registers and a
+    node's two children are every other one."""
+    feat, bin_, dir_, leaf = group
+    width, G, C = feat.shape
+    F, R = digits[0].shape
+    with jax.named_scope("gbdt.score.select"):
+        picks = feat.reshape(-1, 1) == jnp.arange(F, dtype=feat.dtype)
+        onehot = picks.astype(jnp.bfloat16)
+        v = None
+        for k, digit in enumerate(digits):
+            # f32 accumulation of one nonzero term; bf16 holds 0..255
+            part = jnp.dot(onehot, digit,
+                           preferred_element_type=jnp.bfloat16
+                           ).astype(jnp.float32)
+            v = part if v is None else v + part * float(256 ** k)
+    with jax.named_scope("gbdt.score.walk"):
+        def nodes(a):
+            return a.reshape(-1, 1).astype(jnp.float32)
+
+        nb = nodes(bin_)
+        right = v > nb
+        if cfg.missing_bin:
+            right = jnp.where(v == 0, nodes(dir_) > 0, right)
+        cat_mask = cfg._cat_mask()
+        if cat_mask is not None:
+            node_cat = (picks & jnp.asarray(cat_mask)).any(1, keepdims=True)
+            right = jnp.where(node_cat, (v == nb) & (nb != cfg.n_bins - 1),
+                              right)
+        right = right.reshape(width, G * C, R)
+        # from the leaves up: a node's value is its right child's where
+        # the row goes right there, its left child's elsewhere; only
+        # selects, so a non-finite leaf reaches the rows that reach it.
+        # One array a node, so that the whole walk is one elementwise
+        # expression over the decisions and nothing in between is kept.
+        leaf = leaf.reshape(width, G * C, 1)
+        val = [leaf[j] for j in range(width)]
+        for d in reversed(range(cfg.depth)):
+            val = [jnp.where(right[2 ** d - 1 + j], val[2 * j + 1],
+                             val[2 * j]) for j in range(2 ** d)]
+        delta = jnp.broadcast_to(val[0], (G * C, R)).reshape(G, C, R)
+        for g in range(G):
+            out = out + cfg.learning_rate * delta[g]
+    return out
+
+
+def score_shard(bins, stacked, out, start, rows: int, cfg: GBDTConfig,
+                axis_name=None):
+    """Score ``rows`` rows of this shard from row ``start`` on: ``bins``
+    [N, F] under the whole ensemble, their margins written into ``out``
+    ([C, N] f32, C = 1 unless softmax; the other rows are passed on).
+    ``stacked``: (feat, bin, dir, leaf), each [groups, 2**depth, G, C],
+    as ``_stack_trees`` lays them out. Per row the sum runs over the
+    trees in their order, f32, as ``predict_tree`` after
+    ``predict_tree`` would give it."""
+    F = bins.shape[1]
+    C = out.shape[0]
+    n_digits = _bin_digits(cfg.n_bins)
+    row_chunk, chunks = score_row_chunks(rows)
+
+    def chunk_fn(out, c):
+        at = start + jnp.minimum(c * row_chunk, rows - row_chunk)
+        with jax.named_scope("gbdt.score.select"):
+            part = lax.dynamic_slice(bins, (at, jnp.int32(0)),
+                                     (row_chunk, F)).T
+            digits = [((part >> (8 * k)) & 255 if n_digits > 1 else part
+                       ).astype(jnp.bfloat16) for k in range(n_digits)]
+        acc = jnp.zeros((C, row_chunk), jnp.float32)
+        if axis_name is not None:
+            acc = lax.pcast(acc, axis_name, to="varying")
+        acc, _ = lax.scan(
+            lambda acc, group: (_score_group(digits, group, acc, cfg), None),
+            acc, stacked)
+        with jax.named_scope("gbdt.score.walk"):
+            return lax.dynamic_update_slice(
+                out, acc, (jnp.int32(0), at)), None
+
+    out, _ = lax.scan(chunk_fn, out, jnp.arange(chunks, dtype=jnp.int32))
+    return out
+
+
+# ----------------------------------------------------------------------
 # driver: full training under shard_map over a mesh
 # ----------------------------------------------------------------------
 class GBDTTrainer(DataParallelTrainer):
@@ -693,7 +829,8 @@ class GBDTTrainer(DataParallelTrainer):
         self.cfg = cfg
         self._step = None
         self._jobs = 0         # train() calls so far: the spans' ``job``
-        self._predict = None
+        self._score_jobs = 0   # predict() calls so far, likewise
+        self._score_programs = {}   # (table shape, rows, rounds) -> program
         self._margin_step = None
         self._stacked_trees = None
         self.eval_history_: list[float] = []
@@ -749,17 +886,29 @@ class GBDTTrainer(DataParallelTrainer):
         ``sample_weight`` ([N] f32, optional — ytk-learn's instance
         weights) scales each sample's gradient/hessian contribution and
         composes with the padding zeros."""
-        self._check_bins_width(bins)
         N = bins.shape[0]
-        (bins, y), per, w = self._pad_rows([bins, y])
+        dbins = self.shard_bins(bins)
+        (y,), per, w = self._pad_rows([y])
         w[:N] *= self._stage_weights(sample_weight, N)
         if self.cfg.loss == "softmax":
             preds = np.zeros((y.shape[0], self.cfg.n_classes), np.float32)
         else:
             preds = np.zeros_like(y, np.float32)
-        return (self._put_sharded(bins, per), self._put_sharded(y, per),
+        return (dbins, self._put_sharded(y, per),
                 self._put_sharded(preds, per),
                 self._put_sharded(w, per))
+
+    def shard_bins(self, bins: np.ndarray, each=None):
+        """Pad a binned table [N, n_features] to whole shards and place
+        it on the mesh as [n_shards, N/shard, n_features], rows sharded
+        (``_put_sharded``: a shard of 2**32 bytes or more crosses in
+        row chunks, and ``each(table, start, stop)`` is called for the
+        rows of every shard as they are placed). What ``train`` and
+        ``predict`` both stage; the padding rows are cut from
+        ``predict``'s margins and weigh nothing in ``train``."""
+        self._check_bins_width(bins)
+        (bins,), per, _ = self._pad_rows([bins])
+        return self._put_sharded(bins, per, each)
 
     def train(self, bins: np.ndarray, y: np.ndarray,
               n_trees: int | None = None, seed: int = 0,
@@ -954,49 +1103,95 @@ class GBDTTrainer(DataParallelTrainer):
         logp = z - np.log(np.exp(z).sum(axis=1, keepdims=True))
         return float(-np.mean(logp[np.arange(len(y)), y.astype(int)]))
 
+    def _build_score(self, shape, rows: int, rounds: int):
+        """The scoring program for a staged table of ``shape``
+        ([n_shards, rows a shard, n_features]), ``rows`` rows of every
+        shard a call and ``rounds`` rounds: ``score_shard`` under
+        ``shard_map``, rows sharded and the ensemble replicated. It
+        takes (table, ensemble, margins [n_shards, C, rows a shard],
+        first row) and returns the margins, donated, with those rows
+        filled in."""
+        cfg = self.cfg
+        axes = self.axes
+
+        @partial(jax.shard_map, mesh=self.mesh,
+                 in_specs=(P(axes), P(), P(axes), P()), out_specs=P(axes))
+        def score(bins, stacked, out, start):
+            return score_shard(bins[0], stacked, out[0], start, rows, cfg,
+                               axes)[None]
+
+        n_classes = cfg.n_classes if cfg.loss == "softmax" else 1
+        row_chunk, chunks = score_row_chunks(rows)
+        with spans.span("mp4j.step.build", key="gbdt_score",
+                        group=score_group_size(rounds, n_classes),
+                        rows=rows, row_chunk=row_chunk, row_chunks=chunks):
+            return jax.jit(score, donate_argnums=2)
+
     def predict(self, bins: np.ndarray, trees,
                 proba: bool = False) -> np.ndarray:
-        """Ensemble prediction: sum of learning-rate-scaled tree outputs
-        over any binned matrix (one jit; ``lax.scan`` over the stacked
-        ensemble, so program size is one tree regardless of T).
-        Returns raw margins ([N], or [N, n_classes] for softmax);
-        ``proba=True`` applies the sigmoid (logistic) or softmax. The
-        jitted runner is cached on the trainer — repeated predict()
-        calls retrace only when (bins shape, tree count) changes."""
-        if self._predict is None:
-            cfg = self.cfg
-            softmax = cfg.loss == "softmax"
+        """Ensemble prediction: the sum of learning-rate-scaled tree
+        outputs over any binned matrix. Returns raw margins ([N], or
+        [N, n_classes] for softmax); ``proba=True`` applies the sigmoid
+        (logistic) or softmax.
 
-            @jax.jit
-            def run(bins, stacked):
-                # lax.scan over the stacked ensemble: program size is
-                # one tree regardless of T (the unrolled loop compiled
-                # O(T) programs — a compile-time cliff at ytk-learn-
-                # scale ensembles)
-                def body(out, tree):
-                    if softmax:
-                        delta = jnp.stack(
-                            [predict_tree(bins,
-                                          tuple(a[c] for a in tree), cfg)
-                             for c in range(cfg.n_classes)], axis=1)
-                    else:
-                        delta = predict_tree(bins, tree, cfg)
-                    return out + cfg.learning_rate * delta, None
-
-                shape = ((bins.shape[0], cfg.n_classes) if softmax
-                         else (bins.shape[0],))
-                out, _ = lax.scan(body, jnp.zeros(shape, jnp.float32),
-                                  stacked)
-                return out
-
-            self._predict = run
+        The table is staged as ``train`` stages it (``shard_bins``:
+        rows padded to whole shards and sharded over the trainer's
+        mesh, a shard of 2**32 bytes or more in row chunks), the
+        ensemble is replicated, and one jitted ``shard_map`` program
+        scores it (``score_shard``): rows outermost in chunks of at
+        most 2**17, trees in groups of up to 16 inside a chunk, so the
+        table is read once a job and not once a level of every tree. A
+        table that crosses in chunks is scored a chunk at a time, as
+        soon as the chunk is in place, while the next ones cross (two
+        at a time, up to twelve ahead of the device). A
+        row's margin is the f32 sum over the trees in their order. The
+        program is kept by (table shape, rows a call, tree count): a
+        repeated ``predict`` of the same shape builds nothing."""
         bins = np.asarray(bins, np.int32)
         self._check_bins_width(bins)
-        out = np.asarray(self._predict(jnp.asarray(bins),
-                                       self._stack_trees(trees)))
+        trees = list(trees)
+        N = bins.shape[0]
+        softmax = self.cfg.loss == "softmax"
+        C = self.cfg.n_classes if softmax else 1
+        if not trees or not N:
+            # an untrained / zero-round ensemble leaves the margins zero
+            out = np.zeros((N, C), np.float32)
+        else:
+            job, self._score_jobs = self._score_jobs, self._score_jobs + 1
+            stacked = self._stack_trees(trees)
+            margins = None              # the device's, as last returned
+            scored = 0                  # rows of a shard scored so far
+
+            def score(table, start: int, stop: int):
+                nonlocal margins, scored
+                # the last chunk of a staging starts early, over rows
+                # that the one before it brought: those are done
+                start, scored = max(start, scored), stop
+                key = (table.shape, stop - start, len(trees))
+                program = self._score_programs.get(key)
+                if program is None:
+                    program = self._score_programs[key] = \
+                        self._build_score(*key)
+                with spans.span("mp4j.gbdt.score.dispatch", job=job,
+                                trees=len(trees), start=start):
+                    if margins is None:
+                        margins = jnp.zeros(
+                            (table.shape[0], C, table.shape[1]), jnp.float32,
+                            device=self._row_sharding())
+                    margins = program(table, stacked, margins,
+                                      np.int32(start))
+
+            with spans.span("mp4j.gbdt.score.stage", job=job):
+                self.shard_bins(bins, each=score)
+            with spans.span("mp4j.gbdt.score.fetch", job=job):
+                out = self._to_host(margins)
+            # [n_shards, C, rows a shard] -> [N, C]
+            out = out.transpose(0, 2, 1).reshape(-1, C)[:N]
+        if not softmax:
+            out = out[:, 0]
         if not proba:
             return out
-        if self.cfg.loss == "softmax":
+        if softmax:
             z = out - out.max(axis=1, keepdims=True)   # overflow-free
             e = np.exp(z)
             return e / e.sum(axis=1, keepdims=True)
@@ -1009,38 +1204,40 @@ class GBDTTrainer(DataParallelTrainer):
         p[~pos] = e / (1.0 + e)
         return p
 
-    def _stack_trees(self, trees):
-        """Stack the per-round tree tuples into [T(, n_classes), ...]
-        component arrays so predict can ``lax.scan`` over the ensemble
-        (trees are fixed-shape tuples — SURVEY.md section 2 GBDT row).
-        Host-side fetch doubles as the non-addressable-device hop for
-        multi-process meshes. The stacked tuple is cached by tree
-        identity (holding the list keeps ids stable), so repeated
-        predict() on the same ensemble pays the O(T) fetch once."""
-        trees = list(trees)
+    def _stack_trees(self, trees: list):
+        """The ensemble as the scoring program takes it: (feat, bin,
+        dir, leaf), each [groups, 2**depth, G, C], replicated on the
+        mesh: a tree's nodes in level order with one unused slot (or
+        its leaves), G rounds a group (``score_group_size``), C trees a
+        round (1 unless softmax). The last group is filled up with
+        trees of frozen nodes and zero leaves, which add 0.0. The
+        ensemble is fetched in one ``device_get`` (the hop off
+        non-addressable devices on multi-process meshes too) and the
+        result kept by tree identity (holding the list keeps ids
+        stable), so repeated predict() on the same ensemble pays for it
+        once."""
         cached = self._stacked_trees
         if (cached is not None and len(cached[0]) == len(trees)
                 and all(a is b for a, b in zip(cached[0], trees))):
             return cached[1]
-        if not trees:
-            # length-0 scan: margins stay at the zero init, matching the
-            # pre-scan contract for an untrained/zero-round ensemble
-            C = 2 ** self.cfg.depth
-            lead = ((0, self.cfg.n_classes)
-                    if self.cfg.loss == "softmax" else (0,))
-            return (jnp.zeros(lead + (C - 1,), jnp.int32),
-                    jnp.zeros(lead + (C - 1,), jnp.int32),
-                    jnp.zeros(lead + (C - 1,), jnp.int32),
-                    jnp.zeros(lead + (C,), jnp.float32))
-        if self.cfg.loss == "softmax":
-            stacked = tuple(
-                jnp.asarray(np.stack(
-                    [[np.asarray(cls[j]) for cls in rnd] for rnd in trees]))
-                for j in range(4))
-        else:
-            stacked = tuple(
-                jnp.asarray(np.stack([np.asarray(t[j]) for t in trees]))
-                for j in range(4))
+        cfg = self.cfg
+        host = jax.device_get(trees)
+        if cfg.loss != "softmax":
+            host = [(rnd,) for rnd in host]
+        T, C, width = len(host), len(host[0]), 2 ** cfg.depth
+        G = score_group_size(T, C)
+        groups = -(-T // G)
+        stacked = []
+        for j, (dtype, fill) in enumerate((
+                (np.int32, 0), (np.int32, cfg.n_bins - 1), (np.int32, 0),
+                (np.float32, 0.0))):
+            a = np.full((groups * G, C, width), fill, dtype)
+            part = np.stack([[np.asarray(cls[j]) for cls in rnd]
+                             for rnd in host])
+            a[:T, :, :part.shape[2]] = part
+            stacked.append(np.ascontiguousarray(
+                a.reshape(groups, G, C, width).transpose(0, 3, 1, 2)))
+        stacked = self._place_replicated(tuple(stacked))
         self._stacked_trees = (trees, stacked)
         return stacked
 
