@@ -1,6 +1,7 @@
 """The replicated sparse FM/FFM step in block form: the table held by
 feature, one gather and one scatter descriptor a (sample, feature), the
-field vectors picked out of the block on the device.
+field vectors picked out of the block on the device and the feature's
+linear weight in the block's last column.
 
 Everything goes through the public surface (``fit`` / ``fit_stream`` on
 the [n_rows, k] table) and is held to a float64 numpy SGD step written
@@ -123,13 +124,20 @@ def test_block_step_matches_a_float64_step(rng, model, case, n_shards):
 
 @pytest.mark.parametrize("l2", [0.0, 1e-2])
 @pytest.mark.parametrize("capacity", [None, NFEAT, 17])
-def test_three_block_steps_with_decay_and_dedupe(rng, l2, capacity):
+@pytest.mark.parametrize("model,k", [("ffm", KDIM), ("ffm", 4), ("fm", KDIM)])
+def test_three_block_steps_with_decay_and_dedupe(rng, model, k, l2,
+                                                 capacity):
     """Several steps, with the multiplicative l2 decay and with the
     local merge of duplicate features before the all_gather: a capacity
-    in FEATURES (17 holds each shard's distinct features here)."""
-    cfg = _cfg("ffm", l2=l2)
+    in FEATURES (17 holds each shard's distinct features here). Feature
+    8 is in every row, twice in every other one; the last slot of every
+    row is padding (value 0, id 0) and no other slot holds feature 0.
+    At k = 4 the runs fill the block's 128 columns and the weight's
+    column is the tail of the last run."""
+    cfg = _cfg(model, k=k, l2=l2)
     feats, fields, vals, _ = _instances(rng, "one_feature_many_rows")
-    feats = feats % 16                      # at most 16 distinct features
+    feats = 1 + feats % 15                  # at most 16 distinct features
+    feats[:, -1], vals[:, -1] = 0, 0.0
     y = rng.integers(0, 2, feats.shape[0]).astype(np.float32)
     start = _start(cfg, rng)
     tr = FMTrainer(cfg, mesh=make_mesh(2), sparse_grads=True,
@@ -142,6 +150,17 @@ def test_three_block_steps_with_decay_and_dedupe(rng, l2, capacity):
     np.testing.assert_allclose(losses, want_losses, rtol=5e-6)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), w, rtol=5e-5, atol=5e-7)
+    # the linear weight of every touched feature moved (to where the
+    # float64 steps took it: above)
+    touched = np.unique(feats[:, :-1])
+    got_w = np.asarray(got[1])
+    assert (got_w[touched] != start[1][touched]).all()
+    # a padded slot's gradient is exactly 0.0: feature 0's weight sees
+    # the decay alone, bit for bit
+    w_pad = start[1][0]
+    for _ in range(3):
+        w_pad = w_pad * np.float32(1.0 - cfg.learning_rate * l2)
+    assert got_w[0].view(np.uint32) == np.float32(w_pad).view(np.uint32)
 
 
 @pytest.mark.parametrize("n_shards", [1, 2])
@@ -173,17 +192,21 @@ def test_a_field_no_row_has_keeps_its_vectors_bit_for_bit(rng, n_shards):
     [[0, 1, 2]], [[2, 2, 0]], [[4, 4, 4]], [[3, 0, 3], [1, 1, 2]]],
     ids=["distinct", "twice", "all_one", "two_rows"])
 def test_field_select_is_exact_and_its_transpose_adds(rng, fields):
-    """``E[n, a, b]`` is bit for bit ``blk[n, a, fields[n, b]]``, and the
-    gradient comes back summed over the slots of a field, 0.0 elsewhere."""
+    """``E[n, a, b]`` is bit for bit ``blk[n, a, fields[n, b]]`` and
+    ``wv[n, a]`` the block's last column, and the gradient comes back
+    summed over the slots of a field, the weight's in its column, 0.0
+    elsewhere."""
     cfg = _cfg("ffm")
     fields = np.asarray(fields, np.int32)
     N, K = fields.shape
     width = fm_mod._block_width(cfg)
     assert width == 128
     blk = rng.standard_normal((N, K, width)).astype(np.float32)
-    E, back = jax.vjp(lambda b: fm_mod._select_fields(b, fields, cfg),
-                      jnp.asarray(blk))
+    (wv, E), back = jax.vjp(
+        lambda b: fm_mod._select_fields(b, fields, cfg), jnp.asarray(blk))
     stride = fm_mod._block_stride(cfg)
+    np.testing.assert_array_equal(np.asarray(wv).view(np.uint32),
+                                  blk[:, :, -1].view(np.uint32))
 
     def by_field(a):            # [N, K, width] -> [N, K, n_fields, k]
         return a[:, :, :KDIM * stride].reshape(N, K, KDIM, stride)[
@@ -194,13 +217,15 @@ def test_field_select_is_exact_and_its_transpose_adds(rng, fields):
     np.testing.assert_array_equal(np.asarray(E).view(np.uint32),
                                   want.view(np.uint32))
     gE = rng.standard_normal(E.shape).astype(np.float32)
-    (gblk,) = back(jnp.asarray(gE))
+    gw = rng.standard_normal(wv.shape).astype(np.float32)
+    (gblk,) = back((jnp.asarray(gw), jnp.asarray(gE)))
     want_g = np.zeros((N, K, NFIELDS, KDIM), np.float64)
     for n in range(N):
         for b in range(K):
             want_g[n, :, fields[n, b]] += gE[n, :, b]
     gblk = np.asarray(gblk)
-    nonzero = np.count_nonzero(gblk)
+    np.testing.assert_allclose(gblk[:, :, -1], gw, rtol=1e-6, atol=0)
+    nonzero = np.count_nonzero(gblk) - gw.size
     gblk = by_field(gblk)
     assert np.count_nonzero(gblk) == nonzero    # the padding got 0.0
     np.testing.assert_allclose(gblk, want_g, rtol=1e-6, atol=0)
@@ -254,29 +279,48 @@ def test_one_chunk_fed_e_times_is_fit_of_e_steps(rng, model):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(z))
 
 
-def test_table_round_trip_through_the_block_form_is_exact(rng):
-    """Converting in and out moves no bit, with a last conversion block
-    that starts early (77 features in blocks of 32)."""
-    cfg = _cfg("ffm", n_features=77)
+@pytest.mark.parametrize("model,k,stride", [
+    ("ffm", KDIM, 42), ("ffm", 4, 32), ("fm", KDIM, None)],
+    ids=["ffm", "ffm_runs_fill_the_width", "fm"])
+def test_table_round_trip_through_the_block_form_is_exact(rng, model, k,
+                                                          stride):
+    """``narrow(widen(params))`` returns ``w0``, ``w`` and ``V`` bit for
+    bit (a -0.0 keeps its sign), with a last conversion block that
+    starts early (77 features in blocks of 32)."""
+    cfg = _cfg(model, k=k, n_features=77)
     tr = FMTrainer(cfg, mesh=make_mesh(2), sparse_grads=True)
     tr._CONVERT_ROWS = 32 * NFIELDS
-    V = rng.standard_normal((77 * NFIELDS, KDIM)).astype(np.float32)
+    V = rng.standard_normal((tr.n_rows, k)).astype(np.float32)
     w = rng.standard_normal(77).astype(np.float32)
-    state = tr._enter((np.float32(0.5), w, V))
-    # a block is padded with zeros to whole 128-lane words; entry j of
-    # the vector against field fl is column j * 42 + fl
-    assert state[2].shape == (77, 128)
-    assert fm_mod._block_stride(cfg) == 42
-    T = np.asarray(state[2])
-    np.testing.assert_array_equal(
-        T[:, :126].reshape(77, KDIM, 42)[:, :, :NFIELDS],
-        V.reshape(77, NFIELDS, KDIM).transpose(0, 2, 1))
-    assert not T[:, :126].reshape(77, KDIM, 42)[:, :, NFIELDS:].any()
-    assert not T[:, 126:].any()
-    back = tr._leave(state)
-    np.testing.assert_array_equal(np.asarray(back[2]), V)
-    np.testing.assert_array_equal(np.asarray(back[1]), w)
-    assert float(back[0]) == 0.5
+    V[::5, 0], w[::7] = -0.0, -0.0
+    w0, T = tr._enter((np.float32(0.5), w, V))
+    T = np.asarray(T)
+    assert float(w0) == 0.5
+    # the linear weight is the block's last column
+    assert fm_mod._weight_column(cfg) == T.shape[1] - 1
+    np.testing.assert_array_equal(T[:, -1].view(np.uint32),
+                                  w.view(np.uint32))
+    if model == "fm":
+        assert T.shape == (77, k + 1)
+        np.testing.assert_array_equal(T[:, :k], V)
+    else:
+        # a block is padded with zeros to whole 128-lane words; entry j
+        # of the vector against field fl is column j * stride + fl
+        assert T.shape == (77, 128)
+        assert fm_mod._block_stride(cfg) == stride
+        runs = T[:, :k * stride].reshape(77, k, stride)
+        np.testing.assert_array_equal(
+            runs[:, :, :NFIELDS],
+            V.reshape(77, NFIELDS, k).transpose(0, 2, 1))
+        # every column but the vectors' and the weight's is 0.0
+        vectors = np.zeros(128, bool)
+        vectors[:k * stride].reshape(k, stride)[:, :NFIELDS] = True
+        assert vectors.sum() == NFIELDS * k and not vectors[-1]
+        assert not T[:, ~vectors][:, :-1].any()
+    back = tr._leave((w0, jnp.asarray(T)))
+    for b, given in zip(back, (np.float32(0.5), w, V)):
+        np.testing.assert_array_equal(
+            np.asarray(b).view(np.uint32), np.asarray(given).view(np.uint32))
 
 
 def test_early_stopping_returns_the_best_rounds_public_params(rng):
@@ -327,8 +371,10 @@ def test_the_step_refuses_the_public_table(rng):
     slots = jax.ShapeDtypeStruct((1, 8, NNZ), jnp.float32)
     ids = jax.ShapeDtypeStruct((1, 8, NNZ), jnp.int32)
     row = jax.ShapeDtypeStruct((1, 8), jnp.float32)
-    step.lower(tr._state_avals(), ids, ids, slots, slots, row, row)
-    public = tr._state_avals()[:2] + (
-        jax.ShapeDtypeStruct((tr.n_rows, cfg.k), jnp.float32),)
-    with pytest.raises(Mp4jError, match="by feature"):
-        step.lower(public, ids, ids, slots, slots, row, row)
+    state = tr._state_avals()
+    step.lower(state, ids, ids, slots, slots, row, row)
+    table = jax.ShapeDtypeStruct((tr.n_rows, cfg.k), jnp.float32)
+    w = jax.ShapeDtypeStruct((NFEAT,), jnp.float32)
+    for public in [(state[0], table), (state[0], w, table)]:
+        with pytest.raises(Mp4jError, match="by feature"):
+            step.lower(public, ids, ids, slots, slots, row, row)

@@ -333,37 +333,44 @@ def _ffm_cell():
 @pytest.fixture(scope="module")
 def ffm_programs(topo_devices):
     """The step and both conversions of ``ffm-criteo.stream-zipf`` at the
-    cell's own size, compiled for one described chip."""
+    cell's own size, compiled for one described chip; the step also for
+    the four of the described host, each with the cell's chunk."""
     from ytk_mp4j_tpu.models.fm import FMConfig, FMTrainer
 
     c, t = _ffm_cell()
-    mesh = Mesh(np.asarray(topo_devices[:1]), ("mp4j",))
-    trainer = FMTrainer(FMConfig(
-        model=c["model"], n_features=c["n_features"], n_fields=c["n_fields"],
-        k=c["k"], max_nnz=c["max_nnz"], learning_rate=c["learning_rate"]),
-        mesh=mesh, sparse_grads=c["sparse_grads"],
-        table_sharding=c["table_sharding"])
-    rows, rep = NamedSharding(mesh, P("mp4j")), NamedSharding(mesh, P())
+    descriptors = t["rows_per_chunk"] * c["max_nnz"]
 
-    def aval(shape, dtype, sharding=rows):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    def trainer_and_step(chips):
+        mesh = Mesh(np.asarray(topo_devices[:chips]), ("mp4j",))
+        trainer = FMTrainer(FMConfig(
+            model=c["model"], n_features=c["n_features"],
+            n_fields=c["n_fields"], k=c["k"], max_nnz=c["max_nnz"],
+            learning_rate=c["learning_rate"]),
+            mesh=mesh, sparse_grads=c["sparse_grads"],
+            table_sharding=c["table_sharding"])
+        rows = NamedSharding(mesh, P("mp4j"))
+        slots = (chips, t["rows_per_chunk"], c["max_nnz"])
+        batch = [jax.ShapeDtypeStruct(shape, dtype, sharding=rows)
+                 for shape, dtype in (
+                     (slots, jnp.int32), (slots, jnp.int32),
+                     (slots, jnp.float32), (slots, jnp.float32),
+                     (slots[:2], jnp.float32), (slots[:2], jnp.float32))]
+        return trainer, trainer._build_step(descriptors).lower(
+            trainer._state_avals(), *batch).compile()
 
-    slots = (1, t["rows_per_chunk"], c["max_nnz"])
-    batch = (aval(slots, jnp.int32), aval(slots, jnp.int32),
-             aval(slots, jnp.float32), aval(slots, jnp.float32),
-             aval(slots[:2], jnp.float32), aval(slots[:2], jnp.float32))
-    state = trainer._state_avals()
-    public = (aval((), jnp.float32, rep),
-              aval((c["n_features"],), jnp.float32, rep),
-              aval((trainer.n_rows, c["k"]), jnp.float32, rep))
+    trainer, step = trainer_and_step(1)
+    rep = NamedSharding(trainer.mesh, P())
+    public = tuple(
+        jax.ShapeDtypeStruct(shape, jnp.float32, sharding=rep)
+        for shape in ((), (c["n_features"],), (trainer.n_rows, c["k"])))
     widen, narrow = trainer._build_converters()
     return {
         "config": c,
-        "descriptors": t["rows_per_chunk"] * c["max_nnz"],
-        "step": trainer._build_step(t["rows_per_chunk"] * c["max_nnz"])
-        .lower(state, *batch).compile(),
+        "descriptors": descriptors,
+        "step": step,
+        "step_on_four": trainer_and_step(4)[1],
         "widen": widen.lower(public).compile(),
-        "narrow": narrow.lower(state).compile(),
+        "narrow": narrow.lower(trainer._state_avals()).compile(),
     }
 
 
@@ -392,34 +399,71 @@ def test_table_sized_detector():
 def test_ffm_step_scatters_into_the_table_where_it_rests(ffm_programs):
     c, step = ffm_programs["config"], ffm_programs["step"]
     text = step.as_text()
-    # 39 fields x 4 floats in two 128-lane words
+    # 39 fields x 4 floats and the linear weight in two 128-lane words
     F, width = c["n_features"], 256
-    assert c["n_fields"] * c["k"] == 156
+    assert c["n_fields"] * c["k"] + 1 == 157
     table = r"f32\[%d,%d\]\{1,0:T\(8,128\)\}" % (F, width)
     # the table comes in row-major by feature, which no layout is pinned
     # for: a width that is not whole 128-lane words rests with the
     # features on the lanes and is copied whole twice a step
-    assert re.search(table + r" parameter\(2\)", text)
-    assert re.search(r"input_output_alias=\{.*\{2\}: \(2, \{\}, may-alias\)",
+    assert re.search(table + r" parameter\(1\)", text)
+    assert re.search(r"input_output_alias=\{.*\{1\}: \(1, \{\}, may-alias\)",
                      text)
     # one native gather and one native scatter of N x K descriptors, on
     # the parameter itself: no loop of slices, no copy of the table
     d = ffm_programs["descriptors"]
     assert re.search(
-        r"= f32\[%d,%d\]\S* fusion\(%%params_2_\S*, [^)]*\), kind=kCustom"
+        r"= f32\[%d,%d\]\S* fusion\(%%params_1_\S*, [^)]*\), kind=kCustom"
         r".*ffm\.table_gather" % (d, width), text)
     assert re.search(
-        r"= " + table + r" fusion\(%params_2_\S*, [^)]*\), kind=kCustom"
+        r"= " + table + r" fusion\(%params_1_\S*, [^)]*\), kind=kCustom"
         r".*ffm\.table_update", text)
     assert " while(" not in text
     assert _table_sized(text, "copy", F * width // 2) == []
     assert _table_sized(text, "transpose", F * width // 2) == []
 
 
+@pytest.mark.parametrize("which", ["step", "step_on_four"])
+def test_ffm_step_has_one_index_stream(ffm_programs, which):
+    """The linear weights ride in the blocks: a step holds one gather and
+    one scatter, both on the table, and nothing at all of the shape
+    [n_features] (the parent gathered ``w[feats]``, scattered the
+    weights' gradient into a dense zero vector, updated all of ``w`` and,
+    on more than one chip, all-reduced that vector)."""
+    c = ffm_programs["config"]
+    text = ffm_programs[which].as_text()
+    F, width = c["n_features"], 256
+
+    def instructions(opcode):
+        return re.findall(r"^.* = (.+?) %s\(" % opcode, text, re.M)
+
+    assert len(instructions("gather")) == len(instructions("scatter")) == 1
+    # both as native fusions that take the table itself
+    custom = [line for line in text.splitlines()
+              if " fusion(" in line and "kind=kCustom" in line
+              and re.search(r"ffm\.table_(gather|update)", line)]
+    assert len(custom) == 2
+    by_name = dict(re.findall(r"(%\S+) = (\S+) parameter\(", text))
+    for line in custom:
+        first = re.search(r" fusion\((%[^,)]+)", line).group(1)
+        assert by_name[first].startswith("f32[%d,%d]" % (F, width)), line
+    # no operand or result of the shape [n_features], of any type, in any
+    # instruction: no gather, scatter, all-reduce or elementwise op on one
+    assert re.search(r"\[%d\]" % F, text) is None
+    # what crosses chips: the scalars (loss, weight sum, the bias's
+    # gradient) all-reduced, the slots' indices and blocks all-gathered
+    reduced = instructions("all-reduce")
+    assert all(re.fullmatch(r"\(?(f32\[\]\S*,? ?)+\)?", shapes)
+               for shapes in reduced), reduced
+    assert bool(reduced) == (which == "step_on_four")
+    assert _table_sized(text, "copy", F * width // 2) == []
+    assert ffm_programs[which].memory_analysis().temp_size_in_bytes < 0.5e9
+
+
 def test_ffm_step_holds_one_table_and_small_temporaries(ffm_programs):
     c = ffm_programs["config"]
     m = ffm_programs["step"].memory_analysis()
-    # 156 floats in 256: 4.29 GB for 2.62 GB of values
+    # 157 floats in 256: 4.29 GB for 2.64 GB of values
     padded = c["n_features"] * 256 * 4
     assert padded <= m.alias_size_in_bytes < padded + 2 ** 25
     assert m.temp_size_in_bytes < 0.5e9, m.temp_size_in_bytes
@@ -428,10 +472,15 @@ def test_ffm_step_holds_one_table_and_small_temporaries(ffm_programs):
 
 @pytest.mark.parametrize("which", ["widen", "narrow"])
 def test_ffm_conversions_go_a_block_at_a_time(ffm_programs, which):
-    """2.62 GB in and 4.29 GB out (or the reverse) with a third of a GB
-    between them: a whole-table relayout would need 83.7 GB."""
+    """2.63 GB in (the table and the weights) and 4.29 GB out, or the
+    reverse, with a third of a GB between them: a whole-table relayout
+    would need 83.7 GB."""
     m = ffm_programs[which].memory_analysis()
     assert m.temp_size_in_bytes < 0.5e9, m.temp_size_in_bytes
+    F = ffm_programs["config"]["n_features"]
+    text = ffm_programs[which].as_text()
+    for opcode in ("copy", "transpose", "pad", "concatenate"):
+        assert _table_sized(text, opcode, F * 156 // 2) == [], opcode
     assert m.alias_size_in_bytes == 0       # the caller's table is kept
     sizes = sorted([m.argument_size_in_bytes, m.output_size_in_bytes])
     assert 2.6e9 < sizes[0] < 2.7e9 and 4.29e9 < sizes[1] < 4.35e9

@@ -210,10 +210,12 @@ def test_fit_stream_leaves_exactly_its_spans(rng, ring, family,
         assert converters[6] == {"key": "table_converters",
                                  "block_features": 32}
         assert _inside(build, dispatch[0])
-        # one gather and one scatter descriptor a (sample, feature)
+        # one gather and one scatter descriptor a (sample, feature),
+        # the linear weight's included
         assert build[6] == {"key": (16 // N_SHARDS) * 4,
                             "table_form": "blocks",
-                            "descriptors": (16 // N_SHARDS) * 4}
+                            "descriptors": (16 // N_SHARDS) * 4,
+                            "index_streams": 1}
         # the table is converted before the first chunk is staged and
         # after the last loss is fetched
         assert widen[2] + widen[3] <= stage[0][2]
@@ -237,14 +239,16 @@ def test_a_new_padded_shape_is_a_second_build_span(rng, ring):
     ids=["replicated", "dedupe", "sharded"])
 def test_step_build_span_says_the_table_form(rng, ring, kw, carries):
     """The replicated sparse step indexes by feature (N x K descriptors
-    of a shard); the sharded step still indexes a row a slot pair."""
+    of a shard) and by nothing else (one index stream: the linear
+    weights ride in the blocks); the sharded step still indexes a row a
+    slot pair and the weights beside it."""
     tr, chunks = _ffm(rng, 1, **kw)
     tr.fit_stream(iter(chunks))
     build = [s[6] for s in _named(_trainer_spans(), "mp4j.step.build")
              if s[6]["key"] == 16]
     want = {"key": 16}
     if carries:
-        want.update(table_form="blocks", descriptors=16)
+        want.update(table_form="blocks", descriptors=16, index_streams=1)
     assert build == [want]
 
 

@@ -374,7 +374,8 @@ def check_ffm(results: dict, devices, n: int, per: int = 1024):
     batch_avals = (_i32(n, per, cfg.max_nnz), _i32(n, per, cfg.max_nnz),
                    _f32(n, per, cfg.max_nnz), _f32(n, per, cfg.max_nnz),
                    _f32(n, per), _f32(n, per))
-    # the replicated sparse step takes the table by feature, in blocks
+    # the replicated sparse step takes (w0, T): the table by feature, in
+    # blocks that hold the linear weights too
     _compile("ffm/sparse_train_step", results,
              tr._build_step(per * cfg.max_nnz),
              tr._state_avals(), *batch_avals)

@@ -29,10 +29,12 @@ TPU-first rebuild. Instances are padded to ``max_nnz`` static slots
   descriptor, where a row a slot PAIR would be ``max_nnz`` times as
   many. The serial unit charges by the descriptor, not by its bytes
   (TPU v5 lite, PR 27, 79,872 descriptors: scatter-add 89.3 ns at
-  1 KB against 87.0 ns at 16 B, gather 13.0 against 17.3). The step
-  donates that table and updates it in place; ``fit`` / ``fit_stream``
-  convert the public ``[n_rows, k]`` table once on the way in and once
-  on the way out.
+  1 KB against 87.0 ns at 16 B, gather 13.0 against 17.3). The
+  feature's linear weight rides in the block's last column, so the
+  step makes that one gather and that one scatter-add and touches
+  nothing else of size ``n_features``. The step donates that table and
+  updates it in place; ``fit`` / ``fit_stream`` convert the public
+  ``(w0, w, V)`` once on the way in and once on the way out.
 
 Model scores (order-2, sigmoid/logloss for classification):
 
@@ -97,20 +99,32 @@ def _gather_slots(V, rows):
 
 
 def _block_width(cfg: FMConfig) -> int:
-    """Floats in a row of the step's table. An FFM block is padded with
-    zeros to whole 128-lane words (39 fields x 4: 156 floats in 256), so
-    that the table rests row-major on the TPU, a feature's block one
-    contiguous run: XLA keeps a [n_features, 156] parameter with the
-    features on the lanes, and both the gather and the scatter then copy
-    the whole table every step (AOT for v5e, ISSUE 27: 4.30 GB of
-    temporaries). Pinning the layout of the unpadded shape
-    (``jax.experimental.layout``) rests the same bytes the same way, but
-    an executable read back from the persistent compilation cache
-    reports its output in the default layout and the next call refuses
-    it (my chip run, PR 27, jax 0.9.0). An FM row is its one vector."""
+    """Floats in a row of the step's table: the feature's vectors and
+    its linear weight (:func:`_weight_column`). An FFM block is padded
+    with zeros to whole 128-lane words (39 fields x 4 and the weight:
+    157 floats in 256), so that the table rests row-major on the TPU, a
+    feature's block one contiguous run: XLA keeps a [n_features, 156]
+    parameter with the features on the lanes, and both the gather and
+    the scatter then copy the whole table every step (AOT for v5e,
+    ISSUE 27: 4.30 GB of temporaries). Pinning the layout of the
+    unpadded shape (``jax.experimental.layout``) rests the same bytes
+    the same way, but an executable read back from the persistent
+    compilation cache reports its output in the default layout and the
+    next call refuses it (my chip run, PR 27, jax 0.9.0). An FM row is
+    its one vector and the weight."""
     if cfg.model == "fm":
-        return cfg.k
-    return -(-cfg.n_fields * cfg.k // 128) * 128
+        return cfg.k + 1
+    return -(-(cfg.n_fields * cfg.k + 1) // 128) * 128
+
+
+def _weight_column(cfg: FMConfig) -> int:
+    """Column of a block that holds the feature's linear weight: the
+    last. No vector entry lives there: the k runs of ``stride`` columns
+    (:func:`_block_stride`) either end before it or, where they fill
+    the width, leave the last run's tail free (``width >= n_fields * k
+    + 1`` makes ``stride > n_fields`` then), so ``_select_fields``
+    never reads it as a vector's entry."""
+    return _block_width(cfg) - 1
 
 
 def _block_stride(cfg: FMConfig) -> int:
@@ -121,56 +135,67 @@ def _block_stride(cfg: FMConfig) -> int:
     component's run of a feature's fields is a run of lanes there too,
     and a conversion moves whole runs (TPU v5 lite, PR 27, the 2.62 GB
     table: 0.057 s a conversion; with the fields outermost, column
-    ``fl * k + j``, every entry changes lane and it takes 0.266 s)."""
+    ``fl * k + j``, every entry changes lane and it takes 0.266 s).
+    An FM block has no runs: its vector, then the weight."""
     return _block_width(cfg) // cfg.k
 
 
 def _gather_blocks(T, feats):
-    """The embedding gather of the step's table ``T``
-    [n_features, block]: one descriptor a (sample, feature) brings the
-    feature's vectors against every field; [N, K, block]."""
+    """The one gather of the step's table ``T`` [n_features, block]:
+    one descriptor a (sample, feature) brings the feature's vectors
+    against every field and its linear weight; [N, K, block]."""
     with jax.named_scope("ffm.table_gather"):
         return T[feats]
 
 
 def _select_fields(blk, fields, cfg: FMConfig):
-    """``E[n, a, b] = v_{feat_a, field_b}`` picked out of the gathered
-    blocks ``blk`` [N, K, block] -> [N, K, K, k] (FM: the block is the
+    """``(wv, E)`` out of the gathered blocks ``blk`` [N, K, block]:
+    ``wv[n, a]`` the linear weight of feature a [N, K], and
+    ``E[n, a, b] = v_{feat_a, field_b}`` [N, K, K, k] (FM: the block's
     vector, [N, K, k]).
 
     A one-hot contraction over the block's columns (column
     ``j * stride + field_b`` of feature a's block is entry j of
-    E[n, a, b], :func:`_block_stride`; the padding columns are never
-    selected and get a gradient of 0.0), exact in f32: each output is
-    one block entry times 1.0 plus zeros (``HIGHEST``: the f32 value
-    crosses the MXU as three bf16 pieces whose sum is the value). Not
-    a second indexed gather, which would
+    E[n, a, b], :func:`_block_stride`; one more column of the one-hot
+    picks the weight, :func:`_weight_column`; the padding columns are
+    never selected and get a gradient of 0.0), exact in f32: each
+    output is one block entry times 1.0 plus zeros (``HIGHEST``: the
+    f32 value crosses the MXU as three bf16 pieces whose sum is the
+    value). Not a second indexed gather, which would
     bring back a descriptor a slot pair. Its transpose is what carries
     the gradient back into the block: two slots of a row in one field
-    add, a field the row lacks gets exactly 0.0. Contracting the
+    add, a field the row lacks gets exactly 0.0, and the weight's
+    gradient lands in its column of the same array. Contracting the
     columns as they lie ([N, K, block] x [N, block, K * k]) took 3.1 ms
     of a 2,048 x 39-slot step on TPU v5 lite (PR 27); contracting a
     [N, K, n_fields, k] view over the fields 5.4, a compare-and-sum
-    5.8."""
+    5.8. The weight as a lane slice of ``blk`` beside the contraction,
+    its gradient padded back into the block's width: 0.48 ms a step
+    more than as the contraction's 157th output (PR 31)."""
+    wcol = _weight_column(cfg)
     if cfg.model == "fm":
-        return blk
+        return blk[..., wcol], blk[..., :cfg.k]
     N, K, width = blk.shape
     cols = (fields[:, :, None] + _block_stride(cfg)
             * jnp.arange(cfg.k, dtype=fields.dtype)).reshape(N, K * cfg.k)
+    cols = jnp.concatenate(
+        [cols, jnp.full((N, 1), wcol, cols.dtype)], axis=1)
     sel = jax.nn.one_hot(cols, width, dtype=blk.dtype, axis=1)
-    return jnp.einsum("nac,ncm->nam", blk, sel,
-                      precision=lax.Precision.HIGHEST).reshape(
-                          N, K, K, cfg.k)
+    out = jnp.einsum("nac,ncm->nam", blk, sel,
+                     precision=lax.Precision.HIGHEST)
+    return out[..., -1], out[..., :-1].reshape(N, K, K, cfg.k)
 
 
-def _score_from_slots(w0, w, E, feats, xv, cfg: FMConfig):
-    """Model score given the already-gathered embedding rows ``E``.
+def _score_from_slots(w0, wv, E, xv, cfg: FMConfig):
+    """Model score given the already-gathered linear weights ``wv``
+    [N, K] and embedding rows ``E`` of every slot.
 
     Split out from :func:`_score` so the sparse train step can
-    differentiate with respect to E DIRECTLY (per-slot gradient rows)
-    instead of the full table — the backward of a table gather is a
-    dense scatter-add over |V| rows on the serial scatter unit."""
-    linear = jnp.sum(w[feats] * xv, axis=1)
+    differentiate with respect to what it gathered DIRECTLY (per-slot
+    gradient rows) instead of the full table — the backward of a table
+    gather is a dense scatter-add over |V| rows on the serial scatter
+    unit."""
+    linear = jnp.sum(wv * xv, axis=1)
     if cfg.model == "fm":
         # 0.5 * ((sum_a v_a x_a)^2 - sum_a (v_a x_a)^2), summed over k
         Ex = E * xv[..., None]                         # [N, K, k]
@@ -180,7 +205,7 @@ def _score_from_slots(w0, w, E, feats, xv, cfg: FMConfig):
         # FFM: E[a, b] = v_{feat_a, field_b}; z += <E[a,b], E[b,a]> x_a x_b
         pair = jnp.einsum("nabk,nbak->nab", E, E)
         pair = pair * (xv[:, :, None] * xv[:, None, :])
-        K = feats.shape[1]
+        K = xv.shape[1]
         upper = jnp.triu(jnp.ones((K, K), pair.dtype), 1)
         inter = jnp.sum(pair * upper, axis=(1, 2))
     return w0 + linear + inter
@@ -195,14 +220,15 @@ def _score(params, feats, fields, vals, mask, cfg: FMConfig):
     w0, w, V = params
     xv = vals * mask                                   # zero padded slots
     E = _gather_slots(V, _slot_rows(feats, fields, cfg))
-    return _score_from_slots(w0, w, E, feats, xv, cfg)
+    return _score_from_slots(w0, w[feats], E, xv, cfg)
 
 
 def _score_blocks(state, feats, fields, vals, mask, cfg: FMConfig):
-    """:func:`_score` from the step's [n_features, block] table."""
-    w0, w, T = state
-    E = _select_fields(_gather_blocks(T, feats), fields, cfg)
-    return _score_from_slots(w0, w, E, feats, vals * mask, cfg)
+    """:func:`_score` from the step's ``(w0, T)``, the table by feature
+    [n_features, block]."""
+    w0, T = state
+    wv, E = _select_fields(_gather_blocks(T, feats), fields, cfg)
+    return _score_from_slots(w0, wv, E, vals * mask, cfg)
 
 
 def _slot_rows(feats, fields, cfg: FMConfig):
@@ -232,9 +258,9 @@ def _pcast_params(params, axis_name):
 def _weighted_mean_grads(p, score_fn, y, sw, cfg: FMConfig, axis_name):
     """Global-mean loss + grads of the sample-weighted shard loss —
     the one prologue shared by the dense and sparse steps. ``p`` is
-    the differentiated pytree (full params, or (w0, w, E) with the
-    gathered embedding rows on the sparse path); ``score_fn(p)`` the
-    margin."""
+    the differentiated pytree (full params; (w0, blk) with the gathered
+    blocks on the replicated sparse path; (w0, w, E) with the gathered
+    rows on the sharded one); ``score_fn(p)`` the margin."""
     def shard_sum(q):
         return jnp.sum(per_example_loss(score_fn(q), y, cfg.loss) * sw)
 
@@ -274,47 +300,51 @@ def train_step_sparse(params, batch, cfg: FMConfig, capacity: int,
                       axis_name="mp4j"):
     """One step; embedding gradients ride the SPARSE path.
 
-    ``params`` is ``(w0, w, T)`` with the table in the step's form,
+    ``params`` is ``(w0, T)`` with the table in the step's form,
     ``T`` [n_features, block]: a feature's vectors against every field
-    side by side (:func:`_block_width`). Instead of
+    side by side and its linear weight in the last column
+    (:func:`_block_width`, :func:`_weight_column`). Instead of
     psum'ing the dense gradient table, each shard ships its touched
     ``(feature, grad_block)`` slots over ONE all_gather each and the
     merged update is a single identity-dropping scatter-add into T,
     which sums duplicate features natively (bandwidth ~touched slots,
     not ~|V|). A (sample, feature) is ONE gather and ONE scatter
-    descriptor whatever ``n_fields``: the serial unit charges by the
-    descriptor (PERF.md section 5). ``capacity`` is the static bound,
-    in features, that the optional local dedupe packs into (it shrinks
-    the all_gather payload when capacity < S; nothing is ever dropped
-    by the scatter).
+    descriptor whatever ``n_fields``, the weight included: the serial
+    unit charges by the descriptor (PERF.md section 5), and the step
+    reads, reduces and writes nothing else of size ``n_features``.
+    ``capacity`` is the static bound, in features, that the optional
+    local dedupe packs into (it shrinks the all_gather payload when
+    capacity < S; nothing is ever dropped by the scatter).
 
     The table enters autodiff only through the GATHERED blocks
     (``_select_fields`` + ``_score_from_slots``), so the backward
-    yields the per-slot gradient blocks [S, block] directly —
-    differentiating through the gather would scatter-add a dense
-    |V|-row gradient table on the serial scatter unit and immediately
-    re-gather its touched rows (1.8x the step time at 8M rows on the
-    previous installation; not measured on this chip).
+    yields the per-slot gradient blocks [S, block] directly, the
+    weight's gradient in its column — differentiating through the
+    gather would scatter-add a dense |V|-row gradient table on the
+    serial scatter unit and immediately re-gather its touched rows
+    (1.8x the step time at 8M rows on the previous installation; not
+    measured on this chip).
     """
     feats, fields, vals, mask, y, sw = batch
-    if params[2].shape != (cfg.n_features, _block_width(cfg)):
+    if (len(params) != 2
+            or params[1].shape != (cfg.n_features, _block_width(cfg))):
         # the public [n_rows, k] table would index and compile too, as
         # n_rows features of one k-wide block: another model
         raise Mp4jError(
-            "the sparse step takes the table by feature, "
+            "the sparse step takes (w0, T), the table by feature, "
             f"[{cfg.n_features}, {_block_width(cfg)}] "
-            f"(FMTrainer._enter converts it), got {params[2].shape}")
-    w0, w, T = _pcast_params(params, axis_name)
+            "(FMTrainer._enter converts the public params), got "
+            f"{[getattr(p, 'shape', None) for p in params]}")
+    w0, T = _pcast_params(params, axis_name)
     blk = _gather_blocks(T, feats)              # [N, K, block]
     xv = vals * mask
-    loss, (g0, gw, gblk), denom = _weighted_mean_grads(
-        (w0, w, blk),
+    loss, (g0, gblk), denom = _weighted_mean_grads(
+        (w0, blk),
         lambda p: _score_from_slots(
-            p[0], p[1], _select_fields(p[2], fields, cfg), feats, xv, cfg),
+            p[0], *_select_fields(p[1], fields, cfg), xv, cfg),
         y, sw, cfg, axis_name)
     if axis_name is not None:
         g0 = lax.psum(g0, axis_name)
-        gw = lax.psum(gw, axis_name)     # linear part stays dense (small)
 
     # Local duplicate-feature merge (sort + segmented reduction) runs
     # ONLY when it shrinks the all_gather payload (capacity < S): the
@@ -343,13 +373,14 @@ def train_step_sparse(params, batch, cfg: FMConfig, capacity: int,
         oi, ov = li, lv
     lr = cfg.learning_rate
     w0 = w0 - lr * (g0 / denom)
-    w = w - lr * (gw / denom + cfg.l2 * w)
     if cfg.l2:
-        T = T * (1.0 - lr * cfg.l2)     # decay all rows, like the dense
+        # decay all rows, like the dense step: vectors and weights alike
+        # (the padding columns stay 0.0)
+        T = T * (1.0 - lr * cfg.l2)
     safe = jnp.where(oi == sparse_ops.SENTINEL, T.shape[0], oi)
     with jax.named_scope("ffm.table_update"):
         T = T.at[safe].add(-(lr / denom) * ov, mode="drop")
-    return (w0, w, T), loss
+    return (w0, T), loss
 
 
 def _fetch_rows_sharded(Vs, flat_rows, me, axis_name):
@@ -427,7 +458,7 @@ def train_step_sparse_sharded(params, batch, cfg: FMConfig, n: int,
     xv = vals * mask
     loss, (g0, gw, gE), denom = _weighted_mean_grads(
         (w0, w, E),
-        lambda p: _score_from_slots(p[0], p[1], p[2], feats, xv, cfg),
+        lambda p: _score_from_slots(p[0], p[1][feats], p[2], xv, cfg),
         y, sw, cfg, axis_name)
     g0 = lax.psum(g0, axis_name)
     gw = lax.psum(gw, axis_name)     # linear part stays dense (small)
@@ -577,62 +608,71 @@ class FMTrainer(DataParallelTrainer):
     _CONVERT_ROWS = 5 * 2 ** 17
 
     def _state_avals(self):
-        """Shapes of the step's ``(w0, w, T)``, replicated (compile
+        """Shapes of the step's ``(w0, T)``, replicated (compile
         proofs: check/checkaot.py, the AOT tests)."""
         cfg = self.cfg
         rep = NamedSharding(self.mesh, P())
         return tuple(
             jax.ShapeDtypeStruct(shape, jnp.float32, sharding=rep)
-            for shape in ((), (cfg.n_features,),
-                          (cfg.n_features, _block_width(cfg))))
+            for shape in ((), (cfg.n_features, _block_width(cfg))))
 
     def _build_converters(self):
-        """``(widen, narrow)``: public params -> the step's own state and
-        back, for the replicated sparse step. Both return new buffers
-        (the step donates its state, never the caller's arrays). The
-        FFM table goes ``_CONVERT_ROWS`` public rows at a time, the last
-        block starting early and rewriting rows the one before placed."""
+        """``(widen, narrow)``: public ``(w0, w, V)`` -> the step's own
+        ``(w0, T)`` and back, for the replicated sparse step. Both return
+        new buffers (the step donates its state, never the caller's
+        arrays). The linear weights go into their column of the blocks
+        (:func:`_weight_column`) and come back out of it. The FFM table
+        goes ``_CONVERT_ROWS`` public rows at a time, the last block
+        starting early and rewriting rows the one before placed."""
         cfg = self.cfg
         k, F = cfg.k, cfg.n_features
         nf = self.n_rows // F       # vectors a block: n_fields, or FM's one
         width, stride = _block_width(cfg), _block_stride(cfg)
+        wcol = _weight_column(cfg)
         B = max(1, min(F, self._CONVERT_ROWS // nf))
         if B >= 128:
             B -= B % 128        # starts on whole lane tiles of the public table
         n_blocks = -(-F // B)
 
-        def blockwise(src, out_shape, move):
+        def blockwise(src, out, move):
             def body(i, out):
                 return move(src, out, jnp.minimum(i * B, F - B))
-            return lax.fori_loop(0, n_blocks, body,
-                                 jnp.zeros(out_shape, src.dtype))
+            return lax.fori_loop(0, n_blocks, body, out)
 
-        def to_blocks(V, T, f0):
+        def to_blocks(public, T, f0):
+            w, V = public
             rows = lax.dynamic_slice(V, (f0 * nf, 0), (B * nf, k))
             runs = jnp.pad(rows.T.reshape(k, B, nf),
                            ((0, 0), (0, 0), (0, stride - nf)))
             blk = jnp.pad(runs.transpose(1, 0, 2).reshape(B, k * stride),
                           ((0, 0), (0, width - k * stride)))
+            blk = lax.dynamic_update_slice(
+                blk, lax.dynamic_slice(w, (f0,), (B,))[:, None], (0, wcol))
             return lax.dynamic_update_slice(T, blk, (f0, 0))
 
-        def to_rows(T, V, f0):
-            blk = lax.dynamic_slice(T, (f0, 0), (B, k * stride))
-            runs = blk.reshape(B, k, stride)[:, :, :nf]
-            return lax.dynamic_update_slice(
-                V, runs.transpose(1, 0, 2).reshape(k, B * nf).T,
-                (f0 * nf, 0))
+        def to_rows(T, public, f0):
+            w, V = public
+            blk = lax.dynamic_slice(T, (f0, 0), (B, width))
+            runs = blk[:, :k * stride].reshape(B, k, stride)[:, :, :nf]
+            return (lax.dynamic_update_slice(w, blk[:, wcol], (f0,)),
+                    lax.dynamic_update_slice(
+                        V, runs.transpose(1, 0, 2).reshape(k, B * nf).T,
+                        (f0 * nf, 0)))
 
         def widen(params):
             w0, w, V = params
-            T = (jnp.copy(V) if nf == 1
-                 else blockwise(V, (F, width), to_blocks))
-            return jnp.copy(w0), jnp.copy(w), T
+            T = (jnp.concatenate([V, w[:, None]], axis=1) if nf == 1
+                 else blockwise((w, V), jnp.zeros((F, width), V.dtype),
+                                to_blocks))
+            return jnp.copy(w0), T
 
         def narrow(state):
-            w0, w, T = state
-            V = (jnp.copy(T) if nf == 1
-                 else blockwise(T, (F * nf, k), to_rows))
-            return jnp.copy(w0), jnp.copy(w), V
+            w0, T = state
+            w, V = ((T[:, wcol], T[:, :k]) if nf == 1
+                    else blockwise(T, (jnp.zeros((F,), T.dtype),
+                                       jnp.zeros((F * nf, k), T.dtype)),
+                                   to_rows))
+            return jnp.copy(w0), w, V
 
         # committed like the placed params, so that the first step call
         # compiles the program every later one runs
@@ -644,7 +684,7 @@ class FMTrainer(DataParallelTrainer):
 
     def _enter(self, params):
         """Public params -> the state the step carries. The replicated
-        sparse step gets its own ``(w0, w, T)`` (``widen``); every other
+        sparse step gets its own ``(w0, T)`` (``widen``); every other
         step takes the placed params as they are."""
         with spans.span("mp4j.stream.widen"):
             if (self.table_sharding != "sharded"
@@ -709,8 +749,11 @@ class FMTrainer(DataParallelTrainer):
             # the state is the trainer's own (``_enter``): donated, the
             # table is scattered into where it rests
             jit_args = dict(donate_argnums=0)
+            # index_streams: gather/scatter-add pairs the step issues a
+            # (sample, feature); the weights ride in the blocks
             build_args = dict(table_form="blocks",
-                              descriptors=per_shard_slots)
+                              descriptors=per_shard_slots,
+                              index_streams=1)
             # params are pcast to varying but returned under replicated
             # P() out_specs (every shard computes the identical update
             # from the all-gathered slots + psum'd scalars), which VMA
@@ -851,10 +894,11 @@ class FMTrainer(DataParallelTrainer):
         At most ``max_in_flight`` steps stay in flight, bounding device
         memory at ~max_in_flight staged batches. With
         ``sparse_grads=True`` on a replicated table the step carries
-        the table by feature and updates it in place: it is converted
-        once here (``mp4j.stream.widen``) and once before the return
-        (``mp4j.stream.narrow``); the table passed in is left as it
-        was, and the one returned is [n_rows, k]. ``max_in_flight=0``
+        the table by feature, the linear weights inside it, and updates
+        it in place: it is converted once here (``mp4j.stream.widen``)
+        and once before the return (``mp4j.stream.narrow``); the table
+        passed in is left as it was, and the one returned is
+        [n_rows, k]. ``max_in_flight=0``
         reproduces the fully serialized round-4 behavior (the
         overlap's gain was not resolved above noise on the previous
         installation, 2026-07; see ROADMAP S6)."""
@@ -962,7 +1006,7 @@ class FMTrainer(DataParallelTrainer):
                 Vs, rows.reshape(-1).astype(jnp.int32),
                 flat_index(axes), axes)
             E = E_flat.reshape(rows.shape + (Vs.shape[1],))
-            z = _score_from_slots(w0, w, E, f0, vals[0] * mask[0], cfg)
+            z = _score_from_slots(w0, w[f0], E, vals[0] * mask[0], cfg)
             if cfg.loss == "logistic":
                 z = jax.nn.sigmoid(z)
             return z[None]
