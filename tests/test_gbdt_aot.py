@@ -321,13 +321,24 @@ def test_kernel_compiles_where_the_gate_says_so(topo_devices, B, n_feat,
 
 
 # ------------------------------------------------ the FFM cell's sparse step
-def _ffm_cell():
+def _ffm_cell(config="ffm-criteo"):
     import json
     from pathlib import Path
 
     root = Path(__file__).resolve().parent.parent / "benchmark"
-    return (json.loads((root / "configs" / "ffm-criteo.json").read_text()),
+    return (json.loads((root / "configs" / f"{config}.json").read_text()),
             json.loads((root / "traffic" / "stream-zipf.json").read_text()))
+
+
+def _ffm_batch(mesh, chips, c, t):
+    """The cell's chunk as the step takes it, rows sharded."""
+    rows = NamedSharding(mesh, P("mp4j"))
+    slots = (chips, t["rows_per_chunk"], c["max_nnz"])
+    return [jax.ShapeDtypeStruct(shape, dtype, sharding=rows)
+            for shape, dtype in (
+                (slots, jnp.int32), (slots, jnp.int32),
+                (slots, jnp.float32), (slots, jnp.float32),
+                (slots[:2], jnp.float32), (slots[:2], jnp.float32))]
 
 
 @pytest.fixture(scope="module")
@@ -348,17 +359,12 @@ def ffm_programs(topo_devices):
             learning_rate=c["learning_rate"]),
             mesh=mesh, sparse_grads=c["sparse_grads"],
             table_sharding=c["table_sharding"])
-        rows = NamedSharding(mesh, P("mp4j"))
-        slots = (chips, t["rows_per_chunk"], c["max_nnz"])
-        batch = [jax.ShapeDtypeStruct(shape, dtype, sharding=rows)
-                 for shape, dtype in (
-                     (slots, jnp.int32), (slots, jnp.int32),
-                     (slots, jnp.float32), (slots, jnp.float32),
-                     (slots[:2], jnp.float32), (slots[:2], jnp.float32))]
-        return trainer, trainer._build_step(descriptors).lower(
-            trainer._state_avals(), *batch).compile()
+        lowered = trainer._build_step(descriptors).lower(
+            trainer._state_avals(), *_ffm_batch(mesh, chips, c, t))
+        return trainer, lowered.compile(), lowered.as_text()
 
-    trainer, step = trainer_and_step(1)
+    trainer, step, lowered = trainer_and_step(1)
+    _, step_on_four, lowered_on_four = trainer_and_step(4)
     rep = NamedSharding(trainer.mesh, P())
     public = tuple(
         jax.ShapeDtypeStruct(shape, jnp.float32, sharding=rep)
@@ -368,7 +374,8 @@ def ffm_programs(topo_devices):
         "config": c,
         "descriptors": descriptors,
         "step": step,
-        "step_on_four": trainer_and_step(4)[1],
+        "step_on_four": step_on_four,
+        "lowered": {"step": lowered, "step_on_four": lowered_on_four},
         "widen": widen.lower(public).compile(),
         "narrow": narrow.lower(trainer._state_avals()).compile(),
     }
@@ -484,3 +491,113 @@ def test_ffm_conversions_go_a_block_at_a_time(ffm_programs, which):
     assert m.alias_size_in_bytes == 0       # the caller's table is kept
     sizes = sorted([m.argument_size_in_bytes, m.output_size_in_bytes])
     assert 2.6e9 < sizes[0] < 2.7e9 and 4.29e9 < sizes[1] < 4.35e9
+
+
+# What ``FMTrainer._build_step`` lowered for ``ffm-criteo.stream-zipf`` at
+# the commit before ``FMConfig.optimizer`` existed (PR 31's tree, jax
+# 0.9.0 with x64 on as ``conftest.py`` sets it, for the described v5e):
+# sha256 of ``lowered.as_text()``. The
+# AdaGrad step is another function; choosing it must leave SGD's program
+# as it was, to the letter. A PR that changes the SGD step on purpose, or
+# a new jax, changes these with it.
+SGD_STEP_LOWERED_SHA256 = {
+    "step": "b5d51aaa68cd861b0cec40e4967282b5e1c0e9c14633f184b54a76a98151a139",
+    "step_on_four":
+        "6f085d6d0e4c366a65821aaab71bd0ee9ce04274714cc12835ab9eb3f5463028",
+}
+
+
+@pytest.mark.parametrize("which", ["step", "step_on_four"])
+def test_sgd_step_lowers_to_the_program_it_was(ffm_programs, which):
+    import hashlib
+
+    text = ffm_programs["lowered"][which]
+    assert "384" not in text                 # no AdaGrad block anywhere
+    assert (hashlib.sha256(text.encode()).hexdigest()
+            == SGD_STEP_LOWERED_SHA256[which])
+
+
+# ------------------------------------- the AdaGrad cell's step (PR 32)
+@pytest.fixture(scope="module")
+def adagrad_programs(topo_devices):
+    """The step and both conversions of ``ffm-criteo-adagrad.stream-zipf``
+    at the cell's own size, compiled for one described chip."""
+    from ytk_mp4j_tpu.models.fm import FMConfig, FMTrainer
+
+    c, t = _ffm_cell("ffm-criteo-adagrad")
+    mesh = Mesh(np.asarray(topo_devices[:1]), ("mp4j",))
+    trainer = FMTrainer(FMConfig(
+        model=c["model"], n_features=c["n_features"], n_fields=c["n_fields"],
+        k=c["k"], max_nnz=c["max_nnz"], learning_rate=c["learning_rate"],
+        l2=c["l2"], optimizer=c["optimizer"],
+        adagrad_init=c["adagrad_init"]),
+        mesh=mesh, sparse_grads=c["sparse_grads"],
+        table_sharding=c["table_sharding"])
+    descriptors = t["rows_per_chunk"] * c["max_nnz"]
+    rep = NamedSharding(mesh, P())
+    public = tuple(
+        jax.ShapeDtypeStruct(shape, jnp.float32, sharding=rep)
+        for shape in ((), (c["n_features"],), (trainer.n_rows, c["k"])))
+    widen, narrow = trainer._build_converters()
+    return {
+        "config": c,
+        "descriptors": descriptors,
+        "step": trainer._build_step(descriptors).lower(
+            trainer._state_avals(), *_ffm_batch(mesh, 1, c, t)).compile(),
+        "widen": widen.lower(public, public).compile(),
+        "narrow": narrow.lower(trainer._state_avals()).compile(),
+    }
+
+
+def test_adagrad_step_sets_the_blocks_into_the_table_where_it_rests(
+        adagrad_programs):
+    c, step = adagrad_programs["config"], adagrad_programs["step"]
+    text, d = step.as_text(), adagrad_programs["descriptors"]
+    # 157 parameters and 157 accumulators in three 128-lane words
+    F, width = c["n_features"], 384
+    table = r"f32\[%d,%d\]\{1,0:T\(8,128\)\}" % (F, width)
+    assert re.search(table + r" parameter\(1\)", text)
+    assert re.search(r"input_output_alias=\{.*\{1\}: \(1, \{\}, may-alias\)",
+                     text)
+    # the slots' blocks and the distinct features' blocks are gathered
+    # from the parameter itself, and the new blocks set into it: native
+    # fusions, no loop of slices, no copy of 6.44 GB
+    gathers = re.findall(
+        r"= f32\[%d,%d\]\S* fusion\(%%params_1_\S*, [^)]*\), kind=kCustom"
+        r".*ffm\.table_gather" % (d, width), text)
+    assert len(gathers) == 2
+    assert re.search(
+        r"= " + table + r" fusion\(%params_1_\S*, [^)]*\), kind=kCustom"
+        r".*ffm\.table_update", text)
+    assert " while(" not in text
+    assert _table_sized(text, "copy", F * width // 2) == []
+    assert _table_sized(text, "transpose", F * width // 2) == []
+    # the merge is on the step's path: a sort under ffm.grad_merge, and
+    # nothing of the shape [n_features]
+    assert re.search(r" sort\(.*ffm\.grad_merge/sparse\.sort_by_key", text)
+    assert re.search(r"ffm\.grad_merge/sparse\.segment_reduce", text)
+    assert re.search(r"ffm\.adagrad_rule", text)
+    assert re.search(r"\[%d\]" % F, text) is None
+    m = step.memory_analysis()
+    # arguments 6.44 GB (the table; the chunk is 1.3 MB), the output is
+    # the table itself, temporaries 0.46 GB: the slots' blocks and
+    # gradients and the merge's buffers, [79,872, 192..384] f32 each
+    assert F * width * 4 <= m.alias_size_in_bytes < F * width * 4 + 2 ** 25
+    assert m.argument_size_in_bytes < F * width * 4 + 2 ** 25
+    assert m.output_size_in_bytes - m.alias_size_in_bytes < 2 ** 20
+    assert m.temp_size_in_bytes < 0.6e9, m.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("which", ["widen", "narrow"])
+def test_adagrad_conversions_go_a_block_at_a_time(adagrad_programs, which):
+    """5.27 GB in (parameters and accumulators, the shapes of (w0, w, V)
+    twice) and 6.44 GB out, or the reverse, nothing between them."""
+    m = adagrad_programs[which].memory_analysis()
+    assert m.temp_size_in_bytes < 0.5e9, m.temp_size_in_bytes
+    F = adagrad_programs["config"]["n_features"]
+    text = adagrad_programs[which].as_text()
+    for opcode in ("copy", "transpose", "pad", "concatenate"):
+        assert _table_sized(text, opcode, F * 156 // 2) == [], opcode
+    assert m.alias_size_in_bytes == 0       # the caller's arrays are kept
+    sizes = sorted([m.argument_size_in_bytes, m.output_size_in_bytes])
+    assert 5.26e9 < sizes[0] < 5.28e9 and 6.44e9 < sizes[1] < 6.45e9

@@ -64,9 +64,9 @@ def _gbdt(rng):
     return GBDTTrainer(cfg, mesh=make_mesh(N_SHARDS)), bins, y
 
 
-def _ffm(rng, n_chunks, **kw):
+def _ffm(rng, n_chunks, optimizer="sgd", **kw):
     cfg = FMConfig(n_features=32, n_fields=4, k=4, max_nnz=4, model="ffm",
-                   learning_rate=0.1)
+                   learning_rate=0.1, optimizer=optimizer)
     chunks = []
     for _ in range(n_chunks):
         feats = rng.integers(0, 32, (16, 4)).astype(np.int32)
@@ -215,7 +215,8 @@ def test_fit_stream_leaves_exactly_its_spans(rng, ring, family,
         assert build[6] == {"key": (16 // N_SHARDS) * 4,
                             "table_form": "blocks",
                             "descriptors": (16 // N_SHARDS) * 4,
-                            "index_streams": 1}
+                            "index_streams": 1, "optimizer": "sgd",
+                            "block_width": 128, "capacity": 32}
         # the table is converted before the first chunk is staged and
         # after the last loss is fetched
         assert widen[2] + widen[3] <= stage[0][2]
@@ -235,20 +236,25 @@ def test_a_new_padded_shape_is_a_second_build_span(rng, ring):
 
 @pytest.mark.parametrize("kw,carries", [
     ({}, True), ({"sparse_capacity": 8}, True),
-    ({"table_sharding": "sharded"}, False)],
-    ids=["replicated", "dedupe", "sharded"])
+    ({"table_sharding": "sharded"}, False),
+    ({"optimizer": "adagrad"}, True)],
+    ids=["replicated", "dedupe", "sharded", "adagrad"])
 def test_step_build_span_says_the_table_form(rng, ring, kw, carries):
     """The replicated sparse step indexes by feature (N x K descriptors
     of a shard) and by nothing else (one index stream: the linear
-    weights ride in the blocks); the sharded step still indexes a row a
-    slot pair and the weights beside it."""
+    weights ride in the blocks; AdaGrad's accumulators too, in a block
+    twice as wide); the sharded step still indexes a row a slot pair
+    and the weights beside it."""
     tr, chunks = _ffm(rng, 1, **kw)
     tr.fit_stream(iter(chunks))
     build = [s[6] for s in _named(_trainer_spans(), "mp4j.step.build")
              if s[6]["key"] == 16]
     want = {"key": 16}
     if carries:
-        want.update(table_form="blocks", descriptors=16, index_streams=1)
+        # 17 floats, or 17 and their 17 accumulators, in one 128-lane word
+        want.update(table_form="blocks", descriptors=16, index_streams=1,
+                    optimizer=kw.get("optimizer", "sgd"), block_width=128,
+                    capacity=kw.get("sparse_capacity", 32))
     assert build == [want]
 
 

@@ -18,9 +18,15 @@ TPU-first rebuild. Instances are padded to ``max_nnz`` static slots
   static-shape ``(feature, grad_block)`` buffers — ONE all_gather each,
   then a single identity-dropping scatter-add into the table, which
   merges duplicate features natively (the device-native analogue of
-  the reference's key-wise map merge; the map API's sort + segment
-  pack would be pure overhead here: 64.2 -> 38.1 ms/step on the
-  previous installation, 2026-07, not measured on this chip).
+  the reference's key-wise map merge). That holds of plain SGD, whose
+  update is linear in the gradient: there the map API's sort + segment
+  pack would be pure overhead (64.2 -> 38.1 ms/step on the previous
+  installation, 2026-07, not measured on this chip). AdaGrad
+  (``optimizer="adagrad"``, libffm's rule) squares a feature's SUMMED
+  gradient, so there the merge runs first, after the all_gather:
+  ``ops/sparse.sort_by_key`` + ``segment_reduce_sorted`` pack the
+  slots into distinct ``(feature, grad_block)`` pairs, the rule is
+  applied to the distinct features' blocks, and the scatter SETS them.
   Bandwidth ~nnz instead of ~|V|: the TPU translation of the
   reference's sparse map path. The replicated step holds the table by
   FEATURE, ``[n_features, block]`` with a feature's ``n_fields``
@@ -32,9 +38,13 @@ TPU-first rebuild. Instances are padded to ``max_nnz`` static slots
   1 KB against 87.0 ns at 16 B, gather 13.0 against 17.3). The
   feature's linear weight rides in the block's last column, so the
   step makes that one gather and that one scatter-add and touches
-  nothing else of size ``n_features``. The step donates that table and
-  updates it in place; ``fit`` / ``fit_stream`` convert the public
-  ``(w0, w, V)`` once on the way in and once on the way out.
+  nothing else of size ``n_features``. Under AdaGrad every parameter's
+  accumulator rides in the same block, in the block's second half (157
+  and 157 floats in a row of 384), so the state costs no descriptor of
+  its own. The step donates that table and updates it in place;
+  ``fit`` / ``fit_stream`` convert the public ``(w0, w, V)`` (and the
+  accumulators, in the same shapes) once on the way in and once on the
+  way out.
 
 Model scores (order-2, sigmoid/logloss for classification):
 
@@ -65,6 +75,7 @@ from ytk_mp4j_tpu.ops import sparse as sparse_ops
 
 MODELS = ("fm", "ffm")
 LOSSES = ("logistic", "squared")
+OPTIMIZERS = ("sgd", "adagrad")
 
 
 @dataclass(frozen=True)
@@ -78,6 +89,11 @@ class FMConfig:
     learning_rate: float = 0.1
     l2: float = 0.0                 # on embeddings + linear weights
     init_scale: float = 0.01
+    # "adagrad" is libffm's rule whole (:func:`train_step_adagrad`): the
+    # chunk's SUMMED gradient, l2 on the touched parameters only, the
+    # accumulator (started at ``adagrad_init``) first and then the step
+    optimizer: str = "sgd"
+    adagrad_init: float = 1.0
 
     def __post_init__(self):
         if self.model not in MODELS:
@@ -86,6 +102,14 @@ class FMConfig:
             raise Mp4jError(f"loss must be one of {LOSSES}")
         if self.model == "ffm" and self.n_fields < 2:
             raise Mp4jError("ffm needs n_fields >= 2")
+        if self.optimizer not in OPTIMIZERS:
+            raise Mp4jError(f"optimizer must be one of {OPTIMIZERS}")
+        if self.optimizer == "adagrad" and self.model != "ffm":
+            raise Mp4jError(
+                "optimizer='adagrad' is libffm's rule, stated for the "
+                "field-aware model: which entries a pair loop touches. "
+                "model='fm' would need that reading for its "
+                "sum-of-squares form, and a reference")
 
 
 def _gather_slots(V, rows):
@@ -100,9 +124,12 @@ def _gather_slots(V, rows):
 
 def _block_width(cfg: FMConfig) -> int:
     """Floats in a row of the step's table: the feature's vectors and
-    its linear weight (:func:`_weight_column`). An FFM block is padded
+    its linear weight (:func:`_weight_column`), and under AdaGrad an
+    accumulator for each of them in the row's second half
+    (:func:`_weights_width`). An FFM block is padded
     with zeros to whole 128-lane words (39 fields x 4 and the weight:
-    157 floats in 256), so that the table rests row-major on the TPU, a
+    157 floats in 256; with their accumulators 314 in 384), so that the
+    table rests row-major on the TPU, a
     feature's block one contiguous run: XLA keeps a [n_features, 156]
     parameter with the features on the lanes, and both the gather and
     the scatter then copy the whole table every step (AOT for v5e,
@@ -114,17 +141,34 @@ def _block_width(cfg: FMConfig) -> int:
     its one vector and the weight."""
     if cfg.model == "fm":
         return cfg.k + 1
-    return -(-(cfg.n_fields * cfg.k + 1) // 128) * 128
+    live = cfg.n_fields * cfg.k + 1
+    if cfg.optimizer == "adagrad":
+        live *= 2
+    return -(-live // 128) * 128
+
+
+def _weights_width(cfg: FMConfig) -> int:
+    """Columns of a block that hold parameters: all of an SGD block, the
+    first half of an AdaGrad block. There the accumulator of the
+    parameter in column c is column ``c + _weights_width``, so the
+    forward and backward pass take the first half as it lies (the
+    slots' gradients are [S, 192] at 39 x 4, no wider than SGD's), and
+    the rule reads and writes the two halves side by side. Two tables
+    [n_features, 256] would hold the same numbers in 8.59 GB for 6.44
+    and double the rule's gather and scatter descriptors."""
+    width = _block_width(cfg)
+    return width // 2 if cfg.optimizer == "adagrad" else width
 
 
 def _weight_column(cfg: FMConfig) -> int:
     """Column of a block that holds the feature's linear weight: the
-    last. No vector entry lives there: the k runs of ``stride`` columns
+    last of the parameters' columns (:func:`_weights_width`). No vector
+    entry lives there: the k runs of ``stride`` columns
     (:func:`_block_stride`) either end before it or, where they fill
     the width, leave the last run's tail free (``width >= n_fields * k
     + 1`` makes ``stride > n_fields`` then), so ``_select_fields``
     never reads it as a vector's entry."""
-    return _block_width(cfg) - 1
+    return _weights_width(cfg) - 1
 
 
 def _block_stride(cfg: FMConfig) -> int:
@@ -137,7 +181,21 @@ def _block_stride(cfg: FMConfig) -> int:
     table: 0.057 s a conversion; with the fields outermost, column
     ``fl * k + j``, every entry changes lane and it takes 0.266 s).
     An FM block has no runs: its vector, then the weight."""
-    return _block_width(cfg) // cfg.k
+    return _weights_width(cfg) // cfg.k
+
+
+def _field_columns(cfg: FMConfig) -> np.ndarray:
+    """[n_fields + 1, _weights_width] of 0/1: row ``fl`` marks the k
+    columns ``j * stride + fl`` of the vector against field ``fl``, the
+    last row the linear weight's column. Every column some row marks
+    holds a parameter; the rest is padding, zero and never anything
+    else."""
+    marks = np.zeros((cfg.n_fields + 1, _weights_width(cfg)), np.float32)
+    fl = np.arange(cfg.n_fields)
+    for j in range(cfg.k):
+        marks[fl, j * _block_stride(cfg) + fl] = 1.0
+    marks[cfg.n_fields, _weight_column(cfg)] = 1.0
+    return marks
 
 
 def _gather_blocks(T, feats):
@@ -225,9 +283,11 @@ def _score(params, feats, fields, vals, mask, cfg: FMConfig):
 
 def _score_blocks(state, feats, fields, vals, mask, cfg: FMConfig):
     """:func:`_score` from the step's ``(w0, T)``, the table by feature
-    [n_features, block]."""
-    w0, T = state
-    wv, E = _select_fields(_gather_blocks(T, feats), fields, cfg)
+    [n_features, block] (AdaGrad's state holds more, which no score
+    reads)."""
+    w0, T = state[:2]
+    blk = _gather_blocks(T, feats)[..., :_weights_width(cfg)]
+    wv, E = _select_fields(blk, fields, cfg)
     return _score_from_slots(w0, wv, E, vals * mask, cfg)
 
 
@@ -296,6 +356,20 @@ def train_step_dense(params, batch, cfg: FMConfig, axis_name=None):
     return (w0, w, V), loss
 
 
+def _check_block_table(params, n_arrays: int, cfg: FMConfig):
+    """A block step's ``params``: ``n_arrays`` of them, the second the
+    table by feature."""
+    if (len(params) != n_arrays
+            or params[1].shape != (cfg.n_features, _block_width(cfg))):
+        # the public [n_rows, k] table would index and compile too, as
+        # n_rows features of one k-wide block: another model
+        raise Mp4jError(
+            "the sparse step takes (w0, T, ...), the table by feature, "
+            f"[{cfg.n_features}, {_block_width(cfg)}] "
+            "(FMTrainer._enter converts the public params), got "
+            f"{[getattr(p, 'shape', None) for p in params]}")
+
+
 def train_step_sparse(params, batch, cfg: FMConfig, capacity: int,
                       axis_name="mp4j"):
     """One step; embedding gradients ride the SPARSE path.
@@ -314,7 +388,10 @@ def train_step_sparse(params, batch, cfg: FMConfig, capacity: int,
     reads, reduces and writes nothing else of size ``n_features``.
     ``capacity`` is the static bound, in features, that the optional
     local dedupe packs into (it shrinks the all_gather payload when
-    capacity < S; nothing is ever dropped by the scatter).
+    capacity < S; nothing is ever dropped by the scatter). This is the
+    SGD step: its update is linear in the gradient, so the scatter-add
+    may sum a feature's duplicates. A rule that is not
+    (:func:`train_step_adagrad`) merges them first.
 
     The table enters autodiff only through the GATHERED blocks
     (``_select_fields`` + ``_score_from_slots``), so the backward
@@ -326,15 +403,7 @@ def train_step_sparse(params, batch, cfg: FMConfig, capacity: int,
     measured on this chip).
     """
     feats, fields, vals, mask, y, sw = batch
-    if (len(params) != 2
-            or params[1].shape != (cfg.n_features, _block_width(cfg))):
-        # the public [n_rows, k] table would index and compile too, as
-        # n_rows features of one k-wide block: another model
-        raise Mp4jError(
-            "the sparse step takes (w0, T), the table by feature, "
-            f"[{cfg.n_features}, {_block_width(cfg)}] "
-            "(FMTrainer._enter converts the public params), got "
-            f"{[getattr(p, 'shape', None) for p in params]}")
+    _check_block_table(params, 2, cfg)
     w0, T = _pcast_params(params, axis_name)
     blk = _gather_blocks(T, feats)              # [N, K, block]
     xv = vals * mask
@@ -347,7 +416,7 @@ def train_step_sparse(params, batch, cfg: FMConfig, capacity: int,
         g0 = lax.psum(g0, axis_name)
 
     # Local duplicate-feature merge (sort + segmented reduction) runs
-    # ONLY when it shrinks the all_gather payload (capacity < S): the
+    # ONLY when it shrinks the all_gather payload (capacity < S): SGD's
     # final scatter-add merges duplicates natively, so with
     # capacity >= S the local sort would buy nothing (about 35 ms of
     # pure overhead at S = 512k on the previous installation; not
@@ -363,9 +432,9 @@ def train_step_sparse(params, batch, cfg: FMConfig, capacity: int,
         li, lv = flat_feats.astype(jnp.int32), flat_g
     if axis_name is not None:
         # NOT sparse_allreduce: its post-gather sort + segment reduce
-        # packs unique keys for the map API, but the table update below
-        # is a scatter-add, which merges duplicates natively — the pack
-        # would be pure overhead. Gather every shard's slots and
+        # packs unique keys for the map API, but SGD's table update
+        # below is a scatter-add, which merges duplicates natively — the
+        # pack would be pure overhead here. Gather every shard's slots and
         # scatter them all.
         oi = lax.all_gather(li, axis_name, axis=0, tiled=True)
         ov = lax.all_gather(lv, axis_name, axis=0, tiled=True)
@@ -381,6 +450,120 @@ def train_step_sparse(params, batch, cfg: FMConfig, capacity: int,
     with jax.named_scope("ffm.table_update"):
         T = T.at[safe].add(-(lr / denom) * ov, mode="drop")
     return (w0, T), loss
+
+
+def _touch_counts(fields, mask, sw, cfg: FMConfig):
+    """``(live, cnt)``: ``live`` [N, K] is 1.0 for a slot that holds a
+    feature (``mask > 0``) in a row that counts (``sw > 0``), and
+    ``cnt[n, a]`` [N, K, n_fields + 1] says what of feature a's block
+    the row's pair loop reaches, as libffm's does: ``cnt[n, a, fl]`` is
+    the number of OTHER live slots of the row in field ``fl`` (the
+    vector ``v[feat_a, fl]`` meets each of them; with one feature a
+    field, never the vector against the feature's own field), and the
+    last entry is ``live`` itself, for the linear weight."""
+    live = ((mask > 0) & (sw[:, None] > 0)).astype(jnp.float32)
+    held = jax.nn.one_hot(fields, cfg.n_fields,
+                          dtype=jnp.float32) * live[..., None]
+    others = live[..., None] * (jnp.sum(held, axis=1, keepdims=True) - held)
+    return live, jnp.concatenate([others, live[..., None]], axis=-1)
+
+
+def _touched_columns(cnt, cfg: FMConfig):
+    """[C, n_fields + 1] counts of a feature (:func:`_touch_counts`,
+    summed over its slots) -> [C, _weights_width] bool, the block's
+    columns that some row touched: column ``j * stride + fl`` for every
+    j where field ``fl`` was met, and the weight's column. A product
+    with :func:`_field_columns`, which copies a count into its k
+    columns; any count above zero stays above zero at any matmul
+    precision."""
+    return (cnt @ _field_columns(cfg)) > 0
+
+
+def _merge_slots(keys, payload, capacity: int, axis_name):
+    """Every shard's ``(feature, payload)`` slots -> at most ``capacity``
+    DISTINCT features, ascending, each with the sum of its slots'
+    payloads; SENTINEL keys are dropped and pad the tail. This is
+    ``ops/sparse.sparse_allreduce``'s shape: the ``all_gather`` first,
+    because two shards that both saw a feature must sum before a rule
+    that is not linear in the gradient, then one sort and one segmented
+    reduction."""
+    with jax.named_scope("ffm.grad_merge"):
+        if axis_name is not None:
+            keys = lax.all_gather(keys, axis_name, axis=0, tiled=True)
+            payload = lax.all_gather(payload, axis_name, axis=0, tiled=True)
+        si, sv = sparse_ops.sort_by_key(keys, payload)
+        return sparse_ops.segment_reduce_sorted(si, sv, capacity,
+                                                Operators.SUM)
+
+
+def _adagrad(p, G, g, lr):
+    """``(p, G)`` after libffm's update for the gradient ``g``: the
+    accumulator first, then the step by the NEW accumulator."""
+    G = G + g * g
+    return p - lr * g / jnp.sqrt(G), G
+
+
+def train_step_adagrad(params, batch, cfg: FMConfig, capacity: int,
+                       axis_name="mp4j"):
+    """One step of libffm's rule (Juan et al., RecSys 2016, Algorithm 1)
+    on the replicated table, a chunk at a time.
+
+    ``params`` is ``(w0, T, a0)``: the bias, the table by feature with
+    every parameter's accumulator ``_weights_width`` columns to its
+    right, and the bias's accumulator. With ``kappa_n = sw_n *
+    dloss/dz_n`` (a SUM over the chunk's rows, all shards', not a mean:
+    beside G = 1 a mean's square vanishes in f32), a parameter p that a
+    live row's pair loop reaches (:func:`_touch_counts`) gets
+
+        g = sum_n kappa_n dz_n/dp + l2 * p;  G += g * g;  p -= lr * g / sqrt(G)
+
+    and every other parameter and accumulator keeps its bits; ``l2``
+    costs nothing of size ``n_features``. The bias has no ``l2``. The
+    reported loss is the weighted mean, as the SGD step's.
+
+    The rule squares g, so a feature's slots are summed BEFORE it
+    (:func:`_merge_slots`: the map plane's sort and segmented reduce,
+    which SGD's scatter-add makes needless): a feature a chunk holds 200
+    times gets one update, not 200. ``capacity`` bounds the distinct
+    features and must be all the slots there are (or ``n_features``), so
+    that the merge drops nothing. The distinct features' blocks are
+    gathered, updated and SET back (no index repeats; SENTINEL slots are
+    dropped), one descriptor each way a feature as in the SGD step."""
+    feats, fields, vals, mask, y, sw = batch
+    _check_block_table(params, 3, cfg)
+    w0, T, a0 = _pcast_params(params, axis_name)
+    hw = _weights_width(cfg)
+    blk = _gather_blocks(T, feats)[..., :hw]        # [N, K, hw]
+    xv = vals * mask
+    loss, (g0, gblk), _ = _weighted_mean_grads(
+        (w0, blk),
+        lambda p: _score_from_slots(
+            p[0], *_select_fields(p[1], fields, cfg), xv, cfg),
+        y, sw, cfg, axis_name)
+    if axis_name is not None:
+        g0 = lax.psum(g0, axis_name)
+
+    S = feats.size
+    live, cnt = _touch_counts(fields, mask, sw, cfg)
+    keys = jnp.where(live > 0, feats, sparse_ops.SENTINEL).reshape(-1)
+    payload = jnp.concatenate(
+        [gblk.reshape(S, hw), cnt.reshape(S, -1)], axis=1)
+    ui, uv = _merge_slots(keys, payload, capacity, axis_name)
+
+    lr = cfg.learning_rate
+    dead = ui == sparse_ops.SENTINEL
+    with jax.named_scope("ffm.table_gather"):
+        cur = T[jnp.where(dead, 0, ui)]             # [capacity, block]
+    with jax.named_scope("ffm.adagrad_rule"):
+        p, G = cur[:, :hw], cur[:, hw:]
+        touched = _touched_columns(uv[:, hw:], cfg)
+        g = jnp.where(touched, uv[:, :hw] + cfg.l2 * p, 0.0)
+        stepped, G = _adagrad(p, G, g, lr)  # untouched: G + 0.0, its bits
+        new = jnp.concatenate([jnp.where(touched, stepped, p), G], axis=1)
+        w0, a0 = _adagrad(w0, a0, g0, lr)
+    with jax.named_scope("ffm.table_update"):
+        T = T.at[jnp.where(dead, T.shape[0], ui)].set(new, mode="drop")
+    return (w0, T, a0), loss
 
 
 def _fetch_rows_sharded(Vs, flat_rows, me, axis_name):
@@ -534,6 +717,23 @@ class FMTrainer(DataParallelTrainer):
         # the replicated sparse step keeps the table by feature, in
         # blocks, and updates it in place
         self._blocks = sparse_grads and table_sharding == "replicated"
+        self._adagrad = cfg.optimizer == "adagrad"
+        if self._adagrad and not self._blocks:
+            raise Mp4jError(
+                "optimizer='adagrad' runs on the replicated sparse step "
+                "(sparse_grads=True, table_sharding='replicated'). The "
+                "sharded table would need its accumulators sharded with "
+                "the rows and the merge on the owner's side, after the "
+                "all_to_all; the dense step a [n_rows, k] accumulator "
+                "and a mask of the touched rows, or l2 is no longer lazy")
+        if self._adagrad and sparse_capacity is not None:
+            raise Mp4jError(
+                "optimizer='adagrad' merges every slot of a step: its "
+                "capacity is all of them, and a smaller sparse_capacity "
+                "would drop features")
+        # AdaGrad's accumulators after the last fit / fit_stream, in the
+        # shapes of (w0, w, V); ``opt_state=`` hands them to the next
+        self.opt_state_ = None
         self._step = None
         self._step_key = None
         self._converters = None   # (widen, narrow), built on first use
@@ -608,13 +808,14 @@ class FMTrainer(DataParallelTrainer):
     _CONVERT_ROWS = 5 * 2 ** 17
 
     def _state_avals(self):
-        """Shapes of the step's ``(w0, T)``, replicated (compile
-        proofs: check/checkaot.py, the AOT tests)."""
+        """Shapes of the step's ``(w0, T)`` (AdaGrad: ``(w0, T, a0)``),
+        replicated (compile proofs: check/checkaot.py, the AOT tests)."""
         cfg = self.cfg
         rep = NamedSharding(self.mesh, P())
+        shapes = ((), (cfg.n_features, _block_width(cfg)))
         return tuple(
             jax.ShapeDtypeStruct(shape, jnp.float32, sharding=rep)
-            for shape in ((), (cfg.n_features, _block_width(cfg))))
+            for shape in shapes + ((),) * self._adagrad)
 
     def _build_converters(self):
         """``(widen, narrow)``: public ``(w0, w, V)`` -> the step's own
@@ -623,56 +824,89 @@ class FMTrainer(DataParallelTrainer):
         arrays). The linear weights go into their column of the blocks
         (:func:`_weight_column`) and come back out of it. The FFM table
         goes ``_CONVERT_ROWS`` public rows at a time, the last block
-        starting early and rewriting rows the one before placed."""
+        starting early and rewriting rows the one before placed.
+
+        Under AdaGrad ``widen(params, opt)`` also takes the accumulators
+        ``(a0, Gw, GV)`` in the shapes of ``(w0, w, V)``, or None for
+        fresh ones (``adagrad_init`` beside every parameter, made in the
+        blocks: no table of ones is held), and lays them into the
+        blocks' second halves; ``narrow`` gives ``(params, opt)``."""
         cfg = self.cfg
         k, F = cfg.k, cfg.n_features
         nf = self.n_rows // F       # vectors a block: n_fields, or FM's one
         width, stride = _block_width(cfg), _block_stride(cfg)
-        wcol = _weight_column(cfg)
+        hw, wcol = _weights_width(cfg), _weight_column(cfg)
         B = max(1, min(F, self._CONVERT_ROWS // nf))
         if B >= 128:
             B -= B % 128        # starts on whole lane tiles of the public table
         n_blocks = -(-F // B)
+        if self._adagrad:
+            fresh = jnp.asarray(np.float32(cfg.adagrad_init)
+                                * _field_columns(cfg).max(axis=0))
 
         def blockwise(src, out, move):
             def body(i, out):
                 return move(src, out, jnp.minimum(i * B, F - B))
             return lax.fori_loop(0, n_blocks, body, out)
 
-        def to_blocks(public, T, f0):
-            w, V = public
+        def half_in(pair, f0):
+            """[B, hw] of features f0.. from one public ``(w, V)``."""
+            if pair is None:
+                return jnp.broadcast_to(fresh, (B, hw))
+            w, V = pair
             rows = lax.dynamic_slice(V, (f0 * nf, 0), (B * nf, k))
             runs = jnp.pad(rows.T.reshape(k, B, nf),
                            ((0, 0), (0, 0), (0, stride - nf)))
             blk = jnp.pad(runs.transpose(1, 0, 2).reshape(B, k * stride),
-                          ((0, 0), (0, width - k * stride)))
-            blk = lax.dynamic_update_slice(
+                          ((0, 0), (0, hw - k * stride)))
+            return lax.dynamic_update_slice(
                 blk, lax.dynamic_slice(w, (f0,), (B,))[:, None], (0, wcol))
-            return lax.dynamic_update_slice(T, blk, (f0, 0))
 
-        def to_rows(T, public, f0):
-            w, V = public
-            blk = lax.dynamic_slice(T, (f0, 0), (B, width))
+        def half_out(blk, pair, f0):
+            """One public ``(w, V)`` with features f0.. taken from the
+            [B, hw] half ``blk``."""
+            w, V = pair
             runs = blk[:, :k * stride].reshape(B, k, stride)[:, :, :nf]
             return (lax.dynamic_update_slice(w, blk[:, wcol], (f0,)),
                     lax.dynamic_update_slice(
                         V, runs.transpose(1, 0, 2).reshape(k, B * nf).T,
                         (f0 * nf, 0)))
 
-        def widen(params):
+        def to_blocks(pairs, T, f0):
+            halves = [half_in(pair, f0) for pair in pairs]
+            blk = (halves[0] if len(halves) == 1
+                   else jnp.concatenate(halves, axis=1))
+            return lax.dynamic_update_slice(T, blk, (f0, 0))
+
+        def to_rows(T, pairs, f0):
+            blk = lax.dynamic_slice(T, (f0, 0), (B, width))
+            return tuple(half_out(blk[:, h * hw:(h + 1) * hw], pair, f0)
+                         for h, pair in enumerate(pairs))
+
+        def widen(params, opt=None):
             w0, w, V = params
-            T = (jnp.concatenate([V, w[:, None]], axis=1) if nf == 1
-                 else blockwise((w, V), jnp.zeros((F, width), V.dtype),
-                                to_blocks))
-            return jnp.copy(w0), T
+            if nf == 1:
+                return jnp.copy(w0), jnp.concatenate([V, w[:, None]], axis=1)
+            pairs = ((w, V),)
+            if self._adagrad:
+                pairs += (None if opt is None else opt[1:],)
+            T = blockwise(pairs, jnp.zeros((F, width), V.dtype), to_blocks)
+            if not self._adagrad:
+                return jnp.copy(w0), T
+            a0 = (jnp.full((), cfg.adagrad_init, w0.dtype) if opt is None
+                  else jnp.copy(opt[0]))
+            return jnp.copy(w0), T, a0
 
         def narrow(state):
-            w0, T = state
-            w, V = ((T[:, wcol], T[:, :k]) if nf == 1
-                    else blockwise(T, (jnp.zeros((F,), T.dtype),
-                                       jnp.zeros((F * nf, k), T.dtype)),
-                                   to_rows))
-            return jnp.copy(w0), w, V
+            w0, T = state[:2]
+            if nf == 1:
+                return jnp.copy(w0), T[:, wcol], T[:, :k]
+            empty = (jnp.zeros((F,), T.dtype), jnp.zeros((F * nf, k), T.dtype))
+            pairs = blockwise(T, (empty,) * (1 + self._adagrad), to_rows)
+            params = (jnp.copy(w0), *pairs[0])
+            if not self._adagrad:
+                return params
+            return params, (jnp.copy(state[2]), *pairs[1])
 
         # committed like the placed params, so that the first step call
         # compiles the program every later one runs
@@ -682,31 +916,52 @@ class FMTrainer(DataParallelTrainer):
             return (jax.jit(widen, out_shardings=rep),
                     jax.jit(narrow, out_shardings=rep))
 
-    def _enter(self, params):
+    def _enter(self, params, opt_state=None):
         """Public params -> the state the step carries. The replicated
-        sparse step gets its own ``(w0, T)`` (``widen``); every other
-        step takes the placed params as they are."""
+        sparse step gets its own ``(w0, T)`` (``widen``), under AdaGrad
+        with the accumulators ``opt_state`` in it (None: fresh ones);
+        every other step takes the placed params as they are."""
         with spans.span("mp4j.stream.widen"):
             if (self.table_sharding != "sharded"
                     and params[2].shape != (self.n_rows, self.cfg.k)):
                 raise Mp4jError(
                     f"the embedding table must be [n_rows={self.n_rows}, "
                     f"k={self.cfg.k}], got {params[2].shape}")
+            if opt_state is not None:
+                if not self._adagrad:
+                    raise Mp4jError(
+                        "opt_state is AdaGrad's accumulators; this "
+                        f"trainer's optimizer is {self.cfg.optimizer!r}, "
+                        "which carries none")
+                shapes = [tuple(np.shape(a)) for a in opt_state]
+                if shapes != [tuple(np.shape(p)) for p in params]:
+                    raise Mp4jError(
+                        "opt_state must have the shapes of (w0, w, V), "
+                        f"got {shapes}")
+                opt_state = self._place_replicated(tuple(opt_state))
+            # the accumulators of the call before are the caller's now
+            self.opt_state_ = None
             params = self._place_params(params)
             if not self._blocks:
                 return params
             if self._converters is None:
                 self._converters = self._build_converters()
-            return jax.block_until_ready(self._converters[0](params))
+            widen = self._converters[0]
+            return jax.block_until_ready(
+                widen(params, opt_state) if self._adagrad else widen(params))
 
     def _leave(self, state):
         """The step's state -> public params, in new buffers (``state``
         stays valid: the snapshot of an early-stopping round is taken
-        this way too)."""
+        this way too). AdaGrad's accumulators come out beside them, into
+        ``opt_state_``."""
         with spans.span("mp4j.stream.narrow"):
             if not self._blocks:
                 return state
-            return jax.block_until_ready(self._converters[1](state))
+            out = jax.block_until_ready(self._converters[1](state))
+            if self._adagrad:
+                out, self.opt_state_ = out
+            return out
 
     def save_params(self, path: str, params) -> None:
         """Persist with the table in its portable [n_rows, k] shape
@@ -744,8 +999,9 @@ class FMTrainer(DataParallelTrainer):
                 # global unique touched features can't exceed total
                 # slots this step, nor the vocabulary
                 cap = min(cfg.n_features, per_shard_slots * self.n_shards)
-            step_fn = partial(train_step_sparse, cfg=cfg, capacity=cap,
-                              axis_name=axes)
+            step_fn = partial(
+                train_step_adagrad if self._adagrad else train_step_sparse,
+                cfg=cfg, capacity=cap, axis_name=axes)
             # the state is the trainer's own (``_enter``): donated, the
             # table is scattered into where it rests
             jit_args = dict(donate_argnums=0)
@@ -753,7 +1009,8 @@ class FMTrainer(DataParallelTrainer):
             # (sample, feature); the weights ride in the blocks
             build_args = dict(table_form="blocks",
                               descriptors=per_shard_slots,
-                              index_streams=1)
+                              index_streams=1, optimizer=cfg.optimizer,
+                              block_width=_block_width(cfg), capacity=cap)
             # params are pcast to varying but returned under replicated
             # P() out_specs (every shard computes the identical update
             # from the all-gathered slots + psum'd scalars), which VMA
@@ -813,7 +1070,7 @@ class FMTrainer(DataParallelTrainer):
     def fit(self, feats, fields, vals, y, n_steps: int = 100, params=None,
             seed: int = 0, eval_set=None,
             early_stopping_rounds: int | None = None,
-            sample_weight=None):
+            sample_weight=None, opt_state=None):
         """Full-batch training; returns (params, losses).
 
         ``eval_set=(feats_va, fields_va, vals_va, y_va)`` evaluates the
@@ -821,7 +1078,11 @@ class FMTrainer(DataParallelTrainer):
         ``self.eval_history_``); ``early_stopping_rounds=k`` stops after
         k non-improving steps and returns the best round's params;
         ``sample_weight`` ([N]) weights each example's loss/gradient
-        (integer weights == row duplication).
+        (integer weights == row duplication). With
+        ``optimizer="adagrad"`` the accumulators are left in
+        ``self.opt_state_`` (those of the returned params), and
+        ``opt_state=`` takes them back: n steps and n more with the
+        state handed over are 2n steps, bit for bit.
         """
         if early_stopping_rounds is not None and eval_set is None:
             raise Mp4jError("early_stopping_rounds requires an eval_set")
@@ -836,8 +1097,8 @@ class FMTrainer(DataParallelTrainer):
             self._step_key = per_shard_slots
         if params is None:
             params = self.init_params(seed)
-        state = self._enter(params)
-        del params
+        state = self._enter(params, opt_state)
+        del params, opt_state
         va = None
         if eval_set is not None:
             va = self._prep_eval(*eval_set)
@@ -869,7 +1130,7 @@ class FMTrainer(DataParallelTrainer):
 
     def fit_stream(self, batches, params=None, seed: int = 0,
                    batch_rows: int | None = None,
-                   max_in_flight: int = 2):
+                   max_in_flight: int = 2, opt_state=None):
         """Chunked (out-of-core) training for data that cannot be staged
         in memory — the Criteo-1TB shape of configs[4], where
         ytk-learn consumes streamed libsvm-format text. ``batches`` is
@@ -898,15 +1159,19 @@ class FMTrainer(DataParallelTrainer):
         it in place: it is converted once here (``mp4j.stream.widen``)
         and once before the return (``mp4j.stream.narrow``); the table
         passed in is left as it was, and the one returned is
-        [n_rows, k]. ``max_in_flight=0``
+        [n_rows, k]. AdaGrad's accumulators make the same two trips
+        inside the same blocks: ``opt_state=`` takes those of an earlier
+        call (``self.opt_state_``, the shapes of ``(w0, w, V)``; None
+        starts them at ``adagrad_init``), so that a stream of 2n chunks
+        and two calls of n give the same bits. ``max_in_flight=0``
         reproduces the fully serialized round-4 behavior (the
         overlap's gain was not resolved above noise on the previous
         installation, 2026-07; see ROADMAP S6)."""
         if params is None:
             params = self.init_params(seed)
-        state = [self._enter(params)]
+        state = [self._enter(params, opt_state)]
         # the caller's table is theirs: not donated, and not kept here
-        del params
+        del params, opt_state
 
         def dispatch(staged):
             sharded, per_shard_slots = staged
