@@ -397,3 +397,78 @@ def test_the_block_holds_every_accumulator_beside_its_parameter():
     live = fm._field_columns(tr.cfg).any(axis=0)
     assert np.all(T[:, hw:][:, live] == np.float32(INIT)) and a0 == INIT
     assert np.all(T[:, hw:][:, ~live] == 0) and np.all(T[:, :hw][:, ~live] == 0)
+
+
+# --------------------------------- the update loop over the live prefix
+WIDE = 96       # features: capacity is then the chunk's 64 slots
+
+
+def _chunk_of(rng, distinct):
+    """16 rows x 4 slots that hold exactly ``distinct`` features between
+    them (4 .. 64), each field's ids in its own range."""
+    per = WIDE // NFIELDS
+    feats = np.empty((ROWS, NFIELDS), np.int32)
+    for f in range(NFIELDS):
+        d = distinct // NFIELDS + (f < distinct % NFIELDS)
+        ids = rng.choice(per, d, replace=False) + f * per
+        feats[:, f] = np.concatenate([ids, rng.choice(ids, ROWS - d)])
+    assert len(np.unique(feats)) == distinct
+    fields = np.broadcast_to(np.arange(NFIELDS, dtype=np.int32),
+                             (ROWS, NFIELDS)).copy()
+    return (feats, fields, np.full((ROWS, NFIELDS), 0.5, np.float32),
+            (rng.random(ROWS) < 0.5).astype(np.float32))
+
+
+@pytest.mark.parametrize("n_devices,tile", [(1, 8), (4, 8), (1, 7)],
+                         ids=["one_device", "four_devices",
+                              "tile_not_dividing"])
+@pytest.mark.parametrize("case", ["whole_tiles", "one_past_a_tile",
+                                  "under_one_tile", "no_live_row",
+                                  "every_slot_distinct"])
+def test_the_update_loop_visits_what_the_chunk_holds(monkeypatch, case,
+                                                     n_devices, tile):
+    """The distinct features number a multiple of the tile, one more,
+    fewer than a tile, none (every row's weight 0: no trip, every bit
+    kept) and all 64 slots (every trip): each reached parameter gets its
+    one update, whatever tile its feature falls into."""
+    monkeypatch.setattr(fm, "_UPDATE_TILE", tile)
+    distinct = {"whole_tiles": 2 * tile, "one_past_a_tile": 2 * tile + 1,
+                "under_one_tile": tile - 3, "no_live_row": 2 * tile + 1,
+                "every_slot_distinct": ROWS * NFIELDS}[case]
+    rng = np.random.default_rng(37 + tile)
+    params = (np.float32(0.05),
+              (0.1 * rng.standard_normal(WIDE)).astype(np.float32),
+              rng.uniform(0, 0.5, (WIDE * NFIELDS, KDIM)).astype(np.float32))
+    chunk = _chunk_of(rng, distinct)
+    tr = _trainer(n_devices, n_features=WIDE)
+    assert fm._update_tile(ROWS * NFIELDS) == tile
+    if case == "no_live_row":
+        # the public entries refuse weights that sum to zero: the step
+        # itself, on the chunk as they stage it
+        (staged, slots), _ = tr._stage_stream_chunk(chunk, None)
+        no_weight = jax.device_put(jnp.zeros_like(staged[5]),
+                                   staged[5].sharding)
+        state, loss = tr._build_step(slots)(
+            tr._enter(params), *staged[:5], no_weight)
+        for g, p in zip(tr._leave(state), params):
+            assert np.array_equal(np.asarray(g), p)
+        for G in tr.opt_state_:
+            assert np.all(np.asarray(G) == np.float32(INIT))
+        assert float(loss) == 0.0
+        return
+    got, losses = tr.fit_stream(iter([chunk]), params=params)
+    loss, want, want_o = _reference_step(params, _fresh(params), chunk)
+    _assert_close(got, want, case)
+    _assert_close(tr.opt_state_, want_o, case + " accumulators")
+    np.testing.assert_allclose(losses[0], loss, rtol=1e-5)
+    # every feature the chunk holds moved, and nothing else did
+    seen = np.zeros(WIDE, bool)
+    seen[chunk[0].reshape(-1)] = True
+    assert seen.sum() == distinct
+    w, Gw = np.asarray(got[1]), np.asarray(tr.opt_state_[1])
+    assert np.all(w[seen] != params[1][seen]) and np.all(Gw[seen] > INIT)
+    assert np.array_equal(w[~seen], params[1][~seen])
+    assert np.all(Gw[~seen] == np.float32(INIT))
+    rows = np.repeat(~seen, NFIELDS)
+    assert np.array_equal(np.asarray(got[2])[rows], params[2][rows])
+    assert np.all(np.asarray(tr.opt_state_[2])[rows] == np.float32(INIT))

@@ -255,3 +255,52 @@ def test_custom_operator_shadowing_builtin_name():
     oi, ov = run_sparse_allreduce(per_rank, capacity=2, operator=absmax)
     got = {int(i): float(v) for i, v in zip(oi, ov) if i != sp.SENTINEL}
     assert got == {3: -5.0}, got          # builtin MAX would say 3.0
+
+
+@pytest.mark.parametrize("L,tile,n_live", [
+    (24, 8, 0),         # a list of sentinels: no trip
+    (24, 8, 5),         # fewer than one tile
+    (24, 8, 16),        # exactly two tiles: the third is never reached
+    (24, 8, 17),        # one more than a multiple
+    (24, 8, 24),        # every entry live: every tile
+    (20, 8, 20),        # a length the tile does not divide, all live
+    (20, 8, 17),        # ... and its padded last tile reached half full
+    (5, 8, 3),          # a list shorter than a tile
+])
+def test_fold_live_tiles_visits_the_live_prefix_once(L, tile, n_live, rng):
+    """Against a numpy loop over the list: each live entry is handed to
+    the body once, with its own value, and of the sentinel tail only what
+    fills the last tile reached."""
+    size = 64
+    idx = np.full(L, sp.SENTINEL, np.int32)
+    idx[:n_live] = np.sort(rng.choice(size, n_live, replace=False))
+    val = np.zeros((L, 3), np.float32)
+    val[:n_live] = rng.standard_normal((n_live, 3))
+
+    def body(carry, ti, tv):
+        seen, total, trips, dead = carry
+        assert ti.shape == (tile,) and tv.shape == (tile, 3)
+        live = ti != sp.SENTINEL
+        at = jnp.where(live, ti, size)
+        return (seen.at[at].add(1, mode="drop"),
+                total.at[at].add(tv, mode="drop"),
+                trips + 1, dead + jnp.sum(~live, dtype=jnp.int32))
+
+    init = (jnp.zeros(size, jnp.int32), jnp.zeros((size, 3), jnp.float32),
+            jnp.int32(0), jnp.int32(0))
+    seen, total, trips, dead = jax.jit(
+        lambda i, v: sp.fold_live_tiles(i, v, tile, body, init))(idx, val)
+
+    want_seen = np.zeros(size, np.int32)
+    want_total = np.zeros((size, 3), np.float32)
+    want_trips = 0
+    for start in range(0, n_live, tile):            # the live prefix alone
+        for j in range(start, min(start + tile, n_live)):
+            want_seen[idx[j]] += 1
+            want_total[idx[j]] += val[j]
+        want_trips += 1
+    assert np.array_equal(np.asarray(seen), want_seen)
+    assert want_seen.max(initial=0) <= 1
+    assert np.array_equal(np.asarray(total), want_total)
+    assert int(trips) == want_trips == -(-n_live // tile)
+    assert int(dead) == want_trips * tile - n_live

@@ -503,6 +503,22 @@ def _adagrad(p, G, g, lr):
     return p - lr * g / jnp.sqrt(G), G
 
 
+# Distinct features the AdaGrad step gathers, updates and sets back a
+# trip of its loop. A descriptor costs 102 ns through gather, rule and
+# scatter, live or dropped, and a chunk overshoots its live count by half
+# a tile on average; a trip costs about 3 us of its own. At the Criteo
+# cell's shape (79,872 slots, 33,940 live) that is flat from 512 to 2,048
+# and this is the fastest of the twelve tiles swept on the chip, 128 to
+# 79,872 (PERF.md section 5, PR 33).
+_UPDATE_TILE = 768
+
+
+def _update_tile(capacity: int) -> int:
+    """The tile of :func:`train_step_adagrad`'s update loop for a merged
+    list of ``capacity`` entries."""
+    return min(_UPDATE_TILE, capacity)
+
+
 def train_step_adagrad(params, batch, cfg: FMConfig, capacity: int,
                        axis_name="mp4j"):
     """One step of libffm's rule (Juan et al., RecSys 2016, Algorithm 1)
@@ -526,9 +542,20 @@ def train_step_adagrad(params, batch, cfg: FMConfig, capacity: int,
     which SGD's scatter-add makes needless): a feature a chunk holds 200
     times gets one update, not 200. ``capacity`` bounds the distinct
     features and must be all the slots there are (or ``n_features``), so
-    that the merge drops nothing. The distinct features' blocks are
-    gathered, updated and SET back (no index repeats; SENTINEL slots are
-    dropped), one descriptor each way a feature as in the SGD step."""
+    that the merge drops nothing.
+
+    The merged list is ascending, the distinct features first and
+    SENTINEL after them, and the serial unit charges a dropped sentinel
+    what it charges a live descriptor. So the distinct features' blocks
+    are gathered, updated and SET back (no index repeats) a tile of
+    :func:`_update_tile` at a time, over the list's LIVE PREFIX only
+    (``ops/sparse.fold_live_tiles``: the trip count is the live count's,
+    read from the list): a chunk pays for the features it holds, rounded
+    up to a tile, and not for its ``capacity`` slots. The loop carries
+    the donated table, which stays where it rests; tiles are disjoint,
+    so every reached parameter is still updated exactly once a chunk; the
+    sentinels of the last tile reached are dropped by the scatter, one
+    descriptor each way a feature as in the SGD step."""
     feats, fields, vals, mask, y, sw = batch
     _check_block_table(params, 3, cfg)
     w0, T, a0 = _pcast_params(params, axis_name)
@@ -551,18 +578,28 @@ def train_step_adagrad(params, batch, cfg: FMConfig, capacity: int,
     ui, uv = _merge_slots(keys, payload, capacity, axis_name)
 
     lr = cfg.learning_rate
-    dead = ui == sparse_ops.SENTINEL
-    with jax.named_scope("ffm.table_gather"):
-        cur = T[jnp.where(dead, 0, ui)]             # [capacity, block]
+
+    def update_tile(T, ti, tv):
+        dead = ti == sparse_ops.SENTINEL
+        with jax.named_scope("ffm.table_gather"):
+            cur = T[jnp.where(dead, 0, ti)]             # [tile, block]
+        with jax.named_scope("ffm.adagrad_rule"):
+            p, G = cur[:, :hw], cur[:, hw:]
+            touched = _touched_columns(tv[:, hw:], cfg)
+            g = jnp.where(touched, tv[:, :hw] + cfg.l2 * p, 0.0)
+            stepped, G = _adagrad(p, G, g, lr)  # untouched: G + 0.0, its bits
+            new = jnp.concatenate(
+                [jnp.where(touched, stepped, p), G], axis=1)
+        with jax.named_scope("ffm.table_update"):
+            return T.at[jnp.where(dead, T.shape[0], ti)].set(
+                new, mode="drop")
+
+    # the loop itself is under no scope: gather, rule and update are read
+    # apart in a device trace (PERF.md section 3)
+    T = sparse_ops.fold_live_tiles(ui, uv, _update_tile(capacity),
+                                   update_tile, T)
     with jax.named_scope("ffm.adagrad_rule"):
-        p, G = cur[:, :hw], cur[:, hw:]
-        touched = _touched_columns(uv[:, hw:], cfg)
-        g = jnp.where(touched, uv[:, :hw] + cfg.l2 * p, 0.0)
-        stepped, G = _adagrad(p, G, g, lr)  # untouched: G + 0.0, its bits
-        new = jnp.concatenate([jnp.where(touched, stepped, p), G], axis=1)
         w0, a0 = _adagrad(w0, a0, g0, lr)
-    with jax.named_scope("ffm.table_update"):
-        T = T.at[jnp.where(dead, T.shape[0], ui)].set(new, mode="drop")
     return (w0, T, a0), loss
 
 
@@ -1011,6 +1048,12 @@ class FMTrainer(DataParallelTrainer):
                               descriptors=per_shard_slots,
                               index_streams=1, optimizer=cfg.optimizer,
                               block_width=_block_width(cfg), capacity=cap)
+            if self._adagrad:
+                # the update loop's tile, and its trips for a chunk whose
+                # slots are all distinct
+                tile = _update_tile(cap)
+                build_args.update(update_tile=tile,
+                                  update_tiles=-(-cap // tile))
             # params are pcast to varying but returned under replicated
             # P() out_specs (every shard computes the identical update
             # from the all-gathered slots + psum'd scalars), which VMA

@@ -137,6 +137,36 @@ def segment_reduce_sorted(idx, val, capacity: int,
     return out_idx, out_val
 
 
+def fold_live_tiles(idx, val, tile: int, body, carry):
+    """Fold ``body`` over the LIVE prefix of a packed ``(idx, val)`` list,
+    ``tile`` entries at a time: ``carry = body(carry, idx_tile, val_tile)``
+    for tiles 0 .. ceil(n_live / tile) - 1, where ``n_live`` counts the
+    entries that are not SENTINEL. The list is
+    :func:`segment_reduce_sorted`'s: live entries first, SENTINEL after
+    them. The trip count is read from the data, so the work goes with
+    what the list holds and not with its static length; a list of
+    sentinels runs no tile and returns ``carry`` as it came.
+
+    Tiles are disjoint and cover the prefix once: a length that ``tile``
+    does not divide is padded with SENTINEL / zeros first (a
+    ``dynamic_slice`` that clamped at the end would hand ``body`` the
+    entries before it a second time). Only the last tile reached may
+    hold sentinels; ``body`` drops them as a whole-list pass would.
+    Inside ``shard_map`` every member must hold the same list (the trip
+    count is per member and the loop holds no collective of its own)."""
+    L = idx.shape[0]
+    if L % tile:
+        idx, val = pad_to(idx, val, -(-L // tile) * tile)
+    n_live = jnp.sum(idx != SENTINEL, dtype=jnp.int32)
+
+    def step(t, carry):
+        return body(carry,
+                    lax.dynamic_slice_in_dim(idx, t * tile, tile),
+                    lax.dynamic_slice_in_dim(val, t * tile, tile))
+
+    return lax.fori_loop(0, (n_live + (tile - 1)) // tile, step, carry)
+
+
 def _generic_segment_reduce(val, seg, capacity: int, operator: Operator):
     """Segment reduction for user-defined operators via a segmented
     suffix scan (Hillis-Steele): after round k, acc[i] covers elements
