@@ -7,8 +7,10 @@ no instruction of the optimised program."""
 
 import contextlib
 import glob
+import hashlib
 import json
 import re
+import time
 from functools import partial
 
 import numpy as np
@@ -19,6 +21,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ytk_mp4j_tpu.models import fm as fm_mod
+from ytk_mp4j_tpu.models._base import DataParallelTrainer
 from ytk_mp4j_tpu.models.fm import FMConfig, FMTrainer
 from ytk_mp4j_tpu.models.gbdt import GBDTConfig, GBDTTrainer
 from ytk_mp4j_tpu.models.linear import LinearConfig, LinearTrainer
@@ -93,7 +96,8 @@ def test_gbdt_train_leaves_exactly_its_spans(rng, ring):
     got = _trainer_spans()
     assert sorted({s[0] for s in got}) == [
         "mp4j.gbdt.dispatch", "mp4j.gbdt.fetch", "mp4j.gbdt.stage",
-        "mp4j.put_sharded", "mp4j.step.build"]
+        "mp4j.put_sharded", "mp4j.stage.prep", "mp4j.stage.send",
+        "mp4j.step.build"]
     (stage,), (fetch,) = (_named(got, "mp4j.gbdt.stage"),
                           _named(got, "mp4j.gbdt.fetch"))
     dispatch = _named(got, "mp4j.gbdt.dispatch")
@@ -104,8 +108,20 @@ def test_gbdt_train_leaves_exactly_its_spans(rng, ring):
     assert len(puts) == 4                       # bins, y, preds, weights
     assert all(_inside(p, stage) for p in puts)
     assert puts[0][6] == {"bytes": bins.nbytes}
+    # one hand-over a put (the one-transfer regime), inside it
+    sends = _named(got, "mp4j.stage.send")
+    assert [s[6] for s in sends] == [{"chunk": 0, **p[6]} for p in puts]
+    assert all(_inside(s, p) for s, p in zip(sends, puts))
+    # what the host builds before anything is sent: the table's weights
+    # (512 rows fill four shards: nothing is padded), the labels' weights,
+    # the margins; inside the stage span and outside every put
+    preps = _named(got, "mp4j.stage.prep")
+    assert [s[6] for s in preps] == [{"bytes": 4 * 512}] * 3
+    assert all(_inside(s, stage) for s in preps)
+    assert not any(_inside(s, p) for s in preps for p in puts)
     # in order on the host: build, stage, every tree, fetch
-    order = [s[0] for s in got if s[0] != "mp4j.put_sharded"]
+    order = [s[0] for s in got if s[0] != "mp4j.put_sharded"
+             and not s[0].startswith("mp4j.stage.")]
     assert order == ["mp4j.step.build", "mp4j.gbdt.stage"] \
         + ["mp4j.gbdt.dispatch"] * 3 + ["mp4j.gbdt.fetch"]
     assert all(a[2] + a[3] <= b[2] for a, b in zip(
@@ -175,7 +191,7 @@ def test_fit_stream_leaves_exactly_its_spans(rng, ring, family,
     tr.fit_stream(iter(chunks), max_in_flight=max_in_flight)
     got = _trainer_spans()
     stream = ["mp4j.stream.dispatch", "mp4j.stream.fetch",
-              "mp4j.stream.stage"]
+              "mp4j.stream.next", "mp4j.stream.stage", "mp4j.stage.send"]
     if max_in_flight < n - 1:
         stream.append("mp4j.stream.throttle")
     # the linear step is built outside the loop and is not a span; the
@@ -189,6 +205,11 @@ def test_fit_stream_leaves_exactly_its_spans(rng, ring, family,
     dispatch = _named(got, "mp4j.stream.dispatch")
     assert [s[6] for s in stage] == [{"chunk": k} for k in range(n)]
     assert [s[6] for s in dispatch] == [{"chunk": k} for k in range(n)]
+    # the caller's iterator is asked once a chunk and once more, the call
+    # that finds it exhausted; chunk k is taken before it is staged
+    nexts = _named(got, "mp4j.stream.next")
+    assert [s[6] for s in nexts] == [{"chunk": k} for k in range(n + 1)]
+    assert all(nexts[k][2] + nexts[k][3] <= stage[k][2] for k in range(n))
     # the throttle names the chunk it waits for: after chunk k is
     # launched, the one ``max_in_flight`` before it
     throttle = _named(got, "mp4j.stream.throttle")
@@ -202,6 +223,10 @@ def test_fit_stream_leaves_exactly_its_spans(rng, ring, family,
     puts = _named(got, "mp4j.put_sharded")
     assert len(puts) % n == 0 and all(
         any(_inside(p, s) for s in stage) for p in puts)
+    sends = _named(got, "mp4j.stage.send")
+    assert len(sends) == len(puts) and all(
+        _inside(s, p) and s[6] == {"chunk": 0, **p[6]}
+        for s, p in zip(sends, puts))
     if built:
         converters, build = _named(got, "mp4j.step.build")
         (widen,) = _named(got, "mp4j.stream.widen")
@@ -262,10 +287,93 @@ def test_step_build_span_says_the_table_form(rng, ring, kw, carries):
     assert build == [want]
 
 
-@pytest.mark.parametrize("family", ["gbdt", "ffm"])
+def _chunked(n_shards, per=1000, width=16):
+    """A trainer whose shards cross in sixteen chunks of 64 rows, the
+    last one early, whichever way (``tests/test_row_chunk_staging.py``)."""
+    t = DataParallelTrainer(n_devices=n_shards)
+    t._ONE_TRANSFER_BYTES = per * width * 4
+    t._CHUNK_BYTES = t._EACH_CHUNK_BYTES = 4096
+    return t, np.arange(n_shards * per * width, dtype=np.int32).reshape(
+        n_shards * per, width)
+
+
+@pytest.mark.parametrize("n_shards", [1, 4])
+@pytest.mark.parametrize("with_each", [False, True], ids=["stage", "each"])
+def test_row_chunks_leave_a_send_and_a_place_a_chunk(ring, n_shards,
+                                                     with_each):
+    t, a = _chunked(n_shards)
+    calls = []
+    got = t._put_sharded(a, 1000, each=(
+        lambda table, start, stop: calls.append((start, stop)))
+        if with_each else None)
+    np.testing.assert_array_equal(np.asarray(got).reshape(a.shape), a)
+    recorded = _trainer_spans()
+    (put,) = _named(recorded, "mp4j.put_sharded")
+    assert put[6] == {"bytes": a.nbytes}
+    stage = [s for s in recorded if s[0].startswith("mp4j.stage.")]
+    assert all(_inside(s, put) for s in stage)
+    # children on one thread: together no longer than their parent
+    assert sum(s[3] for s in stage) <= put[3]
+    send, place = (_named(stage, "mp4j.stage.send"),
+                   _named(stage, "mp4j.stage.place"))
+    # what is handed to the runtime: sixteen whole chunks of every shard,
+    # so the 24 rows that the early last chunk brings again count twice
+    assert [s[6] for s in send] == [
+        {"chunk": k, "bytes": n_shards * 64 * 16 * 4} for k in range(16)]
+    assert sum(s[6]["bytes"] for s in send) \
+        == a.nbytes + n_shards * 24 * 16 * 4
+    assert [s[6] for s in place] == [{"chunk": k} for k in range(16)]
+    assert all(s[2] + s[3] <= p[2] for s, p in zip(send, place))
+    link = [s[6]["chunk"] for s in _named(stage, "mp4j.stage.link_wait")]
+    device = [s[6]["chunk"] for s in _named(stage, "mp4j.stage.device_wait")]
+    if with_each:
+        # two crossing: chunk k - 1 has crossed before k + 1 is sent; the
+        # device is waited for only when twelve wait for their turn
+        assert link == list(range(15)) and device == list(range(4))
+        assert calls == [(min(64 * k, 1000 - 64), min(64 * k, 1000 - 64) + 64)
+                         for k in range(16)]
+    else:
+        assert link == [] and device == list(range(14))
+    assert {s[0] for s in stage} == {
+        "mp4j.stage.send", "mp4j.stage.place", "mp4j.stage.device_wait",
+        *(["mp4j.stage.link_wait"] if with_each else [])}
+
+
+@pytest.mark.parametrize("family", ["ffm", "linear"])
+def test_a_slow_iterator_shows_in_stream_next_and_nowhere_else(rng, ring,
+                                                               family):
+    n, slow, nap = 4, 2, 0.25
+    tr, chunks = (_ffm if family == "ffm" else _linear)(rng, n)
+
+    def reader():
+        for k, chunk in enumerate(chunks):
+            if k == slow:
+                time.sleep(nap)
+            yield chunk
+
+    tr.fit_stream(reader())
+    got = _trainer_spans()
+    nexts = _named(got, "mp4j.stream.next")
+    assert [s[6]["chunk"] for s in nexts] == list(range(n + 1))
+    assert nexts[slow][3] >= nap
+    assert all(s[3] < nap / 2 for s in nexts if s is not nexts[slow])
+    # no other span holds any of it: none overlaps a next
+    others = [s for s in got if s[0] != "mp4j.stream.next"]
+    assert len(others) > 3 * n and all(
+        s[2] + s[3] <= nx[2] or nx[2] + nx[3] <= s[2]
+        for s in others for nx in nexts)
+
+
+@pytest.mark.parametrize("family", ["gbdt", "ffm", "row-chunks",
+                                    "row-chunks-each"])
 def test_ring_off_leaves_no_span_and_the_same_bits(rng, family):
     def run():
         r = np.random.default_rng(7)
+        if family.startswith("row-chunks"):
+            t, a = _chunked(N_SHARDS)
+            each = ((lambda table, start, stop: None)
+                    if family.endswith("each") else None)
+            return [np.asarray(t._put_sharded(a, 1000, each=each))]
         if family == "gbdt":
             tr, bins, y = _gbdt(r)
             trees, margins = tr.train(bins, y)
@@ -448,3 +556,59 @@ def test_scopes_change_no_instruction_of_the_ffm_step(rng, monkeypatch):
     a, b = instructions(with_scopes), instructions(without)
     assert len(a) == len(b) > 20
     assert a == b
+
+
+# ------------------------------------------- the spans are the host's only
+def _lower_placer(rng):
+    t, a = _chunked(N_SHARDS)
+    t._put_sharded(a, 1000)
+    ((shape, dtype, rows), place), = t._row_placers.items()
+    sharding = t._row_sharding()
+    return place.lower(
+        jax.ShapeDtypeStruct(shape, np.dtype(dtype), sharding=sharding),
+        jax.ShapeDtypeStruct((N_SHARDS, rows * 16 // 128, 128),
+                             np.dtype(dtype), sharding=sharding),
+        np.int32(0))
+
+
+def _lower_score(rng):
+    tr, bins, y = _gbdt(rng)
+    trees, _ = tr.train(bins, y)
+    table = tr.shard_bins(bins)
+    per = table.shape[1]
+    margins = jnp.zeros((N_SHARDS, 1, per), jnp.float32,
+                        device=tr._row_sharding())
+    return tr._build_score(table.shape, per, len(trees)).lower(
+        table, tr._stack_trees(trees), margins, np.int32(0))
+
+
+# sha256 of the lowered text of every program a staging or a stream span
+# is near, taken on the parent of the PR that added those spans (ISSUE 34)
+# with this file's helpers: the spans are the host's and no line of a
+# jitted function moved. A PR that changes one of these programs on
+# purpose prints the new digest with this test and pins it.
+LOWERED = {
+    "placer": (
+        _lower_placer,
+        "2582168225277df2d3bae310438d4541f84c320762e7cd20458db82dc974884a"),
+    "gbdt": (
+        _lower_gbdt,
+        "6c5ca8871ebcc87cb0732348a22485b157747531f3ed541b105915b6ebb1a88f"),
+    "score": (
+        _lower_score,
+        "65e9cddff36da71d3c0d1b039b56f4d4137a955b457d12271ce2745044751514"),
+    "ffm": (
+        _lower_ffm,
+        "2fa0b34bc566d097ee4e4388f689302adb926463ff2c822c38ad73125808ca15"),
+    "ffm-adagrad": (
+        partial(_lower_ffm, optimizer="adagrad"),
+        "01e0c1063e2cf9a337abcdbfb3085ec66f89feeedcd4eadcf11ef43b4742ba79"),
+}
+
+
+@pytest.mark.parametrize("program", sorted(LOWERED))
+def test_lowered_programs_are_the_parents_to_the_letter(program):
+    lower, want = LOWERED[program]
+    text = lower(np.random.default_rng(0)).as_text()
+    assert "func.func public @main" in text
+    assert hashlib.sha256(text.encode()).hexdigest() == want

@@ -9,6 +9,8 @@ any N (SURVEY.md section 4's differential-testing requirement).
 
 from __future__ import annotations
 
+import itertools
+
 import numpy as np
 
 import jax
@@ -148,13 +150,19 @@ class DataParallelTrainer:
         n = self.n_shards
         per = -(-N // n)
         pad = per * n - N
-        sw = np.ones(N, np.float32)
+        # what the host builds here: the weights, and every array again
+        # where the rows do not fill the shards
+        built = 4 * per * n
         if pad:
-            arrays = [
-                np.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
-                for a in arrays
-            ]
-            sw = np.pad(sw, (0, pad))
+            built += sum(a.nbytes // N * per * n for a in arrays)
+        with spans.span("mp4j.stage.prep", bytes=built):
+            sw = np.ones(N, np.float32)
+            if pad:
+                arrays = [
+                    np.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+                    for a in arrays
+                ]
+                sw = np.pad(sw, (0, pad))
         return arrays, per, sw
 
     def _stream_fit(self, batches, stage_chunk, dispatch,
@@ -185,9 +193,19 @@ class DataParallelTrainer:
             with spans.span("mp4j.stream.dispatch", chunk=len(pending)):
                 pending.append(dispatch(staged))
 
-        # every host phase is a span carrying its chunk's index; the
-        # time between spans is the caller's iterator (reader, parser)
-        for k, chunk in enumerate(batches):
+        def chunks():
+            # the caller's iterator (reader, parser) under a span of its
+            # own, the call that finds it exhausted too
+            it, done = iter(batches), object()
+            for k in itertools.count():
+                with spans.span("mp4j.stream.next", chunk=k):
+                    chunk = next(it, done)
+                if chunk is done:
+                    return
+                yield k, chunk
+
+        # every host phase is a span carrying its chunk's index
+        for k, chunk in chunks():
             if staged is not None:  # overlap: device runs step k-1
                 launch()
                 if len(pending) > max_in_flight:
@@ -270,13 +288,22 @@ class DataParallelTrainer:
         rows [start, stop) of every shard are on their way into
         ``table`` (the array that holds them; it is only good until the
         next call): after every chunk, or once for the whole shard. What
-        it dispatches on those rows runs while the rest crosses."""
+        it dispatches on those rows runs while the rest crosses.
+
+        The ``mp4j.put_sharded`` span's children say what the host waited
+        for: ``mp4j.stage.send`` (the hand-over to the runtime, which is
+        long where the runtime copies or tiles inline), and on the
+        chunked path ``place`` (the placer's launch), ``link_wait`` (a
+        chunk crossing) and ``device_wait`` (the device placing one).
+        Here ``send`` returns before the array has landed and nothing
+        waits for it: when it lands no span of the program's can say."""
         with spans.span("mp4j.put_sharded", bytes=a.nbytes):
             a = a.reshape((self.n_shards, per) + a.shape[1:])
             if a.nbytes // self.n_shards >= self._ONE_TRANSFER_BYTES:
                 return self._put_in_row_chunks(a, each)
-            table = jax.make_array_from_callback(
-                a.shape, self._row_sharding(), lambda idx: a[idx])
+            with spans.span("mp4j.stage.send", chunk=0, bytes=a.nbytes):
+                table = jax.make_array_from_callback(
+                    a.shape, self._row_sharding(), lambda idx: a[idx])
             if each is not None:
                 each(table, 0, per)
             return table
@@ -369,24 +396,30 @@ class DataParallelTrainer:
         table = jnp.zeros(a.shape, a.dtype, device=sharding)
         placed, crossing = [], []
         ahead = 2 if each is None else self._CHUNKS_AHEAD
-        for start in range(0, per, rows):
+        for k, start in enumerate(range(0, per, rows)):
             # the last chunk is as long as the others: it starts early
             # and rewrites rows the chunk before it already placed
             start = min(start, per - rows)
             chunk = a[:, start:start + rows]
-            dchunk = jax.make_array_from_callback(
-                wire, sharding,
-                lambda idx, chunk=chunk: chunk[idx[0]].reshape(
-                    (-1,) + wire[1:]))
-            table, done = place(table, dchunk, np.int32(start))
+            with spans.span("mp4j.stage.send", chunk=k, bytes=chunk.nbytes):
+                dchunk = jax.make_array_from_callback(
+                    wire, sharding,
+                    lambda idx, chunk=chunk: chunk[idx[0]].reshape(
+                        (-1,) + wire[1:]))
+            with spans.span("mp4j.stage.place", chunk=k):
+                table, done = place(table, dchunk, np.int32(start))
             placed.append(done)
             if each is not None:
                 each(table, start, start + rows)
                 crossing.append(dchunk)
                 if len(crossing) >= self._CHUNKS_CROSSING:
-                    jax.block_until_ready(crossing.pop(0))
+                    with spans.span("mp4j.stage.link_wait",
+                                    chunk=k + 1 - len(crossing)):
+                        jax.block_until_ready(crossing.pop(0))
             if len(placed) > ahead:
-                jax.block_until_ready(placed.pop(0))
+                with spans.span("mp4j.stage.device_wait",
+                                chunk=k + 1 - len(placed)):
+                    jax.block_until_ready(placed.pop(0))
         return table
 
     def save_params(self, path: str, params) -> None:

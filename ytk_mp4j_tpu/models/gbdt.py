@@ -889,11 +889,11 @@ class GBDTTrainer(DataParallelTrainer):
         N = bins.shape[0]
         dbins = self.shard_bins(bins)
         (y,), per, w = self._pad_rows([y])
-        w[:N] *= self._stage_weights(sample_weight, N)
-        if self.cfg.loss == "softmax":
-            preds = np.zeros((y.shape[0], self.cfg.n_classes), np.float32)
-        else:
-            preds = np.zeros_like(y, np.float32)
+        margins = (y.shape + (self.cfg.n_classes,)
+                   if self.cfg.loss == "softmax" else y.shape)
+        with spans.span("mp4j.stage.prep", bytes=4 * int(np.prod(margins))):
+            w[:N] *= self._stage_weights(sample_weight, N)
+            preds = np.zeros(margins, np.float32)
         return (dbins, self._put_sharded(y, per),
                 self._put_sharded(preds, per),
                 self._put_sharded(w, per))
