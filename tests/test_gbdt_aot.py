@@ -612,6 +612,37 @@ def test_adagrad_step_sets_the_blocks_into_the_table_where_it_rests(
     assert m.temp_size_in_bytes < 0.4e9, m.temp_size_in_bytes
 
 
+def test_adagrad_scatters_are_told_what_pays_to_tell(adagrad_programs):
+    """The segment ids are a cumulative sum, and the merge's scatter-add
+    is told so (PR 35): XLA's own sort of the 79,872 ids and its gather
+    of the ``[79,872, 232]`` rows by that order are not in the program,
+    and the one ``sort`` left is the library's. The loop's scatter into
+    the table is NOT told that a tile's ids ascend, though they do: told,
+    XLA takes its large-buffer scatter, which passes over the whole
+    6.44 GB operand a call (19.4 ms a tile of 768 on the chip against
+    0.075; PERF.md section 6, PR 35). It stays the native fusion on the
+    carried table, in place."""
+    c, step = adagrad_programs["config"], adagrad_programs["step"]
+    text = step.as_text()
+    F, width = c["n_features"], 384
+    table = r"f32\[%d,%d\]\{1,0:T\(8,128\)\}" % (F, width)
+
+    def scatters(scope):
+        return [line for line in text.splitlines()
+                if re.search(r" scatter\(.*op_name=\"[^\"]*" + scope, line)]
+
+    (update,) = scatters(r"while/body/ffm\.table_update")
+    assert re.search(r"= " + table + r" scatter\(", update)
+    assert "indices_are_sorted=true" not in update
+    merge = scatters(r"ffm\.grad_merge/sparse\.segment_reduce")
+    (added,) = [line for line in merge if "scatter-add" in line]
+    assert "indices_are_sorted=true" in added
+    sorts = [line for line in text.splitlines()
+             if re.search(r" sort\(.*ffm\.grad_merge", line)]
+    assert len(sorts) == 1 and "sparse.sort_by_key" in sorts[0]
+    assert len(re.findall(r" sort\(", text)) == 1
+
+
 @pytest.mark.parametrize("which", ["widen", "narrow"])
 def test_adagrad_conversions_go_a_block_at_a_time(adagrad_programs, which):
     """5.27 GB in (parameters and accumulators, the shapes of (w0, w, V)

@@ -304,3 +304,66 @@ def test_fold_live_tiles_visits_the_live_prefix_once(L, tile, n_live, rng):
     assert np.array_equal(np.asarray(total), want_total)
     assert int(trips) == want_trips == -(-n_live // tile)
     assert int(dead) == want_trips * tile - n_live
+
+
+# ------------------- what the merge's reducer is told of its segment ids
+@pytest.mark.parametrize("operator,reducer", [
+    (Operators.SUM, jax.ops.segment_sum),
+    (Operators.PROD, jax.ops.segment_prod),
+    (Operators.MAX, jax.ops.segment_max),
+    (Operators.MIN, jax.ops.segment_min)], ids=["SUM", "PROD", "MAX", "MIN"])
+@pytest.mark.parametrize("L,n_keys,dead,capacity", [
+    (40, 9, 6, 40),     # runs of duplicates, a sentinel tail, empty segments
+    (40, 9, 0, 40),     # no sentinel at all
+    (40, 40, 0, 40),    # every key its own run: a full union
+    (40, 5, 40, 8),     # a list of sentinels
+    (40, 30, 3, 16),    # more runs than capacity: the overflow is dropped
+], ids=["duplicates", "all_live", "full_union", "all_dead", "overflow"])
+def test_segment_reduce_told_sorted_is_the_untold_reduction(
+        monkeypatch, operator, reducer, L, n_keys, dead, capacity, rng):
+    """Against the same function with the reducer told nothing, as it
+    was: the same packed list, bit for bit (and the reduction itself)."""
+    keys = rng.integers(0, n_keys, L).astype(np.int32)
+    if n_keys == L:
+        keys = rng.permutation(L).astype(np.int32)
+    keys[rng.choice(L, dead, replace=False)] = sp.SENTINEL
+    val = rng.uniform(0.5, 1.5, (L, 3)).astype(np.float32)
+    si, sv = sp.sort_by_key(jnp.asarray(keys), jnp.asarray(val))
+    gi, gv = jax.jit(lambda i, v: sp.segment_reduce_sorted(
+        i, v, capacity, operator))(si, sv)
+    monkeypatch.setitem(
+        sp._SEGMENT_REDUCERS, operator,
+        lambda v, s, num_segments, indices_are_sorted: reducer(
+            v, s, num_segments=num_segments))
+    wi, wv = jax.jit(lambda i, v: sp.segment_reduce_sorted(
+        i, v, capacity, operator))(si, sv)
+    assert np.array_equal(np.asarray(gi), np.asarray(wi))
+    assert np.array_equal(np.asarray(gv).view(np.int32),
+                          np.asarray(wv).view(np.int32))
+    # and both are the reduction: against numpy, key by key
+    live = np.unique(keys[keys != sp.SENTINEL])[:capacity]
+    assert np.array_equal(np.asarray(gi)[:len(live)], live)
+    assert np.all(np.asarray(gi)[len(live):] == sp.SENTINEL)
+    for k, row in zip(live, np.asarray(gv)):
+        np.testing.assert_allclose(
+            row, operator.np_fn.reduce(val[keys == k], axis=0), rtol=1e-5)
+
+
+def test_segment_reduce_tells_its_reducer_the_ids_ascend():
+    """``seg`` is a cumulative sum; the scatter-add under ``segment_sum``
+    carries the promise, so on the TPU XLA neither sorts the ids itself
+    nor gathers the rows by that order a second time. The index set
+    beside it stays untold: 0.375 ms on the chip either way (PR 35)."""
+    jaxpr = jax.make_jaxpr(lambda i, v: sp.segment_reduce_sorted(i, v, 8))(
+        jnp.zeros(8, jnp.int32), jnp.zeros((8, 3)))
+
+    def eqns(jaxpr):
+        for e in jaxpr.eqns:
+            yield e
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from eqns(sub)
+
+    told = {e.primitive.name: e.params["indices_are_sorted"]
+            for e in eqns(jaxpr.jaxpr)
+            if e.primitive.name.startswith("scatter")}
+    assert told == {"scatter-add": True, "scatter": False}

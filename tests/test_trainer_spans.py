@@ -586,7 +586,8 @@ def _lower_score(rng):
 # is near, taken on the parent of the PR that added those spans (ISSUE 34)
 # with this file's helpers: the spans are the host's and no line of a
 # jitted function moved. A PR that changes one of these programs on
-# purpose prints the new digest with this test and pins it.
+# purpose prints the new digest with this test and pins it (PR 35: the
+# AdaGrad step's, whose merge tells ``segment_sum`` its ids ascend).
 LOWERED = {
     "placer": (
         _lower_placer,
@@ -602,7 +603,7 @@ LOWERED = {
         "2fa0b34bc566d097ee4e4388f689302adb926463ff2c822c38ad73125808ca15"),
     "ffm-adagrad": (
         partial(_lower_ffm, optimizer="adagrad"),
-        "01e0c1063e2cf9a337abcdbfb3085ec66f89feeedcd4eadcf11ef43b4742ba79"),
+        "66798885a69e0d038f05e705aa22a0624fa128ef0dfd15e6ec28f5f06446d8a3"),
 }
 
 

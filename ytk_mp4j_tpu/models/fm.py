@@ -509,7 +509,9 @@ def _adagrad(p, G, g, lr):
 # a tile on average; a trip costs about 3 us of its own. At the Criteo
 # cell's shape (79,872 slots, 33,940 live) that is flat from 512 to 2,048
 # and this is the fastest of the twelve tiles swept on the chip, 128 to
-# 79,872 (PERF.md section 5, PR 33).
+# 79,872 (PERF.md section 5, PR 33; swept again at PR 35, the merge 0.41
+# ms shorter and the descriptor's price where it was: the same order,
+# 9.960 ms a step here, 9.982 at 1,536, 9.983 at 512).
 _UPDATE_TILE = 768
 
 
@@ -546,7 +548,10 @@ def train_step_adagrad(params, batch, cfg: FMConfig, capacity: int,
 
     The merged list is ascending, the distinct features first and
     SENTINEL after them, and the serial unit charges a dropped sentinel
-    what it charges a live descriptor. So the distinct features' blocks
+    what it charges a live descriptor (97.9 ns a row of a tile of 768
+    set alone, sentinel tail or none). The scatter is not told that a
+    tile's ids ascend: told, XLA passes over the whole table a call
+    (19.4 ms at 6.44 GB, whatever the tile). So the distinct features' blocks
     are gathered, updated and SET back (no index repeats) a tile of
     :func:`_update_tile` at a time, over the list's LIVE PREFIX only
     (``ops/sparse.fold_live_tiles``: the trip count is the live count's,
