@@ -1,0 +1,351 @@
+"""``FMTrainer.predict`` on a replicated table (ISSUE 36): one jitted,
+row-sharded program that reads the entered ``(w0, T)`` a block a (row,
+slot), over instances staged in row chunks and scored in tiles. Against
+a float64 numpy score and against the old row form, across chunk, tile
+and shard boundaries."""
+
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from ytk_mp4j_tpu.exceptions import Mp4jError
+from ytk_mp4j_tpu.models import fm
+from ytk_mp4j_tpu.models._base import packed_width
+from ytk_mp4j_tpu.models.fm import EnteredModel, FMConfig, FMTrainer
+from ytk_mp4j_tpu.obs import spans
+
+V, NF, K, NNZ = 120, 5, 4, 7
+
+
+def _cfg(model="ffm", optimizer="sgd"):
+    return FMConfig(n_features=V, n_fields=NF, k=K, max_nnz=NNZ, model=model,
+                    optimizer=optimizer, learning_rate=0.1)
+
+
+def _params(tr, seed=0):
+    """Public parameters with every term alive: bias, weights, vectors."""
+    rng = np.random.default_rng(seed)
+    return (jnp.float32(0.3),
+            jnp.asarray(rng.uniform(-0.5, 0.5, V).astype(np.float32)),
+            jnp.asarray(rng.uniform(-0.5, 0.5, (tr.n_rows, K)).astype(
+                np.float32)))
+
+
+def _instances(n, slots, seed=1):
+    """``n`` rows of ``slots`` slots: some slots empty, row 2 (where
+    there is one) all zeros."""
+    rng = np.random.default_rng(seed)
+    feats = rng.integers(0, V, (n, slots)).astype(np.int32)
+    fields = rng.integers(0, NF, (n, slots)).astype(np.int32)
+    vals = rng.standard_normal((n, slots)).astype(np.float32)
+    vals[rng.random((n, slots)) < 0.2] = 0.0
+    vals[2:3] = 0.0
+    return feats, fields, vals
+
+
+def _score64(params, feats, fields, vals, model):
+    """The model's margin in float64, slot by slot and pair by pair."""
+    w0, w, table = (np.asarray(p, np.float64) for p in params)
+    z = np.full(feats.shape[0], w0)
+    for n in range(feats.shape[0]):
+        live = [a for a in range(feats.shape[1]) if vals[n, a] != 0]
+        for a in live:
+            z[n] += w[feats[n, a]] * vals[n, a]
+        for i, a in enumerate(live):
+            for b in live[i + 1:]:
+                if model == "ffm":
+                    va = table[feats[n, a] * NF + fields[n, b]]
+                    vb = table[feats[n, b] * NF + fields[n, a]]
+                else:
+                    va, vb = table[feats[n, a]], table[feats[n, b]]
+                z[n] += va @ vb * vals[n, a] * vals[n, b]
+    return z
+
+
+def _row_form(tr, params, feats, fields, vals):
+    """What ``predict`` returned before it read blocks: the row form."""
+    f, fl, v = tr._stage_instances(feats, fields, vals)
+    z = fm._score(params, jnp.asarray(f), jnp.asarray(fl), jnp.asarray(v),
+                  jnp.asarray(fm._live_mask(v)), tr.cfg)
+    return np.asarray(jax.nn.sigmoid(z))
+
+
+def _small_pieces(monkeypatch, chunk_rows, tile):
+    """Staging chunks of ``chunk_rows`` rows a shard, tiles of ``tile``."""
+    monkeypatch.setattr(FMTrainer, "_EACH_CHUNK_BYTES",
+                        chunk_rows * 3 * NNZ * 4)
+    monkeypatch.setattr(fm, "_SCORE_TILE", tile)
+
+
+def _logit(p):
+    p = np.asarray(p, np.float64)
+    return np.log(p) - np.log1p(-p)
+
+
+@pytest.mark.parametrize("model,optimizer,n_devices,rows,slots", [
+    ("ffm", "sgd", 1, 37, 7),       # 37: no whole tile, no whole chunk
+    ("ffm", "sgd", 4, 37, 5),       # no whole shard; slots padded to 7
+    ("ffm", "sgd", 4, 64, 7),       # whole shards, whole chunks and tiles
+    ("ffm", "adagrad", 1, 33, 6),   # the accumulators must not enter
+    ("ffm", "adagrad", 4, 41, 7),
+    ("fm", "sgd", 1, 37, 7),
+    ("fm", "sgd", 4, 30, 4),
+    ("ffm", "sgd", 1, 1, 7),        # one row
+    ("ffm", "sgd", 4, 3, 2),        # fewer rows than shards
+])
+def test_predict_is_the_float64_score_and_the_row_form(
+        monkeypatch, model, optimizer, n_devices, rows, slots):
+    _small_pieces(monkeypatch, chunk_rows=8, tile=3)
+    cfg = _cfg(model, optimizer)
+    blocks = optimizer == "adagrad"
+    tr = FMTrainer(cfg, n_devices=n_devices, sparse_grads=blocks)
+    params = _params(tr)
+    feats, fields, vals = _instances(rows, slots)
+    if blocks:
+        # parameters that the rule itself made, accumulators beside them
+        y = (np.arange(rows) % 2).astype(np.float32)
+        params, _ = tr.fit(feats, fields, vals, y, n_steps=2, params=params)
+        assert tr.opt_state_ is not None
+    got = tr.predict(params, feats, fields, vals)
+    assert got.shape == (rows,) and got.dtype == np.float32
+    want = _score64(params, feats, fields, vals, model)
+    assert np.abs(_logit(got) - want).max() < 2e-5
+    np.testing.assert_allclose(got, _row_form(tr, params, feats, fields,
+                                              vals), rtol=0, atol=5e-7)
+    if rows > 2:
+        # a row of zeros scores the bias alone
+        assert _logit(got[2]) == pytest.approx(float(params[0]), abs=1e-6)
+
+
+@pytest.mark.parametrize("model,n_devices,chunk_rows,tile", [
+    ("ffm", 1, 8, 3), ("ffm", 1, 16, 16), ("ffm", 1, 5, 7), ("ffm", 4, 4, 3),
+    ("ffm", 4, 3, 2), ("fm", 1, 8, 3), ("fm", 4, 4, 3),
+])
+def test_chunks_and_tiles_change_no_bit(monkeypatch, model, n_devices,
+                                        chunk_rows, tile):
+    """A file longer than a staging chunk, in tiles that divide neither
+    the chunk nor its remainder: the same bits as one chunk, one tile."""
+    tr = FMTrainer(_cfg(model), n_devices=n_devices)
+    params = _params(tr)
+    feats, fields, vals = _instances(53, NNZ)
+    whole = tr.predict(params, feats, fields, vals)
+    built = len(tr._score_programs)
+    _small_pieces(monkeypatch, chunk_rows, tile)
+    got = tr.predict(params, feats, fields, vals)
+    assert len(tr._score_programs) > built      # other programs ran
+    assert got.tobytes() == whole.tobytes()
+
+
+def test_no_rows_no_program():
+    tr = FMTrainer(_cfg(), n_devices=4)
+    got = tr.predict(_params(tr), *_instances(0, NNZ))
+    assert got.shape == (0,) and got.dtype == np.float32
+    assert tr._score_programs == {}
+
+
+def _count(name, since):
+    return sum(s[0] == name for s in spans.take_since(since)[1])
+
+
+def test_a_second_predict_of_the_same_shape_builds_nothing(monkeypatch):
+    _small_pieces(monkeypatch, chunk_rows=8, tile=3)
+    tr = FMTrainer(_cfg(), n_devices=4)
+    params = _params(tr)
+    feats, fields, vals = _instances(53, NNZ)
+    cursor = spans.take_since(0)[0]
+    first = tr.predict(params, feats, fields, vals)
+    # the conversion, the placer, a chunk's program and the remainder's
+    assert _count("mp4j.step.build", cursor) == 4
+    cursor = spans.take_since(0)[0]
+    again = tr.predict(params, *_instances(53, NNZ, seed=2))
+    assert _count("mp4j.step.build", cursor) == 0
+    assert again.shape == first.shape and (again != first).any()
+
+
+def test_an_entered_model_converts_once():
+    tr = FMTrainer(_cfg(), n_devices=4)
+    params = _params(tr)
+    feats, fields, vals = _instances(21, NNZ)
+    cursor = spans.take_since(0)[0]
+    model = tr.enter_model(params)
+    assert isinstance(model, EnteredModel)
+    assert model.T.shape == (V, 128) and model.w0.shape == ()
+    a = tr.predict(model, feats, fields, vals)
+    b = tr.predict(model, feats, fields, vals)
+    assert _count("mp4j.ffm.score.enter", cursor) == 1
+    assert a.tobytes() == b.tobytes()
+    # the public params convert a call, to the same bits
+    c = tr.predict(params, feats, fields, vals)
+    assert _count("mp4j.ffm.score.enter", cursor) == 2
+    assert c.tobytes() == a.tobytes()
+    # the caller's arrays are theirs still
+    assert np.asarray(params[2]).shape == (tr.n_rows, K)
+
+
+@pytest.mark.parametrize("sparse_grads,optimizer,own", [
+    (False, "sgd", True),       # the dense step has no converter
+    (True, "sgd", False),       # the SGD block is the one scoring reads
+    (True, "adagrad", True),    # 256 columns a row there, 128 here
+])
+def test_scoring_builds_a_converter_only_where_training_has_another(
+        sparse_grads, optimizer, own):
+    tr = FMTrainer(_cfg(optimizer=optimizer), n_devices=1,
+                   sparse_grads=sparse_grads)
+    model = tr.enter_model(_params(tr))
+    assert model.T.shape == (V, 128)
+    assert (tr._score_widen is not None) == own
+    assert (tr._converters is not None) == (not own)
+    if not own:
+        # what training enters with is the same function
+        state = tr._enter(_params(tr))
+        assert np.array_equal(np.asarray(state[1]), np.asarray(model.T))
+
+
+def test_the_job_leaves_its_spans(monkeypatch):
+    _small_pieces(monkeypatch, chunk_rows=8, tile=3)
+    tr = FMTrainer(_cfg(), n_devices=1)
+    cursor = spans.take_since(0)[0]
+    tr.predict(_params(tr), *_instances(20, NNZ))
+    tr.predict(_params(tr), *_instances(20, NNZ))
+    taken = spans.take_since(cursor)[1]
+    names = [s[0] for s in taken]
+    for name, times in [("mp4j.ffm.score.enter", 2),
+                        ("mp4j.ffm.score.stage", 2),
+                        ("mp4j.ffm.score.fetch", 2),
+                        ("mp4j.ffm.score.dispatch", 6),
+                        ("mp4j.put_sharded", 2), ("mp4j.stage.prep", 2),
+                        ("mp4j.stage.send", 6), ("mp4j.stage.place", 6)]:
+        assert names.count(name) == times, name
+    stage = [s[6] for s in taken if s[0] == "mp4j.ffm.score.stage"]
+    assert [a["job"] for a in stage] == [0, 1] and stage[0]["rows"] == 20
+    dispatch = [s[6] for s in taken if s[0] == "mp4j.ffm.score.dispatch"]
+    # 20 rows in chunks of 8: the last starts at 12 and scores from 16 on
+    assert [(a["start"], a["rows"]) for a in dispatch[:3]] == [
+        (0, 8), (8, 8), (16, 4)]
+    # what crosses is the three arrays as the host holds them
+    sent = [s[6]["bytes"] for s in taken if s[0] == "mp4j.put_sharded"]
+    assert sent == [20 * 3 * NNZ * 4] * 2
+    chunks = [s[6]["bytes"] for s in taken if s[0] == "mp4j.stage.send"]
+    assert chunks == [8 * 3 * NNZ * 4] * 6
+
+
+@pytest.mark.parametrize("model", ["ffm", "fm"])
+def test_the_program_names_its_scopes_and_gathers_a_block_a_slot(model):
+    tr = FMTrainer(_cfg(model), n_devices=4)
+    shape = (4, 10, packed_width(3 * NNZ))
+    width = fm._block_width(tr._score_cfg)
+    rows, rep = tr._row_sharding(), tr._place_replicated
+    text = tr._build_score(shape, 10).lower(
+        jnp.zeros(shape, jnp.int32, device=rows),
+        rep((jnp.float32(0), jnp.zeros((V, width), jnp.float32))),
+        jnp.zeros(shape[:2], jnp.float32, device=rows),
+        np.int32(0)).as_text(debug_info=True)
+    scopes = ["ffm.table_gather", "ffm.score.pairs"]
+    if model == "ffm":
+        scopes.append("ffm.score.select")   # FM's block is its vector
+    for scope in scopes:
+        assert re.search(rf'loc\("(?:[^"]*/)?{re.escape(scope)}[/"]', text), \
+            scope
+    # one gather, of whole blocks: [tile, slots] indices, no [.., 7, 7]
+    gathers = re.findall(r'"stablehlo\.gather".*', text)
+    assert len(gathers) == 1
+    assert f"slice_sizes = array<i64: 1, {width}>" in gathers[0]
+    assert "all_reduce" not in text and "all_gather" not in text
+    assert "all_to_all" not in text and "collective_permute" not in text
+
+
+def test_the_replicated_path_is_off_the_row_form():
+    import inspect
+
+    for fn in (fm.predict, fm.score_rows, FMTrainer._build_score,
+               FMTrainer.enter_model):
+        src = inspect.getsource(fn)
+        assert "_slot_rows" not in src and "_gather_slots" not in src
+    # the sharded table keeps it
+    assert "_slot_rows" in inspect.getsource(FMTrainer._build_sharded_predict)
+
+
+def test_what_predict_refuses():
+    tr = FMTrainer(_cfg(), n_devices=1)
+    params = _params(tr)
+    feats, fields, vals = _instances(9, NNZ)
+    with pytest.raises(Mp4jError, match="alike"):
+        tr.predict(params, feats, fields[:, :3], vals)
+    with pytest.raises(Mp4jError, match="feature id out of range"):
+        tr.predict(params, np.where(feats == feats[8, 0], V, feats), fields,
+                   vals)
+    with pytest.raises(Mp4jError, match="field id out of range"):
+        tr.predict(params, feats, fields + NF, vals)
+    with pytest.raises(Mp4jError, match=r"feats must be \[N, K<=7\]"):
+        tr.predict(params, *_instances(9, NNZ + 1))
+    with pytest.raises(Mp4jError, match="a model is"):
+        tr.enter_model((params[0], params[1], params[2][:-1]))
+    sharded = FMTrainer(_cfg(), n_devices=4, sparse_grads=True,
+                        table_sharding="sharded")
+    with pytest.raises(Mp4jError, match="replicated table only"):
+        sharded.enter_model(params)
+    # and scores the params themselves, as before
+    got = sharded.predict(sharded._stage_table(params), feats, fields, vals)
+    np.testing.assert_allclose(got, tr.predict(params, feats, fields, vals),
+                               rtol=0, atol=5e-7)
+
+
+def test_instances_that_are_full_width_already_are_not_copied(monkeypatch):
+    tr = FMTrainer(_cfg(), n_devices=1)
+    feats, fields, vals = _instances(9, NNZ)
+    f, fl, v = tr._stage_instances(feats, fields, vals)
+    assert f is feats and fl is fields and v is vals
+    # and what predict hands the staging loop are views of them
+    staged = []
+    put = FMTrainer._put_in_row_chunks
+    monkeypatch.setattr(
+        FMTrainer, "_put_in_row_chunks",
+        lambda self, a, each=None: staged.extend(a) or put(self, a, each))
+    tr.predict(_params(tr), feats, fields, vals)
+    assert [a.shape for a in staged] == [(1, 9, NNZ)] * 3
+    assert all(np.shares_memory(a, b)
+               for a, b in zip(staged, (feats, fields, vals)))
+    # narrower ones are padded with empty slots
+    f, fl, v = tr._stage_instances(*_instances(9, 3))
+    assert f.shape == (9, NNZ) and (v[:, 3:] == 0).all()
+
+
+@pytest.mark.parametrize("n_devices,per,chunk_rows", [
+    (1, 20, 8), (4, 11, 4), (1, 256, 128), (4, 5, 64),
+])
+def test_a_tuple_of_arrays_rests_side_by_side(monkeypatch, n_devices, per,
+                                              chunk_rows):
+    """``_put_in_row_chunks`` with a tuple: every array crosses on its
+    own, a chunk of rows at a time, and the table holds a row's words side
+    by side (f32 as its bits), zeros up to a width of whole 8s."""
+    monkeypatch.setattr(FMTrainer, "_EACH_CHUNK_BYTES",
+                        chunk_rows * 3 * NNZ * 4)
+    tr = FMTrainer(_cfg(), n_devices=n_devices)
+    rng = np.random.default_rng(3)
+    shape = (n_devices, per, NNZ)
+    a, b = (rng.integers(0, 2 ** 31 - 1, shape).astype(np.int32)
+            for _ in range(2))
+    c = rng.standard_normal(shape).astype(np.float32)
+    seen = []
+    cursor = spans.take_since(0)[0]
+    table = tr._put_in_row_chunks(
+        (a, b, c), each=lambda t, start, stop: seen.append((start, stop)))
+    width = packed_width(3 * NNZ)
+    assert width == 24 and table.shape == (n_devices, per, width)
+    assert table.dtype == jnp.int32
+    got = np.asarray(table)
+    assert np.array_equal(got[..., :NNZ], a)
+    assert np.array_equal(got[..., NNZ:2 * NNZ], b)
+    assert np.array_equal(got[..., 2 * NNZ:3 * NNZ].view(np.float32), c)
+    assert (got[..., 3 * NNZ:] == 0).all()
+    rows = min(per, chunk_rows)
+    assert seen[0] == (0, rows) and seen[-1] == (per - rows, per)
+    assert len(seen) == -(-per // rows)
+    # one placer, built once; a second staging builds nothing
+    assert _count("mp4j.step.build", cursor) == 1
+    tr._put_in_row_chunks((a, b, c), each=lambda *args: None)
+    assert _count("mp4j.step.build", cursor) == 1

@@ -656,3 +656,114 @@ def test_adagrad_conversions_go_a_block_at_a_time(adagrad_programs, which):
     assert m.alias_size_in_bytes == 0       # the caller's arrays are kept
     sizes = sorted([m.argument_size_in_bytes, m.output_size_in_bytes])
     assert 5.26e9 < sizes[0] < 5.28e9 and 6.44e9 < sizes[1] < 6.45e9
+
+
+# ------------------------------------- the FFM scoring cell (PR 36)
+@pytest.fixture(scope="module")
+def ffm_score_programs(topo_devices):
+    """``FMTrainer.predict``'s scoring program at the size of
+    ``ffm-criteo-score.file-zipf``, a staging chunk's rows a call, on one
+    described chip and on the four of the described host; the conversion
+    that ``enter_model`` runs for a trainer whose step has another block
+    (AdaGrad's), on one; and the scoring program's build span."""
+    import json
+    from pathlib import Path
+
+    from ytk_mp4j_tpu.models._base import packed_width
+    from ytk_mp4j_tpu.models.fm import FMConfig, FMTrainer
+    from ytk_mp4j_tpu.obs import spans
+
+    c = json.loads((Path(__file__).resolve().parent.parent / "benchmark"
+                    / "configs" / "ffm-criteo-score.json").read_text())
+    cfg = FMConfig(model=c["model"], n_features=c["n_features"],
+                   n_fields=c["n_fields"], k=c["k"], max_nnz=c["max_nnz"],
+                   loss=c["loss"], optimizer="adagrad")
+    width = packed_width(3 * cfg.max_nnz)
+    out = {"config": c, "width": width}
+    for chips in (1, 4):
+        mesh = Mesh(np.asarray(topo_devices[:chips]), ("mp4j",))
+        trainer = FMTrainer(cfg, mesh=mesh, sparse_grads=True)
+        rows, rep = NamedSharding(mesh, P("mp4j")), NamedSharding(mesh, P())
+        per = -(-c["rows"] // chips)
+        chunk = (FMTrainer._EACH_CHUNK_BYTES // (3 * c["max_nnz"] * 4)
+                 // 128 * 128)
+        model = tuple(jax.ShapeDtypeStruct(shape, jnp.float32, sharding=rep)
+                      for shape in ((), (c["n_features"], 256)))
+        spans.clear()
+        program = trainer._build_score((chips, per, width), chunk)
+        out[f"built_on_{chips}"] = [s[-1] for s in spans.snapshot()
+                                    if s[0] == "mp4j.step.build"]
+        out[f"score_on_{chips}"] = program.lower(
+            jax.ShapeDtypeStruct((chips, per, width), jnp.int32,
+                                 sharding=rows), model,
+            jax.ShapeDtypeStruct((chips, per), jnp.float32, sharding=rows),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)).compile()
+        if chips == 1:
+            public = tuple(
+                jax.ShapeDtypeStruct(shape, jnp.float32, sharding=rep)
+                for shape in ((), (c["n_features"],),
+                              (trainer.n_rows, c["k"])))
+            out["enter"] = trainer._build_converters(scoring=True)[0].lower(
+                public).compile()
+            out["chunk"], out["rows"] = chunk, per
+    return out
+
+
+def test_ffm_scoring_program_holds_a_tile_beside_its_arguments(
+        ffm_score_programs):
+    from ytk_mp4j_tpu.models import fm
+
+    p = ffm_score_programs
+    rows, width, chunk = p["rows"], p["width"], p["chunk"]
+    assert (rows, width, chunk) == (6_042_135, 120, 286_720)
+    built, = p["built_on_1"]
+    assert built["key"] == "ffm_score" and built["rows"] == chunk
+    assert built["tile"] == fm._SCORE_TILE
+    assert built["tiles"] == -(-chunk // fm._SCORE_TILE)
+    assert built["block_width"] == 256      # parameters only, AdaGrad or not
+    for chips in (1, 4):
+        m = p[f"score_on_{chips}"].memory_analysis()
+        # the 4.29 GB table, a shard of the file, the probabilities
+        assert m.argument_size_in_bytes >= 2 ** 32 + rows // chips * width * 4
+        # a chunk's 286,720 rows gathered at once would be 11.5 GB
+        assert m.temp_size_in_bytes < 1e9, m.temp_size_in_bytes
+        assert m.argument_size_in_bytes + m.temp_size_in_bytes < 9e9
+        assert m.output_size_in_bytes == m.alias_size_in_bytes > 0
+
+
+def test_ffm_scoring_program_reads_file_and_table_as_they_rest(
+        ffm_score_programs):
+    """The packed rows rest as [120, N] in (8, 128) tiles and a tile is
+    sliced out of them as they lie; the table rests row-major and is
+    gathered a block a (row, slot); neither is copied."""
+    from ytk_mp4j_tpu.models import fm
+
+    p = ffm_score_programs
+    text = p["score_on_1"].as_text()
+    F, tile = p["config"]["n_features"], fm._SCORE_TILE
+    assert "s32[1,%d,%d]{1,2,0:T(8,128)} parameter(0)" % (
+        p["rows"], p["width"]) in text
+    assert "f32[%d,256]{1,0:T(8,128)} parameter(2)" % F in text
+    for opcode in ("copy", "transpose", "pad", "concatenate"):
+        assert _table_sized(text, opcode, p["rows"] * 39) == [], opcode
+    gathers = [ln for ln in text.splitlines() if " gather(" in ln]
+    assert len(gathers) == 1 and "ffm.table_gather" in gathers[0]
+    assert "f32[%d,39,256]" % tile in gathers[0]
+    assert "slice_sizes={1,256}" in gathers[0]
+    assert "ffm.score.select" in text and "ffm.score.pairs" in text
+    assert "operand_precision={highest,highest}" in text
+    assert "all-reduce" not in text and "all-gather" not in text
+    assert "input_output_alias" in text
+    four = p["score_on_4"].as_text()
+    assert "all-reduce" not in four and "all-gather" not in four
+    assert "all-to-all" not in four and "collective-permute" not in four
+
+
+def test_ffm_model_enters_a_block_at_a_time(ffm_score_programs):
+    """2.63 GB in and 4.29 GB out whatever the trainer's rule: the block
+    scoring reads has no accumulators."""
+    m = ffm_score_programs["enter"].memory_analysis()
+    assert m.temp_size_in_bytes < 0.5e9, m.temp_size_in_bytes
+    assert m.alias_size_in_bytes == 0       # the caller's table is kept
+    assert 2.6e9 < m.argument_size_in_bytes < 2.7e9
+    assert 4.29e9 < m.output_size_in_bytes < 4.35e9

@@ -114,6 +114,17 @@ class EarlyStopper:
                 and round_idx - self.best_round >= self.rounds)
 
 
+def packed_width(words: int) -> int:
+    """32-bit words in a row of a table that ``_put_in_row_chunks`` packs
+    out of several arrays: ``words`` rounded up to whole 8s. A width of
+    whole 8s rests on the TPU as [width, rows] in (8, 128) tiles, and a
+    program slices a tile of rows out of it as it lies; any other width
+    rests in (1, 128) tiles and the program copies all of it before it
+    reads a row (AOT for v5e, 6,042,135 x 117: 3.03 GB of temporaries a
+    call, none at 120)."""
+    return -(-words // 8) * 8
+
+
 class DataParallelTrainer:
     """Mesh bookkeeping + sample sharding shared by the trainers."""
 
@@ -365,47 +376,83 @@ class DataParallelTrainer:
         own shape and the host's threads tiled it; whole jobs of 8
         trees, six each in one process, 10.014 s with a quartile
         distance of 0.023 against 10.081 s and 0.093. A chunk whose
-        elements do not fill rows of 128 crosses in its own shape."""
-        n, per = a.shape[:2]
-        row = int(np.prod(a.shape[2:]))         # elements a row
+        elements do not fill rows of 128 crosses in its own shape.
+
+        A tuple ``a`` of arrays of 32-bit elements, each [n_shards, per,
+        columns], is staged as ONE table of int32 words, a row the
+        arrays' rows side by side (:func:`packed_width` words, the last
+        ones zero): every array crosses on its own, a chunk of rows at a
+        time and as the host holds it, and the placer puts the chunks'
+        rows together on the device. The host copies nothing
+        (``FMTrainer.predict`` stages ids, fields and values so: packed
+        on the host, 134 MB a chunk took it 235 ms, longer than the
+        device took to score the chunk; my chip run, PR 36)."""
+        packed = isinstance(a, tuple)
+        parts = a if packed else (a,)
+        n, per = parts[0].shape[:2]
+        cols = [int(np.prod(p.shape[2:])) for p in parts]
+        row = sum(cols)                         # elements a row
         chunk_bytes = (self._CHUNK_BYTES if each is None
                        else self._EACH_CHUNK_BYTES)
-        rows = max(1, min(per, chunk_bytes // (row * a.itemsize)))
+        rows = max(1, min(per, chunk_bytes // (row * parts[0].itemsize)))
         if rows >= 128:
             rows -= rows % 128                  # whole rows of 128 lanes
-        shape = (n, rows) + a.shape[2:]
-        wire = ((n, rows * row // 128, 128) if rows * row % 128 == 0
-                else shape)
+        shapes = [(n, rows) + p.shape[2:] for p in parts]
+        wires = [(n, rows * c // 128, 128) if rows * c % 128 == 0 else shape
+                 for c, shape in zip(cols, shapes)]
+        shape = shapes[0]
         sharding = self._row_sharding()
+        if packed:
+            width = packed_width(row)
+            table_shape, dtype = (n, per, width), np.dtype(np.int32)
+            key = (table_shape, tuple(p.dtype.str for p in parts), rows)
+        else:
+            table_shape, dtype = a.shape, a.dtype
+            key = (a.shape, a.dtype.str, rows)
         # one program a (table, chunk) shape, kept with the trainer: a
         # job after the first builds nothing
-        key = (a.shape, a.dtype.str, rows)
         place = self._row_placers.get(key)
         if place is None:
-            def place(table, chunk, start):
-                at = [jnp.zeros((), start.dtype)] * table.ndim
-                at[1] = start
-                return (jax.lax.dynamic_update_slice(
-                    table, chunk.reshape(shape), at), chunk.reshape(-1)[0])
+            if packed:
+                def place(table, chunks, start):
+                    words = [jax.lax.bitcast_convert_type(
+                        c.reshape(n, rows, -1), jnp.int32) for c in chunks]
+                    words.append(jnp.zeros((n, rows, width - row), jnp.int32))
+                    at = [jnp.zeros((), start.dtype)] * table.ndim
+                    at[1] = start
+                    return (jax.lax.dynamic_update_slice(
+                        table, jnp.concatenate(words, axis=2), at),
+                        chunks[0].reshape(-1)[0])
+            else:
+                def place(table, chunk, start):
+                    at = [jnp.zeros((), start.dtype)] * table.ndim
+                    at[1] = start
+                    return (jax.lax.dynamic_update_slice(
+                        table, chunk.reshape(shape), at),
+                        chunk.reshape(-1)[0])
 
             with spans.span("mp4j.step.build", key="row_placer",
                             rows=rows):
                 place = self._row_placers[key] = jax.jit(
                     place, donate_argnums=0,
                     out_shardings=(sharding, None))
-        table = jnp.zeros(a.shape, a.dtype, device=sharding)
+        table = jnp.zeros(table_shape, dtype, device=sharding)
         placed, crossing = [], []
         ahead = 2 if each is None else self._CHUNKS_AHEAD
         for k, start in enumerate(range(0, per, rows)):
             # the last chunk is as long as the others: it starts early
             # and rewrites rows the chunk before it already placed
             start = min(start, per - rows)
-            chunk = a[:, start:start + rows]
-            with spans.span("mp4j.stage.send", chunk=k, bytes=chunk.nbytes):
-                dchunk = jax.make_array_from_callback(
-                    wire, sharding,
-                    lambda idx, chunk=chunk: chunk[idx[0]].reshape(
-                        (-1,) + wire[1:]))
+            chunks = [p[:, start:start + rows] for p in parts]
+            with spans.span("mp4j.stage.send", chunk=k,
+                            bytes=sum(c.nbytes for c in chunks)):
+                dchunks = tuple(
+                    jax.make_array_from_callback(
+                        wire, sharding,
+                        lambda idx, chunk=chunk, wire=wire: chunk[
+                            idx[0]].reshape((-1,) + wire[1:]))
+                    for chunk, wire in zip(chunks, wires))
+            dchunk = dchunks if packed else dchunks[0]
             with spans.span("mp4j.stage.place", chunk=k):
                 table, done = place(table, dchunk, np.int32(start))
             placed.append(done)

@@ -52,12 +52,20 @@ Model scores (order-2, sigmoid/logloss for classification):
   O(K k) sum-of-squares identity.
 - FFM: ``v`` is per (feature, field): ``sum_{a<b} <v_{a, field_b},
   v_{b, field_a}> x_a x_b`` over K^2 slot pairs (K = max_nnz, static).
+
+Scoring a file (``FMTrainer.predict`` on a replicated table) reads the
+same table by feature, parameters only (``FMTrainer.enter_model``
+converts the public params once a model): :func:`score_rows` under
+``shard_map``, rows sharded and no collective, a tile of rows at a time,
+over instances that ``_put_in_row_chunks`` stages a chunk of rows at a
+time and that are scored as they cross.
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from functools import partial
+from typing import NamedTuple
 
 import numpy as np
 
@@ -297,8 +305,9 @@ def _slot_rows(feats, fields, cfg: FMConfig):
     FM touches row ``feat`` per slot ([N, K]); FFM touches row
     ``feat * n_fields + field_b`` per slot PAIR ([N, K, K]) — matching
     the [N, K(, K), k] layout of ``_score``'s gathers. The dense step,
-    the sharded step and ``predict`` index this way; the replicated
-    sparse step indexes by feature (:func:`_gather_blocks`).
+    the sharded step and the sharded table's ``predict`` index this way;
+    the replicated sparse step and the replicated table's ``predict``
+    index by feature (:func:`_gather_blocks`).
     """
     if cfg.model == "fm":
         return feats
@@ -712,11 +721,96 @@ def train_step_sparse_sharded(params, batch, cfg: FMConfig, n: int,
     return (w0, w, Vs), loss
 
 
-def predict(params, feats, fields, vals, mask, cfg: FMConfig):
-    z = _score(params, feats, fields, vals, mask, cfg)
-    if cfg.loss == "logistic":
-        return jax.nn.sigmoid(z)
-    return z
+def predict(state, feats, fields, vals, cfg: FMConfig):
+    """What the model says of a batch of padded sparse instances, from
+    the entered ``(w0, T)`` (the table by feature, a parameters-only
+    block a row: :func:`_scoring_cfg`): the probability under the
+    logistic loss, else the score. A padded slot carries the value 0,
+    so ``vals`` is its own mask. One descriptor a (row, slot) brings the
+    feature's block (:func:`_gather_blocks`); the row form's
+    (:func:`_score`) gather is a descriptor a slot PAIR."""
+    w0, T = state
+    blk = _gather_blocks(T, feats)
+    with jax.named_scope("ffm.score.select"):
+        wv, E = _select_fields(blk, fields, cfg)
+    with jax.named_scope("ffm.score.pairs"):
+        z = _score_from_slots(w0, wv, E, vals, cfg)
+        return jax.nn.sigmoid(z) if cfg.loss == "logistic" else z
+
+
+# Rows :func:`score_rows` takes through :func:`predict` a trip of its
+# loop. Swept on the chip as ``_UPDATE_TILE`` was: the scoring program
+# alone on one staged chunk of 286,720 rows at the Criteo cell's shape
+# (39 slots, 39 fields x 4, the 4.29 GB table), 26 tiles from 64 to 4,096,
+# ms a chunk, the best of four (my chip runs, PR 36; PERF.md section 5):
+# 64: 224.8, 256: 199.0, 384: 191.5, 512: 203.2, 768: 192.4, 1,024: 237.5,
+# 1,152: 192.5, 1,280: 199.4, 1,408: 204.0, 1,536: 158.0, 1,664: 167.7,
+# 1,792: 174.6, 1,920: 187.4, 2,048: 221.0, 2,304: 180.3, 2,560: 184.9,
+# 3,072: 254.3, 3,584: 180.2, 4,096: 255.8. Up to 1,408 XLA keeps a
+# tile's temporaries out of HBM and the chunk takes 191-204 ms whatever
+# the tile (a trip's own cost shows under 128); from 1,536 on it holds
+# them in HBM (63 MB there) and the gather runs a third faster, less so
+# the larger the tile; whole multiples of 1,024 lose 20% either way.
+_SCORE_TILE = 1536
+
+
+def _score_tile(rows: int) -> int:
+    """The tile of :func:`score_rows`'s loop for a call of ``rows``."""
+    return min(_SCORE_TILE, rows)
+
+
+def score_rows(packed, state, out, start, rows: int, cfg: FMConfig):
+    """Score ``rows`` rows of this shard from row ``start`` on and write
+    what :func:`predict` says of them into ``out`` [N] f32 (the other
+    rows are passed on). ``packed`` [N, ``packed_width(3 * max_nnz)``] holds a
+    row's feature ids, its field ids and the bits of its f32 values as
+    32-bit words side by side (``_put_in_row_chunks`` packs them so),
+    ``state`` is the entered ``(w0, T)``.
+
+    The rows go through in tiles of :func:`_score_tile`, so that what
+    the program holds beside its arguments is a tile's and not the
+    call's (a staging chunk of 286,720 rows would gather 11.5 GB of
+    blocks). The last tile is as long as the others: it starts early and
+    scores rows again that the one before it scored, to the same bits (a
+    row's score takes nothing from its neighbours)."""
+    K = cfg.max_nnz
+    tile = _score_tile(rows)
+
+    def score_tile(c, out):
+        at = start + jnp.minimum(c * tile, rows - tile)
+        part = lax.dynamic_slice(packed, (at, jnp.zeros((), at.dtype)),
+                                 (tile, packed.shape[1]))
+        vals = lax.bitcast_convert_type(part[:, 2 * K:3 * K], jnp.float32)
+        p = predict(state, part[:, :K], part[:, K:2 * K], vals, cfg)
+        return lax.dynamic_update_slice(out, p, (at,))
+
+    # the loop itself is under no scope: gather, select and pairs are
+    # read apart in a device trace (PERF.md section 3)
+    return lax.fori_loop(0, -(-rows // tile), score_tile, out)
+
+
+def _scoring_cfg(cfg: FMConfig) -> FMConfig:
+    """The configuration whose block is what scoring reads: parameters
+    only, whatever the rule that trained them ([n_features, 256] at 39
+    fields x 4; AdaGrad's step carries 384, its accumulators beside the
+    parameters, and no score reads those)."""
+    return replace(cfg, optimizer="sgd")
+
+
+class EnteredModel(NamedTuple):
+    """A model as ``FMTrainer.predict`` scores it, on the trainer's
+    mesh: the bias and the table by feature (:func:`_scoring_cfg`'s
+    block a row, the linear weight in its last column).
+    ``FMTrainer.enter_model`` makes one from the public ``(w0, w, V)``;
+    whoever scores many files with one model holds on to it."""
+    w0: jax.Array
+    T: jax.Array
+
+
+def _live_mask(vals: np.ndarray) -> np.ndarray:
+    """1.0 for a slot that holds a feature: a padded slot carries the
+    value 0."""
+    return (vals != 0).astype(np.float32)
 
 
 class FMTrainer(DataParallelTrainer):
@@ -781,6 +875,13 @@ class FMTrainer(DataParallelTrainer):
         self._converters = None   # (widen, narrow), built on first use
         self._eval_fn = None
         self._pred_fn = None      # sharded serve (jit retraces by shape)
+        # scoring (``predict`` on a replicated table): the block it reads,
+        # the conversion into it where the step's is another, the
+        # programs by (staged shape, rows a call), predict() calls so far
+        self._score_cfg = _scoring_cfg(cfg)
+        self._score_widen = None
+        self._score_programs = {}
+        self._score_jobs = 0
         self.eval_history_: list[float] = []
 
     @property
@@ -859,9 +960,11 @@ class FMTrainer(DataParallelTrainer):
             jax.ShapeDtypeStruct(shape, jnp.float32, sharding=rep)
             for shape in shapes + ((),) * self._adagrad)
 
-    def _build_converters(self):
+    def _build_converters(self, scoring: bool = False):
         """``(widen, narrow)``: public ``(w0, w, V)`` -> the step's own
-        ``(w0, T)`` and back, for the replicated sparse step. Both return
+        ``(w0, T)`` and back, for the replicated sparse step
+        (``scoring``: into and out of the parameters-only block that
+        ``predict`` reads, :func:`_scoring_cfg`). Both return
         new buffers (the step donates its state, never the caller's
         arrays). The linear weights go into their column of the blocks
         (:func:`_weight_column`) and come back out of it. The FFM table
@@ -873,7 +976,8 @@ class FMTrainer(DataParallelTrainer):
         fresh ones (``adagrad_init`` beside every parameter, made in the
         blocks: no table of ones is held), and lays them into the
         blocks' second halves; ``narrow`` gives ``(params, opt)``."""
-        cfg = self.cfg
+        cfg = self._score_cfg if scoring else self.cfg
+        adagrad = cfg.optimizer == "adagrad"
         k, F = cfg.k, cfg.n_features
         nf = self.n_rows // F       # vectors a block: n_fields, or FM's one
         width, stride = _block_width(cfg), _block_stride(cfg)
@@ -882,7 +986,7 @@ class FMTrainer(DataParallelTrainer):
         if B >= 128:
             B -= B % 128        # starts on whole lane tiles of the public table
         n_blocks = -(-F // B)
-        if self._adagrad:
+        if adagrad:
             fresh = jnp.asarray(np.float32(cfg.adagrad_init)
                                 * _field_columns(cfg).max(axis=0))
 
@@ -930,10 +1034,10 @@ class FMTrainer(DataParallelTrainer):
             if nf == 1:
                 return jnp.copy(w0), jnp.concatenate([V, w[:, None]], axis=1)
             pairs = ((w, V),)
-            if self._adagrad:
+            if adagrad:
                 pairs += (None if opt is None else opt[1:],)
             T = blockwise(pairs, jnp.zeros((F, width), V.dtype), to_blocks)
-            if not self._adagrad:
+            if not adagrad:
                 return jnp.copy(w0), T
             a0 = (jnp.full((), cfg.adagrad_init, w0.dtype) if opt is None
                   else jnp.copy(opt[0]))
@@ -944,9 +1048,9 @@ class FMTrainer(DataParallelTrainer):
             if nf == 1:
                 return jnp.copy(w0), T[:, wcol], T[:, :k]
             empty = (jnp.zeros((F,), T.dtype), jnp.zeros((F * nf, k), T.dtype))
-            pairs = blockwise(T, (empty,) * (1 + self._adagrad), to_rows)
+            pairs = blockwise(T, (empty,) * (1 + adagrad), to_rows)
             params = (jnp.copy(w0), *pairs[0])
-            if not self._adagrad:
+            if not adagrad:
                 return params
             return params, (jnp.copy(state[2]), *pairs[1])
 
@@ -1080,13 +1184,10 @@ class FMTrainer(DataParallelTrainer):
                         **build_args):
             return jax.jit(step, **jit_args)
 
-    def _check_instances(self, feats: np.ndarray, fields: np.ndarray):
+    def _check_ids(self, feats: np.ndarray, fields: np.ndarray):
         """Shared id-range validation for fit and predict inputs (JAX
         gathers clamp out-of-range indices silently, so bad ids must be
         rejected on the host)."""
-        if feats.ndim != 2 or feats.shape[1] > self.cfg.max_nnz:
-            raise Mp4jError(
-                f"feats must be [N, K<={self.cfg.max_nnz}], got {feats.shape}")
         if (feats.min(initial=0) < 0
                 or feats.max(initial=0) >= self.cfg.n_features):
             raise Mp4jError("feature id out of range")
@@ -1105,8 +1206,8 @@ class FMTrainer(DataParallelTrainer):
         the weight sum, so integer weights train exactly like row
         duplication) and composes with the padding zeros."""
         y = np.asarray(y, np.float32)
-        feats, fields, vals, mask = self._stage_instances(feats, fields,
-                                                          vals)
+        feats, fields, vals = self._stage_instances(feats, fields, vals)
+        mask = _live_mask(vals)
         N = feats.shape[0]
         (feats, fields, vals, mask, y), per, sw = self._pad_rows(
             [feats, fields, vals, mask, y])
@@ -1243,8 +1344,8 @@ class FMTrainer(DataParallelTrainer):
         feats, fields, vals, y = chunk[:4]
         weights = chunk[4] if len(chunk) > 4 else None
         y = np.asarray(y, np.float32)
-        feats, fields, vals, mask = self._stage_instances(
-            feats, fields, vals)
+        feats, fields, vals = self._stage_instances(feats, fields, vals)
+        mask = _live_mask(vals)
         if batch_rows is None:
             batch_rows = (-(-feats.shape[0] // self.n_shards)
                           * self.n_shards)
@@ -1256,29 +1357,39 @@ class FMTrainer(DataParallelTrainer):
                         for a in (feats, fields, vals, mask, y, sw))
         return (sharded, per * self.cfg.max_nnz), batch_rows
 
-    def _stage_instances(self, feats, fields, vals):
+    def _stage_instances(self, feats, fields, vals, check_ids: bool = True):
         """The one staging path for padded-sparse instances: validate id
-        ranges, pad the slot axis to max_nnz, derive the nonzero mask
-        (padded slots carry value 0). Shared by shard_data, predict and
-        eval so the padding convention cannot drift between them."""
+        ranges and pad the slot axis to max_nnz (padded slots carry value
+        0, which is what :func:`_live_mask` reads). Shared by shard_data,
+        predict and eval so the padding convention cannot drift between
+        them. Arrays that are int32 / float32 and ``max_nnz`` wide
+        already come back as they are, not copied. ``check_ids=False``
+        leaves the ids' ranges to the caller (``predict`` validates a
+        staging chunk at a time, :meth:`_check_ids`)."""
         feats = np.asarray(feats, np.int32)
         fields = np.asarray(fields, np.int32)
         vals = np.asarray(vals, np.float32)
-        self._check_instances(feats, fields)
+        if feats.shape != fields.shape or feats.shape != vals.shape:
+            raise Mp4jError(
+                "feats, fields and vals must be [N, K] alike, got "
+                f"{feats.shape}, {fields.shape}, {vals.shape}")
+        if feats.ndim != 2 or feats.shape[1] > self.cfg.max_nnz:
+            raise Mp4jError(
+                f"feats must be [N, K<={self.cfg.max_nnz}], got {feats.shape}")
+        if check_ids:
+            self._check_ids(feats, fields)
         padK = self.cfg.max_nnz - feats.shape[1]
         if padK:
             zK = ((0, 0), (0, padK))
             feats, fields, vals = (np.pad(feats, zK), np.pad(fields, zK),
                                    np.pad(vals, zK))
-        mask = (vals != 0).astype(np.float32)
-        return feats, fields, vals, mask
+        return feats, fields, vals
 
     def _prep_eval(self, feats, fields, vals, y):
         """Pad + stage a held-out batch once for per-step evaluation."""
-        feats, fields, vals, mask = self._stage_instances(feats, fields,
-                                                          vals)
+        feats, fields, vals = self._stage_instances(feats, fields, vals)
         return (jnp.asarray(feats), jnp.asarray(fields),
-                jnp.asarray(vals), jnp.asarray(mask),
+                jnp.asarray(vals), jnp.asarray(_live_mask(vals)),
                 jnp.asarray(np.asarray(y, np.float32)))
 
     def _eval_loss(self, params, va, score=_score) -> float:
@@ -1326,14 +1437,96 @@ class FMTrainer(DataParallelTrainer):
 
         return jax.jit(run)
 
-    def predict(self, params, feats, fields, vals):
-        feats, fields, vals, mask = self._stage_instances(feats, fields,
-                                                          vals)
+    def enter_model(self, params) -> EnteredModel:
+        """Public ``(w0, w, V)`` -> the model as ``predict`` scores it,
+        on the trainer's mesh: the bias and the table by feature, a
+        parameters-only block a row whatever ``cfg.optimizer`` is
+        ([n_features, 256] at 39 fields x 4; accumulators never enter).
+        ``predict`` takes the result in place of the params, so a caller
+        who scores many files with one model converts it once (TPU v5
+        lite, the 2.62 GB table: 0.06 s of device time, and both tables
+        side by side while it runs). New buffers: the caller's arrays
+        stay as they were. Span ``mp4j.ffm.score.enter``."""
         if self.table_sharding == "sharded":
+            raise Mp4jError(
+                "a sharded table is scored where it rests, a row a slot "
+                "pair (predict takes the params themselves); a model "
+                "enters for the replicated table only")
+        w0, w, V = params
+        cfg = self.cfg
+        shapes = [tuple(np.shape(a)) for a in (w0, w, V)]
+        if shapes != [(), (cfg.n_features,), (self.n_rows, cfg.k)]:
+            raise Mp4jError(
+                f"a model is (w0 [], w [n_features={cfg.n_features}], "
+                f"V [n_rows={self.n_rows}, k={cfg.k}]), got {shapes}")
+        with spans.span("mp4j.ffm.score.enter"):
+            if self._blocks and not self._adagrad:
+                # the SGD step's own block is the one scoring reads
+                if self._converters is None:
+                    self._converters = self._build_converters()
+                widen = self._converters[0]
+            else:
+                if self._score_widen is None:
+                    self._score_widen = self._build_converters(
+                        scoring=True)[0]
+                widen = self._score_widen
+            return EnteredModel(*jax.block_until_ready(
+                widen(self._place_replicated((w0, w, V)))))
+
+    def _build_score(self, shape, rows: int):
+        """The scoring program for packed instances staged as ``shape``
+        ([n_shards, rows a shard, ``packed_width(3 * max_nnz)``]), ``rows``
+        rows of every shard a call: :func:`score_rows` under ``shard_map``,
+        rows sharded, the entered model replicated, no collective. It takes
+        (instances, model, probabilities [n_shards, rows a shard], first
+        row) and returns the probabilities, donated, with those rows
+        filled in."""
+        cfg = self._score_cfg
+        axes = self.axes
+
+        @partial(jax.shard_map, mesh=self.mesh, check_vma=False,
+                 in_specs=(P(axes), P(), P(axes), P()), out_specs=P(axes))
+        def score(packed, model, out, start):
+            return score_rows(packed[0], model, out[0], start, rows,
+                              cfg)[None]
+
+        tile = _score_tile(rows)
+        with spans.span("mp4j.step.build", key="ffm_score", rows=rows,
+                        tile=tile, tiles=-(-rows // tile),
+                        block_width=_block_width(cfg)):
+            return jax.jit(score, donate_argnums=2)
+
+    def predict(self, params, feats, fields, vals):
+        """What the model says of padded-sparse instances: a probability
+        a row under the logistic loss, else the score; [N] f32, in the
+        rows' order. ``params`` is the public ``(w0, w, V)``, or the
+        :class:`EnteredModel` that ``enter_model`` made of it (a
+        replicated table; whoever scores more than one file with a model
+        enters it once).
+
+        On a replicated table the instances are staged as ``fit``'s are,
+        rows padded to whole shards and sharded over the trainer's mesh,
+        and cross in row chunks as the host holds them, ids, fields and
+        values each on its own (``_put_in_row_chunks`` puts them side by
+        side on the device; arrays that are int32 / float32 and full
+        width are not copied on the host; ids are validated a chunk at a
+        time, while the chunks before are scored); one jitted
+        ``shard_map`` program (:func:`score_rows`) scores each chunk as
+        soon as it is in place, while the next ones cross, a tile of rows
+        at a time and one gather descriptor a (row, slot); the
+        probabilities are written into one donated array and fetched
+        once. The programs are kept by (staged shape, rows a call): a
+        repeated ``predict`` of the same shape builds nothing. Spans
+        ``mp4j.ffm.score.stage`` / ``dispatch`` / ``fetch``.
+
+        A sharded table keeps its own program
+        (``_build_sharded_predict``, the row form; ROADMAP R1a's)."""
+        if self.table_sharding == "sharded":
+            feats, fields, vals = self._stage_instances(feats, fields, vals)
             params = self._stage_table(params)
             N = feats.shape[0]
             (f, fl, v, m), per, _sw = self._pad_rows(
-                [feats, fields, vals, mask])
+                [feats, fields, vals, _live_mask(vals)])
             if self._pred_fn is None:
                 self._pred_fn = self._build_sharded_predict()
             staged = [self._put_sharded(a, per) for a in (f, fl, v, m)]
@@ -1343,9 +1536,45 @@ class FMTrainer(DataParallelTrainer):
             # must call predict together there
             out = self._to_host(self._pred_fn(params, *staged))
             return out.reshape(-1)[:N]
-        return np.asarray(predict(params, jnp.asarray(feats),
-                                  jnp.asarray(fields), jnp.asarray(vals),
-                                  jnp.asarray(mask), self.cfg))
+        model = (params if isinstance(params, EnteredModel)
+                 else self.enter_model(params))
+        feats, fields, vals = self._stage_instances(feats, fields, vals,
+                                                    check_ids=False)
+        N = feats.shape[0]
+        if not N:
+            return np.zeros(0, np.float32)
+        job, self._score_jobs = self._score_jobs, self._score_jobs + 1
+        probs = None                # the device's, as last returned
+        scored = 0                  # rows of a shard scored so far
+
+        def score(table, start: int, stop: int):
+            nonlocal probs, scored
+            # the last chunk of a staging starts early, over rows that
+            # the one before it brought: those are done
+            start, scored = max(start, scored), stop
+            # on its way already, and not scored if an id is out of range
+            self._check_ids(parts[0][:, start:stop], parts[1][:, start:stop])
+            key = (table.shape, stop - start)
+            program = self._score_programs.get(key)
+            if program is None:
+                program = self._score_programs[key] = self._build_score(*key)
+            with spans.span("mp4j.ffm.score.dispatch", job=job,
+                            rows=stop - start, start=start):
+                if probs is None:
+                    probs = jnp.zeros(table.shape[:2], jnp.float32,
+                                      device=self._row_sharding())
+                probs = program(table, tuple(model), probs, np.int32(start))
+
+        with spans.span("mp4j.ffm.score.stage", job=job, rows=N):
+            arrays, per, _sw = self._pad_rows([feats, fields, vals])
+            parts = tuple(a.reshape(self.n_shards, per, -1) for a in arrays)
+            with spans.span("mp4j.put_sharded",
+                            bytes=sum(a.nbytes for a in parts)):
+                self._put_in_row_chunks(parts, each=score)
+        with spans.span("mp4j.ffm.score.fetch", job=job):
+            # _to_host: a collective fetch on multi-process meshes, where
+            # every process calls predict together
+            return self._to_host(probs).reshape(-1)[:N]
 
 
 # ----------------------------------------------------------------------
