@@ -189,13 +189,15 @@ def test_a_field_no_row_has_keeps_its_vectors_bit_for_bit(rng, n_shards):
 
 
 @pytest.mark.parametrize("fields", [
-    [[0, 1, 2]], [[2, 2, 0]], [[4, 4, 4]], [[3, 0, 3], [1, 1, 2]]],
-    ids=["distinct", "twice", "all_one", "two_rows"])
+    [[0, 1, 2]], [[2, 2, 0]], [[4, 4, 4]], [[3, 0, 3], [1, 1, 2]],
+    [[3, 0, 4, 1, 2, 0]]],
+    ids=["distinct", "twice", "all_one", "two_rows", "all_and_one_again"])
 def test_field_select_is_exact_and_its_transpose_adds(rng, fields):
-    """``E[n, a, b]`` is bit for bit ``blk[n, a, fields[n, b]]`` and
-    ``wv[n, a]`` the block's last column, and the gradient comes back
-    summed over the slots of a field, the weight's in its column, 0.0
-    elsewhere."""
+    """``E[n, a, j, b]`` is bit for bit ``blk[n, a, j * stride +
+    fields[n, b]]`` ([N, K, k, K]: component by component, never the k
+    components last) and ``wv[n, a]`` the block's last column, and the
+    gradient comes back summed over the slots of a field, the weight's
+    in its column, 0.0 elsewhere."""
     cfg = _cfg("ffm")
     fields = np.asarray(fields, np.int32)
     N, K = fields.shape
@@ -207,22 +209,25 @@ def test_field_select_is_exact_and_its_transpose_adds(rng, fields):
     stride = fm_mod._block_stride(cfg)
     np.testing.assert_array_equal(np.asarray(wv).view(np.uint32),
                                   blk[:, :, -1].view(np.uint32))
+    assert E.shape == (N, K, KDIM, K)
+    want = np.empty(E.shape, np.float32)
+    for n in range(N):
+        for j in range(KDIM):
+            want[n, :, j, :] = blk[n][:, j * stride + fields[n]]
+    np.testing.assert_array_equal(np.asarray(E).view(np.uint32),
+                                  want.view(np.uint32))
 
     def by_field(a):            # [N, K, width] -> [N, K, n_fields, k]
         return a[:, :, :KDIM * stride].reshape(N, K, KDIM, stride)[
             ..., :NFIELDS].transpose(0, 1, 3, 2)
 
-    b4 = by_field(blk)
-    want = np.stack([b4[n][:, fields[n]] for n in range(N)])
-    np.testing.assert_array_equal(np.asarray(E).view(np.uint32),
-                                  want.view(np.uint32))
     gE = rng.standard_normal(E.shape).astype(np.float32)
     gw = rng.standard_normal(wv.shape).astype(np.float32)
     (gblk,) = back((jnp.asarray(gw), jnp.asarray(gE)))
     want_g = np.zeros((N, K, NFIELDS, KDIM), np.float64)
     for n in range(N):
         for b in range(K):
-            want_g[n, :, fields[n, b]] += gE[n, :, b]
+            want_g[n, :, fields[n, b]] += gE[n, :, :, b]
     gblk = np.asarray(gblk)
     np.testing.assert_allclose(gblk[:, :, -1], gw, rtol=1e-6, atol=0)
     nonzero = np.count_nonzero(gblk) - gw.size
@@ -232,6 +237,49 @@ def test_field_select_is_exact_and_its_transpose_adds(rng, fields):
     lacking = np.ones((N, NFIELDS), bool)
     lacking[np.arange(N)[:, None], fields] = False
     assert (gblk.transpose(0, 2, 1, 3)[lacking] == 0.0).all()
+
+
+@pytest.mark.parametrize("case", ["plain", "field_twice", "fields_absent",
+                                  "padded_slots", "k_below_n_fields"])
+def test_the_pairs_on_the_component_form_are_the_float64_score(rng, case):
+    """``_score_from_slots``'s FFM branch reads ``E[n, a, j, b]``
+    ([N, K, k, K]): what ``_select_fields`` hands it out of the blocks
+    and what the row form gathers ([N, K, K, k]) after its one
+    ``moveaxis`` are the same array, and either scores as the model's
+    definition does in float64, pair by pair."""
+    cfg = _cfg("ffm")
+    feats, fields, vals, _ = _instances(rng, case)
+    N, K = feats.shape
+    w0, w, V = _start(cfg, rng)
+    rows = fm_mod._slot_rows(jnp.asarray(feats), jnp.asarray(fields), cfg)
+    by_row = np.asarray(V)[np.asarray(rows)]                # [N, K, K, k]
+    assert by_row.shape == (N, K, K, KDIM)
+    E = fm_mod._by_component(jnp.asarray(by_row), cfg)
+    assert E.shape == (N, K, KDIM, K)
+    # the block form of the same table gives the same E, bit for bit
+    stride, width = fm_mod._block_stride(cfg), fm_mod._block_width(cfg)
+    T = np.zeros((NFEAT, width), np.float32)
+    for j in range(KDIM):
+        T[:, j * stride:j * stride + NFIELDS] = np.asarray(V).reshape(
+            NFEAT, NFIELDS, KDIM)[:, :, j]
+    T[:, -1] = w
+    wv, from_blocks = fm_mod._select_fields(jnp.asarray(T[feats]),
+                                            jnp.asarray(fields), cfg)
+    np.testing.assert_array_equal(np.asarray(from_blocks).view(np.uint32),
+                                  np.asarray(E).view(np.uint32))
+    np.testing.assert_array_equal(np.asarray(wv), np.asarray(w)[feats])
+    got = np.asarray(fm_mod._score_from_slots(
+        jnp.float32(w0), wv, E, jnp.asarray(vals), cfg))
+    want = np.full(N, np.float64(w0))
+    V64, x = np.asarray(V, np.float64), vals.astype(np.float64)
+    for n in range(N):
+        want[n] += np.sum(np.asarray(w, np.float64)[feats[n]] * x[n])
+        for a in range(K):
+            for b in range(a + 1, K):
+                ra = feats[n, a] * NFIELDS + fields[n, b]
+                rb = feats[n, b] * NFIELDS + fields[n, a]
+                want[n] += V64[ra] @ V64[rb] * x[n, a] * x[n, b]
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
 
 
 @pytest.mark.parametrize("model", ["ffm", "fm"])

@@ -35,15 +35,31 @@ def _params(tr, seed=0):
                 np.float32)))
 
 
-def _instances(n, slots, seed=1):
+def _instances(n, slots, seed=1, how="drawn"):
     """``n`` rows of ``slots`` slots: some slots empty, row 2 (where
-    there is one) all zeros."""
+    there is one) all zeros. ``how`` says which field a slot holds:
+    ``drawn`` at random; ``shuffled`` another permutation of the fields
+    a row (never ``0..slots-1`` in order throughout); ``twice`` the
+    permutation with its first field again in the slot after it;
+    ``missing`` fields 0 and 3 only; ``padded`` the permutation with
+    every slot from the third on empty."""
     rng = np.random.default_rng(seed)
     feats = rng.integers(0, V, (n, slots)).astype(np.int32)
-    fields = rng.integers(0, NF, (n, slots)).astype(np.int32)
+    if how in ("drawn", "missing"):
+        fields = (rng.integers(0, NF, (n, slots)) if how == "drawn"
+                  else rng.choice([0, 3], (n, slots))).astype(np.int32)
+    else:
+        assert slots <= NF
+        fields = np.stack([rng.permutation(NF)[:slots]
+                           for _ in range(n)]).astype(np.int32)
+        assert (fields != np.arange(slots)).any(axis=1).sum() > n // 2
     vals = rng.standard_normal((n, slots)).astype(np.float32)
     vals[rng.random((n, slots)) < 0.2] = 0.0
     vals[2:3] = 0.0
+    if how == "twice":
+        fields[:, 1] = fields[:, 0]
+    elif how == "padded":
+        vals[:, 2:] = 0.0
     return feats, fields, vals
 
 
@@ -86,25 +102,33 @@ def _logit(p):
     return np.log(p) - np.log1p(-p)
 
 
-@pytest.mark.parametrize("model,optimizer,n_devices,rows,slots", [
-    ("ffm", "sgd", 1, 37, 7),       # 37: no whole tile, no whole chunk
-    ("ffm", "sgd", 4, 37, 5),       # no whole shard; slots padded to 7
-    ("ffm", "sgd", 4, 64, 7),       # whole shards, whole chunks and tiles
-    ("ffm", "adagrad", 1, 33, 6),   # the accumulators must not enter
-    ("ffm", "adagrad", 4, 41, 7),
-    ("fm", "sgd", 1, 37, 7),
-    ("fm", "sgd", 4, 30, 4),
-    ("ffm", "sgd", 1, 1, 7),        # one row
-    ("ffm", "sgd", 4, 3, 2),        # fewer rows than shards
+@pytest.mark.parametrize("model,optimizer,n_devices,rows,slots,how", [
+    ("ffm", "sgd", 1, 37, 7, "drawn"),      # no whole tile, no whole chunk
+    ("ffm", "sgd", 4, 37, 5, "drawn"),      # no whole shard; slots padded
+    ("ffm", "sgd", 4, 64, 7, "drawn"),      # whole shards, chunks, tiles
+    ("ffm", "adagrad", 1, 33, 6, "drawn"),  # accumulators must not enter
+    ("ffm", "adagrad", 4, 41, 7, "drawn"),
+    ("fm", "sgd", 1, 37, 7, "drawn"),
+    ("fm", "sgd", 4, 30, 4, "drawn"),
+    ("ffm", "sgd", 1, 1, 7, "drawn"),       # one row
+    ("ffm", "sgd", 4, 3, 2, "drawn"),       # fewer rows than shards
+    # the select is a contraction whatever field a slot holds: no row
+    # here has fields 0..slots-1 in order
+    ("ffm", "sgd", 1, 37, 5, "shuffled"),
+    ("ffm", "sgd", 4, 37, 5, "shuffled"),
+    ("ffm", "sgd", 1, 37, 5, "twice"),
+    ("ffm", "sgd", 1, 37, 7, "missing"),
+    ("ffm", "sgd", 4, 37, 4, "padded"),
+    ("ffm", "adagrad", 1, 33, 5, "twice"),
 ])
 def test_predict_is_the_float64_score_and_the_row_form(
-        monkeypatch, model, optimizer, n_devices, rows, slots):
+        monkeypatch, model, optimizer, n_devices, rows, slots, how):
     _small_pieces(monkeypatch, chunk_rows=8, tile=3)
     cfg = _cfg(model, optimizer)
     blocks = optimizer == "adagrad"
     tr = FMTrainer(cfg, n_devices=n_devices, sparse_grads=blocks)
     params = _params(tr)
-    feats, fields, vals = _instances(rows, slots)
+    feats, fields, vals = _instances(rows, slots, how=how)
     if blocks:
         # parameters that the rule itself made, accumulators beside them
         y = (np.arange(rows) % 2).astype(np.float32)
@@ -138,6 +162,34 @@ def test_chunks_and_tiles_change_no_bit(monkeypatch, model, n_devices,
     got = tr.predict(params, feats, fields, vals)
     assert len(tr._score_programs) > built      # other programs ran
     assert got.tobytes() == whole.tobytes()
+
+
+@pytest.mark.parametrize("tile", [fm._SCORE_TILE - 128, fm._SCORE_TILE,
+                                  fm._SCORE_TILE + 128])
+def test_the_shipped_tile_and_its_neighbours_change_no_bit(monkeypatch,
+                                                           tile):
+    """The constant itself and the tiles a sweep tries beside it, on a
+    call long enough for two whole tiles and a last one that starts
+    early: the same bits as tiles of 64."""
+    # whole 128s, so that the rows cross as one staging chunk
+    feats, fields, vals = _instances(2 * tile + 256, NNZ)
+
+    def run(t):
+        monkeypatch.setattr(fm, "_SCORE_TILE", t)
+        # a trainer a tile: programs are kept by shape, the tile is read
+        # when one is built
+        tr = FMTrainer(_cfg(), n_devices=1)
+        cursor = spans.take_since(0)[0]
+        got = tr.predict(_params(tr), feats, fields, vals)
+        build, = [s[6] for s in spans.take_since(cursor)[1]
+                  if s[0] == "mp4j.step.build"
+                  and s[6].get("key") == "ffm_score"]
+        return got, build
+
+    want, _ = run(64)
+    got, build = run(tile)
+    assert (build["tile"], build["tiles"]) == (tile, 3)
+    assert got.tobytes() == want.tobytes()
 
 
 def test_no_rows_no_program():

@@ -493,17 +493,19 @@ def test_ffm_conversions_go_a_block_at_a_time(ffm_programs, which):
     assert 2.6e9 < sizes[0] < 2.7e9 and 4.29e9 < sizes[1] < 4.35e9
 
 
-# What ``FMTrainer._build_step`` lowered for ``ffm-criteo.stream-zipf`` at
-# the commit before ``FMConfig.optimizer`` existed (PR 31's tree, jax
-# 0.9.0 with x64 on as ``conftest.py`` sets it, for the described v5e):
-# sha256 of ``lowered.as_text()``. The
+# What ``FMTrainer._build_step`` lowers for ``ffm-criteo.stream-zipf``
+# (jax 0.9.0 with x64 on as ``conftest.py`` sets it, for the described
+# v5e): sha256 of ``lowered.as_text()``, first taken at the commit before
+# ``FMConfig.optimizer`` existed (PR 31's tree) and again at PR 37, which
+# changed the step on purpose (``_select_fields``' output columns run
+# component by component, the weight inside the runs). The
 # AdaGrad step is another function; choosing it must leave SGD's program
 # as it was, to the letter. A PR that changes the SGD step on purpose, or
 # a new jax, changes these with it.
 SGD_STEP_LOWERED_SHA256 = {
-    "step": "b5d51aaa68cd861b0cec40e4967282b5e1c0e9c14633f184b54a76a98151a139",
+    "step": "5338ede3b2b0082c7892a96af10c85cee0e42a039993b840a82d256b5674e6ca",
     "step_on_four":
-        "6f085d6d0e4c366a65821aaab71bd0ee9ce04274714cc12835ab9eb3f5463028",
+        "83d305f79c452fbbcea5d20a23856b971c4c9dfaee1e79ffdcf5e17cbbead906",
 }
 
 
@@ -721,6 +723,7 @@ def test_ffm_scoring_program_holds_a_tile_beside_its_arguments(
     assert built["tile"] == fm._SCORE_TILE
     assert built["tiles"] == -(-chunk // fm._SCORE_TILE)
     assert built["block_width"] == 256      # parameters only, AdaGrad or not
+    assert built["select_columns"] == "component"
     for chips in (1, 4):
         m = p[f"score_on_{chips}"].memory_analysis()
         # the 4.29 GB table, a shard of the file, the probabilities
@@ -748,7 +751,11 @@ def test_ffm_scoring_program_reads_file_and_table_as_they_rest(
         assert _table_sized(text, opcode, p["rows"] * 39) == [], opcode
     gathers = [ln for ln in text.splitlines() if " gather(" in ln]
     assert len(gathers) == 1 and "ffm.table_gather" in gathers[0]
-    assert "f32[%d,39,256]" % tile in gathers[0]
+    # 39 slots and an empty one: whole sublane tiles, so the gathered
+    # blocks are the select's operand as they lie
+    assert "f32[%d,40,256]" % tile in gathers[0]
+    assert [ln for ln in text.splitlines()
+            if " reshape(" in ln and "f32[%d,40,256]" % tile in ln] == []
     assert "slice_sizes={1,256}" in gathers[0]
     assert "ffm.score.select" in text and "ffm.score.pairs" in text
     assert "operand_precision={highest,highest}" in text
@@ -757,6 +764,46 @@ def test_ffm_scoring_program_reads_file_and_table_as_they_rest(
     four = p["score_on_4"].as_text()
     assert "all-reduce" not in four and "all-gather" not in four
     assert "all-to-all" not in four and "collective-permute" not in four
+
+
+def _lanes(text, dtype="f32"):
+    """(dimensions, size of the dimension on the lanes) of every array of
+    ``dtype`` and rank two or more that the program's text names: the
+    first index of a layout's minor-to-major list is the dimension that
+    rests on the lanes."""
+    found = set()
+    for dims, layout in re.findall(
+            r"\b%s\[((?:\d+,)+\d+)\]\{((?:\d+,)+\d+)" % dtype, text):
+        dims = tuple(int(d) for d in dims.split(","))
+        found.add((dims, dims[int(layout.split(",")[0])]))
+    return found
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_ffm_scoring_program_never_rests_the_components_on_the_lanes(
+        ffm_score_programs, chips, capsys):
+    """The select hands the pairs a component's [K, K] matrix of a row
+    as a run of slots (``E[n, a, j, b]``), so no array of the compiled
+    program has the k = 4 components as the dimension that rests on the
+    128 lanes (31 of 32 lanes would be padding). Its temporaries are
+    reported, and are a tile's."""
+    p = ffm_score_programs
+    k = p["config"]["k"]
+    program = p[f"score_on_{chips}"]
+    arrays = _lanes(program.as_text())
+    tile = p["built_on_1"][0]["tile"]
+    # the tile's own arrays are among them: the gathered blocks, and the
+    # select's output cut into runs
+    assert any(dims[0] == tile and dims[-1] == 256 for dims, _ in arrays)
+    assert [dims for dims, lanes in arrays if lanes == k] == []
+    # (XLA may still VIEW the rows-minor copy of the select's output as
+    # [tile, K, K, k]: a bitcast, whose lanes hold the tile's rows)
+    m = program.memory_analysis()
+    with capsys.disabled():
+        print(f"\nffm scoring program on {chips} chip(s), tile {tile}: "
+              f"temporaries {m.temp_size_in_bytes / 1e6:.1f} MB, "
+              f"arguments {m.argument_size_in_bytes / 1e9:.3f} GB")
+    assert 0 < m.temp_size_in_bytes < 0.3e9, m.temp_size_in_bytes
 
 
 def test_ffm_model_enters_a_block_at_a_time(ffm_score_programs):

@@ -241,7 +241,8 @@ def test_fit_stream_leaves_exactly_its_spans(rng, ring, family,
                             "table_form": "blocks",
                             "descriptors": (16 // N_SHARDS) * 4,
                             "index_streams": 1, "optimizer": "sgd",
-                            "block_width": 128, "capacity": 32}
+                            "block_width": 128, "capacity": 32,
+                            "select_columns": "component"}
         # the table is converted before the first chunk is staged and
         # after the last loss is fetched
         assert widen[2] + widen[3] <= stage[0][2]
@@ -279,7 +280,8 @@ def test_step_build_span_says_the_table_form(rng, ring, kw, carries):
         # 17 floats, or 17 and their 17 accumulators, in one 128-lane word
         want.update(table_form="blocks", descriptors=16, index_streams=1,
                     optimizer=kw.get("optimizer", "sgd"), block_width=128,
-                    capacity=kw.get("sparse_capacity", 32))
+                    capacity=kw.get("sparse_capacity", 32),
+                    select_columns="component")
     if "optimizer" in kw:
         # the update loop's tile (the whole list where it is shorter than
         # one) and its trips when every slot holds another feature
@@ -587,7 +589,9 @@ def _lower_score(rng):
 # with this file's helpers: the spans are the host's and no line of a
 # jitted function moved. A PR that changes one of these programs on
 # purpose prints the new digest with this test and pins it (PR 35: the
-# AdaGrad step's, whose merge tells ``segment_sum`` its ids ascend).
+# AdaGrad step's, whose merge tells ``segment_sum`` its ids ascend; PR 37:
+# both FFM steps', whose select's output columns run component by
+# component).
 LOWERED = {
     "placer": (
         _lower_placer,
@@ -600,10 +604,10 @@ LOWERED = {
         "65e9cddff36da71d3c0d1b039b56f4d4137a955b457d12271ce2745044751514"),
     "ffm": (
         _lower_ffm,
-        "2fa0b34bc566d097ee4e4388f689302adb926463ff2c822c38ad73125808ca15"),
+        "3c2afc6fb182843c9f57a903bdf17350b6bd7bf477931d6ef34469fb406b0b9d"),
     "ffm-adagrad": (
         partial(_lower_ffm, optimizer="adagrad"),
-        "66798885a69e0d038f05e705aa22a0624fa128ef0dfd15e6ec28f5f06446d8a3"),
+        "4a36d027bb96c7faace1521c2076ca6a7123209d8f68a75a434bc5d43585d047"),
 }
 
 

@@ -188,6 +188,11 @@ def _block_stride(cfg: FMConfig) -> int:
     and a conversion moves whole runs (TPU v5 lite, PR 27, the 2.62 GB
     table: 0.057 s a conversion; with the fields outermost, column
     ``fl * k + j``, every entry changes lane and it takes 0.266 s).
+    :func:`_select_fields` keeps that order in what it hands on: its
+    output columns run component by component too, each a run of the
+    row's slots, so a component's [K, K] matrix of a row is a run of the
+    matmul's neighbouring lanes and nothing downstream holds an array
+    whose last dimension is the k components.
     An FM block has no runs: its vector, then the weight."""
     return _weights_width(cfg) // cfg.k
 
@@ -217,44 +222,79 @@ def _gather_blocks(T, feats):
 def _select_fields(blk, fields, cfg: FMConfig):
     """``(wv, E)`` out of the gathered blocks ``blk`` [N, K, block]:
     ``wv[n, a]`` the linear weight of feature a [N, K], and
-    ``E[n, a, b] = v_{feat_a, field_b}`` [N, K, K, k] (FM: the block's
-    vector, [N, K, k]).
+    ``E[n, a, j, b] = v_{feat_a, field_b}[j]`` [N, K, k, K], component by
+    component as the block rests (FM: the block's vector, [N, K, k]).
 
-    A one-hot contraction over the block's columns (column
-    ``j * stride + field_b`` of feature a's block is entry j of
-    E[n, a, b], :func:`_block_stride`; one more column of the one-hot
-    picks the weight, :func:`_weight_column`; the padding columns are
-    never selected and get a gradient of 0.0), exact in f32: each
-    output is one block entry times 1.0 plus zeros (``HIGHEST``: the
-    f32 value crosses the MXU as three bf16 pieces whose sum is the
-    value). Not a second indexed gather, which would
-    bring back a descriptor a slot pair. Its transpose is what carries
-    the gradient back into the block: two slots of a row in one field
-    add, a field the row lacks gets exactly 0.0, and the weight's
-    gradient lands in its column of the same array. Contracting the
-    columns as they lie ([N, K, block] x [N, block, K * k]) took 3.1 ms
-    of a 2,048 x 39-slot step on TPU v5 lite (PR 27); contracting a
-    [N, K, n_fields, k] view over the fields 5.4, a compare-and-sum
-    5.8. The weight as a lane slice of ``blk`` beside the contraction,
-    its gradient padded back into the block's width: 0.48 ms a step
-    more than as the contraction's 157th output (PR 31)."""
+    A one-hot contraction over the block's columns. Its output columns
+    run as the block's do (:func:`_block_stride`), k runs of K + 1:
+    column ``j * (K + 1) + b`` takes column ``j * stride + field_b`` of
+    feature a's block, and the last column of every run the weight's
+    (:func:`_weight_column`; run 0's is handed on); the block's padding
+    columns are never selected and get a gradient of 0.0. Exact in f32:
+    each output is one block entry times 1.0 plus zeros (``HIGHEST``:
+    the f32 value crosses the MXU as three bf16 pieces whose sum is the
+    value; told that the one-hot needs one piece, ``(HIGHEST,
+    DEFAULT)``, XLA takes the same 49 ms a 286,720-row chunk and gives
+    the same bits: TPU v5 lite, PR 37). Not a second indexed gather,
+    which would bring back a descriptor a slot pair, and not a slice
+    where ``fields`` happens to count up: any assignment of fields to
+    slots goes through the same contraction. Its transpose is what
+    carries the gradient back into the block: two slots of a row in one
+    field add, a field the row lacks gets exactly 0.0, and the weight's
+    gradient lands in its column of the same array.
+
+    The output is cut into its runs by one reshape and nothing is
+    sliced off it as it lies: XLA turns it rows-minor ONCE for the pair
+    product, and the weight is a slice of that copy's major dimensions.
+    As one more column after the runs (until PR 37: columns ``b * k +
+    j`` and the weight's, ``E`` [N, K, K, k]) the weight was a lane
+    slice of the matmul's output ([N, K, 1], a lane of 128 in use) and a
+    copy of its own: 3.5 + 3.9 ms of a chunk's 158.1, as much as the
+    copy of the other 156 columns (9.5), where it now costs 1.7 on that
+    copy (my chip runs, PR 37; PERF.md section 6). In the older form,
+    contracting the columns as they lie ([N, K, block] x [N, block,
+    K * k]) took 3.1 ms of a 2,048 x 39-slot step on TPU v5 lite (PR
+    27); contracting a [N, K, n_fields, k] view over the fields 5.4, a
+    compare-and-sum 5.8. The weight as a lane slice of ``blk`` beside
+    the contraction, its gradient padded back into the block's width:
+    0.48 ms a step more than as an output of the contraction (PR 31)."""
     wcol = _weight_column(cfg)
     if cfg.model == "fm":
         return blk[..., wcol], blk[..., :cfg.k]
     N, K, width = blk.shape
-    cols = (fields[:, :, None] + _block_stride(cfg)
-            * jnp.arange(cfg.k, dtype=fields.dtype)).reshape(N, K * cfg.k)
+    runs = _block_stride(cfg) * jnp.arange(cfg.k, dtype=fields.dtype)
+    cols = fields[:, None, :] + runs[:, None]                  # [N, k, K]
     cols = jnp.concatenate(
-        [cols, jnp.full((N, 1), wcol, cols.dtype)], axis=1)
-    sel = jax.nn.one_hot(cols, width, dtype=blk.dtype, axis=1)
+        [cols, jnp.full((N, cfg.k, 1), wcol, cols.dtype)], axis=2)
+    sel = jax.nn.one_hot(cols.reshape(N, cfg.k * (K + 1)), width,
+                         dtype=blk.dtype, axis=1)
     out = jnp.einsum("nac,ncm->nam", blk, sel,
                      precision=lax.Precision.HIGHEST)
-    return out[..., -1], out[..., :-1].reshape(N, K, K, cfg.k)
+    out = out.reshape(N, K, cfg.k, K + 1)
+    return out[:, :, 0, K], out[..., :K]
+
+
+def _select_build_args(cfg: FMConfig) -> dict:
+    """What ``mp4j.step.build`` says of a program that holds
+    :func:`_select_fields`: the order of the one-hot's output columns,
+    so a trace says which form ran. FM has no select."""
+    return dict(select_columns="component") if cfg.model == "ffm" else {}
+
+
+def _by_component(E, cfg: FMConfig):
+    """What a row form gathered (:func:`_slot_rows`: FFM [N, K, K, k], a
+    row a slot pair) in the form :func:`_score_from_slots` reads,
+    [N, K, k, K]; FM's [N, K, k] as it is."""
+    return jnp.moveaxis(E, -1, 2) if cfg.model == "ffm" else E
 
 
 def _score_from_slots(w0, wv, E, xv, cfg: FMConfig):
     """Model score given the already-gathered linear weights ``wv``
-    [N, K] and embedding rows ``E`` of every slot.
+    [N, K] and the embedding entries ``E`` of every slot: FM [N, K, k];
+    FFM [N, K, k, K], ``E[n, a, j, b]`` entry j of slot a's vector
+    against slot b's field (what :func:`_select_fields` hands on; the
+    row forms gather [N, K, K, k] and pass it through
+    :func:`_by_component`).
 
     Split out from :func:`_score` so the sparse train step can
     differentiate with respect to what it gathered DIRECTLY (per-slot
@@ -268,8 +308,9 @@ def _score_from_slots(w0, wv, E, xv, cfg: FMConfig):
         s = jnp.sum(Ex, axis=1)                        # [N, k]
         inter = 0.5 * jnp.sum(s * s - jnp.sum(Ex * Ex, axis=1), axis=1)
     else:
-        # FFM: E[a, b] = v_{feat_a, field_b}; z += <E[a,b], E[b,a]> x_a x_b
-        pair = jnp.einsum("nabk,nbak->nab", E, E)
+        # FFM: z += sum_j E[a, j, b] E[b, j, a] x_a x_b over a < b: each
+        # component's [K, K] matrix times its own transpose
+        pair = jnp.einsum("najb,nbja->nab", E, E)
         pair = pair * (xv[:, :, None] * xv[:, None, :])
         K = xv.shape[1]
         upper = jnp.triu(jnp.ones((K, K), pair.dtype), 1)
@@ -285,7 +326,7 @@ def _score(params, feats, fields, vals, mask, cfg: FMConfig):
     """
     w0, w, V = params
     xv = vals * mask                                   # zero padded slots
-    E = _gather_slots(V, _slot_rows(feats, fields, cfg))
+    E = _by_component(_gather_slots(V, _slot_rows(feats, fields, cfg)), cfg)
     return _score_from_slots(w0, w[feats], E, xv, cfg)
 
 
@@ -692,7 +733,8 @@ def train_step_sparse_sharded(params, batch, cfg: FMConfig, n: int,
     xv = vals * mask
     loss, (g0, gw, gE), denom = _weighted_mean_grads(
         (w0, w, E),
-        lambda p: _score_from_slots(p[0], p[1][feats], p[2], xv, cfg),
+        lambda p: _score_from_slots(p[0], p[1][feats],
+                                    _by_component(p[2], cfg), xv, cfg),
         y, sw, cfg, axis_name)
     g0 = lax.psum(g0, axis_name)
     gw = lax.psum(gw, axis_name)     # linear part stays dense (small)
@@ -721,6 +763,12 @@ def train_step_sparse_sharded(params, batch, cfg: FMConfig, n: int,
     return (w0, w, Vs), loss
 
 
+# Rows of a 32-bit array's tile on the TPU: an [N, K, block] array rests
+# with K padded to whole 8s, so [N * K, block] is [N, K, block] as it
+# lies only where K is whole 8s.
+_SUBLANES = 8
+
+
 def predict(state, feats, fields, vals, cfg: FMConfig):
     """What the model says of a batch of padded sparse instances, from
     the entered ``(w0, T)`` (the table by feature, a parameters-only
@@ -728,8 +776,21 @@ def predict(state, feats, fields, vals, cfg: FMConfig):
     logistic loss, else the score. A padded slot carries the value 0,
     so ``vals`` is its own mask. One descriptor a (row, slot) brings the
     feature's block (:func:`_gather_blocks`); the row form's
-    (:func:`_score`) gather is a descriptor a slot PAIR."""
+    (:func:`_score`) gather is a descriptor a slot PAIR.
+
+    The slots are first padded to whole ``_SUBLANES`` with empty ones
+    (feature 0, field 0, value 0: they score exactly nothing). The
+    gather leaves its blocks as [N * K, block]; with K whole 8s that IS
+    [N, K, block] and the select reads the blocks where the gather put
+    them. At K = 39 XLA rewrote every tile's 61 MB into the padded form
+    between the two (TPU v5 lite, PR 37: ``reshape``, 18.0 ms of a
+    286,720-row chunk's 158.1, 0.38 s of a 6,042,135-row job; one more
+    descriptor in forty costs 1.6 of the gather's 63.7)."""
     w0, T = state
+    pad = -feats.shape[1] % _SUBLANES
+    if pad:
+        feats, fields, vals = (jnp.pad(a, ((0, 0), (0, pad)))
+                               for a in (feats, fields, vals))
     blk = _gather_blocks(T, feats)
     with jax.named_scope("ffm.score.select"):
         wv, E = _select_fields(blk, fields, cfg)
@@ -741,17 +802,24 @@ def predict(state, feats, fields, vals, cfg: FMConfig):
 # Rows :func:`score_rows` takes through :func:`predict` a trip of its
 # loop. Swept on the chip as ``_UPDATE_TILE`` was: the scoring program
 # alone on one staged chunk of 286,720 rows at the Criteo cell's shape
-# (39 slots, 39 fields x 4, the 4.29 GB table), 26 tiles from 64 to 4,096,
-# ms a chunk, the best of four (my chip runs, PR 36; PERF.md section 5):
-# 64: 224.8, 256: 199.0, 384: 191.5, 512: 203.2, 768: 192.4, 1,024: 237.5,
-# 1,152: 192.5, 1,280: 199.4, 1,408: 204.0, 1,536: 158.0, 1,664: 167.7,
-# 1,792: 174.6, 1,920: 187.4, 2,048: 221.0, 2,304: 180.3, 2,560: 184.9,
-# 3,072: 254.3, 3,584: 180.2, 4,096: 255.8. Up to 1,408 XLA keeps a
-# tile's temporaries out of HBM and the chunk takes 191-204 ms whatever
-# the tile (a trip's own cost shows under 128); from 1,536 on it holds
-# them in HBM (63 MB there) and the gather runs a third faster, less so
-# the larger the tile; whole multiples of 1,024 lose 20% either way.
-_SCORE_TILE = 1536
+# (39 slots padded to 40, 39 fields x 4, the 4.29 GB table), PR 36's 26
+# tiles and nine odd multiples of 64 between them, ms a chunk, the best
+# of four (my chip runs, PR 37; PERF.md section 5): 64: 216.6, 128: 239.2,
+# 192: 192.7, 256: 239.5, 320: 187.5, 384: 229.6, 448: 212.0, 512: 241.2,
+# 576: 194.0, 640: 233.5, 768: 229.0, 896: 236.4, 1,024: 235.9, 1,088:
+# 187.5, 1,152: 228.3, 1,216: 196.2, 1,280: 236.8, 1,344: 192.7, 1,408:
+# 233.6, 1,472: 141.3, 1,536: 211.8, 1,600: 139.1, 1,664: 215.1, 1,728:
+# 141.3, 1,792: 183.9, 1,856: 141.8, 1,920: 186.7, 1,984: 144.0, 2,048:
+# 186.1, 2,112: 143.0, 2,304: 217.2, 2,560: 219.0, 3,072: 235.1, 3,584:
+# 236.6, 4,096: 235.3. Two of XLA's choices decide a tile's time, and a
+# new jax or libtpu can move both. Where XLA does not pad a tile's index
+# list (every even multiple of 64 at 40 slots; PR 36's "whole multiples
+# of 1,024" at 39) the gather fusion takes descriptors 128 at a time and
+# not 256 and runs at 9.4 to 12.2 ns a descriptor, not 5.6. And up to
+# 1,408 rows the gathered blocks rest in VMEM, where the select's matmul
+# takes them a row an iteration and not six (100 ms a chunk, not 49);
+# from 1,472 on they rest in HBM.
+_SCORE_TILE = 1600
 
 
 def _score_tile(rows: int) -> int:
@@ -1156,7 +1224,8 @@ class FMTrainer(DataParallelTrainer):
             build_args = dict(table_form="blocks",
                               descriptors=per_shard_slots,
                               index_streams=1, optimizer=cfg.optimizer,
-                              block_width=_block_width(cfg), capacity=cap)
+                              block_width=_block_width(cfg), capacity=cap,
+                              **_select_build_args(cfg))
             if self._adagrad:
                 # the update loop's tile, and its trips for a chunk whose
                 # slots are all distinct
@@ -1429,7 +1498,8 @@ class FMTrainer(DataParallelTrainer):
             E_flat, _, _ = _fetch_rows_sharded(
                 Vs, rows.reshape(-1).astype(jnp.int32),
                 flat_index(axes), axes)
-            E = E_flat.reshape(rows.shape + (Vs.shape[1],))
+            E = _by_component(
+                E_flat.reshape(rows.shape + (Vs.shape[1],)), cfg)
             z = _score_from_slots(w0, w[f0], E, vals[0] * mask[0], cfg)
             if cfg.loss == "logistic":
                 z = jax.nn.sigmoid(z)
@@ -1493,7 +1563,8 @@ class FMTrainer(DataParallelTrainer):
         tile = _score_tile(rows)
         with spans.span("mp4j.step.build", key="ffm_score", rows=rows,
                         tile=tile, tiles=-(-rows // tile),
-                        block_width=_block_width(cfg)):
+                        block_width=_block_width(cfg),
+                        **_select_build_args(cfg)):
             return jax.jit(score, donate_argnums=2)
 
     def predict(self, params, feats, fields, vals):
