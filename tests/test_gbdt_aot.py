@@ -814,3 +814,167 @@ def test_ffm_model_enters_a_block_at_a_time(ffm_score_programs):
     assert m.alias_size_in_bytes == 0       # the caller's table is kept
     assert 2.6e9 < m.argument_size_in_bytes < 2.7e9
     assert 4.29e9 < m.output_size_in_bytes < 4.35e9
+
+
+# ------------- the cell whose table no chip can hold (PR 38): four chips
+@pytest.fixture(scope="module")
+def sharded_programs(topo_devices):
+    """The step and both conversions of
+    ``ffm-criteo-sharded.stream-zipf-4chip`` at the cell's own size (2^25
+    features, 8.59 GB of blocks a chip), compiled for the four described
+    chips of the host, with the cell's chunk (2,048 rows a chip)."""
+    import json
+    from pathlib import Path
+
+    from ytk_mp4j_tpu.models.fm import FMConfig, FMTrainer
+
+    root = Path(__file__).resolve().parent.parent / "benchmark"
+    c = json.loads((root / "configs/ffm-criteo-sharded.json").read_text())
+    t = json.loads((root / "traffic/stream-zipf-4chip.json").read_text())
+    chips = c["chips"]
+    per = t["rows_per_chunk"] // chips
+    mesh = Mesh(np.asarray(topo_devices[:chips]), ("mp4j",))
+    trainer = FMTrainer(FMConfig(
+        model=c["model"], n_features=c["n_features"],
+        n_fields=c["n_fields"], k=c["k"], max_nnz=c["max_nnz"],
+        learning_rate=c["learning_rate"]),
+        mesh=mesh, sparse_grads=c["sparse_grads"],
+        table_sharding=c["table_sharding"])
+    lowered = trainer._build_step(per * c["max_nnz"]).lower(
+        trainer._state_avals(),
+        *_ffm_batch(mesh, chips, c, {"rows_per_chunk": per}))
+    rep, rows = NamedSharding(mesh, P()), NamedSharding(mesh, P("mp4j"))
+    public = (jax.ShapeDtypeStruct((), jnp.float32, sharding=rep),
+              jax.ShapeDtypeStruct((c["n_features"],), jnp.float32,
+                                   sharding=rep),
+              jax.ShapeDtypeStruct((trainer.n_rows_padded, c["k"]),
+                                   jnp.float32, sharding=rows))
+    widen, narrow = trainer._build_converters()
+    return {
+        "config": c, "chips": chips, "slots": per * c["max_nnz"],
+        "lowered": lowered.as_text(),
+        "step": lowered.compile(),
+        "widen": widen.lower(public).compile(),
+        "narrow": narrow.lower(trainer._state_avals()).compile(),
+    }
+
+
+def _collectives(text):
+    """(result shape, opcode) of every collective of a compiled program."""
+    return re.findall(r"= (\(?[^=]*?\)?) (all-to-all|all-gather|all-reduce|"
+                      r"collective-permute|reduce-scatter)(?:-start)?\(",
+                      text)
+
+
+def test_sharded_step_updates_its_shard_where_it_rests(sharded_programs):
+    p = sharded_programs
+    c, text = p["config"], p["step"].as_text()
+    mine = c["n_features"] // p["chips"]            # features a chip owns
+    table = r"f32\[%d,256\]\{1,0:T\(8,128\)\}" % mine
+    # a chip's share of the table by feature comes in row-major, donated,
+    # and goes out in the same buffer: 8.59 GB, and nothing like it beside
+    assert re.search(r"\(param\S*: f32\[\], param\S*: f32\[%d,256\]" % mine,
+                     text)
+    assert re.search(r"input_output_alias=\{.*\{1\}: \(1, \{\}, may-alias\)",
+                     text)
+    m = p["step"].memory_analysis()
+    shard = mine * 256 * 4
+    assert shard <= m.alias_size_in_bytes < shard + 2 ** 20
+    assert m.temp_size_in_bytes < 1.0e9, m.temp_size_in_bytes
+    for opcode in ("copy", "transpose", "pad", "concatenate"):
+        assert _table_sized(text, opcode, mine * 256 // 2) == [], opcode
+    assert re.search(r"= " + table + r" fusion\([^)]*\), kind=kCustom"
+                     r".*ffm\.table_update", text)
+
+
+def test_sharded_step_pays_for_what_a_chip_owns_and_touches(
+        sharded_programs):
+    """The owner's gather and scatter-add run a tile of 512 blocks at a
+    time inside the walk over each member's list (a loop whose trips go
+    with the list's live prefix): no gather or scatter of the table takes
+    n x S slots (319,488 here), let alone n x N x K^2, and nothing of
+    size [n_features] or [n_features / chips] is held."""
+    p = sharded_programs
+    text, S = p["step"].as_text(), p["slots"]
+    custom = [ln for ln in text.splitlines()
+              if " fusion(" in ln and "kind=kCustom" in ln]
+    gathers = [ln for ln in custom if "ffm.table_gather" in ln]
+    updates = [ln for ln in custom if "ffm.table_update" in ln]
+    assert len(gathers) == len(updates) == p["chips"]   # one a member's list
+    for ln in gathers:
+        assert re.search(r"= f32\[512,256\]", ln), ln
+        assert "while/body/while/body" in ln, ln
+    for ln in updates:
+        assert "while/body/while/body" in ln, ln
+        assert "scatter-add" in ln, ln
+    # the requester's own gathers and sums take its S slots, from lists
+    # it holds itself, never from the table
+    mine = p["config"]["n_features"] // p["chips"]
+    shard = set(re.findall(r"(%%\S+) = f32\[%d,256\]" % mine, text))
+    assert shard
+    for ln in custom:
+        if "ffm.table_" in ln:
+            continue
+        operands = re.search(r" fusion\(([^)]*)\)", ln).group(1).split(", ")
+        assert not shard & set(operands), ln
+    assert S == 79872
+    V = p["config"]["n_features"]
+    assert re.search(r"\[%d\]|\[%d\]" % (V, V // p["chips"]), text) is None
+
+
+def test_sharded_step_exchanges_by_owner_and_reduces_scalars_only(
+        sharded_programs):
+    found = _collectives(sharded_programs["step"].as_text())
+    exchanged = sorted(shape.split("{")[0] for shape, op in found
+                       if op == "all-to-all")
+    # ids out and blocks back, ids and gradients out: 16,384 a member a
+    # round, 256 floats a block
+    assert exchanged == ["f32[4,16384,256]", "f32[4,16384,256]",
+                         "s32[4,1,16384]", "s32[4,1,16384]"]
+    others = [(shape, op) for shape, op in found if op != "all-to-all"]
+    assert {op for _, op in others} == {"all-reduce"}
+    for shape, _ in others:     # the rounds' pmax; loss, weight sum, bias
+        assert re.fullmatch(r"\(?((f32|s32)\[\]\S*,? ?)+\)?", shape), shape
+
+
+@pytest.mark.parametrize("which", ["widen", "narrow"])
+def test_sharded_conversions_stay_on_the_chip_that_owns_the_features(
+        sharded_programs, which):
+    """5.37 GB a chip in (its rows of the public table, and the weights)
+    and 8.59 GB out, or the reverse, a block of features at a time; the
+    table crosses no link, and the only collective is ``narrow``'s
+    gathering of the linear weights into their replicated vector."""
+    p = sharded_programs
+    m = p[which].memory_analysis()
+    assert m.temp_size_in_bytes < 0.5e9, m.temp_size_in_bytes
+    assert m.alias_size_in_bytes == 0       # the caller's table is kept
+    sizes = sorted([m.argument_size_in_bytes, m.output_size_in_bytes])
+    assert 5.2e9 < sizes[0] < 5.4e9 and 8.58e9 < sizes[1] < 8.65e9
+    text = p[which].as_text()
+    mine = p["config"]["n_features"] // p["chips"]
+    for opcode in ("copy", "transpose", "pad", "concatenate"):
+        assert _table_sized(text, opcode, mine * 156 // 2) == [], opcode
+    found = _collectives(text)
+    if which == "widen":
+        assert found == []
+    else:
+        assert [(shape.split("{")[0], op) for shape, op in found] == [
+            ("f32[%d]" % p["config"]["n_features"], "all-gather")]
+
+
+# What ``FMTrainer._build_step`` lowers for the sharded cell (jax 0.9.0
+# with x64 on as ``conftest.py`` sets it, for the described v5e:2x2):
+# sha256 of ``lowered.as_text()``, first taken at PR 38. A PR that changes
+# the sharded step, ``ops/sparse``'s merge or ``ops/collectives.
+# all_to_all`` on purpose, or a new jax, changes it with them.
+SHARDED_STEP_LOWERED_SHA256 = (
+    "b40337e7c767a6c6fd2caa2af88473392db837f1b3ea436297063564337d598a")
+
+
+def test_sharded_step_lowers_to_the_program_it_was(sharded_programs):
+    import hashlib
+
+    text = sharded_programs["lowered"]
+    assert "all_to_all" in text
+    assert (hashlib.sha256(text.encode()).hexdigest()
+            == SHARDED_STEP_LOWERED_SHA256)

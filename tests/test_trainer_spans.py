@@ -269,8 +269,12 @@ def test_step_build_span_says_the_table_form(rng, ring, kw, carries):
     """The replicated sparse step indexes by feature (N x K descriptors
     of a shard) and by nothing else (one index stream: the linear
     weights ride in the blocks; AdaGrad's accumulators too, in a block
-    twice as wide); the sharded step still indexes a row a slot pair
-    and the weights beside it."""
+    twice as wide). The sharded step is in the same form, a member's
+    share of the blocks: it says how many owners the table is cut over
+    and how many ids a member asks of one owner a round of the exchange
+    (no more than the member has slots), and carries no ``capacity``
+    (all of a member's slots are merged) and no ``optimizer`` (SGD, the
+    only rule it has)."""
     tr, chunks = _ffm(rng, 1, **kw)
     tr.fit_stream(iter(chunks))
     build = [s[6] for s in _named(_trainer_spans(), "mp4j.step.build")
@@ -286,6 +290,11 @@ def test_step_build_span_says_the_table_form(rng, ring, kw, carries):
         # the update loop's tile (the whole list where it is shorter than
         # one) and its trips when every slot holds another feature
         want.update(update_tile=32, update_tiles=1)
+    if "table_sharding" in kw:
+        want.update(table_sharding="sharded", table_form="blocks",
+                    owners=N_SHARDS, exchange_cap=16, exchange_tile=16,
+                    descriptors=16, index_streams=1,
+                    block_width=128, select_columns="component")
     assert build == [want]
 
 
@@ -483,8 +492,7 @@ def _batch_avals(tr, rows):
 
 def _lower_ffm(rng, **kw):
     tr, _ = _ffm(rng, 0, **kw)
-    # the replicated sparse step takes its own state; the sharded step
-    # the placed public params
+    # a sparse step takes its own state
     state = tr._enter(tr.init_params(0))
     step = tr._build_step((16 // tr.n_shards) * tr.cfg.max_nnz)
     return step.lower(state, *_batch_avals(tr, 16))
@@ -518,8 +526,9 @@ def _lower_collectives(rng):
      ["ffm.table_gather", "ffm.table_update", "sparse.sort_by_key",
       "sparse.segment_reduce"]),
     (partial(_lower_ffm, table_sharding="sharded"),
-     ["ffm.table_gather", "ffm.table_update", "sparse.sort_by_key",
-      "sparse.segment_reduce"]),
+     ["ffm.shard.route", "mp4j.all_to_all", "ffm.table_gather",
+      "ffm.shard.spread", "ffm.grad_merge", "ffm.table_update",
+      "sparse.sort_by_key", "sparse.segment_reduce"]),
     (_lower_collectives, ["mp4j.allreduce", "mp4j.reduce_scatter",
                           "mp4j.allgather"]),
 ], ids=["gbdt", "ffm", "ffm-dedupe", "ffm-sharded", "collectives"])

@@ -114,6 +114,12 @@ def check_collectives(results: dict, mesh: Mesh, n: int, L: int = 4096):
              _shard_mapped(mesh, rooted, P(AXIS),
                            (P(AXIS), P(None), P(None), P(AXIS), P(AXIS))),
              _f32(n, L))
+    # the exchange by owner: member i's j-th slice to member j
+    _compile("collectives/all_to_all", results,
+             _shard_mapped(mesh,
+                           lambda x: coll.all_to_all(x[0], AXIS)[None],
+                           P(AXIS), P(AXIS)),
+             _f32(n, n, L))
 
 
 def check_rings(results: dict, mesh: Mesh, n: int, L: int | None = None):
@@ -379,17 +385,20 @@ def check_ffm(results: dict, devices, n: int, per: int = 1024):
     _compile("ffm/sparse_train_step", results,
              tr._build_step(per * cfg.max_nnz),
              tr._state_avals(), *batch_avals)
-    # round-4 A/B: mesh-sharded table (owner-routed rows over
-    # all_to_all + compacted per-shard scatter) vs the replicated path
+    # the table sharded by feature over the mesh: the distinct features'
+    # blocks fetched from and returned to their owners over all_to_all,
+    # the owner gathering and scatter-adding what it owns and a chunk
+    # touches; its state is a member's share of the blocks
     trs = FMTrainer(cfg, mesh=mesh, sparse_grads=True,
                     table_sharding="sharded")
+    _compile("ffm/sparse_train_step_sharded", results,
+             trs._build_step(per * cfg.max_nnz),
+             trs._state_avals(), *batch_avals)
+    # the sharded SERVE program reads the public params, a row a slot pair
     sharded_avals = (
         jax.ShapeDtypeStruct((), jnp.float32),
         jax.ShapeDtypeStruct((cfg.n_features,), jnp.float32),
         _f32(trs.n_rows_padded, cfg.k))
-    _compile("ffm/sparse_train_step_sharded", results,
-             trs._build_step(per * cfg.max_nnz),
-             sharded_avals, *batch_avals)
     # round-5: fit_stream's double-buffered dispatch compiles THIS SAME
     # program (the stream stages chunks into identical padded shapes),
     # so the sharded+stream composition is covered by the row above;

@@ -45,6 +45,16 @@ TPU-first rebuild. Instances are padded to ``max_nnz`` static slots
   ``fit`` / ``fit_stream`` convert the public ``(w0, w, V)`` (and the
   accumulators, in the same shapes) once on the way in and once on the
   way out.
+- **sharded table** (``table_sharding="sharded"``, on the sparse path):
+  the same table by feature, cut over the mesh by contiguous ranges of
+  features, member m holding the blocks of features ``[m * B, (m + 1) *
+  B)`` and nobody the whole. A step fetches the blocks of the DISTINCT
+  features its rows hold from their owners and returns their summed
+  gradients the same way (:func:`train_step_sparse_sharded`), both
+  through ``ops/collectives.all_to_all``; an owner gathers and
+  scatter-adds what it owns and a chunk touches, not every member's
+  slots. This is the form for a vocabulary whose table no chip can hold
+  (configs[4] hashed to 2^25: 34.4 GB by feature).
 
 Model scores (order-2, sigmoid/logloss for classification):
 
@@ -79,6 +89,7 @@ from ytk_mp4j_tpu.models._base import (DataParallelTrainer,
                                        EarlyStopper, per_example_loss)
 from ytk_mp4j_tpu.obs import spans
 from ytk_mp4j_tpu.operators import Operators
+from ytk_mp4j_tpu.ops import collectives
 from ytk_mp4j_tpu.ops import sparse as sparse_ops
 
 MODELS = ("fm", "ffm")
@@ -345,10 +356,10 @@ def _slot_rows(feats, fields, cfg: FMConfig):
 
     FM touches row ``feat`` per slot ([N, K]); FFM touches row
     ``feat * n_fields + field_b`` per slot PAIR ([N, K, K]) — matching
-    the [N, K(, K), k] layout of ``_score``'s gathers. The dense step,
-    the sharded step and the sharded table's ``predict`` index this way;
-    the replicated sparse step and the replicated table's ``predict``
-    index by feature (:func:`_gather_blocks`).
+    the [N, K(, K), k] layout of ``_score``'s gathers. The dense step
+    and the sharded table's ``predict`` index this way; the sparse steps,
+    sharded table or not, and the replicated table's ``predict`` index
+    by feature (:func:`_gather_blocks`).
     """
     if cfg.model == "fm":
         return feats
@@ -369,8 +380,7 @@ def _weighted_mean_grads(p, score_fn, y, sw, cfg: FMConfig, axis_name):
     """Global-mean loss + grads of the sample-weighted shard loss —
     the one prologue shared by the dense and sparse steps. ``p`` is
     the differentiated pytree (full params; (w0, blk) with the gathered
-    blocks on the replicated sparse path; (w0, w, E) with the gathered
-    rows on the sharded one); ``score_fn(p)`` the margin."""
+    blocks on the sparse paths); ``score_fn(p)`` the margin."""
     def shard_sum(q):
         return jnp.sum(per_example_loss(score_fn(q), y, cfg.loss) * sw)
 
@@ -406,11 +416,14 @@ def train_step_dense(params, batch, cfg: FMConfig, axis_name=None):
     return (w0, w, V), loss
 
 
-def _check_block_table(params, n_arrays: int, cfg: FMConfig):
+def _check_block_table(params, n_arrays: int, cfg: FMConfig,
+                       sharded: bool = False):
     """A block step's ``params``: ``n_arrays`` of them, the second the
-    table by feature."""
-    if (len(params) != n_arrays
-            or params[1].shape != (cfg.n_features, _block_width(cfg))):
+    table by feature (``sharded``: a member's share of its rows)."""
+    shape = getattr(params[1], "shape", ()) if len(params) > 1 else ()
+    rows_ok = len(shape) == 2 and (sharded or shape[0] == cfg.n_features)
+    if (len(params) != n_arrays or not rows_ok
+            or shape[1] != _block_width(cfg)):
         # the public [n_rows, k] table would index and compile too, as
         # n_rows features of one k-wide block: another model
         raise Mp4jError(
@@ -674,93 +687,210 @@ def _fetch_rows_sharded(Vs, flat_rows, me, axis_name):
     with jax.named_scope("ffm.table_gather"):
         contrib = Vs[local]                     # [n, S, k] row gather
     contrib = jnp.where((owner == me)[..., None], contrib, 0.0)
-    recv = lax.all_to_all(contrib, axis_name, split_axis=0,
-                          concat_axis=0, tiled=False)   # [n, S, k]
+    recv = collectives.all_to_all(contrib, axis_name)   # [n, S, k]
     return jnp.sum(recv, axis=0), gi, owner
 
 
+# A member asks one owner for at most this many distinct features' blocks
+# a round of the sharded step's exchange, so the three buffers a member
+# holds ([n, cap, block]: the answers it gathers, what comes back, the
+# gradients it sends) are 67 MB each on four members at 39 fields x 4. A
+# step whose fullest (requester, owner) pair holds more runs another round
+# (:func:`train_step_sparse_sharded`). 16,384 is 1.7 times what a member
+# asks of an owner at the Criteo cell's shape (about 9,400 of a chunk's
+# 79,872 slots at Zipf 1.1 over four owners; PERF.md section 4).
+_EXCHANGE_CAP = 16384
+
+# Blocks an owner gathers, or scatter-adds, a trip of its walk over the
+# live prefix of one member's list; ``_EXCHANGE_CAP`` is whole tiles. The
+# step alone on one chip (one member, 2^23 features, one list of 37,600
+# live ids; my chip run, PR 38), ms a step by tile: 512: 8.884; 1,024:
+# 8.956; 2,048: 9.020; 4,096: 9.002 (a trip's own cost against half a tile
+# of dropped sentinels a list, as ``_UPDATE_TILE`` weighs them).
+_SHARD_TILE = 512
+
+
+def _exchange_cap(per_shard_slots: int) -> int:
+    """The sharded step's ``cap`` for a member's ``per_shard_slots``
+    slots: a member cannot hold more distinct features than slots, so a
+    short batch gets buffers of its own length (whole tiles) and one round
+    whatever its skew."""
+    tile = min(_SHARD_TILE, per_shard_slots)
+    return min(_EXCHANGE_CAP, -(-per_shard_slots // tile) * tile)
+
+
+def _shard_tile(cap: int) -> int:
+    """The tile of the owner's walks over lists of ``cap`` ids: whole
+    tiles, or the list at once."""
+    return _SHARD_TILE if cap % _SHARD_TILE == 0 else cap
+
+
 def train_step_sparse_sharded(params, batch, cfg: FMConfig, n: int,
-                              axis_name="mp4j"):
-    """One step with the embedding table SHARDED over the mesh: member
-    m owns rows ``[m*B, (m+1)*B)`` of the (padded) table, B = rows/n.
+                              cap: int, axis_name="mp4j"):
+    """One SGD step with the table SHARDED by feature over the ``n``
+    members of the mesh, in the block form of :func:`train_step_sparse`.
 
-    The replicated sparse step's serial floor is the per-chip
-    scatter-add of ALL members' gradient slots (n*S descriptors into a
-    full replica; TPU v5 lite, PR 22: 85.8 ns a scattered 16-byte row,
-    15.5 ns a gathered one). This step still indexes the public
-    [rows, k] table a slot PAIR (``_slot_rows``); the block form of
-    the replicated step is not taken here (a shard boundary would have
-    to fall on a feature's block; ROADMAP S3). Sharding changes both
-    sides:
+    ``params`` is ``(w0, Ts, rounds)``: the bias, this member's shard
+    ``Ts`` [B, block] of the table by feature (member m holds the blocks
+    of features ``[m * B, (m + 1) * B)``, linear weights in their column:
+    a boundary never cuts a block, and the step holds nothing of size
+    ``n_features``), and the count of exchange rounds so far (below).
+    Rows are data-parallel as everywhere else. A step is
 
-    - forward: slot row-ids ride one (tiny, int32) all_gather; each
-      member gathers the requested rows IT OWNS from its shard and one
-      ``all_to_all`` delivers them — wire n*S*k, the same order as the
-      replicated path's gradient all_gather;
-    - backward: gradient rows route to their owners by ``all_to_all``
-      (replacing the all_gather), then each member merges its received
-      rows by sort + segmented reduction into at most
-      ``C = min(n*S, B)`` slots — C is bounded by the SHARD SIZE, so
-      no overflow is possible — and scatter-adds C descriptors into
-      its [B, k] shard. Drop-mode scatters pay the serial unit per
-      DESCRIPTOR, not per applied row (previous installation, round 4:
-      7/8 sentinel rows saved only 3%), so the compaction is what
-      converts ownership into a real 1/n serial-floor cut; the
-      set-scatter inside the segmented reduction is the cheaper
-      scatter form (previous installation, round 3: 15 vs 42 ms at
-      524288 rows). None of this step was measured on this chip: no
-      cell runs it.
+    - *route* (scope ``ffm.shard.route``): the member's live slots' ids,
+      sorted and made distinct (``ops/sparse``: one sort, one pass).
+      Ownership is monotone in the id, so the ascending list is already
+      bucketed by owner: an owner's requests are a slice of it.
+    - *fetch*: the ids go to their owners (``all_to_all``, [n, cap]
+      int32), each owner gathers the blocks asked of it from its shard
+      (scope ``ffm.table_gather``; one descriptor a distinct feature a
+      requester, walked over each list's live prefix a tile at a time:
+      what the chunk touches of what the member owns, not n x S), a
+      second ``all_to_all`` brings them back, and the member spreads the
+      distinct blocks over its slots (``ffm.shard.spread``).
+    - score and gradient: the replicated step's own functions on the
+      [N, K, block] blocks.
+    - *merge and return*: the slots' gradient blocks are summed by
+      distinct feature in the same sorted order (``ffm.grad_merge``),
+      sent to their owners by ``all_to_all``, and the owner scatter-adds
+      each member's list into its shard (``ffm.table_update``, live
+      prefix again). The lists are not merged with each other first: SGD
+      is linear in the gradient, so a feature that two members touched
+      is two descriptors (about a fifth more at the Criteo cell's shape)
+      where a merge would cost a sort of n x cap blocks.
 
-    Table memory per chip is V/n rows — the piece that makes
-    configs[4]'s Criteo-scale vocabulary fit a pod at all.
-    """
-    from ytk_mp4j_tpu.ops.collectives import flat_index
+    Nothing is dropped, whatever the skew: a member sends an owner ``cap``
+    ids a round, and a step in which some (requester, owner) pair holds
+    more runs ``ceil(most / cap)`` rounds of the same exchange, the count
+    agreed by a ``pmax`` so every member joins every collective. A chunk
+    whose every slot belongs to one owner gives what the replicated step
+    gives. ``rounds`` is carried so that a caller can say how many ran
+    (``FMTrainer.exchange_rounds_``).
 
+    Dead slots (padding, or a row of weight 0) ask for nothing and get a
+    block of zeros; a feature nobody's live slot holds is not touched.
+    ``l2`` decays every row, as the replicated step does.
+
+    Measured (four TPU v5 lite, PR 38, 2^25 features, a chunk of 8,192
+    rows, 37,700 distinct features a member; PERF.md section 5): a step
+    10.52 ms, of which the owner's scatter-add 3.41, the gradients'
+    merge 2.13, select and backward 1.62, the four exchanges 1.53 (all
+    of it exposed: XLA emits them as synchronous operations), route 0.76,
+    spread 0.66, the owner's gather 0.25; one member alone on a chip's
+    share of the table 8.08."""
     feats, fields, vals, mask, y, sw = batch
-    w0, w, Vs = params              # Vs: [B, k], this member's shard
-    w0, w = (lax.pcast(w0, axis_name, to="varying"),
-             lax.pcast(w, axis_name, to="varying"))
-    B, k = Vs.shape
-    me = flat_index(axis_name)
-    rows = _slot_rows(feats, fields, cfg)       # [N, K] / [N, K, K]
-    S = rows.size
-    flat_rows = rows.reshape(-1).astype(jnp.int32)
+    _check_block_table(params, 3, cfg, sharded=True)
+    w0, Ts, rounds_run = params
+    w0 = lax.pcast(w0, axis_name, to="varying")
+    B, width = Ts.shape
+    me = collectives.flat_index(axis_name)
+    S = feats.size
+    i32 = jnp.int32
+    tile = _shard_tile(cap)
+    lane = jnp.arange(cap, dtype=i32)
 
-    # ---- forward: owner-routed row fetch ----
-    E_flat, gi, owner = _fetch_rows_sharded(Vs, flat_rows, me, axis_name)
-    E = E_flat.reshape(rows.shape + (k,))
+    with jax.named_scope("ffm.shard.route"):
+        live = (mask > 0) & (sw[:, None] > 0)
+        keys = jnp.where(live, feats, sparse_ops.SENTINEL).reshape(-1)
+        sk, perm = sparse_ops.sort_by_key(keys.astype(i32),
+                                          jnp.arange(S, dtype=i32))
+        uk, seg = sparse_ops.distinct_sorted(sk, S)
+        # seg in the slots' own order: where a slot's block is in the list
+        _, inv = sparse_ops.sort_by_key(perm, seg)
+        owner = jnp.where(uk == sparse_ops.SENTINEL, n, uk // B)
+        count = jnp.sum(owner[None, :] == jnp.arange(n, dtype=i32)[:, None],
+                        axis=1, dtype=i32)              # [n] asked of each
+        start = jnp.cumsum(count) - count
+        rounds = lax.pmax((jnp.max(count) + (cap - 1)) // cap, axis_name)
+        # a slice that starts in the list's last cap entries must not be
+        # pulled back over the ones before it
+        ukp = jnp.concatenate(
+            [uk, jnp.full((cap,), sparse_ops.SENTINEL, i32)])
+
+    def asked_of(r):
+        """[n, cap]: the ids this member asks of each owner in round r,
+        ascending, SENTINEL after them."""
+        with jax.named_scope("ffm.shard.route"):
+            return jnp.stack([
+                jnp.where(lane < count[m] - r * cap,
+                          lax.dynamic_slice_in_dim(
+                              ukp, start[m] + r * cap, cap),
+                          sparse_ops.SENTINEL)
+                for m in range(n)])
+
+    def local(ids, dead_as):
+        """Rows of this shard for ids it owns; ``dead_as`` for SENTINEL."""
+        return jnp.where(ids == sparse_ops.SENTINEL, dead_as, ids - me * B)
+
+    def fetch(r, ublk):
+        asks = collectives.all_to_all(asked_of(r), axis_name)   # [n, cap]
+
+        def gather_tile(out, ti, at):
+            got = _gather_blocks(Ts, local(ti, 0))
+            return lax.dynamic_update_slice_in_dim(out, got, at[0], axis=0)
+
+        answers = jnp.zeros((n * cap, width), Ts.dtype)
+        for j in range(n):
+            answers = sparse_ops.fold_live_tiles(
+                asks[j], lane + j * cap, tile, gather_tile, answers)
+        back = collectives.all_to_all(
+            answers.reshape(n, cap, width), axis_name)
+        with jax.named_scope("ffm.shard.spread"):
+            for m in range(n):      # owner m's answers, into their slice
+                at = start[m] + r * cap
+                mine = (lane < count[m] - r * cap)[:, None]
+                ublk = lax.dynamic_update_slice_in_dim(
+                    ublk, jnp.where(mine, back[m],
+                                    lax.dynamic_slice_in_dim(ublk, at, cap)),
+                    at, axis=0)
+        return ublk
+
+    # the distinct features' blocks, as ``uk`` lists them (the sentinel
+    # segment's, and the tail a last slice runs into: zeros)
+    ublk = lax.fori_loop(0, rounds, fetch,
+                         jnp.zeros((S + cap, width), Ts.dtype))
+    with jax.named_scope("ffm.shard.spread"):
+        blk = ublk[inv].reshape(feats.shape + (width,))     # [N, K, block]
 
     xv = vals * mask
-    loss, (g0, gw, gE), denom = _weighted_mean_grads(
-        (w0, w, E),
-        lambda p: _score_from_slots(p[0], p[1][feats],
-                                    _by_component(p[2], cfg), xv, cfg),
+    loss, (g0, gblk), denom = _weighted_mean_grads(
+        (w0, blk),
+        lambda p: _score_from_slots(
+            p[0], *_select_fields(p[1], fields, cfg), xv, cfg),
         y, sw, cfg, axis_name)
     g0 = lax.psum(g0, axis_name)
-    gw = lax.psum(gw, axis_name)     # linear part stays dense (small)
 
-    # ---- backward: owner-routed gradient rows ----
-    dest = flat_rows // B                           # [S]
-    onehot = dest[None, :] == jnp.arange(n)[:, None]
-    send = gE.reshape(S, k)[None] * onehot[..., None]   # [n, S, k]
-    recvg = lax.all_to_all(send, axis_name, split_axis=0,
-                           concat_axis=0, tiled=False)  # [n, S, k]
-    # received row j,s carries my local row id iff I own gi[j, s]
-    loc_ids = jnp.where(owner == me, gi - me * B, sparse_ops.SENTINEL)
-    si, sv = sparse_ops.sort_by_key(loc_ids.reshape(-1),
-                                    recvg.reshape(-1, k))
-    C = min(n * S, B)
-    li, lv = sparse_ops.segment_reduce_sorted(si, sv, C, Operators.SUM)
+    with jax.named_scope("ffm.grad_merge"):
+        _, ug = sparse_ops.segment_reduce_sorted(
+            sk, gblk.reshape(S, width)[perm], S + cap, Operators.SUM)
 
     lr = cfg.learning_rate
-    w0 = w0 - lr * (g0 / denom)
-    w = w - lr * (gw / denom + cfg.l2 * w)
     if cfg.l2:
-        Vs = Vs * (1.0 - lr * cfg.l2)
-    safe = jnp.where(li == sparse_ops.SENTINEL, B, li)
-    with jax.named_scope("ffm.table_update"):
-        Vs = Vs.at[safe].add(-(lr / denom) * lv, mode="drop")
-    return (w0, w, Vs), loss
+        Ts = Ts * (1.0 - lr * cfg.l2)
+    scale = -(lr / denom)
+
+    def give(r, Ts):
+        ids = collectives.all_to_all(asked_of(r), axis_name)
+        with jax.named_scope("ffm.shard.route"):
+            sends = jnp.stack([
+                lax.dynamic_slice_in_dim(ug, start[m] + r * cap, cap)
+                for m in range(n)])
+        grads = collectives.all_to_all(sends, axis_name)    # [n, cap, block]
+
+        def add_tile(Ts, ti, tv):
+            # a list's ids are distinct; what follows them is SENTINEL,
+            # whatever the sender's slice ran into: dropped
+            with jax.named_scope("ffm.table_update"):
+                return Ts.at[local(ti, B)].add(scale * tv, mode="drop")
+
+        for j in range(n):
+            Ts = sparse_ops.fold_live_tiles(ids[j], grads[j], tile,
+                                            add_tile, Ts)
+        return Ts
+
+    Ts = lax.fori_loop(0, rounds, give, Ts)
+    w0 = w0 - lr * (g0 / denom)
+    return (w0, Ts, rounds_run + rounds), loss
 
 
 # Rows of a 32-bit array's tile on the TPU: an [N, K, block] array rests
@@ -914,21 +1044,24 @@ class FMTrainer(DataParallelTrainer):
             raise Mp4jError(
                 "sparse_capacity applies to the replicated sparse path "
                 "only (sparse_grads=True, table_sharding='replicated'); "
-                "the sharded step sizes its buffers as "
-                "C = min(n_shards * batch_slots, table_rows) and the "
+                "the sharded step merges all of a member's slots and "
+                "exchanges them in rounds of a fixed size, and the "
                 "dense step has no capacity at all")
         self.table_sharding = table_sharding
-        # the replicated sparse step keeps the table by feature, in
-        # blocks, and updates it in place
-        self._blocks = sparse_grads and table_sharding == "replicated"
+        self._sharded = table_sharding == "sharded"
+        # the sparse steps keep the table by feature, in blocks (the
+        # sharded one a member's share of them), and update it in place
+        self._blocks = sparse_grads
         self._adagrad = cfg.optimizer == "adagrad"
-        if self._adagrad and not self._blocks:
+        if self._adagrad and (self._sharded or not self._blocks):
             raise Mp4jError(
                 "optimizer='adagrad' runs on the replicated sparse step "
                 "(sparse_grads=True, table_sharding='replicated'). The "
-                "sharded table would need its accumulators sharded with "
-                "the rows and the merge on the owner's side, after the "
-                "all_to_all; the dense step a [n_rows, k] accumulator "
+                "sharded step has the blocks and the exchange; what it "
+                "lacks is the accumulators in the sharded block and the "
+                "rule on the owner's side, where the members' lists "
+                "would have to be merged first (SGD adds them one by "
+                "one); the dense step a [n_rows, k] accumulator "
                 "and a mask of the touched rows, or l2 is no longer lazy")
         if self._adagrad and sparse_capacity is not None:
             raise Mp4jError(
@@ -938,6 +1071,11 @@ class FMTrainer(DataParallelTrainer):
         # AdaGrad's accumulators after the last fit / fit_stream, in the
         # shapes of (w0, w, V); ``opt_state=`` hands them to the next
         self.opt_state_ = None
+        # rounds of the sharded step's exchange that the last fit /
+        # fit_stream ran, all steps together (one a step unless some
+        # member held more than ``_EXCHANGE_CAP`` distinct features of
+        # one owner); None on a replicated table
+        self.exchange_rounds_ = None
         self._step = None
         self._step_key = None
         self._converters = None   # (widen, narrow), built on first use
@@ -960,12 +1098,19 @@ class FMTrainer(DataParallelTrainer):
         return self.cfg.n_features * self.cfg.n_fields
 
     @property
-    def n_rows_padded(self) -> int:
-        """Table rows padded to a multiple of the shard count (sharded
-        mode stores B = n_rows_padded / n rows per member; the padding
-        rows are never referenced — ids stay < n_rows)."""
+    def n_features_padded(self) -> int:
+        """Features padded to a multiple of the shard count: sharded mode
+        gives every member the same number of WHOLE features, so that a
+        boundary never cuts a feature's vectors (the padding features are
+        never referenced: ids stay < n_features)."""
         n = self.n_shards
-        return -(-self.n_rows // n) * n
+        return -(-self.cfg.n_features // n) * n
+
+    @property
+    def n_rows_padded(self) -> int:
+        """Rows of the public table as sharded mode places it: those of
+        ``n_features_padded`` features, n_rows_padded / n a member."""
+        return self.n_features_padded * (self.n_rows // self.cfg.n_features)
 
     def init_params(self, seed: int = 0):
         rng = np.random.default_rng(seed)
@@ -974,7 +1119,7 @@ class FMTrainer(DataParallelTrainer):
                  np.float32)
         params = (jnp.zeros((), jnp.float32),
                   jnp.zeros((self.cfg.n_features,), jnp.float32),
-                  jnp.asarray(V) if self.table_sharding != "sharded"
+                  jnp.asarray(V) if not self._sharded
                   else V)
         return self._stage_table(params)   # no-op unless sharded
 
@@ -989,7 +1134,7 @@ class FMTrainer(DataParallelTrainer):
         the first step call compiles the same program signature as
         every later one — see ``DataParallelTrainer._place_replicated``
         for the duplicate-compile failure this prevents."""
-        if self.table_sharding == "sharded":
+        if self._sharded:
             params = self._stage_table(params)
             return (*self._place_replicated(params[:2]), params[2])
         return self._place_replicated(params)
@@ -998,7 +1143,7 @@ class FMTrainer(DataParallelTrainer):
         """Sharded mode: place a host/full-size table onto the mesh
         (padded to n_rows_padded, block-sharded). Already-staged params
         (from init_params or a previous step) pass through."""
-        if self.table_sharding != "sharded":
+        if not self._sharded:
             return params
         V = params[2]
         if (isinstance(V, jax.Array)
@@ -1019,10 +1164,18 @@ class FMTrainer(DataParallelTrainer):
     _CONVERT_ROWS = 5 * 2 ** 17
 
     def _state_avals(self):
-        """Shapes of the step's ``(w0, T)`` (AdaGrad: ``(w0, T, a0)``),
-        replicated (compile proofs: check/checkaot.py, the AOT tests)."""
+        """Shapes of the step's ``(w0, T)`` replicated (AdaGrad:
+        ``(w0, T, a0)``), or on a sharded table ``(w0, T, rounds)`` with
+        the table's features cut over the mesh (compile proofs:
+        check/checkaot.py, the AOT tests)."""
         cfg = self.cfg
         rep = NamedSharding(self.mesh, P())
+        if self._sharded:
+            return (jax.ShapeDtypeStruct((), jnp.float32, sharding=rep),
+                    jax.ShapeDtypeStruct(
+                        (self.n_features_padded, _block_width(cfg)),
+                        jnp.float32, sharding=self._row_sharding()),
+                    jax.ShapeDtypeStruct((), jnp.int32, sharding=rep))
         shapes = ((), (cfg.n_features, _block_width(cfg)))
         return tuple(
             jax.ShapeDtypeStruct(shape, jnp.float32, sharding=rep)
@@ -1043,11 +1196,25 @@ class FMTrainer(DataParallelTrainer):
         ``(a0, Gw, GV)`` in the shapes of ``(w0, w, V)``, or None for
         fresh ones (``adagrad_init`` beside every parameter, made in the
         blocks: no table of ones is held), and lays them into the
-        blocks' second halves; ``narrow`` gives ``(params, opt)``."""
+        blocks' second halves; ``narrow`` gives ``(params, opt)``.
+
+        On a sharded table both run under ``shard_map``, every member on
+        the features it owns: their rows of the public table
+        ([n_rows_padded, k], cut over the mesh where ``_stage_table``
+        cuts it) and their linear weights lie on the member that holds
+        their blocks, so ``widen`` holds no collective at all and
+        ``narrow`` one, the gathering of the linear weights into the
+        replicated [n_features] vector that the public form keeps;
+        nothing of the table crosses. The state is ``(w0, T, rounds)``,
+        ``rounds`` from 0."""
         cfg = self._score_cfg if scoring else self.cfg
         adagrad = cfg.optimizer == "adagrad"
-        k, F = cfg.k, cfg.n_features
-        nf = self.n_rows // F       # vectors a block: n_fields, or FM's one
+        sharded = self._sharded and not scoring
+        k = cfg.k
+        nf = self.n_rows // cfg.n_features  # vectors a block: n_fields, or 1
+        # features a program converts: all, or a member's share
+        F = (self.n_features_padded // self.n_shards if sharded
+             else cfg.n_features)
         width, stride = _block_width(cfg), _block_stride(cfg)
         hw, wcol = _weights_width(cfg), _weight_column(cfg)
         B = max(1, min(F, self._CONVERT_ROWS // nf))
@@ -1125,18 +1292,50 @@ class FMTrainer(DataParallelTrainer):
         # committed like the placed params, so that the first step call
         # compiles the program every later one runs
         rep = NamedSharding(self.mesh, P())
+        if sharded:
+            axes, rows = self.axes, self._row_sharding()
+            n_features = cfg.n_features
+            on_shards = partial(jax.shard_map, mesh=self.mesh,
+                                check_vma=False)
+
+            def widen_shards(params):
+                w0, w, V = params
+
+                def mine(w, V):     # the bias takes no part in a table
+                    f0 = collectives.flat_index(axes) * F
+                    return widen((np.float32(0),
+                                  lax.dynamic_slice(w, (f0,), (F,)), V))[1]
+
+                T = on_shards(mine, in_specs=(P(), P(axes)),
+                              out_specs=P(axes))(
+                    jnp.pad(w, (0, self.n_features_padded - n_features)), V)
+                return jnp.copy(w0), T, jnp.zeros((), jnp.int32)
+
+            def narrow_shards(state):
+                w0, T = state[:2]
+                w, V = on_shards(lambda T: narrow((np.float32(0), T))[1:],
+                                 in_specs=P(axes),
+                                 out_specs=(P(axes), P(axes)))(T)
+                return jnp.copy(w0), w[:n_features], V
+
+            with spans.span("mp4j.step.build", key="table_converters",
+                            block_features=B, table_sharding="sharded"):
+                return (jax.jit(widen_shards, out_shardings=(rep, rows, rep)),
+                        jax.jit(narrow_shards,
+                                out_shardings=(rep, rep, rows)))
         with spans.span("mp4j.step.build", key="table_converters",
                         block_features=B):
             return (jax.jit(widen, out_shardings=rep),
                     jax.jit(narrow, out_shardings=rep))
 
     def _enter(self, params, opt_state=None):
-        """Public params -> the state the step carries. The replicated
-        sparse step gets its own ``(w0, T)`` (``widen``), under AdaGrad
-        with the accumulators ``opt_state`` in it (None: fresh ones);
-        every other step takes the placed params as they are."""
+        """Public params -> the state the step carries. The sparse steps
+        get their own ``(w0, T)`` (``widen``; a sharded table cut over
+        the mesh, with the count of exchange rounds beside it), under
+        AdaGrad with the accumulators ``opt_state`` in it (None: fresh
+        ones); the dense step takes the placed params as they are."""
         with spans.span("mp4j.stream.widen"):
-            if (self.table_sharding != "sharded"
+            if (not self._sharded
                     and params[2].shape != (self.n_rows, self.cfg.k)):
                 raise Mp4jError(
                     f"the embedding table must be [n_rows={self.n_rows}, "
@@ -1168,8 +1367,14 @@ class FMTrainer(DataParallelTrainer):
         """The step's state -> public params, in new buffers (``state``
         stays valid: the snapshot of an early-stopping round is taken
         this way too). AdaGrad's accumulators come out beside them, into
-        ``opt_state_``."""
-        with spans.span("mp4j.stream.narrow"):
+        ``opt_state_``; the sharded step's count of exchange rounds into
+        ``exchange_rounds_`` and the span's arguments."""
+        said = {}
+        if self._sharded:
+            # replicated: any of this process's copies
+            self.exchange_rounds_ = int(state[2].addressable_data(0))
+            said = dict(exchange_rounds=self.exchange_rounds_)
+        with spans.span("mp4j.stream.narrow", **said):
             if not self._blocks:
                 return state
             out = jax.block_until_ready(self._converters[1](state))
@@ -1181,7 +1386,7 @@ class FMTrainer(DataParallelTrainer):
         """Persist with the table in its portable [n_rows, k] shape
         (a sharded table is gathered + unpadded first, so the file is
         loadable at any shard count)."""
-        if self.table_sharding == "sharded":
+        if self._sharded:
             params = (self._to_host(params[0]),
                       self._to_host(params[1]), self.full_table(params))
         super().save_params(path, params)
@@ -1190,10 +1395,14 @@ class FMTrainer(DataParallelTrainer):
         cfg = self.cfg
         axes = self.axes
         dspec = P(axes)
-        if self.table_sharding == "sharded":
+        if self._sharded:
+            cap = _exchange_cap(per_shard_slots)
             step_fn = partial(train_step_sparse_sharded, cfg=cfg,
-                              n=self.n_shards, axis_name=axes)
-            pspec = (P(), P(), dspec)   # table sharded over the mesh
+                              n=self.n_shards, cap=cap, axis_name=axes)
+            # the table's features cut over the mesh; the bias and the
+            # count of rounds are every member's alike (psum, pmax),
+            # which VMA checking cannot prove of pcast values
+            pspec = (P(), dspec, P())
 
             @partial(jax.shard_map, mesh=self.mesh, check_vma=False,
                      in_specs=(pspec,) + (dspec,) * 6,
@@ -1203,8 +1412,17 @@ class FMTrainer(DataParallelTrainer):
                          sw[0])
                 return step_fn(params, batch)
 
-            with spans.span("mp4j.step.build", key=per_shard_slots):
-                return jax.jit(step)
+            # descriptors: the slots a member sorts; what an owner gathers
+            # and scatter-adds goes with the distinct features asked of it
+            with spans.span("mp4j.step.build", key=per_shard_slots,
+                            table_sharding="sharded", table_form="blocks",
+                            owners=self.n_shards, exchange_cap=cap,
+                            exchange_tile=_shard_tile(cap),
+                            descriptors=per_shard_slots, index_streams=1,
+                            block_width=_block_width(cfg),
+                            **_select_build_args(cfg)):
+                # the state is the trainer's own (``_enter``): donated
+                return jax.jit(step, donate_argnums=0)
         build_args = {}
         jit_args = {}
         if self.sparse_grads:
@@ -1372,12 +1590,14 @@ class FMTrainer(DataParallelTrainer):
         while the device runs it; losses are fetched once at the end.
         At most ``max_in_flight`` steps stay in flight, bounding device
         memory at ~max_in_flight staged batches. With
-        ``sparse_grads=True`` on a replicated table the step carries
-        the table by feature, the linear weights inside it, and updates
-        it in place: it is converted once here (``mp4j.stream.widen``)
-        and once before the return (``mp4j.stream.narrow``); the table
-        passed in is left as it was, and the one returned is
-        [n_rows, k]. AdaGrad's accumulators make the same two trips
+        ``sparse_grads=True`` the step carries the table by feature,
+        the linear weights inside it, and updates it in place: it is
+        converted once here (``mp4j.stream.widen``) and once before the
+        return (``mp4j.stream.narrow``); the table passed in is left as
+        it was, and the one returned is [n_rows, k] (a sharded table:
+        [n_rows_padded, k] cut over the mesh, every member converting
+        the features it owns; ``exchange_rounds_`` says how many rounds
+        the steps' exchanges ran). AdaGrad's accumulators make the same two trips
         inside the same blocks: ``opt_state=`` takes those of an earlier
         call (``self.opt_state_``, the shapes of ``(w0, w, V)``; None
         starts them at ``adagrad_init``), so that a stream of 2n chunks
@@ -1517,7 +1737,7 @@ class FMTrainer(DataParallelTrainer):
         lite, the 2.62 GB table: 0.06 s of device time, and both tables
         side by side while it runs). New buffers: the caller's arrays
         stay as they were. Span ``mp4j.ffm.score.enter``."""
-        if self.table_sharding == "sharded":
+        if self._sharded:
             raise Mp4jError(
                 "a sharded table is scored where it rests, a row a slot "
                 "pair (predict takes the params themselves); a model "
@@ -1591,8 +1811,9 @@ class FMTrainer(DataParallelTrainer):
         ``mp4j.ffm.score.stage`` / ``dispatch`` / ``fetch``.
 
         A sharded table keeps its own program
-        (``_build_sharded_predict``, the row form; ROADMAP R1a's)."""
-        if self.table_sharding == "sharded":
+        (``_build_sharded_predict``, the row form: a row a slot pair,
+        every member's requests gathered by every owner; ROADMAP R1)."""
+        if self._sharded:
             feats, fields, vals = self._stage_instances(feats, fields, vals)
             params = self._stage_table(params)
             N = feats.shape[0]

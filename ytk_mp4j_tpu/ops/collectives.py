@@ -9,7 +9,9 @@ the compiler schedule ICI DMA.
 
 Semantics of each collective match the reference's capability list
 (SURVEY.md section 1): allreduce / reduce / broadcast / allgather /
-gather / scatter / reduce_scatter, over a named axis. Operators with a
+gather / scatter / reduce_scatter, over a named axis, and one the
+reference lacks: all_to_all, the exchange by owner that a table sharded
+over the mesh is fetched and updated through. Operators with a
 native XLA reduction (SUM / MAX / MIN) use ``lax.psum / pmax / pmin``;
 PROD and user-defined operators tree-reduce a gathered axis (XLA fuses the
 reduction; correctness for any associative+commutative ``jnp_fn``).
@@ -193,6 +195,34 @@ def reduce_scatter(x, operator: Operator = Operators.SUM, axis_name="mp4j"):
     block = x.shape[0] // n
     idx = flat_index(axis_name)
     return lax.dynamic_slice_in_dim(full, idx * block, block, axis=0)
+
+
+@jax.named_scope("mp4j.all_to_all")
+def all_to_all(x, axis_name="mp4j", split_axis: int = 0,
+               concat_axis: int = 0, tiled: bool = False):
+    """Member i's j-th slice of ``x`` along ``split_axis`` arrives as
+    member j's i-th along ``concat_axis`` (i, j = :func:`flat_index`,
+    row-major over tuple axes): every member sends each other member its
+    own part, as an owner-routed fetch or update does. ``tiled=False``
+    takes ``x.shape[split_axis]`` equal to the axis size and keeps the
+    member axis; ``tiled=True`` splits ``split_axis`` into that many
+    equal runs and concatenates what arrives. One XLA ``all-to-all``,
+    tuple axes included; an identity on one member, where the collective
+    has nobody to exchange with (``tiled=False`` still moves the member
+    axis of length 1 to ``concat_axis``, as ``lax.all_to_all`` does).
+
+    The reference has no such collective (its socket plane routes by
+    key inside ``allreduceMap``), so the host plane gets no twin."""
+    n = _axis_size(axis_name)
+    if x.shape[split_axis] % n != 0 or (
+            not tiled and x.shape[split_axis] != n):
+        raise Mp4jError(
+            f"all_to_all splits dim {split_axis} of {x.shape} over {n} "
+            f"members: it must be {'a multiple of ' if tiled else ''}{n}")
+    if n == 1:
+        return x if tiled else jnp.moveaxis(x, split_axis, concat_axis)
+    return lax.all_to_all(x, axis_name, split_axis=split_axis,
+                          concat_axis=concat_axis, tiled=tiled)
 
 
 def barrier(axis_name="mp4j"):

@@ -144,6 +144,27 @@ def segment_reduce_sorted(idx, val, capacity: int,
     return out_idx, out_val
 
 
+def distinct_sorted(idx, capacity: int):
+    """``(out_idx, seg)`` of an ascending list ``idx``: its distinct ids
+    packed at the front of ``out_idx`` [capacity], ascending, SENTINEL
+    after them (:func:`segment_reduce_sorted`'s ``out_idx``), and
+    ``seg[i]``, the position of entry i's id in that list. For a caller
+    that needs the inverse as well as the merge: the blocks fetched for
+    ``out_idx`` are spread back over the entries by ``seg``, and
+    ``segment_reduce_sorted(idx, val, capacity)`` sums ``val`` into the
+    same positions. SENTINEL entries share the one segment after the live
+    ones, whose ``out_idx`` stays SENTINEL."""
+    first = jnp.ones((1,), dtype=jnp.int32)
+    bounds = jnp.concatenate([first, (idx[1:] != idx[:-1]).astype(jnp.int32)])
+    seg = jnp.cumsum(bounds) - 1
+    # seg is a cumulative sum and the operand is the list itself: the
+    # sorted form of the scatter is the cheap one here (PERF.md section
+    # 6, PR 35)
+    out_idx = (jnp.full((capacity,), SENTINEL, dtype=jnp.int32)
+               .at[seg].set(idx, mode="drop", indices_are_sorted=True))
+    return out_idx, seg
+
+
 def fold_live_tiles(idx, val, tile: int, body, carry):
     """Fold ``body`` over the LIVE prefix of a packed ``(idx, val)`` list,
     ``tile`` entries at a time: ``carry = body(carry, idx_tile, val_tile)``
