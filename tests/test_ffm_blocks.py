@@ -128,8 +128,9 @@ def test_block_step_matches_a_float64_step(rng, model, case, n_shards):
 def test_three_block_steps_with_decay_and_dedupe(rng, model, k, l2,
                                                  capacity):
     """Several steps, with the multiplicative l2 decay and with the
-    local merge of duplicate features before the all_gather: a capacity
-    in FEATURES (17 holds each shard's distinct features here). Feature
+    merged list at its default length, at ``n_features`` and at a tight
+    one: a capacity in FEATURES (17 holds the distinct features of both
+    shards' batches here, which are merged after the all_gather). Feature
     8 is in every row, twice in every other one; the last slot of every
     row is padding (value 0, id 0) and no other slot holds feature 0.
     At k = 4 the runs fill the block's 128 columns and the weight's
@@ -161,6 +162,103 @@ def test_three_block_steps_with_decay_and_dedupe(rng, model, k, l2,
     for _ in range(3):
         w_pad = w_pad * np.float32(1.0 - cfg.learning_rate * l2)
     assert got_w[0].view(np.uint32) == np.float32(w_pad).view(np.uint32)
+
+
+# ------------------------- the update loop over the merged list (PR 39)
+WIDE = 160      # features: all 24 x 6 slots of a batch can be distinct
+TILE = 8
+
+
+def _batch_holding(rng, case):
+    """24 rows x 6 slots whose ids are what ``case`` says; the distinct
+    count against a tile of 8: one, 144 (18 tiles), 21 (two tiles and
+    five), 32 (four whole tiles), 5 (under one)."""
+    feats, fields, vals, _ = _instances(rng, "plain")
+    S = feats.size
+    if case == "one_feature_in_every_slot":
+        feats[:] = 7
+    elif case == "padded_slots":
+        # value 0, id 0, in the last two slots; no other slot holds 0
+        feats = 1 + feats % 20
+        feats[:, -2:], vals[:, -2:] = 0, 0.0
+    else:
+        distinct = {"every_slot_distinct": S, "two_tiles_and_five": 21,
+                    "four_whole_tiles": 32, "under_one_tile": 5}[case]
+        ids = rng.choice(WIDE, distinct, replace=False)
+        feats = rng.permutation(np.concatenate(
+            [ids, rng.choice(ids, S - distinct)])).reshape(
+                feats.shape).astype(np.int32)
+        assert np.unique(feats).size == distinct
+    return feats, fields, vals
+
+
+@pytest.mark.parametrize("n_shards", [1, 2])
+@pytest.mark.parametrize("tile", [TILE, 7], ids=["tile_8", "tile_7"])
+@pytest.mark.parametrize("case", [
+    "one_feature_in_every_slot", "every_slot_distinct",
+    "two_tiles_and_five", "four_whole_tiles", "under_one_tile",
+    "padded_slots"])
+def test_the_merged_update_reaches_what_the_batch_holds(monkeypatch, rng,
+                                                        case, tile,
+                                                        n_shards):
+    """The step sums a batch's slot gradients by feature and scatter-adds
+    the merged list's live prefix a tile at a time: a float64 step's
+    parameters whatever the distinct features number against the tile
+    (one segment of all slots, every slot its own, a count the tile does
+    not divide, whole tiles, fewer than one; a tile of 7 does not divide
+    the list's 144 entries either), and every feature the batch does not
+    hold keeps its bits. Padded slots (value 0, id 0) sum to a gradient of
+    exactly 0.0: feature 0 keeps its bits too."""
+    monkeypatch.setattr(fm_mod, "_UPDATE_TILE", tile)
+    cfg = _cfg("ffm", n_features=WIDE)
+    feats, fields, vals = _batch_holding(rng, case)
+    y = rng.integers(0, 2, feats.shape[0]).astype(np.float32)
+    start = (np.float32(0.1),
+             (0.1 * rng.standard_normal(WIDE)).astype(np.float32),
+             (0.3 * rng.standard_normal((WIDE * NFIELDS, KDIM))).astype(
+                 np.float32))
+    tr = FMTrainer(cfg, mesh=make_mesh(n_shards), sparse_grads=True)
+    got, losses = tr.fit(feats, fields, vals, y, n_steps=1, params=start)
+    want_loss, want = _np_step(cfg, start, feats, fields, vals, y, None)
+    np.testing.assert_allclose(losses[0], want_loss, rtol=2e-6)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), w, rtol=2e-5, atol=2e-7)
+    held = np.zeros(WIDE, bool)
+    held[feats[vals > 0]] = True
+    got_w, got_V = np.asarray(got[1]), np.asarray(got[2])
+    assert (got_w[held] != start[1][held]).all()
+    assert np.array_equal(got_w[~held].view(np.uint32),
+                          start[1][~held].view(np.uint32))
+    rows = np.repeat(~held, NFIELDS)
+    assert np.array_equal(got_V[rows].view(np.uint32),
+                          start[2][rows].view(np.uint32))
+
+
+@pytest.mark.parametrize("capacity,tile,tiles", [
+    (None, 144, 1), (NFEAT, NFEAT, 1), (17, 17, 1), (None, TILE, 18)],
+    ids=["all_slots", "n_features", "17", "tile_8"])
+def test_step_build_span_says_the_update_loops_tile(monkeypatch, rng,
+                                                    capacity, tile, tiles):
+    """``mp4j.step.build`` of the SGD step: the tile of the loop over the
+    merged list (the whole list where that is shorter than
+    ``_UPDATE_TILE``) and its trips when every slot holds another feature;
+    ``sparse_capacity`` is the merged list's length."""
+    from ytk_mp4j_tpu.obs import spans
+
+    if tile == TILE:
+        monkeypatch.setattr(fm_mod, "_UPDATE_TILE", TILE)
+    cfg = _cfg("ffm", n_features=WIDE if capacity is None else NFEAT)
+    feats, fields, vals, _ = _instances(rng, "plain")
+    feats = 1 + feats % 15
+    y = rng.integers(0, 2, feats.shape[0]).astype(np.float32)
+    tr = FMTrainer(cfg, mesh=make_mesh(2), sparse_grads=True,
+                   sparse_capacity=capacity)
+    tr.fit(feats, fields, vals, y, n_steps=1)
+    (build,) = [s[6] for s in spans.snapshot()
+                if s[0] == "mp4j.step.build" and s[6].get("key") == 72
+                and s[6].get("capacity") == (capacity or 144)][-1:]
+    assert build["update_tile"] == tile and build["update_tiles"] == tiles
+    assert build["optimizer"] == "sgd" and build["descriptors"] == 72
 
 
 @pytest.mark.parametrize("n_shards", [1, 2])

@@ -404,6 +404,8 @@ def test_table_sized_detector():
 
 
 def test_ffm_step_scatters_into_the_table_where_it_rests(ffm_programs):
+    from ytk_mp4j_tpu.models import fm
+
     c, step = ffm_programs["config"], ffm_programs["step"]
     text = step.as_text()
     # 39 fields x 4 floats and the linear weight in two 128-lane words
@@ -416,41 +418,88 @@ def test_ffm_step_scatters_into_the_table_where_it_rests(ffm_programs):
     assert re.search(table + r" parameter\(1\)", text)
     assert re.search(r"input_output_alias=\{.*\{1\}: \(1, \{\}, may-alias\)",
                      text)
-    # one native gather and one native scatter of N x K descriptors, on
-    # the parameter itself: no loop of slices, no copy of the table
+    # one native gather of N x K descriptors, on the parameter itself
     d = ffm_programs["descriptors"]
     assert re.search(
         r"= f32\[%d,%d\]\S* fusion\(%%params_1_\S*, [^)]*\), kind=kCustom"
         r".*ffm\.table_gather" % (d, width), text)
-    assert re.search(
-        r"= " + table + r" fusion\(%params_1_\S*, [^)]*\), kind=kCustom"
-        r".*ffm\.table_update", text)
-    assert " while(" not in text
+    # the merged list's blocks are added a tile at a time through ONE loop
+    # over its live prefix (PR 39), whose carry is the table itself: no
+    # copy of 4.29 GB into or out of it
+    loops = re.findall(r"^\s*%\S+ = \((.*)\) while\(", text, re.M)
+    assert len(loops) == 1
+    assert len(re.findall(table, loops[0])) == 1
+    carried = set(re.findall(
+        r"(%\S+) = " + table + r" get-tuple-element\(", text))
+    tile = fm._update_tile(d)
+    assert d % tile == 0 and tile < d // 8
+    update = re.search(
+        r"= " + table + r" fusion\((%[^,)]+), [^)]*\), kind=kCustom"
+        r".*while/body/ffm\.table_update", text)
+    assert update is not None and update.group(1) in carried
+    # nothing of the old form: no scatter-add of all d slots into the table
+    assert not re.search(r"fusion\(%params_1_.*ffm\.table_update", text)
+    # the merge is on the step's path, the library's one sort in it, and
+    # what it holds is the slots' [79,872, 256] blocks, 82 MB each
+    assert re.search(r" sort\(.*ffm\.grad_merge/sparse\.sort_by_key", text)
+    assert re.search(r"ffm\.grad_merge/sparse\.segment_reduce", text)
+    assert len(re.findall(r" sort\(", text)) == 1
     assert _table_sized(text, "copy", F * width // 2) == []
     assert _table_sized(text, "transpose", F * width // 2) == []
+    assert step.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
 @pytest.mark.parametrize("which", ["step", "step_on_four"])
 def test_ffm_step_has_one_index_stream(ffm_programs, which):
     """The linear weights ride in the blocks: a step holds one gather and
-    one scatter, both on the table, and nothing at all of the shape
-    [n_features] (the parent gathered ``w[feats]``, scattered the
+    one scatter that take the table, and nothing at all of the shape
+    [n_features] (before PR 31 a step gathered ``w[feats]``, scattered the
     weights' gradient into a dense zero vector, updated all of ``w`` and,
-    on more than one chip, all-reduced that vector)."""
+    on more than one chip, all-reduced that vector). Since PR 39 the
+    scatter is the loop's, a tile of the merged list a trip, and is NOT
+    told that a tile's ids ascend (told, XLA passes over the whole 4.29 GB
+    operand a call: 14.3 ms on the chip, PERF.md section 6, PR 35); the
+    merge's own segmented sum, whose operand is the list, is told."""
     c = ffm_programs["config"]
     text = ffm_programs[which].as_text()
     F, width = c["n_features"], 256
+    chips = 4 if which == "step_on_four" else 1
+    table = r"f32\[%d,%d\]" % (F, width)
 
     def instructions(opcode):
         return re.findall(r"^.* = (.+?) %s\(" % opcode, text, re.M)
 
-    assert len(instructions("gather")) == len(instructions("scatter")) == 1
-    # both as native fusions that take the table itself
+    def under(opcode, scope):
+        return [line for line in text.splitlines()
+                if re.search(r" %s\(.*op_name=\"[^\"]*%s" % (opcode, scope),
+                             line)]
+
+    # of the table's shape: one scatter, the loop's, and the one gather's
+    # operand; every other gather and scatter is the merge's, on the slots
+    (update,) = [line for line in text.splitlines()
+                 if re.search(r"= " + table + r"\S* scatter\(", line)]
+    assert re.search(r"while/body/ffm\.table_update", update)
+    assert "indices_are_sorted=true" not in update
+    assert len(under("gather", r"ffm\.table_gather")) == 1
+    others = [s for s in instructions("scatter") + instructions("gather")
+              if not re.match(table, s)]
+    slots = chips * ffm_programs["descriptors"]
+    rows = ffm_programs["descriptors"] // c["max_nnz"]
+    assert all(re.match(r"[fs]32\[(%d|%d,%d)[,\]]"
+                        % (slots, rows, c["max_nnz"]), s)
+               for s in others), others
+    (added,) = [line for line in under(
+        "scatter", r"ffm\.grad_merge/sparse\.segment_reduce")
+        if "scatter-add" in line]
+    assert "indices_are_sorted=true" in added
+    # both as native fusions whose first operand is the table itself: the
+    # parameter for the gather, the loop's carry for the scatter-add
     custom = [line for line in text.splitlines()
               if " fusion(" in line and "kind=kCustom" in line
               and re.search(r"ffm\.table_(gather|update)", line)]
     assert len(custom) == 2
-    by_name = dict(re.findall(r"(%\S+) = (\S+) parameter\(", text))
+    by_name = dict(re.findall(
+        r"(%\S+) = (\S+) (?:parameter|get-tuple-element)\(", text))
     for line in custom:
         first = re.search(r" fusion\((%[^,)]+)", line).group(1)
         assert by_name[first].startswith("f32[%d,%d]" % (F, width)), line
@@ -463,8 +512,13 @@ def test_ffm_step_has_one_index_stream(ffm_programs, which):
     assert all(re.fullmatch(r"\(?(f32\[\]\S*,? ?)+\)?", shapes)
                for shapes in reduced), reduced
     assert bool(reduced) == (which == "step_on_four")
+    assert bool(instructions("all-gather")) == (which == "step_on_four")
     assert _table_sized(text, "copy", F * width // 2) == []
-    assert ffm_programs[which].memory_analysis().temp_size_in_bytes < 0.5e9
+    assert _table_sized(text, "transpose", F * width // 2) == []
+    # the merge's operands: every member's slots' blocks, [chips x 79,872,
+    # 256] f32, 82 MB a member each
+    assert (ffm_programs[which].memory_analysis().temp_size_in_bytes
+            < 0.25e9 * (1 + chips))
 
 
 def test_ffm_step_holds_one_table_and_small_temporaries(ffm_programs):
@@ -498,14 +552,16 @@ def test_ffm_conversions_go_a_block_at_a_time(ffm_programs, which):
 # v5e): sha256 of ``lowered.as_text()``, first taken at the commit before
 # ``FMConfig.optimizer`` existed (PR 31's tree) and again at PR 37, which
 # changed the step on purpose (``_select_fields``' output columns run
-# component by component, the weight inside the runs). The
+# component by component, the weight inside the runs) and at PR 39, which
+# did too (the slots' gradients merged, the merged list's live prefix
+# scatter-added in tiles). The
 # AdaGrad step is another function; choosing it must leave SGD's program
 # as it was, to the letter. A PR that changes the SGD step on purpose, or
 # a new jax, changes these with it.
 SGD_STEP_LOWERED_SHA256 = {
-    "step": "5338ede3b2b0082c7892a96af10c85cee0e42a039993b840a82d256b5674e6ca",
+    "step": "16e02177dd9072b401d36b6eda368ae90cbde0e6d3fe8dc5a59a89ab67bb6051",
     "step_on_four":
-        "83d305f79c452fbbcea5d20a23856b971c4c9dfaee1e79ffdcf5e17cbbead906",
+        "d3015a31b832f8a14e3770ad4b115ead175ac4af76b3dc041f2dd8e08490c901",
 }
 
 

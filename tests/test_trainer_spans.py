@@ -235,13 +235,14 @@ def test_fit_stream_leaves_exactly_its_spans(rng, ring, family,
         assert converters[6] == {"key": "table_converters",
                                  "block_features": 32}
         assert _inside(build, dispatch[0])
-        # one gather and one scatter descriptor a (sample, feature),
-        # the linear weight's included
+        # one gather descriptor a (sample, feature) and one scatter
+        # descriptor a distinct feature, the linear weight's included
         assert build[6] == {"key": (16 // N_SHARDS) * 4,
                             "table_form": "blocks",
                             "descriptors": (16 // N_SHARDS) * 4,
                             "index_streams": 1, "optimizer": "sgd",
                             "block_width": 128, "capacity": 32,
+                            "update_tile": 32, "update_tiles": 1,
                             "select_columns": "component"}
         # the table is converted before the first chunk is staged and
         # after the last loss is fetched
@@ -274,7 +275,8 @@ def test_step_build_span_says_the_table_form(rng, ring, kw, carries):
     and how many ids a member asks of one owner a round of the exchange
     (no more than the member has slots), and carries no ``capacity``
     (all of a member's slots are merged) and no ``optimizer`` (SGD, the
-    only rule it has)."""
+    only rule it has). Both replicated steps walk the merged list's live
+    prefix in tiles and say the tile."""
     tr, chunks = _ffm(rng, 1, **kw)
     tr.fit_stream(iter(chunks))
     build = [s[6] for s in _named(_trainer_spans(), "mp4j.step.build")
@@ -285,11 +287,13 @@ def test_step_build_span_says_the_table_form(rng, ring, kw, carries):
         want.update(table_form="blocks", descriptors=16, index_streams=1,
                     optimizer=kw.get("optimizer", "sgd"), block_width=128,
                     capacity=kw.get("sparse_capacity", 32),
-                    select_columns="component")
-    if "optimizer" in kw:
-        # the update loop's tile (the whole list where it is shorter than
-        # one) and its trips when every slot holds another feature
-        want.update(update_tile=32, update_tiles=1)
+                    select_columns="component",
+                    # the update loop's tile (the whole merged list where
+                    # it is shorter than one; SGD's step walks it too
+                    # since PR 39) and its trips when every slot holds
+                    # another feature
+                    update_tile=kw.get("sparse_capacity", 32),
+                    update_tiles=1)
     if "table_sharding" in kw:
         want.update(table_sharding="sharded", table_form="blocks",
                     owners=N_SHARDS, exchange_cap=16, exchange_tile=16,
@@ -521,10 +525,11 @@ def _lower_collectives(rng):
 @pytest.mark.parametrize("lower,scopes", [
     (_lower_gbdt, ["gbdt.hist", "gbdt.route", "gbdt.best_splits",
                    "gbdt.leaf"]),
-    (_lower_ffm, ["ffm.table_gather", "ffm.table_update"]),
+    (_lower_ffm, ["ffm.table_gather", "ffm.grad_merge", "ffm.table_update",
+                  "sparse.sort_by_key", "sparse.segment_reduce"]),
     (partial(_lower_ffm, sparse_capacity=8),
-     ["ffm.table_gather", "ffm.table_update", "sparse.sort_by_key",
-      "sparse.segment_reduce"]),
+     ["ffm.table_gather", "ffm.grad_merge", "ffm.table_update",
+      "sparse.sort_by_key", "sparse.segment_reduce"]),
     (partial(_lower_ffm, table_sharding="sharded"),
      ["ffm.shard.route", "mp4j.all_to_all", "ffm.table_gather",
       "ffm.shard.spread", "ffm.grad_merge", "ffm.table_update",
@@ -600,7 +605,9 @@ def _lower_score(rng):
 # purpose prints the new digest with this test and pins it (PR 35: the
 # AdaGrad step's, whose merge tells ``segment_sum`` its ids ascend; PR 37:
 # both FFM steps', whose select's output columns run component by
-# component).
+# component; PR 39: the SGD FFM step's, which merges its slots' gradients
+# and scatter-adds the merged list's live prefix in tiles, the AdaGrad
+# step's as it was).
 LOWERED = {
     "placer": (
         _lower_placer,
@@ -613,7 +620,7 @@ LOWERED = {
         "65e9cddff36da71d3c0d1b039b56f4d4137a955b457d12271ce2745044751514"),
     "ffm": (
         _lower_ffm,
-        "3c2afc6fb182843c9f57a903bdf17350b6bd7bf477931d6ef34469fb406b0b9d"),
+        "5fc1fd06e712c79b80a05be56bbf588a5809d6cb094459d1c50524c713ad2510"),
     "ffm-adagrad": (
         partial(_lower_ffm, optimizer="adagrad"),
         "4a36d027bb96c7faace1521c2076ca6a7123209d8f68a75a434bc5d43585d047"),

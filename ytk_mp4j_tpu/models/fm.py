@@ -16,17 +16,24 @@ TPU-first rebuild. Instances are padded to ``max_nnz`` static slots
   whenever the vocabulary fits comfortably on-chip.
 - **sparse mode** (``sparse_grads=True``): per-slot gradients ride as
   static-shape ``(feature, grad_block)`` buffers — ONE all_gather each,
-  then a single identity-dropping scatter-add into the table, which
-  merges duplicate features natively (the device-native analogue of
-  the reference's key-wise map merge). That holds of plain SGD, whose
-  update is linear in the gradient: there the map API's sort + segment
-  pack would be pure overhead (64.2 -> 38.1 ms/step on the previous
-  installation, 2026-07, not measured on this chip). AdaGrad
-  (``optimizer="adagrad"``, libffm's rule) squares a feature's SUMMED
-  gradient, so there the merge runs first, after the all_gather:
-  ``ops/sparse.sort_by_key`` + ``segment_reduce_sorted`` pack the
-  slots into distinct ``(feature, grad_block)`` pairs, the rule is
-  applied to the distinct features' blocks, and the scatter SETS them.
+  then the map plane's merge (:func:`_merge_slots`:
+  ``ops/sparse.sort_by_key`` + ``segment_reduce_sorted`` pack the slots
+  into distinct ``(feature, summed grad_block)`` pairs, the
+  device-native analogue of the reference's key-wise map merge), then a
+  walk over the merged list's LIVE PREFIX a tile at a time
+  (``ops/sparse.fold_live_tiles``) that touches the table once a
+  distinct feature. All three sparse steps share that shape and differ
+  in the walk's body: plain SGD scatter-ADDS a tile's summed gradients
+  (:func:`train_step_sparse`; the sharded step the same on the owner's
+  side), AdaGrad (``optimizer="adagrad"``, libffm's rule, which squares
+  a feature's SUMMED gradient) gathers the tile's blocks, applies the
+  rule and SETS them. SGD's update is linear in the gradient, so one
+  scatter-add of every slot would merge duplicates natively; the SGD
+  step merges all the same, because the serial unit charges by the
+  descriptor and a click log's chunk holds each feature two or three
+  times (42.5% distinct at Zipf 1.1: 7.00 ms for 79,872 slots against
+  2.5 + 2.9 for the merge and 34,000 features, TPU v5 lite, PERF.md
+  section 6, PR 39; the two forms meet at 61% distinct).
   Bandwidth ~nnz instead of ~|V|: the TPU translation of the
   reference's sparse map path. The replicated step holds the table by
   FEATURE, ``[n_features, block]`` with a feature's ``n_fields``
@@ -37,11 +44,12 @@ TPU-first rebuild. Instances are padded to ``max_nnz`` static slots
   (TPU v5 lite, PR 27, 79,872 descriptors: scatter-add 89.3 ns at
   1 KB against 87.0 ns at 16 B, gather 13.0 against 17.3). The
   feature's linear weight rides in the block's last column, so the
-  step makes that one gather and that one scatter-add and touches
-  nothing else of size ``n_features``. Under AdaGrad every parameter's
-  accumulator rides in the same block, in the block's second half (157
-  and 157 floats in a row of 384), so the state costs no descriptor of
-  its own. The step donates that table and updates it in place;
+  step makes that one gather a slot and that one scatter-add a distinct
+  feature and touches nothing else of size ``n_features``. Under
+  AdaGrad every parameter's accumulator rides in the same block, in the
+  block's second half (157 and 157 floats in a row of 384), so the
+  state costs no descriptor of its own. The step donates that table and
+  updates it in place;
   ``fit`` / ``fit_stream`` convert the public ``(w0, w, V)`` (and the
   accumulators, in the same shapes) once on the way in and once on the
   way out.
@@ -441,20 +449,25 @@ def train_step_sparse(params, batch, cfg: FMConfig, capacity: int,
     ``T`` [n_features, block]: a feature's vectors against every field
     side by side and its linear weight in the last column
     (:func:`_block_width`, :func:`_weight_column`). Instead of
-    psum'ing the dense gradient table, each shard ships its touched
-    ``(feature, grad_block)`` slots over ONE all_gather each and the
-    merged update is a single identity-dropping scatter-add into T,
-    which sums duplicate features natively (bandwidth ~touched slots,
-    not ~|V|). A (sample, feature) is ONE gather and ONE scatter
-    descriptor whatever ``n_fields``, the weight included: the serial
-    unit charges by the descriptor (PERF.md section 5), and the step
-    reads, reduces and writes nothing else of size ``n_features``.
-    ``capacity`` is the static bound, in features, that the optional
-    local dedupe packs into (it shrinks the all_gather payload when
-    capacity < S; nothing is ever dropped by the scatter). This is the
-    SGD step: its update is linear in the gradient, so the scatter-add
-    may sum a feature's duplicates. A rule that is not
-    (:func:`train_step_adagrad`) merges them first.
+    psum'ing the dense gradient table, each shard ships its
+    ``(feature, grad_block)`` slots over ONE all_gather each, the slots
+    are summed by feature (:func:`_merge_slots`: one sort, one segmented
+    sum) and the merged list's LIVE PREFIX is scatter-added into T a
+    tile of :func:`_update_tile` at a time
+    (``ops/sparse.fold_live_tiles``, the donated table the loop's carry,
+    updated where it rests): the shape of :func:`train_step_adagrad`
+    and of the sharded step's owner side, with a scatter-add for a
+    body. A (sample, feature) is ONE gather descriptor and a distinct
+    feature ONE scatter descriptor whatever ``n_fields``, the weight
+    included: the serial unit charges by the descriptor, live or
+    dropped (PERF.md section 5), so a chunk pays for the features it
+    holds, rounded up to a tile, and not for its slots; the step reads,
+    reduces and writes nothing else of size ``n_features``. SGD's
+    update is linear in the gradient, so every slot's gradient still
+    reaches its feature, summed in f32 before the add instead of by it.
+    ``capacity`` is the merged list's static length, in features: all
+    the slots of every shard by default, so that nothing is dropped; a
+    smaller one must hold the distinct features of a step.
 
     The table enters autodiff only through the GATHERED blocks
     (``_select_fields`` + ``_score_from_slots``), so the backward
@@ -478,40 +491,26 @@ def train_step_sparse(params, batch, cfg: FMConfig, capacity: int,
     if axis_name is not None:
         g0 = lax.psum(g0, axis_name)
 
-    # Local duplicate-feature merge (sort + segmented reduction) runs
-    # ONLY when it shrinks the all_gather payload (capacity < S): SGD's
-    # final scatter-add merges duplicates natively, so with
-    # capacity >= S the local sort would buy nothing (about 35 ms of
-    # pure overhead at S = 512k on the previous installation; not
-    # measured on this chip).
     S = feats.size
-    flat_feats = feats.reshape(-1)
-    flat_g = gblk.reshape(S, -1)
-    if capacity < S:
-        si, sv = sparse_ops.sort_by_key(flat_feats, flat_g)
-        li, lv = sparse_ops.segment_reduce_sorted(
-            si, sv, capacity, Operators.SUM)
-    else:
-        li, lv = flat_feats.astype(jnp.int32), flat_g
-    if axis_name is not None:
-        # NOT sparse_allreduce: its post-gather sort + segment reduce
-        # packs unique keys for the map API, but SGD's table update
-        # below is a scatter-add, which merges duplicates natively — the
-        # pack would be pure overhead here. Gather every shard's slots and
-        # scatter them all.
-        oi = lax.all_gather(li, axis_name, axis=0, tiled=True)
-        ov = lax.all_gather(lv, axis_name, axis=0, tiled=True)
-    else:
-        oi, ov = li, lv
+    ui, uv = _merge_slots(feats.reshape(-1).astype(jnp.int32),
+                          gblk.reshape(S, -1), capacity, axis_name)
     lr = cfg.learning_rate
     w0 = w0 - lr * (g0 / denom)
     if cfg.l2:
         # decay all rows, like the dense step: vectors and weights alike
         # (the padding columns stay 0.0)
         T = T * (1.0 - lr * cfg.l2)
-    safe = jnp.where(oi == sparse_ops.SENTINEL, T.shape[0], oi)
-    with jax.named_scope("ffm.table_update"):
-        T = T.at[safe].add(-(lr / denom) * ov, mode="drop")
+    scale = -(lr / denom)
+
+    def add_tile(T, ti, tv):
+        # NOT told that a tile's ids ascend and are distinct: told, XLA
+        # passes over the whole table a call (14.3 ms at 4.29 GB, PR 35)
+        with jax.named_scope("ffm.table_update"):
+            return T.at[jnp.where(ti == sparse_ops.SENTINEL, T.shape[0],
+                                  ti)].add(scale * tv, mode="drop")
+
+    T = sparse_ops.fold_live_tiles(ui, uv, _update_tile(capacity),
+                                   add_tile, T)
     return (w0, T), loss
 
 
@@ -549,7 +548,9 @@ def _merge_slots(keys, payload, capacity: int, axis_name):
     ``ops/sparse.sparse_allreduce``'s shape: the ``all_gather`` first,
     because two shards that both saw a feature must sum before a rule
     that is not linear in the gradient, then one sort and one segmented
-    reduction."""
+    reduction. Both replicated steps call it (the SGD step for the
+    descriptors it saves, the AdaGrad step because its rule needs the
+    sum) and hand the list to ``ops/sparse.fold_live_tiles``."""
     with jax.named_scope("ffm.grad_merge"):
         if axis_name is not None:
             keys = lax.all_gather(keys, axis_name, axis=0, tiled=True)
@@ -566,21 +567,24 @@ def _adagrad(p, G, g, lr):
     return p - lr * g / jnp.sqrt(G), G
 
 
-# Distinct features the AdaGrad step gathers, updates and sets back a
-# trip of its loop. A descriptor costs 102 ns through gather, rule and
-# scatter, live or dropped, and a chunk overshoots its live count by half
-# a tile on average; a trip costs about 3 us of its own. At the Criteo
-# cell's shape (79,872 slots, 33,940 live) that is flat from 512 to 2,048
-# and this is the fastest of the twelve tiles swept on the chip, 128 to
-# 79,872 (PERF.md section 5, PR 33; swept again at PR 35, the merge 0.41
-# ms shorter and the descriptor's price where it was: the same order,
-# 9.960 ms a step here, 9.982 at 1,536, 9.983 at 512).
+# Distinct features a replicated step's update loop takes a trip: the
+# AdaGrad step gathers, updates and sets them back, the SGD step
+# scatter-adds them. Under AdaGrad a descriptor costs 102 ns through
+# gather, rule and scatter, live or dropped, and a chunk overshoots its
+# live count by half a tile on average; a trip costs about 3 us of its
+# own. At the Criteo cell's shape (79,872 slots, 33,940 live) that is
+# flat from 512 to 2,048 and this is the fastest of the twelve tiles
+# swept on the chip, 128 to 79,872 (PERF.md section 5, PR 33; swept again
+# at PR 35, the merge 0.41 ms shorter and the descriptor's price where it
+# was: the same order, 9.960 ms a step here, 9.982 at 1,536, 9.983 at
+# 512). The SGD step is as flat (PR 39: 7.855 ms a step at 512, 7.818
+# here, 7.810 at 1,024, 7.848 at 1,536), so both share the one tile.
 _UPDATE_TILE = 768
 
 
 def _update_tile(capacity: int) -> int:
-    """The tile of :func:`train_step_adagrad`'s update loop for a merged
-    list of ``capacity`` entries."""
+    """The tile of a replicated step's update loop for a merged list of
+    ``capacity`` entries."""
     return min(_UPDATE_TILE, capacity)
 
 
@@ -604,10 +608,10 @@ def train_step_adagrad(params, batch, cfg: FMConfig, capacity: int,
 
     The rule squares g, so a feature's slots are summed BEFORE it
     (:func:`_merge_slots`: the map plane's sort and segmented reduce,
-    which SGD's scatter-add makes needless): a feature a chunk holds 200
-    times gets one update, not 200. ``capacity`` bounds the distinct
-    features and must be all the slots there are (or ``n_features``), so
-    that the merge drops nothing.
+    which the SGD step runs too, for the descriptors it saves): a
+    feature a chunk holds 200 times gets one update, not 200.
+    ``capacity`` bounds the distinct features and must be all the slots
+    there are (or ``n_features``), so that the merge drops nothing.
 
     The merged list is ascending, the distinct features first and
     SENTINEL after them, and the serial unit charges a dropped sentinel
@@ -623,7 +627,7 @@ def train_step_adagrad(params, batch, cfg: FMConfig, capacity: int,
     the donated table, which stays where it rests; tiles are disjoint,
     so every reached parameter is still updated exactly once a chunk; the
     sentinels of the last tile reached are dropped by the scatter, one
-    descriptor each way a feature as in the SGD step."""
+    descriptor each way a distinct feature."""
     feats, fields, vals, mask, y, sw = batch
     _check_block_table(params, 3, cfg)
     w0, T, a0 = _pcast_params(params, axis_name)
@@ -1017,6 +1021,11 @@ class FMTrainer(DataParallelTrainer):
     ``sparse_grads=True`` routes embedding gradients through the
     device-native sparse allreduce (the FFM workload of
     BASELINE.json configs[4]); default is the dense psum.
+    ``sparse_capacity`` (replicated SGD step only) is the length of the
+    list the step merges every shard's slot gradients into, so it bounds
+    the DISTINCT features that all shards' batches of one step hold
+    together; features beyond it would be dropped. The default, every
+    slot of every shard (or ``n_features``), drops nothing.
     """
 
     TABLE_SHARDINGS = ("replicated", "sharded")
@@ -1038,9 +1047,10 @@ class FMTrainer(DataParallelTrainer):
                 "path; pass sparse_grads=True")
         if sparse_capacity is not None and (
                 table_sharding == "sharded" or not sparse_grads):
-            # only the replicated sparse step consumes it (a bound on
-            # the distinct FEATURES a shard's batch touches); anywhere
-            # else a tuned capacity would be silently dropped
+            # only the replicated sparse step consumes it (the merged
+            # list's length: a bound on the distinct FEATURES that all
+            # shards' batches of a step touch together); anywhere else a
+            # tuned capacity would be silently dropped
             raise Mp4jError(
                 "sparse_capacity applies to the replicated sparse path "
                 "only (sparse_grads=True, table_sharding='replicated'); "
@@ -1439,17 +1449,16 @@ class FMTrainer(DataParallelTrainer):
             jit_args = dict(donate_argnums=0)
             # index_streams: gather/scatter-add pairs the step issues a
             # (sample, feature); the weights ride in the blocks
+            # update_tile: the update loop's tile; update_tiles: its
+            # trips for a chunk whose slots are all distinct
+            tile = _update_tile(cap)
             build_args = dict(table_form="blocks",
                               descriptors=per_shard_slots,
                               index_streams=1, optimizer=cfg.optimizer,
                               block_width=_block_width(cfg), capacity=cap,
+                              update_tile=tile,
+                              update_tiles=-(-cap // tile),
                               **_select_build_args(cfg))
-            if self._adagrad:
-                # the update loop's tile, and its trips for a chunk whose
-                # slots are all distinct
-                tile = _update_tile(cap)
-                build_args.update(update_tile=tile,
-                                  update_tiles=-(-cap // tile))
             # params are pcast to varying but returned under replicated
             # P() out_specs (every shard computes the identical update
             # from the all-gathered slots + psum'd scalars), which VMA

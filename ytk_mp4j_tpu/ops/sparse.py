@@ -88,7 +88,14 @@ def sort_by_key(idx, val):
 # Widest value row that still rides the sort network as payload; wider
 # rows fall back to argsort + one row gather (the comparator cost grows
 # linearly with payload width while the gather cost is width-invariant).
-_MAX_SORT_PAYLOAD_COLS = 128
+# Under one 128-lane word: a whole word or more is an FFM block
+# (``models/fm.py:_block_width``), which every sparse step merges since
+# PR 39, and the TPU's compiler takes over 19 minutes for a sort of 129
+# operands where it takes 13 s for the argsort and the gather (AOT for
+# v5e, 45,056 slots of a 22-field FFM; the CPU backend runs it five
+# times slower). Narrow rows still pay the network's compile: 136 s at
+# 9 columns, 323 s at 16, against 11 s (PERF.md section 7, PR 39).
+_MAX_SORT_PAYLOAD_COLS = 127
 
 
 def pad_to(idx, val, capacity: int, operator: Operator = Operators.SUM):
@@ -183,7 +190,15 @@ def fold_live_tiles(idx, val, tile: int, body, carry):
     The list is ascending with every live id once, so each tile is too,
     its sentinels trailing: bodies may rely on it.
     Inside ``shard_map`` every member must hold the same list (the trip
-    count is per member and the loop holds no collective of its own)."""
+    count is per member and the loop holds no collective of its own).
+
+    The three sparse FFM steps of ``models/fm.py`` are this loop with
+    another body: the SGD step scatter-adds a tile's summed gradients
+    into the table it carries, the AdaGrad step gathers, updates and
+    sets a tile's blocks, the sharded step's owner gathers or
+    scatter-adds each member's list. The serial unit charges a
+    descriptor live or dropped, so what the loop saves is the list's
+    dead tail."""
     L = idx.shape[0]
     if L % tile:
         idx, val = pad_to(idx, val, -(-L // tile) * tile)
