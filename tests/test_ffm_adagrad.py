@@ -236,6 +236,74 @@ def test_a_row_or_slot_that_does_not_count_touches_nothing(how):
         _assert_close(got, want, how)
 
 
+def _bits(a):
+    return np.asarray(a, np.float32).view(np.uint32)
+
+
+@pytest.mark.parametrize("rows,slots", [(128, 8), (1024, 1)],
+                         ids=["128x8", "1024x1"])
+def test_dead_rows_on_whole_1024s_change_no_bit(monkeypatch, rows, slots):
+    """A chunk whose slots are a whole number of 1,024s is stepped with
+    ``_DEAD_ROWS`` dead rows after it (``fm._with_dead_rows``). Their
+    weight is 0, so their slots' keys are SENTINEL like every dead
+    slot's, and the merge drops them after its sort
+    (``fm._merge_slots``): every feature's parameters and accumulators
+    come out as the step without the rows leaves them, to the bit, and
+    feature 0, which the dead slots name and no live slot holds, is not
+    reached. The loss and the bias's gradient are sums over the rows,
+    the same terms and eight of 0.0 after them: the same but for the
+    order the backend sums a vector that much longer in (f32; the bias's
+    accumulator squares it). ``model="fm"`` has no AdaGrad."""
+    rng = np.random.default_rng(17)
+    feats = rng.integers(1, NFEAT, (rows, slots)).astype(np.int32)
+    fields = rng.integers(0, NFIELDS, (rows, slots)).astype(np.int32)
+    vals = (rng.random((rows, slots)) + 0.5).astype(np.float32)
+    vals[::5, 0] = 0.0
+    y = (rng.random(rows) < 0.5).astype(np.float32)
+    sw = rng.integers(0, 3, rows).astype(np.float32)
+    params = _start(rng)
+
+    def one_step(dead):
+        monkeypatch.setattr(fm, "_DEAD_ROWS", dead)
+        tr = _trainer(max_nnz=slots)
+        got, losses = tr.fit(feats, fields, vals, y, n_steps=1,
+                             params=params, sample_weight=sw)
+        return got, tr.opt_state_, losses
+
+    assert fm._DEAD_ROWS == 8 and fm._dead_rows(rows * slots) == 8
+    (got, got_o, got_l), (want, want_o, want_l) = one_step(8), one_step(0)
+    np.testing.assert_allclose(got_l, want_l, rtol=1e-6, atol=0)
+    for g, w in ((got[0], want[0]), (got_o[0], want_o[0])):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=0)
+    for g, w in zip(got[1:] + tuple(got_o[1:]), want[1:] + tuple(want_o[1:])):
+        assert np.array_equal(_bits(g), _bits(w))
+    assert np.array_equal(_bits(got[2])[:NFIELDS], _bits(params[2])[:NFIELDS])
+    assert np.asarray(got[1])[0] == params[1][0]
+    assert np.all(np.asarray(got_o[2])[:NFIELDS] == np.float32(INIT))
+    assert np.asarray(got_o[1])[0] == np.float32(INIT)
+    assert np.all(np.asarray(got_o[1])[np.unique(feats[vals > 0])] > INIT)
+
+
+def test_a_chunk_off_the_1024s_lowers_to_the_program_it_was(monkeypatch):
+    """100 rows of 8 slots are no whole number of 1,024s: the step takes
+    no dead row and lowers to the same text whatever ``_DEAD_ROWS`` says;
+    128 rows of 8 do, and the step is traced on 136."""
+    def lowered(rows, dead):
+        monkeypatch.setattr(fm, "_DEAD_ROWS", dead)
+        tr = _trainer(max_nnz=8)
+        slots = (1, rows, 8)
+        i32, f32, row = (jax.ShapeDtypeStruct(slots, jnp.int32),
+                         jax.ShapeDtypeStruct(slots, jnp.float32),
+                         jax.ShapeDtypeStruct(slots[:2], jnp.float32))
+        return tr._build_step(rows * 8).lower(
+            tr._state_avals(), i32, i32, f32, f32, row, row).as_text()
+
+    assert lowered(100, 8) == lowered(100, 0)
+    assert "108x8" not in lowered(100, 8)
+    assert lowered(128, 8) != lowered(128, 0)
+    assert "136x8" in lowered(128, 8) and "136x8" not in lowered(128, 0)
+
+
 @pytest.mark.parametrize("n_devices", [1, 2])
 @pytest.mark.parametrize("entry", ["fit_stream", "fit"])
 def test_two_calls_with_the_state_handed_over_are_one_call(entry, n_devices):

@@ -261,6 +261,79 @@ def test_step_build_span_says_the_update_loops_tile(monkeypatch, rng,
     assert build["optimizer"] == "sgd" and build["descriptors"] == 72
 
 
+def _bits(a):
+    return np.asarray(a, np.float32).view(np.uint32)
+
+
+@pytest.mark.parametrize(
+    "rows,slots,n_shards", [(128, 8, 1), (1024, 1, 1), (256, 8, 2)],
+    ids=["128x8", "1024x1", "256x8-on-two"])
+@pytest.mark.parametrize("model", ["ffm", "fm"])
+def test_dead_rows_on_whole_1024s_change_no_bit(monkeypatch, rng, model,
+                                                rows, slots, n_shards):
+    """A member's chunk whose slots are a whole number of 1,024s is
+    stepped with ``_DEAD_ROWS`` dead rows after it (``_with_dead_rows``:
+    the table gather's index list is then one XLA pads itself). The rows
+    weigh nothing and the merge drops their slots after its sort, every
+    member's (``_merge_slots``): the same weights and table, to the bit,
+    as the step without them, and feature 0, which the dead slots name
+    and no live slot holds, keeps the bits it came with. The loss and
+    the bias's gradient are sums over the rows, the same terms and eight
+    of 0.0 after them, summed in f32 in the order the backend picks for
+    a vector that much longer: the last bits may differ (the loss's does
+    on the CPU, by one ulp)."""
+    cfg = _cfg(model, max_nnz=slots)
+    feats = rng.integers(1, NFEAT, (rows, slots)).astype(np.int32)
+    fields = rng.integers(0, NFIELDS, (rows, slots)).astype(np.int32)
+    vals = (rng.random((rows, slots)) + 0.5).astype(np.float32)
+    vals[::5, 0] = 0.0                      # padded slots among the live
+    sw = rng.integers(0, 3, rows).astype(np.float32)
+    y = rng.integers(0, 2, rows).astype(np.float32)
+    start = _start(cfg, rng)
+
+    def one_step(dead):
+        monkeypatch.setattr(fm_mod, "_DEAD_ROWS", dead)
+        tr = FMTrainer(cfg, mesh=make_mesh(n_shards), sparse_grads=True)
+        return tr.fit(feats, fields, vals, y, n_steps=1, params=start,
+                      sample_weight=sw)
+
+    assert fm_mod._DEAD_ROWS == 8
+    assert fm_mod._dead_rows(rows // n_shards * slots) == 8
+    (got, got_loss), (want, want_loss) = one_step(8), one_step(0)
+    np.testing.assert_allclose(got_loss, want_loss, rtol=1e-6, atol=0)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-6, atol=0)
+    for g, w in zip(got[1:], want[1:]):
+        assert np.array_equal(_bits(g), _bits(w))
+    per = NFIELDS if model == "ffm" else 1
+    assert np.array_equal(_bits(got[1])[0], _bits(start[1])[0])
+    assert np.array_equal(_bits(got[2])[:per], _bits(start[2])[:per])
+    assert not np.array_equal(_bits(got[1])[1:], _bits(start[1])[1:])
+
+
+@pytest.mark.parametrize("model", ["ffm", "fm"])
+def test_a_chunk_off_the_1024s_lowers_to_the_program_it_was(monkeypatch,
+                                                            model):
+    """100 rows of 8 slots are no whole number of 1,024s: the step takes
+    no dead row and lowers to the same text whatever ``_DEAD_ROWS`` says;
+    128 rows of 8 do, and the step is traced on 136."""
+    cfg = _cfg(model, max_nnz=8)
+
+    def lowered(rows, dead):
+        monkeypatch.setattr(fm_mod, "_DEAD_ROWS", dead)
+        tr = FMTrainer(cfg, mesh=make_mesh(1), sparse_grads=True)
+        slots = (1, rows, 8)
+        i32, f32, row = (jax.ShapeDtypeStruct(slots, jnp.int32),
+                         jax.ShapeDtypeStruct(slots, jnp.float32),
+                         jax.ShapeDtypeStruct(slots[:2], jnp.float32))
+        return tr._build_step(rows * 8).lower(
+            tr._state_avals(), i32, i32, f32, f32, row, row).as_text()
+
+    assert lowered(100, 8) == lowered(100, 0)
+    assert "108x8" not in lowered(100, 8)
+    assert lowered(128, 8) != lowered(128, 0)
+    assert "136x8" in lowered(128, 8) and "136x8" not in lowered(128, 0)
+
+
 @pytest.mark.parametrize("n_shards", [1, 2])
 def test_a_field_no_row_has_keeps_its_vectors_bit_for_bit(rng, n_shards):
     """A feature's block is scattered whole; the fields its rows lack

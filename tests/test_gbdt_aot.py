@@ -341,6 +341,19 @@ def _ffm_batch(mesh, chips, c, t):
                 (slots[:2], jnp.float32), (slots[:2], jnp.float32))]
 
 
+def _step_descriptors(c, t):
+    """The slots a replicated step runs on: the cell's chunk is 2,048 x 39
+    = 79,872, a whole number of 1,024s, so the step takes eight dead rows
+    more (``fm._with_dead_rows``, PR 40): gather, select, pairs,
+    backward and the merge's sort run on 80,184 slots; the segmented sum
+    and the update keep the chunk's 79,872 (``fm._merge_slots``)."""
+    from ytk_mp4j_tpu.models import fm
+
+    rows, K = t["rows_per_chunk"], c["max_nnz"]
+    assert (rows * K, fm._dead_rows(rows * K)) == (79872, 8)
+    return (rows + fm._dead_rows(rows * K)) * K
+
+
 @pytest.fixture(scope="module")
 def ffm_programs(topo_devices):
     """The step and both conversions of ``ffm-criteo.stream-zipf`` at the
@@ -373,6 +386,7 @@ def ffm_programs(topo_devices):
     return {
         "config": c,
         "descriptors": descriptors,
+        "step_descriptors": _step_descriptors(c, t),
         "step": step,
         "step_on_four": step_on_four,
         "lowered": {"step": lowered, "step_on_four": lowered_on_four},
@@ -418,11 +432,13 @@ def test_ffm_step_scatters_into_the_table_where_it_rests(ffm_programs):
     assert re.search(table + r" parameter\(1\)", text)
     assert re.search(r"input_output_alias=\{.*\{1\}: \(1, \{\}, may-alias\)",
                      text)
-    # one native gather of N x K descriptors, on the parameter itself
+    # one native gather of the step's N x K descriptors (the chunk's and
+    # the dead rows'), on the parameter itself
     d = ffm_programs["descriptors"]
     assert re.search(
         r"= f32\[%d,%d\]\S* fusion\(%%params_1_\S*, [^)]*\), kind=kCustom"
-        r".*ffm\.table_gather" % (d, width), text)
+        r".*ffm\.table_gather" % (ffm_programs["step_descriptors"], width),
+        text)
     # the merged list's blocks are added a tile at a time through ONE loop
     # over its live prefix (PR 39), whose carry is the table itself: no
     # copy of 4.29 GB into or out of it
@@ -483,10 +499,13 @@ def test_ffm_step_has_one_index_stream(ffm_programs, which):
     assert len(under("gather", r"ffm\.table_gather")) == 1
     others = [s for s in instructions("scatter") + instructions("gather")
               if not re.match(table, s)]
-    slots = chips * ffm_programs["descriptors"]
-    rows = ffm_programs["descriptors"] // c["max_nnz"]
-    assert all(re.match(r"[fs]32\[(%d|%d,%d)[,\]]"
-                        % (slots, rows, c["max_nnz"]), s)
+    # (the step's slots, 80,184 a member, or what the merge keeps of
+    # them after its sort, 79,872)
+    slots = chips * ffm_programs["step_descriptors"]
+    merged = chips * ffm_programs["descriptors"]
+    rows = ffm_programs["step_descriptors"] // c["max_nnz"]
+    assert all(re.match(r"[fs]32\[(%d|%d|%d,%d)[,\]]"
+                        % (slots, merged, rows, c["max_nnz"]), s)
                for s in others), others
     (added,) = [line for line in under(
         "scatter", r"ffm\.grad_merge/sparse\.segment_reduce")
@@ -508,7 +527,11 @@ def test_ffm_step_has_one_index_stream(ffm_programs, which):
     assert re.search(r"\[%d\]" % F, text) is None
     # what crosses chips: the scalars (loss, weight sum, the bias's
     # gradient) all-reduced, the slots' indices and blocks all-gathered
-    reduced = instructions("all-reduce")
+    # (since PR 40 a member's index list is 80,184 long, not whole tiles
+    # of 1,024, and XLA gathers such a list as an all-reduce of the
+    # members' lists laid into zeros: 1.28 MB beside the blocks' 328 MB)
+    reduced = [shapes for shapes in instructions("all-reduce")
+               if not shapes.startswith("s32[%d]" % slots)]
     assert all(re.fullmatch(r"\(?(f32\[\]\S*,? ?)+\)?", shapes)
                for shapes in reduced), reduced
     assert bool(reduced) == (which == "step_on_four")
@@ -554,14 +577,17 @@ def test_ffm_conversions_go_a_block_at_a_time(ffm_programs, which):
 # changed the step on purpose (``_select_fields``' output columns run
 # component by component, the weight inside the runs) and at PR 39, which
 # did too (the slots' gradients merged, the merged list's live prefix
-# scatter-added in tiles). The
-# AdaGrad step is another function; choosing it must leave SGD's program
+# scatter-added in tiles), and at PR 40 (the cell's chunk, 79,872 slots
+# and so whole 1,024s, is stepped with eight dead rows after it: gather,
+# select, pairs, backward and the merge's sort run on 80,184 slots, the
+# segmented sum and the update on the chunk's 79,872).
+# The AdaGrad step is another function; choosing it must leave SGD's program
 # as it was, to the letter. A PR that changes the SGD step on purpose, or
 # a new jax, changes these with it.
 SGD_STEP_LOWERED_SHA256 = {
-    "step": "16e02177dd9072b401d36b6eda368ae90cbde0e6d3fe8dc5a59a89ab67bb6051",
+    "step": "fb3a7086048a0461e45dcbdf329b9458b2594d935de468220e5653d24177eb6c",
     "step_on_four":
-        "d3015a31b832f8a14e3770ad4b115ead175ac4af76b3dc041f2dd8e08490c901",
+        "0211adbc75b0747a494082d61444270950b51a8c225c6b8236ae30aec8f3c19b",
 }
 
 
@@ -600,6 +626,7 @@ def adagrad_programs(topo_devices):
     return {
         "config": c,
         "descriptors": descriptors,
+        "step_descriptors": _step_descriptors(c, t),
         "step": trainer._build_step(descriptors).lower(
             trainer._state_avals(), *_ffm_batch(mesh, 1, c, t)).compile(),
         "widen": widen.lower(public, public).compile(),
@@ -620,10 +647,11 @@ def test_adagrad_step_sets_the_blocks_into_the_table_where_it_rests(
     assert re.search(r"input_output_alias=\{.*\{1\}: \(1, \{\}, may-alias\)",
                      text)
     # the slots' blocks are gathered from the parameter itself, one
-    # native fusion of N x K descriptors
+    # native fusion of the step's N x K descriptors (the dead rows' too)
     assert len(re.findall(
         r"= f32\[%d,%d\]\S* fusion\(%%params_1_\S*, [^)]*\), kind=kCustom"
-        r".*ffm\.table_gather" % (d, width), text)) == 1
+        r".*ffm\.table_gather"
+        % (adagrad_programs["step_descriptors"], width), text)) == 1
     # the distinct features' blocks go a tile at a time through ONE loop
     # over the merged list's live prefix, whose carry is the table
     # itself: no second loop, no copy of 6.44 GB into or out of it
@@ -1034,3 +1062,89 @@ def test_sharded_step_lowers_to_the_program_it_was(sharded_programs):
     assert "all_to_all" in text
     assert (hashlib.sha256(text.encode()).hexdigest()
             == SHARDED_STEP_LOWERED_SHA256)
+
+
+# ------------------- which form of its gather XLA emits for the table (PR 40)
+def _table_gathers(text):
+    """``(rows, width, integer_config, scoped VMEM bytes, index operand)``
+    of every native gather fusion under ``ffm.table_gather``: how many
+    descriptors XLA issues at a time, what it holds of VMEM for them, and
+    the fusion that makes its index list."""
+    found = []
+    for line in text.splitlines():
+        if not (" fusion(" in line and "kind=kCustom" in line
+                and re.search(r"ffm\.table_gather/gather", line)):
+            continue
+        rows, width = re.search(r"= f32\[(\d+),(\d+)\]", line).groups()
+        feeder = re.search(r" fusion\(%[^,)]+, %([^,)]+)\)", line).group(1)
+        found.append((
+            int(rows), int(width),
+            int(re.search(r'"integer_config":\{"integer":"(\d+)"',
+                          line).group(1)),
+            int(re.search(r'"used_scoped_memory_configs":\[\{[^}]*'
+                          r'"size":"(\d+)"', line).group(1)),
+            re.sub(r"[.\d]+$", "", feeder)))
+    return found
+
+
+def test_table_gathers_detector():
+    text = """
+  %fusion = f32[80184,256]{1,0:T(8,128)S(1)} fusion(%params_1_.1, %pad_clamp_fusion.1), kind=kCustom, calls=%fused_computation, metadata={op_name="jit(step)/ffm.table_gather/gather" stack_frame_id=1}, backend_config={"flag_configs":[],"integer_config":{"integer":"256"},"scoped_memory_configs":[],"used_scoped_memory_configs":[{"memory_space":"1","offset":"0","size":"1048576"}],"retry_config":{"retry_count":"0"}}
+  %fusion.1 = f32[79872,384]{1,0:T(8,128)} fusion(%params_1_.1, %broadcast_clamp_fusion), kind=kCustom, calls=%fused_computation.1, metadata={op_name="jit(step)/ffm.table_gather/gather"}, backend_config={"integer_config":{"integer":"128"},"used_scoped_memory_configs":[{"memory_space":"1","offset":"0","size":"458752"}]}
+  %fusion.17 = f32[79872,232]{1,0:T(8,128)} fusion(%param_0.71, %param_1.90), kind=kCustom, metadata={op_name="jit(step)/ffm.grad_merge/sparse.sort_by_key/gather"}
+"""
+    assert _table_gathers(text) == [
+        (80184, 256, 256, 1048576, "pad_clamp_fusion"),
+        (79872, 384, 128, 458752, "broadcast_clamp_fusion")]
+
+
+# (fixture, program, the gathers' [rows, width] in the program's order)
+_WIDE_GATHERS = {
+    "sgd": ("ffm_programs", "step", [(80184, 256)]),
+    "sgd_on_four": ("ffm_programs", "step_on_four", [(80184, 256)]),
+    "adagrad": ("adagrad_programs", "step", [(768, 384), (80184, 384)]),
+    "sharded": ("sharded_programs", "step", [(512, 256)] * 4),
+    "score": ("ffm_score_programs", "score_on_1", [(1600 * 40, 256)]),
+}
+
+
+@pytest.mark.parametrize("which", list(_WIDE_GATHERS))
+def test_every_table_gather_is_the_wide_form(request, which):
+    """XLA emits the table gather 256 descriptors at a time, with 4 KB of
+    scoped VMEM a descriptor's 1 KB block, where it pads the index list to
+    the next 1,024 itself (``pad_clamp_fusion``), and 128 at a time where
+    the list is whole 1,024s already (``broadcast_clamp_fusion``): 5.6 ns
+    a block on the chip against 11.1 (PERF.md section 6, PR 40). Every
+    program that gathers from the table holds the wide form only: the
+    replicated steps because a chunk of 2,048 x 39 slots takes eight dead
+    rows more (``fm._with_dead_rows``), the sharded step's tiles of 512
+    and the scoring program's of 1,600 x 40 because they are off the
+    1,024s as they are. And the steps cut nothing back in a pass of its
+    own: no ``slice``, ``pad`` or ``copy`` of anything the size of the
+    slots' blocks (the gathered blocks cut to the chunk's rows compile to
+    one more pass over the 82 MB): the dead rows' slots leave with the
+    tail of the merge's sorted order (``fm._merge_slots``)."""
+    from ytk_mp4j_tpu.models import fm
+
+    fixture, program, want = _WIDE_GATHERS[which]
+    programs = request.getfixturevalue(fixture)
+    text = programs[program].as_text()
+    found = _table_gathers(text)
+    assert sorted(g[:2] for g in found) == sorted(want), found
+    for rows, width, at_a_time, vmem, feeder in found:
+        assert at_a_time == 256, found
+        assert vmem == 256 * width * 16, found
+        assert feeder == "pad_clamp_fusion", found
+    if "step_descriptors" in programs:
+        cut = re.findall(
+            r"= f32\[(\d+),%d\]\S* (?:slice|pad|copy|concatenate)\("
+            % want[-1][1], text)
+        # (the AdaGrad rule lays a tile's halves side by side: 768 rows)
+        assert [n for n in cut if int(n) > fm._UPDATE_TILE] == [], cut
+        # nor inside another instruction at a price: the gradient blocks
+        # cut to the chunk's rows compile into the backward's last matmul,
+        # which XLA then prices at 1e18 cycles and the chip runs in 536 ms
+        # (PERF.md section 6, PR 40); the dearest here is under a million
+        cycles = [int(n) for n in re.findall(r'"estimated_cycles":"(\d+)"',
+                                             text)]
+        assert cycles and max(cycles) < 10 ** 7, max(cycles)

@@ -228,6 +228,28 @@ def test_sort_by_key_wide_payload_fallback(rng):
     np.testing.assert_array_equal(sv, val[order])
 
 
+@pytest.mark.parametrize("shape", [(), (0,), (3,), (5, 2),
+                                   (sp._MAX_SORT_PAYLOAD_COLS + 2,)],
+                         ids=["flat", "empty", "narrow", "two-dims", "wide"])
+def test_sort_by_key_keeps_the_head_of_the_sorted_stream(rng, shape):
+    """``keep=n`` is the first n entries of what the call without it
+    returns, whichever way the payload rides (the sort network, or the
+    argsort and a row gather of those n rows alone): a caller whose
+    last entries are SENTINEL padding sheds them there."""
+    L, n = 64, 40
+    idx = rng.integers(0, 30, L).astype(np.int32)
+    idx[rng.choice(L, L - n, replace=False)] = sp.SENTINEL
+    val = rng.standard_normal((L,) + shape).astype(np.float32)
+    want_i, want_v = sp.sort_by_key(jnp.asarray(idx), jnp.asarray(val))
+    si, sv = jax.jit(lambda i, v: sp.sort_by_key(i, v, keep=n))(
+        jnp.asarray(idx), jnp.asarray(val))
+    assert si.shape == (n,) and sv.shape == (n,) + shape
+    np.testing.assert_array_equal(si, np.asarray(want_i)[:n])
+    np.testing.assert_array_equal(sv, np.asarray(want_v)[:n])
+    assert (np.asarray(si) != sp.SENTINEL).all()
+    assert (np.asarray(want_i)[n:] == sp.SENTINEL).all()
+
+
 def test_sparse_allreduce_wide_vector_values(rng):
     """Map-of-arrays operands wider than the sort-payload cutoff ride
     the fallback inside sparse_allreduce; differential vs numpy."""

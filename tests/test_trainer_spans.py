@@ -240,6 +240,7 @@ def test_fit_stream_leaves_exactly_its_spans(rng, ring, family,
         assert build[6] == {"key": (16 // N_SHARDS) * 4,
                             "table_form": "blocks",
                             "descriptors": (16 // N_SHARDS) * 4,
+                            "dead_rows": 0,
                             "index_streams": 1, "optimizer": "sgd",
                             "block_width": 128, "capacity": 32,
                             "update_tile": 32, "update_tiles": 1,
@@ -284,7 +285,8 @@ def test_step_build_span_says_the_table_form(rng, ring, kw, carries):
     want = {"key": 16}
     if carries:
         # 17 floats, or 17 and their 17 accumulators, in one 128-lane word
-        want.update(table_form="blocks", descriptors=16, index_streams=1,
+        want.update(table_form="blocks", descriptors=16, dead_rows=0,
+                    index_streams=1,
                     optimizer=kw.get("optimizer", "sgd"), block_width=128,
                     capacity=kw.get("sparse_capacity", 32),
                     select_columns="component",
@@ -300,6 +302,26 @@ def test_step_build_span_says_the_table_form(rng, ring, kw, carries):
                     descriptors=16, index_streams=1,
                     block_width=128, select_columns="component")
     assert build == [want]
+
+
+@pytest.mark.parametrize("optimizer", ["sgd", "adagrad"])
+@pytest.mark.parametrize("rows,dead", [(2048, 8), (2000, 0)])
+def test_step_build_span_says_the_dead_rows(ring, optimizer, rows, dead):
+    """A member's chunk whose slots are a whole number of 1,024s (2,048
+    rows of 39) is stepped with eight dead rows after it
+    (``fm._with_dead_rows``), any other as it comes: ``dead_rows`` says
+    which form was built, and ``descriptors`` is the step's own count,
+    the dead rows' slots included; ``key`` and ``capacity`` stay the
+    caller's slots (the merge drops the dead rows' after its sort)."""
+    cfg = FMConfig(n_features=1 << 20, n_fields=39, k=4, max_nnz=39,
+                   model="ffm", optimizer=optimizer)
+    tr = FMTrainer(cfg, mesh=make_mesh(1), sparse_grads=True)
+    tr._build_step(rows * 39)
+    (build,) = [s[6] for s in _named(_trainer_spans(), "mp4j.step.build")]
+    assert build["key"] == rows * 39 == build["capacity"]
+    assert build["dead_rows"] == dead
+    assert build["descriptors"] == (rows + dead) * 39
+    assert build["optimizer"] == optimizer
 
 
 def _chunked(n_shards, per=1000, width=16):

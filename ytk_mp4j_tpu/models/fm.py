@@ -233,9 +233,63 @@ def _field_columns(cfg: FMConfig) -> np.ndarray:
 def _gather_blocks(T, feats):
     """The one gather of the step's table ``T`` [n_features, block]:
     one descriptor a (sample, feature) brings the feature's vectors
-    against every field and its linear weight; [N, K, block]."""
+    against every field and its linear weight; [N, K, block].
+
+    What a descriptor costs goes with the LENGTH of the index list. XLA
+    emits this gather 256 descriptors at a time (``integer_config`` 256,
+    1 MB of scoped VMEM) where it pads the flattened list to the next
+    1,024 itself (``pad_clamp_fusion``), and 128 at a time (256 KB)
+    where the list is whole 1,024s already and needs no pad
+    (``broadcast_clamp_fusion``): AOT for v5e, PR 40, every length
+    tried. On the chip, this gather alone on a [4,194,304, 256] table:
+    0.979 ms for 79,872 descriptors, 0.4535 for 80,184 (12.3 and 5.65 ns
+    each; 13.6 and 6.39 at 384 columns; PERF.md section 6, PR 40). The
+    scoring program's tile of 1,600 x 40 is on the wide side; a training
+    chunk of 2,048 x 39 = 79,872 slots was on the narrow one. So the
+    replicated steps take a chunk off the 1,024s before they call this
+    (:func:`_with_dead_rows`); cutting a longer gather back here instead
+    compiles to a ``slice`` of its own over the gathered blocks, one more
+    pass over 82 MB."""
     with jax.named_scope("ffm.table_gather"):
         return T[feats]
+
+
+# Rows a replicated step appends to a chunk whose slots are a whole number
+# of 1,024s (:func:`_with_dead_rows`): a sublane's worth, so that the
+# gathered [rows, K, block] still holds whole (8, 128) tiles. 2,048 x 39
+# becomes 2,056 x 39 = 80,184 slots, 0.39% more.
+_DEAD_ROWS = 8
+
+
+def _dead_rows(slots: int) -> int:
+    """Rows a replicated step adds to a member's chunk of ``slots`` slots
+    (rows x ``max_nnz``, static at trace time)."""
+    return _DEAD_ROWS if slots % 1024 == 0 else 0
+
+
+def _with_dead_rows(batch):
+    """``(batch, dead_slots)``: the step's batch with :func:`_dead_rows`
+    dead rows after it, where its slots are a whole number of 1,024s, and
+    the slots those rows hold. The table gather's index list is then one
+    XLA pads itself and emits in its wide form (:func:`_gather_blocks`),
+    and nothing is cut back afterwards: the select, the pair products and
+    the backward run on that many rows more (cutting the gradient blocks
+    back to the caller's rows compiles into the backward's last matmul
+    and makes it run 1,600 times as long: 536 ms, my chip run, PR 40).
+    A dead row is what ``fit_stream`` pads a short chunk with: ids,
+    fields, values, mask, label and weight 0, so its kappa, its gradient
+    blocks and its share of the loss's denominator are exactly 0.0. Its
+    slots go into the merge under SENTINEL keys (the AdaGrad step keys
+    every dead slot so; the SGD step these) and :func:`_merge_slots`
+    drops them after the sort, so feature 0 gets no descriptor of them.
+    Any other batch comes back as it is, and its step is the program it
+    was."""
+    dead = _dead_rows(batch[0].size)
+    if dead:
+        batch = tuple(
+            jnp.concatenate([a, jnp.zeros((dead,) + a.shape[1:], a.dtype)])
+            for a in batch)
+    return batch, dead * batch[0].shape[1]
 
 
 def _select_fields(blk, fields, cfg: FMConfig):
@@ -478,7 +532,7 @@ def train_step_sparse(params, batch, cfg: FMConfig, capacity: int,
     (1.8x the step time at 8M rows on the previous installation; not
     measured on this chip).
     """
-    feats, fields, vals, mask, y, sw = batch
+    (feats, fields, vals, mask, y, sw), dead = _with_dead_rows(batch)
     _check_block_table(params, 2, cfg)
     w0, T = _pcast_params(params, axis_name)
     blk = _gather_blocks(T, feats)              # [N, K, block]
@@ -492,8 +546,11 @@ def train_step_sparse(params, batch, cfg: FMConfig, capacity: int,
         g0 = lax.psum(g0, axis_name)
 
     S = feats.size
-    ui, uv = _merge_slots(feats.reshape(-1).astype(jnp.int32),
-                          gblk.reshape(S, -1), capacity, axis_name)
+    keys = feats.reshape(-1).astype(jnp.int32)
+    if dead:
+        keys = keys.at[S - dead:].set(sparse_ops.SENTINEL)
+    ui, uv = _merge_slots(keys, gblk.reshape(S, -1), capacity, axis_name,
+                          dead)
     lr = cfg.learning_rate
     w0 = w0 - lr * (g0 / denom)
     if cfg.l2:
@@ -541,7 +598,7 @@ def _touched_columns(cnt, cfg: FMConfig):
     return (cnt @ _field_columns(cfg)) > 0
 
 
-def _merge_slots(keys, payload, capacity: int, axis_name):
+def _merge_slots(keys, payload, capacity: int, axis_name, dead: int = 0):
     """Every shard's ``(feature, payload)`` slots -> at most ``capacity``
     DISTINCT features, ascending, each with the sum of its slots'
     payloads; SENTINEL keys are dropped and pad the tail. This is
@@ -550,12 +607,22 @@ def _merge_slots(keys, payload, capacity: int, axis_name):
     that is not linear in the gradient, then one sort and one segmented
     reduction. Both replicated steps call it (the SGD step for the
     descriptors it saves, the AdaGrad step because its rule needs the
-    sum) and hand the list to ``ops/sparse.fold_live_tiles``."""
+    sum) and hand the list to ``ops/sparse.fold_live_tiles``.
+
+    ``dead`` of a shard's slots are its step's dead rows'
+    (:func:`_with_dead_rows`): SENTINEL-keyed, so the last of the sorted
+    order, and cut from it before the payload is gathered by it. The
+    segmented sum then runs on the callers' slots, lists that are whole
+    1,024s as they were (on the Criteo cells' 80,184 it took 0.27 ms
+    longer than on their 79,872, more than the SGD step's gather had
+    saved: my chip runs, PR 40)."""
+    own = keys.shape[0]
     with jax.named_scope("ffm.grad_merge"):
         if axis_name is not None:
             keys = lax.all_gather(keys, axis_name, axis=0, tiled=True)
             payload = lax.all_gather(payload, axis_name, axis=0, tiled=True)
-        si, sv = sparse_ops.sort_by_key(keys, payload)
+        keep = keys.shape[0] // own * (own - dead) if dead else None
+        si, sv = sparse_ops.sort_by_key(keys, payload, keep=keep)
         return sparse_ops.segment_reduce_sorted(si, sv, capacity,
                                                 Operators.SUM)
 
@@ -628,7 +695,7 @@ def train_step_adagrad(params, batch, cfg: FMConfig, capacity: int,
     so every reached parameter is still updated exactly once a chunk; the
     sentinels of the last tile reached are dropped by the scatter, one
     descriptor each way a distinct feature."""
-    feats, fields, vals, mask, y, sw = batch
+    (feats, fields, vals, mask, y, sw), dead = _with_dead_rows(batch)
     _check_block_table(params, 3, cfg)
     w0, T, a0 = _pcast_params(params, axis_name)
     hw = _weights_width(cfg)
@@ -647,7 +714,7 @@ def train_step_adagrad(params, batch, cfg: FMConfig, capacity: int,
     keys = jnp.where(live > 0, feats, sparse_ops.SENTINEL).reshape(-1)
     payload = jnp.concatenate(
         [gblk.reshape(S, hw), cnt.reshape(S, -1)], axis=1)
-    ui, uv = _merge_slots(keys, payload, capacity, axis_name)
+    ui, uv = _merge_slots(keys, payload, capacity, axis_name, dead)
 
     lr = cfg.learning_rate
 
@@ -1439,8 +1506,10 @@ class FMTrainer(DataParallelTrainer):
             cap = self.sparse_capacity
             if cap is None:
                 # global unique touched features can't exceed total
-                # slots this step, nor the vocabulary
+                # slots this step, nor the vocabulary (the caller's
+                # slots: :func:`_merge_slots` drops a step's dead rows')
                 cap = min(cfg.n_features, per_shard_slots * self.n_shards)
+            dead = _dead_rows(per_shard_slots)
             step_fn = partial(
                 train_step_adagrad if self._adagrad else train_step_sparse,
                 cfg=cfg, capacity=cap, axis_name=axes)
@@ -1453,7 +1522,9 @@ class FMTrainer(DataParallelTrainer):
             # trips for a chunk whose slots are all distinct
             tile = _update_tile(cap)
             build_args = dict(table_form="blocks",
-                              descriptors=per_shard_slots,
+                              descriptors=(per_shard_slots
+                                           + dead * cfg.max_nnz),
+                              dead_rows=dead,
                               index_streams=1, optimizer=cfg.optimizer,
                               block_width=_block_width(cfg), capacity=cap,
                               update_tile=tile,
