@@ -62,6 +62,8 @@ def test_the_walk_sees_what_it_should():
     assert got == [f"{PKG}.obs.spans", f"{PKG}.parallel",
                    f"{PKG}.utils.tuning", f"{PKG}.comm.master"]
     assert [_allowed(m) for m in got] == [True, True, False, False]
+    assert [_host_plane(m) for m in got] == [False, False, False, True]
+    assert _host_plane(f"{PKG}.obs.health")
 
 
 @pytest.mark.parametrize("rel", FILES)
@@ -70,4 +72,30 @@ def test_device_layers_import_no_host_plane(rel):
         imports = package_imports(fh.read(), rel)
     bad = [f"{rel}:{line} imports {m}" for line, m in imports
            if not _allowed(m)]
+    assert not bad, "\n".join(bad)
+
+
+# The device drivers and the chip checks sit one step up: they may use
+# the rest of ``comm`` and ``utils``, but a chip run must not need the
+# socket backend's master, its slave, its transports, or the planes
+# built on them (ISSUE 41).
+DRIVERS = ("comm/tpu_comm.py", "comm/distributed.py",
+           "check/checktpu.py", "check/checkaot.py", "../chip_smoke.py")
+HOST_PLANE = tuple(f"{PKG}.{m}" for m in (
+    "resilience", "serve", "analysis", "transport", "comm.master",
+    "comm.process_comm", "obs"))
+
+
+def _host_plane(module: str) -> bool:
+    return module != f"{PKG}.obs.spans" and any(
+        module == h or module.startswith(h + ".") for h in HOST_PLANE)
+
+
+@pytest.mark.parametrize("rel", DRIVERS)
+def test_device_drivers_import_no_socket_backend(rel):
+    with open(os.path.join(ROOT, rel), encoding="utf-8") as fh:
+        imports = package_imports(fh.read(), rel)
+    assert imports, f"{rel}: the walk found no package import at all"
+    bad = [f"{rel}:{line} imports {m}" for line, m in imports
+           if _host_plane(m)]
     assert not bad, "\n".join(bad)

@@ -43,8 +43,8 @@ def test_cli_exits_zero_on_repo_strict():
 
 def test_package_lock_order_graph_is_cycle_free():
     """The job-wide lock-order graph over the real package has no
-    cycle — the "master -> controller only" / outbox disciplines are
-    machine-checked from this PR on (ISSUE 14 acceptance)."""
+    cycle — the "tuner lock, then master lock" / outbox disciplines
+    are machine-checked (ISSUE 14 acceptance)."""
     from ytk_mp4j_tpu.analysis.engine import Engine, Program
     contexts, errors = Engine(rules=[]).load_contexts([PKG_DIR])
     assert not errors, errors
@@ -52,7 +52,7 @@ def test_package_lock_order_graph_is_cycle_free():
     # sanity: the model actually sees the package's lock landscape
     # (a refactor that silently blinds discovery must fail loudly)
     displays = {d.display for d in model.locks.values()}
-    assert {"Master._lock", "_Slot.lock", "Autoscaler._lock",
+    assert {"Master._lock", "_Slot.lock", "Master._tuner_lock",
             "ProcessCommSlave._tel_lock",
             "ProcessCommSlave._master_lock"} <= displays
     assert len(model.edges) >= 2, "order edges vanished — model blind?"

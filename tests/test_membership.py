@@ -748,3 +748,385 @@ def test_replace_mid_map_sync_vocab_replay():
             for k in got[r][i]:
                 assert got[r][i][k] == want[r][i][k], (r, i, k)
     assert master.final_code == 0, log
+
+
+# ----------------------------------------------------------------------
+# what left with ISSUE 41: one way to change a roster, a rank's death.
+# The names that went are spelled in pieces here so that a grep for
+# them over the tree stays empty.
+# ----------------------------------------------------------------------
+_AS = "auto" + "scale"
+GONE_MASTER_ARGS = (_AS, _AS + "_cooldown", _AS + "_budget", _AS + "_tick",
+                    "provision" + "_hook", "provision" + "_cmd")
+
+
+def test_elastic_modes_are_off_replace_shrink(monkeypatch):
+    assert tuning.ELASTIC_MODES == ("off", "replace", "shrink")
+    monkeypatch.setenv("MP4J_ELASTIC", "grow")
+    with pytest.raises(Mp4jError) as ei:
+        tuning.elastic_mode()
+    assert all(m in str(ei.value) for m in ("off", "replace", "shrink"))
+    # the slave validates the same value, before it dials anything
+    with pytest.raises(Mp4jError, match="MP4J_ELASTIC='grow'"):
+        ProcessCommSlave("127.0.0.1", 1, timeout=0.1)
+
+
+def test_master_rejects_elastic_grow_before_binding():
+    with pytest.raises(Mp4jError) as ei:
+        Master(2, elastic="grow")
+    assert "['off', 'replace', 'shrink']" in str(ei.value)
+
+
+def test_master_constructor_lost_the_controller_seams():
+    import inspect
+    params = inspect.signature(Master.__init__).parameters
+    assert not set(GONE_MASTER_ARGS) & set(params)
+    # what drives a roster change now: the mode, the pool, the deadline
+    assert {"elastic", "spares", "adopt_secs"} <= set(params)
+    for name in GONE_MASTER_ARGS:
+        with pytest.raises(TypeError):
+            Master(2, **{name: None})
+
+
+def test_slave_has_no_roster_growth_boundary():
+    assert not hasattr(ProcessCommSlave, "resize" + "_point")
+    # the boundaries a schedule can name are the collectives and this
+    assert callable(ProcessCommSlave.barrier)
+    from ytk_mp4j_tpu.comm import master as master_mod
+    assert not hasattr(master_mod, "RESIZE")
+    assert not hasattr(Master, "request_" + "planned" + "_evict")
+
+
+# ----------------------------------------------------------------------
+# the replace and shrink rounds, branch by branch, on a scripted
+# master: no sockets, no threads of ranks, no sleeps. Each wire records
+# what the master pushed; the test plays the ranks' control messages.
+# ----------------------------------------------------------------------
+class _Wire:
+    """A control channel that records every push; ``recv`` blocks
+    until ``close`` (a parked spare's serve thread waits there)."""
+
+    def __init__(self):
+        self.sent: list = []
+        self.closed = False
+        self._gate = threading.Event()
+
+    def send_obj(self, obj):
+        if self.closed:
+            raise OSError("wire closed")
+        self.sent.append(obj)
+
+    def recv(self):
+        self._gate.wait(JOIN)
+        raise OSError("wire closed")
+
+    def close(self, *args, **kwargs):
+        self.closed = True
+        self._gate.set()
+
+    def pushes(self):
+        """The pushes in order, less the health-alert pipe (a DEAD
+        verdict or a tuner event rides to the lowest live rank)."""
+        return [m for m in self.sent
+                if isinstance(m, tuple) and m[0] != "health_alert"]
+
+    def kinds(self):
+        return [m[0] for m in self.pushes()]
+
+    def last(self, kind):
+        return [m for m in self.sent
+                if isinstance(m, tuple) and m[0] == kind][-1]
+
+
+@pytest.fixture
+def scripted():
+    from ytk_mp4j_tpu.comm.master import _Slot
+    made = []
+
+    def make(n, elastic, spares=0, **kw):
+        m = Master(n, elastic=elastic, log_stream=io.StringIO(), **kw)
+        made.append(m)
+        wires = [_Wire() for _ in range(n)]
+        m._slots = [_Slot(r, w) for r, w in enumerate(wires)]
+        m._roster = [("h", 9000 + r, "") for r in range(n)]
+        pool = []
+        for k in range(spares):
+            rec = membership.SpareRecord(k, _Wire(), ("s", 9100 + k, ""))
+            m._spare_pool.append(rec)
+            m._spare_seq += 1
+            pool.append(rec)
+        return m, wires, pool
+
+    yield make
+    for m in made:
+        m._stop.set()
+        m._release_spares("test over")
+        m._server.close()
+
+
+def _ack(m, ranks, epoch, seq, inflight=True):
+    for r in ranks:
+        m._handle_abort_ack(r, {"epoch": epoch, "seq": seq,
+                                "inflight": inflight})
+
+
+def _manifest(m, rank, epoch, **extra):
+    m._handle_manifest(rank, {"epoch": epoch, "vocab": {"i": [7, 9]},
+                              "seq": 5, "inflight": True,
+                              "stats_seq": 11, "barrier_gen": 2,
+                              **extra})
+
+
+def test_replace_round_adopts_then_releases_one_epoch(scripted):
+    m, wires, (spare,) = scripted(3, "replace", spares=1)
+    m._on_rank_dead(1, "connection lost", "rank 1 is dead; aborting")
+    # one round, one epoch: survivors torn down, lowest donates
+    assert wires[0].kinds() == ["abort", "manifest_req"]
+    assert wires[2].kinds() == ["abort"]
+    assert wires[1].kinds() == ["abort_fatal"]     # the declared-dead
+    assert spare.ch.sent == []                     # nothing before acks
+    _ack(m, (0, 2), 1, 5)
+    assert spare.ch.sent == []                     # ... nor the manifest
+    _manifest(m, 0, 1)
+    kind, info = spare.ch.sent[-1]
+    assert kind == "adopt"
+    assert set(info) == {"rank", "epoch", "roster", "job", "seq",
+                         "stats_seq", "barrier_gen", "vocab",
+                         "watermark", "why"}
+    assert (info["rank"], info["epoch"], info["seq"]) == (1, 1, 4)
+    assert (info["stats_seq"], info["barrier_gen"]) == (11, 2)
+    assert info["roster"][1] == spare.entry
+    assert info["why"] == "connection lost"
+    assert "abort_go" not in wires[0].kinds()      # held for the ack
+    slot = m._finish_adoption(spare)
+    assert slot.rank == 1 and m._slots[1] is slot
+    go = wires[0].last("abort_go")
+    assert go[1] == 1 and go[2]["replaced"] == [1]
+    assert go[2]["roster"][1] == spare.entry
+    assert wires[2].last("abort_go") == go
+    # the joiner needs no go: its adoption seeded it AT the epoch
+    assert spare.ch.kinds() == ["adopt"]
+    # the round closed whole
+    assert m._abort_since is None and m._round_kind is None
+    assert m._round_dead == {} and m._round_adopted == {}
+    assert m._departed == {} and m._spare_pool == []
+    ms = m.membership_status()
+    assert set(ms) == {"mode", "replacements", "shrinks",
+                       "spares_available", "spares_total", "badges",
+                       "events"}
+    assert ms["replacements"] == 1 and ms["badges"] == {
+        "1": "REPLACED@e1"}
+    assert [e["kind"] for e in ms["events"]] == ["replace"]
+
+
+def test_death_during_an_open_abort_round_upgrades_it(scripted):
+    """A rank dies while a plain abort round is collecting acks: the
+    SAME epoch becomes the membership round (no second fan-out), and
+    the dead rank's missing ack no longer gates it."""
+    m, wires, (spare,) = scripted(3, "replace", spares=1)
+    m._handle_abort_req(0, {"epoch": 0, "collective": "allreduce",
+                            "error": "reset"})
+    assert [w.kinds() for w in wires] == [["abort"]] * 3
+    _ack(m, (0,), 1, 5)
+    m._mark_departed(2, "unreachable on push")
+    assert m._abort_epoch == 1 and m._round_kind == "replace"
+    assert wires[0].kinds() == ["abort", "manifest_req"]
+    assert wires[1].kinds() == ["abort"]            # not fanned twice
+    _ack(m, (1,), 1, 5)
+    _manifest(m, 0, 1)
+    assert spare.ch.last("adopt")[1]["rank"] == 2
+    m._finish_adoption(spare)
+    assert wires[1].last("abort_go")[2]["replaced"] == [2]
+
+
+def test_second_death_mid_round_takes_a_second_spare(scripted):
+    m, wires, (s0, s1) = scripted(4, "replace", spares=2)
+    m._on_rank_dead(3, "connection lost", "rank 3 is dead")
+    _ack(m, (0, 1, 2), 1, 5)
+    _manifest(m, 0, 1)
+    assert s0.ch.last("adopt")[1]["rank"] == 3
+    # rank 2 dies while spare #0 is still seeding rank 3
+    m._on_rank_dead(2, "connection lost", "rank 2 is dead")
+    assert m._abort_epoch == 1                      # same round
+    assert s1.ch.last("adopt")[1]["rank"] == 2
+    # each joiner's roster names every adoption assigned so far
+    assert s1.ch.last("adopt")[1]["roster"][3] == s0.entry
+    m._finish_adoption(s0)
+    assert "abort_go" not in wires[0].kinds()       # one still pending
+    m._finish_adoption(s1)
+    go = wires[0].last("abort_go")
+    assert go[2]["replaced"] == [2, 3]
+    assert m.membership_status()["replacements"] == 2
+
+
+def test_donor_death_moves_the_manifest_request(scripted):
+    m, wires, _ = scripted(4, "replace", spares=2)
+    m._on_rank_dead(3, "connection lost", "rank 3 is dead")
+    assert wires[0].kinds() == ["abort", "manifest_req"]
+    m._on_rank_dead(0, "connection lost", "rank 0 is dead")
+    assert wires[1].kinds() == ["abort", "manifest_req"]
+    assert m._round_manifest_from == 1
+    # the dead donor's manifest, had it been in flight, is refused
+    m._slots[0].dead = True
+    _ack(m, (1, 2), 1, 5)
+    _manifest(m, 1, 1)
+    assert sorted(rec.adopting_rank for rec in m._spare_pool) == [0, 3]
+
+
+def test_spare_lost_mid_adoption_retries_then_goes_terminal(scripted):
+    m, wires, (s0, s1) = scripted(3, "replace", spares=2)
+    m._on_rank_dead(1, "connection lost", "rank 1 is dead; aborting")
+    _ack(m, (0, 2), 1, 5)
+    _manifest(m, 0, 1)
+    assert s0.ch.kinds() == ["adopt"] and s1.ch.sent == []
+    m._spare_gone(s0, "adoption not acked within 1.0s")
+    assert s0.ch.closed and not s0.alive
+    assert s1.ch.last("adopt")[1]["rank"] == 1      # next one tried
+    assert m._fatal_msg is None
+    m._spare_gone(s1, "connection lost")
+    # the pool is dry: the same clean fatal as never having had one
+    assert m._fatal_msg.startswith("rank 1 is dead; aborting; no warm "
+                                   "spare available")
+    for w in (wires[0], wires[2]):
+        assert w.last("abort_fatal")[1] == m._fatal_msg
+    # a late ack of the lost adoption is stale, not a resurrection
+    assert m._finish_adoption(s0) is None
+
+
+def test_replace_with_dry_pool_is_fatal_at_once(scripted):
+    m, wires, _ = scripted(3, "replace", spares=0)
+    m._on_rank_dead(2, "connection lost", "rank 2 is dead; aborting")
+    assert m._fatal_msg == ("rank 2 is dead; aborting; no warm spare "
+                            "available to replace rank(s) [2]")
+    assert "abort" not in wires[0].kinds()          # no round fanned
+    assert wires[0].last("abort_fatal")[1] == m._fatal_msg
+
+
+def test_spare_registered_mid_round_waits_in_the_pool(scripted):
+    """A spare that registers while a round is open is pooled and
+    acked, and changes nothing about the round in flight."""
+    m, wires, (s0,) = scripted(3, "replace", spares=1)
+    m._on_rank_dead(1, "connection lost", "rank 1 is dead")
+    _ack(m, (0,), 1, 5)                             # rank 2 still out
+    late = _Wire()
+    m._register_spare(late, ("s", 9200, ""))
+    assert late.sent == [{"spare": 1, "job": m.job_id}]
+    assert m.membership_status()["spares_available"] == 2
+    assert s0.ch.sent == [] and m._round_adoptions == {}
+    _ack(m, (2,), 1, 5)
+    _manifest(m, 0, 1)
+    assert s0.ch.kinds() == ["adopt"]               # oldest first
+    assert late.kinds() == []
+    m._finish_adoption(s0)
+    assert m.membership_status()["spares_available"] == 1
+
+
+def test_manifest_is_refused_outside_its_replace_round(scripted):
+    m, wires, (spare,) = scripted(3, "replace", spares=1)
+    _manifest(m, 0, 0)                              # no round at all
+    assert m._round_manifest is None
+    m._handle_abort_req(0, {"epoch": 0, "collective": "x", "error": "e"})
+    _manifest(m, 0, 1)                              # a plain abort round
+    assert m._round_manifest is None
+    _ack(m, (0, 1, 2), 1, 5)
+    assert wires[1].last("abort_go") == ("abort_go", 1)
+    m._on_rank_dead(2, "connection lost", "rank 2 is dead")
+    _manifest(m, 0, 1)                              # the LAST epoch's
+    assert m._round_manifest is None
+    _manifest(m, 0, 2)
+    assert m._round_manifest_from == 0 and m._round_manifest["seq"] == 5
+    # and a shrink master never takes one
+    ms, _, _ = scripted(3, "shrink")
+    ms._on_rank_dead(2, "connection lost", "rank 2 is dead")
+    _manifest(ms, 0, 1)
+    assert ms._round_manifest is None
+
+
+def test_replace_round_spanning_a_collective_boundary_is_fatal(scripted):
+    m, wires, (spare,) = scripted(3, "replace", spares=1)
+    m._on_rank_dead(1, "connection lost", "rank 1 is dead")
+    m._handle_abort_ack(0, {"epoch": 1, "seq": 5, "inflight": True})
+    m._handle_abort_ack(2, {"epoch": 1, "seq": 7, "inflight": True})
+    assert "spans a collective boundary" in m._fatal_msg
+    assert spare.ch.kinds() == ["release"]          # never adopted
+    assert spare.ch.closed
+
+
+def test_shrink_renumbers_and_releases_a_filled_barrier(scripted):
+    m, wires, _ = scripted(4, "shrink")
+    for r in (0, 2, 3):                             # rank 1 never came
+        m._barrier(m._slots[r], 0)
+    assert "barrier_release" not in wires[0].kinds()
+    m._on_rank_dead(1, "connection lost", "rank 1 is dead")
+    _ack(m, (0, 2), 1, 5, inflight=False)
+    assert m.slave_num == 4                         # one ack missing
+    _ack(m, (3,), 1, 5, inflight=False)
+    assert m.slave_num == 3 and len(m._slots) == 3
+    assert [s.rank for s in m._slots] == [0, 1, 2]
+    assert m._slots[1].ch is wires[2] and m._slots[2].ch is wires[3]
+    go = wires[3].last("abort_go")
+    assert go[2]["shrink"]["ranks"] == {0: 0, 2: 1, 3: 2}
+    assert go[2]["shrink"]["departed"] == [1]
+    assert len(go[2]["shrink"]["roster"]) == 3
+    # the generation only the dead rank was missing from goes with it
+    for w in (wires[0], wires[2], wires[3]):
+        assert w.kinds()[-2:] == ["abort_go", "barrier_release"]
+    assert m._barrier_waiting == {} and m._barrier_max_released == 0
+    assert m._departed == {} and m._round_kind is None
+    assert m.membership_status()["badges"] == {
+        "1": "SHRUNK 2->1@e1", "2": "SHRUNK 3->2@e1"}
+
+
+def test_shrink_down_to_the_last_rank_and_past_it(scripted):
+    m, wires, _ = scripted(2, "shrink")
+    m._on_rank_dead(0, "connection lost", "rank 0 is dead")
+    _ack(m, (1,), 1, 3, inflight=False)
+    assert m.slave_num == 1 and m._slots[0].ch is wires[1]
+    assert wires[1].last("abort_go")[2]["shrink"]["ranks"] == {1: 0}
+    # the survivor goes too: nothing is left to continue
+    m._on_rank_dead(0, "connection lost", "rank 0 is dead; aborting")
+    assert m._fatal_msg == ("rank 0 is dead; aborting; no surviving "
+                            "rank left")
+
+
+def test_declared_dead_ranks_channel_error_kills_nobody(scripted):
+    """After a shrink the dead rank's old slot may carry a survivor's
+    number; its serve thread's late error must not declare THAT rank."""
+    m, wires, _ = scripted(3, "shrink")
+    zombie = m._slots[1]
+    m._on_rank_dead(1, "barrier gen 0 stalled", "rank 1 is dead")
+    _ack(m, (0, 2), 1, 5, inflight=False)
+    assert m.slave_num == 2 and zombie.dead and zombie.rank == 1
+    zombie.ch.close()
+    m._serve_slave(zombie)          # recv raises at once; returns
+    assert m._departed == {} and m._abort_since is None
+    assert m._fatal_msg is None and m.slave_num == 2
+    assert "declared-dead rank's channel closed" in (
+        m.log_stream.getvalue())
+
+
+def test_tuner_fence_takes_acks_only_and_yields_to_a_death(scripted):
+    m, wires, _ = scripted(3, "shrink")
+    assert m.request_tuner_leaders({0: 1})
+    token = wires[0].last("fence")[1]
+    assert [w.kinds() for w in wires] == [["fence"]] * 3
+    assert not m.request_tuner_leaders({0: 2})      # one at a time
+    m._handle_fence_ack(0, {"token": token, "seq": 4})
+    m._handle_fence_ack(1, {"token": token + 1, "seq": 4})   # stale
+    m._barrier(m._slots[2], 0)      # idling in a barrier is no ack
+    assert m._tuner_fence is not None
+    assert m._tuner_fence["acks"] == {0: 4}
+    m._on_rank_dead(2, "connection lost", "rank 2 is dead")
+    assert m._tuner_fence is None
+    assert wires[0].kinds() == ["fence", "abort", "fence_release"]
+    assert not m.request_tuner_leaders({0: 1})      # a round is open
+    _ack(m, (0, 1), 1, 4, inflight=False)
+    # the round over, the update goes through: equal acks complete it
+    assert m.request_tuner_leaders({0: 1})
+    token = wires[0].last("fence")[1]
+    for r in (0, 1):
+        m._handle_fence_ack(r, {"token": token, "seq": 4})
+    for w in (wires[0], wires[1]):
+        assert w.pushes()[-2:] == [("tuner_leaders", {0: 1}),
+                                   ("fence_release", token)]
+    assert m.tuner_status()["overrides"] == {0: 1}

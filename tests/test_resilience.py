@@ -719,3 +719,102 @@ def test_async_slow_rank_still_bit_exact(k, transport):
     for r in range(N):
         for i in range(k):
             np.testing.assert_array_equal(got[r][i], want[r][i])
+
+
+# ----------------------------------------------------------------------
+# RecoveryManager alone, scripted: the "master" is the send_ctl hook,
+# which answers on the caller's own thread — no sockets, no sleeps
+# ----------------------------------------------------------------------
+def _scripted_recovery(on_ctl, seq=3, **kw):
+    from ytk_mp4j_tpu.resilience.recovery import RecoveryManager
+    from ytk_mp4j_tpu.utils.stats import CommStats
+    sent: list = []
+    pos = {"seq": seq}
+
+    def send_ctl(kind, payload):
+        sent.append((kind, dict(payload)))
+        on_ctl(rm, kind, payload)
+
+    rm = RecoveryManager(rank=1, max_retries=3, dead_rank_secs=5.0,
+                         send_ctl=send_ctl, teardown=lambda: None,
+                         stats=CommStats(),
+                         progress=lambda: (pos["seq"], False), **kw)
+    return rm, sent, pos
+
+
+def _run_once(rm):
+    calls = []
+    out = rm.run("allreduce_array", lambda: calls.append(1) or "done",
+                 lambda: None, None)
+    return out, len(calls)
+
+
+def test_terminal_message_is_one_class_for_every_waiter():
+    fired = []
+    rm, sent, _ = _scripted_recovery(lambda *a: None,
+                                     terminal_hook=fired.append)
+    rm.on_fatal("rank 2 is dead; aborting the job")
+    rm.on_fatal("a later, different message")      # first one wins
+    assert fired == ["rank 2 is dead; aborting the job"]
+    for raiser in (rm.poll, lambda: _run_once(rm)):
+        with pytest.raises(Mp4jFatalError) as ei:
+            raiser()
+        assert type(ei.value) is Mp4jFatalError
+        assert str(ei.value) == "rank 2 is dead; aborting the job"
+    assert str(rm.fatal_exc("local")) == "local"
+    assert [k for _, k, _ in rm.events()] == ["fatal"]
+    assert sent == []
+
+
+def test_fence_parks_at_the_boundary_until_released():
+    def master(rm, kind, payload):
+        if kind == "fence_ack":
+            assert payload == {"token": 7, "seq": 3}
+            rm.on_fence_release(7)          # applied; resume free
+    rm, sent, _ = _scripted_recovery(master)
+    rm.on_fence_release(7)                  # nothing armed: a no-op
+    rm.on_fence(7)
+    assert _run_once(rm) == ("done", 1)
+    assert [k for k, _ in sent] == ["fence_ack"]
+    assert _run_once(rm) == ("done", 1)     # released: no second park
+    assert [k for k, _ in sent] == ["fence_ack"]
+    # (the scripted master answers inside the ack, before the park)
+    assert [k for _, k, _ in rm.events()] == [
+        "fence_release", "fence", "fence_release", "fence_park"]
+
+
+def test_fence_advance_runs_the_laggard_on_then_reparks():
+    script = iter(["advance", "release"])
+
+    def master(rm, kind, payload):
+        if kind == "fence_ack" and next(script) == "advance":
+            rm.on_fence_advance(8, 5)       # a stale token: ignored
+            rm.on_fence_advance(7, 5)
+        elif kind == "fence_ack":
+            rm.on_fence_release(7)
+    rm, sent, pos = _scripted_recovery(master)
+    rm.on_fence(7)
+    assert _run_once(rm) == ("done", 1)     # seq 3 < goal 5: runs on
+    pos["seq"] = 4
+    assert _run_once(rm) == ("done", 1)     # still behind: no ack
+    assert [p["seq"] for _, p in sent] == [3]
+    pos["seq"] = 5
+    assert _run_once(rm) == ("done", 1)     # at the goal: park + ack
+    assert [p["seq"] for _, p in sent] == [3, 5]
+
+
+def test_abort_round_supersedes_an_armed_fence():
+    def master(rm, kind, payload):
+        if kind == "fence_ack":
+            rm.on_abort(1)                  # a death opened a round
+        elif kind == "abort_ack":
+            assert payload == {"epoch": 1, "seq": 3, "inflight": False}
+            rm.on_go(1)
+    rm, sent, _ = _scripted_recovery(master)
+    rm.on_fence(7)
+    assert _run_once(rm) == ("done", 1)
+    assert [k for k, _ in sent] == ["fence_ack", "abort_ack"]
+    assert rm.epoch == 1 and not rm.abort_pending()
+    rm.on_fence_release(7)                  # the late cancel: harmless
+    assert _run_once(rm) == ("done", 1)
+    assert [k for k, _ in sent] == ["fence_ack", "abort_ack"]

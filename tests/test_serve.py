@@ -81,17 +81,13 @@ def test_version_validation():
         validate_version(-1)
 
 
-def test_serve_knob_validation(monkeypatch):
+def test_serve_knob_validation():
     with pytest.raises(Mp4jError):
         tuning.serve_deadline_ms(0.0)
     with pytest.raises(Mp4jError):
         tuning.serve_max_batch(0)
     with pytest.raises(Mp4jError):
         tuning.serve_cache_rows(-1)
-    monkeypatch.setenv("MP4J_SERVE_IDLE_QPS", "10")
-    monkeypatch.setenv("MP4J_SERVE_BUSY_QPS", "5")
-    with pytest.raises(Mp4jError):
-        tuning.serve_busy_qps()
 
 
 # ----------------------------------------------------------------------

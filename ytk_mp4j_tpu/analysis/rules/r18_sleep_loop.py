@@ -2,16 +2,15 @@
 resilience/obs control code).
 
 The packages this rule covers run long-lived control loops: the
-master's watchdog, the autoscaler controller (ISSUE 13), heartbeat and
-sink drain threads, the progression scheduler. A loop that paces
+master's watchdog, heartbeat and sink drain threads, the progression
+scheduler. A loop that paces
 itself with ``time.sleep()`` is deaf for the whole interval — it can
 neither shut down promptly when the job ends (every sleeping thread
 adds its full interval to shutdown latency) nor react to a state flip
-it exists to watch (a circuit-breaker trip, a terminal abort, a stop
-flag). The discipline is ``Event.wait(timeout)`` (or a ``Condition``
+it exists to watch (a terminal abort, a stop flag). The discipline is ``Event.wait(timeout)`` (or a ``Condition``
 wait): same pacing, but the setter wakes the loop IMMEDIATELY — the
-master's watchdog (``self._stop.wait(tick)``) and the autoscaler loop
-are the house pattern.
+master's watchdog (``self._stop.wait(tick)``) and the slave's
+heartbeat loop are the house pattern.
 
 Heuristic: a ``time.sleep(...)`` call lexically inside a ``while``
 statement, in files under ``comm/``, ``resilience/`` or ``obs/``.

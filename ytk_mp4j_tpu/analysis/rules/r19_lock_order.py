@@ -3,18 +3,18 @@
 Two code paths that acquire the same pair of locks in opposite orders
 deadlock the first time their threads interleave — and in this package
 the two acquisitions are usually in DIFFERENT functions, often
-different modules (the master's telemetry fold vs the controller's
-dispatch path), which is why eighteen per-file rules never saw the
+different modules (the master's telemetry fold vs the tuner
+controller's tick), which is why eighteen per-file rules never saw the
 class. The lock model builds the package-wide lock-order graph — an
 edge ``A -> B`` for every witnessed "``B`` acquired while ``A`` held",
 through ``with`` nesting and call chains alike — and R19 reports every
 strongly connected component of size >= 2, with one witness chain per
 direction.
 
-The "master -> controller only" discipline (PR 13's module docstring)
-stops being prose here: an autoscaler path that dispatched into the
-master while holding the controller lock would close the cycle with
-the master's ``status()`` path and fire this rule.
+The master's "tuner lock, then master lock, never the reverse"
+discipline (``Master._tuner_tick`` reads the roster under both)
+stops being prose here: a path that took the tuner lock while holding
+the master lock would close the cycle and fire this rule.
 
 Same-lock re-entry through a call chain is R21's half of the job;
 edges between two instances of one ``(class, attr)`` site share a

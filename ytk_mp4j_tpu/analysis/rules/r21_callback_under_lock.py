@@ -1,6 +1,6 @@
 """R21 — callback/dispatch under the minting lock (ISSUE 14).
 
-The PR 13 outbox discipline, machine-checked. Two shapes:
+The outbox discipline, machine-checked. Two shapes:
 
 (a) **Hook under lock** — invoking a user-supplied callable
     (``*_hook`` / ``*_callback`` / ``*_cb``), directly or through a
@@ -8,14 +8,15 @@ The PR 13 outbox discipline, machine-checked. Two shapes:
     can block, it can call back into the object that is holding the
     lock, and no review of THIS repo can bound it. Fire the hook
     after releasing (collect under the lock, dispatch from an outbox
-    outside it — the autoscaler's ``_emit_locked``/``_flush_events``
-    pair is the house pattern and the negative case).
+    outside it — ``Master._try_advance_round`` composing its release
+    under the lock and fanning it out after is the house pattern and
+    the negative case).
 
 (b) **Re-entrant dispatch** — a call chain started while holding a
-    non-reentrant lock that RE-ACQUIRES that same lock (the
-    controller holding its lock dispatching into the master, whose
-    path calls ``controller.status()``, which takes the controller
-    lock again: self-deadlock on a plain ``Lock``). R19 catches
+    non-reentrant lock that RE-ACQUIRES that same lock (the master
+    holding its lock calling ``_send_to``, which takes the master
+    lock again to look the slot up: self-deadlock on a plain
+    ``Lock``). R19 catches
     opposite-order PAIRS; this catches the same-lock loop. Edges
     between two instances of one ``(class, attr)`` site share a node,
     so a genuinely per-instance nesting needs a reasoned suppression

@@ -51,7 +51,6 @@ from ytk_mp4j_tpu.obs import health as health_mod
 from ytk_mp4j_tpu.obs import metrics as metrics_mod
 from ytk_mp4j_tpu.obs import postmortem as postmortem_mod
 from ytk_mp4j_tpu.obs import telemetry as telemetry_mod
-from ytk_mp4j_tpu.resilience import autoscaler as autoscaler_mod
 from ytk_mp4j_tpu.resilience import membership as membership_mod
 from ytk_mp4j_tpu.transport.channel import Channel
 from ytk_mp4j_tpu.transport.tcp import TcpChannel
@@ -71,7 +70,6 @@ ABORT_ACK = "abort_ack"   # slave finished tearing down the old epoch
 SPARE_PING = "spare_ping"  # an idle warm spare proving liveness
 ADOPT_ACK = "adopt_ack"   # a spare finished seeding its adopted rank
 MANIFEST = "manifest"     # a survivor's adoption manifest contribution
-RESIZE = "resize"         # a rank reached a resize_point() boundary
 FENCE_ACK = "fence_ack"   # a rank parked at its collective boundary
 
 
@@ -82,7 +80,7 @@ class _Slot:
     thread must attribute every later message to the rank the slave
     currently holds, not the one it registered with (ISSUE 10)."""
 
-    __slots__ = ("rank", "ch", "lock", "dead", "quiet")
+    __slots__ = ("rank", "ch", "lock", "dead")
 
     def __init__(self, rank: int, ch: Channel):
         self.rank = rank
@@ -93,13 +91,6 @@ class _Slot:
         # attributing this zombie's messages to a rank id that a
         # replacement spare may now legitimately hold
         self.dead = False
-        # planned eviction in flight (ISSUE 13): inbound messages are
-        # dropped WITHOUT closing the channel — the victim's rank id
-        # already belongs to the adopted spare, but the channel must
-        # stay open until the ("evicted",) release lands on it (a
-        # dead-style close here would turn the clean Mp4jEvicted into
-        # a "master connection lost" fatal on the victim)
-        self.quiet = False
 
 
 class Master:
@@ -118,12 +109,6 @@ class Master:
                  spares: int | None = None,
                  adopt_secs: float | None = None,
                  health: bool | None = None,
-                 autoscale: str | None = None,
-                 autoscale_cooldown: float | None = None,
-                 autoscale_budget: int | None = None,
-                 provision_hook=None,
-                 provision_cmd: str | None = None,
-                 autoscale_tick: float = 0.25,
                  tuner: str | None = None):
         """``timeout`` bounds the whole rendezvous; ``handshake_timeout``
         bounds each accepted connection's registration message, so one
@@ -176,25 +161,8 @@ class Master:
         health`): every heartbeat fold also feeds per-rank baselines
         and the detector set, verdict transitions are pushed to the
         subject rank's recovery log + durable sink and exported on
-        ``/metrics``, and :meth:`health_status` is the operator hook
-        the autoscaler calls — the health plane recommends, the
-        AUTOSCALER acts.
-
-        ``autoscale`` (ISSUE 13; None reads ``MP4J_AUTOSCALE``,
-        default ``off``) arms mp4j-autopilot
-        (:mod:`ytk_mp4j_tpu.resilience.autoscaler`): the controller
-        loop that reads :meth:`health_status` and drives the
-        membership machinery — planned eviction of
-        ``EVICT_RECOMMENDED`` ranks, spare auto-provisioning via
-        ``provision_hook`` (a callable receiving this master) or
-        ``provision_cmd`` (None reads ``MP4J_PROVISION_CMD``), and
-        grow approval at ``resize_point()`` boundaries — behind the
-        cooldown (``autoscale_cooldown`` /
-        ``MP4J_AUTOSCALE_COOLDOWN_SECS``), budget
-        (``autoscale_budget`` / ``MP4J_AUTOSCALE_BUDGET``),
-        audit-green and circuit-breaker safety rails. ``observe``
-        runs the controller but only LOGS would-be actions;
-        ``autoscale_tick`` paces the loop (tests).
+        ``/metrics``, and :meth:`health_status` is the operator's
+        hook — the health plane recommends, the operator acts.
 
         ``tuner`` (ISSUE 15; None reads ``MP4J_TUNER``, default
         ``observe``) arms the master's half of the self-tuning data
@@ -233,7 +201,7 @@ class Master:
         # mp4j-lint: disable=R11 (identity timestamp, not a duration)
         self.started_wall = time.time()
         # bumped under the lock at every roster publication
-        # (rendezvous, replace, shrink, grow) — scrapers distinguish
+        # (rendezvous, replace, shrink) — scrapers distinguish
         # "same job, new roster" from "same roster, fresh numbers"
         self._roster_gen = 0
         # rendezvous listen socket — sanctioned raw-socket site: the
@@ -244,12 +212,6 @@ class Master:
         self._server.bind((host or "0.0.0.0", port))
         self._server.listen(slave_num * 2)
         self.port = self._server.getsockname()[1]
-        # the address the master ADVERTISES to out-of-process tooling
-        # (the autoscaler's MP4J_PROVISION_CMD env): the explicit bind
-        # host when given, else this machine's hostname — a
-        # wildcard-bound master must not hand a provisioner on
-        # another host a loopback address (ISSUE 13)
-        self.host = host or ""
         self._slots: list[_Slot] = []           # by CURRENT rank
         self._exit_codes: dict[int, int] = {}
         self._barrier_waiting: dict[int, list[int]] = {}  # gen -> ranks
@@ -286,42 +248,11 @@ class Master:
         self._round_seq: int | None = None      # joiner resume ordinal
         self._round_adoptions: dict[int, membership_mod.SpareRecord] = {}
         self._round_adopted: dict[int, membership_mod.SpareRecord] = {}
-        # planned eviction (ISSUE 13): the LIVE ranks this round
-        # replaces proactively, with the victims' pre-adoption slots
-        # kept aside for the ("evicted",) release push
-        self._round_evict: dict[int, str] = {}
-        self._round_evict_slots: dict[int, _Slot] = {}
-        # resize/grow state (ISSUE 13): per-generation arrival lists,
-        # the donor payload (rank 0's vocab + positions), and the open
-        # grow round's adoption bookkeeping
-        self._resize_waiting: dict[int, list[int]] = {}
-        self._resize_since: dict[int, float] = {}
-        self._resize_donor: dict[int, dict] = {}
-        # generations CLAIMED by a _complete_resize call: two slave
-        # serve threads can see the same generation complete (the
-        # last two arrivals race), and the grow decision consults the
-        # controller OUTSIDE the lock — without the claim, the loser
-        # releases the generation unchanged while the winner's grow
-        # is mid-adoption, orphaning it. A generation is completed
-        # exactly once; gens are monotone, so claims never recycle.
-        self._resize_claimed: set[int] = set()
-        # generations RELEASED so far (next expected = this value):
-        # the adoption manifest's resize seed takes the max of this
-        # and the donor's own count — a donor sampled in the window
-        # between a generation's release fan-out and its ctl-side
-        # processing reports one generation stale, and a joiner
-        # seeded stale would re-send a completed generation that can
-        # never fill (watchdog fatal on a healthy job)
-        self._resize_released = 0
-        self._grow_state: dict | None = None
-        # the eviction fence (ISSUE 13): before a planned-eviction
-        # round tears anything down, every live rank must be parked
-        # at a collective boundary (fence ack) or idle in a barrier/
-        # resize wait — quiescence BY CONSTRUCTION, so the round can
-        # never manufacture the mixed-progress fatal. A fence that
-        # cannot complete cancels with zero disruption (the wire was
-        # never touched).
-        self._evict_fence: dict | None = None
+        # the tuner fence (ISSUE 15): a leader-override update lands
+        # only once every live rank has acked a park at the SAME
+        # outermost collective boundary. A fence that cannot complete
+        # cancels with zero disruption (the wire was never touched).
+        self._tuner_fence: dict | None = None
         self._fence_seq = 0
         self._fence_secs = max(1.0, min(self._adopt_secs, 5.0))
         # rank -> last heartbeat: progress fields + stats + arrival time
@@ -343,22 +274,6 @@ class Master:
                 drift_pct=tuning.health_drift_pct(),
                 hb_secs=self._hb_secs)
             if tuning.health_enabled(health) else None)
-        # autoscaler (ISSUE 13): knobs validated even when off (the
-        # PR 5 discipline — a typo'd MP4J_AUTOSCALE_COOLDOWN_SECS
-        # fails setup, not the first action); the controller itself
-        # only exists in observe/act and starts with serve()
-        autoscale_mode = tuning.autoscale_mode(autoscale)
-        tuning.autoscale_cooldown_secs(autoscale_cooldown)
-        tuning.autoscale_budget(autoscale_budget)
-        self._autoscaler: autoscaler_mod.Autoscaler | None = None
-        if autoscale_mode != "off":
-            self._autoscaler = autoscaler_mod.Autoscaler(
-                self, mode=autoscale_mode,
-                cooldown_secs=autoscale_cooldown,
-                budget=autoscale_budget,
-                provision_hook=provision_hook,
-                provision_cmd=provision_cmd,
-                tick_secs=autoscale_tick)
         # self-tuning data plane, master half (ISSUE 15): the tuner
         # controller state — leader overrides live + proposed, the
         # audit trip latch, event history. Guarded by its own lock
@@ -468,11 +383,6 @@ class Master:
                                         daemon=True,
                                         name="mp4j-watchdog")
             watchdog.start()
-        # the autoscaler controller loop (ISSUE 13): observes/acts on
-        # health verdicts for the job's lifetime; the shared stop
-        # event ends it with serve()
-        if self._autoscaler is not None:
-            self._autoscaler.start(self._stop)
         try:
             # the list GROWS when a spare is adopted (its serve thread
             # becomes the rank's), so re-read it until drained
@@ -496,8 +406,6 @@ class Master:
                 "this spare")
         if watchdog is not None:
             watchdog.join(2.0)
-        if self._autoscaler is not None:
-            self._autoscaler.join(2.0)
         # serve()'s finally closes the listener, refreshes the
         # flight-recorder manifest with the FINAL table (the slaves'
         # fatal-path telemetry flushes landed after the fan-out-time
@@ -593,8 +501,8 @@ class Master:
             pending.append((ch, (host, listen_port, fp)))
         roster = [hp for _, hp in pending]
         slots = [_Slot(rank, ch) for rank, (ch, _) in enumerate(pending)]
-        # publish table + slots under the lock (the autoscaler and
-        # spare-accept threads are already running); the handshake
+        # publish table + slots under the lock (the spares' serve
+        # threads and the metrics endpoint already run); the handshake
         # sends stay OUTSIDE it — send_obj blocks on the peer
         with self._lock:
             self._roster = roster
@@ -615,12 +523,6 @@ class Master:
                     # connection instead of laundering its messages
                     ch.close()
                     return
-                if slot.quiet:
-                    # planned eviction in flight (ISSUE 13): the rank
-                    # id belongs to the adopted spare, but the channel
-                    # must survive until the ("evicted",) release —
-                    # drop the message, keep serving
-                    continue
                 # the CURRENT rank, re-read per message: a shrink round
                 # renumbers survivors mid-job (ISSUE 10)
                 rank = slot.rank
@@ -638,8 +540,6 @@ class Master:
                     self._handle_abort_ack(rank, payload)
                 elif kind == MANIFEST:
                     self._handle_manifest(rank, payload)
-                elif kind == RESIZE:
-                    self._handle_resize(slot, payload)
                 elif kind == FENCE_ACK:
                     self._handle_fence_ack(rank, payload)
                 elif kind == CLOSE:
@@ -684,10 +584,8 @@ class Master:
             # under MP4J_ELASTIC=off. The elastic modes (ISSUE 10)
             # dispatch through _on_rank_dead instead: replacement from
             # a warm spare, or a contiguous shrink of the survivors.
-            if slot.dead or slot.quiet:
-                # this rank was ALREADY declared dead — or released by
-                # a planned eviction (ISSUE 13), whose clean process
-                # exit closes the channel — either way the error is
+            if slot.dead:
+                # this rank was ALREADY declared dead: the error is
                 # expected aftermath, and a shrink may meanwhile have
                 # renumbered a healthy survivor into slot.rank, so a
                 # fresh declaration here would kill THAT rank (found
@@ -695,7 +593,7 @@ class Master:
                 # dispatch shifted this race's timing, but the hole
                 # predates it)
                 self._log(slot.rank, "INFO",
-                          f"evicted/declared-dead rank's channel "
+                          f"declared-dead rank's channel "
                           f"closed: {e!r}")
                 return
             rank = slot.rank
@@ -793,8 +691,6 @@ class Master:
         self._round_seq = None
         self._round_adoptions = {}
         self._round_adopted = {}
-        self._round_evict = {}
-        self._round_evict_slots = {}
 
     def _handle_abort_ack(self, rank: int, payload: dict) -> None:
         with self._lock:
@@ -810,323 +706,11 @@ class Master:
         pinned keycodec vocabularies + its progress/barrier position."""
         with self._lock:
             if (int(payload.get("epoch", 0)) != self._abort_epoch
-                    or self._round_kind not in ("replace", "evict")):
+                    or self._round_kind != "replace"):
                 return          # stale round, or mode changed
             self._round_manifest = payload
             self._round_manifest_from = rank
         self._try_advance_round()
-
-    # -- resize points + grow mode (ISSUE 13) ---------------------------
-    def _handle_resize(self, slot: _Slot, payload: dict) -> None:
-        """A rank reached a ``resize_point()`` boundary. Arrivals
-        collect per generation like barriers; rank 0's message carries
-        the canonical vocabulary export (at a quiesced boundary every
-        rank's codecs are identical by construction — the sync rounds
-        grow them lockstep). When the last rank arrives the round
-        completes: grow under ``MP4J_ELASTIC=grow`` +
-        ``MP4J_AUTOSCALE=act`` (behind the autoscaler's rails), or a
-        no-change release."""
-        gen = int(payload.get("gen", 0))
-        with self._lock:
-            rank = slot.rank
-            fatal = self._fatal_msg
-            if fatal is None:
-                waiting = self._resize_waiting.setdefault(gen, [])
-                self._resize_since.setdefault(gen, time.monotonic())
-                if rank not in waiting:
-                    waiting.append(rank)
-                if payload.get("vocab") is not None:
-                    self._resize_donor[gen] = dict(payload)
-        if fatal is not None:
-            # like a barrier into a dead job: re-push the terminal
-            self._send_to(rank, ("abort_fatal", fatal))
-            return
-        self._check_resize_complete()
-        # a resize arrival is a boundary too (ISSUE 13)
-        self._check_fence()
-
-    def _check_resize_complete(self) -> None:
-        """Complete every resize generation all CURRENT ranks have
-        reached. Callers re-invoke after membership changes (a shrink
-        may have removed the only missing arrival) — one pass per
-        call."""
-        with self._lock:
-            # strictly IN ORDER, and never while a grow is in flight:
-            # freshly adopted joiners resize at gen+1 against the OLD
-            # slave_num (it only advances at grow finalize), so an
-            # arrival-count check alone would complete gen+1 for the
-            # joiners while the survivors are still inside gen's grow
-            # — the release paths bump _resize_released and re-invoke
-            # this scan, so held generations complete on their turn
-            done = [gen for gen, ranks
-                    in self._resize_waiting.items()
-                    if len(ranks) >= self.slave_num
-                    and gen == self._resize_released
-                    and self._grow_state is None]
-        for gen in sorted(done):
-            self._complete_resize(gen)
-
-    def _complete_resize(self, gen: int) -> None:
-        """All ranks quiesced at resize generation ``gen``: grow when
-        the mode + the autoscaler's safety rails allow, else release
-        unchanged. The grow decision consults the autoscaler OUTSIDE
-        the master lock (lock discipline: master -> controller only)."""
-        with self._lock:
-            if gen not in self._resize_waiting:
-                return          # already completed (re-entry)
-            if gen in self._resize_claimed:
-                return          # another serve thread owns this gen
-            self._resize_claimed.add(gen)
-            if self._grow_state is not None \
-                    and self._grow_state["gen"] == gen:
-                # THIS generation's grow is mid-adoption (a joiner's
-                # early next-gen resize_point can re-trigger the
-                # completeness scan): releasing it unchanged here
-                # would orphan the grow — survivors resume at the old
-                # n while the joiners arrive at n+k. The finalize (or
-                # abort) path owns this generation's release.
-                return
-            donor = self._resize_donor.get(gen)
-            avail = [s for s in self._spare_pool
-                     if s.alive and s.adopting_rank is None]
-            can_grow = (self.elastic == "grow"
-                        and self._fatal_msg is None
-                        and self._abort_since is None
-                        and self._grow_state is None
-                        # an armed eviction fence owns the quiesce: a
-                        # grow starting under it would race the
-                        # fence's round into two concurrent
-                        # membership changes over one roster
-                        and self._evict_fence is None
-                        and donor is not None and bool(avail))
-            audit = self._auditor.status()
-            ranks = list(self._resize_waiting[gen])
-        k = 0
-        if can_grow and self._autoscaler is not None:
-            k = self._autoscaler.approve_grow(len(avail), audit)
-        if k <= 0:
-            with self._lock:
-                self._resize_waiting.pop(gen, None)
-                self._resize_since.pop(gen, None)
-                self._resize_donor.pop(gen, None)
-                self._resize_released = max(self._resize_released,
-                                            gen + 1)
-            for r in ranks:
-                self._send_to(r, ("resize_go", gen, None))
-            self._check_resize_complete()
-            return
-        adopts: list = []
-        with self._lock:
-            # revalidate under the lock (a spare may have died while
-            # the controller deliberated)
-            avail = [s for s in self._spare_pool
-                     if s.alive and s.adopting_rank is None][:k]
-            if not avail or self._grow_state is not None \
-                    or self._abort_since is not None:
-                chosen = []
-            else:
-                chosen = avail
-            if not chosen:
-                # the approved grow DROPPED at revalidation (the
-                # spare died / a round opened while the controller
-                # deliberated): nothing was touched, so the
-                # controller's pending 'grow' must settle as a benign
-                # RETRY, not bleed out at the deadline as a breaker
-                # failure — record the cancel event it resolves on
-                self._membership.note_grow_cancel(
-                    gen, "grow dropped at revalidation: spare lost "
-                    "or a round opened while the controller "
-                    "deliberated")
-                self._resize_waiting.pop(gen, None)
-                self._resize_since.pop(gen, None)
-                self._resize_donor.pop(gen, None)
-                self._resize_released = max(self._resize_released,
-                                            gen + 1)
-            else:
-                base = self.slave_num
-                grown = membership_mod.grow_roster(
-                    self._roster, [rec.entry for rec in chosen])
-                epoch = self._abort_epoch
-                now = time.monotonic()
-                pending: dict[int, membership_mod.SpareRecord] = {}
-                for i, rec in enumerate(chosen):
-                    rec.adopting_rank = base + i
-                    rec.grow = True
-                    rec.adopt_since = now
-                    pending[base + i] = rec
-                self._grow_state = {
-                    "gen": gen, "pending": pending, "adopted": {},
-                    "roster": grown, "epoch": epoch,
-                    "resume_seq": int(donor.get("seq", 0)),
-                    # kept for mid-grow adoption retries: a
-                    # replacement joiner must seed from the SAME
-                    # donor payload (barrier position, vocabulary)
-                    # as the spare it replaces
-                    "donor": dict(donor),
-                }
-                for i, rec in enumerate(chosen):
-                    adopts.append((base + i, rec,
-                                   self._grow_adopt_info(
-                                       base + i, grown, donor, gen,
-                                       epoch, "grow")))
-        if not adopts:
-            for r in ranks:
-                self._send_to(r, ("resize_go", gen, None))
-            self._check_resize_complete()
-            return
-        for r, rec, info in adopts:
-            self._log("M", "WARN",
-                      f"grow: adopting spare #{rec.idx} into NEW "
-                      f"rank {r} (resize {gen}, epoch "
-                      f"{info['epoch']})")
-            self._send_spare(rec, ("adopt", info))
-
-    def _grow_adopt_info(self, rank: int, roster: list, donor: dict,
-                         gen: int, epoch: int, why: str) -> dict:
-        """ONE builder for the grow adoption message — the initial
-        adoptions and the mid-grow retry must seed joiners from the
-        identical donor payload shape, or a field added to one path
-        silently mis-seeds joiners adopted via the other (the
-        parked-barrier / divergent-codes class)."""
-        seq = int(donor.get("seq", 0))
-        return {
-            "rank": rank, "epoch": epoch, "roster": list(roster),
-            "job": self.job_id, "grow": True, "seq": seq,
-            "stats_seq": int(donor.get("stats_seq", seq)),
-            "barrier_gen": int(donor.get("barrier_gen", 0)),
-            # the joiner's NEXT resize pairs with the survivors' next
-            "resize_gen": gen + 1,
-            "vocab": donor.get("vocab") or {},
-            "watermark": self._auditor.verified_seq,
-            "why": why,
-        }
-
-    def _try_advance_grow(self) -> None:
-        """Every grow adoption acked: advance the roster/slave_num,
-        record the event, and release the resize generation to the
-        pre-existing ranks with the grown roster."""
-        with self._lock:
-            gs = self._grow_state
-            if gs is None or gs["pending"] or self._fatal_msg is not None:
-                return
-            self._grow_state = None
-            gen = gs["gen"]
-            new_ranks = sorted(gs["adopted"])
-            old_n = self.slave_num
-            self._roster = gs["roster"]
-            self._roster_gen += 1
-            self.slave_num = len(self._roster)
-            self._rank_width = max(
-                1, len(str(max(self.slave_num - 1, 0))))
-            self._membership.note_grow(new_ranks, gs["epoch"], gen)
-            audit_lines = self._auditor.note_grow(
-                self.slave_num, gs["resume_seq"])
-            if self._health is not None:
-                self._health.note_grow(self.slave_num)
-            ranks = [r for r in self._resize_waiting.pop(gen, [])
-                     if r not in self._departed]
-            self._resize_since.pop(gen, None)
-            self._resize_donor.pop(gen, None)
-            self._resize_released = max(self._resize_released,
-                                        gen + 1)
-            info = {"roster": self._roster, "grown": new_ranks,
-                    "gen": gen}
-        for line in audit_lines:
-            self._log("M", "ERROR", line)
-        self._log("M", "WARN",
-                  f"grow round complete: {old_n} -> {self.slave_num} "
-                  f"rank(s) (new: {new_ranks}); releasing resize "
-                  f"{gen}")
-        for r in ranks:
-            self._send_to(r, ("resize_go", gen, info))
-        # a held NEXT generation (the joiners resize early) may be
-        # complete at the grown slave_num now
-        self._check_resize_complete()
-
-    def _retry_grow_adoption(self, rank: int, why: str) -> None:
-        """A grow adoption failed: when NO other joiner has been
-        seeded yet (their roster copies would hold the dead spare's
-        listen address for this rank), try the next available spare
-        for the same NEW rank id; otherwise roll the whole grow back
-        — degrading a growth to a no-op is always safe (nobody
-        depends on ranks that never existed)."""
-        abort = None
-        adopt = None
-        with self._lock:
-            gs = self._grow_state
-            if gs is None:
-                return
-            rec = next((s for s in self._spare_pool
-                        if s.alive and s.adopting_rank is None), None)
-            if rec is None:
-                abort = why + "; warm-spare pool exhausted"
-            elif gs["adopted"] or gs["pending"]:
-                abort = (why + "; other joiners already hold the "
-                         "promised roster — rolling the grow back")
-            else:
-                rec.adopting_rank = rank
-                rec.grow = True
-                rec.adopt_since = time.monotonic()
-                gs["pending"][rank] = rec
-                # the grown roster promised THIS listen address for
-                # the new rank — swap the replacement's entry in
-                gs["roster"][rank] = rec.entry
-                # seed from the SAME donor payload as the spare this
-                # one replaces (one builder: _grow_adopt_info)
-                adopt = (rank, rec, self._grow_adopt_info(
-                    rank, gs["roster"], gs.get("donor") or {},
-                    gs["gen"], gs["epoch"], "grow (retry)"))
-        if abort is not None:
-            self._abort_grow(abort)
-            return
-        r, rec, info = adopt
-        self._log("M", "WARN",
-                  f"grow: retrying NEW rank {r} with spare "
-                  f"#{rec.idx} ({why})")
-        self._send_spare(rec, ("adopt", info))
-
-    def _abort_grow(self, reason: str) -> None:
-        """Roll a failed grow back: release every already-seeded
-        joiner with a clean ``Mp4jEvicted``, release the resize
-        generation UNCHANGED to the waiting ranks, and record the
-        failure (the autoscaler's circuit breaker reads it)."""
-        with self._lock:
-            gs, self._grow_state = self._grow_state, None
-            if gs is None:
-                return
-            gen = gs["gen"]
-            victims = {**gs["pending"], **gs["adopted"]}
-            for r in victims:
-                if 0 <= r < len(self._slots) \
-                        and self._slots[r] is not None \
-                        and self._slots[r].rank == r:
-                    self._slots[r].quiet = True
-            ranks = [r for r in self._resize_waiting.pop(gen, [])
-                     if r not in self._departed]
-            self._resize_since.pop(gen, None)
-            self._resize_donor.pop(gen, None)
-            self._resize_released = max(self._resize_released,
-                                        gen + 1)
-            self._membership.note_grow_abort(
-                sorted(victims), gen, reason)
-        self._log("M", "ERROR",
-                  f"grow round ABORTED ({reason}): releasing resize "
-                  f"{gen} unchanged; {len(victims)} joiner(s) "
-                  "released")
-        for r, rec in sorted(victims.items()):
-            try:
-                rec.ch.send_obj(("evicted",
-                                 f"grow round aborted: {reason}"))
-            except (Mp4jError, OSError):
-                pass
-        with self._lock:
-            for r in victims:
-                if 0 <= r < len(self._slots) \
-                        and self._slots[r] is not None:
-                    self._slots[r].dead = True
-        for r in ranks:
-            self._send_to(r, ("resize_go", gen, None))
-        self._check_resize_complete()
 
     # -- elastic membership (ISSUE 10) ----------------------------------
     def _on_rank_dead(self, rank: int, why: str, fatal_msg: str) -> None:
@@ -1168,22 +752,10 @@ class Master:
         fan_abort = False
         manifest_req: int | None = None
         fatal: str | None = None
-        # a death outranks an in-flight grow: its joiners were seeded
-        # at an epoch this round is about to retire — roll the grow
-        # back before the membership round claims the spare pool
-        with self._lock:
-            grow_pending = self._grow_state is not None
-        if grow_pending and dead:
-            self._abort_grow(
-                f"membership round opened (rank(s) {sorted(dead)} "
-                "dead)")
         with self._lock:
             if self._fatal_msg is not None:
                 return
-            # grow mode's death response IS replacement (it has a
-            # spare pool by construction); shrink/replace unchanged
-            mode = ("replace" if self.elastic == "grow"
-                    else self.elastic)
+            mode = self.elastic
             fresh = {r: w for r, w in dead.items()
                      if r not in self._round_dead}
             for r, w in dead.items():
@@ -1191,33 +763,6 @@ class Master:
             if self._abort_since is None:
                 self._open_round_locked(self._abort_epoch + 1)
                 fan_abort = True
-            if self._round_evict and dead:
-                # a REAL death arrived while a planned eviction was
-                # quiescing (ISSUE 13): abandon the eviction — the
-                # victim stays a live member of what is now an
-                # ordinary membership round, and the autoscaler's
-                # pending action resolves as failed. An adoption
-                # already assigned to a still-alive victim is
-                # released back to the pool; one assigned to a victim
-                # that itself just died carries over (the replace
-                # path below adopts into exactly that id).
-                for r, rec in list(self._round_adoptions.items()):
-                    if r in self._round_evict and r not in dead:
-                        rec.adopting_rank = None
-                        rec.adopt_since = None
-                        del self._round_adoptions[r]
-                for r in self._round_evict:
-                    # the cancel event settles the controller's
-                    # pending action as a benign retry NOW — without
-                    # it the one-in-flight rail blocks every other
-                    # action until the ~25 s deadline, then charges a
-                    # breaker strike for an abandonment the master
-                    # chose deliberately
-                    self._membership.note_evict_cancel(
-                        r, 0, "a real death superseded the planned "
-                        "eviction")
-                self._round_evict = {}
-                self._round_evict_slots = {}
             self._round_kind = mode
             for r, w in fresh.items():
                 self._round_dead[r] = w
@@ -1270,8 +815,8 @@ class Master:
                 self._send_to(r, ("abort", target))
         if manifest_req is not None:
             self._send_to(manifest_req, ("manifest_req", target))
-        # a real membership round cancels any armed eviction fence
-        # (the death outranks the planned action — ISSUE 13)
+        # a membership round cancels any armed tuner fence (the
+        # death outranks the topology update)
         self._check_fence()
         self._try_advance_round()
 
@@ -1300,91 +845,34 @@ class Master:
             epoch = self._abort_epoch
             progress = {r: self._abort_progress.get(r, (0, False))
                         for r in sorted(live)}
-            if kind == "evict":
-                # the victim's progress is EXCLUDED from the
-                # per-collective coherence check, exactly like a dead
-                # rank's (ISSUE 13): a persistently slow victim sits
-                # one collective BEHIND its peers at quiesce time —
-                # the precise state eviction exists to resolve — and
-                # its unfinished collective leaves with it (survivors
-                # already hold its contributions to everything they
-                # completed; the joiner enters the retried collective
-                # fresh, the dead-replacement rule)
-                progress = {r: p for r, p in progress.items()
-                            if r not in self._round_evict}
             mixed = self._mixed_progress(progress)
             if mixed is not None:
                 fatal = mixed
             elif kind == "abort":
                 self._abort_since = None
                 self._round_kind = None
-                release = ("abort", epoch, None, sorted(live), [], (),
-                           ())
-            elif kind in ("replace", "evict"):
-                # one adoption path for both variants: `replace` fills
-                # DEAD ranks (empty pool is terminal — the job cannot
-                # continue at n), `evict` proactively swaps LIVE ranks
-                # (ISSUE 13: empty pool ABANDONS the eviction and
-                # releases a plain abort — the victim is still a
-                # member, so degrading to no-op is strictly safer)
-                casualties = (self._round_dead if kind == "replace"
-                              else self._round_evict)
+                release = ("abort", epoch, None, sorted(live), [], ())
+            elif kind == "replace":
+                # fill the DEAD ranks from the pool; an empty pool is
+                # terminal — the job cannot continue at n
                 if self._round_manifest is not None:
                     if self._round_seq is None:
                         self._round_seq = membership_mod.joiner_seq(
                             progress)
-                    need = [r for r in sorted(casualties)
+                    need = [r for r in sorted(self._round_dead)
                             if r not in self._round_adoptions
                             and r not in self._round_adopted]
-                    abandon = None
                     for r in need:
                         rec = self._next_spare_locked()
                         if rec is None:
-                            if kind == "replace":
-                                fatal = (self._round_why
-                                         + "; no warm spare available "
-                                         f"to replace rank {r}")
-                            else:
-                                abandon = (
-                                    "planned eviction of rank(s) "
-                                    f"{sorted(casualties)} abandoned: "
-                                    "warm-spare pool exhausted; "
-                                    "releasing the round as a plain "
-                                    "abort")
+                            fatal = (self._round_why
+                                     + "; no warm spare available "
+                                     f"to replace rank {r}")
                             break
                         rec.adopting_rank = r
                         rec.adopt_since = time.monotonic()
                         self._round_adoptions[r] = rec
-                    if abandon is not None:
-                        # abandoning is only SOUND when the quiesced
-                        # state is coherent INCLUDING the victim: a
-                        # victim interrupted one collective behind
-                        # would retry ordinal m-1 against survivors
-                        # retrying m — raw exchanges carry no
-                        # collective tag, so the mispairing is silent
-                        # corruption, not an error. Incoherent + no
-                        # spare -> hold the round open for a late
-                        # spare registration (_register_spare
-                        # re-drives it; the watchdog's stalled-round
-                        # fatal bounds the wait).
-                        full = {r2: self._abort_progress.get(
-                                    r2, (0, False))
-                                for r2 in sorted(live)}
-                        if self._mixed_progress(full) is not None:
-                            abandon = None
-                    if abandon is not None:
-                        self._membership.note_evict_abort(
-                            sorted(casualties), epoch, abandon)
-                        self._abort_since = None
-                        self._round_kind = None
-                        self._round_evict = {}
-                        self._round_evict_slots = {}
-                        self._round_manifest = None
-                        self._round_manifest_from = None
-                        self._round_seq = None
-                        release = ("abort", epoch, None, sorted(live),
-                                   [abandon], (), ())
-                    elif fatal is None:
+                    if fatal is None:
                         man = self._round_manifest
                         repl = {r2: rec2.entry for r2, rec2
                                 in self._round_adoptions.items()}
@@ -1405,21 +893,12 @@ class Master:
                                     "stats_seq", self._round_seq)),
                                 "barrier_gen": int(
                                     man.get("barrier_gen", 0)),
-                                # max with the master's released
-                                # count: a pending generation needs
-                                # the joiner's arrival (donor == the
-                                # master then), a just-released one
-                                # must not be replayed (see
-                                # _resize_released)
-                                "resize_gen": max(
-                                    int(man.get("resize_gen", 0)),
-                                    self._resize_released),
                                 "vocab": man.get("vocab") or {},
                                 "watermark":
                                     self._auditor.verified_seq,
-                                "why": casualties.get(r, ""),
+                                "why": self._round_dead.get(r, ""),
                             }))
-                        if (not adopts and set(casualties)
+                        if (not adopts and set(self._round_dead)
                                 <= set(self._round_adopted)):
                             release = self._finalize_replace_locked(
                                 epoch, live)
@@ -1435,20 +914,7 @@ class Master:
             self._send_spare(rec, ("adopt", info))
         if release is None:
             return
-        (kind, epoch, info, targets, extra_lines, release_gens,
-         evict_notify) = release
-        # planned-eviction release (ISSUE 13), ordered for the victim
-        # race: the ("evicted",) push rides the still-open channel
-        # FIRST (its slot is already quiet, so inbound noise cannot
-        # close it), only then does the slot go fully dead — and the
-        # epoch releases to the survivors + joiner after that
-        for slot, r, msg in evict_notify:
-            try:
-                with slot.lock:
-                    slot.ch.send_obj(("evicted", msg))
-            except (Mp4jError, OSError):
-                pass    # the victim died anyway; nothing to release
-            slot.dead = True
+        kind, epoch, info, targets, extra_lines, release_gens = release
         for line in extra_lines:
             self._log("M", "ERROR", line)
         if kind == "abort":
@@ -1475,219 +941,91 @@ class Master:
             for gen in release_gens:
                 for r in range(self.slave_num):
                     self._send_to(r, ("barrier_release", gen))
-        # a membership change can complete a pending resize round
-        # (shrink: the dead rank was the only missing arrival)
-        self._check_resize_complete()
 
-    # -- planned eviction (ISSUE 13) ------------------------------------
-    def request_planned_evict(self, rank: int, why: str) -> bool:
-        """Proactively replace a LIVE rank from a warm spare at the
-        next collective boundary — the autoscaler's actuation hook
-        (callable by an operator too). Opens a membership round of
-        kind ``evict``: every rank (victim included) quiesces through
-        the epoch-fenced abort round, the lowest live NON-victim
-        survivor donates the adoption manifest, a spare is adopted
-        into the victim's id, and the victim is released with a clean
-        :class:`~ytk_mp4j_tpu.exceptions.Mp4jEvicted` while everyone
-        else continues bit-exactly — the proactive twin of the
-        death-driven replace path.
-
-        Returns False (nothing opened) when the request cannot start:
-        wrong elastic mode, a round or fence already open, the rank
-        gone, no live peer to donate the manifest, or no spare
-        available. Everything is validated HERE under the lock — the
-        caller's snapshot may be stale, and a refusal is always safe.
-
-        The quiesce is a two-step: first the soft FENCE parks every
-        live rank at its next outermost collective entry (the wire
-        untouched — a fence that cannot complete cancels for free),
-        and only a fully-fenced cluster opens the abort round, so the
-        round's teardown can never manufacture the per-collective
-        mixed-progress fatal on a healthy job."""
-        why = str(why)[:300]
-        with self._lock:
-            live = set(range(self.slave_num)) - set(self._departed)
-            ok = (self.elastic in ("replace", "grow")
-                  and self._fatal_msg is None
-                  and self._abort_since is None
-                  and self._grow_state is None
-                  and self._evict_fence is None
-                  and rank in live and len(live) >= 2
-                  and self._next_spare_locked() is not None
-                  # rendezvous must have seated every rank (a request
-                  # this early has no slot to fence)
-                  and len(self._slots) >= self.slave_num
-                  and 0 <= rank < len(self._slots)
-                  and not (self._slots[rank].dead
-                           or self._slots[rank].quiet))
-            if not ok:
-                return False
-            self._fence_seq += 1
-            token = self._fence_seq
-            self._evict_fence = {"token": token, "rank": rank,
-                                 "why": why, "acks": {},
-                                 "goal": 0,
-                                 "since": time.monotonic()}
-        self._log("M", "WARN",
-                  f"planned eviction: fencing the job at the next "
-                  f"collective boundary to replace LIVE rank {rank} "
-                  f"({why})")
-        for r in sorted(live):
-            self._send_to(r, ("fence", token))
-        self._check_fence()
-        return True
-
+    # -- the tuner fence (ISSUE 15) -------------------------------------
     def _handle_fence_ack(self, rank: int, payload: dict) -> None:
         with self._lock:
-            f = self._evict_fence
+            f = self._tuner_fence
             if f is None or int(payload.get("token", -1)) != f["token"]:
                 return          # stale fence
             f["acks"][rank] = int(payload.get("seq", 0))
         self._check_fence()
 
     def _check_fence(self) -> None:
-        """Evaluate the armed eviction fence: complete it into an
-        abort round once every live rank is provably at a boundary
-        (fence ack, or idle in a barrier/resize wait — SPMD makes
-        those states schedule-equivalent), or cancel it (fence
-        release, zero disruption) when it can no longer succeed:
-        victim gone, a real round opened, the pool drained, or the
-        deadline passed (a rank deep in application compute never
-        reaches a boundary — retrying later is free)."""
-        start = None
+        """Evaluate the armed tuner fence: complete it (push the
+        leader overrides, then release) once every live rank has acked
+        a park at the SAME collective boundary, advance the parked
+        ranks a peer's in-flight collective still needs, or cancel it
+        (fence release, zero disruption) when it can no longer
+        succeed: the job is aborting, a round opened, or the deadline
+        passed (a rank deep in application compute never reaches a
+        boundary — retrying later is free)."""
         cancel = None
         advance = None
-        push = None     # tuner fence completion (ISSUE 15)
+        push = None
         with self._lock:
-            f = self._evict_fence
+            f = self._tuner_fence
             if f is None:
                 return
-            kind = f.get("kind", "evict")
             live = set(range(self.slave_num)) - set(self._departed)
-            victim = f["rank"]
             now = time.monotonic()
             if self._fatal_msg is not None:
                 cancel = "job is terminally aborting"
             elif self._abort_since is not None:
                 cancel = "a membership/abort round opened meanwhile"
-            elif self._grow_state is not None:
-                # the mirror of _complete_resize's fence guard: two
-                # concurrent membership rounds over one roster would
-                # finalize in either order and silently resurrect
-                # stale entries
-                cancel = "a grow round is in flight"
-            elif kind == "evict" and (
-                    victim not in live or len(live) < 2
-                    or self._slots[victim].dead
-                    or self._slots[victim].quiet):
-                cancel = f"rank {victim} is no longer an evictable " \
-                         "member"
-            elif kind == "evict" \
-                    and self._next_spare_locked() is None:
-                cancel = "the warm-spare pool drained"
             elif now - f["since"] > self._fence_secs:
                 missing = sorted(live - set(f["acks"]))
                 cancel = (f"rank(s) {missing} did not reach a "
                           f"collective boundary within "
                           f"{self._fence_secs:.1f}s")
             else:
-                idle = set(f["acks"])
-                for ranks in self._barrier_waiting.values():
-                    idle.update(ranks)
-                for ranks in self._resize_waiting.values():
-                    idle.update(ranks)
-                # starvation rule (ISSUE 13): a rank parked at an
-                # ordinal BEHIND a peer's position starves every
-                # in-flight batch that still needs it — advance the
-                # laggards to the global max ordinal (acked positions
-                # plus the un-acked ranks' heartbeat in-flight seqs)
-                # and only complete the fence when every parked rank
-                # sits at the SAME boundary
+                # the update needs every live rank PARKED at one
+                # boundary via an explicit ack. Starvation rule for
+                # hot blocking jobs: a rank BLOCKED INSIDE ordinal K
+                # reports the same entered-seq as a rank PARKED AT K's
+                # entry (the wrapper bumps before the park), so equal
+                # seqs can still hide a deadlock — the parked ranks
+                # starve the blocked ones. Whenever an unacked rank's
+                # heartbeat position is at or past a parked rank's,
+                # advance the parked ranks PAST that ordinal (goal =
+                # max + 1 — strictly above their entered seq, which is
+                # what wakes the slave-side park); they run it,
+                # everyone converges on the next boundary and re-acks.
+                acked = set(f["acks"])
                 seqs = set(f["acks"].values())
-                hb_max = max(
-                    (int(self._telemetry[r]["seq"])
-                     for r in live - set(f["acks"])
-                     if r in self._telemetry), default=0)
-                # the goal never decreases, but an ack BELOW an
-                # already-set goal must still be advanced (a rank
-                # acking late at a low seq would otherwise stall the
-                # fence to its deadline: goal>f["goal"] is false yet
-                # the seqs can never equalize)
-                goal = max([hb_max, f["goal"],
-                            *f["acks"].values()], default=0)
-                laggards = [r for r, s in f["acks"].items()
-                            if s < goal]
-                if kind == "tuner":
-                    # the tuner update needs every live rank PARKED at
-                    # one boundary via an explicit ack. Starvation
-                    # rule, sharpened for hot blocking jobs: a rank
-                    # BLOCKED INSIDE ordinal K reports the same
-                    # entered-seq as a rank PARKED AT K's entry (the
-                    # wrapper bumps before the park), so equal seqs
-                    # can still hide a deadlock — the parked ranks
-                    # starve the blocked ones. Whenever an unacked
-                    # rank's heartbeat position is at or past a parked
-                    # rank's, advance the parked ranks PAST that
-                    # ordinal (goal = max + 1 — strictly above their
-                    # entered seq, which is what wakes the slave-side
-                    # park); they run it, everyone converges on the
-                    # next boundary and re-acks.
-                    acked = set(f["acks"])
-                    hb_unacked = [
-                        int(self._telemetry[r]["seq"])
-                        for r in live - acked if r in self._telemetry]
-                    goal = None
-                    if live <= acked and len(seqs) <= 1:
-                        self._evict_fence = None
-                        push = (f["token"], dict(f["payload"]),
-                                sorted(live))
-                    elif live <= acked:
-                        # every rank parked, at UNEQUAL boundaries
-                        # (rooted/partial collectives let a rank
-                        # complete ordinals a peer never touched):
-                        # advance the behind ranks to the front
-                        # rank's position — max(seqs) exceeds their
-                        # entered seq, so the slave-side park wakes
-                        goal = max(max(seqs), f["goal"])
-                    elif (f["acks"] and hb_unacked
-                          and max(hb_unacked)
-                          >= min(f["acks"].values())):
-                        goal = max(max(hb_unacked) + 1, f["goal"])
-                    if goal is not None:
-                        laggards = [r for r, s in f["acks"].items()
-                                    if s < goal]
-                        if laggards:
-                            f["goal"] = goal
-                            for r in laggards:
-                                del f["acks"][r]
-                            advance = (f["token"], goal, laggards)
-                elif laggards:
-                    f["goal"] = goal
-                    for r in laggards:
-                        del f["acks"][r]
-                    advance = (f["token"], goal, laggards)
-                elif live <= idle and len(seqs) <= 1:
-                    self._evict_fence = None
-                    self._open_round_locked(self._abort_epoch + 1)
-                    self._round_kind = "evict"
-                    self._round_why = (f"planned eviction of rank "
-                                       f"{victim}: {f['why']}")
-                    self._round_evict = {victim: f["why"]}
-                    self._round_evict_slots = {
-                        victim: self._slots[victim]}
-                    donor = min(live - {victim})
-                    self._round_manifest_from = donor
-                    start = (self._abort_epoch, donor, sorted(live))
+                hb_unacked = [
+                    int(self._telemetry[r]["seq"])
+                    for r in live - acked if r in self._telemetry]
+                goal = None
+                if live <= acked and len(seqs) <= 1:
+                    self._tuner_fence = None
+                    push = (f["token"], dict(f["payload"]),
+                            sorted(live))
+                elif live <= acked:
+                    # every rank parked, at UNEQUAL boundaries
+                    # (rooted/partial collectives let a rank complete
+                    # ordinals a peer never touched): advance the
+                    # behind ranks to the front rank's position —
+                    # max(seqs) exceeds their entered seq, so the
+                    # slave-side park wakes
+                    goal = max(max(seqs), f["goal"])
+                elif (seqs and hb_unacked
+                      and max(hb_unacked) >= min(seqs)):
+                    goal = max(max(hb_unacked) + 1, f["goal"])
+                if goal is not None:
+                    laggards = [r for r, s in f["acks"].items()
+                                if s < goal]
+                    if laggards:
+                        f["goal"] = goal
+                        for r in laggards:
+                            del f["acks"][r]
+                        advance = (f["token"], goal, laggards)
             if cancel is not None:
                 token = f["token"]
-                self._evict_fence = None
-                if kind == "evict":
-                    self._membership.note_evict_cancel(
-                        victim, token, cancel)
+                self._tuner_fence = None
         if cancel is not None:
             self._log("M", "WARN",
-                      f"{'tuner' if kind == 'tuner' else 'eviction'} "
-                      f"fence canceled ({cancel}); releasing "
+                      f"tuner fence canceled ({cancel}); releasing "
                       "the parked ranks untouched")
             for r in sorted(self._live_ranks()):
                 self._send_to(r, ("fence_release", token))
@@ -1695,80 +1033,53 @@ class Master:
         if advance is not None:
             token, goal, laggards = advance
             self._log("M", "WARN",
-                      f"eviction fence: advancing rank(s) {laggards} "
+                      f"tuner fence: advancing rank(s) {laggards} "
                       f"to ordinal {goal} (a peer's in-flight batch "
                       "still needs them)")
             for r in laggards:
                 self._send_to(r, ("fence_advance", token, goal))
             return
-        if push is not None:
-            # tuner fence complete (ISSUE 15): every live rank is
-            # parked at the SAME collective boundary — push the
-            # leader overrides (applied on each rank's ctl thread),
-            # THEN release the fence: the master channel is ordered,
-            # so every rank applies before its collective thread
-            # resumes. Atomic topology switch, wire untouched.
-            token, overrides, targets = push
-            with self._tuner_lock:
-                ctl = self._tuner_ctl
-                if ctl is not None:
-                    ctl["overrides"] = dict(overrides)
-                    ctl["version"] += 1
-                    ctl["demotions"] += 1
-            self._log("M", "WARN",
-                      f"tuner fence complete: applying leader "
-                      f"overrides {overrides} at a job-wide "
-                      "collective boundary")
-            for r in targets:
-                self._send_to(r, ("tuner_leaders", overrides))
-                self._send_to(r, ("fence_release", token))
-            self._tuner_event(
-                "demote", f"leader overrides {overrides} applied "
-                f"(fence token {token})")
+        if push is None:
             return
-        if start is None:
-            return
-        target, donor, targets = start
+        # fence complete: every live rank is parked at the SAME
+        # collective boundary — push the leader overrides (applied on
+        # each rank's ctl thread), THEN release the fence: the master
+        # channel is ordered, so every rank applies before its
+        # collective thread resumes. Atomic topology switch, wire
+        # untouched.
+        token, overrides, targets = push
+        with self._tuner_lock:
+            ctl = self._tuner_ctl
+            if ctl is not None:
+                ctl["overrides"] = dict(overrides)
+                ctl["version"] += 1
+                ctl["demotions"] += 1
         self._log("M", "WARN",
-                  f"eviction fence complete: every rank at a "
-                  f"boundary; abort round -> epoch {target}")
+                  f"tuner fence complete: applying leader "
+                  f"overrides {overrides} at a job-wide "
+                  "collective boundary")
         for r in targets:
-            self._send_to(r, ("abort", target))
-        self._send_to(donor, ("manifest_req", target))
-        self._try_advance_round()
+            self._send_to(r, ("tuner_leaders", overrides))
+            self._send_to(r, ("fence_release", token))
+        self._tuner_event(
+            "demote", f"leader overrides {overrides} applied "
+            f"(fence token {token})")
 
     def _finalize_replace_locked(self, epoch: int, live: set[int]):
         """All survivors acked, every casualty's spare acked its
         adoption: swap the roster, resurrect the replaced ranks and
-        compose the go message (caller holds the lock and fans out).
-        Planned evictions (ISSUE 13) finalize through the same path —
-        the difference is the victim is ALIVE: its pre-adoption slot
-        goes ``quiet`` here (inbound dropped, channel kept) and the
-        composed ``evict_notify`` pushes the clean ``("evicted",)``
-        release before the epoch go."""
+        compose the go message (caller holds the lock and fans out)."""
         repl = {r: rec.entry for r, rec in self._round_adopted.items()}
         self._roster = membership_mod.swap_roster(self._roster, repl)
         self._roster_gen += 1
         joiners = sorted(self._round_adopted)
         extra_lines: list[str] = []
-        evict_notify: list[tuple[_Slot, int, str]] = []
         for r in joiners:
             rec = self._round_adopted[r]
             self._departed.pop(r, None)
             self._exit_codes.pop(r, None)
-            if r in self._round_evict:
-                why = self._round_evict.get(r, "")
-                self._membership.note_evict(r, epoch, rec.idx, why)
-                old = self._round_evict_slots.get(r)
-                if old is not None:
-                    old.quiet = True
-                    evict_notify.append((old, r, (
-                        f"rank {r} evicted by the autoscaler and "
-                        f"replaced from warm spare #{rec.idx} @ epoch "
-                        f"{epoch}: {why}")))
-            else:
-                self._membership.note_replace(
-                    r, epoch, rec.idx, self._round_dead.get(r, ""))
+            self._membership.note_replace(
+                r, epoch, rec.idx, self._round_dead.get(r, ""))
             extra_lines.extend(
                 self._auditor.note_replacement(
                     r, self._round_seq or 0))
@@ -1787,13 +1098,10 @@ class Master:
         self._round_dead = {}
         self._round_adoptions = {}
         self._round_adopted = {}
-        self._round_evict = {}
-        self._round_evict_slots = {}
         self._round_manifest = None
         self._round_manifest_from = None
         self._round_seq = None
-        return ("replace", epoch, info, targets, extra_lines, (),
-                evict_notify)
+        return ("replace", epoch, info, targets, extra_lines, ())
 
     def _finalize_shrink_locked(self, epoch: int):
         """All survivors acked a shrink round: renumber them
@@ -1830,12 +1138,6 @@ class Master:
             self._health.note_shrink(self.slave_num, mapping)
         self._membership.note_shrink(dead_list, mapping, epoch,
                                      self._round_why)
-        # pending resize generations renumber like barriers; a
-        # generation completed by the shrink is picked up by the
-        # _check_resize_complete scan after the release fan-out
-        self._resize_waiting = {
-            gen: [mapping[r] for r in ranks if r in mapping]
-            for gen, ranks in self._resize_waiting.items()}
         # pending barriers renumber too; one now-complete generation
         # (every survivor already arrived, only the dead were missing)
         # releases on the way out
@@ -1858,7 +1160,7 @@ class Master:
         self._round_manifest = None
         self._round_manifest_from = None
         self._round_seq = None
-        return ("shrink", epoch, info, targets, [], release_gens, ())
+        return ("shrink", epoch, info, targets, [], release_gens)
 
     # -- warm spares (ISSUE 10) -----------------------------------------
     def _register_spare(self, ch: Channel, entry: tuple) -> None:
@@ -1869,10 +1171,6 @@ class Master:
             self._spare_seq += 1
             rec = membership_mod.SpareRecord(idx, ch, entry)
             self._spare_pool.append(rec)
-            # the registration EVENT is what a pending provision
-            # action resolves on — a waiting round may claim this
-            # spare before any status snapshot shows the pool > 0
-            self._membership.note_spare(idx)
         try:
             ch.send_obj({"spare": idx, "job": self.job_id})
         except (Mp4jError, OSError):
@@ -1886,10 +1184,6 @@ class Master:
         self._log("M", "INFO",
                   f"warm spare #{idx} registered "
                   f"({entry[0]}:{entry[1]})")
-        # a membership round waiting out an exhausted pool (ISSUE 13:
-        # an evict round that cannot safely abandon) resumes the
-        # moment a fresh spare registers
-        self._try_advance_round()
 
     def _spare_accept_loop(self) -> None:
         """Post-rendezvous listener: only spare registrations are
@@ -1964,22 +1258,8 @@ class Master:
                 return None
             rec.adopt_since = None
             slot = _Slot(r, rec.ch)
-            if rec.grow:
-                # grow adoption (ISSUE 13): a NEW rank id — the slot
-                # list extends (acks may land out of rank order; the
-                # padding slots fill as their own acks arrive, and the
-                # roster/slave_num only advance at grow finalize)
-                gs = self._grow_state
-                if gs is None or gs["pending"].get(r) is not rec:
-                    return None     # grow aborted meanwhile
-                del gs["pending"][r]
-                gs["adopted"][r] = rec
-                while len(self._slots) <= r:
-                    self._slots.append(None)
-                self._slots[r] = slot
-            else:
-                self._slots[r] = slot
-                self._round_adopted[r] = rec
+            self._slots[r] = slot
+            self._round_adopted[r] = rec
             if rec in self._spare_pool:
                 self._spare_pool.remove(rec)
             # the dead occupant's telemetry must not pollute the
@@ -1990,12 +1270,8 @@ class Master:
             self._rank_totals.pop(r, None)
             self._serve_threads.append(threading.current_thread())
         self._log("M", "WARN",
-                  f"spare #{rec.idx} adopted as rank {r}"
-                  + (" (grow)" if rec.grow else ""))
-        if rec.grow:
-            self._try_advance_grow()
-        else:
-            self._try_advance_round()
+                  f"spare #{rec.idx} adopted as rank {r}")
+        self._try_advance_round()
         return slot
 
     def _send_spare(self, rec, obj) -> None:
@@ -2010,8 +1286,6 @@ class Master:
         next spare is tried, or the round goes terminal through the
         no-spare path."""
         retry = False
-        retry_evict = False
-        retry_grow = False
         with self._lock:
             rec.alive = False
             if rec in self._spare_pool:
@@ -2021,16 +1295,7 @@ class Master:
             rec.adopt_since = None
             if r is not None and self._round_adoptions.get(r) is rec:
                 del self._round_adoptions[r]
-                # a planned-eviction round retries (or abandons)
-                # through its own branch — _begin_membership would
-                # misread the round as a death (ISSUE 13)
-                retry_evict = self._round_kind == "evict"
-                retry = not retry_evict
-            gs = self._grow_state
-            if (rec.grow and gs is not None
-                    and gs["pending"].get(r) is rec):
-                del gs["pending"][r]
-                retry_grow = True
+                retry = True
             round_why = self._round_why
         self._log("M", "WARN", f"warm spare #{rec.idx} lost: {why}")
         try:
@@ -2043,13 +1308,6 @@ class Master:
             self._begin_membership({}, round_why or
                                    f"spare #{rec.idx} died mid-adoption")
             self._try_advance_round()
-        elif retry_evict:
-            # the evict branch assigns the next spare, or abandons the
-            # eviction and releases a plain abort (never fatal)
-            self._try_advance_round()
-        if retry_grow:
-            self._retry_grow_adoption(
-                r, f"spare #{rec.idx} died mid-grow-adoption")
 
     def _release_spares(self, reason: str) -> None:
         with self._lock:
@@ -2255,41 +1513,16 @@ class Master:
             if target is not None and 0 <= target < len(self._slots):
                 self._send_to(target, ("health_alert", ev))
 
-    def _autoscale_event(self, ev: dict, level: str = "WARN") -> None:
-        """Land one structured autoscaler event everywhere at once
-        (ISSUE 13, the repo precedent): master log line, plus the
-        health-alert control push to the lowest live rank — whose
-        recovery log and durable sink make the action history outlive
-        the master, and whose ``alerts`` records interleave actions
-        with verdict transitions in every ``mp4j-scope health``
-        timeline. Called by the autoscaler WITHOUT the master lock
-        held (the push takes per-slot locks only)."""
-        self._log("M", level,
-                  "autoscale: " + health_mod.format_alert(ev))
-        target = next(iter(sorted(self._live_ranks())), None)
-        if target is not None and 0 <= target < len(self._slots):
-            self._send_to(target, ("health_alert", ev))
-
-    def autoscale_status(self) -> dict | None:
-        """The autoscaler document (ISSUE 13): mode, per-action
-        counters, observed (would-be) actions, budget, circuit-breaker
-        state, the in-flight action and the bounded event history
-        (schema: resilience.autoscaler.Autoscaler.status). None when
-        ``MP4J_AUTOSCALE=off``."""
-        return (self._autoscaler.status()
-                if self._autoscaler is not None else None)
-
     # -- self-tuning data plane, master half (ISSUE 15) ----------------
     def _tuner_event(self, kind: str, msg: str,
                      rank: int | None = None,
                      level: str = "WARN") -> dict:
         """Mint + dispatch one structured tuner event through the
-        health-alert pipe (the autoscaler precedent): master log line
-        plus a control push to the lowest live rank, whose recovery
-        log and durable sink make the history outlive the master.
-        Ids are negative in a range disjoint from the autoscaler's
-        (-1e6 - seq) so timeline dedup can never collide. Called
-        WITHOUT the master or tuner lock held."""
+        health-alert pipe: master log line plus a control push to the
+        lowest live rank, whose recovery log and durable sink make the
+        history outlive the master. Ids are negative (-1e6 - seq), a
+        range no health alert uses, so timeline dedup can never
+        collide. Called WITHOUT the master or tuner lock held."""
         with self._tuner_lock:
             ctl = self._tuner_ctl
             if ctl is None:
@@ -2316,7 +1549,7 @@ class Master:
         (1) the AUDIT RAIL — any fresh cross-rank digest divergence
         trips every rank's tuner back to static defaults, latched for
         the job (re-pushed to any rank whose heartbeat shows an
-        untripped tuner — a replacement/grow joiner constructs fresh
+        untripped tuner — a replacement joiner constructs fresh
         and must inherit the latch); (2) the DOMINATOR watch — feed
         the health engine's cause-aware rows to the pure
         leader-demotion policy and, in act mode, actuate through a
@@ -2388,28 +1621,25 @@ class Master:
         """Apply a tuner leader-override map job-wide through a FENCE
         (callable by an operator too): park every live rank at the
         same outermost-collective boundary, push ``tuner_leaders``,
-        release. Unlike the eviction fence nothing is torn down and
-        no spare is needed — a fence that cannot complete cancels
-        with zero disruption and the controller retries after its
-        cooldown. Returns False when the request cannot start (a
+        release. Nothing is torn down — a fence that cannot complete
+        cancels with zero disruption and the controller retries after
+        its cooldown. Returns False when the request cannot start (a
         round or fence already open, rendezvous incomplete)."""
         with self._lock:
             ok = (self._fatal_msg is None
                   and self._abort_since is None
-                  and self._grow_state is None
-                  and self._evict_fence is None
+                  and self._tuner_fence is None
                   and len(self._slots) >= self.slave_num)
             if not ok:
                 return False
             self._fence_seq += 1
             token = self._fence_seq
             live = set(range(self.slave_num)) - set(self._departed)
-            self._evict_fence = {
-                "token": token, "kind": "tuner", "rank": None,
+            self._tuner_fence = {
+                "token": token,
                 "payload": {int(k): int(v)
                             for k, v in (overrides or {}).items()},
-                "why": "tuner leader update", "acks": {}, "goal": 0,
-                "since": time.monotonic()}
+                "acks": {}, "goal": 0, "since": time.monotonic()}
         self._log("M", "WARN",
                   f"tuner: fencing the job at the next collective "
                   f"boundary to apply leader overrides {overrides}")
@@ -2584,12 +1814,9 @@ class Master:
         folded histograms, windowed rates). Plain JSON-ready dicts —
         ``obs.metrics.to_prometheus`` renders the text form."""
         now = time.monotonic()
-        # controller status sampled OUTSIDE the master lock (lock
-        # discipline: the controller never holds its own lock while
-        # calling master methods, and this order — controller lock
-        # only, then master lock — can never cycle)
-        autoscale_status = (self._autoscaler.status()
-                            if self._autoscaler is not None else None)
+        # tuner status sampled OUTSIDE the master lock (lock
+        # discipline: it takes the tuner lock, then the master lock,
+        # each alone — this order can never cycle)
         tuner_status = self.tuner_status()
         with self._lock:
             roster_gen = self._roster_gen
@@ -2661,7 +1888,6 @@ class Master:
                 "audit": audit_status,
                 "membership": membership_status,
                 "health": health_status,
-                "autoscale": autoscale_status,
                 "tuner": tuner_status,
                 "serve": serve_status,
             },
@@ -2672,8 +1898,7 @@ class Master:
         serve section of :meth:`metrics_doc` — QPS, latency
         quantiles, cache hit rate, degraded-batch count — or ``None``
         when no rank has reported serve traffic (a pure training
-        job). The autoscaler's load-following policy and
-        ``mp4j-scope live/fleet`` read exactly this."""
+        job). ``mp4j-scope live/fleet`` read exactly this."""
         return self.metrics_doc()["cluster"]["serve"]
 
     def _membership_status_locked(self) -> dict:
@@ -2704,7 +1929,7 @@ class Master:
 
     def health_status(self) -> dict | None:
         """The health plane's verdict document (ISSUE 12) — THE
-        operator hook the future elastic autoscaler calls: per-rank
+        operator's hook: per-rank
         state (``HEALTHY``/``DEGRADED``/``SUSPECT``/
         ``EVICT_RECOMMENDED``/``DEAD``) with detector-pressure
         evidence, the ``evict_recommended`` list, dominator window
@@ -2721,8 +1946,6 @@ class Master:
         """Flight-recorder manifest (once per write site, idempotent
         overwrite): only on a terminal abort — a clean job leaves no
         postmortem."""
-        autoscale_status = (self._autoscaler.status()
-                            if self._autoscaler is not None else None)
         with self._lock:
             reason = self._fatal_msg
             departed = dict(self._departed)
@@ -2744,8 +1967,7 @@ class Master:
                 audit=audit_status,
                 sink_dir=self._sink_dir or None,
                 membership=membership_status,
-                health=health_status,
-                autoscale=autoscale_status)
+                health=health_status)
         except OSError:
             pass  # best-effort: the job is already terminal
 
@@ -2814,8 +2036,7 @@ class Master:
                                 escalate.setdefault(
                                     r, "no teardown ack within "
                                     f"{self.dead_rank_secs:.1f}s")
-                    elif self._round_kind in ("replace", "shrink",
-                                              "evict"):
+                    elif self._round_kind in ("replace", "shrink"):
                         # acks complete but the membership half never
                         # finished (manifest or adoption wedged past
                         # every narrower deadline): terminal
@@ -2830,37 +2051,6 @@ class Master:
                     if (rec.adopt_since is not None
                             and now - rec.adopt_since > self._adopt_secs):
                         lost_spares.append(rec)
-                # grow adoptions share the deadline (ISSUE 13)
-                if self._grow_state is not None:
-                    for r, rec in list(
-                            self._grow_state["pending"].items()):
-                        if (rec.adopt_since is not None
-                                and now - rec.adopt_since
-                                > self._adopt_secs):
-                            lost_spares.append(rec)
-                # a resize generation stalled past the dead-rank
-                # threshold means a rank never reached the boundary —
-                # same escalation as a stalled barrier (ISSUE 13)
-                for gen, since in list(self._resize_since.items()):
-                    if gen not in self._resize_waiting:
-                        continue
-                    age = now - since
-                    if (age > self.dead_rank_secs
-                            and self._fatal_msg is None
-                            and fatal is None
-                            and not (self.elastic != "off"
-                                     and round_open)):
-                        missing = sorted(
-                            set(range(self.slave_num))
-                            - set(self._resize_waiting[gen]))
-                        fatal = (f"resize gen {gen} stalled for "
-                                 f"{age:.1f}s waiting on ranks "
-                                 f"{missing}; aborting the job")
-                        if self.elastic != "off":
-                            for r in missing:
-                                escalate.setdefault(
-                                    r, f"resize gen {gen} stalled "
-                                    f"{age:.1f}s without it")
             for gen, ranks, age in stalled:
                 missing = sorted(set(range(self.slave_num)) - set(ranks))
                 self._log("M", "WARN",
@@ -2873,8 +2063,7 @@ class Master:
                 self._spare_gone(
                     rec, f"adoption not acked within "
                     f"{self._adopt_secs:.1f}s")
-            # the eviction fence's deadline + liveness re-checks ride
-            # the same tick (ISSUE 13)
+            # the tuner fence's deadline rides the same tick
             self._check_fence()
             if fatal is not None:
                 if self.elastic != "off" and escalate:
@@ -2918,9 +2107,6 @@ class Master:
             with self._lock:
                 del self._barrier_waiting[gen]
                 self._barrier_since.pop(gen, None)
-        # a barrier arrival can complete an armed eviction fence (a
-        # rank idling in a barrier IS at a boundary — ISSUE 13)
-        self._check_fence()
 
 
 def _serve_section(ranks: dict, cluster_hists: dict) -> dict | None:

@@ -497,12 +497,6 @@ class ProcessCommSlave(CommSlave):
         # the adoption manifest ships this count so a joiner's next
         # barrier call pairs with the survivors' (ISSUE 10)
         self._barrier_done = 0
-        # resize-point generations (ISSUE 13): entered / completed
-        # counts mirror the barrier pair; the ctl thread parks results
-        # per generation until resize_point() collects them
-        self._resize_gen = 0
-        self._resize_done = 0
-        self._resize_results: dict[int, dict] = {}
         # adoption resume position (0 on ordinary members): the
         # application reads these to know where the job already is
         self.resume_seq = 0
@@ -604,49 +598,6 @@ class ProcessCommSlave(CommSlave):
                 # the previous value
                 self._barrier_done = gen + 1
                 return
-        raise self._recovery.fatal_exc()
-
-    def resize_point(self) -> list:
-        """An explicit APP EPOCH BOUNDARY the roster may change at
-        (ISSUE 13 grow mode): every rank calls this at the same point
-        in its schedule (like :meth:`barrier`); under
-        ``MP4J_ELASTIC=grow`` + ``MP4J_AUTOSCALE=act`` the master
-        adopts registered warm spares into NEW rank ids here —
-        EXPANDING ``slave_num`` between epochs — and every rank
-        returns the (possibly grown) roster. The adopted joiners'
-        constructors return fully seeded (``resume_seq`` names the
-        collective ordinal the job is at), exactly like replacement
-        adoption. With growth unavailable (mode off, no spares, rails
-        closed) this is a no-op rendezvous returning the current
-        roster.
-
-        Rank 0's call donates the canonical columnar vocabulary for
-        the joiners' seed — at a quiesced boundary every rank's codec
-        tables are identical by construction (they only ever grow
-        inside the synchronized novelty exchange)."""
-        if self._async is not None:
-            # the collective-boundary drain, like barrier(): the
-            # roster must not change under outstanding futures
-            self._async.drain_for_blocking()
-        gen = self._resize_gen
-        self._resize_gen += 1
-        payload = {"gen": gen, "seq": self._progress_state[0],
-                   "stats_seq": self._comm_stats.progress()["seq"],
-                   "barrier_gen": self._barrier_done}
-        if self._rank == 0:
-            payload["vocab"] = self._vocab_export()
-        self._master_send((master_mod.RESIZE, payload))
-        with self._ctl_cv:
-            # unbounded like barrier(): the release waits on the
-            # slowest rank; a terminal abort (or an eviction) breaks
-            # the wait with the cluster-wide error
-            self._ctl_cv.wait_for(
-                lambda: gen in self._resize_results
-                or self._recovery.fatal is not None)
-            if gen in self._resize_results:
-                self._resize_results.pop(gen)
-                self._resize_done = gen + 1
-                return list(self._roster)
         raise self._recovery.fatal_exc()
 
     # -- control-plane receiver (ISSUE 5) -------------------------------
@@ -754,7 +705,6 @@ class ProcessCommSlave(CommSlave):
                     # collective thread waits out the round
                     with self._ctl_cv:
                         barrier_gen = self._barrier_done
-                        resize_gen = self._resize_done
                     try:
                         self._master_send((master_mod.MANIFEST, {
                             "epoch": int(msg[1]),
@@ -764,7 +714,6 @@ class ProcessCommSlave(CommSlave):
                             "stats_seq": self._comm_stats.progress()[
                                 "seq"],
                             "barrier_gen": barrier_gen,
-                            "resize_gen": resize_gen,
                         }))
                     except (Mp4jError, OSError):
                         pass  # master gone; its watchdog owns this
@@ -775,17 +724,9 @@ class ProcessCommSlave(CommSlave):
                     # drains — the evidence must survive the process
                     ev = msg[1] if isinstance(msg[1], dict) else {}
                     self._health_alerts.note(ev)
-                    if ev.get("kind") == "autoscale":
-                        # controller action events (ISSUE 13) share
-                        # the pipe: timelines interleave actions with
-                        # the verdicts that caused them
-                        self._recovery.note(
-                            "autoscale",
-                            f"{ev.get('event')} {ev.get('action')}: "
-                            f"{ev.get('msg', '')}"[:160])
-                    elif ev.get("kind") == "tuner":
+                    if ev.get("kind") == "tuner":
                         # tuner controller events (ISSUE 15: demote /
-                        # would_demote / trip) — same pipe, logged
+                        # would_demote / trip) share the pipe, logged
                         # under their own kind so mp4j-scope tuner
                         # finds them (the health onset fallback would
                         # render them as "rank None onset (None)")
@@ -819,7 +760,7 @@ class ProcessCommSlave(CommSlave):
                         self._tuner.trip(why)
                     self._recovery.note("tuner", f"TRIPPED: {why}")
                 elif kind == "fence":
-                    # eviction fence (ISSUE 13): park at the next
+                    # tuner fence (ISSUE 15): park at the next
                     # outermost collective boundary, wire untouched
                     self._recovery.on_fence(int(msg[1]))
                 elif kind == "fence_advance":
@@ -827,30 +768,6 @@ class ProcessCommSlave(CommSlave):
                                                     int(msg[2]))
                 elif kind == "fence_release":
                     self._recovery.on_fence_release(int(msg[1]))
-                elif kind == "evicted":
-                    # planned eviction (ISSUE 13): this rank's id now
-                    # belongs to an adopted spare — every parked wait
-                    # breaks with a clean Mp4jEvicted, close() skips
-                    # the handshake the master already wrote off
-                    self._recovery.on_evicted(str(msg[1]))
-                elif kind == "resize_go":
-                    # resize release (ISSUE 13): a grown roster lands
-                    # BEFORE resize_point() wakes (its re-dials and
-                    # the next collective's schedule read it); None
-                    # info = no change this generation
-                    info = msg[2] if len(msg) > 2 else None
-                    if info and "roster" in info:
-                        self._set_roster(info["roster"])
-                        self._sync_identity()
-                        self._recovery.note(
-                            "grow",
-                            f"roster grew to {self._n} rank(s) "
-                            f"(new: {info.get('grown')}) @ resize "
-                            f"{msg[1]}")
-                    with self._ctl_cv:
-                        self._resize_results[int(msg[1])] = info or {}
-                        self._ctl_cv.notify_all()
-                    self._ctl_wake()
                 elif kind == "abort_fatal":
                     self._recovery.on_fatal(str(msg[1]))
                 else:
@@ -966,12 +883,6 @@ class ProcessCommSlave(CommSlave):
         gen = int(info.get("barrier_gen", 0))
         self._barrier_gen = gen
         self._barrier_done = gen
-        # resize position (ISSUE 13): the joiner's next resize_point
-        # pairs with the survivors' next one (grow adoptions seed
-        # gen+1 of the round that adopted them)
-        rz = int(info.get("resize_gen", 0))
-        self._resize_gen = rz
-        self._resize_done = rz
         self.resume_seq = seq
         self.resume_barrier_gen = gen
         membership_mod.import_vocab(self._map_codecs,
@@ -981,9 +892,8 @@ class ProcessCommSlave(CommSlave):
         self._comm_stats.add("replacements_seen", 1)
         self._recovery.note(
             "adopted",
-            f"rank {self._rank} @ epoch {epoch} seq {seq}"
-            + (" (grow)" if info.get("grow") else "")
-            + f" ({info.get('why', '')})"[:160])
+            f"rank {self._rank} @ epoch {epoch} seq {seq} "
+            f"({info.get('why', '')})"[:160])
 
     def _vocab_export(self) -> dict[str, list]:
         """This rank's keycodec vocabularies for the adoption manifest,
@@ -1164,32 +1074,21 @@ class ProcessCommSlave(CommSlave):
         # heartbeat thread takes _tel_lock then _master_lock; nesting
         # them here in the other order would be a lock-order inversion)
         flush = self._telemetry_payload()
-        # an EVICTED rank (ISSUE 13) skips the whole handshake: the
-        # master already wrote this process off (its rank id belongs
-        # to the adopted spare, inbound messages are dropped), so the
-        # CLOSE would land nowhere and the "closed" ack would never
-        # come — waiting it out would turn every clean eviction into
-        # a 5 s shutdown stall
-        evicted = self._recovery.evicted
         with self._master_lock:
             if self._closed:
                 return
             # final telemetry flush so the master's skew table covers
             # the whole run, then the close handshake
-            if not evicted:
-                try:
-                    self._master.send_obj(
-                        (master_mod.TELEMETRY, flush))
-                except (Mp4jError, OSError):
-                    pass  # master may already be gone; close proceeds
+            try:
+                self._master.send_obj((master_mod.TELEMETRY, flush))
+            except (Mp4jError, OSError):
+                pass  # master may already be gone; close proceeds
             self._closed = True
-            if not evicted:
-                try:
-                    self._master.send_obj(
-                        (master_mod.CLOSE, {"code": code}))
-                    sent = True
-                except (Mp4jError, OSError):
-                    pass
+            try:
+                self._master.send_obj((master_mod.CLOSE, {"code": code}))
+                sent = True
+            except (Mp4jError, OSError):
+                pass
         if sent:
             # the "closed" ack arrives on the control thread; bounded —
             # a vanished master must not wedge shutdown
@@ -1391,17 +1290,6 @@ class ProcessCommSlave(CommSlave):
                         or isinstance(peer_epoch, bool)
                         or not isinstance(peer_epoch, int)):
                     raise TypeError(f"malformed peer handshake {hs!r}")
-                if peer_rank >= self._n:
-                    # a freshly grown joiner dials the moment its
-                    # constructor returns, which can beat the master's
-                    # resize_go to this rank by one control push
-                    # (ISSUE 13): wait briefly for the roster to grow
-                    # instead of rejecting a healthy peer
-                    with self._peer_cv:
-                        self._peer_cv.wait_for(
-                            lambda: peer_rank < self._n
-                            or self._recovery.fatal is not None,
-                            timeout=self._handshake_timeout)
                 if seg_token is not None:
                     # only a fingerprint-matched peer may offer a shm
                     # segment (a stray dial-in must not make us mmap

@@ -38,17 +38,6 @@ class Mp4jFatalError(Mp4jError):
     Deliberately not a transport error — nothing retries it."""
 
 
-class Mp4jEvicted(Mp4jFatalError):
-    """This rank was PROACTIVELY evicted by the elastic autoscaler
-    (ISSUE 13): the health plane recommended replacing it, the
-    controller quiesced the job at a collective boundary, a warm spare
-    was adopted into this rank's id, and the job continues without this
-    process. A clean release, not a failure — the hosting process
-    should treat it like :class:`Mp4jSpareReleased` (exit 0). Subclass
-    of :class:`Mp4jFatalError` so every wait that a terminal abort
-    breaks also breaks for an eviction, and nothing ever retries it."""
-
-
 class Mp4jSpareReleased(Mp4jError):
     """A warm spare (ISSUE 10, ``ProcessCommSlave(spare=True)``) was
     released without ever being adopted: the job completed (or died)

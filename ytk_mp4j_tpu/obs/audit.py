@@ -645,19 +645,6 @@ class ClusterAuditor:
             lines.extend(self._maybe_verify(seq, set()))
         return lines
 
-    def note_grow(self, slave_num: int, resume_seq: int) -> list[str]:
-        """The roster GREW (ISSUE 13): ordinals at or below the
-        joiners' resume position can never receive their records —
-        settle those pending seqs against whoever did report (the
-        ``note_replacement`` rule), then widen the expected rank
-        count for everything after."""
-        lines: list[str] = []
-        for seq in sorted(s for s in self._pending if s <= resume_seq):
-            # live=∅ forces completeness among the actual reporters
-            lines.extend(self._maybe_verify(seq, set()))
-        self.slave_num = slave_num
-        return lines
-
     def note_shrink(self, slave_num: int,
                     mapping: dict[int, int]) -> None:
         """The roster renumbered (shrink): remap the per-rank audit

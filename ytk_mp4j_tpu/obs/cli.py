@@ -73,7 +73,7 @@ fingerprint, and cross-job ``CONTENTION`` rows. ``--sink DIR`` (or
 ``MP4J_FLEET_SINK_DIR``) additionally lands the fleet history
 durably as crc-framed segments; ``fleet-report`` reconstructs the
 merged fleet event timeline (job up/stale/gone/restart, health
-transitions, autoscaler actions, contention episodes) offline from
+transitions, contention episodes) offline from
 such a directory.
 
 Exit codes: 0 ok, 1 replay divergence, 2 bad invocation / unreadable

@@ -32,7 +32,7 @@ that read-only fleet plane:
   recovery guarantees, same rotation/eviction budget discipline), and
   :func:`fleet_report` reconstructs the merged **fleet event
   timeline** — job up/stale/gone/restart, per-rank health
-  transitions, autoscaler actions, contention onsets — offline from a
+  transitions, contention onsets — offline from a
   fleet sink directory (``mp4j-scope fleet-report``).
 
 Obs discipline: imports nothing from ``comm`` — the poller observes
@@ -136,7 +136,6 @@ def job_summary(metrics_doc: dict, health_doc: dict | None = None
     ladder: dict[str, int] = {}
     for s in hstates.values():
         ladder[s] = ladder.get(s, 0) + 1
-    asc = cl.get("autoscale") or {}
     # serve summary (ISSUE 19): carried whole so the fleet view can
     # render serve jobs distinctly (QPS cell); None for batch jobs
     serve = cl.get("serve") if (cl.get("serve") or {}).get("active") \
@@ -161,9 +160,6 @@ def job_summary(metrics_doc: dict, health_doc: dict | None = None
             "evict_recommended": list(
                 (hdoc or {}).get("evict_recommended") or ()),
         },
-        "autoscale_actions": int(
-            sum((asc.get("actions") or {}).values())
-            + sum((asc.get("observed") or {}).values())),
         "serve": serve,
     }
 
@@ -363,12 +359,6 @@ class FleetPoller:
                     self._event("health", job,
                                 f"job {jid}: rank {r} {o}->{s}",
                                 events_out)
-            if (summary["autoscale_actions"]
-                    > prev_summary["autoscale_actions"]):
-                self._event("autoscale", job,
-                            f"job {jid}: autoscaler acted "
-                            f"({summary['autoscale_actions']} total)",
-                            events_out)
         job.update(state=LIVE, job_id=jid, summary=summary,
                    last_ok=self._now(), failures=0, last_error=None,
                    next_try=self._now())
@@ -634,7 +624,7 @@ def read_fleet(root: str) -> dict:
 def fleet_report(root: str) -> dict:
     """Offline reconstruction from a fleet sink dir: the merged event
     timeline (job up/stale/gone/restart, health transitions,
-    autoscaler actions, contention on/off), the jobs ever seen with
+    contention on/off), the jobs ever seen with
     their last-known state, and contention EPISODES (onset..clear
     windows, open-ended when the history ends contended)."""
     doc = read_fleet(root)

@@ -5,9 +5,8 @@ The repo measures three telemetry planes — mp4j-scope time spans
 plane (ISSUE 8) — but until this module nothing *interpreted* them.
 This is the health plane: it folds every heartbeat into rolling
 per-rank baselines, runs a detector set over the deltas, and drives a
-per-rank hysteresis state machine whose verdicts are the decision
-substrate the elastic autoscaler (ROADMAP) consumes — this plane
-RECOMMENDS, it never acts.
+per-rank hysteresis state machine whose verdicts an operator reads —
+this plane RECOMMENDS, it never acts.
 
 State machine (per rank)::
 
@@ -63,7 +62,7 @@ sink (the ``alerts`` record kind in :mod:`sink`), exported as
 Prometheus series (``mp4j_rank_health_state``, ``mp4j_alerts_total``,
 ``mp4j_evict_recommended``, ``mp4j_straggler_onsets_total``,
 ``mp4j_critpath_dominator``), surfaced via ``Master.health_status()``
-(the operator hook a future autoscaler calls), the ``health`` column
+(the operator's hook), the ``health`` column
 in ``mp4j-scope live``, the ``mp4j-scope health`` subcommand, and the
 postmortem report's health timeline.
 
@@ -599,16 +598,6 @@ class HealthEngine:
                         self._ranks[int(rank)])
         return [ev]
 
-    def note_grow(self, slave_num: int) -> None:
-        """The roster GREW (ISSUE 13): widen the expected rank count.
-        Ordinals completed before the growth can never collect the
-        joiners' cells — drop them (counted, never silent) so they
-        don't jam the pending table until the cap prunes them; the
-        joiners' verdicts start HEALTHY lazily on their first fold."""
-        self.slave_num = int(slave_num)
-        self._cells_dropped += sum(len(c) for c in self._cells.values())
-        self._cells.clear()
-
     def note_shrink(self, slave_num: int,
                     mapping: dict[int, int]) -> None:
         """The roster renumbered: remap verdicts, drop the dead, and
@@ -642,7 +631,7 @@ class HealthEngine:
             links = {int(p): lk for p, lk
                      in (cell.get("links") or {}).items()}
             # rolling per-link wire GB/s baseline (status evidence for
-            # the autoscaler: which link a slow rank is slow ON)
+            # the operator: which link a slow rank is slow ON)
             for peer, lk in links.items():
                 secs = float(lk.get("secs") or 0.0)
                 if secs > 0 and lk.get("bytes"):
@@ -874,10 +863,9 @@ class HealthEngine:
     def status(self) -> dict:
         """The health document — ``Master.health_status()``, the
         metrics doc's ``cluster.health`` section, the postmortem
-        manifest. This is the contract the future elastic autoscaler
-        reads: ``evict_recommended`` lists the ranks this plane
-        RECOMMENDS replacing (it never acts), each with the detector
-        evidence behind the verdict."""
+        manifest. ``evict_recommended`` lists the ranks this plane
+        RECOMMENDS replacing (it never acts — that is the operator's
+        call), each with the detector evidence behind the verdict."""
         ranks = {}
         for r in sorted(self._ranks):
             rec = self._ranks[r]
@@ -923,17 +911,10 @@ _fmt_wall = critpath.fmt_wall
 
 
 def format_alert(ev: dict) -> str:
-    if ev.get("kind") == "autoscale":
-        # an autoscaler action event (ISSUE 13) — rides the same
-        # alert pipe so timelines interleave actions with verdicts
-        return (f"{_fmt_wall(ev.get('wall'))}  autoscaler "
-                f"{ev.get('event')} {ev.get('action')}"
-                + (f" rank {ev['rank']}"
-                   if ev.get("rank") is not None else "")
-                + f": {ev.get('msg', '')}")
     if ev.get("kind") == "tuner":
         # a self-tuning data-plane event (ISSUE 15: leader demotion,
-        # audit trip) — same pipe, same timelines
+        # audit trip) — rides the same alert pipe so timelines
+        # interleave actions with verdicts
         return (f"{_fmt_wall(ev.get('wall'))}  tuner "
                 f"{ev.get('event')}"
                 + (f" rank {ev['rank']}"
@@ -972,8 +953,8 @@ def format_status(health: dict) -> str:
     evict = health.get("evict_recommended") or []
     if evict:
         lines.append(f"EVICT RECOMMENDED: rank(s) "
-                     f"{', '.join(map(str, evict))} — the autoscaler "
-                     "hook (health_status()) carries the evidence")
+                     f"{', '.join(map(str, evict))} — "
+                     "health_status() carries the evidence")
     dom = health.get("dominator") or {}
     if dom.get("shares"):
         share_s = ", ".join(f"rank {r}: {s * 100:.0f}%"

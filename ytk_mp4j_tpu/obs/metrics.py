@@ -161,15 +161,6 @@ METRICS_DOC: dict[str, str] = {
     "mp4j_critpath_dominator": "per-rank share of recently attributed "
                                "ordinals this rank gated (sliding "
                                "window)",
-    # -- autoscaler (ISSUE 13) ------------------------------------------
-    "mp4j_autoscale_actions_total": "autoscaler actions DISPATCHED, "
-                                    "by action (evict_replace / "
-                                    "provision / grow) — alert on "
-                                    "unexpected growth",
-    "mp4j_autoscale_tripped": "1 when the autoscaler's circuit "
-                              "breaker tripped it back to "
-                              "recommend-only (two consecutive "
-                              "failed actions)",
     # -- serve plane (ISSUE 19) -----------------------------------------
     "mp4j_serve_requests_total": "serve requests completed per rank "
                                  "(+ cluster total)",
@@ -689,21 +680,6 @@ def to_prometheus(doc: dict) -> str:
     if srv is not None and srv.get("active"):
         out.append("# TYPE mp4j_serve_qps gauge")
         out.append(f"mp4j_serve_qps {_fmt(float(srv.get('qps', 0.0)))}")
-
-    # autoscaler (ISSUE 13): per-action dispatch counters + the
-    # circuit-breaker gauge — present whenever the master runs a
-    # controller (MP4J_AUTOSCALE=observe|act), absent entirely when
-    # off (no zero-noise; `off` is today's behavior bit-for-bit)
-    asc = doc.get("cluster", {}).get("autoscale")
-    if asc is not None:
-        out.append("# TYPE mp4j_autoscale_actions_total counter")
-        for action, n in sorted((asc.get("actions") or {}).items()):
-            out.append(
-                f'mp4j_autoscale_actions_total{{action="{_esc(action)}"'
-                f"}} {int(n)}")
-        out.append("# TYPE mp4j_autoscale_tripped gauge")
-        out.append(f"mp4j_autoscale_tripped "
-                   f"{1 if asc.get('tripped') else 0}")
 
     out.append("# TYPE mp4j_collective_latency_seconds histogram")
     hists = doc.get("cluster", {}).get("histograms", {})

@@ -99,8 +99,7 @@ def write_master_manifest(root: str, *, slave_num: int, reason: str,
                           audit: dict | None = None,
                           sink_dir: str | None = None,
                           membership: dict | None = None,
-                          health: dict | None = None,
-                          autoscale: dict | None = None) -> str:
+                          health: dict | None = None) -> str:
     """The master's cluster-level half of the recorder: who the job
     thought was alive, why it died, and the final heartbeat table
     (fresh — the slaves' fatal-path telemetry flush lands before the
@@ -114,10 +113,7 @@ def write_master_manifest(root: str, *, slave_num: int, reason: str,
     job ever ran under; ``health`` (ISSUE 12) freezes the health
     plane's final verdicts — per-rank state, the first-degradation
     event and the recent alert tail — so the report can answer *what
-    degraded first, when, and which detector saw it*; ``autoscale``
-    (ISSUE 13) freezes the controller's ledger — actions taken,
-    would-be actions observed, circuit-breaker state — so the report
-    shows what the autopilot DID about the degradation it saw."""
+    degraded first, when, and which detector saw it*."""
     os.makedirs(root, exist_ok=True)
     path = os.path.join(root, "manifest.json")
     _dump(root, "manifest.json", {
@@ -129,7 +125,6 @@ def write_master_manifest(root: str, *, slave_num: int, reason: str,
         "sink_dir": sink_dir or None,
         "membership": membership,
         "health": health,
-        "autoscale": autoscale,
         "table": {str(r): t for r, t in table.items()},
         # mp4j-lint: disable=R11 (artifact timestamp, not a duration)
         "wall_time": time.time(),
@@ -262,31 +257,6 @@ def merge_report(root: str) -> str:
             lines.append(
                 f"health: EVICT was recommended for rank(s) "
                 f"{', '.join(map(str, evict))} before the fatal")
-
-    # autoscaler actions (ISSUE 13): what the autopilot did (or would
-    # have done) about the degradation the health section describes —
-    # a postmortem that shows verdicts without actions can't tell a
-    # controller that failed to act from one that was never armed
-    asc = (manifest or {}).get("autoscale") or {}
-    if asc:
-        lines.append(
-            f"autoscaler: mode={asc.get('mode')}"
-            + (" TRIPPED (recommend-only)" if asc.get("tripped")
-               else "")
-            + f", actions {asc.get('actions')}, "
-            f"budget {asc.get('budget', {}).get('used', 0)}/"
-            f"{asc.get('budget', {}).get('limit', 0)}")
-        if asc.get("tripped"):
-            lines.append(
-                f"autoscaler: breaker tripped: {asc.get('tripped_why')}")
-        for ev in asc.get("events") or []:
-            lines.append(
-                f"autoscaler event: {ev.get('event')} "
-                f"{ev.get('action')}"
-                + (f" rank {ev['rank']}"
-                   if ev.get("rank") is not None else "")
-                + f" at {_fmt_wall(ev.get('wall'))}: "
-                  f"{ev.get('msg', '')}")
 
     # known-good watermark (ISSUE 8): the last collective ordinal the
     # master cross-rank-verified before the fatal — everything up to

@@ -189,24 +189,6 @@ def format_live(doc: dict) -> str:
                     else "off")
                  + (f" | {sv['degraded_batches']} DEGRADED"
                     if sv.get("degraded_batches") else ""))
-    # autoscaler head-line (ISSUE 13): mode, trip state, action tally;
-    # absent entirely when MP4J_AUTOSCALE=off (no controller exists)
-    asc = cl.get("autoscale") or {}
-    if asc:
-        acted = sum((asc.get("actions") or {}).values())
-        would = sum((asc.get("observed") or {}).values())
-        head += (f"\nautoscale: mode={asc.get('mode')}"
-                 + (" TRIPPED" if asc.get("tripped") else "")
-                 + f" | {acted} action(s)"
-                 + (f", {would} observed" if would else "")
-                 + f" | budget {asc.get('budget', {}).get('used', 0)}"
-                 f"/{asc.get('budget', {}).get('limit', 0)}")
-        events = asc.get("events") or []
-        if events:
-            ev = events[-1]
-            head += (f"\n  last: {ev.get('event')} "
-                     f"{ev.get('action')} "
-                     f"{str(ev.get('msg', ''))[:60]}")
     if not ranks:
         return head + "\n(no rank telemetry yet)"
     skew = cluster_skew({int(r): info.get("stats", {})
@@ -396,7 +378,7 @@ def _wall_hms(wall) -> str:
 def format_fleet_report(report: dict) -> str:
     """The ``mp4j-scope fleet-report`` view: jobs ever seen with their
     last-known state, the merged event timeline (job up/stale/gone/
-    restart, health transitions, autoscaler actions, contention
+    restart, health transitions, contention
     on/off) and contention episodes, from
     :func:`ytk_mp4j_tpu.obs.fleet.fleet_report`'s dict. Pure."""
     lines = [f"fleet report — {report.get('snapshots', 0)} "

@@ -20,15 +20,10 @@ deliberate departure from that scope:
   same clean ``Mp4jFatalError`` naming the dead rank — never a hang,
   never a partial result.
 - :mod:`ytk_mp4j_tpu.resilience.membership` — elastic membership
-  (ISSUE 10): warm-spare replacement, degraded shrink, and the grow
-  roster algebra; pure protocol functions + the master's spare pool
-  and membership event log.
-- :mod:`ytk_mp4j_tpu.resilience.autoscaler` — mp4j-autopilot
-  (ISSUE 13): the closed-loop controller that reads
-  ``Master.health_status()`` verdicts and ACTS through the membership
-  machinery — planned eviction, spare auto-provisioning, grow
-  approval — behind cooldown/budget/audit-green/circuit-breaker
-  safety rails (``MP4J_AUTOSCALE=off|observe|act``).
+  (ISSUE 10): warm-spare replacement and degraded shrink, both
+  started by a rank's death; pure protocol functions + the master's
+  spare pool and membership event log. ``Master.health_status()``
+  only recommends: acting on a verdict is the operator's call.
 """
 
 from ytk_mp4j_tpu.resilience.faults import (  # noqa: F401

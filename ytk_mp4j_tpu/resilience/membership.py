@@ -112,13 +112,6 @@ def shrink_roster(roster: list, mapping: dict[int, int]) -> list:
     return out
 
 
-def grow_roster(roster: list, entries: list[tuple]) -> list:
-    """The n+k roster (ISSUE 13 grow mode): the adopted spares'
-    (host, port, fp) entries appended in NEW-rank order — existing
-    ids never move, so survivors keep every peer channel they hold."""
-    return list(roster) + list(entries)
-
-
 # ----------------------------------------------------------------------
 # vocabulary replay (the manifest's columnar half)
 # ----------------------------------------------------------------------
@@ -171,7 +164,7 @@ class SpareRecord:
     (host, listen_port, fp) and lifecycle flags."""
 
     __slots__ = ("idx", "ch", "entry", "alive", "adopting_rank",
-                 "adopt_since", "last_ping", "grow")
+                 "adopt_since", "last_ping")
 
     def __init__(self, idx: int, ch, entry: tuple):
         self.idx = idx
@@ -181,9 +174,6 @@ class SpareRecord:
         self.adopting_rank: int | None = None   # mid-adoption target
         self.adopt_since: float | None = None   # mono ts of adopt send
         self.last_ping = time.monotonic()
-        # ISSUE 13: this adoption EXPANDS the roster (a NEW rank id at
-        # a resize_point boundary) instead of replacing a casualty
-        self.grow = False
 
 
 class MembershipLog:
@@ -197,11 +187,6 @@ class MembershipLog:
         self.mode = mode
         self.replacements = 0
         self.shrinks = 0
-        # ISSUE 13: planned (autoscaler-driven) evictions and grow
-        # rounds, counted apart from death-driven replacements — the
-        # operator must be able to tell recovery from actuation
-        self.planned_evictions = 0
-        self.grows = 0
         self.events: collections.deque = collections.deque(maxlen=64)
         # rank -> current badge ("REPLACED@e1", "SHRUNK 3->2@e1")
         self.badges: dict[int, str] = {}
@@ -214,80 +199,6 @@ class MembershipLog:
             "kind": "replace", "rank": rank, "epoch": epoch,
             "spare": spare_idx, "why": why,
             "mono": time.monotonic()})
-
-    def note_evict(self, rank: int, epoch: int, spare_idx: int,
-                   why: str) -> None:
-        """A LIVE rank was proactively evicted and replaced (ISSUE 13
-        planned eviction) — the autoscaler polls for this event kind
-        to confirm its action landed."""
-        self.planned_evictions += 1
-        self.replacements += 1
-        self.badges[rank] = f"EVICTED@e{epoch}"
-        self.events.append({
-            "kind": "planned_evict", "rank": rank, "epoch": epoch,
-            "spare": spare_idx, "why": why,
-            "mono": time.monotonic()})
-
-    def note_spare(self, idx: int) -> None:
-        """A warm spare registered. The autoscaler resolves a pending
-        ``provision`` action on this event — observing the
-        ``spares_available`` gauge alone is race-prone: a waiting
-        membership round can claim the fresh spare synchronously at
-        registration, so the gauge never visibly leaves 0 even though
-        the provision succeeded (and saved the job)."""
-        self.events.append({
-            "kind": "spare_registered", "spare": idx,
-            "mono": time.monotonic()})
-
-    def note_evict_cancel(self, rank: int, token: int,
-                          why: str) -> None:
-        """An eviction FENCE was canceled before anything was torn
-        down — zero disruption, the victim stays a member. The
-        autoscaler reads this as a benign RETRY (budget refunded),
-        never a circuit-breaker failure."""
-        self.events.append({
-            "kind": "evict_fence_cancel", "rank": rank,
-            "token": token, "why": why, "mono": time.monotonic()})
-
-    def note_evict_abort(self, ranks: list[int], epoch: int,
-                         why: str) -> None:
-        """A planned-eviction round could not complete (spare pool
-        exhausted mid-round): the round was released as a plain abort
-        with the victim still a member. The autoscaler reads this
-        event as a FAILED action (circuit-breaker input)."""
-        self.events.append({
-            "kind": "evict_abort", "ranks": list(ranks),
-            "epoch": epoch, "why": why, "mono": time.monotonic()})
-
-    def note_grow(self, new_ranks: list[int], epoch: int,
-                  gen: int) -> None:
-        """Registered spares were adopted into NEW rank ids at a
-        ``resize_point()`` boundary (ISSUE 13 grow mode)."""
-        self.grows += 1
-        for r in new_ranks:
-            self.badges[r] = f"GROWN@z{gen}"
-        self.events.append({
-            "kind": "grow", "ranks": list(new_ranks), "epoch": epoch,
-            "gen": gen, "mono": time.monotonic()})
-
-    def note_grow_cancel(self, gen: int, why: str) -> None:
-        """An approved grow was dropped BEFORE any adoption was
-        dispatched (revalidation under the lock found the spare gone
-        or a round open): zero disruption — the autoscaler settles
-        its pending action as a benign retry, mirroring
-        :meth:`note_evict_cancel`."""
-        self.events.append({
-            "kind": "grow_cancel", "gen": gen, "why": why,
-            "mono": time.monotonic()})
-
-    def note_grow_abort(self, ranks: list[int], gen: int,
-                        why: str) -> None:
-        """A grow round failed mid-adoption and was rolled back: the
-        resize released unchanged, any seeded joiners were released
-        with ``Mp4jEvicted``. A FAILED action for the autoscaler."""
-        self.events.append({
-            "kind": "grow_abort", "ranks": list(ranks), "gen": gen,
-            "why": why, "mono": time.monotonic()})
 
     def note_shrink(self, dead: list[int], mapping: dict[int, int],
                     epoch: int, why: str) -> None:
@@ -306,8 +217,6 @@ class MembershipLog:
             "mode": self.mode,
             "replacements": self.replacements,
             "shrinks": self.shrinks,
-            "planned_evictions": self.planned_evictions,
-            "grows": self.grows,
             "spares_available": spares_available,
             "spares_total": spares_total,
             "badges": {str(r): b for r, b in self.badges.items()},

@@ -47,8 +47,7 @@ import threading
 import time
 
 from ytk_mp4j_tpu.exceptions import (
-    Mp4jAbortError, Mp4jError, Mp4jEvicted, Mp4jFatalError,
-    Mp4jTransportError)
+    Mp4jAbortError, Mp4jError, Mp4jFatalError, Mp4jTransportError)
 from ytk_mp4j_tpu.obs import spans
 
 # the recoverable class: wire-level Mp4jTransportError (which includes
@@ -113,14 +112,10 @@ class RecoveryManager:
         self.epoch = 0          # last epoch the master released (go)
         self._target = 0        # highest abort epoch announced
         self._fatal: str | None = None
-        # planned eviction (ISSUE 13): the terminal message is a clean
-        # release, not a failure — waiters raise Mp4jEvicted instead
-        # of Mp4jFatalError and the postmortem recorder stays quiet
-        self._evicted = False
-        # the soft boundary fence (ISSUE 13): while set, the
-        # collective thread PARKS at its next outermost entry (acking
-        # its position) instead of starting the collective — the
-        # master's planned-eviction quiesce, with the wire untouched.
+        # the soft boundary fence: while set, the collective thread
+        # PARKS at its next outermost entry (acking its position)
+        # instead of starting the collective — the master's quiesce
+        # for a tuner topology update (ISSUE 15), wire untouched.
         # ``_fence_goal`` is the ordinal the master wants COMPLETED
         # before parking (fence_advance): a rank parked early would
         # starve a peer's in-flight batch that still needs it, so the
@@ -172,7 +167,7 @@ class RecoveryManager:
 
     def on_fence(self, token: int) -> None:
         """The master wants every rank parked at a collective
-        boundary (ISSUE 13 planned eviction): arm the fence. The
+        boundary (a tuner topology update): arm the fence. The
         collective thread acks and parks at its NEXT outermost entry
         — nothing is torn down, so a canceled fence costs nothing."""
         with self._cond:
@@ -199,8 +194,9 @@ class RecoveryManager:
 
     def on_fence_release(self, token: int) -> None:
         """The master canceled the fence (a rank could not reach a
-        boundary in time, or the eviction became moot): parked ranks
-        resume exactly where they were — zero disruption."""
+        boundary in time, or a round opened) or completed it (the
+        update already landed on the ctl thread): parked ranks resume
+        exactly where they were — zero disruption."""
         with self._cond:
             if self._fence_token == int(token):
                 self._fence_token = None
@@ -212,9 +208,9 @@ class RecoveryManager:
         """Collective-thread side of the fence: at an OUTERMOST
         collective entry with the fence armed — and this rank's
         position at or past the fence goal — ack the position and
-        park until the fence resolves: into an abort round (the
-        eviction proceeds; ``_join_pending_round`` below takes over),
-        a release (canceled; resume free), an ADVANCE (a peer's
+        park until the fence resolves: into an abort round
+        (``_join_pending_round`` below takes over), a release
+        (applied or canceled; resume free), an ADVANCE (a peer's
         in-flight batch still needs this rank — resume through the
         new goal, re-park at the next boundary), or a terminal
         message. Bounded: a masterless fence must not hang the job
@@ -313,45 +309,14 @@ class RecoveryManager:
         spans.mark("abort_fatal", self.rank)
         self._wake()
 
-    def on_evicted(self, msg: str) -> None:
-        """Planned eviction (ISSUE 13): the master's autoscaler
-        replaced this LIVE rank from a warm spare at a collective
-        boundary, and this message is the release. Terminal like a
-        fatal (the data plane belongs to the replacement now; every
-        blocked wait must break), but CLEAN: waiters raise
-        :class:`Mp4jEvicted`, the terminal hook stays unfired (a
-        planned eviction leaves no postmortem — nothing failed), and
-        ``close()`` skips the master handshake the master already
-        wrote off."""
-        with self._cond:
-            self._terminal_fired = True   # no flight-recorder dump
-            if self._fatal is None:
-                self._fatal = msg
-                self._evicted = True
-            self._cond.notify_all()
-        self._note("evicted", msg[:120])
-        self._teardown()
-        spans.mark("evicted", self.rank)
-        self._wake()
-
     @property
     def fatal(self) -> str | None:
         return self._fatal
 
-    @property
-    def evicted(self) -> bool:
-        """Whether the terminal message is a planned eviction."""
-        return self._evicted
-
-    def fatal_exc(self, msg: str | None = None) -> Mp4jError:
-        """THE terminal-exception constructor: every site that raises
-        the job-wide terminal message must come through here so a
-        planned eviction surfaces as :class:`Mp4jEvicted` (clean
-        release) and everything else as :class:`Mp4jFatalError` —
-        two sites deciding independently would disagree."""
-        text = self._fatal if msg is None else msg
-        return (Mp4jEvicted(text) if self._evicted
-                else Mp4jFatalError(text))
+    def fatal_exc(self, msg: str | None = None) -> Mp4jFatalError:
+        """The terminal exception: the job-wide terminal message (or
+        ``msg``) as the one class every waiter raises."""
+        return Mp4jFatalError(self._fatal if msg is None else msg)
 
     # ------------------------------------------------------------------
     # collective-thread side

@@ -39,8 +39,8 @@ Safety argument (why per-link decisions cannot desync a pair):
   (mp4j-lint R8's reasoning, honored by construction);
 - **application timing**: decisions queue and apply only at
   outermost-collective boundaries (the slave's recovery wrapper),
-  never mid-collective — the same fence discipline the autoscaler
-  uses.
+  never mid-collective — the boundary the master's tuner fence
+  parks ranks at.
 
 Numeric thresholds for transport decisions live HERE or in
 :mod:`ytk_mp4j_tpu.utils.tuning` — nowhere else (mp4j-lint R22, the
@@ -416,7 +416,7 @@ class LinkTuner:
             return pending, revert
 
     def reset(self) -> None:
-        """Membership change (replacement, shrink renumbering, grow):
+        """Membership change (replacement, shrink renumbering):
         every per-link accumulator, hysteresis state and committed
         decision is evidence about the OLD rank numbering — a
         renumbered (or replaced) peer id must not inherit the old

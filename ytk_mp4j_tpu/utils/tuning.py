@@ -120,11 +120,8 @@ Knobs (all validated where they are consumed; garbage raises
   rank is a job-wide ``Mp4jFatalError``, exactly the pre-elastic
   contract), ``replace`` (the master adopts a warm spare into the dead
   rank's id at the next epoch and the fenced retry continues
-  bit-exactly), ``shrink`` (survivors renumber contiguously and
-  continue at n-1 — reduction-only workloads), or ``grow`` (ISSUE 13:
-  replacement-on-death PLUS roster EXPANSION — registered spares are
-  adopted into NEW rank ids at an explicit app epoch boundary,
-  ``ProcessCommSlave.resize_point()``, gated by ``MP4J_AUTOSCALE=act``).
+  bit-exactly), or ``shrink`` (survivors renumber contiguously and
+  continue at n-1 — reduction-only workloads).
   JOB-wide like ``native_transport``. CONFLICTS with
   ``MP4J_MAX_RETRIES=0``: the fenced retry IS the mechanism that
   re-runs the interrupted collective after a membership change, so
@@ -175,32 +172,12 @@ Knobs (all validated where they are consumed; garbage raises
 - ``MP4J_HEALTH_WINDOW`` — sliding window (attributed collective
   ordinals) the online dominator computes dominance shares over.
 - ``MP4J_HEALTH_DOMINATOR_ORDINALS`` — consecutive slow ordinals one
-  rank must gate before the engine recommends eviction (the ROADMAP
-  autoscaler contract: "dominator for 500 consecutive ordinals should
-  be evictable"); SUSPECT is forced at half this streak.
+  rank must gate before the engine recommends eviction ("dominator
+  for 500 consecutive ordinals should be evictable"); SUSPECT is
+  forced at half this streak.
 - ``MP4J_HEALTH_DRIFT_PCT`` — how far (percent) a rank's per-family
   latency must rise above its OWN rolling baseline — with the log2-
   histogram bucket shift confirming — before the drift detector fires.
-- ``MP4J_AUTOSCALE`` — the closed-loop elastic autoscaler (ISSUE 13;
-  ``resilience/autoscaler.py``): ``off`` (default — the master runs no
-  controller, today's behavior bit-for-bit), ``observe`` (the
-  controller runs, evaluates the health verdicts and LOGS every action
-  it would take, but never acts), ``act`` (planned eviction of
-  ``EVICT_RECOMMENDED`` ranks, spare auto-provisioning at pool
-  exhaustion, and grow adoption at ``resize_point()`` boundaries all
-  fire autonomously, behind the safety rails). Master-side only.
-- ``MP4J_AUTOSCALE_COOLDOWN_SECS`` — minimum seconds between two
-  autoscaler actions of the same kind; the anti-flap rail (a verdict
-  that persists through the cooldown is a trend, not a blip).
-- ``MP4J_AUTOSCALE_BUDGET`` — job-lifetime cap on autoscaler actions;
-  a controller that wants action N+1 is oscillating, and a bounded
-  actuator is strictly safer than an unbounded one.
-- ``MP4J_PROVISION_CMD`` — operator hook command: when the warm-spare
-  pool drains to zero under ``MP4J_AUTOSCALE=act``, the master runs
-  this shell command (env ``MP4J_MASTER_HOST``/``MP4J_MASTER_PORT``
-  point at the rendezvous listener) to spawn a fresh ``spare=True``
-  process; empty disables the subprocess path (the
-  ``Master(provision_hook=)`` constructor seam still works).
 - ``MP4J_TUNER`` — the self-tuning data plane (ISSUE 15;
   ``utils/tuner.py``): ``off`` (static knobs only, the pre-tuner
   behavior bit-for-bit), ``observe`` (default: the policy core
@@ -309,7 +286,7 @@ DEFAULT_SINK_FLUSH_SECS = 1.0
 # still far below MP4J_DEAD_RANK_SECS so a dead spare costs one
 # deadline, not the whole recovery budget.
 DEFAULT_ELASTIC_MODE = "off"
-ELASTIC_MODES = ("off", "replace", "shrink", "grow")
+ELASTIC_MODES = ("off", "replace", "shrink")
 DEFAULT_SPARES = 0
 DEFAULT_ADOPT_SECS = 10.0
 # Metrics-plane default (ISSUE 6): the window the master's rate ring
@@ -789,74 +766,6 @@ def health_drift_pct() -> float:
                      minimum=1.0)
 
 
-# Autoscaler defaults (ISSUE 13): OFF by default — acting on health
-# verdicts is an operator opt-in on top of the elastic machinery. The
-# cooldown is deliberately long relative to the health plane's
-# detection latency (one action per verdict trend, never per fold);
-# the budget bounds a flapping controller's lifetime damage.
-AUTOSCALE_MODES = ("off", "observe", "act")
-DEFAULT_AUTOSCALE_MODE = "off"
-DEFAULT_AUTOSCALE_COOLDOWN_SECS = 30.0
-DEFAULT_AUTOSCALE_BUDGET = 16
-
-
-def autoscale_mode(override=None) -> str:
-    """The autoscaler's mode (``MP4J_AUTOSCALE``): one of
-    :data:`AUTOSCALE_MODES`. ``override`` is the explicit
-    ``Master(autoscale=...)`` constructor value — same validation as
-    the env path (one validator per knob, the PR 5 discipline).
-    Master-side only: slaves never read it."""
-    if override is not None:
-        raw = str(override)
-    else:
-        raw = os.environ.get("MP4J_AUTOSCALE")
-        if raw is None or raw.strip() == "":
-            return DEFAULT_AUTOSCALE_MODE
-    name = raw.strip().lower()
-    if name not in AUTOSCALE_MODES:
-        raise Mp4jError(
-            f"MP4J_AUTOSCALE={raw!r} is not one of "
-            f"{list(AUTOSCALE_MODES)}")
-    return name
-
-
-def autoscale_cooldown_secs(override=None) -> float:
-    """Minimum seconds between two autoscaler actions of one kind
-    (``MP4J_AUTOSCALE_COOLDOWN_SECS``); >= 0 (0 is legal for tests —
-    the budget and the one-action-in-flight rule still bound the
-    controller)."""
-    if override is None:
-        return env_float("MP4J_AUTOSCALE_COOLDOWN_SECS",
-                         DEFAULT_AUTOSCALE_COOLDOWN_SECS, minimum=0.0)
-    val = float(override)
-    if val < 0:
-        raise Mp4jError(
-            f"autoscale_cooldown={override} must be >= 0")
-    return val
-
-
-def autoscale_budget(override=None) -> int:
-    """Job-lifetime autoscaler action cap (``MP4J_AUTOSCALE_BUDGET``);
-    must be >= 1 — disabling the controller is ``MP4J_AUTOSCALE=off``,
-    not a zero budget."""
-    if override is None:
-        return env_int("MP4J_AUTOSCALE_BUDGET",
-                       DEFAULT_AUTOSCALE_BUDGET, minimum=1)
-    val = int(override)
-    if val < 1:
-        raise Mp4jError(f"autoscale_budget={override} must be >= 1")
-    return val
-
-
-def provision_cmd() -> str:
-    """The operator's spare-provisioning shell command
-    (``MP4J_PROVISION_CMD``; '' disables the subprocess hook). Run by
-    the master with ``MP4J_MASTER_HOST``/``MP4J_MASTER_PORT`` in the
-    environment when the warm-spare pool drains to zero under
-    ``MP4J_AUTOSCALE=act``."""
-    return os.environ.get("MP4J_PROVISION_CMD", "").strip()
-
-
 # Self-tuning data plane defaults (ISSUE 15): OBSERVE by default — the
 # policy core runs and its would-be decisions are visible everywhere
 # (telemetry, `mp4j-scope tuner`), but nothing changes until the
@@ -903,16 +812,11 @@ def tuner_window_secs() -> float:
 # frontend's hot-key row cache: CACHE_ROWS caps resident rows (LRU),
 # STALE_VERSIONS is the published staleness bound — a cached row may
 # lag the live table by at most that many model-version bumps before a
-# lookup treats it as a miss. The load-following thresholds feed the
-# autoscaler's observe-first serve policy (idle QPS below IDLE_QPS for
-# IDLE_SECS proposes a shrink; QPS above BUSY_QPS proposes a grow).
+# lookup treats it as a miss.
 DEFAULT_SERVE_DEADLINE_MS = 2.0
 DEFAULT_SERVE_MAX_BATCH = 32
 DEFAULT_SERVE_CACHE_ROWS = 100_000
 DEFAULT_SERVE_STALE_VERSIONS = 0
-DEFAULT_SERVE_IDLE_QPS = 1.0
-DEFAULT_SERVE_BUSY_QPS = 1000.0
-DEFAULT_SERVE_IDLE_SECS = 60.0
 
 
 def serve_deadline_ms(override=None) -> float:
@@ -973,37 +877,6 @@ def serve_stale_versions(override=None) -> int:
         raise Mp4jError(
             f"serve stale_versions={override} must be >= 0")
     return val
-
-
-def serve_idle_qps() -> float:
-    """Load-following shrink threshold (``MP4J_SERVE_IDLE_QPS``):
-    sustained serve QPS below this proposes releasing a serve rank
-    (observe mode first — ISSUE 19)."""
-    return env_float("MP4J_SERVE_IDLE_QPS", DEFAULT_SERVE_IDLE_QPS,
-                     minimum=0.0)
-
-
-def serve_busy_qps() -> float:
-    """Load-following grow threshold (``MP4J_SERVE_BUSY_QPS``): serve
-    QPS at or above this proposes growing the roster at the next
-    ``resize_point()``. Must exceed the idle threshold — a crossed
-    pair would flap."""
-    idle = serve_idle_qps()
-    val = env_float("MP4J_SERVE_BUSY_QPS", DEFAULT_SERVE_BUSY_QPS,
-                    minimum=0.0)
-    if val <= idle:
-        raise Mp4jError(
-            f"MP4J_SERVE_BUSY_QPS={val} must exceed "
-            f"MP4J_SERVE_IDLE_QPS={idle}")
-    return val
-
-
-def serve_idle_secs() -> float:
-    """How long serve QPS must stay below the idle threshold before
-    the shrink proposal fires (``MP4J_SERVE_IDLE_SECS``) — sustained
-    idleness, not one quiet window."""
-    return env_float("MP4J_SERVE_IDLE_SECS", DEFAULT_SERVE_IDLE_SECS,
-                     minimum=0.0)
 
 
 def so_buf_map() -> dict[int, tuple[int, int]]:
