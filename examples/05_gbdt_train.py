@@ -1,5 +1,6 @@
 """End-to-end consumer: distributed GBDT (the north-star workload).
-Continuous features are quantile-binned on device, samples shard over
+Continuous features cross to the mesh once, in row chunks, and are
+sketched and quantile-binned on device, samples shard over
 the mesh, each boosting round is ONE jitted shard_map step whose
 histogram allreduce is a psum, and ensemble predict runs in one jit."""
 import numpy as np
@@ -30,6 +31,15 @@ mse0 = float(np.mean(y ** 2))
 mse = float(np.mean((preds - y) ** 2))
 print(f"mse: {mse0:.4f} -> {mse:.4f} after {len(trees)} trees")
 assert mse < mse0
+
+# a table that arrives in pieces (a CSV read a block of rows at a time):
+# any iterable of (X [m, F] float32 with NaN for empty cells, y [m]) and
+# the total. The floats cross to the mesh once, the quantile sketch and
+# the binning run there; the trees do not depend on where the chunks
+# were cut (train_raw is this front end over row slices of one array).
+reader = ((X[s:s + 3_000], y[s:s + 3_000]) for s in range(0, N, 3_000))
+chunked_trees, chunked_preds = GBDTTrainer(cfg).train_raw_chunks(reader, N)
+assert np.array_equal(chunked_preds, train_preds)
 
 # the manual wiring underneath: the sketch/merge pair is what
 # fit_distributed runs per rank on a multi-host job (edges are the

@@ -1148,3 +1148,89 @@ def test_every_table_gather_is_the_wide_form(request, which):
         cycles = [int(n) for n in re.findall(r'"estimated_cycles":"(\d+)"',
                                              text)]
         assert cycles and max(cycles) < 10 ** 7, max(cycles)
+
+
+# The raw front end's programs at the size of
+# benchmark/configs/gbdt-bosch-968-raw (ISSUE 44): the staged floats rest
+# as the bins do, [F, N] in (8, 128) tiles; a reader's chunk of 65,536
+# rows crosses in two pieces of 32,768 and the placer puts a piece into
+# the donated table; the sketch sorts eight columns at a time as they
+# rest and holds little beside the table; the transform reads the floats
+# and writes the bins in one pass, in the layout the step's parameter(0)
+# has (``test_wide_step_reads_the_table_as_it_rests``).
+RAW_CHUNK_ROWS = 65_536                 # the configuration's chunk_rows
+RAW_PIECE_ROWS = RAW_CHUNK_ROWS // 2    # under _EACH_CHUNK_BYTES
+
+
+@pytest.fixture(scope="module")
+def raw_programs(topo_devices):
+    from jax.sharding import SingleDeviceSharding
+
+    from ytk_mp4j_tpu.models import binning
+
+    mesh = Mesh(np.asarray(topo_devices[:1]), ("mp4j",))
+    rows, whole = NamedSharding(mesh, P("mp4j")), NamedSharding(mesh, P())
+    one = SingleDeviceSharding(topo_devices[0])
+    table = jax.ShapeDtypeStruct((1, WIDE_ROWS, WIDE_F), jnp.float32,
+                                 sharding=rows)
+    trainer = GBDTTrainer(GBDTConfig(n_features=WIDE_F, n_bins=B,
+                                     missing_bin=True), mesh=mesh)
+    assert (-(-RAW_CHUNK_ROWS * WIDE_F * 4 // trainer._EACH_CHUNK_BYTES)
+            == RAW_CHUNK_ROWS // RAW_PIECE_ROWS)
+    wire = (RAW_PIECE_ROWS * WIDE_F // 128, 128)
+    return {
+        "placer": trainer._row_chunk_placer(
+            WIDE_ROWS, WIDE_F, RAW_PIECE_ROWS, wire).lower(
+                jax.ShapeDtypeStruct((1, WIDE_ROWS, WIDE_F), jnp.float32,
+                                     sharding=one),
+                jax.ShapeDtypeStruct(wire, jnp.float32, sharding=one),
+                jax.ShapeDtypeStruct((), jnp.int32, sharding=one)).compile(),
+        "transform": binning._transform_program(True, rows).lower(
+            table, jax.ShapeDtypeStruct((WIDE_F, B - 2), jnp.float32,
+                                        sharding=whole)).compile(),
+        "sketch": binning._sketch_program(B - 1, rows).lower(
+            table, jax.ShapeDtypeStruct((WIDE_ROWS,), jnp.bool_,
+                                        sharding=whole)).compile()}
+
+
+def test_raw_placer_puts_a_piece_into_the_table_it_was_given(raw_programs):
+    compiled = raw_programs["placer"]
+    mem = compiled.memory_analysis()
+    # the table is donated and updated where it rests: never held twice
+    assert mem.alias_size_in_bytes >= WIDE_TABLE_BYTES
+    # beside it the piece, as it crossed and in the table's tiles
+    assert mem.temp_size_in_bytes < 3 * RAW_PIECE_ROWS * WIDE_F * 4
+    assert re.search(r"f32\[1,%d,%d\]\{1,2,0:T\(8,128\)\} parameter\(0\)"
+                     % (WIDE_ROWS, WIDE_F), compiled.as_text())
+
+
+def test_transform_writes_the_bins_where_the_step_reads_them(raw_programs):
+    compiled = raw_programs["transform"]
+    text = compiled.as_text()
+    resting = r"\[1,%d,%d\]\{1,2,0:T\(8,128\)\}" % (WIDE_ROWS, WIDE_F)
+    assert re.search(r"f32%s parameter\(0\)" % resting, text)
+    # one pass: the bins are the root of a fusion that reads the floats,
+    # and the program's scope is on it
+    assert re.search(r"ROOT \S+ = s32%s fusion\(" % resting, text)
+    assert "bin.transform" in text
+    assert not re.search(
+        r"= \w+\[[\d,]*\d{6,},[\d,]*\d{3,}[\d,]*\]\S* "
+        r"(copy|pad|transpose|while)\(", text)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < WIDE_TABLE_BYTES // 1000
+    assert mem.output_size_in_bytes < WIDE_TABLE_BYTES * 1.001
+
+
+def test_sketch_holds_a_block_of_columns_beside_the_table(raw_programs):
+    compiled = raw_programs["sketch"]
+    text = compiled.as_text()
+    # one sort, in the loop over column blocks, of every row of eight
+    # columns, as the table rests: the rows along the lanes
+    (sort,) = re.findall(r"= f32\[(\d+),(\d+)\]\{0,1:\S* sort\(", text)
+    assert sort == (str(WIDE_ROWS), "8")
+    for scope in ("bin.sketch.gather", "bin.sketch.sort", "bin.sketch.edges"):
+        assert re.search(r"/while/body/(\w+/)?%s/" % re.escape(scope),
+                         text), scope
+    # the blocks in flight and the mask: 0.61 GB when this was written
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < WIDE_TABLE_BYTES // 4

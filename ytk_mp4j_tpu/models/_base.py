@@ -469,6 +469,126 @@ class DataParallelTrainer:
                     jax.block_until_ready(placed.pop(0))
         return table
 
+    def _put_row_chunks(self, chunks, n_rows: int, width: int):
+        """Rows that arrive a chunk at a time, each ``[m, width]`` f32 in
+        the order of the table, onto the mesh as ``[n_shards, rows a
+        shard, width]`` f32, rows sharded as ``_pad_rows`` and
+        ``_put_sharded`` shard them (row r is row ``r % per`` of shard
+        ``r // per``): what ``_put_in_row_chunks`` does with an array it
+        holds whole, for a caller (a file's reader) who never holds one.
+        The table is allocated once from ``n_rows``, every cell NaN, so
+        the rows that pad the last shard are empty; a chunk crosses as
+        it arrives, as ``[M, 128]`` words where its cells fill them (the
+        host tiles nothing), and a donated ``dynamic_update_slice``
+        places it, so the table is never held twice. A chunk goes to the
+        device that holds its rows, cut where it spans two shards and
+        into pieces of ``_EACH_CHUNK_BYTES`` at most, two crossing at a
+        time and up to ``_CHUNKS_AHEAD`` waiting to be placed (the pace
+        ``_put_in_row_chunks`` keeps under ``each``; the ledger's notes
+        of PR 43 have 4.58 GB of floats at 0.339-0.341 s so, against
+        0.42-0.48 s three at a time). One placer a (shard, piece) shape,
+        kept with the trainer.
+
+        A chunk of another width, or chunks that do not add up to
+        ``n_rows``, raise: nothing is padded in silence. The iterator's
+        arrays must stay as they are until the table is returned (a
+        reader that refills one buffer has to yield copies).
+
+        The spans are ``_put_sharded``'s: one ``mp4j.put_sharded``
+        (``bytes``: the table's, known from ``n_rows``) round the loop;
+        under it ``mp4j.stream.next`` (the caller's iterator),
+        ``mp4j.stage.send`` / ``place`` / ``link_wait`` /
+        ``device_wait`` a piece."""
+        from ytk_mp4j_tpu.exceptions import Mp4jError
+
+        n = self.n_shards
+        per = max(1, -(-n_rows // n))
+        sharding = self._row_sharding()
+        shape = (n, per, width)
+        # shard -> the device that holds it (this process's only)
+        where = {index[0].start or 0: device for device, index in
+                 sharding.addressable_devices_indices_map(shape).items()}
+        tables = {s: jnp.full((1, per, width), jnp.nan, jnp.float32,
+                              device=d) for s, d in where.items()}
+        piece_rows = max(1, self._EACH_CHUNK_BYTES // (4 * width))
+        placed, crossing = [], []
+        done, sent = 0, 0
+        it, end = iter(chunks), object()
+        with spans.span("mp4j.put_sharded", bytes=4 * n_rows * width):
+            for k in itertools.count():
+                with spans.span("mp4j.stream.next", chunk=k):
+                    chunk = next(it, end)
+                if chunk is end:
+                    break
+                chunk = np.ascontiguousarray(chunk, np.float32)
+                if chunk.ndim != 2 or chunk.shape[1] != width:
+                    raise Mp4jError(
+                        f"chunk {k} must be [rows, {width}], got "
+                        f"{chunk.shape}")
+                if done + len(chunk) > n_rows:
+                    raise Mp4jError(
+                        f"chunk {k} brings the rows to "
+                        f"{done + len(chunk)}, more than n_rows={n_rows}")
+                # pieces: cut at shard ends, then evenly under the cap
+                at = 0
+                while at < len(chunk):
+                    shard, start = divmod(done + at, per)
+                    m = min(len(chunk) - at, per - start)
+                    parts = -(-m // piece_rows)
+                    m = min(m, -(-m // parts))
+                    piece = chunk[at:at + m]
+                    at += m
+                    if shard not in where:      # another process's rows
+                        continue
+                    wire = ((m * width // 128, 128)
+                            if m * width % 128 == 0 else (m, width))
+                    with spans.span("mp4j.stage.send", chunk=sent,
+                                    bytes=piece.nbytes):
+                        dpiece = jax.device_put(piece.reshape(wire),
+                                                where[shard])
+                    with spans.span("mp4j.stage.place", chunk=sent):
+                        tables[shard], marker = self._row_chunk_placer(
+                            per, width, m, wire)(
+                                tables[shard], dpiece, np.int32(start))
+                    placed.append(marker)
+                    crossing.append(dpiece)
+                    sent += 1
+                    if len(crossing) >= self._CHUNKS_CROSSING:
+                        with spans.span("mp4j.stage.link_wait",
+                                        chunk=sent - len(crossing)):
+                            jax.block_until_ready(crossing.pop(0))
+                    if len(placed) > self._CHUNKS_AHEAD:
+                        with spans.span("mp4j.stage.device_wait",
+                                        chunk=sent - len(placed)):
+                            jax.block_until_ready(placed.pop(0))
+                done += len(chunk)
+            if done != n_rows:
+                raise Mp4jError(
+                    f"the chunks hold {done} rows, n_rows={n_rows} were "
+                    f"announced")
+            return jax.make_array_from_single_device_arrays(
+                shape, sharding, [tables[s] for s in sorted(tables)])
+
+    def _row_chunk_placer(self, per: int, width: int, rows: int, wire):
+        """The program that puts a piece of ``rows`` rows, crossed as
+        ``wire``, into one shard of ``_put_row_chunks``'s table at a row
+        it is told, the table donated; also returns the piece's first
+        word, which is there when the piece has been placed."""
+        key = ("rows", per, width, rows)
+        place = self._row_placers.get(key)
+        if place is None:
+            def place(table, chunk, start):
+                zero = jnp.zeros((), start.dtype)
+                return (jax.lax.dynamic_update_slice(
+                    table, chunk.reshape(1, rows, width),
+                    (zero, start, zero)), chunk.reshape(-1)[0])
+
+            with spans.span("mp4j.step.build", key="row_chunk_placer",
+                            rows=rows):
+                place = self._row_placers[key] = jax.jit(
+                    place, donate_argnums=0)
+        return place
+
     def save_params(self, path: str, params) -> None:
         """Persist a flat tuple of parameter arrays + the trainer config
         as a portable .npz (the train-then-serve flow; the GBDT trainer

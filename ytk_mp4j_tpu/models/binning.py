@@ -2,11 +2,25 @@
 
 The reference's GBDT consumer (ytk-learn) bins continuous features into
 <=256 quantile buckets before histogram building; this is that front
-end rebuilt TPU-first. Bin edges are fit from (a sample of) the data on
-the host (one pass of np.quantile per feature); the transform runs on
-device as a one-hot-free comparison count — ``bin(x) = #edges <= x`` —
-which is N*F*B VPU lane-ops, the same shape as one histogram level, and
-avoids the serial gather unit a searchsorted would use.
+end rebuilt TPU-first. Bin edges are the order statistics of (a row
+sample of) the data; the transform is a one-hot-free comparison count —
+``bin(x) = #edges <= x`` — which is N*F*B VPU lane-ops, the same shape
+as one histogram level, and avoids the serial gather unit a searchsorted
+would use (``_count_edges``: the one compare-count there is).
+
+What runs where. ``fit`` takes a host array and fits on the host
+(``np.nanquantile``, a column at a time; the weighted and the
+distributed sketches too). ``fit_staged`` fits the same edges, to the
+bit, on a float table that already rests on a mesh: the sample's rows
+are picked there, its columns sorted there a block at a time, and the
+order statistics every edge lies between read there (``_sketch``); the
+host interpolates the 254 picked pairs a column as numpy does.
+``transform_staged`` bins such a table where it rests, into the int32
+table a ``GBDTTrainer`` step takes; ``transform`` takes a host array,
+sends it through the same program a chunk of rows at a time and fetches
+the bins. ``GBDTTrainer.train_raw_chunks`` / ``train_raw`` stage the
+floats once and use the staged pair, so a binned table crosses the host
+link in neither direction.
 
 Distributed fitting (``fit_distributed``): each rank sketches its own
 shard — per-feature quantile edges plus finite-value counts — and the
@@ -29,7 +43,7 @@ single-host fit, including 90%-mass-in-5-values, in
 from __future__ import annotations
 
 import warnings
-from functools import partial
+from functools import lru_cache
 from typing import NamedTuple
 
 import numpy as np
@@ -38,6 +52,7 @@ import jax
 import jax.numpy as jnp
 
 from ytk_mp4j_tpu.exceptions import Mp4jError
+from ytk_mp4j_tpu.obs import spans
 
 
 class FeatureSketch(NamedTuple):
@@ -156,8 +171,12 @@ class QuantileBinner:
 
     def fit(self, X, sample: int | None = 1_000_000, seed: int = 0,
             sample_weight=None):
-        """Fit per-feature quantile edges from (a row sample of) X.
+        """Fit per-feature quantile edges from (a row sample of) X, a
+        host array, on the host (:meth:`fit_staged` fits the same edges
+        on a table that rests on a mesh).
 
+        The rows are ``np.random.default_rng(seed).choice(N, sample,
+        replace=False)`` where X has more than ``sample``.
         Missing values (NaN) are ignored when computing quantiles; at
         transform time they land in bin 0 (the missing bucket — every
         ``x >= edge`` comparison is False). A feature with no finite
@@ -174,9 +193,8 @@ class QuantileBinner:
             raise Mp4jError(f"X must be [N, F], got {X.shape}")
         sw = (None if sample_weight is None
               else _check_weights(sample_weight, X.shape[0]))
-        if sample is not None and X.shape[0] > sample:
-            idx = np.random.default_rng(seed).choice(
-                X.shape[0], sample, replace=False)
+        idx = _sample_rows(X.shape[0], sample, seed)
+        if idx is not None:
             X = X[idx]
             if sw is not None:
                 sw = sw[idx]   # uniform row sample keeps weights unbiased
@@ -186,14 +204,10 @@ class QuantileBinner:
         # time and land inf samples in the top bins)
         evid = (np.isfinite(X) if sw is None
                 else np.isfinite(X) & (sw[:, None] > 0))
-        bad = ~evid.any(axis=0)
-        if bad.any():
-            raise Mp4jError(
-                f"features {np.flatnonzero(bad).tolist()} have no "
-                "finite values to fit quantile edges from"
-                + ("" if sw is None else " (zero-weight rows carry no "
-                   "evidence)"))
-        nb = self.n_bins - 1 if self.missing_bucket else self.n_bins
+        _refuse_unbinnable(~evid.any(axis=0),
+                           "" if sw is None else " (zero-weight rows "
+                           "carry no evidence)")
+        nb = self._quantiles()
         qs = np.arange(1, nb) / nb
         if sw is not None:
             edges = np.empty((X.shape[1], nb - 1), np.float32)
@@ -249,9 +263,8 @@ class QuantileBinner:
         else:
             counts = ((~np.isnan(X)) * sw[:, None]).sum(
                 axis=0).astype(np.float32)
-        if sample is not None and X.shape[0] > sample:
-            idx = np.random.default_rng(seed).choice(
-                X.shape[0], sample, replace=False)
+        idx = _sample_rows(X.shape[0], sample, seed)
+        if idx is not None:
             X = X[idx]
             if sw is not None:
                 sw = sw[idx]
@@ -264,7 +277,7 @@ class QuantileBinner:
         finite = np.isfinite(X).any(axis=0).astype(np.float32)
         counts = np.where((~np.isnan(X)).any(axis=0), counts,
                           np.float32(0.0))
-        nb = self.n_bins - 1 if self.missing_bucket else self.n_bins
+        nb = self._quantiles()
         qs = np.arange(1, nb) / nb
         with warnings.catch_warnings():
             warnings.simplefilter("ignore", RuntimeWarning)
@@ -320,7 +333,7 @@ class QuantileBinner:
         fit at every grid quantile; tied runs are widened to their true
         weighted jump, like the unweighted path."""
         F = X.shape[1]
-        nb = self.n_bins - 1 if self.missing_bucket else self.n_bins
+        nb = self._quantiles()
         E = nb + 1
         qs = np.arange(1, nb) / nb
         grid = np.arange(E) / nb
@@ -397,7 +410,7 @@ class QuantileBinner:
         sketch_stack = np.asarray(sketch_stack, np.float32)
         counts_stack = np.asarray(counts_stack, np.float32)
         R, F, E = sketch_stack.shape
-        nb = self.n_bins - 1 if self.missing_bucket else self.n_bins
+        nb = self._quantiles()
         if E != nb + 1:
             raise Mp4jError(
                 f"sketch has {E} points per feature; this binner needs "
@@ -524,47 +537,285 @@ class QuantileBinner:
             rows[:, o3:o4],
             cdf_stack=rows[:, o1:o2].reshape(n, F, E))
 
+    def _quantiles(self) -> int:
+        """Q: the edges are the i/Q quantiles, i = 1 .. Q - 1."""
+        return self.n_bins - 1 if self.missing_bucket else self.n_bins
+
+    def fit_staged(self, table, n_rows: int,
+                   sample: int | None = 1_000_000, seed: int = 0):
+        """:meth:`fit` (unweighted) of a float table that already rests
+        on a mesh: ``table`` [n_shards, rows a shard, F] f32, rows
+        sharded, NaN where a cell is empty, of which the first
+        ``n_rows`` (in shard order) are the data and the rest padding.
+        The rows are the ones ``fit`` samples and the edges are the f32
+        numbers ``fit`` gives for the host array, to the bit.
+
+        The device does the sorting (``_sketch``: the sample's rows
+        picked where they rest, every column sorted with NaN last, the
+        order statistics round every quantile read out); the host waits
+        for those picks (``mp4j.bin.device_wait``), [F, Q - 1] triples,
+        and interpolates them as numpy does (``_edges_of``)."""
+        if table.ndim != 3 or table.dtype != np.float32:
+            raise Mp4jError(f"table must be f32 [n_shards, rows, F], got "
+                            f"{table.dtype} {table.shape}")
+        rows = table.shape[0] * table.shape[1]
+        idx = _sample_rows(n_rows, sample, seed)
+        with spans.span("mp4j.bin.fit", columns=table.shape[2],
+                        sample_rows=n_rows if idx is None else len(idx),
+                        blocks=-(-table.shape[2] // min(
+                            table.shape[2], _SKETCH_COLUMNS))):
+            keep = np.zeros(rows, bool)
+            keep[slice(n_rows) if idx is None else idx] = True
+            found = _sketch_program(self._quantiles(), table.sharding)(
+                table, keep)
+            with spans.span("mp4j.bin.device_wait"):
+                picks, n, finite = jax.device_get(found)
+            _refuse_unbinnable(~finite)
+            self.edges = _edges_of(picks, n, self._quantiles())
+        return self
+
+    # Bytes of a host table that cross at a time in ``transform``: the
+    # compare-count holds nothing of [rows, F, edges], so a chunk is
+    # sized by the link and not by the number of edges (the guard it
+    # replaces cut 968 columns into chunks of 272 rows).
+    _TRANSFORM_CHUNK_BYTES = 64 << 20
+
     def transform(self, X) -> np.ndarray:
-        """Continuous [N, F] -> int32 bin ids in [0, n_bins).
+        """Continuous [N, F] -> int32 bin ids in [0, n_bins), host array
+        in and host array out (``predict_raw``, and a caller's own use;
+        :meth:`transform_staged` bins a table that rests on a mesh and
+        leaves the bins there).
 
         NaN inputs land in bin 0 (the missing bucket; see fit) — this
         deliberately diverges from ``np.searchsorted``, which sorts NaN
         after every edge. Under ``missing_bucket`` finite values land
-        in [1, n_bins) and bin 0 is EXACTLY the NaN set."""
+        in [1, n_bins) and bin 0 is EXACTLY the NaN set.
+
+        The rows go through the device's compare-count
+        (``_count_edges``, the program ``transform_staged`` runs) in
+        chunks of ``_TRANSFORM_CHUNK_BYTES``, one dispatch where the
+        table is smaller; a chunk's bins are fetched while the next
+        chunk is binned."""
+        X = np.asarray(X, np.float32)
+        self._check_width(X.shape, X.ndim == 2)
+        program = _transform_program(bool(self.missing_bucket))
+        edges = jnp.asarray(self.edges)
+        rows = max(1, self._TRANSFORM_CHUNK_BYTES // (4 * X.shape[1]))
+        if X.shape[0] <= rows:
+            return np.asarray(program(jnp.asarray(X), edges))
+        out = np.empty(X.shape, np.int32)
+        binned = None
+        for s in range(0, X.shape[0], rows):
+            # the last chunk is as long as the others (one program): it
+            # starts early and bins rows again
+            s = min(s, X.shape[0] - rows)
+            launched = s, program(jnp.asarray(X[s:s + rows]), edges)
+            if binned is not None:
+                out[binned[0]:binned[0] + rows] = binned[1]
+            binned = launched
+        out[binned[0]:binned[0] + rows] = binned[1]
+        return out
+
+    def transform_staged(self, table):
+        """:meth:`transform` of a float table that rests on a device or
+        a mesh ([N, F], or [n_shards, rows a shard, F] with its rows
+        sharded) into int32 bins that rest there likewise: same shape,
+        same sharding, and the layout a placed host array has, which is
+        what ``GBDTTrainer``'s step takes from ``shard_bins``. One
+        program over the whole table, launched and not waited for;
+        nothing visits the host. The span says how many compares a cell
+        the program issues (``compares``: one an edge)."""
+        self._check_width(table.shape, table.ndim in (2, 3))
+        with spans.span("mp4j.bin.transform", columns=table.shape[-1],
+                        rows=int(np.prod(table.shape[:-1])),
+                        compares=self.edges.shape[1]):
+            return _transform_program(
+                bool(self.missing_bucket),
+                table.sharding if table.ndim == 3 else None)(
+                    table, self.edges)
+
+    def _check_width(self, shape, ranked: bool) -> None:
         if self.edges is None:
             raise Mp4jError("binner is not fitted")
-        X = np.asarray(X, np.float32)
-        if X.ndim != 2 or X.shape[1] != self.edges.shape[0]:
+        if not ranked or shape[-1] != self.edges.shape[0]:
             raise Mp4jError(
-                f"X must be [N, {self.edges.shape[0]}], got {X.shape}")
-        # The compare-count broadcasts to an [rows, F, B-1] intermediate
-        # before the reduction; if the backend fails to fuse it (seen on
-        # CPU), a Higgs-scale transform would transiently need ~7 GB.
-        # Chunk rows so the worst-case intermediate stays ~256 MB.
-        fb = self.edges.shape[0] * max(1, self.edges.shape[1])
-        chunk = max(1, (64 << 20) // fb)
-        edges_d = jnp.asarray(self.edges)
-        run = partial(_transform_device, shift=self.missing_bucket)
-        if X.shape[0] <= chunk:
-            return np.asarray(run(jnp.asarray(X), edges_d))
-        out = np.empty(X.shape, np.int32)
-        for s in range(0, X.shape[0], chunk):
-            e = min(s + chunk, X.shape[0])
-            out[s:e] = np.asarray(run(jnp.asarray(X[s:e]), edges_d))
-        return out
+                f"X must be [N, {self.edges.shape[0]}], got {shape}")
 
     def fit_transform(self, X, **kw) -> np.ndarray:
         return self.fit(X, **kw).transform(X)
 
 
-@partial(jax.jit, static_argnames=("shift",))
-def _transform_device(X, edges, shift: bool = False):
-    # bin = #edges <= x; comparison count instead of searchsorted keeps
-    # the op off the serial gather unit (see module docstring). With
-    # ``shift`` (the reserved missing bucket), finite values move up to
-    # [1, B) and NaN — for which every comparison is False — stays the
-    # SOLE occupant of bin 0.
-    b = (X[:, :, None] >= edges[None, :, :]).sum(-1, dtype=jnp.int32)
-    if shift:
-        b = jnp.where(jnp.isnan(X), 0, b + 1)
-    return b
+def _sample_rows(n_rows: int, sample: int | None, seed: int):
+    """The rows a fit looks at: ``sample`` of them drawn without
+    replacement from ``default_rng(seed)``, or None for all of them."""
+    if sample is None or n_rows <= sample:
+        return None
+    return np.random.default_rng(seed).choice(n_rows, sample, replace=False)
+
+
+def _refuse_unbinnable(bad: np.ndarray, why: str = "") -> None:
+    """A feature must have at least one finite value (of positive
+    weight, when weighted); inf sentinels beside it are fine (they
+    produce inf edges, which compare like any other value at transform
+    time and land inf samples in the top bins)."""
+    if bad.any():
+        raise Mp4jError(
+            f"features {np.flatnonzero(bad).tolist()} have no "
+            f"finite values to fit quantile edges from{why}")
+
+
+def _whole(sharding):
+    """The sharding that holds an array whole on every device of
+    ``sharding``'s mesh."""
+    return jax.sharding.NamedSharding(sharding.mesh,
+                                      jax.sharding.PartitionSpec())
+
+
+# Columns whose sample is sorted at a time. A staged table rests with
+# its rows along the lanes and eight columns to a tile's sublanes, and a
+# block of eight is sorted as it rests; a wider block is first copied so
+# that its columns lie along the lanes. The sorts of 1,183,747 rows x
+# 968 columns, in s (ledger notes of PR 43, whose chip runs these were):
+# 2.287 at 128 columns a block, 1.822 at 64, 1.781 at 32, 1.773 at 16,
+# 1.449 at 8. A block and its sorted copy are all the device holds
+# beside the table (76 MB at that size).
+_SKETCH_COLUMNS = 8
+# Edges compared in one unrolled chain; more (n_bins above 257) take a
+# loop of such chains, each a pass over the table.
+_EDGE_CHAIN = 256
+
+
+def _sketch(sample, nb: int, keep):
+    """The order statistics the edges of ``sample`` [S, F] f32 lie
+    between. A row that ``keep`` [S] does not mark is a row of NaN to
+    the sketch (cheaper than taking the marked rows out of a table that
+    rests with its rows along the lanes); NaN sorts last, so a column's
+    n values are its first n order statistics. Returns ``picks`` [3, F,
+    nb - 1] f32, ``n`` [F] (the column's values that are not NaN) and
+    ``finite`` [F] bool (whether it holds a finite value).
+
+    The i/nb quantile, i = 1 .. nb - 1, sits at ``i (n - 1) / nb = k +
+    r / nb`` among the sorted values; k is found by integer arithmetic
+    with ``n - 1 = nb a + b`` (f32 cannot hold the position at a million
+    rows, and ``i (n - 1)`` passes 2**31 at nine million). numpy floors
+    the float64 product ``(n - 1) * (i / nb)``, which is k or, where the
+    product should be whole and comes out a hair under, k - 1; so the
+    picks are the values at k - 1, k and k + 1 (clipped to the column),
+    and ``_edges_of`` takes numpy's two of the three.
+
+    The columns are taken ``_SKETCH_COLUMNS`` at a time in a loop, so
+    that one block and its sorted copy are all the device holds beside
+    the table; the last block starts early where the width is no whole
+    number of blocks, and finds the same values again."""
+    rows, width = sample.shape
+    columns = min(width, _SKETCH_COLUMNS)
+    i = jnp.arange(1, nb, dtype=jnp.uint32)[:, None]
+
+    def block_values(c, found):
+        start = jnp.minimum(c * columns, width - columns)
+        with jax.named_scope("bin.sketch.gather"):
+            block = jax.lax.dynamic_slice(sample, (0, start),
+                                          (rows, columns))
+            block = jnp.where(keep[:, None], block, jnp.nan)
+            n = (~jnp.isnan(block)).sum(axis=0, dtype=jnp.uint32)
+            finite = jnp.isfinite(block).any(axis=0)
+        with jax.named_scope("bin.sketch.sort"):
+            ordered = jnp.sort(block, axis=0, stable=False)  # no index rides
+        with jax.named_scope("bin.sketch.edges"):
+            last = jnp.maximum(n, 1)[None, :] - 1
+            k = i * (last // nb) + i * (last % nb) // nb
+            at = jnp.stack([jnp.maximum(k, 1) - 1, k,
+                            jnp.minimum(k + 1, last)]).astype(jnp.int32)
+            picks = jnp.take_along_axis(ordered[None], at, axis=1)
+        update = jax.lax.dynamic_update_slice_in_dim
+        return (update(found[0], picks.transpose(0, 2, 1), start, axis=1),
+                update(found[1], n, start, axis=0),
+                update(found[2], finite, start, axis=0))
+
+    return jax.lax.fori_loop(
+        0, -(-width // columns), block_values,
+        (jnp.zeros((3, width, nb - 1), jnp.float32),
+         jnp.zeros(width, jnp.uint32), jnp.zeros(width, bool)))
+
+
+def _edges_of(picks, n, nb: int) -> np.ndarray:
+    """The edges [F, nb - 1] f32 from ``_sketch``'s picks, on the host,
+    as ``np.nanquantile(X, i / nb)`` takes them from f32 values, to the
+    bit: the position ``(n - 1) * (i / nb)`` in float64 and its floor,
+    the difference of the two neighbours in f32, the step in float64
+    from the nearer end (numpy's ``_lerp``), rounded to f32 once. 968 x
+    254 triples cost the host a millisecond; on the device the same in
+    f32 is three roundings, 2.2 ulps of the larger neighbour where the
+    two have opposite signs, and its division is not IEEE's."""
+    n = np.maximum(n.astype(np.int64), 1)[:, None]
+    i = np.arange(1, nb, dtype=np.int64)
+    at = (n - 1) * (i / nb)                     # numpy's virtual index
+    below = np.floor(at).astype(np.int64)
+    k = i * (n - 1) // nb                       # the device's
+    if not ((below == k) | (below == k - 1)).all():
+        raise Mp4jError("a quantile's position left the picked values")
+    first = np.where(below == k, 1, 0)[None]    # picks hold k-1, k, k+1
+    lo = np.take_along_axis(picks, first, axis=0)[0]
+    hi = np.take_along_axis(picks, first + 1, axis=0)[0]
+    t = at - below
+    with np.errstate(invalid="ignore"):     # inf - inf at sentinel runs
+        step = hi - lo                      # f32, as numpy subtracts
+        edges = np.where(t >= 0.5, hi - step * (1 - t), lo + step * t)
+    # quantiles straddling inf sentinels interpolate to NaN; an edge of
+    # +inf keeps the edge vector ordered and is matched only by x = +inf
+    # (x >= inf), which belongs in the top bins
+    return np.where(np.isnan(edges), np.inf, edges).astype(np.float32)
+
+
+@lru_cache(maxsize=None)
+def _sketch_program(nb: int, sharding):
+    """The jitted sketch of the rows that ``keep`` [rows] marks of a
+    staged table [n_shards, rows a shard, F] (``sharding``: the
+    table's); the picks are whole on every device of its mesh."""
+    def program(table, keep):
+        return _sketch(table.reshape((-1, table.shape[-1])), nb, keep)
+
+    with spans.span("mp4j.step.build", key="bin_sketch", quantiles=nb,
+                    columns=_SKETCH_COLUMNS):
+        return jax.jit(program, out_shardings=_whole(sharding))
+
+
+def _count_edges(X, edges, shift: bool):
+    """bin = #edges <= x, as a chain of compares against one edge of
+    every feature at a time: one elementwise pass over ``X`` [..., F]
+    on any backend (a reduction over a broadcast [rows, F, edges]
+    operand is materialised by the CPU backend), off the serial gather
+    unit a searchsorted would use. With ``shift`` (the reserved missing
+    bucket) finite values move up to [1, B) and NaN — for which every
+    comparison is False — stays the SOLE occupant of bin 0."""
+    n_edges = edges.shape[1]
+    chain = min(n_edges, _EDGE_CHAIN)
+    links = -(-n_edges // chain)
+    # an edge of NaN is below nothing: the last chain's padding
+    by_edge = jnp.pad(edges.T, ((0, links * chain - n_edges), (0, 0)),
+                      constant_values=jnp.nan).reshape(links, chain, -1)
+
+    def link(k, count):
+        for edge in by_edge[k]:
+            count = count + (X >= edge).astype(jnp.int32)
+        return count
+
+    zero = jnp.zeros(X.shape, jnp.int32)
+    count = (link(0, zero) if links == 1
+             else jax.lax.fori_loop(0, links, link, zero))
+    return jnp.where(jnp.isnan(X), 0, count + 1) if shift else count
+
+
+@lru_cache(maxsize=None)
+def _transform_program(shift: bool, sharding=None):
+    """The jitted transform, of a table on a device or (``sharding``
+    given) of a staged one, whose bins rest as the floats did."""
+    def program(X, edges):
+        with jax.named_scope("bin.transform"):
+            return _count_edges(X, edges, shift)
+
+    placed = ({} if sharding is None else
+              {"in_shardings": (sharding, _whole(sharding)),
+               "out_shardings": sharding})
+    with spans.span("mp4j.step.build", key="bin_transform", shift=shift):
+        return jax.jit(program, **placed)

@@ -886,16 +886,20 @@ class GBDTTrainer(DataParallelTrainer):
         ``sample_weight`` ([N] f32, optional — ytk-learn's instance
         weights) scales each sample's gradient/hessian contribution and
         composes with the padding zeros."""
-        N = bins.shape[0]
-        dbins = self.shard_bins(bins)
+        return (self.shard_bins(bins),
+                *self._shard_vectors(y, sample_weight))
+
+    def _shard_vectors(self, y: np.ndarray, sample_weight=None):
+        """The labels, zero margins and weights of ``shard_data``, each
+        placed beside the table's rows."""
+        N = y.shape[0]
         (y,), per, w = self._pad_rows([y])
         margins = (y.shape + (self.cfg.n_classes,)
                    if self.cfg.loss == "softmax" else y.shape)
         with spans.span("mp4j.stage.prep", bytes=4 * int(np.prod(margins))):
             w[:N] *= self._stage_weights(sample_weight, N)
             preds = np.zeros(margins, np.float32)
-        return (dbins, self._put_sharded(y, per),
-                self._put_sharded(preds, per),
+        return (self._put_sharded(y, per), self._put_sharded(preds, per),
                 self._put_sharded(w, per))
 
     def shard_bins(self, bins: np.ndarray, each=None):
@@ -928,27 +932,42 @@ class GBDTTrainer(DataParallelTrainer):
         the best round. The per-round metric history is available as
         ``self.eval_history_`` afterwards.
         """
+        def stage(job):
+            va = None
+            if eval_set is not None:
+                va_host = np.asarray(eval_set[0], np.int32)
+                self._check_bins_width(va_host, "eval_set bins")
+                va = (jnp.asarray(va_host), np.asarray(eval_set[1]))
+            return (*self.shard_data(np.asarray(bins, np.int32),
+                                     self._labels(y),
+                                     sample_weight=sample_weight), va)
+
+        return self._train(stage, n_trees, seed, early_stopping_rounds,
+                           held_out=eval_set is not None)
+
+    def _labels(self, y) -> np.ndarray:
+        if self.cfg.loss == "softmax":
+            return stage_softmax_labels(y, self.cfg.n_classes)
+        return np.asarray(y, np.float32)
+
+    def _train(self, stage, n_trees, seed, early_stopping_rounds,
+               held_out: bool):
+        """:meth:`train` from the point where the table is on the mesh,
+        wherever it came from. ``stage(job)`` runs inside the job's
+        ``mp4j.gbdt.stage`` span and returns ``(dbins, dy, dpreds, dw,
+        va)``: the four arrays ``shard_data`` places, and the held-out
+        ``(bins on a device, labels)`` or None (``held_out`` says which,
+        before anything is staged). ``train`` hands over its
+        host bins; ``train_raw_chunks`` and ``train_raw`` the bins they
+        made on the mesh from the floats they staged."""
+        if early_stopping_rounds is not None and not held_out:
+            raise Mp4jError("early_stopping_rounds requires an eval_set")
         if self._step is None:
             self._step = self._build_step()
-        if self.cfg.loss == "softmax":
-            y = stage_softmax_labels(y, self.cfg.n_classes)
-        else:
-            y = np.asarray(y, np.float32)
         job, self._jobs = self._jobs, self._jobs + 1
         with spans.span("mp4j.gbdt.stage", job=job):
-            dbins, dy, dpreds, dw = self.shard_data(
-                np.asarray(bins, np.int32), y, sample_weight=sample_weight)
-
-        if early_stopping_rounds is not None and eval_set is None:
-            raise Mp4jError("early_stopping_rounds requires an eval_set")
-        va = None
-        if eval_set is not None:
-            va_host = np.asarray(eval_set[0], np.int32)
-            self._check_bins_width(va_host, "eval_set bins")
-            va_bins = jnp.asarray(va_host)
-            va_y = np.asarray(eval_set[1])
-            va_margins = None
-            va = (va_bins, va_y)
+            dbins, dy, dpreds, dw, va = stage(job)
+        va_margins = None
         stopper = EarlyStopper(early_stopping_rounds)
         self.eval_history_ = stopper.history
 
@@ -975,41 +994,52 @@ class GBDTTrainer(DataParallelTrainer):
             return trees, preds.reshape(-1, self.cfg.n_classes)
         return trees, preds.reshape(-1)
 
-    def train_raw(self, X, y, n_trees: int | None = None, seed: int = 0,
-                  sample_weight: np.ndarray | None = None,
-                  eval_set=None, early_stopping_rounds: int | None = None,
-                  binner=None, comm=None,
-                  bin_sample: int | None = 1_000_000):
-        """The ytk-learn consumer entry point: RAW continuous features
-        [N, F] -> internal quantile binning -> boosted training, in one
-        call (the reference consumer bins internally; SURVEY.md
-        section 1 flagship consumer + section 3b).
+    def train_raw_chunks(self, chunks, n_rows: int,
+                         n_trees: int | None = None, seed: int = 0,
+                         binner=None, bin_sample: int | None = 1_000_000):
+        """Boosted training straight from a table that arrives in
+        pieces, floats and gaps as they are: ``chunks`` is any iterable
+        of ``(X [m, n_features] float32 with NaN where a cell is empty,
+        y [m])`` in the table's order, ``n_rows`` their total (a reader
+        knows it from its index or a line count). Nobody holds the table
+        as one array: ytk-learn's GBDT reads its training file line by
+        line, a CSV is read a block of rows at a time.
 
-        A :class:`~ytk_mp4j_tpu.models.binning.QuantileBinner` with
+        The job, in order. (a) Each chunk crosses the host link as it
+        arrives and is placed into a float table ``[n_shards, rows a
+        shard, n_features]`` allocated once from ``n_rows``
+        (``_put_row_chunks``: span ``mp4j.gbdt.raw.stage``). (b) A
+        :class:`~ytk_mp4j_tpu.models.binning.QuantileBinner` with
         ``n_bins=cfg.n_bins`` and ``missing_bucket=cfg.missing_bin`` is
-        fitted on X — via ``fit_distributed`` over ``comm`` when one is
-        given (an mp4j comm with ``slave_num > 1``: every rank calls
-        ``train_raw`` together, each sketches its OWN X and one
-        allgather merges, so raw features never leave their rank) —
-        then X is transformed and :meth:`train` runs. NaN feature
-        values flow to the missing bucket (pair with
-        ``cfg.missing_bin=True`` for learned default directions).
+        fitted there (``fit_staged``: the rows of
+        ``default_rng(seed).choice(n_rows, bin_sample, replace=False)``,
+        all of them where ``n_rows <= bin_sample``; the edges are
+        ``np.nanquantile``'s of that sample, to the bit), unless
+        ``binner`` comes fitted, whose edges are used as they are. (c)
+        The table is binned there (``transform_staged``) into the int32
+        table the step takes, and the floats are freed. (d)
+        :meth:`train`'s loop. The floats cross once and no binned cell
+        crosses in either direction; what comes back is the edges' picks
+        (968 x 254 x 3 floats at the Bosch width) and the margins.
 
-        The fitted binner is kept as ``self.binner_`` and persisted by
-        :meth:`save_model`; ``eval_set=(X_va, y_va)`` takes RAW
-        features, transformed with the same binner. Pass a pre-fitted
-        ``binner`` to reuse edges (its edges are used as-is).
-        ``sample_weight`` both weights the quantile sketch (a heavily
-        weighted region earns finer bins, ytk-learn's weighted
-        training) and scales the boosting gradients. Returns
-        ``(trees, margins)`` like :meth:`train`; serve raw features
-        with :meth:`predict_raw`."""
+        A chunk of another width, chunks that do not add up to
+        ``n_rows``, labels that do not match their chunk, or a column
+        with no finite value in the sample raise ``Mp4jError``. Returns
+        ``(trees, margins)`` like :meth:`train`; the fitted binner is
+        kept as ``self.binner_``, so :meth:`predict_raw` and
+        :meth:`save_model` work as after :meth:`train_raw`. The bins,
+        and so the trees, are a function of the table and the seed, not
+        of where the chunks were cut."""
+        return self._train_raw(chunks, n_rows, n_trees, seed,
+                               self._checked_binner(binner), bin_sample)
+
+    def _checked_binner(self, binner):
+        """``binner``, or a fresh one of the configuration's size."""
         from ytk_mp4j_tpu.models.binning import QuantileBinner
 
-        X = np.asarray(X, np.float32)
         if binner is None:
-            binner = QuantileBinner(n_bins=self.cfg.n_bins,
-                                    missing_bucket=self.cfg.missing_bin)
+            return QuantileBinner(n_bins=self.cfg.n_bins,
+                                  missing_bucket=self.cfg.missing_bin)
         # a finer binner would emit bin ids >= cfg.n_bins, which the
         # histogram one-hot silently drops from every gradient sum —
         # the same silent-misrouting class _check_bins_width guards;
@@ -1027,19 +1057,124 @@ class GBDTTrainer(DataParallelTrainer):
                 f"cfg.missing_bin={self.cfg.missing_bin}: the reserved "
                 "bin-0 conventions must match or NaN routing silently "
                 "changes")
+        return binner
+
+    def shard_raw_chunks(self, chunks, n_rows: int):
+        """Steps (a) of :meth:`train_raw_chunks` alone: the floats of
+        ``chunks`` placed on the mesh as ``[n_shards, rows a shard,
+        n_features]`` f32 (the rows that pad the last shard are NaN, so
+        their bins are the zeros :meth:`shard_bins` pads with), and
+        their labels as one host array [n_rows]."""
+        labels = []
+
+        def floats():
+            for k, (X, y) in enumerate(chunks):
+                y = np.asarray(y)
+                if y.shape[:1] != np.shape(X)[:1]:
+                    raise Mp4jError(
+                        f"chunk {k} has {np.shape(X)[0]} rows and "
+                        f"{y.shape[0]} labels")
+                labels.append(y)
+                yield X
+
+        table = self._put_row_chunks(floats(), n_rows, self.cfg.n_features)
+        return table, (np.concatenate(labels) if labels
+                       else np.zeros(0, np.float32))
+
+    def _train_raw(self, chunks, n_rows, n_trees, seed, binner, bin_sample,
+                   sample_weight=None, eval_set=None,
+                   early_stopping_rounds=None):
+        """The one raw front end (:meth:`train_raw_chunks`), and the
+        loop after it. ``binner`` either comes fitted (a caller's edges,
+        or the host sketches ``train_raw`` still runs) or is fitted on
+        the staged floats."""
+        n_rows = int(n_rows)
+
+        def stage(job):
+            n_chunks = 0
+
+            def counted():
+                nonlocal n_chunks
+                for n_chunks, chunk in enumerate(chunks, 1):
+                    yield chunk
+
+            # ``chunks`` is known when the loop ends: on the ring's
+            # record, not on the profiler's event
+            with spans.span("mp4j.gbdt.raw.stage", job=job, rows=n_rows,
+                            bytes=4 * n_rows * (self.cfg.n_features + 1)
+                            ) as staged:
+                table, y = self.shard_raw_chunks(counted(), n_rows)
+                staged.args["chunks"] = n_chunks
+            if binner.edges is None:
+                binner.fit_staged(table, n_rows, sample=bin_sample,
+                                  seed=seed)
+            self.binner_ = binner
+            dbins = binner.transform_staged(table)
+            del table       # freed when the last row is binned
+            va = None
+            if eval_set is not None:
+                va_host = np.asarray(eval_set[0], np.float32)
+                self._check_bins_width(va_host, "eval_set X")
+                va = (binner.transform_staged(jnp.asarray(va_host)),
+                      np.asarray(eval_set[1]))
+            return (dbins, *self._shard_vectors(self._labels(y),
+                                                sample_weight), va)
+
+        return self._train(stage, n_trees, seed, early_stopping_rounds,
+                           held_out=eval_set is not None)
+
+    def train_raw(self, X, y, n_trees: int | None = None, seed: int = 0,
+                  sample_weight: np.ndarray | None = None,
+                  eval_set=None, early_stopping_rounds: int | None = None,
+                  binner=None, comm=None,
+                  bin_sample: int | None = 1_000_000):
+        """The ytk-learn consumer entry point for a table held as ONE
+        array: RAW continuous features [N, F] -> internal quantile
+        binning -> boosted training, in one call (the reference
+        consumer bins internally; SURVEY.md section 1 flagship consumer
+        + section 3b). It is :meth:`train_raw_chunks` over row slices
+        of ``X`` (256 MiB each): the floats cross to the mesh once, the
+        sketch and the transform run there, and the binned table never
+        visits the host.
+
+        A :class:`~ytk_mp4j_tpu.models.binning.QuantileBinner` with
+        ``n_bins=cfg.n_bins`` and ``missing_bucket=cfg.missing_bin`` is
+        fitted on X: on the mesh (``fit_staged``) in the plain case;
+        on the host, as before, where the fit is weighted
+        (``sample_weight``) or distributed — via ``fit_distributed``
+        over ``comm`` when one is given (an mp4j comm with ``slave_num
+        > 1``: every rank calls ``train_raw`` together, each sketches
+        its OWN X and one allgather merges, so raw features never leave
+        their rank). Everything after the edges is the device path
+        either way. NaN feature values flow to the missing bucket (pair
+        with ``cfg.missing_bin=True`` for learned default directions).
+
+        The fitted binner is kept as ``self.binner_`` and persisted by
+        :meth:`save_model`; ``eval_set=(X_va, y_va)`` takes RAW
+        features, binned on the device by the same transform. Pass a
+        pre-fitted ``binner`` to reuse edges (its edges are used
+        as-is). ``sample_weight`` both weights the quantile sketch (a
+        heavily weighted region earns finer bins, ytk-learn's weighted
+        training) and scales the boosting gradients. Returns ``(trees,
+        margins)`` like :meth:`train`; serve raw features with
+        :meth:`predict_raw`."""
+        X = np.asarray(X, np.float32)
+        self._check_bins_width(X, "X")
+        y = np.asarray(y)
+        binner = self._checked_binner(binner)
         if binner.edges is None:
             if comm is not None and comm.slave_num > 1:
                 binner.fit_distributed(X, comm, sample=bin_sample,
                                        seed=seed,
                                        sample_weight=sample_weight)
-            else:
+            elif sample_weight is not None:
                 binner.fit(X, sample=bin_sample, seed=seed,
                            sample_weight=sample_weight)
-        self.binner_ = binner
-        if eval_set is not None:
-            eval_set = (binner.transform(eval_set[0]), eval_set[1])
-        return self.train(
-            binner.transform(X), y, n_trees=n_trees, seed=seed,
+        rows = max(1, self._CHUNK_BYTES // (4 * X.shape[1]))
+        slices = ((X[s:s + rows], y[s:s + rows])
+                  for s in range(0, X.shape[0], rows))
+        return self._train_raw(
+            slices, X.shape[0], n_trees, seed, binner, bin_sample,
             sample_weight=sample_weight, eval_set=eval_set,
             early_stopping_rounds=early_stopping_rounds)
 
