@@ -503,6 +503,107 @@ def _train_one(bins, y, cfg):
     return np.asarray(new_preds), [np.asarray(t) for t in tree]
 
 
+# (F, n_nodes) -> whether the level routes on sliced columns: both sides
+# of ``route_sliced`` at rows of N lanes (F = 5, 28, 70: a slice reads
+# its column) and at (8, 128) tiles (F = 40, 520: its tile row's eight)
+_ROUTE_CASES = {
+    (5, 1): True, (5, 4): True, (5, 32): False,
+    (28, 1): True, (28, 4): True, (28, 16): True, (28, 32): False,
+    (40, 1): True, (40, 4): True, (40, 32): False,
+    (70, 1): True, (70, 4): True, (70, 32): True,
+    (520, 1): True, (520, 4): True, (520, 32): True,
+}
+
+
+@pytest.mark.parametrize("rules", ["numeric", "missing+categorical"])
+@pytest.mark.parametrize("F,n_nodes", list(_ROUTE_CASES))
+def test_route_samples_matches_the_plain_statement(F, n_nodes, rules):
+    """``bins[i, feat[node[i]]]`` by ``np.take_along_axis``, then the
+    three rules, on either side of ``route_sliced``: a frozen node, two
+    nodes on one feature, a node no sample is in, a categorical column
+    and stored directions for the missing bucket."""
+    from ytk_mp4j_tpu.models.gbdt import _route_samples, route_sliced
+
+    assert route_sliced(n_nodes, F) == _ROUTE_CASES[F, n_nodes]
+    rng = np.random.default_rng(100 * F + n_nodes)
+    N, B = 333, 16
+    full = rules == "missing+categorical"
+    bins = rng.integers(0 if full else 1, B, (N, F)).astype(np.int32)
+    node = rng.integers(0, n_nodes, N).astype(np.int32)
+    feat = rng.integers(0, F, n_nodes).astype(np.int32)
+    bin_ = rng.integers(0, B - 1, n_nodes).astype(np.int32)
+    dir_ = rng.integers(0, 2, n_nodes).astype(np.int32)
+    cat = np.zeros(F, bool)
+    cat[F - 2] = True
+    if n_nodes >= 4:
+        feat[1] = feat[0]                   # two nodes on one feature
+        node[node == 2] = 3                 # node 2 holds no sample
+        feat[3], bin_[3] = F - 2, 5         # a categorical split
+        bin_[n_nodes - 1], dir_[n_nodes - 1] = B - 1, 0     # frozen
+    else:
+        feat[0] = F - 2
+    got = _route_samples(
+        jnp.asarray(bins), jnp.asarray(node), jnp.asarray(feat),
+        jnp.asarray(bin_), n_nodes, jnp.asarray(dir_),
+        cat if full else None, full, B)
+
+    f, b = feat[node], bin_[node]
+    v = np.take_along_axis(bins, f[:, None], axis=1)[:, 0]
+    right = v > b
+    if full:
+        right = np.where(v == 0, dir_[node] > 0, right)
+        right = np.where(cat[f], (v == b) & (b != B - 1), right)
+    assert got.shape == (N,) and got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), node * 2 + right)
+    if n_nodes >= 4:
+        assert (np.asarray(got)[node == n_nodes - 1]
+                == 2 * (n_nodes - 1)).all()     # frozen: all left
+
+
+def test_sliced_routing_grows_the_trees_of_the_whole_table_form(
+        rng, monkeypatch):
+    """Three trees of a fixed seed, levels 0-3 on sliced columns and
+    level 4 on the whole table: trees and margins to the bit those of
+    the whole-table form at every level (the step before PR 45), of
+    ``predict_tree`` and of ``GBDTServable``'s host router."""
+    from ytk_mp4j_tpu.models import gbdt
+
+    N, F, B = 500, 12, 16
+    cfg = GBDTConfig(n_features=F, n_bins=B, depth=5, loss="logistic",
+                     missing_bin=True, categorical_features=(3,),
+                     learning_rate=0.3)
+    assert [gbdt.route_sliced(2 ** d, F) for d in range(5)] \
+        == [True, True, True, True, False]
+    bins = rng.integers(1, B, (N, F)).astype(np.int32)
+    bins[rng.random((N, F)) < 0.3] = 0
+    y = ((bins[:, 3] == 4) ^ (bins[:, 7] > 9) ^ (bins[:, 0] == 0))
+    y = y.astype(np.float32)
+
+    def train():
+        tr = GBDTTrainer(cfg, mesh=make_mesh(1))
+        trees, margins = tr.train(bins, y, n_trees=3, seed=7)
+        return tr, [tuple(np.asarray(a) for a in t) for t in trees], \
+            np.asarray(margins)[:N]
+
+    tr, trees, margins = train()
+    with monkeypatch.context() as m:
+        m.setattr(gbdt, "route_sliced", lambda n_nodes, F: False)
+        _, whole_trees, whole_margins = train()
+    for tree, whole in zip(trees, whole_trees):
+        for a, b in zip(tree, whole):
+            np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(margins, whole_margins)
+    assert len({tuple(t[0]) for t in trees}) == 3   # three different trees
+
+    serve = gbdt.servable(trees, cfg)
+    replayed = None
+    for tree in trees:
+        leaves = np.asarray(predict_tree(jnp.asarray(bins), tree, cfg))
+        np.testing.assert_array_equal(leaves, serve._route(bins, tree))
+        replayed = tr._update_margins(jnp.asarray(bins), tree, replayed)
+    np.testing.assert_array_equal(np.asarray(replayed), margins)
+
+
 @pytest.mark.parametrize("missing_bin", [False, True])
 def test_missing_direction_matches_oracle(rng, missing_bin):
     N, F, B = 512, 4, 8

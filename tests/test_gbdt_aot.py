@@ -170,6 +170,135 @@ def test_wide_step_temporaries_far_below_the_table(wide_step):
     assert temp < WIDE_TABLE_BYTES // 16, temp / WIDE_TABLE_BYTES
 
 
+def _computations(text):
+    """name -> lines of each computation of a compiled module's text."""
+    found, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%(\S+) \(.*\) -> .* \{$", line)
+        if m:
+            name = "ENTRY" if line.startswith("ENTRY") else m.group(1)
+            found[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            found[name].append(line)
+    return found
+
+
+def _route_table_readers(text, table):
+    """What reads the table under ``gbdt.route``: (slices, whole,
+    others). ``slices``: the ``dynamic-slice``s of the table inside the
+    scope's fusions; ``whole``: the fusions of the scope that use the
+    table in any other way (a level that selects among all F columns);
+    ``others``: instructions of the scope that take the table and are
+    no fusion. ``table``: the parameter's name in the entry
+    computation; a copy of it that XLA prefetches into the fast memory
+    (``copy-start`` / ``copy-done``: the 112 MB of 1M x 28) is the
+    table too."""
+    comps = _computations(text)
+    tables = {table}
+    slices, whole, others = 0, [], []
+    for line in comps["ENTRY"]:
+        head = line.split(", metadata=")[0]
+        taken = [t for t in tables if re.search(re.escape(t) + r"[,)]", head)]
+        if not taken:
+            continue
+        m = re.match(r"\s*(%\S+) = .* copy-(?:start|done)\(", head)
+        if m:
+            tables.add(m.group(1))
+            continue
+        m = re.search(r" fusion\((.*?)\), kind=\w+, calls=%([\w.-]+)", head)
+        if m is None:
+            if "gbdt.route" in line:
+                others.append(line.strip()[:160])
+            continue
+        body = comps[m.group(2)]
+        if "gbdt.route" not in line + "".join(body):
+            continue
+        operands = re.sub(r"/\*.*?\*/", "", m.group(1)).split(", ")
+        for k in (k for k, o in enumerate(operands) if o in taken):
+            param = next(re.match(r"\s*(%\S+) = ", b).group(1) for b in body
+                         if re.search(r" parameter\(%d\)" % k, b))
+            inside = [b for b in body
+                      if re.search(re.escape(param) + r"[,)]", b)]
+            sliced = [b for b in inside if re.search(
+                r" dynamic-slice\(%s," % re.escape(param), b)]
+            slices += len(sliced)
+            if len(sliced) < len(inside):
+                whole.append(line.strip()[:160])
+    return slices, whole, others
+
+
+def test_route_table_readers_detector():
+    """Two sliced levels (one on a prefetched copy of the table), a
+    whole-table level and a table-sized gather, as lines of compiled
+    text."""
+    text = """
+%fused_computation.1 (param_0.1: s32[1,1000000,28], param_1.2: s32[], param_2.3: s32[1000000,1]) -> s32[1000000,1] {
+  %param_0.1 = s32[1,1000000,28]{1,0,2:T(1,128)} parameter(0)
+  %param_1.2 = s32[]{:T(128)S(6)} parameter(1)
+  %constant.1 = s32[]{:T(128)} constant(0)
+  %dynamic-slice.7 = s32[1,1000000,1]{1,0,2:T(1,128)} dynamic-slice(%param_0.1, %constant.1, %constant.1, %param_1.2), dynamic_slice_sizes={1,1000000,1}, metadata={op_name="jit(step)/gbdt.route/dynamic_slice"}
+  %param_2.3 = s32[1000000,1]{0,1:T(1,128)} parameter(2)
+  ROOT %select.1 = s32[1000000,1]{0,1:T(1,128)} select(%param_2.3, %dynamic-slice.7, %param_2.3)
+}
+
+%fused_computation.2 (param_0.4: s32[1000000], param_1.5: s32[1,1000000,28]) -> s32[1000000] {
+  %param_1.5 = s32[1,1000000,28]{1,0,2:T(1,128)} parameter(1)
+  %param_0.4 = s32[1000000]{0:T(1024)} parameter(0)
+  ROOT %reduce_sum.1 = s32[1000000]{0:T(1024)} reduce(%param_1.5, %param_0.4), dimensions={1}, metadata={op_name="jit(step)/gbdt.route/reduce_sum"}
+}
+
+ENTRY %main.1 (bins.1: s32[1,1000000,28], ids.1: s32[1000000]) -> s32[1000000] {
+  %bins.1 = s32[1,1000000,28]{1,0,2:T(1,128)} parameter(0)
+  %ids.1 = s32[1000000]{0:T(1024)} parameter(1)
+  %bitcast.1 = s32[28,1,1000000]{2,1,0:T(1,128)} bitcast(%bins.1), metadata={op_name="jit(step)/gbdt.hist/transpose"}
+  %fusion.1 = s32[1000000,1]{0,1:T(1,128)} fusion(%bins.1, %c.1, /*index=2*/%col.1), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(step)/gbdt.route/select_n"}
+  %copy-start = (s32[1,1000000,28]{1,0,2:T(1,128)S(1)}, s32[1,1000000,28]{1,0,2:T(1,128)}, u32[]{:S(2)}) copy-start(%bins.1)
+  %copy-done = s32[1,1000000,28]{1,0,2:T(1,128)S(1)} copy-done(%copy-start)
+  %fusion.3 = s32[1000000,1]{0,1:T(1,128)} fusion(%copy-done, %c.2, %col.2), kind=kLoop, calls=%fused_computation.1
+  %fusion.2 = s32[1000000]{0:T(1024)} fusion(%ids.1, %copy-done), kind=kLoop, calls=%fused_computation.2
+  %gather.1 = s32[1000000,4]{1,0} gather(%bins.1, %ids.1), metadata={op_name="jit(step)/gbdt.route/gather"}
+}
+"""
+    slices, whole, others = _route_table_readers(text, "%bins.1")
+    assert slices == 2
+    assert len(whole) == 1 and whole[0].startswith("%fusion.2")
+    assert len(others) == 1 and others[0].startswith("%gather.1")
+
+
+@pytest.mark.parametrize("which,n_feat,sliced_levels", [
+    ("step_one_chip", F, 5),        # F rows of N lanes: Higgs
+    ("wide_step", WIDE_F, 6),       # (8, 128) tiles: Bosch
+])
+def test_routing_reads_its_levels_columns(request, which, n_feat,
+                                          sliced_levels):
+    """Under ``gbdt.route`` a sliced level (``route_sliced``: five of
+    six at F = 28, all six at F = 968) takes the table through
+    ``dynamic-slice``s of one column alone, one a node, inside the
+    fusion that selects among them: no column is written out first, no
+    gather is there, and only the levels the rule leaves whole read
+    every column."""
+    from ytk_mp4j_tpu.models.gbdt import route_sliced
+
+    text = request.getfixturevalue(which).as_text()
+    assert re.search(r"%%bins\.1 = s32\[1,\d+,%d\]\S+ parameter\(0\)" % n_feat,
+                     text)
+    levels = [route_sliced(2 ** d, n_feat) for d in range(DEPTH)]
+    assert sum(levels) == sliced_levels
+    slices, whole, others = _route_table_readers(text, "%bins.1")
+    assert slices == sum(2 ** d for d, s in enumerate(levels) if s)
+    assert len(whole) == DEPTH - sliced_levels, whole
+    assert others == []
+    route = [line for line in text.splitlines() if "gbdt.route" in line]
+    assert route and not [line for line in route if " gather(" in line]
+    # a slice of the table is one column of its rows, never more
+    sizes = {m.group(1) for m in (
+        re.search(r" dynamic-slice\(.*dynamic_slice_sizes=\{([\d,]+)\}", line)
+        for line in route) if m and "," in m.group(1)}
+    assert len(sizes) == 1 and sizes.pop().endswith(",1"), sizes
+
+
 SCORE_ROWS, SCORE_TREES = 1_183_748, 500   # configs/gbdt-bosch-score-500
 # rows of a staging chunk of this table where every chunk is scored as
 # it crosses (_put_in_row_chunks with ``each``)

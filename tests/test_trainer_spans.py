@@ -137,17 +137,25 @@ def test_gbdt_train_leaves_exactly_its_spans(rng, ring):
 
 @pytest.mark.parametrize("F,depth,hist_mode,want", [
     # hist_radix: the high digits a bin is split into, level by level
-    # (1, 1, 2 nodes at depth 3)
-    (4, 3, "pallas", {"hist_feature_block": 4, "hist_feature_blocks": 1,
+    # (1, 1, 2 nodes at depth 3); route_sliced_levels: the levels that
+    # route on sliced columns (1, 2, 4 nodes on 4 columns)
+    (4, 3, "pallas", {"route_sliced_levels": 3,
+                      "hist_feature_block": 4, "hist_feature_blocks": 1,
                       "hist_radix": "4,4,4"}),
     # the deepest level builds 2**(depth-2) left children: at 16 nodes
-    # and 256 bins a block holds at most 128 features, 968 go in 11 x 88
-    (968, 6, "pallas", {"hist_feature_block": 88,
+    # and 256 bins a block holds at most 128 features, 968 go in 11 x 88;
+    # a slice reads its tile row's eight columns, 256 of 968 at 32 nodes
+    (968, 6, "pallas", {"route_sliced_levels": 6,
+                        "hist_feature_block": 88,
                         "hist_feature_blocks": 11,
                         "hist_radix": "4,4,4,2,2,1"}),
-    (28, 8, "pallas", {"hist_feature_block": 28, "hist_feature_blocks": 1,
+    # up to 16 nodes ask for fewer columns than the table's 28
+    (28, 8, "pallas", {"route_sliced_levels": 5,
+                       "hist_feature_block": 28, "hist_feature_blocks": 1,
                        "hist_radix": "4,4,4,2,2,1,1,1"}),
-    (968, 6, "matmul", None),
+    (968, 6, "matmul", {"route_sliced_levels": 6}),
+    # a multiple of 128 rests row-major: a slice reads its lane word
+    (256, 6, "matmul", {"route_sliced_levels": 2}),
 ])
 def test_step_build_span_says_which_histogram_grid_runs(ring, F, depth,
                                                         hist_mode, want):
@@ -629,14 +637,15 @@ def _lower_score(rng):
 # both FFM steps', whose select's output columns run component by
 # component; PR 39: the SGD FFM step's, which merges its slots' gradients
 # and scatter-adds the merged list's live prefix in tiles, the AdaGrad
-# step's as it was).
+# step's as it was; PR 45: the GBDT step's, whose routing slices a level's
+# split columns out of the table where ``route_sliced`` says so).
 LOWERED = {
     "placer": (
         _lower_placer,
         "2582168225277df2d3bae310438d4541f84c320762e7cd20458db82dc974884a"),
     "gbdt": (
         _lower_gbdt,
-        "6c5ca8871ebcc87cb0732348a22485b157747531f3ed541b105915b6ebb1a88f"),
+        "c3e37de0608b1e8c44a42c1d84f131879470c6edac17d5653f29d6035e212cbd"),
     "score": (
         _lower_score,
         "65e9cddff36da71d3c0d1b039b56f4d4137a955b457d12271ce2745044751514"),

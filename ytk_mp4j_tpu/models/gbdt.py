@@ -39,7 +39,7 @@ from ytk_mp4j_tpu.models._base import (DataParallelTrainer, EarlyStopper,
                                        stage_softmax_labels)
 from ytk_mp4j_tpu.exceptions import Mp4jError
 from ytk_mp4j_tpu.obs import spans
-from ytk_mp4j_tpu.ops.hist_kernel import split_bf16
+from ytk_mp4j_tpu.ops.hist_kernel import _rests_tiled, split_bf16
 
 
 @dataclass(frozen=True)
@@ -360,36 +360,93 @@ def _onehot_segment_sum2(val_a, val_b, seg_ids, n_segments: int):
     return out[0] + out[1], out[2] + out[3]         # [n_segments] f32 x2
 
 
+def _chain_select(table, ids, n: int):
+    """``table[ids]`` per sample as a chain of ``n`` selects: ``table``
+    an [n] array or a list of n arrays of ``ids``' shape, ``ids`` of
+    any shape and in [0, n) (0 elsewhere, like ``_onehot_select``).
+    Elementwise throughout, so XLA keeps it in whatever fusion reads
+    ``table[j]``, where ``_onehot_select`` reduces an [N, n] one-hot."""
+    out = 0
+    for j in range(n):
+        out = jnp.where(ids == j, table[j], out)
+    return out
+
+
+def route_sliced(n_nodes: int, F: int) -> bool:
+    """Whether a level of ``n_nodes`` nodes routes on its split columns
+    sliced out of the [N, F] table (see ``_route_samples``): while the
+    slices read no more columns than the table has. A slice reads its
+    column where the table rests as F rows of N lanes, the eight
+    columns of its sublane tile where it rests (8, 128)-tiled
+    (``_rests_tiled``), and the 128 of its lane word where a multiple
+    of 128 rests row-major (not measured; counted from the layout)."""
+    reads = 128 if F % 128 == 0 else 8 if _rests_tiled(F) else 1
+    return n_nodes * reads <= F
+
+
 @jax.named_scope("gbdt.route")
 def _route_samples(bins, node_ids, feat, bin_, n_nodes: int, dir_=None,
                    cat_mask=None, missing_bin: bool = False,
                    n_bins: int | None = None):
-    """One level of sample routing: ``node_ids*2 + go_right`` via the
-    exact one-hot selects, where ``go_right`` is ``bins[i, feat[n]] >
-    bin_[n]`` for numeric features, ``== bin_[n]`` for categorical ones
-    (never at the freeze sentinel B-1), and the node's learned default
-    direction ``dir_`` for the missing bucket (bin 0) under
-    ``missing_bin``. The all-numeric default compiles to exactly the
-    round-1 graph. (A fused Pallas version was measured 2x SLOWER —
-    13.3 vs 7.6 ms standalone at N=1M — a kernel block of [tile, F]
-    pins F=28 on the 128-wide lane dimension at 22% occupancy, while
-    XLA is free to lay the N axis across lanes and to fuse the selects
-    into neighboring passes.)"""
-    nf = _onehot_select(feat, node_ids, n_nodes)
-    nb = _onehot_select(bin_, node_ids, n_nodes)
-    v = _onehot_row_select(bins, nf)
+    """One level of sample routing: ``node_ids*2 + go_right``, where
+    ``go_right`` is ``bins[i, feat[n]] > bin_[n]`` for numeric features,
+    ``== bin_[n]`` for categorical ones (never at the freeze sentinel
+    B-1), and the node's learned default direction ``dir_`` for the
+    missing bucket (bin 0) under ``missing_bin``; everything is selected
+    by exact selects, nothing gathered.
+
+    A level splits on at most ``n_nodes`` columns. Where those are few
+    beside the table (``route_sliced``, from the shapes alone) each is
+    taken out by a ``dynamic_slice`` and a sample's value, threshold,
+    direction and kind are chosen among the nodes' by its node id, all
+    in the slices' own [N, 1] shape, so that one fusion reads the
+    slices and nothing else of the table. The barrier before the last
+    reshape holds that shape: without it XLA hoists the reshape onto
+    every slice and writes each column out first (as it does for a
+    concatenation of the slices), and ``jnp.take(bins, feat, axis=1)``
+    lowers to gathers beside a copy of the table. Elsewhere a sample's
+    feature number is selected among all F columns and every column is
+    read, which is the cheaper read once a level needs most of them.
+    Measured (my chip runs, PR 45), the scope's sum over a tree of
+    depth 6 in the train step: 1,183,747 x 968 in (8, 128) tiles, 37.37
+    ms whole at every level against 3.29 sliced at every level (a
+    column costs its tile row); 11,000,000 x 28 as rows of N lanes,
+    13.79 ms whole against 9.38, 7.26, 5.21 and 12.93 with the first
+    three, four, five and all six levels sliced (32 nodes ask for more
+    columns than the table has, and XLA cuts a level of 32 slices into
+    several fusions with masks and broadcasts written out between
+    them). One level alone, which pays the reshape that the step's
+    sliced levels share, at 1, 2, 4, 8, 16, 32 nodes: 6.08, 6.24, 6.24,
+    6.24, 6.25, 6.27 ms whole against 0.14, 0.19, 0.30, 0.51, 0.93,
+    2.13 sliced at the first shape, 2.01, 2.51, 2.51, 2.51, 2.76, 3.07
+    against 0.61, 0.69, 1.01, 1.52, 2.77, 14.57 at the second.
+
+    (A fused Pallas version was measured 2x SLOWER — 13.3 vs 7.6 ms
+    standalone at N=1M — a kernel block of [tile, F] pins F=28 on the
+    128-wide lane dimension at 22% occupancy, while XLA is free to lay
+    the N axis across lanes and to fuse the selects into neighboring
+    passes.)"""
+    sliced = route_sliced(n_nodes, bins.shape[1])
+    if sliced:
+        ids, select = node_ids[:, None], _chain_select  # the slices' shape
+        v = select([lax.dynamic_slice_in_dim(bins, feat[j], 1, axis=1)
+                    for j in range(n_nodes)], ids, n_nodes)
+    else:
+        ids, select = node_ids, _onehot_select
+        v = _onehot_row_select(bins, select(feat, ids, n_nodes))
+    nb = select(bin_, ids, n_nodes)
     go_right = v > nb
     if missing_bin:
-        nd = _onehot_select(dir_, node_ids, n_nodes)
+        nd = select(dir_, ids, n_nodes)
         go_right = jnp.where(v == 0, nd > 0, go_right)
     if cat_mask is not None:
         # is this sample's node split on a categorical feature?
         node_cat = jnp.asarray(cat_mask)[feat]        # [n_nodes] bool
-        sc = _onehot_select(node_cat.astype(jnp.int32), node_ids,
-                            n_nodes) > 0
+        sc = select(node_cat.astype(jnp.int32), ids, n_nodes) > 0
         go_right = jnp.where(sc, (v == nb) & (nb != n_bins - 1),
                              go_right)
-    return node_ids * 2 + go_right.astype(jnp.int32)
+    out = ids * 2 + go_right.astype(jnp.int32)
+    return lax.optimization_barrier(out)[:, 0] if sliced else out
 
 
 @jax.named_scope("gbdt.best_splits")
@@ -685,12 +742,14 @@ def predict_tree(bins, tree, cfg: GBDTConfig):
 # ----------------------------------------------------------------------
 # batch scoring: all trees are known, so the order is free
 #
-# Training routes a level at a time because tree t+1 waits for tree t,
-# and every level reads the whole table (``_route_samples``). Scoring an
-# ensemble that way moves trees x depth x table through HBM: 13.75 TB for
-# 500 trees of depth 6 on 1,183,748 x 968 (40.5 s a job with the
-# transfer, my chip run, PR 30). Here the rows are taken in chunks, and
-# for a chunk the trees in groups: one MXU matmul of the chunk against
+# Training routes a level at a time because tree t+1 waits for tree t.
+# Scoring an ensemble that way, with every level reading the whole table
+# as routing did until PR 45, moves trees x depth x table through HBM:
+# 13.75 TB for 500 trees of depth 6 on 1,183,748 x 968 (40.5 s a job
+# with the transfer, my chip run, PR 30); reading a level's split
+# columns alone (``_route_samples``) it is still 3,000 passes over the
+# rows, one after the other. Here the rows are taken in chunks, and for
+# a chunk the trees in groups: one MXU matmul of the chunk against
 # the group's one-hot of split features selects the bins every node of
 # every tree of the group asks for (exact: one term of a sum is nonzero,
 # and a bin digit 0..255 is a bf16 number), every node is decided at
@@ -857,7 +916,9 @@ class GBDTTrainer(DataParallelTrainer):
                 interpret=interpret, rng_key=rng_key)
             return new_preds[None], tree
 
-        grid = {}
+        # how many of a tree's levels route on sliced columns
+        grid = {"route_sliced_levels": sum(
+            route_sliced(2 ** d, cfg.n_features) for d in range(cfg.depth))}
         if cfg.hist_mode == "pallas":
             # which grid the deepest level's kernel runs (it builds the
             # left children of the last split level), and into how many
@@ -867,10 +928,10 @@ class GBDTTrainer(DataParallelTrainer):
             levels = hist_level_nodes(cfg.depth)
             block, blocks = feature_blocks(
                 cfg.n_features, cfg.n_bins, max(levels, default=1))
-            grid = {"hist_feature_block": block,
-                    "hist_feature_blocks": blocks,
-                    "hist_radix": ",".join(
-                        str(hist_radix(n, cfg.n_bins)) for n in levels)}
+            grid.update(hist_feature_block=block,
+                        hist_feature_blocks=blocks,
+                        hist_radix=",".join(
+                            str(hist_radix(n, cfg.n_bins)) for n in levels))
         with spans.span("mp4j.step.build", **grid):
             return jax.jit(step)
 
