@@ -300,8 +300,8 @@ def test_routing_reads_its_levels_columns(request, which, n_feat,
 
 
 SCORE_ROWS, SCORE_TREES = 1_183_748, 500   # configs/gbdt-bosch-score-500
-# rows of a staging chunk of this table where every chunk is scored as
-# it crosses (_put_in_row_chunks with ``each``)
+# rows of a staging chunk of this table (_put_in_row_chunks: 128 MiB in
+# whole rows of 128 lanes), which ``predict`` scores as it crosses
 SCORE_CHUNK_ROWS = (GBDTTrainer._EACH_CHUNK_BYTES // (WIDE_F * 4)
                     // 128 * 128)
 
@@ -1331,6 +1331,35 @@ def test_raw_placer_puts_a_piece_into_the_table_it_was_given(raw_programs):
     assert mem.temp_size_in_bytes < 3 * RAW_PIECE_ROWS * WIDE_F * 4
     assert re.search(r"f32\[1,%d,%d\]\{1,2,0:T\(8,128\)\} parameter\(0\)"
                      % (WIDE_ROWS, WIDE_F), compiled.as_text())
+
+
+def test_train_placer_puts_a_piece_into_the_table_it_was_given(topo_devices):
+    """``_put_in_row_chunks``' placer at the Bosch cell's size, a 128 MiB
+    piece as ``train()`` and ``predict`` both stage it: the donated table
+    is updated where it rests, and nothing else as large as it is made."""
+    mesh = Mesh(np.asarray(topo_devices[:1]), ("mp4j",))
+    rows, whole = NamedSharding(mesh, P("mp4j")), NamedSharding(mesh, P())
+    trainer = GBDTTrainer(GBDTConfig(n_features=WIDE_F, n_bins=B,
+                                     missing_bin=True), mesh=mesh)
+    piece = SCORE_CHUNK_ROWS * WIDE_F * 4
+    assert piece <= trainer._EACH_CHUNK_BYTES < piece + 128 * WIDE_F * 4
+    compiled = trainer._build_row_placer(
+        (1, SCORE_CHUNK_ROWS, WIDE_F)).lower(
+            jax.ShapeDtypeStruct((1, WIDE_ROWS, WIDE_F), jnp.int32,
+                                 sharding=rows),
+            jax.ShapeDtypeStruct((1, piece // 512, 128), jnp.int32,
+                                 sharding=rows),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=whole)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= WIDE_TABLE_BYTES
+    # beside the table the piece, as it crossed and in the table's tiles
+    assert mem.temp_size_in_bytes < 3 * piece
+    text = compiled.as_text()
+    table = r"s32\[1,%d,%d\]\{1,2,0:T\(8,128\)\}" % (WIDE_ROWS, WIDE_F)
+    assert re.search(table + r" parameter\(0\)", text)
+    assert "input_output_alias" in text
+    made = re.findall(r"= %s (\S+?)\(" % table, text)
+    assert made == ["parameter", "dynamic-update-slice"], made
 
 
 def test_transform_writes_the_bins_where_the_step_reads_them(raw_programs):

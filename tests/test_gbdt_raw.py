@@ -137,7 +137,7 @@ def test_the_chunking_is_nothing_to_the_result(n_devices):
         assert bins.shape == (n_devices, -(-len(X) // n_devices), F)
         runs[name] = (tr.binner_.edges, bins, trees, margins)
     tr = GBDTTrainer(_cfg(), mesh=make_mesh(n_devices))
-    tr._CHUNK_BYTES = 256 * F * 4       # train_raw's slices: 256 rows
+    tr._EACH_CHUNK_BYTES = 256 * F * 4  # train_raw's slices: 256 rows
     trees, margins = tr.train_raw(X, y, seed=3, bin_sample=700)
     runs["train_raw"] = (tr.binner_.edges, runs["one_chunk"][1], trees,
                          margins)

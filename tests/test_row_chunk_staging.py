@@ -25,7 +25,7 @@ from ytk_mp4j_tpu.models.gbdt import GBDTConfig, GBDTTrainer
 def test_row_chunks_place_what_the_plain_path_places(rng, n_shards, per,
                                                      width, chunk_bytes):
     t = DataParallelTrainer(n_devices=n_shards)
-    t._CHUNK_BYTES = chunk_bytes
+    t._EACH_CHUNK_BYTES = chunk_bytes
     a = rng.integers(0, 256, (n_shards * per, width)).astype(np.int32)
     got = t._put_in_row_chunks(a.reshape(n_shards, per, width))
     assert (got.shape, got.dtype) == ((n_shards, per, width), a.dtype)
@@ -47,7 +47,7 @@ def test_row_chunks_place_what_the_plain_path_places(rng, n_shards, per,
 def test_which_arrays_go_in_row_chunks(rng, monkeypatch, n_shards, shape,
                                        limit, chunked):
     t = DataParallelTrainer(n_devices=n_shards)
-    t._ONE_TRANSFER_BYTES, t._CHUNK_BYTES = limit, 4096
+    t._ONE_TRANSFER_BYTES, t._EACH_CHUNK_BYTES = limit, 4096
     calls = []
     inner = t._put_in_row_chunks
     monkeypatch.setattr(
@@ -78,7 +78,7 @@ def test_train_is_the_same_whichever_way_the_table_went(rng, monkeypatch,
 
     def train(chunked):
         tr = GBDTTrainer(cfg, n_devices=n_shards)
-        tr._CHUNK_BYTES = 2048
+        tr._EACH_CHUNK_BYTES = 2048
         calls = []
         if chunked:
             tr._ONE_TRANSFER_BYTES = N // n_shards * F * 4
@@ -103,7 +103,7 @@ def test_a_second_table_of_the_same_shape_builds_nothing(rng):
     first built (the benchmark counts programs built inside its window
     and refuses a run that built one)."""
     t = DataParallelTrainer(n_devices=1)
-    t._CHUNK_BYTES = 4096
+    t._EACH_CHUNK_BYTES = 4096
     a = rng.integers(0, 256, (1, 300, 16)).astype(np.int32)
     t._put_in_row_chunks(a)
     (place,) = t._row_placers.values()

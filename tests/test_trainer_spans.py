@@ -112,11 +112,12 @@ def test_gbdt_train_leaves_exactly_its_spans(rng, ring):
     sends = _named(got, "mp4j.stage.send")
     assert [s[6] for s in sends] == [{"chunk": 0, **p[6]} for p in puts]
     assert all(_inside(s, p) for s, p in zip(sends, puts))
-    # what the host builds before anything is sent: the table's weights
-    # (512 rows fill four shards: nothing is padded), the labels' weights,
-    # the margins; inside the stage span and outside every put
+    # what the host builds before anything is sent: nothing for the
+    # table (512 rows fill four shards, and ``shard_bins`` asks for no
+    # weights), the labels' weights, the margins; inside the stage span
+    # and outside every put
     preps = _named(got, "mp4j.stage.prep")
-    assert [s[6] for s in preps] == [{"bytes": 4 * 512}] * 3
+    assert [s[6] for s in preps] == [{"bytes": 0}] + [{"bytes": 4 * 512}] * 2
     assert all(_inside(s, stage) for s in preps)
     assert not any(_inside(s, p) for s in preps for p in puts)
     # in order on the host: build, stage, every tree, fetch
@@ -181,7 +182,8 @@ def test_row_placer_build_is_a_step_build_span(rng, ring):
     of its own: a job that built it anew would show, as a rebuilt step
     does."""
     tr, bins, y = _gbdt(rng)
-    tr._ONE_TRANSFER_BYTES, tr._CHUNK_BYTES = bins.nbytes // N_SHARDS, 1024
+    tr._ONE_TRANSFER_BYTES = bins.nbytes // N_SHARDS
+    tr._EACH_CHUNK_BYTES = 1024
     for _ in range(2):
         tr.train(bins, y, n_trees=1)
     builds = [s[6] for s in _named(_trainer_spans(), "mp4j.step.build")]
@@ -337,7 +339,7 @@ def _chunked(n_shards, per=1000, width=16):
     last one early, whichever way (``tests/test_row_chunk_staging.py``)."""
     t = DataParallelTrainer(n_devices=n_shards)
     t._ONE_TRANSFER_BYTES = per * width * 4
-    t._CHUNK_BYTES = t._EACH_CHUNK_BYTES = 4096
+    t._EACH_CHUNK_BYTES = 4096
     return t, np.arange(n_shards * per * width, dtype=np.int32).reshape(
         n_shards * per, width)
 
@@ -371,17 +373,90 @@ def test_row_chunks_leave_a_send_and_a_place_a_chunk(ring, n_shards,
     assert all(s[2] + s[3] <= p[2] for s, p in zip(send, place))
     link = [s[6]["chunk"] for s in _named(stage, "mp4j.stage.link_wait")]
     device = [s[6]["chunk"] for s in _named(stage, "mp4j.stage.device_wait")]
-    if with_each:
-        # two crossing: chunk k - 1 has crossed before k + 1 is sent; the
-        # device is waited for only when twelve wait for their turn
-        assert link == list(range(15)) and device == list(range(4))
-        assert calls == [(min(64 * k, 1000 - 64), min(64 * k, 1000 - 64) + 64)
-                         for k in range(16)]
-    else:
-        assert link == [] and device == list(range(14))
+    # one pace, work on every chunk or none: two crossing, so chunk k - 1
+    # has crossed before k + 1 is sent; the device is waited for only
+    # when twelve wait for their turn
+    assert link == list(range(15)) and device == list(range(4))
+    assert calls == ([(min(64 * k, 1000 - 64), min(64 * k, 1000 - 64) + 64)
+                      for k in range(16)] if with_each else [])
     assert {s[0] for s in stage} == {
         "mp4j.stage.send", "mp4j.stage.place", "mp4j.stage.device_wait",
-        *(["mp4j.stage.link_wait"] if with_each else [])}
+        "mp4j.stage.link_wait"}
+
+
+@pytest.mark.parametrize("n_shards", [1, 4])
+@pytest.mark.parametrize("n_rows,width,chunk_rows,words", [
+    (1000, 16, 64, True),       # a chunk's cells fill rows of 128 words
+    (1003, 16, 64, True),       # ... and four shards pad the last one
+    (1000, 24, 45, False),      # 1,080 cells a chunk: in its own shape
+    (1003, 24, 45, False),
+])
+def test_train_stages_a_table_over_one_chunk_to_the_bit(
+        rng, ring, monkeypatch, n_shards, n_rows, width, chunk_rows, words):
+    """``train()``'s staging of a table over one chunk: what rests on the
+    mesh is the host's array to the bit, the last chunk ragged and the
+    last shard padded or not; every chunk is one ``mp4j.stage.send`` of
+    ``_EACH_CHUNK_BYTES``; and nothing N-sized is built for the table
+    (``shard_bins`` asks ``_pad_rows`` for no weights)."""
+    tr = GBDTTrainer(GBDTConfig(n_features=width, n_bins=16, depth=2,
+                                loss="logistic", hist_mode="matmul"),
+                     n_devices=n_shards)
+    tr._ONE_TRANSFER_BYTES = tr._EACH_CHUNK_BYTES = chunk_rows * width * 4
+    bins = rng.integers(0, 16, (n_rows, width)).astype(np.int32)
+    crossed = []
+    put = jax.make_array_from_callback
+    monkeypatch.setattr(
+        jax, "make_array_from_callback",
+        lambda shape, *a, **kw: crossed.append(shape) or put(shape, *a, **kw))
+    table = tr.shard_data(bins, np.zeros(n_rows, np.float32))[0]
+    per = -(-n_rows // n_shards)
+    want = np.zeros((n_shards * per, width), np.int32)
+    want[:n_rows] = bins
+    assert table.shape == (n_shards, per, width) and table.dtype == np.int32
+    np.testing.assert_array_equal(np.asarray(table).reshape(-1, width), want)
+    n_chunks = -(-per // chunk_rows)
+    assert per % chunk_rows and n_chunks > 3    # over a chunk, and ragged
+    recorded = _trainer_spans()
+    sends = [s[6] for s in _named(recorded, "mp4j.stage.send")]
+    assert sends[:n_chunks] == [
+        {"chunk": k, "bytes": n_shards * tr._EACH_CHUNK_BYTES}
+        for k in range(n_chunks)]
+    assert len(sends) == n_chunks + 3       # labels, margins, weights: one each
+    assert crossed[:n_chunks] == [
+        (n_shards, chunk_rows * width // 128, 128) if words
+        else (n_shards, chunk_rows, width)] * n_chunks
+    # the table's prep: a padded copy where the shards need one, and never
+    # the 4 bytes a row of a weight vector
+    padded = want.nbytes if n_shards * per > n_rows else 0
+    assert [s[6]["bytes"] for s in _named(recorded, "mp4j.stage.prep")] == [
+        padded, 4 * n_shards * per + (4 * n_shards * per if padded else 0),
+        4 * n_shards * per]
+
+
+def test_the_bosch_width_crosses_in_128_mib_pieces(ring):
+    """The constants as they are: 36,000 rows of the Bosch table's 968
+    columns (139 MB) cross in two pieces of 34,560 rows (whole rows of
+    128 lanes under 128 MiB), the second starting early, and rest to the
+    bit; ``shard_bins`` builds nothing on the host."""
+    assert (DataParallelTrainer._EACH_CHUNK_BYTES,
+            DataParallelTrainer._CHUNKS_CROSSING,
+            DataParallelTrainer._CHUNKS_AHEAD) == (128 * 2 ** 20, 2, 12)
+    assert not hasattr(DataParallelTrainer, "_CHUNK_BYTES")
+    tr = GBDTTrainer(GBDTConfig(n_features=968, n_bins=256, depth=2,
+                                loss="logistic", hist_mode="matmul"),
+                     n_devices=1)
+    tr._ONE_TRANSFER_BYTES = tr._EACH_CHUNK_BYTES
+    bins = (np.arange(36_000 * 968, dtype=np.int32) % 251).reshape(-1, 968)
+    table = tr.shard_bins(bins)
+    assert np.array_equal(np.asarray(table)[0], bins)
+    recorded = _trainer_spans()
+    assert [s[6] for s in _named(recorded, "mp4j.stage.send")] == [
+        {"chunk": k, "bytes": 34_560 * 968 * 4} for k in range(2)]
+    assert 128 * 2 ** 20 - 128 * 968 * 4 < 34_560 * 968 * 4 <= 128 * 2 ** 20
+    assert [s[6] for s in _named(recorded, "mp4j.stage.prep")] == [
+        {"bytes": 0}]
+    (build,) = _named(recorded, "mp4j.step.build")
+    assert build[6] == {"key": "row_placer", "rows": 34_560}
 
 
 @pytest.mark.parametrize("family", ["ffm", "linear"])

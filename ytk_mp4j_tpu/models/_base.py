@@ -153,27 +153,28 @@ class DataParallelTrainer:
         return jax.tree_util.tree_map(
             lambda p: jax.device_put(p, sh), tree)
 
-    def _pad_rows(self, arrays: list[np.ndarray]):
+    def _pad_rows(self, arrays: list[np.ndarray], weights: bool = True):
         """Pad dim 0 of each array to a multiple of ``n_shards``; returns
         (padded arrays, per-shard rows, sample-weight vector with zeros on
-        the padding rows)."""
+        the padding rows, or None where the caller uses no ``weights``)."""
         N = arrays[0].shape[0]
         n = self.n_shards
         per = -(-N // n)
         pad = per * n - N
         # what the host builds here: the weights, and every array again
         # where the rows do not fill the shards
-        built = 4 * per * n
+        built = 4 * per * n if weights else 0
         if pad:
             built += sum(a.nbytes // N * per * n for a in arrays)
         with spans.span("mp4j.stage.prep", bytes=built):
-            sw = np.ones(N, np.float32)
+            sw = np.ones(N, np.float32) if weights else None
             if pad:
                 arrays = [
                     np.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
                     for a in arrays
                 ]
-                sw = np.pad(sw, (0, pad))
+                if weights:
+                    sw = np.pad(sw, (0, pad))
         return arrays, per, sw
 
     def _stream_fit(self, batches, stage_chunk, dispatch,
@@ -324,33 +325,33 @@ class DataParallelTrainer:
     # [1, rows, 968]: 4.07 GB in 0.40 s = 10.1 GB/s, 4.30 GB in 23.3 s =
     # 0.185 GB/s, the idle time named ``MapDmaBuffer``; 4.58 GB as one
     # flat array, as four rows of a [4, ., 968] array or at another
-    # width: 21-25 s all the same). A shard that large goes in chunks of
-    # ``_CHUNK_BYTES`` (in their own shape 256 MiB and 1 GiB chunks both
-    # took 0.47 s for 4.58 GB, 64 MiB with nine in flight 0.40 s at
-    # three times the spread; the smaller holds less in flight).
+    # width: 21-25 s all the same). A shard that large goes in row
+    # chunks; a smaller one is the runtime's to pace (the Higgs bins,
+    # 1.23 GB, in chunks: a 2.704 s job 20 ms longer, PR 46).
     _ONE_TRANSFER_BYTES = 2 ** 32
-    _CHUNK_BYTES = 256 * 2 ** 20
-    # Where work is dispatched on every chunk (``each``) the device sets
-    # the pace, and what the host has to do is keep the link ahead of it.
-    # This host stops for 110 ms at a time, several times a minute, every
-    # process at once; the device goes on through such a stop only with
-    # the chunks that have crossed. Waiting for the device three chunks
-    # back, as staging alone does, starts three transfers at once (the
-    # first chunk is there after 73 ms, not 25) and then holds the link to
-    # the device's pace, so the device runs out of rows as soon as the
-    # host stops. So with ``each`` the host waits for a chunk to have
-    # crossed before it sends the one after the next, and for the device
-    # only when ``_CHUNKS_AHEAD`` chunks that have crossed wait for their
-    # turn (1.5 GB). Two in flight cross at 13.5 GB/s; one at a time
-    # leaves the link idle between chunks (9.8 GB/s), three share it to no
-    # gain. The chunks are half as long: the device starts when the first
-    # pair has crossed, and how long that takes is all that differs from
-    # one job to the next. My chip runs, PR 30, 4.58 GB scored by 500
-    # trees as it crosses, a job in s (quartile distance in ms):
-    # staging's own wait 0.669 (5); 256 MiB, two crossing 0.649 (5);
-    # 128 MiB 0.615 (1.7); 64 MiB 0.611 (0.3), where the host's loop is
-    # what holds the link (10.3 GB/s). A stop 150 ms or more into a job
-    # costs it nothing at 128 MiB, where it cost 70-95 ms before.
+    # The one pace of a table that crosses in chunks, with work
+    # dispatched on every chunk (``each``) or with none. The host waits
+    # for a chunk to have crossed before it sends the one after the
+    # next: two in flight cross at 13.5 GB/s; one at a time leaves the
+    # link idle between chunks (9.8 GB/s), three share it to no gain.
+    # It waits for the device only when ``_CHUNKS_AHEAD`` chunks
+    # that have crossed wait for their turn (1.5 GB): this host stops for
+    # 110 ms at a time, several times a minute, every process at once,
+    # and the device goes on through such a stop only with the chunks
+    # that have crossed. The chunks are 128 MiB: the device starts when
+    # the first pair has crossed, and how long that takes is all that
+    # differs from one job to the next. Chip runs of PR 30, 4.58 GB
+    # scored by 500 trees as it crosses, a job in s (quartile distance,
+    # ms): waiting for the device three 256 MiB chunks back, which starts
+    # three transfers at once and then holds the link to the device's
+    # pace, 0.669 (5); 256 MiB, two crossing 0.649 (5); 128 MiB 0.615
+    # (1.7); 64 MiB 0.611 (0.3), where the host's loop holds it.
+    # PR 46, the same bytes staged alone, ms till the table is there:
+    # three 256 MiB chunks back 432.9; this pace 339.9; 64 MiB 331.5;
+    # three crossing 342.2. A launch of the placer holds the host 2.5-3
+    # ms behind the chunk in flight whether its row comes with the
+    # launch, was on the device before the loop or is a counter the
+    # placer keeps (339.9, 346.8, 340.8): the link sets the pace.
     _EACH_CHUNK_BYTES = 128 * 2 ** 20
     _CHUNKS_CROSSING = 2
     _CHUNKS_AHEAD = 12
@@ -358,14 +359,10 @@ class DataParallelTrainer:
     def _put_in_row_chunks(self, a: np.ndarray, each=None):
         """``a`` [n_shards, per, ...] onto the mesh, rows sharded, a chunk
         of rows at a time: a ``dynamic_update_slice`` places each chunk
-        in the donated table while the next is on its way. At most three
-        chunks are in flight; the table is never held twice.
-
-        With ``each`` the device has work on every chunk, and the host
-        keeps the link ahead of it instead (``_EACH_CHUNK_BYTES``,
-        ``_CHUNKS_CROSSING``, ``_CHUNKS_AHEAD`` above): chunks half as
-        long, two crossing at a time, and up to twelve that have crossed
-        waiting on the device for their turn.
+        in the donated table while the next ones are on their way, at
+        the one pace the constants above set; the table is never held
+        twice. ``each``, where given, is called for every chunk as it is
+        placed (``_put_sharded``).
 
         A chunk crosses as [n_shards, M, 128], which rests on the device
         in the order the host holds it, so the host's runtime has nothing
@@ -392,9 +389,8 @@ class DataParallelTrainer:
         n, per = parts[0].shape[:2]
         cols = [int(np.prod(p.shape[2:])) for p in parts]
         row = sum(cols)                         # elements a row
-        chunk_bytes = (self._CHUNK_BYTES if each is None
-                       else self._EACH_CHUNK_BYTES)
-        rows = max(1, min(per, chunk_bytes // (row * parts[0].itemsize)))
+        rows = max(1, min(per, self._EACH_CHUNK_BYTES
+                          // (row * parts[0].itemsize)))
         if rows >= 128:
             rows -= rows % 128                  # whole rows of 128 lanes
         shapes = [(n, rows) + p.shape[2:] for p in parts]
@@ -413,32 +409,12 @@ class DataParallelTrainer:
         # job after the first builds nothing
         place = self._row_placers.get(key)
         if place is None:
-            if packed:
-                def place(table, chunks, start):
-                    words = [jax.lax.bitcast_convert_type(
-                        c.reshape(n, rows, -1), jnp.int32) for c in chunks]
-                    words.append(jnp.zeros((n, rows, width - row), jnp.int32))
-                    at = [jnp.zeros((), start.dtype)] * table.ndim
-                    at[1] = start
-                    return (jax.lax.dynamic_update_slice(
-                        table, jnp.concatenate(words, axis=2), at),
-                        chunks[0].reshape(-1)[0])
-            else:
-                def place(table, chunk, start):
-                    at = [jnp.zeros((), start.dtype)] * table.ndim
-                    at[1] = start
-                    return (jax.lax.dynamic_update_slice(
-                        table, chunk.reshape(shape), at),
-                        chunk.reshape(-1)[0])
-
             with spans.span("mp4j.step.build", key="row_placer",
                             rows=rows):
-                place = self._row_placers[key] = jax.jit(
-                    place, donate_argnums=0,
-                    out_shardings=(sharding, None))
+                place = self._row_placers[key] = self._build_row_placer(
+                    shape, width - row if packed else None)
         table = jnp.zeros(table_shape, dtype, device=sharding)
         placed, crossing = [], []
-        ahead = 2 if each is None else self._CHUNKS_AHEAD
         for k, start in enumerate(range(0, per, rows)):
             # the last chunk is as long as the others: it starts early
             # and rewrites rows the chunk before it already placed
@@ -456,18 +432,39 @@ class DataParallelTrainer:
             with spans.span("mp4j.stage.place", chunk=k):
                 table, done = place(table, dchunk, np.int32(start))
             placed.append(done)
+            crossing.append(dchunk)
             if each is not None:
                 each(table, start, start + rows)
-                crossing.append(dchunk)
-                if len(crossing) >= self._CHUNKS_CROSSING:
-                    with spans.span("mp4j.stage.link_wait",
-                                    chunk=k + 1 - len(crossing)):
-                        jax.block_until_ready(crossing.pop(0))
-            if len(placed) > ahead:
+            if len(crossing) >= self._CHUNKS_CROSSING:
+                with spans.span("mp4j.stage.link_wait",
+                                chunk=k + 1 - len(crossing)):
+                    jax.block_until_ready(crossing.pop(0))
+            if len(placed) > self._CHUNKS_AHEAD:
                 with spans.span("mp4j.stage.device_wait",
                                 chunk=k + 1 - len(placed)):
                     jax.block_until_ready(placed.pop(0))
         return table
+
+    def _build_row_placer(self, shape, pad: int | None = None):
+        """``_put_in_row_chunks``' program: a chunk as it crossed into the
+        donated table at a row it is told; also returns the chunk's first
+        word, which is there when the chunk has been placed. ``shape``:
+        the chunk's in the table; ``pad``: a tuple's zero words a row."""
+        n, rows = shape[:2]
+
+        def place(table, chunk, start):
+            if pad is not None:
+                words = [jax.lax.bitcast_convert_type(
+                    c.reshape(n, rows, -1), jnp.int32) for c in chunk]
+                words.append(jnp.zeros((n, rows, pad), jnp.int32))
+            at = [jnp.zeros((), start.dtype)] * table.ndim
+            at[1] = start
+            piece, first = ((chunk.reshape(shape), chunk) if pad is None
+                            else (jnp.concatenate(words, axis=2), chunk[0]))
+            return (jax.lax.dynamic_update_slice(table, piece, at),
+                    first.reshape(-1)[0])
+        return jax.jit(place, donate_argnums=0,
+                       out_shardings=(self._row_sharding(), None))
 
     def _put_row_chunks(self, chunks, n_rows: int, width: int):
         """Rows that arrive a chunk at a time, each ``[m, width]`` f32 in
@@ -483,11 +480,10 @@ class DataParallelTrainer:
         places it, so the table is never held twice. A chunk goes to the
         device that holds its rows, cut where it spans two shards and
         into pieces of ``_EACH_CHUNK_BYTES`` at most, two crossing at a
-        time and up to ``_CHUNKS_AHEAD`` waiting to be placed (the pace
-        ``_put_in_row_chunks`` keeps under ``each``; the ledger's notes
-        of PR 43 have 4.58 GB of floats at 0.339-0.341 s so, against
-        0.42-0.48 s three at a time). One placer a (shard, piece) shape,
-        kept with the trainer.
+        time and up to ``_CHUNKS_AHEAD`` waiting to be placed
+        (``_put_in_row_chunks``' pace; the ledger's notes of PR 43 have
+        4.58 GB of floats at 0.339-0.341 s so). One placer a (shard,
+        piece) shape, kept with the trainer.
 
         A chunk of another width, or chunks that do not add up to
         ``n_rows``, raise: nothing is padded in silence. The iterator's

@@ -1897,8 +1897,8 @@ class FMTrainer(DataParallelTrainer):
             feats, fields, vals = self._stage_instances(feats, fields, vals)
             params = self._stage_table(params)
             N = feats.shape[0]
-            (f, fl, v, m), per, _sw = self._pad_rows(
-                [feats, fields, vals, _live_mask(vals)])
+            (f, fl, v, m), per, _ = self._pad_rows(
+                [feats, fields, vals, _live_mask(vals)], weights=False)
             if self._pred_fn is None:
                 self._pred_fn = self._build_sharded_predict()
             staged = [self._put_sharded(a, per) for a in (f, fl, v, m)]
@@ -1938,7 +1938,8 @@ class FMTrainer(DataParallelTrainer):
                 probs = program(table, tuple(model), probs, np.int32(start))
 
         with spans.span("mp4j.ffm.score.stage", job=job, rows=N):
-            arrays, per, _sw = self._pad_rows([feats, fields, vals])
+            arrays, per, _ = self._pad_rows([feats, fields, vals],
+                                            weights=False)
             parts = tuple(a.reshape(self.n_shards, per, -1) for a in arrays)
             with spans.span("mp4j.put_sharded",
                             bytes=sum(a.nbytes for a in parts)):

@@ -972,7 +972,7 @@ class GBDTTrainer(DataParallelTrainer):
         ``predict`` both stage; the padding rows are cut from
         ``predict``'s margins and weigh nothing in ``train``."""
         self._check_bins_width(bins)
-        (bins,), per, _ = self._pad_rows([bins])
+        (bins,), per, _ = self._pad_rows([bins], weights=False)
         return self._put_sharded(bins, per, each)
 
     def train(self, bins: np.ndarray, y: np.ndarray,
@@ -1194,9 +1194,9 @@ class GBDTTrainer(DataParallelTrainer):
         binning -> boosted training, in one call (the reference
         consumer bins internally; SURVEY.md section 1 flagship consumer
         + section 3b). It is :meth:`train_raw_chunks` over row slices
-        of ``X`` (256 MiB each): the floats cross to the mesh once, the
-        sketch and the transform run there, and the binned table never
-        visits the host.
+        of ``X`` (a staging chunk each): the floats cross to the mesh
+        once, the sketch and the transform run there, and the binned
+        table never visits the host.
 
         A :class:`~ytk_mp4j_tpu.models.binning.QuantileBinner` with
         ``n_bins=cfg.n_bins`` and ``missing_bucket=cfg.missing_bin`` is
@@ -1231,7 +1231,7 @@ class GBDTTrainer(DataParallelTrainer):
             elif sample_weight is not None:
                 binner.fit(X, sample=bin_sample, seed=seed,
                            sample_weight=sample_weight)
-        rows = max(1, self._CHUNK_BYTES // (4 * X.shape[1]))
+        rows = max(1, self._EACH_CHUNK_BYTES // (4 * X.shape[1]))
         slices = ((X[s:s + rows], y[s:s + rows])
                   for s in range(0, X.shape[0], rows))
         return self._train_raw(
