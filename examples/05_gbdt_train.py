@@ -2,7 +2,12 @@
 Continuous features cross to the mesh once, in row chunks, and are
 sketched and quantile-binned on device, samples shard over
 the mesh, each boosting round is ONE jitted shard_map step whose
-histogram allreduce is a psum, and ensemble predict runs in one jit."""
+histogram allreduce is a psum, and ensemble predict runs in one jit,
+from bins or (predict_raw_chunks / predict_raw) from floats that are
+binned on device as they cross."""
+import os
+import tempfile
+
 import numpy as np
 
 from ytk_mp4j_tpu.models.binning import QuantileBinner
@@ -38,8 +43,21 @@ assert mse < mse0
 # the binning run there; the trees do not depend on where the chunks
 # were cut (train_raw is this front end over row slices of one array).
 reader = ((X[s:s + 3_000], y[s:s + 3_000]) for s in range(0, N, 3_000))
-chunked_trees, chunked_preds = GBDTTrainer(cfg).train_raw_chunks(reader, N)
+chunked = GBDTTrainer(cfg)
+chunked_trees, chunked_preds = chunked.train_raw_chunks(reader, N)
 assert np.array_equal(chunked_preds, train_preds)
+
+# ... and is scored the same way: train from chunks, save (the fitted
+# binner's edges ride with the model), load, score from chunks of floats
+# (no labels). Each chunk is binned on the mesh where it lands, inside
+# the scoring program, while the next ones cross; predict_raw is this
+# entry point over row slices of one array.
+path = os.path.join(tempfile.mkdtemp(), "gbdt.npz")
+chunked.save_model(path, chunked_trees)
+cfg2, trees2, binner2 = GBDTTrainer.load_model(path)
+served = GBDTTrainer(cfg2).predict_raw_chunks(
+    (X[s:s + 7_000] for s in range(0, N, 7_000)), N, trees2, binner=binner2)
+assert np.array_equal(served, preds)
 
 # the manual wiring underneath: the sketch/merge pair is what
 # fit_distributed runs per rank on a multi-host job (edges are the

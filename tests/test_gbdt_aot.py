@@ -1392,3 +1392,90 @@ def test_sketch_holds_a_block_of_columns_beside_the_table(raw_programs):
     # the blocks in flight and the mask: 0.61 GB when this was written
     assert compiled.memory_analysis().temp_size_in_bytes \
         < WIDE_TABLE_BYTES // 4
+
+
+# Scoring from floats (ISSUE 47, benchmark/configs/
+# gbdt-bosch-score-raw-500.json): the reader's chunk of 65,536 rows
+# crosses in two pieces of 32,768 and each piece is binned and scored by
+# one call of the scoring program as soon as it is placed; the last
+# chunk's 4,100 rows take a program of their own. A piece's bins exist
+# for the length of a call, beside the resident float table.
+RAW_SCORE_LAST_ROWS = SCORE_ROWS - 18 * RAW_CHUNK_ROWS
+
+
+def _float_score(trainer, piece, stacked, rows, whole):
+    """(the scoring program for a float table at the cell's size and
+    ``piece`` rows a call, compiled; its build span's arguments)."""
+    from ytk_mp4j_tpu.obs import spans
+
+    spans.clear()
+    program = trainer._build_score((1, SCORE_ROWS, WIDE_F), piece,
+                                   SCORE_TREES, (B - 2, True))
+    (built,) = [s[-1] for s in spans.snapshot()
+                if s[0] == "mp4j.step.build"]
+    return program.lower(
+        jax.ShapeDtypeStruct((1, SCORE_ROWS, WIDE_F), jnp.float32,
+                             sharding=rows), stacked,
+        jax.ShapeDtypeStruct((1, 1, SCORE_ROWS), jnp.float32, sharding=rows),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=whole),
+        jax.ShapeDtypeStruct((WIDE_F, B - 2), jnp.float32,
+                             sharding=whole)).compile(), built
+
+
+def test_float_scoring_programs_bin_a_piece_beside_the_resident_table(
+        topo_devices):
+    """Both programs of the cell compile for the chip, hold the float
+    table as it rests and nothing else of its size, and make of a
+    piece's rows no more than its counts and its bf16 digits: an XLA
+    that materialised the whole table's bins would fail here first.
+    Compiled with 64-bit types off, as the cell and every user who has
+    not asked for them run it (``tests/conftest.py`` turns them on)."""
+    from ytk_mp4j_tpu.models.gbdt import score_group_size
+
+    mesh = Mesh(np.asarray(topo_devices[:1]), ("mp4j",))
+    trainer = GBDTTrainer(GBDTConfig(n_features=WIDE_F, n_bins=B,
+                                     depth=DEPTH, loss="logistic",
+                                     missing_bin=True), mesh=mesh)
+    assert RAW_SCORE_LAST_ROWS == 4_100
+    assert (-(-RAW_CHUNK_ROWS * WIDE_F * 4 // trainer._EACH_CHUNK_BYTES)
+            == RAW_CHUNK_ROWS // RAW_PIECE_ROWS)
+    rows, whole = NamedSharding(mesh, P("mp4j")), NamedSharding(mesh, P())
+    group = score_group_size(SCORE_TREES)
+    shape = (-(-SCORE_TREES // group), 2 ** DEPTH, group, 1)
+    stacked = tuple(jax.ShapeDtypeStruct(shape, d, sharding=whole)
+                    for d in (jnp.int32, jnp.int32, jnp.int32, jnp.float32))
+    table = r"f32\[1,%d,%d\]\{1,2,0:T\(8,128\)\}" % (SCORE_ROWS, WIDE_F)
+    for piece in (RAW_PIECE_ROWS, RAW_SCORE_LAST_ROWS):
+        with jax.enable_x64(False):
+            compiled, built = _float_score(trainer, piece, stacked, rows,
+                                           whole)
+        assert built == {"key": "gbdt_score_raw", "edges": B - 2,
+                         "form": "bins", "group": group, "rows": piece,
+                         "row_chunk": piece, "row_chunks": 1}
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes >= SCORE_ROWS * WIDE_F * 4
+        # a piece's floats as they were sliced out to rest, its counts
+        # (int32, where XLA cuts the chain of 254 compares in two) and
+        # its bf16 digits: 10 bytes a cell of the piece, 0.32 GB at
+        # 32,768 rows; and the margins are updated where they rest
+        assert mem.temp_size_in_bytes < 3 * piece * WIDE_F * 4, piece
+        text = compiled.as_text()
+        assert "input_output_alias" in text
+        assert re.search(table + r" parameter\(0\)", text)
+        # nothing of the table's size is made: the only arrays with the
+        # table's rows are the table and the margins
+        made = re.findall(
+            r"= \w+\[[\d,]*\d{6,},[\d,]*\d{3,}[\d,]*\]\S* (\w[\w-]*)\(",
+            text)
+        assert set(made) <= {"parameter"}, made
+        # a piece's floats rest once before they are compared (the
+        # slice fused into the chain of compares is four times slower
+        # on the chip), and the compares read that copy
+        assert len(re.findall(r"= f32\[%d,%d\]\S* (?:fusion|copy)\("
+                              % (piece, WIDE_F), text)) == 1
+        assert re.search(r"= bf16\[%d,%d\]\{1,0:T\(8,128\)\(2,1\)\} fusion\("
+                         % (WIDE_F, piece), text)
+        assert "bin.transform" in text
+        assert "gbdt.score.select/dot_general" in text
+        assert "gbdt.score.walk" in text
+        assert " gather(" not in text and "tpu_custom_call" not in text

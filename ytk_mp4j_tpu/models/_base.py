@@ -466,7 +466,7 @@ class DataParallelTrainer:
         return jax.jit(place, donate_argnums=0,
                        out_shardings=(self._row_sharding(), None))
 
-    def _put_row_chunks(self, chunks, n_rows: int, width: int):
+    def _put_row_chunks(self, chunks, n_rows: int, width: int, each=None):
         """Rows that arrive a chunk at a time, each ``[m, width]`` f32 in
         the order of the table, onto the mesh as ``[n_shards, rows a
         shard, width]`` f32, rows sharded as ``_pad_rows`` and
@@ -484,6 +484,17 @@ class DataParallelTrainer:
         (``_put_in_row_chunks``' pace; the ledger's notes of PR 43 have
         4.58 GB of floats at 0.339-0.341 s so). One placer a (shard,
         piece) shape, kept with the trainer.
+
+        ``each(table, start, stop)``, where given, is ``_put_sharded``'s
+        hand-over: called as soon as rows [start, stop) of EVERY shard
+        are on their way into ``table`` (the whole array; only good
+        until the next call), so that what it dispatches on them runs
+        while the next pieces cross. The rows arrive in the table's
+        order, shard after shard, so that is after every piece of the
+        last shard (of the only one, on one device: after every piece),
+        and once more at the end for the rows the last shard's data does
+        not reach (its padding). Every row of a shard is handed over
+        exactly once.
 
         A chunk of another width, or chunks that do not add up to
         ``n_rows``, raise: nothing is padded in silence. The iterator's
@@ -509,6 +520,12 @@ class DataParallelTrainer:
         piece_rows = max(1, self._EACH_CHUNK_BYTES // (4 * width))
         placed, crossing = [], []
         done, sent = 0, 0
+        handed = 0                  # rows of every shard ``each`` has had
+
+        def whole():
+            return jax.make_array_from_single_device_arrays(
+                shape, sharding, [tables[s] for s in sorted(tables)])
+
         it, end = iter(chunks), object()
         with spans.span("mp4j.put_sharded", bytes=4 * n_rows * width):
             for k in itertools.count():
@@ -534,21 +551,23 @@ class DataParallelTrainer:
                     m = min(m, -(-m // parts))
                     piece = chunk[at:at + m]
                     at += m
-                    if shard not in where:      # another process's rows
-                        continue
-                    wire = ((m * width // 128, 128)
-                            if m * width % 128 == 0 else (m, width))
-                    with spans.span("mp4j.stage.send", chunk=sent,
-                                    bytes=piece.nbytes):
-                        dpiece = jax.device_put(piece.reshape(wire),
-                                                where[shard])
-                    with spans.span("mp4j.stage.place", chunk=sent):
-                        tables[shard], marker = self._row_chunk_placer(
-                            per, width, m, wire)(
-                                tables[shard], dpiece, np.int32(start))
-                    placed.append(marker)
-                    crossing.append(dpiece)
-                    sent += 1
+                    if shard in where:          # not another process's rows
+                        wire = ((m * width // 128, 128)
+                                if m * width % 128 == 0 else (m, width))
+                        with spans.span("mp4j.stage.send", chunk=sent,
+                                        bytes=piece.nbytes):
+                            dpiece = jax.device_put(piece.reshape(wire),
+                                                    where[shard])
+                        with spans.span("mp4j.stage.place", chunk=sent):
+                            tables[shard], marker = self._row_chunk_placer(
+                                per, width, m, wire)(
+                                    tables[shard], dpiece, np.int32(start))
+                        placed.append(marker)
+                        crossing.append(dpiece)
+                        sent += 1
+                    if each is not None and shard == n - 1:
+                        each(whole(), start, start + m)
+                        handed = start + m
                     if len(crossing) >= self._CHUNKS_CROSSING:
                         with spans.span("mp4j.stage.link_wait",
                                         chunk=sent - len(crossing)):
@@ -562,8 +581,9 @@ class DataParallelTrainer:
                 raise Mp4jError(
                     f"the chunks hold {done} rows, n_rows={n_rows} were "
                     f"announced")
-            return jax.make_array_from_single_device_arrays(
-                shape, sharding, [tables[s] for s in sorted(tables)])
+            if each is not None and handed < per:
+                each(whole(), handed, per)
+            return whole()
 
     def _row_chunk_placer(self, per: int, width: int, rows: int, wire):
         """The program that puts a piece of ``rows`` rows, crossed as

@@ -18,9 +18,12 @@ host interpolates the 254 picked pairs a column as numpy does.
 ``transform_staged`` bins such a table where it rests, into the int32
 table a ``GBDTTrainer`` step takes; ``transform`` takes a host array,
 sends it through the same program a chunk of rows at a time and fetches
-the bins. ``GBDTTrainer.train_raw_chunks`` / ``train_raw`` stage the
-floats once and use the staged pair, so a binned table crosses the host
-link in neither direction.
+the bins (public API for a caller who wants bins on the host; no product
+path calls it). ``GBDTTrainer.train_raw_chunks`` / ``train_raw`` stage
+the floats once and use the staged pair, and ``predict_raw_chunks`` /
+``predict_raw`` bin a chunk's rows inside the scoring program
+(``_count_edges`` on the rows ``gbdt.score_shard`` slices), so a binned
+table crosses the host link in neither direction.
 
 Distributed fitting (``fit_distributed``): each rank sketches its own
 shard — per-feature quantile edges plus finite-value counts — and the
@@ -582,9 +585,11 @@ class QuantileBinner:
 
     def transform(self, X) -> np.ndarray:
         """Continuous [N, F] -> int32 bin ids in [0, n_bins), host array
-        in and host array out (``predict_raw``, and a caller's own use;
+        in and host array out: for a caller who wants the bins (and
+        ``fit_transform``). No trainer path calls it:
         :meth:`transform_staged` bins a table that rests on a mesh and
-        leaves the bins there).
+        leaves the bins there, and ``GBDTTrainer.predict_raw_chunks`` /
+        ``predict_raw`` bin inside their scoring program.
 
         NaN inputs land in bin 0 (the missing bucket; see fit) — this
         deliberately diverges from ``np.searchsorted``, which sorts NaN
@@ -800,9 +805,12 @@ def _count_edges(X, edges, shift: bool):
             count = count + (X >= edge).astype(jnp.int32)
         return count
 
-    zero = jnp.zeros(X.shape, jnp.int32)
-    count = (link(0, zero) if links == 1
-             else jax.lax.fori_loop(0, links, link, zero))
+    # the first chain outside the loop: its count is X's own (inside a
+    # ``shard_map`` it varies over the mesh as X does, which a loop's
+    # carry has to from the start)
+    count = link(0, jnp.zeros(X.shape, jnp.int32))
+    if links > 1:
+        count = jax.lax.fori_loop(1, links, link, count)
     return jnp.where(jnp.isnan(X), 0, count + 1) if shift else count
 
 

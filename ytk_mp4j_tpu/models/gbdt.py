@@ -38,6 +38,7 @@ from ytk_mp4j_tpu.models._base import (DataParallelTrainer, EarlyStopper,
                                        per_example_loss,
                                        stage_softmax_labels)
 from ytk_mp4j_tpu.exceptions import Mp4jError
+from ytk_mp4j_tpu.models.binning import _count_edges
 from ytk_mp4j_tpu.obs import spans
 from ytk_mp4j_tpu.ops.hist_kernel import _rests_tiled, split_bf16
 
@@ -843,14 +844,22 @@ def _score_group(digits, group, out, cfg: GBDTConfig):
 
 
 def score_shard(bins, stacked, out, start, rows: int, cfg: GBDTConfig,
-                axis_name=None):
+                axis_name=None, edges=None, shift: bool = False):
     """Score ``rows`` rows of this shard from row ``start`` on: ``bins``
     [N, F] under the whole ensemble, their margins written into ``out``
     ([C, N] f32, C = 1 unless softmax; the other rows are passed on).
     ``stacked``: (feat, bin, dir, leaf), each [groups, 2**depth, G, C],
     as ``_stack_trees`` lays them out. Per row the sum runs over the
     trees in their order, f32, as ``predict_tree`` after
-    ``predict_tree`` would give it."""
+    ``predict_tree`` would give it.
+
+    With ``edges`` ([F, E] f32) the table is one of floats, NaN where a
+    cell is empty, and a chunk's rows are sliced out, to rest on their
+    own, and binned there (``binning._count_edges``, ``shift``: the
+    binner's reserved missing bucket) under the scope ``bin.transform``:
+    a chunk's floats and bins exist for the length of its turn, the bins
+    as the bf16 digits the select reads, and the margins are those of
+    the bins' table."""
     F = bins.shape[1]
     C = out.shape[0]
     n_digits = _bin_digits(cfg.n_bins)
@@ -858,9 +867,20 @@ def score_shard(bins, stacked, out, start, rows: int, cfg: GBDTConfig,
 
     def chunk_fn(out, c):
         at = start + jnp.minimum(c * row_chunk, rows - row_chunk)
-        with jax.named_scope("gbdt.score.select"):
+        with jax.named_scope("gbdt.score.select" if edges is None
+                             else "bin.transform"):
             part = lax.dynamic_slice(bins, (at, jnp.int32(0)),
-                                     (row_chunk, F)).T
+                                     (row_chunk, F))
+            if edges is not None:
+                # the sliced floats rest before they are compared: fused
+                # into the chain of compares, a slice at a row the
+                # program is told (rows lie along the lanes) makes the
+                # chain 21 ms a piece of 32,768 x 968 where it is 5 on a
+                # piece that rests, and the copy is 0.3 (my chip runs,
+                # PR 47: 37.7 against 21.8 ms a piece, 500 trees)
+                part = _count_edges(lax.optimization_barrier(part), edges,
+                                    shift)
+            part = part.T
             digits = [((part >> (8 * k)) & 255 if n_digits > 1 else part
                        ).astype(jnp.bfloat16) for k in range(n_digits)]
         acc = jnp.zeros((C, row_chunk), jnp.float32)
@@ -1239,17 +1259,61 @@ class GBDTTrainer(DataParallelTrainer):
             sample_weight=sample_weight, eval_set=eval_set,
             early_stopping_rounds=early_stopping_rounds)
 
-    def predict_raw(self, X, trees, proba: bool = False):
-        """Serve RAW continuous features through the binner fitted by
-        :meth:`train_raw` (or installed on ``self.binner_`` by
-        :meth:`load_model`'s caller)."""
-        if self.binner_ is None:
+    def predict_raw_chunks(self, chunks, n_rows: int, trees,
+                           proba: bool = False, binner=None) -> np.ndarray:
+        """:meth:`predict` straight from a table that arrives in pieces,
+        floats and gaps as they are: ``chunks`` is any iterable of ``X
+        [m, n_features]`` float32 with NaN where a cell is empty, in the
+        table's order, ``n_rows`` their total, as
+        :meth:`train_raw_chunks` takes them (without labels); ``binner``
+        a fitted :class:`~ytk_mp4j_tpu.models.binning.QuantileBinner`,
+        by default the one the raw training entry points left on
+        ``self.binner_`` (or :meth:`load_model`'s caller put there).
+
+        Each chunk crosses the host link as it arrives into one float
+        table ``[n_shards, rows a shard, n_features]``
+        (``_put_row_chunks``), and its rows are scored as soon as they
+        are in place, while the next ones cross: the scoring program
+        bins a chunk of rows where it slices them (``score_shard`` with
+        the edges, replicated) and reads the bins as :meth:`predict`'s
+        reads a staged table's. The floats cross once, no binned cell
+        crosses in either direction or outlives its chunk's turn, and
+        the host holds no binned copy. Returns what :meth:`predict`
+        returns for the binner's bins of the same table, to the bit,
+        wherever the chunks were cut.
+
+        No fitted binner, a chunk of another width or chunks that do not
+        add up to ``n_rows`` raise ``Mp4jError``."""
+        if binner is None:
+            binner = self.binner_
+        if binner is None or binner.edges is None:
             raise Mp4jError(
                 "no fitted binner on this trainer: train with "
-                "train_raw, or set trainer.binner_ (load_model returns "
-                "the persisted binner)")
-        return self.predict(self.binner_.transform(X), trees,
-                            proba=proba)
+                "train_raw_chunks or train_raw, pass binner=, or set "
+                "trainer.binner_ (load_model returns the persisted "
+                "binner) before predict_raw_chunks / predict_raw")
+        F = self.cfg.n_features
+        if binner.edges.shape[0] != F:
+            raise Mp4jError(
+                f"the binner has edges for {binner.edges.shape[0]} "
+                f"features, cfg.n_features={F}")
+        return self._predict(
+            lambda each: self._put_row_chunks(chunks, int(n_rows), F, each),
+            int(n_rows), trees, proba, binner)
+
+    def predict_raw(self, X, trees, proba: bool = False):
+        """Serve RAW continuous features [N, n_features] held as ONE
+        array through the binner fitted by :meth:`train_raw` /
+        :meth:`train_raw_chunks` (or installed on ``self.binner_`` by
+        :meth:`load_model`'s caller): :meth:`predict_raw_chunks` over
+        row slices of ``X`` (a staging chunk each), so the floats cross
+        to the mesh once and are binned there."""
+        X = np.asarray(X, np.float32)
+        self._check_bins_width(X, "X")
+        rows = max(1, self._EACH_CHUNK_BYTES // (4 * X.shape[1]))
+        return self.predict_raw_chunks(
+            (X[s:s + rows] for s in range(0, X.shape[0], rows)),
+            X.shape[0], trees, proba)
 
     def _check_bins_width(self, bins, what: str = "bins") -> None:
         """A bin matrix narrower/wider than cfg.n_features would make
@@ -1299,26 +1363,36 @@ class GBDTTrainer(DataParallelTrainer):
         logp = z - np.log(np.exp(z).sum(axis=1, keepdims=True))
         return float(-np.mean(logp[np.arange(len(y)), y.astype(int)]))
 
-    def _build_score(self, shape, rows: int, rounds: int):
+    def _build_score(self, shape, rows: int, rounds: int, binning=None):
         """The scoring program for a staged table of ``shape``
         ([n_shards, rows a shard, n_features]), ``rows`` rows of every
         shard a call and ``rounds`` rounds: ``score_shard`` under
         ``shard_map``, rows sharded and the ensemble replicated. It
         takes (table, ensemble, margins [n_shards, C, rows a shard],
         first row) and returns the margins, donated, with those rows
-        filled in."""
+        filled in. ``binning``: None for a table of bins; for one of
+        floats ``(edges a column, the binner's missing_bucket)``, and
+        the program takes the edges [n_features, edges] last,
+        replicated."""
         cfg = self.cfg
         axes = self.axes
+        compares, shift = binning or (None, False)
+        specs = (P(axes), P(), P(axes), P()) + (P(),) * (binning is not None)
 
-        @partial(jax.shard_map, mesh=self.mesh,
-                 in_specs=(P(axes), P(), P(axes), P()), out_specs=P(axes))
-        def score(bins, stacked, out, start):
+        @partial(jax.shard_map, mesh=self.mesh, in_specs=specs,
+                 out_specs=P(axes))
+        def score(bins, stacked, out, start, *edges):
             return score_shard(bins[0], stacked, out[0], start, rows, cfg,
-                               axes)[None]
+                               axes, *edges, shift=shift)[None]
 
         n_classes = cfg.n_classes if cfg.loss == "softmax" else 1
         row_chunk, chunks = score_row_chunks(rows)
-        with spans.span("mp4j.step.build", key="gbdt_score",
+        # a float table's program says how many compares a cell it
+        # issues, and that it bins before it selects (no float select)
+        said = ({"key": "gbdt_score"} if binning is None else
+                {"key": "gbdt_score_raw", "edges": compares,
+                 "form": "bins"})
+        with spans.span("mp4j.step.build", **said,
                         group=score_group_size(rounds, n_classes),
                         rows=rows, row_chunk=row_chunk, row_chunks=chunks):
             return jax.jit(score, donate_argnums=2)
@@ -1342,11 +1416,25 @@ class GBDTTrainer(DataParallelTrainer):
         at a time, up to twelve ahead of the device). A
         row's margin is the f32 sum over the trees in their order. The
         program is kept by (table shape, rows a call, tree count): a
-        repeated ``predict`` of the same shape builds nothing."""
+        repeated ``predict`` of the same shape builds nothing.
+        :meth:`predict_raw_chunks` is the same loop over a table of
+        floats."""
         bins = np.asarray(bins, np.int32)
         self._check_bins_width(bins)
+        return self._predict(lambda each: self.shard_bins(bins, each),
+                             bins.shape[0], trees, proba)
+
+    def _predict(self, stage, N: int, trees, proba: bool, binner=None):
+        """:meth:`predict` from the point where rows are in place on the
+        mesh, whatever they hold: ``stage(each)`` places the table and
+        calls ``each(table, start, stop)`` for the rows of every shard
+        as they are placed (``shard_bins`` with a host table of bins,
+        ``_put_row_chunks`` with a reader's chunks of floats, which the
+        program bins by ``binner``'s edges). One buffer of margins, one
+        cache of programs (its key says a float table's binning too),
+        the spans of one job (``source``: what the table holds), one
+        fetch."""
         trees = list(trees)
-        N = bins.shape[0]
         softmax = self.cfg.loss == "softmax"
         C = self.cfg.n_classes if softmax else 1
         if not trees or not N:
@@ -1354,7 +1442,15 @@ class GBDTTrainer(DataParallelTrainer):
             out = np.zeros((N, C), np.float32)
         else:
             job, self._score_jobs = self._score_jobs, self._score_jobs + 1
+            said = {"job": job,
+                    "source": "bins" if binner is None else "floats"}
             stacked = self._stack_trees(trees)
+            binning, edges = (), ()
+            if binner is not None:
+                binning = ((binner.edges.shape[1],
+                            bool(binner.missing_bucket)),)
+                edges = (self._place_replicated(
+                    np.asarray(binner.edges, np.float32)),)
             margins = None              # the device's, as last returned
             scored = 0                  # rows of a shard scored so far
 
@@ -1363,23 +1459,23 @@ class GBDTTrainer(DataParallelTrainer):
                 # the last chunk of a staging starts early, over rows
                 # that the one before it brought: those are done
                 start, scored = max(start, scored), stop
-                key = (table.shape, stop - start, len(trees))
+                key = (table.shape, stop - start, len(trees)) + binning
                 program = self._score_programs.get(key)
                 if program is None:
                     program = self._score_programs[key] = \
                         self._build_score(*key)
-                with spans.span("mp4j.gbdt.score.dispatch", job=job,
+                with spans.span("mp4j.gbdt.score.dispatch", **said,
                                 trees=len(trees), start=start):
                     if margins is None:
                         margins = jnp.zeros(
                             (table.shape[0], C, table.shape[1]), jnp.float32,
                             device=self._row_sharding())
                     margins = program(table, stacked, margins,
-                                      np.int32(start))
+                                      np.int32(start), *edges)
 
-            with spans.span("mp4j.gbdt.score.stage", job=job):
-                self.shard_bins(bins, each=score)
-            with spans.span("mp4j.gbdt.score.fetch", job=job):
+            with spans.span("mp4j.gbdt.score.stage", **said):
+                stage(score)
+            with spans.span("mp4j.gbdt.score.fetch", **said):
                 out = self._to_host(margins)
             # [n_shards, C, rows a shard] -> [N, C]
             out = out.transpose(0, 2, 1).reshape(-1, C)[:N]
