@@ -746,3 +746,102 @@ def test_weight_validation_errors(rng):
     w[:3] = 0.0
     with pytest.raises(Mp4jError, match="no\nfinite values|no finite"):
         b.fit(X2, sample_weight=w)
+
+
+# ``#edges <= x`` by search (ISSUE 48): ``_count_edges`` off the TPU and
+# the ``mp4j_bin`` kernel, interpreted, against the plain count.
+def _edge_cases(rng, F, E, N, order):
+    """A table [N, F] and edges [F, E]: duplicate edges, +-inf edges,
+    cells equal to an edge, +-inf cells, NaN cells and one column of
+    nothing else; the edges sorted, reversed, or +inf first."""
+    edges = np.sort(np.round(rng.standard_normal((F, E)), 1), axis=1)
+    if E >= 4:
+        edges[0, -1] = np.inf
+        edges[0, 0] = -np.inf
+        edges[1 % F, -2:] = np.inf
+    X = (3 * rng.standard_normal((N, F))).astype(np.float32)
+    X[rng.random((N, F)) < 0.3] = np.nan
+    ties = rng.integers(0, E, (N // 4, F))
+    X[:N // 4] = np.take_along_axis(edges, ties.T, axis=1).T
+    X[N // 4] = np.inf
+    X[N // 4 + 1] = -np.inf
+    X[:, F - 1] = np.nan
+    if order == "reversed":
+        edges = edges[:, ::-1]
+    elif order == "inf_first":              # what ``_edges_of`` can give
+        edges = np.roll(edges, 1, axis=1)
+    return X, np.ascontiguousarray(edges, np.float32)
+
+
+@pytest.mark.parametrize("form", ["jnp", "kernel"])
+@pytest.mark.parametrize("shift", [False, True])
+@pytest.mark.parametrize("F,E,N,order,block_rows", [
+    (8, 1, 130, "sorted", None), (8, 127, 300, "reversed", None),
+    (8, 128, 300, "inf_first", None), (8, 254, 1100, "sorted", 1024),
+    (16, 254, 300, "inf_first", None), (16, 255, 130, "reversed", None),
+    (8, 300, 300, "inf_first", None), (24, 254, 2100, "reversed", 1024),
+    (28, 254, 1100, "inf_first", None), (28, 300, 130, "sorted", None),
+    (12, 255, 8300, "reversed", 1024), (40, 127, 130, "inf_first", None)])
+def test_count_edges_is_the_plain_count(rng, monkeypatch, form, shift, F, E,
+                                        N, order, block_rows):
+    """Every cell's bin is ``(x >= edges).sum()``, NaN 0 and the others
+    one more under ``shift``: widths that rest in sublane tiles (8, 16,
+    24, 40: one to five blocks of eight columns) and that do not (12,
+    28: a block a column), one and several registers of edges, a table
+    under a block, and a ragged last block of rows (blocks of 1,024
+    rows: 1,100 and 2,100 rows in sublane tiles, 8,300 rows of a column
+    in blocks of 8,192)."""
+    import jax.numpy as jnp
+
+    from ytk_mp4j_tpu.models import binning
+    from ytk_mp4j_tpu.ops import bin_kernel
+
+    X, edges = _edge_cases(rng, F, E, N, order)
+    want = (X[..., None] >= edges).sum(-1)
+    if shift:
+        want = np.where(np.isnan(X), 0, want + 1)
+    if form == "kernel":
+        if block_rows:
+            monkeypatch.setattr(bin_kernel, "_BLOCK_ROWS", block_rows)
+        got = bin_kernel.pallas_bin_counts(jnp.asarray(X), jnp.asarray(edges),
+                                           shift, interpret=True)
+    else:
+        got = binning._count_edges(jnp.asarray(X), jnp.asarray(edges), shift)
+    assert got.dtype == jnp.int32 and got.shape == X.shape
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("n_edges,steps,registers", [
+    (1, 1, 1), (2, 2, 1), (127, 7, 1), (128, 8, 2), (254, 8, 2),
+    (255, 8, 2), (256, 9, 4), (510, 9, 4), (65534, 16, 512)])
+def test_search_table_is_the_edges_in_level_order(rng, n_edges, steps,
+                                                  registers):
+    """Node i's children are 2i and 2i + 1, an in-order walk of the
+    nodes gives the sorted edges and then NaN, and the table is whole
+    registers of 128 lanes."""
+    import jax.numpy as jnp
+
+    from ytk_mp4j_tpu.ops import bin_kernel
+
+    edges = rng.standard_normal((2, n_edges)).astype(np.float32)
+    table = np.asarray(bin_kernel.search_table(jnp.asarray(edges)))
+    assert bin_kernel.search_steps(n_edges) == steps
+    assert table.shape == (2, 128 * registers)
+
+    def in_order(i):
+        return (in_order(2 * i) + [i] + in_order(2 * i + 1)
+                if i < 2 ** steps else [])
+
+    walked = table[:, in_order(1)]
+    np.testing.assert_array_equal(walked[:, :n_edges], np.sort(edges, axis=1))
+    assert np.isnan(walked[:, n_edges:]).all() and np.isnan(table[:, 0]).all()
+
+
+@pytest.mark.parametrize("n_edges,block", [
+    (1, (8, 4096)), (254, (8, 4096)), (255, (8, 4096)),
+    (510, (8, 3072)), (998, (8, 2048)), (4094, (8, 1024)),
+    (65534, (8, 1024))])
+def test_a_block_shortens_with_the_registers_its_edges_take(n_edges, block):
+    from ytk_mp4j_tpu.ops import bin_kernel
+
+    assert bin_kernel.bin_blocks(n_edges) == block

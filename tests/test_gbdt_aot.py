@@ -1307,6 +1307,23 @@ def raw_programs(topo_devices):
     assert (-(-RAW_CHUNK_ROWS * WIDE_F * 4 // trainer._EACH_CHUNK_BYTES)
             == RAW_CHUNK_ROWS // RAW_PIECE_ROWS)
     wire = (RAW_PIECE_ROWS * WIDE_F // 128, 128)
+
+    def transform(chips):
+        members = Mesh(np.asarray(topo_devices[:chips]), ("mp4j",))
+        staged = NamedSharding(members, P("mp4j"))
+        return binning._transform_program(True, B - 2, staged).lower(
+            jax.ShapeDtypeStruct((chips, -(-WIDE_ROWS // chips), WIDE_F),
+                                 jnp.float32, sharding=staged),
+            jax.ShapeDtypeStruct((WIDE_F, B - 2), jnp.float32,
+                                 sharding=NamedSharding(members, P()))
+        ).compile()
+
+    with pytest.MonkeyPatch.context() as patch:
+        # the transform as it is built on a TPU (here the backend is the
+        # CPU and ``_count_edges`` would take its ``jnp`` form)
+        patch.setattr(binning, "_kernel_compiles", lambda: True)
+        transforms = {chips: transform(chips) for chips in (1, 4)}
+        binning._transform_program.cache_clear()
     return {
         "placer": trainer._row_chunk_placer(
             WIDE_ROWS, WIDE_F, RAW_PIECE_ROWS, wire).lower(
@@ -1314,9 +1331,7 @@ def raw_programs(topo_devices):
                                      sharding=one),
                 jax.ShapeDtypeStruct(wire, jnp.float32, sharding=one),
                 jax.ShapeDtypeStruct((), jnp.int32, sharding=one)).compile(),
-        "transform": binning._transform_program(True, rows).lower(
-            table, jax.ShapeDtypeStruct((WIDE_F, B - 2), jnp.float32,
-                                        sharding=whole)).compile(),
+        "transform": transforms[1], "transform_on_four": transforms[4],
         "sketch": binning._sketch_program(B - 1, rows).lower(
             table, jax.ShapeDtypeStruct((WIDE_ROWS,), jnp.bool_,
                                         sharding=whole)).compile()}
@@ -1362,21 +1377,62 @@ def test_train_placer_puts_a_piece_into_the_table_it_was_given(topo_devices):
     assert made == ["parameter", "dynamic-update-slice"], made
 
 
-def test_transform_writes_the_bins_where_the_step_reads_them(raw_programs):
-    compiled = raw_programs["transform"]
+def _kernel_call(text, name="mp4j_bin"):
+    """(the ``name`` kernel's custom call, the instruction that makes its
+    first operand), as the compiled text prints them."""
+    (call,) = re.findall(
+        r"^\s*(%%%s\S* = .*custom-call\(.*tpu_custom_call.*)$" % name, text,
+        re.M)
+    operand = re.search(r"custom-call\((%[\w.-]+)", call).group(1)
+    (made,) = re.findall(r"^\s*(%s = .*)$" % re.escape(operand), text,
+                         re.M)
+    return call, made
+
+
+@pytest.mark.parametrize("which,chips", [("transform", 1),
+                                         ("transform_on_four", 4)])
+def test_transform_writes_the_bins_where_the_step_reads_them(raw_programs,
+                                                             which, chips):
+    """The Mosaic compiler takes the search (a libtpu that refused the
+    lane gather would fail the fixture), the float table reaches the
+    kernel as it rests, by a bitcast, and the kernel's result is the
+    binned table as the step reads it, by another: nothing of the
+    table's size is copied, padded, transposed or fused, on one chip
+    and on a member of four."""
+    compiled = raw_programs[which]
     text = compiled.as_text()
-    resting = r"\[1,%d,%d\]\{1,2,0:T\(8,128\)\}" % (WIDE_ROWS, WIDE_F)
-    assert re.search(r"f32%s parameter\(0\)" % resting, text)
-    # one pass: the bins are the root of a fusion that reads the floats,
-    # and the program's scope is on it
-    assert re.search(r"ROOT \S+ = s32%s fusion\(" % resting, text)
-    assert "bin.transform" in text
-    assert not re.search(
-        r"= \w+\[[\d,]*\d{6,},[\d,]*\d{3,}[\d,]*\]\S* "
-        r"(copy|pad|transpose|while)\(", text)
+    rows = -(-WIDE_ROWS // chips)
+    resting = r"\[1,%d,%d\]\{1,2,0:T\(8,128\)\}" % (rows, WIDE_F)
+    kernel = r"\[%d,%d\]\{1,0:T\(8,128\)\}" % (WIDE_F, rows)
+    (floats,) = re.findall(r"(%%\S+) = f32%s parameter\(0\)" % resting, text)
+    call, operand = _kernel_call(text)
+    assert re.search(r"= s32%s custom-call\(" % kernel, call)
+    assert "bin.transform/mp4j_bin" in call
+    assert re.search(r"= f32%s bitcast\(%s\)" % (kernel, re.escape(floats)),
+                     operand), operand
+    assert re.search(r"ROOT \S+ = s32%s bitcast\(%%mp4j_bin" % resting, text)
+    assert text.count("tpu_custom_call") == 1
+    made = re.findall(
+        r"= \w+\[[\d,]*\d{6,}[\d,]*\]\S* (\w[\w-]*)\(", text)
+    assert sorted(made) == ["bitcast", "bitcast", "custom-call",
+                            "parameter"], made
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < WIDE_TABLE_BYTES // 1000
-    assert mem.output_size_in_bytes < WIDE_TABLE_BYTES * 1.001
+    assert mem.temp_size_in_bytes < WIDE_TABLE_BYTES // chips // 1000
+    assert mem.output_size_in_bytes < WIDE_TABLE_BYTES // chips * 1.001
+
+
+def test_kernel_call_detector():
+    """The detector reads the lines the compiled text holds, and a copy
+    that fed the kernel would be the operand it reports."""
+    text = """
+  %bitcast.1 = f32[968,1183747]{1,0:T(8,128)} bitcast(%X.1), metadata={op_name="jit(program)/bin.transform/transpose"}
+  %mp4j_bin.1 = s32[968,1183747]{1,0:T(8,128)} custom-call(%bitcast.1, %concatenate.1), custom_call_target="tpu_custom_call", operand_layout_constraints={f32[968,1183747]{1,0}, f32[968,256]{1,0}}
+"""
+    call, operand = _kernel_call(text)
+    assert call.startswith("%mp4j_bin.1 = s32[968,1183747]")
+    assert operand.startswith("%bitcast.1 = f32[968,1183747]")
+    relaid = text.replace("bitcast(%X.1)", "copy(%X.1)")
+    assert " copy(" in _kernel_call(relaid)[1]
 
 
 def test_sketch_holds_a_block_of_columns_beside_the_table(raw_programs):
@@ -1430,6 +1486,7 @@ def test_float_scoring_programs_bin_a_piece_beside_the_resident_table(
     that materialised the whole table's bins would fail here first.
     Compiled with 64-bit types off, as the cell and every user who has
     not asked for them run it (``tests/conftest.py`` turns them on)."""
+    from ytk_mp4j_tpu.models import binning
     from ytk_mp4j_tpu.models.gbdt import score_group_size
 
     mesh = Mesh(np.asarray(topo_devices[:1]), ("mp4j",))
@@ -1446,18 +1503,22 @@ def test_float_scoring_programs_bin_a_piece_beside_the_resident_table(
                     for d in (jnp.int32, jnp.int32, jnp.int32, jnp.float32))
     table = r"f32\[1,%d,%d\]\{1,2,0:T\(8,128\)\}" % (SCORE_ROWS, WIDE_F)
     for piece in (RAW_PIECE_ROWS, RAW_SCORE_LAST_ROWS):
-        with jax.enable_x64(False):
+        with jax.enable_x64(False), pytest.MonkeyPatch.context() as patch:
+            # as on a TPU: the backend here is the CPU
+            patch.setattr(binning, "_kernel_compiles", lambda: True)
             compiled, built = _float_score(trainer, piece, stacked, rows,
                                            whole)
         assert built == {"key": "gbdt_score_raw", "edges": B - 2,
+                         "compares": 8, "bin_block_columns": 8,
+                         "bin_block_rows": 4096,
                          "form": "bins", "group": group, "rows": piece,
                          "row_chunk": piece, "row_chunks": 1}
         mem = compiled.memory_analysis()
         assert mem.argument_size_in_bytes >= SCORE_ROWS * WIDE_F * 4
         # a piece's floats as they were sliced out to rest, its counts
-        # (int32, where XLA cuts the chain of 254 compares in two) and
-        # its bf16 digits: 10 bytes a cell of the piece, 0.32 GB at
-        # 32,768 rows; and the margins are updated where they rest
+        # (int32, the kernel's) and its bf16 digits: 10 bytes a cell of
+        # the piece, 0.32 GB at 32,768 rows; and the margins are updated
+        # where they rest
         assert mem.temp_size_in_bytes < 3 * piece * WIDE_F * 4, piece
         text = compiled.as_text()
         assert "input_output_alias" in text
@@ -1468,14 +1529,26 @@ def test_float_scoring_programs_bin_a_piece_beside_the_resident_table(
             r"= \w+\[[\d,]*\d{6,},[\d,]*\d{3,}[\d,]*\]\S* (\w[\w-]*)\(",
             text)
         assert set(made) <= {"parameter"}, made
-        # a piece's floats rest once before they are compared (the
-        # slice fused into the chain of compares is four times slower
-        # on the chip), and the compares read that copy
-        assert len(re.findall(r"= f32\[%d,%d\]\S* (?:fusion|copy)\("
-                              % (piece, WIDE_F), text)) == 1
-        assert re.search(r"= bf16\[%d,%d\]\{1,0:T\(8,128\)\(2,1\)\} fusion\("
-                         % (WIDE_F, piece), text)
-        assert "bin.transform" in text
+        # a piece's floats are sliced out once, as they rest ([F, rows],
+        # rows along the lanes), and that is what the kernel reads; its
+        # counts are what the digits are made of: the piece is never
+        # transposed, before the kernel or after it
+        call, operand = _kernel_call(text)
+        resting = r"\[%d,%d\]\{1,0:T\(8,128\)" % (WIDE_F, piece)
+        assert re.search(r"= s32%s\S* custom-call\(" % resting, call)
+        assert "bin.transform/mp4j_bin" in call
+        assert re.search(r"= f32%s\S* fusion\(" % resting, operand), operand
+        assert "dynamic-slice" in operand
+        assert len(re.findall(
+            r"= f32\[(?:%d,%d|%d,%d)\]\S* (?:fusion|copy|transpose)\("
+            % (piece, WIDE_F, WIDE_F, piece), text)) == 1
+        assert re.search(
+            r"= bf16%s\(2,1\)\S* (?:fusion|convert)\(%%mp4j_bin" % resting, text)
+        assert not re.search(r"\[%d,%d\]\S* (?:copy|transpose)\("
+                             % (piece, WIDE_F), text)
+        assert not re.search(r"\[%d,%d\]\S* (?:copy|transpose)\("
+                             % (WIDE_F, piece), text)
         assert "gbdt.score.select/dot_general" in text
         assert "gbdt.score.walk" in text
-        assert " gather(" not in text and "tpu_custom_call" not in text
+        assert " gather(" not in text
+        assert text.count("tpu_custom_call") == 1

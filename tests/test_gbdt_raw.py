@@ -290,7 +290,8 @@ def test_the_front_ends_spans_and_what_crossed(monkeypatch):
     assert raw[6] == {"job": 1, "rows": 512, "chunks": 2,
                       "bytes": 4 * 512 * (F + 1)}
     assert fit[6] == {"sample_rows": 512, "columns": F, "blocks": 3}
-    assert transform[6] == {"rows": 512, "columns": F, "compares": 254}
+    # 254 edges a column: eight levels of the search a cell
+    assert transform[6] == {"rows": 512, "columns": F, "compares": 8}
     puts = named("mp4j.put_sharded")
     assert [p[6]["bytes"] for p in puts] == [4 * 512 * F] + [4 * 512] * 3
     assert inside(puts[0], raw) and all(inside(p, stage) for p in puts)
@@ -321,8 +322,9 @@ def test_one_step_serves_both_entries(first):
         placed.shape, placed.dtype, placed.sharding)
 
 
-def test_more_edges_than_a_chain_count_alike(rng):
-    """n_bins above 257 take a loop of chains of compares."""
+def test_1000_bins_count_alike(rng):
+    """n_bins above 256: 998 edges are ten levels of the search, the
+    deep ones of more than one register of nodes."""
     X = rng.standard_normal((400, 3)).astype(np.float32)
     X[::9, 1] = np.nan
     b = QuantileBinner(1000).fit(X, sample=None)
@@ -351,9 +353,9 @@ def test_transform_at_968_columns_goes_by_bytes(monkeypatch, chunk_rows,
         monkeypatch.setattr(QuantileBinner, "_TRANSFORM_CHUNK_BYTES",
                             chunk_rows * 968 * 4)
     shapes = []
-    program = binning._transform_program(True)
+    program = binning._transform_program(True, 254)
     monkeypatch.setattr(
-        binning, "_transform_program", lambda shift: (
+        binning, "_transform_program", lambda shift, n_edges: (
             lambda X, edges: shapes.append(X.shape) or program(X, edges)))
     bins = b.transform(X)
     assert len(shapes) == dispatches and len(set(shapes)) == 1
@@ -362,15 +364,18 @@ def test_transform_at_968_columns_goes_by_bytes(monkeypatch, chunk_rows,
 
 
 def test_there_is_one_compare_count():
-    """``grep`` finds one compare-count program in ``models/binning.py``:
-    ``transform`` (host arrays) and ``transform_staged`` (the raw path)
-    both run ``_count_edges``."""
+    """``grep`` finds one compare-count program in ``models/binning.py``
+    and one walk under it: ``transform`` (host arrays) and
+    ``transform_staged`` (the raw path) both run ``_count_edges``, whose
+    two backends both run ``bin_kernel.upper_bound``."""
     import inspect
 
     from ytk_mp4j_tpu.models import binning
+    from ytk_mp4j_tpu.ops import bin_kernel
 
     source = inspect.getsource(binning)
-    assert source.count("(X >= edge)") == 1
+    assert ">=" not in inspect.getsource(binning._count_edges)
+    assert inspect.getsource(bin_kernel).count("x >= probe(") == 1
     assert source.count("def _count_edges") == 1
     assert "_count_edges(X, edges, shift)" in inspect.getsource(
         binning._transform_program)

@@ -216,9 +216,9 @@ def test_the_transform_bins_by_the_rule(trouble):
         reference.MARGIN_REL_ERR
 
 
-def test_more_edges_than_one_chain_and_two_digit_bins():
-    """512 bins: 510 edges take two chains of compares, a bin takes two
-    bf16 digits."""
+def test_512_bins_search_nine_levels_and_take_two_digits():
+    """512 bins: 510 edges are nine levels of the search, the last of
+    two registers of nodes, and a bin takes two bf16 digits."""
     cfg = _cfg(n_bins=512)
     rng = np.random.default_rng(1)
     X = rng.standard_normal((ROWS, F)).astype(np.float32)
@@ -350,10 +350,12 @@ def test_a_second_call_of_the_same_shape_builds_nothing(job):
     # the last shard's data: rows 753..1002 in one piece, then its
     # padding row, a program each
     assert scoring == [
-        {"key": "gbdt_score_raw", "edges": 30, "form": "bins", "group": 12,
-         "rows": 250, "row_chunk": 250, "row_chunks": 1},
-        {"key": "gbdt_score_raw", "edges": 30, "form": "bins", "group": 12,
-         "rows": 1, "row_chunk": 1, "row_chunks": 1}]
+        {"key": "gbdt_score_raw", "edges": 30, "compares": 5,
+         "bin_block_columns": 8, "bin_block_rows": 4096, "form": "bins",
+         "group": 12, "rows": 250, "row_chunk": 250, "row_chunks": 1},
+        {"key": "gbdt_score_raw", "edges": 30, "compares": 5,
+         "bin_block_columns": 8, "bin_block_rows": 4096, "form": "bins",
+         "group": 12, "rows": 1, "row_chunk": 1, "row_chunks": 1}]
     assert list(tr._score_programs) == [
         ((4, 251, F), 250, ROUNDS, (30, True)),
         ((4, 251, F), 1, ROUNDS, (30, True))]
@@ -407,4 +409,7 @@ def test_scopes_of_the_float_scoring_program():
         jax.ShapeDtypeStruct((F, 30), jnp.float32)).as_text(debug_info=True)
     for scope in ("bin.transform", "gbdt.score.select", "gbdt.score.walk"):
         assert scope in text, scope
-    assert "gbdt.route" not in text and "gather" not in text
+    # off the TPU the only gathers are the search's probes, one a level
+    # of 30 edges (on it they are inside the kernel, tests/test_gbdt_aot)
+    assert "gbdt.route" not in text
+    assert text.count('"stablehlo.gather"(') == 5

@@ -3,10 +3,13 @@
 The reference's GBDT consumer (ytk-learn) bins continuous features into
 <=256 quantile buckets before histogram building; this is that front
 end rebuilt TPU-first. Bin edges are the order statistics of (a row
-sample of) the data; the transform is a one-hot-free comparison count —
-``bin(x) = #edges <= x`` — which is N*F*B VPU lane-ops, the same shape
-as one histogram level, and avoids the serial gather unit a searchsorted
-would use (``_count_edges``: the one compare-count there is).
+sample of) the data; the transform is the comparison count ``bin(x) =
+#edges <= x``, found by upper-bound search in a column's sorted edges:
+8 probes a cell at 254 edges (``_count_edges``: the one compare-count
+there is). On a TPU it is the Mosaic kernel ``mp4j_bin``
+(``ops/bin_kernel.py``), whose probe is a gather inside a vector
+register and not the serial gather unit XLA's searchsorted would use;
+elsewhere the same walk in ``jnp``.
 
 What runs where. ``fit`` takes a host array and fits on the host
 (``np.nanquantile``, a column at a time; the weighted and the
@@ -56,6 +59,7 @@ import jax.numpy as jnp
 
 from ytk_mp4j_tpu.exceptions import Mp4jError
 from ytk_mp4j_tpu.obs import spans
+from ytk_mp4j_tpu.ops import bin_kernel
 
 
 class FeatureSketch(NamedTuple):
@@ -578,9 +582,9 @@ class QuantileBinner:
         return self
 
     # Bytes of a host table that cross at a time in ``transform``: the
-    # compare-count holds nothing of [rows, F, edges], so a chunk is
-    # sized by the link and not by the number of edges (the guard it
-    # replaces cut 968 columns into chunks of 272 rows).
+    # search holds nothing of [rows, F, edges], so a chunk is sized by
+    # the link and not by the number of edges (the guard it replaces cut
+    # 968 columns into chunks of 272 rows).
     _TRANSFORM_CHUNK_BYTES = 64 << 20
 
     def transform(self, X) -> np.ndarray:
@@ -596,14 +600,15 @@ class QuantileBinner:
         after every edge. Under ``missing_bucket`` finite values land
         in [1, n_bins) and bin 0 is EXACTLY the NaN set.
 
-        The rows go through the device's compare-count
-        (``_count_edges``, the program ``transform_staged`` runs) in
-        chunks of ``_TRANSFORM_CHUNK_BYTES``, one dispatch where the
-        table is smaller; a chunk's bins are fetched while the next
-        chunk is binned."""
+        The rows go through the device's search (``_count_edges``,
+        the program ``transform_staged`` runs) in chunks of
+        ``_TRANSFORM_CHUNK_BYTES``, one dispatch where the table is
+        smaller; a chunk's bins are fetched while the next chunk is
+        binned."""
         X = np.asarray(X, np.float32)
         self._check_width(X.shape, X.ndim == 2)
-        program = _transform_program(bool(self.missing_bucket))
+        program = _transform_program(bool(self.missing_bucket),
+                                     self.edges.shape[1])
         edges = jnp.asarray(self.edges)
         rows = max(1, self._TRANSFORM_CHUNK_BYTES // (4 * X.shape[1]))
         if X.shape[0] <= rows:
@@ -627,15 +632,18 @@ class QuantileBinner:
         sharded) into int32 bins that rest there likewise: same shape,
         same sharding, and the layout a placed host array has, which is
         what ``GBDTTrainer``'s step takes from ``shard_bins``. One
-        program over the whole table, launched and not waited for;
-        nothing visits the host. The span says how many compares a cell
-        the program issues (``compares``: one an edge)."""
+        program over the whole table (on a TPU one ``mp4j_bin`` kernel,
+        whose grid is the loop over its blocks), every member binning
+        its own rows, launched and not waited for; nothing visits the
+        host. The span says how many compares a cell the program issues
+        (``compares``: one a level of the search, 8 at 254 edges)."""
         self._check_width(table.shape, table.ndim in (2, 3))
         with spans.span("mp4j.bin.transform", columns=table.shape[-1],
                         rows=int(np.prod(table.shape[:-1])),
-                        compares=self.edges.shape[1]):
+                        compares=bin_kernel.search_steps(
+                            self.edges.shape[1])):
             return _transform_program(
-                bool(self.missing_bucket),
+                bool(self.missing_bucket), self.edges.shape[1],
                 table.sharding if table.ndim == 3 else None)(
                     table, self.edges)
 
@@ -685,9 +693,6 @@ def _whole(sharding):
 # 1.449 at 8. A block and its sorted copy are all the device holds
 # beside the table (76 MB at that size).
 _SKETCH_COLUMNS = 8
-# Edges compared in one unrolled chain; more (n_bins above 257) take a
-# loop of such chains, each a pass over the table.
-_EDGE_CHAIN = 256
 
 
 def _sketch(sample, nb: int, keep):
@@ -785,45 +790,51 @@ def _sketch_program(nb: int, sharding):
         return jax.jit(program, out_shardings=_whole(sharding))
 
 
+def _kernel_compiles() -> bool:
+    """Whether ``_count_edges`` is being built for a TPU, where its
+    search is the Mosaic kernel (a test that compiles for a described
+    chip answers for it)."""
+    return jax.default_backend() == "tpu"
+
+
 def _count_edges(X, edges, shift: bool):
-    """bin = #edges <= x, as a chain of compares against one edge of
-    every feature at a time: one elementwise pass over ``X`` [..., F]
-    on any backend (a reduction over a broadcast [rows, F, edges]
-    operand is materialised by the CPU backend), off the serial gather
-    unit a searchsorted would use. With ``shift`` (the reserved missing
-    bucket) finite values move up to [1, B) and NaN — for which every
-    comparison is False — stays the SOLE occupant of bin 0."""
-    n_edges = edges.shape[1]
-    chain = min(n_edges, _EDGE_CHAIN)
-    links = -(-n_edges // chain)
-    # an edge of NaN is below nothing: the last chain's padding
-    by_edge = jnp.pad(edges.T, ((0, links * chain - n_edges), (0, 0)),
-                      constant_values=jnp.nan).reshape(links, chain, -1)
-
-    def link(k, count):
-        for edge in by_edge[k]:
-            count = count + (X >= edge).astype(jnp.int32)
-        return count
-
-    # the first chain outside the loop: its count is X's own (inside a
-    # ``shard_map`` it varies over the mesh as X does, which a loop's
-    # carry has to from the start)
-    count = link(0, jnp.zeros(X.shape, jnp.int32))
-    if links > 1:
-        count = jax.lax.fori_loop(1, links, link, count)
-    return jnp.where(jnp.isnan(X), 0, count + 1) if shift else count
+    """bin = #edges <= x for every cell of ``X`` [..., F] against its
+    column's ``edges`` [F, E], by upper-bound search: the edges sorted
+    here and ``bin_kernel.search_steps(E)`` probes a cell (8 at 254
+    edges). On a TPU one ``mp4j_bin`` kernel over the table as it rests
+    (``ops/bin_kernel.py``: the probe is a gather inside a vector
+    register, off the serial gather unit XLA's searchsorted would use);
+    elsewhere the same walk in ``jnp`` (interpreted Pallas inside a
+    ``shard_map`` trips ``check_vma``). With ``shift`` (the reserved
+    missing bucket) finite values move up to [1, B) and NaN, for which
+    every comparison is False, stays the SOLE occupant of bin 0."""
+    if _kernel_compiles():
+        return bin_kernel.pallas_bin_counts(
+            X.reshape((-1, X.shape[-1])), edges, shift).reshape(X.shape)
+    nodes = bin_kernel.search_table(edges).T
+    column = jnp.arange(X.shape[-1])
+    return bin_kernel.upper_bound(
+        X, lambda k, i, went: nodes[i, column],
+        bin_kernel.search_steps(edges.shape[1]), shift)
 
 
 @lru_cache(maxsize=None)
-def _transform_program(shift: bool, sharding=None):
-    """The jitted transform, of a table on a device or (``sharding``
-    given) of a staged one, whose bins rest as the floats did."""
+def _transform_program(shift: bool, n_edges: int, sharding=None):
+    """The jitted transform by ``n_edges`` edges a column, of a table on
+    a device or (``sharding`` given) of a staged one, every member
+    binning its own rows; the bins rest as the floats did. The build
+    span says which block of the table the TPU's kernel takes at a
+    time."""
     def program(X, edges):
         with jax.named_scope("bin.transform"):
             return _count_edges(X, edges, shift)
 
-    placed = ({} if sharding is None else
-              {"in_shardings": (sharding, _whole(sharding)),
-               "out_shardings": sharding})
-    with spans.span("mp4j.step.build", key="bin_transform", shift=shift):
-        return jax.jit(program, **placed)
+    columns, rows = bin_kernel.bin_blocks(n_edges)
+    with spans.span("mp4j.step.build", key="bin_transform", shift=shift,
+                    bin_block_columns=columns, bin_block_rows=rows):
+        if sharding is None:
+            return jax.jit(program)
+        return jax.jit(jax.shard_map(
+            program, mesh=sharding.mesh,
+            in_specs=(sharding.spec, jax.sharding.PartitionSpec()),
+            out_specs=sharding.spec))

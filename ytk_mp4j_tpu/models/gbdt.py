@@ -40,6 +40,7 @@ from ytk_mp4j_tpu.models._base import (DataParallelTrainer, EarlyStopper,
 from ytk_mp4j_tpu.exceptions import Mp4jError
 from ytk_mp4j_tpu.models.binning import _count_edges
 from ytk_mp4j_tpu.obs import spans
+from ytk_mp4j_tpu.ops.bin_kernel import bin_blocks, search_steps
 from ytk_mp4j_tpu.ops.hist_kernel import _rests_tiled, split_bf16
 
 
@@ -855,11 +856,11 @@ def score_shard(bins, stacked, out, start, rows: int, cfg: GBDTConfig,
 
     With ``edges`` ([F, E] f32) the table is one of floats, NaN where a
     cell is empty, and a chunk's rows are sliced out, to rest on their
-    own, and binned there (``binning._count_edges``, ``shift``: the
-    binner's reserved missing bucket) under the scope ``bin.transform``:
-    a chunk's floats and bins exist for the length of its turn, the bins
-    as the bf16 digits the select reads, and the margins are those of
-    the bins' table."""
+    own, and binned there (``binning._count_edges``: on a TPU the
+    ``mp4j_bin`` kernel; ``shift``: the binner's reserved missing
+    bucket) under the scope ``bin.transform``: a chunk's floats and bins
+    exist for the length of its turn, the bins as the bf16 digits the
+    select reads, and the margins are those of the bins' table."""
     F = bins.shape[1]
     C = out.shape[0]
     n_digits = _bin_digits(cfg.n_bins)
@@ -872,14 +873,11 @@ def score_shard(bins, stacked, out, start, rows: int, cfg: GBDTConfig,
             part = lax.dynamic_slice(bins, (at, jnp.int32(0)),
                                      (row_chunk, F))
             if edges is not None:
-                # the sliced floats rest before they are compared: fused
-                # into the chain of compares, a slice at a row the
-                # program is told (rows lie along the lanes) makes the
-                # chain 21 ms a piece of 32,768 x 968 where it is 5 on a
-                # piece that rests, and the copy is 0.3 (my chip runs,
-                # PR 47: 37.7 against 21.8 ms a piece, 500 trees)
-                part = _count_edges(lax.optimization_barrier(part), edges,
-                                    shift)
+                # the slice rests on its own before the kernel reads it
+                # (a custom call's operand: nothing fuses into it), and
+                # the counts come back as [F, rows] rest, so the
+                # transposition below is the kernel's own undone
+                part = _count_edges(part, edges, shift)
             part = part.T
             digits = [((part >> (8 * k)) & 255 if n_digits > 1 else part
                        ).astype(jnp.bfloat16) for k in range(n_digits)]
@@ -1376,7 +1374,7 @@ class GBDTTrainer(DataParallelTrainer):
         replicated."""
         cfg = self.cfg
         axes = self.axes
-        compares, shift = binning or (None, False)
+        n_edges, shift = binning or (None, False)
         specs = (P(axes), P(), P(axes), P()) + (P(),) * (binning is not None)
 
         @partial(jax.shard_map, mesh=self.mesh, in_specs=specs,
@@ -1388,10 +1386,16 @@ class GBDTTrainer(DataParallelTrainer):
         n_classes = cfg.n_classes if cfg.loss == "softmax" else 1
         row_chunk, chunks = score_row_chunks(rows)
         # a float table's program says how many compares a cell it
-        # issues, and that it bins before it selects (no float select)
-        said = ({"key": "gbdt_score"} if binning is None else
-                {"key": "gbdt_score_raw", "edges": compares,
-                 "form": "bins"})
+        # issues for that many edges, which block of a piece the TPU's
+        # kernel takes at a time, and that it bins before it selects (no
+        # float select)
+        said = {"key": "gbdt_score"}
+        if binning is not None:
+            columns, block_rows = bin_blocks(n_edges)
+            said = {"key": "gbdt_score_raw", "edges": n_edges,
+                    "compares": search_steps(n_edges),
+                    "bin_block_columns": columns,
+                    "bin_block_rows": block_rows, "form": "bins"}
         with spans.span("mp4j.step.build", **said,
                         group=score_group_size(rounds, n_classes),
                         rows=rows, row_chunk=row_chunk, row_chunks=chunks):
