@@ -62,6 +62,7 @@ class Adapter:
         self.config, self.traffic, self.seed = config, traffic, seed
         self.devices, self.spans = devices, spans
         self.last_job = None        # (margins, span cursor at its start)
+        self.compares = None        # a cell's, as the program's build says
 
     def setup(self):
         # before the 4.58 GB table is drawn: a checkout without the
@@ -108,9 +109,17 @@ class Adapter:
         """One whole job: it compiles the placers and both scoring
         programs (a whole piece's and the last chunk's) and fills the
         trainer's cache of the stacked ensemble."""
+        cursor = _now()
         with self.spans.span("gbdt.warmup_job"):
             self.trainer.predict_raw_chunks(self._reader(), len(self.X),
                                             self.trees)
+        # what the program says it issues a cell, beside the roofline's
+        # bytes, which do not depend on it (``adapters/gbdt_raw.py``
+        # reads the same argument off ``mp4j.bin.transform``)
+        self.compares = next(
+            (s[6]["compares"] for s in program_spans.take_since(cursor)[1]
+             if s[0] == "mp4j.step.build"
+             and s[6].get("key") == "gbdt_score_raw"), None)
 
     def _job(self):
         cursor = _now()
@@ -145,19 +154,20 @@ class Adapter:
                    / max(jobs, 1) for phase, name in PHASES.items()}
         self.builds_in_window = sum(s[0] == "mp4j.step.build"
                                     for s in recorded)
+        counters = {
+            "jobs": jobs, "rows": rows,
+            "chunks": jobs * -(-len(self.X) // c["chunk_rows"]),
+            "trees": jobs * len(self.trees), "elapsed_s": elapsed,
+            "step_builds_in_window": self.builds_in_window,
+            "transform_least_bytes_per_job":
+                arith_raw.transform_least_bytes(c["rows"], c["n_features"])}
+        if self.compares is not None:
+            counters["transform_compares_per_job"] = (
+                arith_raw.transform_compares(c["rows"], c["n_features"],
+                                             self.compares))
         return {"attempted": attempted, "failed": failed,
                 "metrics": {"rows_per_s": rows / elapsed},
-                "counters": {
-                    "jobs": jobs, "rows": rows,
-                    "chunks": jobs * -(-len(self.X) // c["chunk_rows"]),
-                    "trees": jobs * len(self.trees), "elapsed_s": elapsed,
-                    "step_builds_in_window": self.builds_in_window,
-                    "transform_compares_per_job":
-                        arith_raw.transform_compares(
-                            c["rows"], c["n_features"], self.edges.shape[1]),
-                    "transform_least_bytes_per_job":
-                        arith_raw.transform_least_bytes(
-                            c["rows"], c["n_features"])},
+                "counters": counters,
                 "log": {"job_secs": job_secs, "host_ms_per_job": host_ms}}
 
     def window(self, seconds: float) -> dict:

@@ -15,7 +15,9 @@ def transform_least_bytes(rows: int, n_features: int) -> float:
 
 
 def transform_compares(rows: int, n_features: int, n_edges: int) -> float:
-    """Compares the plain compare-count issues: one a cell and edge. A
-    fact for the run's log beside the share of the roofline, which does
-    not depend on it."""
+    """Compares a transform issues that pays ``n_edges`` a cell: the
+    plain compare-count pays one an edge (254), the search the program
+    has run since PR 48 one a level (8), and the adapters pass what the
+    program's own span says. A fact for the run's log beside the share
+    of the roofline, which does not depend on it."""
     return float(rows) * n_features * n_edges

@@ -19,12 +19,16 @@ from conftest import ROOT
 
 CELL = "gbdt-bosch-968-raw.train-raw-chunks"
 LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
-RAW = ["raw_stage_ms_per_job", "raw_stage_gbps", "raw_sketch_ms_per_job",
+# what the cell must report (it may report more)
+RAW = {"raw_stage_ms_per_job", "raw_sketch_ms_per_job",
        "raw_sketch_device_ms_per_job", "raw_transform_ms_per_job",
-       "raw_transform_roofline", "raw_hist_ms_per_tree",
-       "raw_fetch_wait_ms_per_job", "raw_device_idle_share",
-       "raw_peak_hbm_gb", "raw_compile_s", "raw_compiles_in_window",
-       "raw_step_builds_in_window"]
+       "raw_transform_roofline", "gbdt_hist_ms_per_tree",
+       "gbdt_fetch_wait_ms_per_job", "gbdt_device_idle_share",
+       "peak_hbm_gb", "compile_s", "compiles_in_window",
+       "step_builds_in_window", "gbdt_route_ms_per_tree",
+       "gbdt_split_leaf_ms_per_tree", "gbdt_hist_glue_ms_per_tree",
+       "gbdt_stage_ms_per_job", "gbdt_dispatch_ms_per_tree",
+       "stage_link_wait_ms_per_job", "gbdt_step_mfu"}
 # rows that no chunk size of the toy divides: seven chunks, the last short
 TOY = dict(rows=3001, n_features=200, depth=4, n_trees=2, bin_sample=2000,
            chunk_rows=448)
@@ -61,8 +65,12 @@ def test_the_cell_reports_trees_per_s_and_its_own_layer_metrics():
     assert cell.chips == 1 and cell.adapter_name == "gbdt_raw"
     assert [m["name"] for m in cell.end_to_end] == ["trees_per_s", "setup_s"]
     names = [m["name"] for m in cell.per_layer]
-    assert set(RAW) <= set(names)
-    assert not any(n.startswith("bosch_") for n in names)
+    assert RAW <= set(names)
+    # the kernel's roofline is read by the kernel's own name since PR 49;
+    # its outside twin, by the custom call's target, would count this
+    # cell's second Mosaic kernel, mp4j_bin, in
+    assert "hist_kernel_roofline" in names
+    assert "hist_kernel_ms_per_tree" not in names
     for m in cell.per_layer:
         assert m["spec"]["name"] == m["name"]
         for key in ("layer", "moves", "source"):
@@ -90,7 +98,7 @@ def test_the_accepted_gbdt_cells_report_none_of_this_cells_metrics():
     for name in ("gbdt-higgs-11m.train", "gbdt-bosch-968.train",
                  "gbdt-bosch-score-500.batch"):
         got = {m["name"] for m in cells.load_cell(ROOT, name).per_layer}
-        assert not any(n.startswith("raw_") for n in got)
+        assert not any(n.startswith(("raw_", "rawscore_")) for n in got)
 
 
 def test_untraced_run(capsys, toy_root):
@@ -129,7 +137,9 @@ def test_untraced_run(capsys, toy_root):
             == 4 * 3001 * 200 + 3 * 4 * 3001)
     counters = window["counters"]
     assert counters["trees"] == 2 * counters["jobs"]
-    assert counters["transform_compares_per_job"] == 3001 * 200 * 254
+    # what the program's ``mp4j.bin.transform`` span says it issues a
+    # cell: a step a level of the search in 254 edges
+    assert counters["transform_compares_per_job"] == 3001 * 200 * 8
     assert counters["transform_least_bytes_per_job"] == 8 * 3001 * 200
 
 
@@ -141,12 +151,13 @@ def test_traced_run(capsys, toy_root):
     assert line["correct"] is True
     # the CPU's trace has no device plane: the trace readers find nothing
     # and their metrics are left out; counters and host spans are there
-    assert {"raw_compile_s", "raw_compiles_in_window",
-            "raw_step_builds_in_window", "raw_peak_hbm_gb",
-            "raw_stage_ms_per_job", "raw_sketch_ms_per_job",
-            "raw_fetch_wait_ms_per_job"} <= set(line["metrics"])
-    assert line["metrics"]["raw_compiles_in_window"]["value"] == 0
-    assert line["metrics"]["raw_step_builds_in_window"]["value"] == 0
+    assert {"compile_s", "compiles_in_window", "step_builds_in_window",
+            "peak_hbm_gb", "raw_stage_ms_per_job", "raw_sketch_ms_per_job",
+            "gbdt_fetch_wait_ms_per_job", "gbdt_stage_ms_per_job",
+            "gbdt_dispatch_ms_per_tree",
+            "gbdt_step_mfu"} <= set(line["metrics"])
+    assert line["metrics"]["compiles_in_window"]["value"] == 0
+    assert line["metrics"]["step_builds_in_window"]["value"] == 0
 
 
 def test_a_program_without_the_entry_point_is_refused_at_once(

@@ -344,19 +344,10 @@ def test_new_metrics_are_declared_alike_in_both_places():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     declared = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"]][-len(NEW):] == [
-        "gbdt_stage_ms_per_job", "gbdt_dispatch_ms_per_tree",
-        "gbdt_fetch_wait_ms_per_job", "gbdt_hist_ms_per_tree",
-        "gbdt_bins_relayout_ms_per_tree", "gbdt_route_ms_per_tree",
-        "gbdt_split_leaf_ms_per_tree", "ffm_stage_ms_per_chunk",
-        "ffm_dispatch_ms_per_chunk", "ffm_throttle_wait_ms_per_chunk",
-        "ffm_table_update_ms_per_chunk", "ffm_table_gather_ms_per_chunk",
-        "collective_scope_us_per_tree", "step_builds_in_window"]
-    layers = {m["layer"] for m in bench["per_layer"][:-len(NEW)]}
-    adapters = {w["name"]: next(
-        json.load(open(os.path.join(ROOT, c["file"])))["adapter"]
-        for c in bench["configs"] if c["name"] == w["config"])
-        for w in bench["workloads"]}
+    # declared, wherever later PRs have put them in the list; no file
+    # filters on adapters since PR 49: the entry's cells say who reads it
+    assert set(NEW) <= set(declared)
+    layers = {m["layer"] for m in bench["per_layer"] if m["name"] not in NEW}
     for name in NEW:
         spec, entry = _spec(name), declared[name]
         assert spec["name"] == name
@@ -365,9 +356,7 @@ def test_new_metrics_are_declared_alike_in_both_places():
         assert entry["layer"] in layers and entry["better"] == "lower"
         assert os.path.isfile(os.path.join(
             ROOT, "benchmark", "readers", f"{spec['reader']}.py"))
-        # the cells that list it are the cells whose adapter reads it
-        assert sorted(adapters[w] for w in entry["workloads"]) \
-            == sorted(spec["adapters"])
+        assert "adapters" not in spec and entry["workloads"]
         for cell in entry["workloads"]:
             assert name in {m["name"] for m in
                             cells.load_cell(ROOT, cell).per_layer}
@@ -378,7 +367,8 @@ def test_new_metrics_are_declared_alike_in_both_places():
                               "gbdt_dispatch_ms_per_tree",
                               "gbdt_fetch_wait_ms_per_job"]),
     # the toy slice is 3 chunks with 2 in flight: the host never has to
-    # wait for the queue, so there is no throttle span to read
+    # wait for the queue, so the throttle wait reads 0 (a reading, since
+    # PR 49 merged the metric under ``trace_host_span_total``)
     ("ffm-criteo.stream-zipf", ["ffm_stage_ms_per_chunk",
                                 "ffm_dispatch_ms_per_chunk"]),
 ])
@@ -400,9 +390,10 @@ def test_traced_rehearsal_reports_the_programs_spans(capsys, tiny_root,
         assert line["metrics"][name]["unit"] == "ms"
     assert line["metrics"]["step_builds_in_window"] == {
         "value": 0.0, "unit": "builds"}
-    assert not [m for m in line["metrics"] if "_table_" in m
-                or m in ("gbdt_hist_ms_per_tree", "gbdt_route_ms_per_tree",
-                         "ffm_throttle_wait_ms_per_chunk")]
+    assert not [m for m in line["metrics"] if m.startswith("ffm_table_")
+                or m in ("gbdt_hist_ms_per_tree", "gbdt_route_ms_per_tree")]
+    if "ffm_throttle_wait_ms_per_chunk" in line["metrics"]:
+        assert line["metrics"]["ffm_throttle_wait_ms_per_chunk"]["value"] >= 0
     assert glob.glob(os.path.join(tiny_root, "benchmark", "out", "trace",
                                   workload, "plugins", "profile", "*",
                                   "*.xplane.pb"))
