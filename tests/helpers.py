@@ -1,6 +1,9 @@
 """Shared test helpers: numpy reference reductions, seeded input
 generation, and the master+slave-threads socket harness."""
 
+import base64
+import hashlib
+import re
 import threading
 from pathlib import Path
 
@@ -59,3 +62,43 @@ def run_slaves(n, fn, timeout=60.0, **slave_kwargs):
     master.join(timeout)
     assert master.final_code == 0
     return results
+
+
+def _kernel_without_locations(match) -> str:
+    """A Mosaic kernel's serialized module (``custom_call_config.body``,
+    MLIR bytecode in base64) as the digest of its text printed without
+    locations: the bytecode holds every operation's Python call stack,
+    which a ``with jax.named_scope(...)`` line in the caller changes and
+    the kernel's code does not depend on."""
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    ctx = ir.Context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        module = ir.Module.parse(base64.b64decode(match.group(1)))
+        asm = module.operation.get_asm(enable_debug_info=False)
+    return '"body":"sha256:%s"' % hashlib.sha256(asm.encode()).hexdigest()
+
+
+def program_without_provenance(text: str) -> str:
+    """An optimised module's text (``compiled.as_text()``) less what only
+    says where an instruction came from: every ``metadata={...}``, the
+    tables of files, functions and stack frames that ``stack_frame_id``
+    points into (the CPU compiler's text has them between the header and
+    the first computation), the locations inside a Mosaic kernel's body,
+    and the instructions' own names (one inlined from a jitted helper is
+    NAMED after its name stack: ``%jvp_jit_triu__.4`` under no scope,
+    ``%jit_triu_.4`` under ``jvp(ffm.pairs)``), each replaced by its
+    order of first appearance. Two programs with one such text are one
+    program."""
+    text = re.sub(r"\nFileNames\n.*?\nStackFrames\n.*?\n\n", "\n", text,
+                  count=1, flags=re.S)
+    text = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+    text = re.sub(r'"body":"([A-Za-z0-9+/=]+)"', _kernel_without_locations,
+                  text)
+    order: dict[str, str] = {}
+    return re.sub(r"%[\w.\-]+",
+                  lambda m: order.setdefault(m.group(0), f"%{len(order)}"),
+                  text)

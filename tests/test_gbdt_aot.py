@@ -1116,9 +1116,11 @@ def test_sharded_step_pays_for_what_a_chip_owns_and_touches(
     assert len(gathers) == len(updates) == p["chips"]   # one a member's list
     for ln in gathers:
         assert re.search(r"= f32\[512,256\]", ln), ln
-        assert "while/body/while/body" in ln, ln
+        # the exchange's round loop, then the walk over a member's list
+        # (since PR 50 under its own name)
+        assert "while/body/sparse.fold_live_tiles/while/body" in ln, ln
     for ln in updates:
-        assert "while/body/while/body" in ln, ln
+        assert "while/body/sparse.fold_live_tiles/while/body" in ln, ln
         assert "scatter-add" in ln, ln
     # the requester's own gathers and sums take its S slots, from lists
     # it holds itself, never from the table
@@ -1552,3 +1554,93 @@ def test_float_scoring_programs_bin_a_piece_beside_the_resident_table(
         assert "gbdt.score.walk" in text
         assert " gather(" not in text
         assert text.count("tpu_custom_call") == 1
+
+
+# ------------------------------------------------------------------------
+# ISSUE 50: the scopes a device trace reads the steps by are in the
+# programs compiled for the v5e at the cells' sizes, and they are metadata:
+# the fixtures above are the texts, and nothing here compiles for minutes.
+def _op_names(text, pattern=""):
+    return [m for m in re.findall(r'op_name="([^"]*)"', text)
+            if re.search(pattern, m)]
+
+
+@pytest.mark.parametrize("which", ["step_one_chip", "wide_step"])
+def test_every_kernel_call_says_its_level(request, which):
+    """What ``gbdt_hist_level0_ms_per_tree`` / ``..level5..`` find: each
+    of the six kernel calls under its own ``gbdt.level.<d>``, inside the
+    ``gbdt.hist`` the accepted metrics search for."""
+    text = request.getfixturevalue(which).as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert sorted(m for ln in calls for m in _op_names(ln)) == [
+        f"jit(step)/gbdt.level.{d}/gbdt.hist/mp4j_hist/pallas_call"
+        for d in range(DEPTH)]
+    for part in ("gbdt.route", "gbdt.best_splits"):
+        assert {m.split("/")[1] for m in _op_names(text, re.escape(part))} \
+            == {f"gbdt.level.{d}" for d in range(DEPTH)}
+    assert not _op_names(text, r"gbdt\.level\.\d/.*gbdt\.leaf")
+
+
+@pytest.mark.parametrize("which,inside_the_loop", [
+    ("ffm_programs", ["ffm.table_update"]),
+    ("adagrad_programs", ["ffm.table_gather", "ffm.adagrad_rule",
+                          "ffm.table_update"]),
+    ("sharded_programs", ["ffm.table_gather", "ffm.table_update"]),
+])
+def test_ffm_steps_say_select_pairs_and_backward(request, which,
+                                                 inside_the_loop):
+    """What ``ffm_select_ms_per_chunk``, ``ffm_pairs_ms_per_chunk`` and
+    ``ffm_backward_ms_per_chunk`` find, and the loop's own name round the
+    scopes the accepted metrics read."""
+    text = request.getfixturevalue(which)["step"].as_text()
+    for stack in ("jvp(ffm.select)", "transpose(jvp(ffm.select))",
+                  "jvp(ffm.pairs)", "transpose(jvp(ffm.pairs))"):
+        assert _op_names(text, rf"/{re.escape(stack)}/"), stack
+    for scope in inside_the_loop:
+        assert _op_names(
+            text, rf"sparse\.fold_live_tiles/while/body/{re.escape(scope)}")
+    assert not _op_names(text, r"ffm\.score\.")
+
+
+def test_scopes_change_nothing_of_the_step_compiled_for_the_chip(
+        step_one_chip, topo_devices, monkeypatch):
+    """``tests/test_trainer_scopes.py``'s comparison by the TPU's own
+    compiler: the GBDT step at 1M x 28 and both placers at the Bosch
+    cells' sizes, compiled once more with ``jax.named_scope`` a null
+    context, are the programs the fixtures hold. (By hand, PR 50, the same
+    held of the Bosch step and of the three FFM steps at their cells'
+    sizes: 32 to 100 s a compile, so not here.)"""
+    import contextlib
+
+    from tests.helpers import program_without_provenance as program
+
+    def placers():
+        mesh = Mesh(np.asarray(topo_devices[:1]), ("mp4j",))
+        rows, whole = NamedSharding(mesh, P("mp4j")), NamedSharding(mesh, P())
+        trainer = GBDTTrainer(GBDTConfig(n_features=WIDE_F, n_bins=B,
+                                         missing_bin=True), mesh=mesh)
+        piece = SCORE_CHUNK_ROWS * WIDE_F
+        wire = (RAW_PIECE_ROWS * WIDE_F // 128, 128)
+        yield trainer._build_row_placer((1, SCORE_CHUNK_ROWS, WIDE_F)).lower(
+            jax.ShapeDtypeStruct((1, WIDE_ROWS, WIDE_F), jnp.int32,
+                                 sharding=rows),
+            jax.ShapeDtypeStruct((1, piece // 128, 128), jnp.int32,
+                                 sharding=rows),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=whole)).compile()
+        yield trainer._row_chunk_placer(
+            WIDE_ROWS, WIDE_F, RAW_PIECE_ROWS, wire).lower(
+                jax.ShapeDtypeStruct((1, WIDE_ROWS, WIDE_F), jnp.float32,
+                                     sharding=rows),
+                jax.ShapeDtypeStruct(wire, jnp.float32, sharding=whole),
+                jax.ShapeDtypeStruct((), jnp.int32,
+                                     sharding=whole)).compile()
+
+    as_written = [step_one_chip.as_text()] + [c.as_text() for c in placers()]
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    without = ([_compile_step(topo_devices, 1).as_text()]
+               + [c.as_text() for c in placers()])
+    for a, b, scope in zip(as_written, without,
+                           ("gbdt.level.5", "stage.place", "stage.place")):
+        assert scope in a and scope not in b
+        assert program(a) == program(b), scope

@@ -453,16 +453,19 @@ class DataParallelTrainer:
         n, rows = shape[:2]
 
         def place(table, chunk, start):
-            if pad is not None:
-                words = [jax.lax.bitcast_convert_type(
-                    c.reshape(n, rows, -1), jnp.int32) for c in chunk]
-                words.append(jnp.zeros((n, rows, pad), jnp.int32))
-            at = [jnp.zeros((), start.dtype)] * table.ndim
-            at[1] = start
-            piece, first = ((chunk.reshape(shape), chunk) if pad is None
-                            else (jnp.concatenate(words, axis=2), chunk[0]))
-            return (jax.lax.dynamic_update_slice(table, piece, at),
-                    first.reshape(-1)[0])
+            # the device's side of ``mp4j.stage.place``, by name in a trace
+            with jax.named_scope("stage.place"):
+                if pad is not None:
+                    words = [jax.lax.bitcast_convert_type(
+                        c.reshape(n, rows, -1), jnp.int32) for c in chunk]
+                    words.append(jnp.zeros((n, rows, pad), jnp.int32))
+                at = [jnp.zeros((), start.dtype)] * table.ndim
+                at[1] = start
+                piece, first = (
+                    (chunk.reshape(shape), chunk) if pad is None
+                    else (jnp.concatenate(words, axis=2), chunk[0]))
+                return (jax.lax.dynamic_update_slice(table, piece, at),
+                        first.reshape(-1)[0])
         return jax.jit(place, donate_argnums=0,
                        out_shardings=(self._row_sharding(), None))
 
@@ -594,10 +597,11 @@ class DataParallelTrainer:
         place = self._row_placers.get(key)
         if place is None:
             def place(table, chunk, start):
-                zero = jnp.zeros((), start.dtype)
-                return (jax.lax.dynamic_update_slice(
-                    table, chunk.reshape(1, rows, width),
-                    (zero, start, zero)), chunk.reshape(-1)[0])
+                with jax.named_scope("stage.place"):
+                    zero = jnp.zeros((), start.dtype)
+                    return (jax.lax.dynamic_update_slice(
+                        table, chunk.reshape(1, rows, width),
+                        (zero, start, zero)), chunk.reshape(-1)[0])
 
             with spans.span("mp4j.step.build", key="row_chunk_placer",
                             rows=rows):

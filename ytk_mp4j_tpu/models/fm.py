@@ -391,6 +391,21 @@ def _score_from_slots(w0, wv, E, xv, cfg: FMConfig):
     return w0 + linear + inter
 
 
+def _score_gathered(p, fields, xv, cfg: FMConfig):
+    """The margin a sparse training step differentiates, from ``p`` =
+    ``(w0, blk)`` with the gathered blocks: :func:`_select_fields` under
+    the scope ``ffm.select``, :func:`_score_from_slots` under
+    ``ffm.pairs`` (the loss joins it there: :func:`_weighted_mean_grads`).
+    Inside ``value_and_grad`` a device trace shows the forward pass as
+    ``jvp(ffm.select)`` and the backward as ``transpose(jvp(ffm.select))``.
+    Not ``ffm.score.*``: those are the scoring program's (:func:`predict`),
+    and its metrics read them."""
+    with jax.named_scope("ffm.select"):
+        wv, E = _select_fields(p[1], fields, cfg)
+    with jax.named_scope("ffm.pairs"):
+        return _score_from_slots(p[0], wv, E, xv, cfg)
+
+
 def _score(params, feats, fields, vals, mask, cfg: FMConfig):
     """Model score for a batch of padded sparse instances, from the
     public [n_rows, k] table.
@@ -442,9 +457,15 @@ def _weighted_mean_grads(p, score_fn, y, sw, cfg: FMConfig, axis_name):
     """Global-mean loss + grads of the sample-weighted shard loss —
     the one prologue shared by the dense and sparse steps. ``p`` is
     the differentiated pytree (full params; (w0, blk) with the gathered
-    blocks on the sparse paths); ``score_fn(p)`` the margin."""
+    blocks on the sparse paths); ``score_fn(p)`` the margin. The loss
+    runs under the scope ``ffm.pairs``, where :func:`_score_gathered`
+    puts the margin's last lines (the dense step's loss too, the only
+    name its backward pass carries)."""
     def shard_sum(q):
-        return jnp.sum(per_example_loss(score_fn(q), y, cfg.loss) * sw)
+        z = score_fn(q)
+        # the loss is the pair products' last lines in a device trace
+        with jax.named_scope("ffm.pairs"):
+            return jnp.sum(per_example_loss(z, y, cfg.loss) * sw)
 
     sum_loss, grads = jax.value_and_grad(shard_sum)(p)
     cnt = jnp.sum(sw)
@@ -539,8 +560,7 @@ def train_step_sparse(params, batch, cfg: FMConfig, capacity: int,
     xv = vals * mask
     loss, (g0, gblk), denom = _weighted_mean_grads(
         (w0, blk),
-        lambda p: _score_from_slots(
-            p[0], *_select_fields(p[1], fields, cfg), xv, cfg),
+        lambda p: _score_gathered(p, fields, xv, cfg),
         y, sw, cfg, axis_name)
     if axis_name is not None:
         g0 = lax.psum(g0, axis_name)
@@ -549,8 +569,11 @@ def train_step_sparse(params, batch, cfg: FMConfig, capacity: int,
     keys = feats.reshape(-1).astype(jnp.int32)
     if dead:
         keys = keys.at[S - dead:].set(sparse_ops.SENTINEL)
-    ui, uv = _merge_slots(keys, gblk.reshape(S, -1), capacity, axis_name,
-                          dead)
+    with jax.named_scope("ffm.grad_merge"):
+        # [N, K, block] to the merge's [S, block] is a copy where K is not
+        # whole 8s (39 rests padded to 40): the merge's, not nobody's
+        payload = gblk.reshape(S, -1)
+    ui, uv = _merge_slots(keys, payload, capacity, axis_name, dead)
     lr = cfg.learning_rate
     w0 = w0 - lr * (g0 / denom)
     if cfg.l2:
@@ -703,8 +726,7 @@ def train_step_adagrad(params, batch, cfg: FMConfig, capacity: int,
     xv = vals * mask
     loss, (g0, gblk), _ = _weighted_mean_grads(
         (w0, blk),
-        lambda p: _score_from_slots(
-            p[0], *_select_fields(p[1], fields, cfg), xv, cfg),
+        lambda p: _score_gathered(p, fields, xv, cfg),
         y, sw, cfg, axis_name)
     if axis_name is not None:
         g0 = lax.psum(g0, axis_name)
@@ -712,8 +734,9 @@ def train_step_adagrad(params, batch, cfg: FMConfig, capacity: int,
     S = feats.size
     live, cnt = _touch_counts(fields, mask, sw, cfg)
     keys = jnp.where(live > 0, feats, sparse_ops.SENTINEL).reshape(-1)
-    payload = jnp.concatenate(
-        [gblk.reshape(S, hw), cnt.reshape(S, -1)], axis=1)
+    with jax.named_scope("ffm.grad_merge"):
+        payload = jnp.concatenate(
+            [gblk.reshape(S, hw), cnt.reshape(S, -1)], axis=1)
     ui, uv = _merge_slots(keys, payload, capacity, axis_name, dead)
 
     lr = cfg.learning_rate
@@ -733,8 +756,8 @@ def train_step_adagrad(params, batch, cfg: FMConfig, capacity: int,
             return T.at[jnp.where(dead, T.shape[0], ti)].set(
                 new, mode="drop")
 
-    # the loop itself is under no scope: gather, rule and update are read
-    # apart in a device trace (PERF.md section 3)
+    # the loop is ``sparse.fold_live_tiles`` in a device trace; gather,
+    # rule and update are read apart inside it (PERF.md section 3)
     T = sparse_ops.fold_live_tiles(ui, uv, _update_tile(capacity),
                                    update_tile, T)
     with jax.named_scope("ffm.adagrad_rule"):
@@ -926,8 +949,7 @@ def train_step_sparse_sharded(params, batch, cfg: FMConfig, n: int,
     xv = vals * mask
     loss, (g0, gblk), denom = _weighted_mean_grads(
         (w0, blk),
-        lambda p: _score_from_slots(
-            p[0], *_select_fields(p[1], fields, cfg), xv, cfg),
+        lambda p: _score_gathered(p, fields, xv, cfg),
         y, sw, cfg, axis_name)
     g0 = lax.psum(g0, axis_name)
 

@@ -566,55 +566,60 @@ def _build_tree(bins, g, h, cfg: GBDTConfig, axis_name, interpret,
     prev_hg = prev_hh = None
     # depth static -> unrolled
     for d, n_half in enumerate(hist_level_nodes(cfg.depth)):
-        n_nodes = 2 ** d
-        if d == 0:
-            hg, hh = reduced_histograms(node_ids, n_nodes)
-        else:
-            # histogram-subtraction trick (the classic GBDT sibling
-            # identity hist(parent) = hist(left) + hist(right)): build
-            # only the LEFT children — samples in right nodes map to an
-            # out-of-range sentinel id and contribute nothing — then
-            # derive the right siblings from the previous level's
-            # (already psum'd) parent histograms. Halves both the MXU
-            # work and the allreduce bytes at every level below the
-            # root. Precision caveat: the derived right child inherits
-            # error RELATIVE TO ITS PARENT's magnitude (~5e-6 in the
-            # bf16 hist modes), so a tiny right child's histogram is
-            # noisier than a directly-built one; the hessian clamp
-            # below keeps that noise from producing negative hessian
-            # sums (which could cross H + reg_lambda through zero in
-            # best_splits and crown a garbage split).
-            left_ids = jnp.where(node_ids % 2 == 0, node_ids // 2,
-                                 n_half)
-            hl_g, hl_h = reduced_histograms(left_ids, n_half)
-            hg = jnp.stack([hl_g, prev_hg - hl_g],
-                           axis=1).reshape(n_nodes, *hl_g.shape[1:])
-            hh = jnp.stack([hl_h, jnp.maximum(prev_hh - hl_h, 0.0)],
-                           axis=1).reshape(n_nodes, *hl_h.shape[1:])
-        prev_hg, prev_hh = hg, hh
-        feat, bin_, gain, dir_ = best_splits(
-            hg, hh, cfg.reg_lambda, feat_mask, cfg.min_child_hessian,
-            cat_mask, cfg.missing_bin)
-        # freeze any node whose best gain does not clear the threshold:
-        # bin B-1 routes every sample left (v > B-1 is never true for
-        # numeric, and categorical routing never goes right at B-1),
-        # keeping the node whole. The ~(gain > thr) form also freezes
-        # gain == 0 (empty/pure nodes would otherwise record a phantom
-        # feat-0 "split", poisoning feature_importance), gain == -inf
-        # (no admissible candidate, e.g. min_child_hessian disqualified
-        # everything), and NaN gains (0/0 at reg_lambda == 0).
-        freeze = ~(gain > cfg.min_split_gain)
-        bin_ = jnp.where(freeze, cfg.n_bins - 1, bin_)
-        dir_ = jnp.where(freeze, 0, dir_)   # frozen: missing stays left
-        tree_feat = lax.dynamic_update_slice(tree_feat, feat, (level_start,))
-        tree_bin = lax.dynamic_update_slice(tree_bin, bin_, (level_start,))
-        tree_dir = lax.dynamic_update_slice(tree_dir, dir_, (level_start,))
-        # route samples: go right if bin value > split bin (gather-free,
-        # see the routing performance note above)
-        node_ids = _route_samples(bins, node_ids, feat, bin_, n_nodes,
-                                  dir_, cat_mask, cfg.missing_bin,
-                                  cfg.n_bins)
-        level_start += n_nodes
+        # a name a level (metadata only): a device trace reads a kernel
+        # call as gbdt.level.5/gbdt.hist/mp4j_hist, and the scopes inside
+        # keep their names
+        with jax.named_scope(f"gbdt.level.{d}"):
+            n_nodes = 2 ** d
+            if d == 0:
+                hg, hh = reduced_histograms(node_ids, n_nodes)
+            else:
+                # histogram-subtraction trick (the classic GBDT sibling
+                # identity hist(parent) = hist(left) + hist(right)): build
+                # only the LEFT children — samples in right nodes map to an
+                # out-of-range sentinel id and contribute nothing — then
+                # derive the right siblings from the previous level's
+                # (already psum'd) parent histograms. Halves both the MXU
+                # work and the allreduce bytes at every level below the
+                # root. Precision caveat: the derived right child inherits
+                # error RELATIVE TO ITS PARENT's magnitude (~5e-6 in the
+                # bf16 hist modes), so a tiny right child's histogram is
+                # noisier than a directly-built one; the hessian clamp
+                # below keeps that noise from producing negative hessian
+                # sums (which could cross H + reg_lambda through zero in
+                # best_splits and crown a garbage split).
+                left_ids = jnp.where(node_ids % 2 == 0, node_ids // 2,
+                                     n_half)
+                hl_g, hl_h = reduced_histograms(left_ids, n_half)
+                hg = jnp.stack([hl_g, prev_hg - hl_g],
+                               axis=1).reshape(n_nodes, *hl_g.shape[1:])
+                hh = jnp.stack([hl_h, jnp.maximum(prev_hh - hl_h, 0.0)],
+                               axis=1).reshape(n_nodes, *hl_h.shape[1:])
+            prev_hg, prev_hh = hg, hh
+            feat, bin_, gain, dir_ = best_splits(
+                hg, hh, cfg.reg_lambda, feat_mask, cfg.min_child_hessian,
+                cat_mask, cfg.missing_bin)
+            # freeze any node whose best gain does not clear the threshold:
+            # bin B-1 routes every sample left (v > B-1 is never true for
+            # numeric, and categorical routing never goes right at B-1),
+            # keeping the node whole. The ~(gain > thr) form also freezes
+            # gain == 0 (empty/pure nodes would otherwise record a phantom
+            # feat-0 "split", poisoning feature_importance), gain == -inf
+            # (no admissible candidate, e.g. min_child_hessian disqualified
+            # everything), and NaN gains (0/0 at reg_lambda == 0).
+            freeze = ~(gain > cfg.min_split_gain)
+            bin_ = jnp.where(freeze, cfg.n_bins - 1, bin_)
+            dir_ = jnp.where(freeze, 0, dir_)   # frozen: missing stays left
+            at = (level_start,)
+            tree_feat = lax.dynamic_update_slice(tree_feat, feat, at)
+            tree_bin = lax.dynamic_update_slice(tree_bin, bin_, at)
+            tree_dir = lax.dynamic_update_slice(tree_dir, dir_, at)
+            # route samples: go right if bin value > split bin (gather-free,
+            # see the routing performance note above)
+            node_ids = _route_samples(bins, node_ids, feat, bin_, n_nodes,
+                                      dir_, cat_mask, cfg.missing_bin,
+                                      cfg.n_bins)
+            level_start += n_nodes
 
     # leaf values from (all-reduced) leaf G/H
     n_leaves = 2 ** cfg.depth

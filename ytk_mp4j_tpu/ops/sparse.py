@@ -211,16 +211,19 @@ def fold_live_tiles(idx, val, tile: int, body, carry):
     descriptor live or dropped, so what the loop saves is the list's
     dead tail."""
     L = idx.shape[0]
-    if L % tile:
-        idx, val = pad_to(idx, val, -(-L // tile) * tile)
-    n_live = jnp.sum(idx != SENTINEL, dtype=jnp.int32)
+    # the loop's own counter, slices and copies carry this name in a
+    # device trace; ``body``'s scopes nest inside it
+    with jax.named_scope("sparse.fold_live_tiles"):
+        if L % tile:
+            idx, val = pad_to(idx, val, -(-L // tile) * tile)
+        n_live = jnp.sum(idx != SENTINEL, dtype=jnp.int32)
 
-    def step(t, carry):
-        return body(carry,
-                    lax.dynamic_slice_in_dim(idx, t * tile, tile),
-                    lax.dynamic_slice_in_dim(val, t * tile, tile))
+        def step(t, carry):
+            return body(carry,
+                        lax.dynamic_slice_in_dim(idx, t * tile, tile),
+                        lax.dynamic_slice_in_dim(val, t * tile, tile))
 
-    return lax.fori_loop(0, (n_live + (tile - 1)) // tile, step, carry)
+        return lax.fori_loop(0, (n_live + (tile - 1)) // tile, step, carry)
 
 
 def _generic_segment_reduce(val, seg, capacity: int, operator: Operator):
