@@ -102,3 +102,28 @@ def program_without_provenance(text: str) -> str:
     return re.sub(r"%[\w.\-]+",
                   lambda m: order.setdefault(m.group(0), f"%{len(order)}"),
                   text)
+
+
+def watch_live_arrays(monkeypatch, trainer):
+    """From now on every scoring program ``trainer`` builds notes, at
+    each launch, the bytes of the largest device array that was not
+    there when this was called (``jax.live_arrays()``: pieces in flight,
+    margins, the model, and a table if anything built one). Returns the
+    list the notes go to."""
+    import jax
+
+    before = {id(x) for x in jax.live_arrays()}
+    seen = []
+    build = trainer._build_score
+
+    def watched(*args, **kw):
+        program = build(*args, **kw)
+
+        def launch(*operands):
+            seen.append(max(x.nbytes for x in jax.live_arrays()
+                            if id(x) not in before))
+            return program(*operands)
+        return launch
+
+    monkeypatch.setattr(trainer, "_build_score", watched)
+    return seen

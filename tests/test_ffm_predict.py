@@ -1,8 +1,9 @@
 """``FMTrainer.predict`` on a replicated table (ISSUE 36): one jitted,
 row-sharded program that reads the entered ``(w0, T)`` a block a (row,
-slot), over instances staged in row chunks and scored in tiles. Against
-a float64 numpy score and against the old row form, across chunk, tile
-and shard boundaries."""
+slot), over instances that cross in pieces of rows, ids, fields and
+values each on its own, and are scored in tiles as they crossed (ISSUE
+52: no table of instances is built). Against a float64 numpy score and
+against the old row form, across piece, tile and shard boundaries."""
 
 import re
 
@@ -14,7 +15,6 @@ import jax.numpy as jnp
 
 from ytk_mp4j_tpu.exceptions import Mp4jError
 from ytk_mp4j_tpu.models import fm
-from ytk_mp4j_tpu.models._base import packed_width
 from ytk_mp4j_tpu.models.fm import EnteredModel, FMConfig, FMTrainer
 from ytk_mp4j_tpu.obs import spans
 
@@ -210,8 +210,8 @@ def test_a_second_predict_of_the_same_shape_builds_nothing(monkeypatch):
     feats, fields, vals = _instances(53, NNZ)
     cursor = spans.take_since(0)[0]
     first = tr.predict(params, feats, fields, vals)
-    # the conversion, the placer, a chunk's program and the remainder's
-    assert _count("mp4j.step.build", cursor) == 4
+    # the conversion, a piece's program and the remainder's: no placer
+    assert _count("mp4j.step.build", cursor) == 3
     cursor = spans.take_since(0)[0]
     again = tr.predict(params, *_instances(53, NNZ, seed=2))
     assert _count("mp4j.step.build", cursor) == 0
@@ -270,7 +270,8 @@ def test_the_job_leaves_its_spans(monkeypatch):
                         ("mp4j.ffm.score.fetch", 2),
                         ("mp4j.ffm.score.dispatch", 6),
                         ("mp4j.put_sharded", 2), ("mp4j.stage.prep", 2),
-                        ("mp4j.stage.send", 6), ("mp4j.stage.place", 6)]:
+                        ("mp4j.stage.send", 6), ("mp4j.stage.place", 0),
+                        ("mp4j.stage.link_wait", 4)]:
         assert names.count(name) == times, name
     stage = [s[6] for s in taken if s[0] == "mp4j.ffm.score.stage"]
     assert [a["job"] for a in stage] == [0, 1] and stage[0]["rows"] == 20
@@ -281,22 +282,33 @@ def test_the_job_leaves_its_spans(monkeypatch):
     # what crosses is the three arrays as the host holds them
     sent = [s[6]["bytes"] for s in taken if s[0] == "mp4j.put_sharded"]
     assert sent == [20 * 3 * NNZ * 4] * 2
-    chunks = [s[6]["bytes"] for s in taken if s[0] == "mp4j.stage.send"]
-    assert chunks == [8 * 3 * NNZ * 4] * 6
+    chunks = [s[6] for s in taken if s[0] == "mp4j.stage.send"]
+    assert chunks == [{"chunk": k, "bytes": 8 * 3 * NNZ * 4}
+                      for k in (0, 1, 2)] * 2
+    # and under put_sharded there is the pace and nothing else
+    puts = [s for s in taken if s[0] == "mp4j.put_sharded"]
+    under = [s for s in taken if s[0].startswith("mp4j.stage.")
+             and any(p[2] <= s[2] and s[2] + s[3] <= p[2] + p[3]
+                     for p in puts)]
+    assert {s[0] for s in under} == {"mp4j.stage.send",
+                                     "mp4j.stage.link_wait"}
+    assert [s[6]["chunk"] for s in under
+            if s[0] == "mp4j.stage.link_wait"] == [0, 1] * 2
 
 
 @pytest.mark.parametrize("model", ["ffm", "fm"])
 def test_the_program_names_its_scopes_and_gathers_a_block_a_slot(model):
     tr = FMTrainer(_cfg(model), n_devices=4)
-    shape = (4, 10, packed_width(3 * NNZ))
+    shape = (4, 10, NNZ)
     width = fm._block_width(tr._score_cfg)
     rows, rep = tr._row_sharding(), tr._place_replicated
     text = tr._build_score(shape, 10).lower(
-        jnp.zeros(shape, jnp.int32, device=rows),
+        *(jnp.zeros(shape, dtype, device=rows)
+          for dtype in (jnp.int32, jnp.int32, jnp.float32)),
         rep((jnp.float32(0), jnp.zeros((V, width), jnp.float32))),
-        jnp.zeros(shape[:2], jnp.float32, device=rows),
+        jnp.zeros((4, 25), jnp.float32, device=rows),
         np.int32(0)).as_text(debug_info=True)
-    scopes = ["ffm.table_gather", "ffm.score.pairs"]
+    scopes = ["stage.place", "ffm.table_gather", "ffm.score.pairs"]
     if model == "ffm":
         scopes.append("ffm.score.select")   # FM's block is its vector
     for scope in scopes:
@@ -351,12 +363,12 @@ def test_instances_that_are_full_width_already_are_not_copied(monkeypatch):
     feats, fields, vals = _instances(9, NNZ)
     f, fl, v = tr._stage_instances(feats, fields, vals)
     assert f is feats and fl is fields and v is vals
-    # and what predict hands the staging loop are views of them
+    # and what predict hands the cutting loop are views of them
     staged = []
-    put = FMTrainer._put_in_row_chunks
+    cuts = FMTrainer._array_cuts
     monkeypatch.setattr(
-        FMTrainer, "_put_in_row_chunks",
-        lambda self, a, each=None: staged.extend(a) or put(self, a, each))
+        FMTrainer, "_array_cuts",
+        lambda self, a: staged.extend(a) or cuts(self, a))
     tr.predict(_params(tr), feats, fields, vals)
     assert [a.shape for a in staged] == [(1, 9, NNZ)] * 3
     assert all(np.shares_memory(a, b)
@@ -369,11 +381,11 @@ def test_instances_that_are_full_width_already_are_not_copied(monkeypatch):
 @pytest.mark.parametrize("n_devices,per,chunk_rows", [
     (1, 20, 8), (4, 11, 4), (1, 256, 128), (4, 5, 64),
 ])
-def test_a_tuple_of_arrays_rests_side_by_side(monkeypatch, n_devices, per,
-                                              chunk_rows):
-    """``_put_in_row_chunks`` with a tuple: every array crosses on its
-    own, a chunk of rows at a time, and the table holds a row's words side
-    by side (f32 as its bits), zeros up to a width of whole 8s."""
+def test_a_tuple_of_arrays_crosses_each_on_its_own(monkeypatch, n_devices,
+                                                   per, chunk_rows):
+    """``_array_cuts`` with a tuple: every array crosses on its own, a
+    piece of rows at a time and in its own type, and a piece is the
+    tuple of them; no table, no placer, no padding words."""
     monkeypatch.setattr(FMTrainer, "_EACH_CHUNK_BYTES",
                         chunk_rows * 3 * NNZ * 4)
     tr = FMTrainer(_cfg(), n_devices=n_devices)
@@ -382,22 +394,51 @@ def test_a_tuple_of_arrays_rests_side_by_side(monkeypatch, n_devices, per,
     a, b = (rng.integers(0, 2 ** 31 - 1, shape).astype(np.int32)
             for _ in range(2))
     c = rng.standard_normal(shape).astype(np.float32)
-    seen = []
+    seen, got = [], [np.zeros_like(x) for x in (a, b, c)]
     cursor = spans.take_since(0)[0]
-    table = tr._put_in_row_chunks(
-        (a, b, c), each=lambda t, start, stop: seen.append((start, stop)))
-    width = packed_width(3 * NNZ)
-    assert width == 24 and table.shape == (n_devices, per, width)
-    assert table.dtype == jnp.int32
-    got = np.asarray(table)
-    assert np.array_equal(got[..., :NNZ], a)
-    assert np.array_equal(got[..., NNZ:2 * NNZ], b)
-    assert np.array_equal(got[..., 2 * NNZ:3 * NNZ].view(np.float32), c)
-    assert (got[..., 3 * NNZ:] == 0).all()
+    for k, piece, shard, start, stop, turns in tr._crossed(
+            tr._array_cuts((a, b, c))):
+        assert shard is None and len(piece) == 3 and k == len(seen)
+        assert [p.dtype for p in piece] == [np.int32, np.int32, np.float32]
+        seen.append((start, stop))
+        for whole, p in zip(got, piece):
+            assert p.sharding == tr._row_sharding()
+            whole[:, start:stop] = np.asarray(p).reshape(n_devices, -1, NNZ)
+    for whole, want in zip(got, (a, b, c)):
+        assert np.array_equal(whole, want)
     rows = min(per, chunk_rows)
     assert seen[0] == (0, rows) and seen[-1] == (per - rows, per)
     assert len(seen) == -(-per // rows)
-    # one placer, built once; a second staging builds nothing
-    assert _count("mp4j.step.build", cursor) == 1
-    tr._put_in_row_chunks((a, b, c), each=lambda *args: None)
-    assert _count("mp4j.step.build", cursor) == 1
+    assert _count("mp4j.step.build", cursor) == 0
+    assert _count("mp4j.stage.send", cursor) == len(seen)
+    assert not hasattr(fm, "packed_width") and tr._row_placers == {}
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_no_array_of_the_files_size_is_on_the_mesh(monkeypatch, n_devices):
+    """While a file is scored in pieces the largest device array made
+    since the call began is one array of a piece, the probabilities or
+    the model: no table of instances, at any launch; and every row of a
+    shard is scored exactly once."""
+    from tests.helpers import watch_live_arrays
+
+    _small_pieces(monkeypatch, chunk_rows=128, tile=40)
+    tr = FMTrainer(_cfg(), n_devices=n_devices)
+    model = tr.enter_model(_params(tr))
+    feats, fields, vals = _instances(3000, NNZ)
+    seen = watch_live_arrays(monkeypatch, tr)
+    cursor = spans.take_since(0)[0]
+    got = tr.predict(model, feats, fields, vals)
+    per = 3000 // n_devices
+    assert len(seen) == -(-per // 128)
+    assert max(seen) == max(n_devices * 128 * NNZ * 4, n_devices * per * 4)
+    assert max(seen) * 4 < feats.nbytes
+    dispatch = [s[6] for s in spans.take_since(cursor)[1]
+                if s[0] == "mp4j.ffm.score.dispatch"]
+    assert [d["start"] for d in dispatch] == list(range(0, per, 128))
+    assert sum(d["rows"] for d in dispatch) == per
+    # and the bits are one piece's
+    monkeypatch.setattr(FMTrainer, "_EACH_CHUNK_BYTES", 2 ** 27)
+    whole = FMTrainer(_cfg(), n_devices=n_devices).predict(
+        _params(tr), feats, fields, vals)
+    assert got.tobytes() == whole.tobytes()

@@ -300,16 +300,20 @@ def test_routing_reads_its_levels_columns(request, which, n_feat,
 
 
 SCORE_ROWS, SCORE_TREES = 1_183_748, 500   # configs/gbdt-bosch-score-500
-# rows of a staging chunk of this table (_put_in_row_chunks: 128 MiB in
-# whole rows of 128 lanes), which ``predict`` scores as it crosses
+# rows of a piece of this table (_array_cuts: 128 MiB in whole rows of
+# 128 lanes), which ``predict`` scores as it crossed; the last piece
+# starts early and the rows it adds take a program of their own
 SCORE_CHUNK_ROWS = (GBDTTrainer._EACH_CHUNK_BYTES // (WIDE_F * 4)
                     // 128 * 128)
+SCORE_REST_ROWS = SCORE_ROWS % SCORE_CHUNK_ROWS
+SCORE_WIRE = (1, SCORE_CHUNK_ROWS * WIDE_F // 128, 128)
 
 
 @pytest.fixture(scope="module")
 def score_program(topo_devices):
-    """``GBDTTrainer.predict``'s scoring program at the Bosch scoring
-    cell's size, a staging chunk's rows a call, and its build span."""
+    """``GBDTTrainer.predict``'s two scoring programs at the Bosch
+    scoring cell's size, each taking a piece as it crossed, and their
+    build spans: ``{rows scored: (compiled, span)}``."""
     from ytk_mp4j_tpu.models.gbdt import score_group_size
     from ytk_mp4j_tpu.obs import spans
 
@@ -322,48 +326,81 @@ def score_program(topo_devices):
     shape = (-(-SCORE_TREES // group), 2 ** DEPTH, group, 1)
     stacked = tuple(jax.ShapeDtypeStruct(shape, d, sharding=whole)
                     for d in (jnp.int32, jnp.int32, jnp.int32, jnp.float32))
-    spans.clear()
-    program = trainer._build_score((1, SCORE_ROWS, WIDE_F), SCORE_CHUNK_ROWS,
-                                   SCORE_TREES)
-    built = [s[-1] for s in spans.snapshot() if s[0] == "mp4j.step.build"]
-    compiled = program.lower(
-        jax.ShapeDtypeStruct((1, SCORE_ROWS, WIDE_F), jnp.int32,
-                             sharding=rows), stacked,
-        jax.ShapeDtypeStruct((1, 1, SCORE_ROWS), jnp.float32, sharding=rows),
-        jax.ShapeDtypeStruct((), jnp.int32, sharding=whole)).compile()
-    return compiled, built
+    out = {}
+    for scored in (SCORE_CHUNK_ROWS, SCORE_REST_ROWS):
+        spans.clear()
+        program = trainer._build_score(SCORE_WIRE, scored, SCORE_TREES)
+        (built,) = [s[-1] for s in spans.snapshot()
+                    if s[0] == "mp4j.step.build"]
+        # 64-bit types off, as the cell and every user who has not asked
+        # for them run it (``tests/conftest.py`` turns them on)
+        with jax.enable_x64(False):
+            out[scored] = program.lower(
+                jax.ShapeDtypeStruct(SCORE_WIRE, jnp.int32, sharding=rows),
+                stacked,
+                jax.ShapeDtypeStruct((1, 1, SCORE_ROWS), jnp.float32,
+                                     sharding=rows),
+                jax.ShapeDtypeStruct((), jnp.int32,
+                                     sharding=whole)).compile(), built
+    return out
 
 
-def test_scoring_program_fits_and_passes_the_table_few_times(score_program):
-    compiled, built = score_program
+def _whole_lane_words(rows):
+    """The rows a chunk of ``rows`` rows is scored as: filled up with
+    empty rows to whole 128-lane words (``gbdt._SCORE_LANES``)."""
+    from ytk_mp4j_tpu.models import gbdt
+
+    return -(-rows // gbdt._SCORE_LANES) * gbdt._SCORE_LANES
+
+
+def _dynamic_slices(text):
+    """The sizes of what a compiled program slices at a computed place:
+    in a scoring program a group of the ensemble, and no row of any
+    table."""
+    return set(re.findall(
+        r" dynamic-slice\(.*?dynamic_slice_sizes=\{([\d,]+)\}", text))
+
+
+@pytest.mark.parametrize("scored", [SCORE_CHUNK_ROWS, SCORE_REST_ROWS])
+def test_scoring_program_holds_a_piece_and_no_table(score_program, scored):
+    compiled, built = score_program[scored]
     m = compiled.memory_analysis()
-    assert m.argument_size_in_bytes >= SCORE_ROWS * WIDE_F * 4
-    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 14e9
-    # the temporaries are a chunk's bf16 copy and one group's decisions
+    piece = SCORE_CHUNK_ROWS * WIDE_F * 4
+    assert (SCORE_CHUNK_ROWS, SCORE_REST_ROWS) == (34_560, 8_708)
+    # the piece, the margins, the ensemble: no table among the arguments
+    assert piece <= m.argument_size_in_bytes < piece + 8e6
+    assert m.argument_size_in_bytes < SCORE_ROWS * WIDE_F * 4 / 30
+    # the temporaries are the piece's bf16 copies and a group's decisions
     assert m.temp_size_in_bytes < 0.5e9, m.temp_size_in_bytes
-    assert len(built) == 1 and built[0]["key"] == "gbdt_score"
-    assert built[0]["rows"] == SCORE_CHUNK_ROWS == 34_560
-    assert built[0]["row_chunks"] == 1
-    # a chunk's bf16 copy is read once a group of trees: at most 64 times
-    assert -(-SCORE_TREES // built[0]["group"]) <= 64
+    assert built["key"] == "gbdt_score" and built["rows"] == scored
+    assert built["row_chunks"] == 1
+    # a piece's bf16 copy is read once a group of trees: at most 64 times
+    assert -(-SCORE_TREES // built["group"]) <= 64
 
 
-def test_scoring_program_reads_the_table_as_it_rests(score_program):
-    """The [N, 968] table rests as [F, N]; the program takes a chunk's
-    rows from it as a [968, rows] bf16 array without a transposition,
-    selects by one bf16 matmul a group and decides in its output."""
-    text = score_program[0].as_text()
-    assert "s32[1,%d,%d]{1,2,0:T(8,128)} parameter(0)" % (
-        SCORE_ROWS, WIDE_F) in text
-    assert _row_major_tables(text, SCORE_CHUNK_ROWS // 2, WIDE_F) == []
-    assert not re.search(
-        r"= s32\[[\d,]*\d{6,},[\d,]*\d{3,}[\d,]*\]\S* (copy|pad|transpose)\(",
-        text)
-    group = score_program[1][0]["group"]
-    assert re.search(r"= bf16\[%d,%d\]\{1,0:T\(8,128\)\(2,1\)\} fusion\("
-                     % (WIDE_F, SCORE_CHUNK_ROWS), text)
+@pytest.mark.parametrize("scored", [SCORE_CHUNK_ROWS, SCORE_REST_ROWS])
+def test_scoring_program_takes_the_piece_as_it_crossed(score_program,
+                                                       scored):
+    """The piece comes in as [M, 128] words, as the host held it; the
+    program makes of its scored rows one [968, rows] bf16 array (rows on
+    the lanes), selects by one bf16 matmul a group and decides in its
+    output. Nothing of a table's size is sliced, updated or made: the
+    only ``dynamic-update-slice`` writes the margins where they rest."""
+    compiled, built = score_program[scored]
+    text = compiled.as_text()
+    assert "s32[%d,%d,%d]{2,1,0:T(8,128)} parameter(0)" % SCORE_WIRE in text
+    assert not re.search(r"= \w+\[[\d,]*\d{7,}[\d,]*\]\S* "
+                         r"(copy|pad|transpose|dynamic-slice)\(", text)
+    updates = re.findall(r"= (\w+\[[\d,]+\])\S* dynamic-update-slice\(", text)
+    assert updates == ["f32[1,1,%d]" % SCORE_ROWS], updates
+    assert _dynamic_slices(text) <= {"1,%d,%d,1" % (2 ** DEPTH,
+                                                    built["group"])}
+    assert "stage.place" in text
+    filled = _whole_lane_words(scored)      # 8,708 rows are scored as 8,832
+    assert re.search(r"bf16\[%d,%d\]\{1,0:T\(8,128\)\(2,1\)" % (WIDE_F, filled),
+                     text)
     assert re.search(r"= pred\[%d,%d\]\S* fusion\(.*kind=kOutput"
-                     % (group * 2 ** DEPTH, SCORE_CHUNK_ROWS), text)
+                     % (built["group"] * 2 ** DEPTH, filled), text)
     assert "gbdt.score.select/dot_general" in text
     assert "gbdt.score.walk" in text and "gbdt.route" not in text
     assert " gather(" not in text and "tpu_custom_call" not in text
@@ -877,14 +914,14 @@ def test_adagrad_conversions_go_a_block_at_a_time(adagrad_programs, which):
 @pytest.fixture(scope="module")
 def ffm_score_programs(topo_devices):
     """``FMTrainer.predict``'s scoring program at the size of
-    ``ffm-criteo-score.file-zipf``, a staging chunk's rows a call, on one
+    ``ffm-criteo-score.file-zipf``, a piece's rows a call (ids, fields
+    and values as each crossed, [M, 128] words a shard), on one
     described chip and on the four of the described host; the conversion
     that ``enter_model`` runs for a trainer whose step has another block
     (AdaGrad's), on one; and the scoring program's build span."""
     import json
     from pathlib import Path
 
-    from ytk_mp4j_tpu.models._base import packed_width
     from ytk_mp4j_tpu.models.fm import FMConfig, FMTrainer
     from ytk_mp4j_tpu.obs import spans
 
@@ -893,8 +930,7 @@ def ffm_score_programs(topo_devices):
     cfg = FMConfig(model=c["model"], n_features=c["n_features"],
                    n_fields=c["n_fields"], k=c["k"], max_nnz=c["max_nnz"],
                    loss=c["loss"], optimizer="adagrad")
-    width = packed_width(3 * cfg.max_nnz)
-    out = {"config": c, "width": width}
+    out = {"config": c}
     for chips in (1, 4):
         mesh = Mesh(np.asarray(topo_devices[:chips]), ("mp4j",))
         trainer = FMTrainer(cfg, mesh=mesh, sparse_grads=True)
@@ -904,13 +940,14 @@ def ffm_score_programs(topo_devices):
                  // 128 * 128)
         model = tuple(jax.ShapeDtypeStruct(shape, jnp.float32, sharding=rep)
                       for shape in ((), (c["n_features"], 256)))
+        wire = (chips, chunk * c["max_nnz"] // 128, 128)
         spans.clear()
-        program = trainer._build_score((chips, per, width), chunk)
+        program = trainer._build_score(wire, chunk)
         out[f"built_on_{chips}"] = [s[-1] for s in spans.snapshot()
                                     if s[0] == "mp4j.step.build"]
         out[f"score_on_{chips}"] = program.lower(
-            jax.ShapeDtypeStruct((chips, per, width), jnp.int32,
-                                 sharding=rows), model,
+            *(jax.ShapeDtypeStruct(wire, d, sharding=rows)
+              for d in (jnp.int32, jnp.int32, jnp.float32)), model,
             jax.ShapeDtypeStruct((chips, per), jnp.float32, sharding=rows),
             jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)).compile()
         if chips == 1:
@@ -929,8 +966,8 @@ def test_ffm_scoring_program_holds_a_tile_beside_its_arguments(
     from ytk_mp4j_tpu.models import fm
 
     p = ffm_score_programs
-    rows, width, chunk = p["rows"], p["width"], p["chunk"]
-    assert (rows, width, chunk) == (6_042_135, 120, 286_720)
+    rows, chunk = p["rows"], p["chunk"]
+    assert (rows, chunk) == (6_042_135, 286_720)
     built, = p["built_on_1"]
     assert built["key"] == "ffm_score" and built["rows"] == chunk
     assert built["tile"] == fm._SCORE_TILE
@@ -939,29 +976,38 @@ def test_ffm_scoring_program_holds_a_tile_beside_its_arguments(
     assert built["select_columns"] == "component"
     for chips in (1, 4):
         m = p[f"score_on_{chips}"].memory_analysis()
-        # the 4.29 GB table, a shard of the file, the probabilities
-        assert m.argument_size_in_bytes >= 2 ** 32 + rows // chips * width * 4
-        # a chunk's 286,720 rows gathered at once would be 11.5 GB
+        # the 4.29 GB table, a piece's three arrays, the probabilities:
+        # nothing of the file's size
+        piece = 3 * chunk * 39 * 4
+        assert 2 ** 32 + piece <= m.argument_size_in_bytes \
+            < 2 ** 32 + piece + rows // chips * 4 + 1e6
+        # a piece's 286,720 rows gathered at once would be 11.5 GB
         assert m.temp_size_in_bytes < 1e9, m.temp_size_in_bytes
         assert m.argument_size_in_bytes + m.temp_size_in_bytes < 9e9
-        assert m.output_size_in_bytes == m.alias_size_in_bytes > 0
+        # the probabilities where they rest, and the piece's first word
+        assert 0 <= m.output_size_in_bytes - m.alias_size_in_bytes <= 4096
+        assert m.alias_size_in_bytes >= rows // chips * 4
 
 
-def test_ffm_scoring_program_reads_file_and_table_as_they_rest(
+def test_ffm_scoring_program_reads_pieces_and_table_as_they_rest(
         ffm_score_programs):
-    """The packed rows rest as [120, N] in (8, 128) tiles and a tile is
-    sliced out of them as they lie; the table rests row-major and is
-    gathered a block a (row, slot); neither is copied."""
+    """The three arrays of a piece come in as [M, 128] words, as the host
+    held them, and nothing of the file's size is there to copy; the
+    table rests row-major and is gathered a block a (row, slot); it is
+    not copied."""
     from ytk_mp4j_tpu.models import fm
 
     p = ffm_score_programs
     text = p["score_on_1"].as_text()
     F, tile = p["config"]["n_features"], fm._SCORE_TILE
-    assert "s32[1,%d,%d]{1,2,0:T(8,128)} parameter(0)" % (
-        p["rows"], p["width"]) in text
-    assert "f32[%d,256]{1,0:T(8,128)} parameter(2)" % F in text
+    words = p["chunk"] * 39 // 128
+    for k, dtype in enumerate(("s32", "s32", "f32")):
+        assert "%s[1,%d,128]{2,1,0:T(8,128)} parameter(%d)" % (
+            dtype, words, k) in text
+    assert "f32[%d,256]{1,0:T(8,128)} parameter(4)" % F in text
     for opcode in ("copy", "transpose", "pad", "concatenate"):
         assert _table_sized(text, opcode, p["rows"] * 39) == [], opcode
+    assert "stage.place" in text
     gathers = [ln for ln in text.splitlines() if " gather(" in ln]
     assert len(gathers) == 1 and "ffm.table_gather" in gathers[0]
     # 39 slots and an empty one: whole sublane tiles, so the gathered
@@ -999,7 +1045,8 @@ def test_ffm_scoring_program_never_rests_the_components_on_the_lanes(
     as a run of slots (``E[n, a, j, b]``), so no array of the compiled
     program has the k = 4 components as the dimension that rests on the
     128 lanes (31 of 32 lanes would be padding). Its temporaries are
-    reported, and are a tile's."""
+    reported: a tile's, and since ISSUE 52 the piece's three arrays put
+    into rows of 39 slots (the piece itself crossed as whole words)."""
     p = ffm_score_programs
     k = p["config"]["k"]
     program = p[f"score_on_{chips}"]
@@ -1016,7 +1063,7 @@ def test_ffm_scoring_program_never_rests_the_components_on_the_lanes(
         print(f"\nffm scoring program on {chips} chip(s), tile {tile}: "
               f"temporaries {m.temp_size_in_bytes / 1e6:.1f} MB, "
               f"arguments {m.argument_size_in_bytes / 1e9:.3f} GB")
-    assert 0 < m.temp_size_in_bytes < 0.3e9, m.temp_size_in_bytes
+    assert 0 < m.temp_size_in_bytes < 0.5e9, m.temp_size_in_bytes
 
 
 def test_ffm_model_enters_a_block_at_a_time(ffm_score_programs):
@@ -1352,7 +1399,8 @@ def test_raw_placer_puts_a_piece_into_the_table_it_was_given(raw_programs):
 
 def test_train_placer_puts_a_piece_into_the_table_it_was_given(topo_devices):
     """``_put_in_row_chunks``' placer at the Bosch cell's size, a 128 MiB
-    piece as ``train()`` and ``predict`` both stage it: the donated table
+    piece as ``train()`` stages it (``predict`` scores the same piece and
+    places none, ISSUE 52): the donated table
     is updated where it rests, and nothing else as large as it is made."""
     mesh = Mesh(np.asarray(topo_devices[:1]), ("mp4j",))
     rows, whole = NamedSharding(mesh, P("mp4j")), NamedSharding(mesh, P())
@@ -1455,39 +1503,48 @@ def test_sketch_holds_a_block_of_columns_beside_the_table(raw_programs):
 # Scoring from floats (ISSUE 47, benchmark/configs/
 # gbdt-bosch-score-raw-500.json): the reader's chunk of 65,536 rows
 # crosses in two pieces of 32,768 and each piece is binned and scored by
-# one call of the scoring program as soon as it is placed; the last
-# chunk's 4,100 rows take a program of their own. A piece's bins exist
-# for the length of a call, beside the resident float table.
+# one call of the scoring program as it crossed (ISSUE 52: the program
+# takes the piece, no table of floats rests anywhere); the last chunk's
+# 4,100 rows take a program of their own. A piece's bins exist for the
+# length of a call.
 RAW_SCORE_LAST_ROWS = SCORE_ROWS - 18 * RAW_CHUNK_ROWS
 
 
-def _float_score(trainer, piece, stacked, rows, whole):
-    """(the scoring program for a float table at the cell's size and
-    ``piece`` rows a call, compiled; its build span's arguments)."""
+def _float_wire(piece):
+    """A piece of ``piece`` rows as ``_reader_cuts`` sends it."""
+    return ((piece * WIDE_F // 128, 128) if piece * WIDE_F % 128 == 0
+            else (piece, WIDE_F))
+
+
+def _float_score(trainer, piece, stacked, one):
+    """(the scoring program for a piece of ``piece`` rows of floats at
+    the cell's size, compiled for one device; its build span's
+    arguments)."""
     from ytk_mp4j_tpu.obs import spans
 
+    wire = _float_wire(piece)
     spans.clear()
-    program = trainer._build_score((1, SCORE_ROWS, WIDE_F), piece,
-                                   SCORE_TREES, (B - 2, True))
+    program = trainer._build_score(wire, piece, SCORE_TREES, (B - 2, True))
     (built,) = [s[-1] for s in spans.snapshot()
                 if s[0] == "mp4j.step.build"]
     return program.lower(
-        jax.ShapeDtypeStruct((1, SCORE_ROWS, WIDE_F), jnp.float32,
-                             sharding=rows), stacked,
-        jax.ShapeDtypeStruct((1, 1, SCORE_ROWS), jnp.float32, sharding=rows),
-        jax.ShapeDtypeStruct((), jnp.int32, sharding=whole),
+        jax.ShapeDtypeStruct(wire, jnp.float32, sharding=one), stacked,
+        jax.ShapeDtypeStruct((1, 1, SCORE_ROWS), jnp.float32, sharding=one),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one),
         jax.ShapeDtypeStruct((WIDE_F, B - 2), jnp.float32,
-                             sharding=whole)).compile(), built
+                             sharding=one)).compile(), built
 
 
-def test_float_scoring_programs_bin_a_piece_beside_the_resident_table(
+def test_float_scoring_programs_bin_the_piece_they_are_handed(
         topo_devices):
-    """Both programs of the cell compile for the chip, hold the float
-    table as it rests and nothing else of its size, and make of a
-    piece's rows no more than its counts and its bf16 digits: an XLA
-    that materialised the whole table's bins would fail here first.
-    Compiled with 64-bit types off, as the cell and every user who has
-    not asked for them run it (``tests/conftest.py`` turns them on)."""
+    """Both programs of the cell compile for the chip, take a piece of
+    floats as it crossed and nothing of the table's size, and make of
+    its rows no more than their relaid copy, their counts and their bf16
+    digits. Compiled with 64-bit types off, as the cell and every user
+    who has not asked for them run it (``tests/conftest.py`` turns them
+    on)."""
+    from jax.sharding import SingleDeviceSharding
+
     from ytk_mp4j_tpu.models import binning
     from ytk_mp4j_tpu.models.gbdt import score_group_size
 
@@ -1498,57 +1555,59 @@ def test_float_scoring_programs_bin_a_piece_beside_the_resident_table(
     assert RAW_SCORE_LAST_ROWS == 4_100
     assert (-(-RAW_CHUNK_ROWS * WIDE_F * 4 // trainer._EACH_CHUNK_BYTES)
             == RAW_CHUNK_ROWS // RAW_PIECE_ROWS)
-    rows, whole = NamedSharding(mesh, P("mp4j")), NamedSharding(mesh, P())
+    one = SingleDeviceSharding(topo_devices[0])
     group = score_group_size(SCORE_TREES)
     shape = (-(-SCORE_TREES // group), 2 ** DEPTH, group, 1)
-    stacked = tuple(jax.ShapeDtypeStruct(shape, d, sharding=whole)
+    stacked = tuple(jax.ShapeDtypeStruct(shape, d, sharding=one)
                     for d in (jnp.int32, jnp.int32, jnp.int32, jnp.float32))
-    table = r"f32\[1,%d,%d\]\{1,2,0:T\(8,128\)\}" % (SCORE_ROWS, WIDE_F)
     for piece in (RAW_PIECE_ROWS, RAW_SCORE_LAST_ROWS):
         with jax.enable_x64(False), pytest.MonkeyPatch.context() as patch:
             # as on a TPU: the backend here is the CPU
             patch.setattr(binning, "_kernel_compiles", lambda: True)
-            compiled, built = _float_score(trainer, piece, stacked, rows,
-                                           whole)
+            compiled, built = _float_score(trainer, piece, stacked, one)
         assert built == {"key": "gbdt_score_raw", "edges": B - 2,
                          "compares": 8, "bin_block_columns": 8,
                          "bin_block_rows": 4096,
                          "form": "bins", "group": group, "rows": piece,
                          "row_chunk": piece, "row_chunks": 1}
         mem = compiled.memory_analysis()
-        assert mem.argument_size_in_bytes >= SCORE_ROWS * WIDE_F * 4
-        # a piece's floats as they were sliced out to rest, its counts
-        # (int32, the kernel's) and its bf16 digits: 10 bytes a cell of
-        # the piece, 0.32 GB at 32,768 rows; and the margins are updated
+        # the piece, the margins, the ensemble and the edges: no table
+        assert piece * WIDE_F * 4 <= mem.argument_size_in_bytes \
+            < piece * WIDE_F * 4 + 8e6
+        # the piece's floats as the kernel reads them, its counts (int32,
+        # the kernel's) and its bf16 digits: 10 bytes a cell of the
+        # piece, 0.32 GB at 32,768 rows; and the margins are updated
         # where they rest
         assert mem.temp_size_in_bytes < 3 * piece * WIDE_F * 4, piece
         text = compiled.as_text()
         assert "input_output_alias" in text
-        assert re.search(table + r" parameter\(0\)", text)
-        # nothing of the table's size is made: the only arrays with the
-        # table's rows are the table and the margins
+        wire = ",".join(str(d) for d in _float_wire(piece))
+        assert re.search(r"f32\[%s\]\{\S+\} parameter\(0\)" % wire, text)
+        # nothing of a table's size is there or made, and nothing is
+        # sliced: the margins alone have the table's rows
         made = re.findall(
             r"= \w+\[[\d,]*\d{6,},[\d,]*\d{3,}[\d,]*\]\S* (\w[\w-]*)\(",
             text)
-        assert set(made) <= {"parameter"}, made
-        # a piece's floats are sliced out once, as they rest ([F, rows],
-        # rows along the lanes), and that is what the kernel reads; its
-        # counts are what the digits are made of: the piece is never
-        # transposed, before the kernel or after it
+        assert made in ([], ["parameter"]), made     # the piece, as words
+        assert _dynamic_slices(text) <= {"1,%d,%d,1" % (2 ** DEPTH, group)}
+        updates = re.findall(r"= (\w+\[[\d,]+\])\S* dynamic-update-slice\(",
+                             text)
+        assert updates == ["f32[1,1,%d]" % SCORE_ROWS], updates
+        # the piece is put to rest once, rows along the lanes, under the
+        # placers' name, and that is what the kernel reads; its counts
+        # are what the digits are made of: nothing is transposed after
+        # the kernel
         call, operand = _kernel_call(text)
-        resting = r"\[%d,%d\]\{1,0:T\(8,128\)" % (WIDE_F, piece)
+        resting = r"\[%d,%d\]\{1,0:T\(8,128\)" % (
+            WIDE_F, _whole_lane_words(piece))
         assert re.search(r"= s32%s\S* custom-call\(" % resting, call)
         assert "bin.transform/mp4j_bin" in call
-        assert re.search(r"= f32%s\S* fusion\(" % resting, operand), operand
-        assert "dynamic-slice" in operand
-        assert len(re.findall(
-            r"= f32\[(?:%d,%d|%d,%d)\]\S* (?:fusion|copy|transpose)\("
-            % (piece, WIDE_F, WIDE_F, piece), text)) == 1
+        assert "stage.place" in text
         assert re.search(
             r"= bf16%s\(2,1\)\S* (?:fusion|convert)\(%%mp4j_bin" % resting, text)
-        assert not re.search(r"\[%d,%d\]\S* (?:copy|transpose)\("
+        assert not re.search(r"s32\[%d,%d\]\S* (?:copy|transpose)\("
                              % (piece, WIDE_F), text)
-        assert not re.search(r"\[%d,%d\]\S* (?:copy|transpose)\("
+        assert not re.search(r"s32\[%d,%d\]\S* (?:copy|transpose)\("
                              % (WIDE_F, piece), text)
         assert "gbdt.score.select/dot_general" in text
         assert "gbdt.score.walk" in text

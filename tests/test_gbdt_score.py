@@ -200,6 +200,37 @@ def test_a_non_finite_leaf_reaches_only_its_own_rows():
     np.testing.assert_array_equal(np.isnan(got), np.isnan(want))
 
 
+@pytest.mark.parametrize("loss", LOSSES)
+@pytest.mark.parametrize("rows", [200, 1, 129])
+def test_a_chunk_off_the_lane_words_is_filled_to_the_same_bits(
+        monkeypatch, loss, rows):
+    """A chunk whose rows are not whole 128-lane words is scored with
+    empty rows after it, whose margins are dropped (``_SCORE_LANES``): the
+    bits are those of the chunk alone, and the select's operand is whole
+    words."""
+    cfg = _cfg(loss, "missing-cat")
+    bins, trees = _draw(cfg, rows=rows)
+    got = GBDTTrainer(cfg, n_devices=1).predict(bins, trees)
+    monkeypatch.setattr(gbdt, "_SCORE_LANES", 1)
+    np.testing.assert_array_equal(
+        got, GBDTTrainer(cfg, n_devices=1).predict(bins, trees))
+    monkeypatch.undo()
+    monkeypatch.setattr(gbdt, "_SCORE_ROW_CHUNK", ROW_CHUNK)
+    tr = GBDTTrainer(cfg, mesh=make_mesh(1))
+    C = 3 if loss == "softmax" else 1
+    stacked = tuple(
+        jax.ShapeDtypeStruct((-(-ROUNDS // score_group_size(ROUNDS, C)),
+                              2 ** DEPTH, score_group_size(ROUNDS, C), C), d)
+        for d in (jnp.int32, jnp.int32, jnp.int32, jnp.float32))
+    text = tr._build_score((1, rows, F), rows, ROUNDS).lower(
+        jax.ShapeDtypeStruct((1, rows, F), jnp.int32), stacked,
+        jax.ShapeDtypeStruct((1, C, rows), jnp.float32),
+        jax.ShapeDtypeStruct((), jnp.int32)).as_text()
+    filled = -(-rows // 128) * 128
+    assert f"tensor<{F}x{filled}xbf16>" in text
+    assert f"tensor<{F}x{rows}xbf16>" not in text
+
+
 def test_group_and_chunk_sizes():
     assert score_group_size(500) == 16 and -(-500 // 16) == 32
     assert score_group_size(37) == 13 and score_group_size(16) == 16
@@ -213,45 +244,94 @@ def test_group_and_chunk_sizes():
     assert score_row_chunks(257) == (256, 2)            # whole lane words
 
 
-def test_a_large_shard_is_staged_in_row_chunks(monkeypatch):
-    """predict stages its table through ``_put_sharded``: a shard at or
-    over ``_ONE_TRANSFER_BYTES`` crosses in chunks of
-    ``_EACH_CHUNK_BYTES``, each scored as it crosses; ``train`` stages
-    the table in the same chunks."""
+def _in_pieces(monkeypatch, tr, piece_rows):
+    """``tr`` sends its table in pieces of ``piece_rows`` rows a shard."""
+    monkeypatch.setattr(tr, "_ONE_TRANSFER_BYTES", 1)
+    monkeypatch.setattr(tr, "_EACH_CHUNK_BYTES", piece_rows * F * 4)
+    return tr
+
+
+# rows a piece, of 251 (four shards) or 1003 (one) rows a shard
+PIECES = {"last-overlaps": 64, "a-row-past-a-piece": 250,
+          "under-one-piece": 2000, "lane-words": 128, "a-row-a-piece": 1}
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+@pytest.mark.parametrize("pieces", sorted(PIECES))
+@pytest.mark.parametrize("loss", ["logistic", "softmax"])
+def test_pieces_give_the_margins_of_one_transfer(monkeypatch, loss, pieces,
+                                                 n_devices):
+    """A table that crosses in pieces is scored a piece at a time, each
+    as it crossed, and gives the margins of the table in one transfer to
+    the bit; every row of a shard is scored exactly once (the last piece
+    starts early and only its new rows are scored), and a second call
+    builds nothing."""
+    if pieces == "a-row-a-piece" and n_devices == 1:
+        pytest.skip("a thousand programs of one row")
+    cfg = _cfg(loss, "missing-cat")
+    bins, trees = _draw(cfg)
+    want = _score(loss, "missing-cat", n_devices)[3]
+    tr = _in_pieces(monkeypatch, GBDTTrainer(cfg, n_devices=n_devices),
+                    PIECES[pieces])
+    spans.clear()
+    np.testing.assert_array_equal(tr.predict(bins, trees), want)
+    per = -(-ROWS // n_devices)
+    dispatch = [s[-1] for s in _named("mp4j.gbdt.score.dispatch")]
+    rows = min(per, PIECES[pieces])
+    if rows >= 128:
+        rows -= rows % 128
+    assert [d["start"] for d in dispatch] == list(range(0, per, rows))
+    assert sum(d["rows"] for d in dispatch) == per
+    assert [d["rows"] for d in dispatch[:-1]] == [rows] * (len(dispatch) - 1)
+    # one program for the pieces and one for what the last one adds
+    assert len(tr._score_programs) == 1 + (per % rows > 0)
+    built = len(_named("mp4j.step.build"))
+    np.testing.assert_array_equal(tr.predict(bins, trees), want)
+    assert len(_named("mp4j.step.build")) == built
+
+
+def test_a_large_shard_is_scored_in_the_pieces_train_stages(monkeypatch):
+    """predict sends its table as ``_put_sharded`` does: a shard at or
+    over ``_ONE_TRANSFER_BYTES`` crosses in pieces of
+    ``_EACH_CHUNK_BYTES``, each scored as it crossed by a program that
+    takes the piece; ``train`` places the same pieces in its table."""
     cfg = _cfg("logistic", "missing")
     bins, trees = _draw(cfg)
     want = GBDTTrainer(cfg, n_devices=4).predict(bins, trees)
     tr = GBDTTrainer(cfg, n_devices=4)
     monkeypatch.setattr(tr, "_ONE_TRANSFER_BYTES", 4 * 1024)
     monkeypatch.setattr(tr, "_EACH_CHUNK_BYTES", 64 * F * 4)
-    chunked = []
-    put = tr._put_in_row_chunks
-    monkeypatch.setattr(
-        tr, "_put_in_row_chunks",
-        lambda a, each=None: chunked.append(a.shape) or put(a, each))
+    cut, placed = [], []
+    cuts, put = tr._array_cuts, tr._put_in_row_chunks
+    monkeypatch.setattr(tr, "_array_cuts",
+                        lambda a: cut.append(a.shape) or cuts(a))
+    monkeypatch.setattr(tr, "_put_in_row_chunks",
+                        lambda a: placed.append(a.shape) or put(a))
     spans.clear()
     np.testing.assert_array_equal(tr.predict(bins, trees), want)
-    assert chunked == [(4, 251, F)]
-    # a chunk is scored as soon as it is placed; the last one starts
-    # early (251 rows in chunks of 64: at 187) and only its new rows
-    # are scored, by a program of their own
+    assert cut == [(4, 251, F)] and placed == []
+    assert _named("mp4j.stage.place") == [] and tr._row_placers == {}
+    # a piece is scored as soon as it is on its way; the last one starts
+    # early (251 rows in pieces of 64: at 187) and only its new rows
+    # are scored, by a program of their own, from the same 64-row piece
     assert [s[-1]["start"] for s in _named("mp4j.gbdt.score.dispatch")] == \
         [0, 64, 128, 192]
-    assert list(tr._score_programs) == [((4, 251, F), 64, ROUNDS),
-                                        ((4, 251, F), 59, ROUNDS)]
-    # and shard_bins is what train stages too
+    assert list(tr._score_programs) == [((4, 64, F), 251, 64, ROUNDS),
+                                        ((4, 64, F), 251, 59, ROUNDS)]
+    # and the same pieces are what train stages
     dbins = tr.shard_data(bins, np.zeros(ROWS, np.float32))[0]
-    assert chunked == [(4, 251, F)] * 2
+    assert cut == [(4, 251, F)] * 2 and placed == [(4, 251, F)]
+    assert len(_named("mp4j.stage.place")) == 4
     np.testing.assert_array_equal(
         np.asarray(dbins).reshape(-1, F)[:ROWS], bins)
     np.testing.assert_array_equal(
         np.asarray(tr.shard_bins(bins)), np.asarray(dbins))
 
 
-def test_more_chunks_than_the_host_runs_ahead(monkeypatch):
-    """The host waits for a chunk to have crossed before it sends the
-    one after the next, whether or not each is scored, and for the device
-    once ``_CHUNKS_AHEAD`` placed chunks wait for it: 16 chunks a shard
+def test_more_pieces_than_the_host_runs_ahead(monkeypatch):
+    """The host waits for a piece to have crossed before it sends the
+    one after the next, whoever takes the pieces, and for the device
+    once ``_CHUNKS_AHEAD`` pieces wait for their turn: 16 pieces a shard
     pass both waits and give the margins of one transfer."""
     cfg = _cfg("logistic", "missing")
     bins, trees = _draw(cfg)
@@ -263,20 +343,76 @@ def test_more_chunks_than_the_host_runs_ahead(monkeypatch):
     waited = []
     ready = jax.block_until_ready
     monkeypatch.setattr(jax, "block_until_ready",
-                        lambda x: waited.append(np.ndim(x)) or ready(x))
+                        lambda x: waited.append(np.shape(x)) or ready(x))
     spans.clear()
     np.testing.assert_array_equal(tr.predict(bins, trees), want)
     starts = [s[-1]["start"] for s in _named("mp4j.gbdt.score.dispatch")]
     assert starts == list(range(0, 256, 16))
-    # chunks waited for as they crossed (all but the last), and the
-    # placer's scalars once more than _CHUNKS_AHEAD were outstanding
+    # pieces waited for as they crossed (all but the last), and the
+    # scoring program's markers (a piece's first word of every shard)
+    # once more than _CHUNKS_AHEAD were outstanding
     assert tr._CHUNKS_CROSSING == 2
-    assert sum(1 for d in waited if d == 3) == 15
-    assert sum(1 for d in waited if d == 0) == 16 - 6
-    # staging alone keeps the same pace: ``train`` waits as ``predict``
+    assert sorted(waited) == [(4,)] * (16 - 6) + [(4, 16, F)] * 15
+    # staging alone keeps the same pace: ``train`` waits as ``predict``,
+    # for the placer's scalars
     waited.clear()
     tr.shard_bins(bins)
-    assert sorted(waited) == [0] * (16 - 6) + [3] * 15
+    assert sorted(waited) == [()] * (16 - 6) + [(4, 16, F)] * 15
+
+
+def test_no_array_of_the_tables_size_is_on_the_mesh(monkeypatch):
+    """While a table is scored in pieces the largest device array made
+    since the call began is a piece, the margins or the model: neither a
+    table nor a copy of one, at any launch."""
+    from tests.helpers import watch_live_arrays
+
+    cfg = _cfg("logistic", "missing")
+    bins, trees = _draw(cfg, rows=4000)
+    for n_devices in (1, 4):
+        tr = _in_pieces(monkeypatch, GBDTTrainer(cfg, n_devices=n_devices),
+                        128)
+        seen = watch_live_arrays(monkeypatch, tr)
+        got = tr.predict(bins, trees)
+        per = -(-4000 // n_devices)
+        assert len(seen) == -(-per // 128) > 7
+        model = max(a.nbytes for a in tr._stack_trees(trees))
+        assert max(seen) == max(n_devices * 128 * F * 4, model,
+                                n_devices * per * 4)
+        assert max(seen) * 4 < bins.nbytes
+        np.testing.assert_array_equal(
+            got, GBDTTrainer(cfg, n_devices=n_devices).predict(bins, trees))
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_a_scoring_job_leaves_the_paces_spans_and_no_place(monkeypatch,
+                                                           n_devices):
+    """Under one ``mp4j.put_sharded`` of the table's bytes: a ``send`` a
+    piece with its bytes, ``link_wait`` and ``device_wait`` by the
+    piece they waited for, and nothing else; no placer is launched."""
+    cfg = _cfg("logistic", "missing")
+    bins, trees = _draw(cfg)
+    tr = _in_pieces(monkeypatch, GBDTTrainer(cfg, n_devices=n_devices), 16)
+    spans.clear()
+    tr.predict(bins, trees)
+    per = -(-ROWS // n_devices)
+    pieces = -(-per // 16)
+    (put,) = _named("mp4j.put_sharded")
+    assert put[-1] == {"bytes": n_devices * per * F * 4}
+    (stage,) = _named("mp4j.gbdt.score.stage")
+    under = [s for s in spans.snapshot() if s[0].startswith(
+        ("mp4j.stage.", "mp4j.stream.")) and s[0] != "mp4j.stage.prep"]
+    assert all(put[2] <= s[2] and s[2] + s[3] <= put[2] + put[3]
+               for s in under)
+    assert stage[2] <= put[2] and put[2] + put[3] <= stage[2] + stage[3]
+    assert {s[0] for s in under} == {
+        "mp4j.stage.send", "mp4j.stage.link_wait", "mp4j.stage.device_wait"}
+    assert [s[-1] for s in _named("mp4j.stage.send")] == [
+        {"chunk": k, "bytes": n_devices * 16 * F * 4} for k in range(pieces)]
+    assert [s[-1]["chunk"] for s in _named("mp4j.stage.link_wait")] == \
+        list(range(pieces - 1))
+    assert [s[-1]["chunk"] for s in _named("mp4j.stage.device_wait")] == \
+        list(range(pieces - tr._CHUNKS_AHEAD))
+    assert len(_named("mp4j.gbdt.score.dispatch")) == pieces
 
 
 def _named(name):
@@ -306,6 +442,8 @@ def test_spans_once_a_job_and_one_build(monkeypatch):
               if s[-1].get("key") == "gbdt_score"]
     assert builds == [{"key": "gbdt_score", "group": 13, "rows": 251,
                        "row_chunk": 251, "row_chunks": 1}]
+    # a table under the limit is one piece, itself
+    assert list(tr._score_programs) == [((4, 251, F), 251, 251, ROUNDS)]
     # another table shape or tree count is another program
     tr.predict(bins[:500], device_trees)
     tr.predict(bins, device_trees[:5])

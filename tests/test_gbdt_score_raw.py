@@ -1,6 +1,7 @@
 """Scoring from floats (ISSUE 47): ``GBDTTrainer.predict_raw_chunks``
-takes a float table with NaN in row chunks, bins a chunk's rows where
-they land on the mesh and scores them while the next ones cross;
+takes a float table with NaN in row chunks, bins a piece's rows on the
+device it went to and scores them while the next ones cross, and lets
+the piece go (ISSUE 52: no table of floats is built);
 ``predict_raw`` is the same entry point over row slices of one array.
 Held to the benchmark's plain float64 reference
 (``benchmark/reference/gbdt_score_raw.py``, which imports nothing of the
@@ -154,12 +155,49 @@ def test_the_margins_do_not_depend_on_the_chunking(job, cuts, n_devices):
 
 
 @pytest.mark.parametrize("n_devices", [1, 4])
+@pytest.mark.parametrize("cuts", sorted(CUTS))
+@pytest.mark.parametrize("piece_rows", [64, 250])
+def test_small_pieces_give_the_margins_of_one_transfer(job, cuts, n_devices,
+                                                       piece_rows,
+                                                       monkeypatch):
+    """Pieces forced small: a chunk is cut under the cap and at shard
+    ends (a chunk that spans two shards, a piece of one row past a
+    boundary, a last shard that ends in a padding row), every piece is
+    scored on the device it went to, and the margins are those of the
+    bins in one transfer, to the bit; every row of the table is scored
+    exactly once, and the padding never."""
+    cfg, X, trees, binner, want = job
+    tr = GBDTTrainer(cfg, n_devices=n_devices)
+    monkeypatch.setattr(tr, "_EACH_CHUNK_BYTES", piece_rows * F * 4)
+    spans.clear()
+    got = tr.predict_raw_chunks(_slices(X, CUTS[cuts]), ROWS, trees,
+                                binner=binner)
+    np.testing.assert_array_equal(got, want)
+    per = -(-ROWS // n_devices)
+    dispatch = [s[-1] for s in _named("mp4j.gbdt.score.dispatch")]
+    assert max(d["rows"] for d in dispatch) <= piece_rows
+    # a shard's pieces follow each other, and a new shard starts at 0
+    shard, at, rows = 0, 0, np.zeros(n_devices, np.int64)
+    for d in dispatch:
+        if d["start"] != at:
+            assert d["start"] == 0 and at == per
+            shard, at = shard + 1, 0
+        at += d["rows"]
+        rows[shard] += d["rows"]
+    assert rows.tolist() == [per] * (n_devices - 1) + [
+        ROWS - (n_devices - 1) * per]
+    assert len(dispatch) == len(_named("mp4j.stage.send"))
+    assert _named("mp4j.stage.place") == [] and tr._row_placers == {}
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
 def test_a_chunk_over_the_staging_size_crosses_and_is_scored_in_pieces(
         job, n_devices, monkeypatch):
     """A reader's chunk is cut into pieces of ``_EACH_CHUNK_BYTES`` at
-    most and at shard ends; each piece of the last shard (every piece,
-    on one device) is scored as it is placed, rows that pad the last
-    shard at the end."""
+    most and at shard ends; each piece is scored as it crosses by the
+    device that holds its shard (on four: every shard's pieces, not the
+    last one's alone), and the rows that pad the last shard are no
+    piece."""
     cfg, X, trees, binner, want = job
     tr = GBDTTrainer(cfg, n_devices=n_devices)
     monkeypatch.setattr(tr, "_EACH_CHUNK_BYTES", 64 * F * 4)
@@ -168,17 +206,83 @@ def test_a_chunk_over_the_staging_size_crosses_and_is_scored_in_pieces(
                                 binner=binner)
     np.testing.assert_array_equal(got, want)
     per = -(-ROWS // n_devices)
-    starts = [s[-1]["start"] for s in _named("mp4j.gbdt.score.dispatch")]
-    assert starts == sorted(starts) and starts[0] == 0
+    dispatch = [s[-1] for s in _named("mp4j.gbdt.score.dispatch")]
+    starts = [d["start"] for d in dispatch]
+    assert sum(d["rows"] for d in dispatch) == ROWS
     if n_devices == 1:
         # 600 rows in ten pieces of 60, 403 in seven of 58 or 57
+        assert starts == sorted(starts) and starts[0] == 0
         assert len(starts) == 17 and starts[1] == 60
-        assert len(_named("mp4j.stage.place")) == 17
     else:
-        # the last shard holds rows 753..1002: 250 of its 251
-        assert starts[-1] == ROWS - (n_devices - 1) * per == per - 1
-    # a float table's programs say its binning in their key
-    assert all(k[3] == (BINS - 2, True) for k in tr._score_programs)
+        # four pieces a shard (251 rows; the chunks' cut at 600 makes a
+        # fifth in the third), the last shard's 250 rows likewise
+        assert [s for s in starts if s == 0] == [0] * 4
+        assert len(starts) == 4 + 4 + 5 + 4
+        assert dispatch[-1]["start"] + dispatch[-1]["rows"] == per - 1
+    assert len(_named("mp4j.stage.send")) == len(starts)
+    assert _named("mp4j.stage.place") == []
+    # a float piece's programs say its binning in their key, and are one
+    # device's own: a piece as it crossed, [rows, F] or [M, 128]
+    assert all(k[4] == (BINS - 2, True) and len(k[0]) == 2
+               for k in tr._score_programs)
+
+
+def test_no_array_of_the_tables_size_is_on_the_mesh(monkeypatch):
+    """While a float table is scored the largest device array made since
+    the call began is a piece, a shard's margins or the model."""
+    from tests.helpers import watch_live_arrays
+
+    cfg = _cfg()
+    X, trees = _table(rows=4000), _trees(cfg)
+    binner = _binner(X)
+    for n_devices in (1, 4):
+        tr = GBDTTrainer(cfg, n_devices=n_devices)
+        monkeypatch.setattr(tr, "_EACH_CHUNK_BYTES", 128 * F * 4)
+        seen = watch_live_arrays(monkeypatch, tr)
+        got = tr.predict_raw_chunks(_slices(X, (1500, 3000)), 4000, trees,
+                                    binner=binner)
+        per = 4000 // n_devices
+        assert len(seen) >= 4000 // 128
+        model = max(a.nbytes for a in (*tr._stack_trees(trees),
+                                       binner.edges.astype(np.float32)))
+        assert max(seen) <= max(128 * F * 4, model, per * 4)
+        assert max(seen) * 4 < X.nbytes
+        np.testing.assert_array_equal(
+            got, tr.predict(reference.bins(X, binner.edges), trees))
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_a_job_leaves_the_paces_spans_and_no_place(job, n_devices,
+                                                   monkeypatch):
+    """Under one ``mp4j.put_sharded`` of the announced table's bytes: a
+    ``stream.next`` a chunk and one more, a ``send`` a piece with its
+    bytes, ``link_wait`` and ``device_wait`` by the piece they waited
+    for, and nothing else."""
+    cfg, X, trees, binner, want = job
+    tr = GBDTTrainer(cfg, n_devices=n_devices)
+    monkeypatch.setattr(tr, "_EACH_CHUNK_BYTES", 16 * F * 4)
+    spans.clear()
+    tr.predict_raw_chunks(_slices(X, (500,)), ROWS, trees, binner=binner)
+    (put,) = _named("mp4j.put_sharded")
+    assert put[-1] == {"bytes": 4 * ROWS * F}
+    under = [s for s in spans.snapshot() if s[0].startswith(
+        ("mp4j.stage.", "mp4j.stream."))]
+    assert all(put[2] <= s[2] and s[2] + s[3] <= put[2] + put[3]
+               for s in under)
+    assert {s[0] for s in under} == {
+        "mp4j.stream.next", "mp4j.stage.send", "mp4j.stage.link_wait",
+        "mp4j.stage.device_wait"}
+    assert [s[-1]["chunk"] for s in _named("mp4j.stream.next")] == [0, 1, 2]
+    sends = [s[-1] for s in _named("mp4j.stage.send")]
+    pieces = len(sends)
+    assert [d["chunk"] for d in sends] == list(range(pieces))
+    assert sum(d["bytes"] for d in sends) == 4 * ROWS * F
+    assert max(d["bytes"] for d in sends) <= 16 * F * 4
+    assert [s[-1]["chunk"] for s in _named("mp4j.stage.link_wait")] == \
+        list(range(pieces - 1))
+    assert [s[-1]["chunk"] for s in _named("mp4j.stage.device_wait")] == \
+        list(range(pieces - tr._CHUNKS_AHEAD))
+    assert len(_named("mp4j.gbdt.score.dispatch")) == pieces
 
 
 @pytest.mark.parametrize("trouble", ["on-an-edge", "repeated-edges",
@@ -347,18 +451,16 @@ def test_a_second_call_of_the_same_shape_builds_nothing(job):
     tr.predict_raw_chunks(_slices(X, (600,)), ROWS, trees)
     builds = [s[-1] for s in _named("mp4j.step.build")]
     scoring = [b for b in builds if b.get("key") == "gbdt_score_raw"]
-    # the last shard's data: rows 753..1002 in one piece, then its
-    # padding row, a program each
-    assert scoring == [
-        {"key": "gbdt_score_raw", "edges": 30, "compares": 5,
-         "bin_block_columns": 8, "bin_block_rows": 4096, "form": "bins",
-         "group": 12, "rows": 250, "row_chunk": 250, "row_chunks": 1},
-        {"key": "gbdt_score_raw", "edges": 30, "compares": 5,
-         "bin_block_columns": 8, "bin_block_rows": 4096, "form": "bins",
-         "group": 12, "rows": 1, "row_chunk": 1, "row_chunks": 1}]
+    # one device's programs, by the piece: a whole shard (251 rows: the
+    # first two), the third's 98 and 153 either side of the chunks' cut,
+    # the last shard's 250; its padding row is no piece and no program
+    said = {"key": "gbdt_score_raw", "edges": 30, "compares": 5,
+            "bin_block_columns": 8, "bin_block_rows": 4096, "form": "bins",
+            "group": 12, "row_chunks": 1}
+    assert scoring == [{**said, "rows": r, "row_chunk": r}
+                       for r in (251, 98, 153, 250)]
     assert list(tr._score_programs) == [
-        ((4, 251, F), 250, ROUNDS, (30, True)),
-        ((4, 251, F), 1, ROUNDS, (30, True))]
+        ((r, F), 251, r, ROUNDS, (30, True)) for r in (251, 98, 153, 250)]
     for _ in range(2):
         np.testing.assert_array_equal(
             tr.predict_raw_chunks(_slices(X, (600,)), ROWS, trees), want)
@@ -367,8 +469,8 @@ def test_a_second_call_of_the_same_shape_builds_nothing(job):
         [0, 1, 2]
     # the same table as bins is another program, kept beside these
     tr.predict(reference.bins(X, binner.edges), trees)
-    assert ((4, 251, F), 251, ROUNDS) in tr._score_programs
-    assert len(tr._score_programs) == 3
+    assert ((4, 251, F), 251, 251, ROUNDS) in tr._score_programs
+    assert len(tr._score_programs) == 5
 
 
 def test_the_binner_rides_save_model_between_training_and_scoring(tmp_path):

@@ -2,7 +2,8 @@
 (ISSUE 50): ``ffm.select`` / ``ffm.pairs`` in the three sparse FFM steps
 (forward as ``jvp(...)``, backward as ``transpose(jvp(...))``),
 ``sparse.fold_live_tiles`` round their update loops, ``gbdt.level.<d>``
-round every level of a tree, ``stage.place`` inside both placers.
+round every level of a tree, ``stage.place`` inside both placers and,
+since ISSUE 52, round the relayout of the piece a scoring program takes.
 
 (a) the lowered programs hold them; (b) they are metadata and nothing
 else: compiled as written and with ``jax.named_scope`` a null context,
@@ -24,11 +25,11 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ytk_mp4j_tpu.models._base import DataParallelTrainer
 from ytk_mp4j_tpu.models.gbdt import GBDTConfig, GBDTTrainer
 from ytk_mp4j_tpu.parallel.mesh import make_mesh
 
-from test_trainer_spans import _lower_ffm, _lower_placer
+from test_trainer_spans import (_lower_chunk_placer, _lower_ffm,
+                                 _lower_placer)
 from tests.helpers import program_without_provenance as _stripped
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -47,29 +48,61 @@ def _lower_gbdt(rng, n_features, **cfg):
         *data, jax.random.key_data(jax.random.key(0)))
 
 
-def _lower_packed_placer(rng):
-    """``_put_in_row_chunks``' program for a tuple of arrays (ids, fields
-    and values a row, side by side: ``FMTrainer.predict``'s staging)."""
-    t = DataParallelTrainer(n_devices=2)
-    n, rows, cols, pad = 2, 128, 5, 1
-    place = t._build_row_placer((n, rows, cols), pad)
-    sharding = t._row_sharding()
-    table = jax.ShapeDtypeStruct((n, 512, 3 * cols + pad), jnp.int32,
-                                 sharding=sharding)
-    chunk = tuple(jax.ShapeDtypeStruct((n, rows * cols // 128, 128), dtype,
-                                       sharding=sharding)
-                  for dtype in (jnp.int32, jnp.int32, jnp.float32))
-    return place.lower(table, chunk, np.int32(0))
+def _stacked(shape, sharding=None):
+    return tuple(jax.ShapeDtypeStruct(shape, d, sharding=sharding)
+                 for d in (jnp.int32, jnp.int32, jnp.int32, jnp.float32))
 
 
-def _lower_chunk_placer(rng):
-    """``_put_row_chunks``' program: a piece of floats into one shard."""
-    t = DataParallelTrainer(n_devices=1)
-    per, width, rows = 512, 16, 64
-    wire = (rows * width // 128, 128)
-    return t._row_chunk_placer(per, width, rows, wire).lower(
-        jax.ShapeDtypeStruct((1, per, width), jnp.float32),
-        jax.ShapeDtypeStruct(wire, jnp.float32), np.int32(0))
+def _lower_score_bins(rng, rows=128):
+    """``GBDTTrainer.predict``'s program for a piece of a binned table as
+    it crossed, a slice of every shard as [n, M, 128] words (``rows``
+    under 128: a last piece that began early)."""
+    F, held, per = 16, 128, 1000
+    tr = GBDTTrainer(GBDTConfig(n_features=F, n_bins=256, depth=3,
+                                loss="logistic", missing_bin=True),
+                     mesh=make_mesh(2))
+    sharding = tr._row_sharding()
+    wire = (2, held * F // 128, 128)
+    return tr._build_score(wire, rows, 5).lower(
+        jax.ShapeDtypeStruct(wire, jnp.int32, sharding=sharding),
+        _stacked((1, 8, 5, 1)),
+        jax.ShapeDtypeStruct((2, 1, per), jnp.float32, sharding=sharding),
+        np.int32(0))
+
+
+def _lower_score_floats(rng):
+    """``predict_raw_chunks``' program for a piece of floats that went
+    to one device, [M, 128] words."""
+    F, held, per = 16, 64, 1000
+    tr = GBDTTrainer(GBDTConfig(n_features=F, n_bins=32, depth=3,
+                                loss="logistic", missing_bin=True),
+                     mesh=make_mesh(2))
+    wire = (held * F // 128, 128)
+    return tr._build_score(wire, held, 5, (30, True)).lower(
+        jax.ShapeDtypeStruct(wire, jnp.float32), _stacked((1, 8, 5, 1)),
+        jax.ShapeDtypeStruct((1, 1, per), jnp.float32), np.int32(0),
+        jax.ShapeDtypeStruct((F, 30), jnp.float32))
+
+
+def _lower_score_ffm(rng):
+    """``FMTrainer.predict``'s program for a piece of instances: ids,
+    fields and values as each crossed, three operands."""
+    from ytk_mp4j_tpu.models import fm
+    from ytk_mp4j_tpu.models.fm import FMConfig, FMTrainer
+
+    K, held, per = 8, 64, 1000
+    tr = FMTrainer(FMConfig(n_features=120, n_fields=5, k=4, max_nnz=K,
+                            model="ffm"), mesh=make_mesh(2))
+    sharding = tr._row_sharding()
+    wire = (2, held * K // 128, 128)
+    return tr._build_score(wire, held).lower(
+        *(jax.ShapeDtypeStruct(wire, d, sharding=sharding)
+          for d in (jnp.int32, jnp.int32, jnp.float32)),
+        (jax.ShapeDtypeStruct((), jnp.float32),
+         jax.ShapeDtypeStruct((120, fm._block_width(tr._score_cfg)),
+                              jnp.float32)),
+        jax.ShapeDtypeStruct((2, per), jnp.float32, sharding=sharding),
+        np.int32(0))
 
 
 FFM_STEP = ["jvp(ffm.select)", "transpose(jvp(ffm.select))",
@@ -97,7 +130,20 @@ PROGRAMS = {
     "gbdt-bosch": (partial(_lower_gbdt, n_features=968, missing_bin=True),
                    LEVELS, "gbdt.level.0"),
     "placer": (_lower_placer, ["stage.place"], "stage.place"),
-    "placer-packed": (_lower_packed_placer, ["stage.place"], "stage.place"),
+    # the scoring programs take the piece that crossed (ISSUE 52): its
+    # relayout is theirs, under the placers' name
+    "score-bins": (_lower_score_bins,
+                   ["stage.place", "gbdt.score.select", "gbdt.score.walk"],
+                   "stage.place"),
+    "score-bins-rest": (partial(_lower_score_bins, rows=40),
+                        ["stage.place", "gbdt.score.select",
+                         "gbdt.score.walk"], "gbdt.score.select"),
+    "score-floats": (_lower_score_floats,
+                     ["stage.place", "bin.transform", "gbdt.score.select",
+                      "gbdt.score.walk"], "stage.place"),
+    "score-ffm": (_lower_score_ffm,
+                  ["stage.place", "ffm.table_gather", "ffm.score.select",
+                   "ffm.score.pairs"], "stage.place"),
     "placer-chunks": (_lower_chunk_placer, ["stage.place"], "stage.place"),
 }
 
@@ -114,8 +160,29 @@ def test_lowered_program_holds_the_new_scopes(program):
         # a name stack, not a file's path: the scopes are whole components
         assert re.search(rf'loc\("(?:[^"]*/)?{re.escape(stack)}[/"]', text), \
             stack
-    # the scoring program's names stay the scoring program's
-    assert "ffm.score." not in text
+    # the scoring programs' names stay the scoring programs'
+    assert ("ffm.score." in text) == (program == "score-ffm")
+    assert ("gbdt.score." in text) == (
+        program.startswith("score-") and program != "score-ffm")
+
+
+# what a scoring program may slice or update in place: a piece or the
+# results, never an array of the input's size (there is none to take)
+@pytest.mark.parametrize("program,largest", [
+    ("score-bins", 2 * 1000), ("score-bins-rest", 2 * 128 * 16),
+    ("score-floats", 1000), ("score-ffm", 2 * 1000)])
+def test_a_scoring_program_slices_no_table(program, largest):
+    """``dynamic_slice`` and ``dynamic_update_slice`` in the lowered
+    text: of a piece's rows (a last piece's, or a tile's) and of the
+    results, whose sizes are known here; nothing else is taken apart."""
+    text = _lowered(program).as_text()
+    ops = re.findall(r"stablehlo\.dynamic_(?:update_)?slice.*?: \(tensor<"
+                     r"([\dx]+)x\w+>", text)
+    assert ops, text[:400]
+    for dims in ops:
+        assert np.prod([int(d) for d in dims.split("x")]) <= largest, dims
+    # the piece comes in as it crossed and is put into rows in here
+    assert re.search(r"stablehlo\.reshape.*tensor<[\dx]*128x\w+>\) -> ", text)
 
 
 def test_a_level_wraps_its_scopes_and_hides_none():

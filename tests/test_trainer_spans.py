@@ -345,15 +345,24 @@ def _chunked(n_shards, per=1000, width=16):
 
 
 @pytest.mark.parametrize("n_shards", [1, 4])
-@pytest.mark.parametrize("with_each", [False, True], ids=["stage", "each"])
-def test_row_chunks_leave_a_send_and_a_place_a_chunk(ring, n_shards,
-                                                     with_each):
+@pytest.mark.parametrize("taker", ["table", "pieces"])
+def test_row_chunks_leave_a_send_a_chunk_and_the_paces_waits(ring, n_shards,
+                                                             taker):
+    """One pace whoever takes the pieces: the table builder
+    (``_put_sharded``), which launches its placer under ``place``, or a
+    caller that reads each piece once (``_pieces``: a scoring call),
+    which launches what it likes and leaves no ``place``."""
     t, a = _chunked(n_shards)
     calls = []
-    got = t._put_sharded(a, 1000, each=(
-        lambda table, start, stop: calls.append((start, stop)))
-        if with_each else None)
-    np.testing.assert_array_equal(np.asarray(got).reshape(a.shape), a)
+    if taker == "table":
+        got = np.asarray(t._put_sharded(a, 1000))
+    else:
+        got = np.zeros((n_shards, 1000, 16), np.int32)
+        for k, piece, shard, start, stop, turns in t._pieces(a, 1000):
+            calls.append((start, stop))
+            got[:, start:stop] = np.asarray(piece).reshape(n_shards, -1, 16)
+            turns.append(piece.reshape(-1)[:1])
+    np.testing.assert_array_equal(got.reshape(a.shape), a)
     recorded = _trainer_spans()
     (put,) = _named(recorded, "mp4j.put_sharded")
     assert put[6] == {"bytes": a.nbytes}
@@ -369,19 +378,21 @@ def test_row_chunks_leave_a_send_and_a_place_a_chunk(ring, n_shards,
         {"chunk": k, "bytes": n_shards * 64 * 16 * 4} for k in range(16)]
     assert sum(s[6]["bytes"] for s in send) \
         == a.nbytes + n_shards * 24 * 16 * 4
-    assert [s[6] for s in place] == [{"chunk": k} for k in range(16)]
-    assert all(s[2] + s[3] <= p[2] for s, p in zip(send, place))
     link = [s[6]["chunk"] for s in _named(stage, "mp4j.stage.link_wait")]
     device = [s[6]["chunk"] for s in _named(stage, "mp4j.stage.device_wait")]
-    # one pace, work on every chunk or none: two crossing, so chunk k - 1
-    # has crossed before k + 1 is sent; the device is waited for only
-    # when twelve wait for their turn
+    # two crossing, so chunk k - 1 has crossed before k + 1 is sent; the
+    # device is waited for only when twelve wait for their turn
     assert link == list(range(15)) and device == list(range(4))
-    assert calls == ([(min(64 * k, 1000 - 64), min(64 * k, 1000 - 64) + 64)
-                      for k in range(16)] if with_each else [])
+    if taker == "table":
+        assert [s[6] for s in place] == [{"chunk": k} for k in range(16)]
+        assert all(s[2] + s[3] <= p[2] for s, p in zip(send, place))
+    else:
+        assert place == [] and t._row_placers == {}
+        assert calls == [(min(64 * k, 1000 - 64), min(64 * k, 1000 - 64) + 64)
+                         for k in range(16)]
     assert {s[0] for s in stage} == {
-        "mp4j.stage.send", "mp4j.stage.place", "mp4j.stage.device_wait",
-        "mp4j.stage.link_wait"}
+        "mp4j.stage.send", "mp4j.stage.device_wait",
+        "mp4j.stage.link_wait"} | ({"mp4j.stage.place"} if place else set())
 
 
 @pytest.mark.parametrize("n_shards", [1, 4])
@@ -485,15 +496,19 @@ def test_a_slow_iterator_shows_in_stream_next_and_nowhere_else(rng, ring,
 
 
 @pytest.mark.parametrize("family", ["gbdt", "ffm", "row-chunks",
-                                    "row-chunks-each"])
+                                    "row-chunks-scored"])
 def test_ring_off_leaves_no_span_and_the_same_bits(rng, family):
     def run():
         r = np.random.default_rng(7)
-        if family.startswith("row-chunks"):
+        if family == "row-chunks":
             t, a = _chunked(N_SHARDS)
-            each = ((lambda table, start, stop: None)
-                    if family.endswith("each") else None)
-            return [np.asarray(t._put_sharded(a, 1000, each=each))]
+            return [np.asarray(t._put_sharded(a, 1000))]
+        if family == "row-chunks-scored":
+            # a table scored in pieces as they cross: no table, no placer
+            tr, bins, y = _gbdt(r)
+            trees, _ = tr.train(bins, y)
+            tr._ONE_TRANSFER_BYTES, tr._EACH_CHUNK_BYTES = 1, 16 * 4 * 4
+            return [tr.predict(bins, trees)]
         if family == "gbdt":
             tr, bins, y = _gbdt(r)
             trees, margins = tr.train(bins, y)
@@ -708,6 +723,16 @@ def _lower_score(rng):
         table, tr._stack_trees(trees), margins, np.int32(0))
 
 
+def _lower_chunk_placer(rng):
+    """``_put_row_chunks``' program: a piece of floats into one shard."""
+    t = DataParallelTrainer(n_devices=1)
+    per, width, rows = 512, 16, 64
+    wire = (rows * width // 128, 128)
+    return t._row_chunk_placer(per, width, rows, wire).lower(
+        jax.ShapeDtypeStruct((1, per, width), jnp.float32),
+        jax.ShapeDtypeStruct(wire, jnp.float32), np.int32(0))
+
+
 # sha256 of the lowered text of every program a staging or a stream span
 # is near, taken on the parent of the PR that added those spans (ISSUE 34)
 # with this file's helpers: the spans are the host's and no line of a
@@ -718,17 +743,25 @@ def _lower_score(rng):
 # component; PR 39: the SGD FFM step's, which merges its slots' gradients
 # and scatter-adds the merged list's live prefix in tiles, the AdaGrad
 # step's as it was; PR 45: the GBDT step's, whose routing slices a level's
-# split columns out of the table where ``route_sliced`` says so).
+# split columns out of the table where ``route_sliced`` says so; PR 52:
+# the scoring program's, which takes the piece that crossed, here a whole
+# table in one transfer, and returns its first word beside the margins).
 LOWERED = {
     "placer": (
         _lower_placer,
         "2582168225277df2d3bae310438d4541f84c320762e7cd20458db82dc974884a"),
+    # (ISSUE 52: taken on the parent too, where this helper lay in
+    # tests/test_trainer_scopes.py: both table builders' programs are
+    # the parent's while the scoring programs take the pieces)
+    "placer-chunks": (
+        _lower_chunk_placer,
+        "8dd770276c0e0094b448006f93a10334ef5e2fe8e3539bd2a1030b43a4c441e9"),
     "gbdt": (
         _lower_gbdt,
         "c3e37de0608b1e8c44a42c1d84f131879470c6edac17d5653f29d6035e212cbd"),
     "score": (
         _lower_score,
-        "65e9cddff36da71d3c0d1b039b56f4d4137a955b457d12271ce2745044751514"),
+        "be2711e774c1f0b618daa789b01328e705b0ce2d6053a038d962d494c230180f"),
     "ffm": (
         _lower_ffm,
         "5fc1fd06e712c79b80a05be56bbf588a5809d6cb094459d1c50524c713ad2510"),

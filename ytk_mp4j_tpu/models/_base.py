@@ -114,17 +114,6 @@ class EarlyStopper:
                 and round_idx - self.best_round >= self.rounds)
 
 
-def packed_width(words: int) -> int:
-    """32-bit words in a row of a table that ``_put_in_row_chunks`` packs
-    out of several arrays: ``words`` rounded up to whole 8s. A width of
-    whole 8s rests on the TPU as [width, rows] in (8, 128) tiles, and a
-    program slices a tile of rows out of it as it lies; any other width
-    rests in (1, 128) tiles and the program copies all of it before it
-    reads a row (AOT for v5e, 6,042,135 x 117: 3.03 GB of temporaries a
-    call, none at 120)."""
-    return -(-words // 8) * 8
-
-
 class DataParallelTrainer:
     """Mesh bookkeeping + sample sharding shared by the trainers."""
 
@@ -133,7 +122,7 @@ class DataParallelTrainer:
         self.axes = (self.mesh.axis_names[0]
                      if len(self.mesh.axis_names) == 1
                      else tuple(self.mesh.axis_names))
-        self._row_placers = {}      # _put_in_row_chunks' programs
+        self._row_placers = {}      # the table builders' programs
 
     @property
     def n_shards(self) -> int:
@@ -152,6 +141,15 @@ class DataParallelTrainer:
         sh = NamedSharding(self.mesh, P())
         return jax.tree_util.tree_map(
             lambda p: jax.device_put(p, sh), tree)
+
+    @staticmethod
+    def _replica_on(tree, device):
+        """``device``'s copy of every array of a pytree that
+        ``_place_replicated`` placed: what a program of that device
+        alone takes."""
+        return jax.tree_util.tree_map(
+            lambda p: next(s.data for s in p.addressable_shards
+                           if s.device == device), tree)
 
     def _pad_rows(self, arrays: list[np.ndarray], weights: bool = True):
         """Pad dim 0 of each array to a multiple of ``n_shards``; returns
@@ -282,7 +280,7 @@ class DataParallelTrainer:
                 "sample_weight sums to zero: nothing to train on")
         return sw
 
-    def _put_sharded(self, a: np.ndarray, per: int, each=None):
+    def _put_sharded(self, a: np.ndarray, per: int):
         """Reshape [n*per, ...] -> [n, per, ...] and place on the mesh.
 
         ``make_array_from_callback`` (each process materializes only its
@@ -294,13 +292,9 @@ class DataParallelTrainer:
         device_put on single-process meshes.
 
         A shard of ``_ONE_TRANSFER_BYTES`` or more crosses in row chunks
-        instead (``_put_in_row_chunks``).
-
-        ``each(table, start, stop)``, where given, is called as soon as
-        rows [start, stop) of every shard are on their way into
-        ``table`` (the array that holds them; it is only good until the
-        next call): after every chunk, or once for the whole shard. What
-        it dispatches on those rows runs while the rest crosses.
+        instead (``_put_in_row_chunks``). This is the table builder (a
+        training job reads its table for every tree); a scoring call,
+        which reads a row once, takes the same rows from :meth:`_pieces`.
 
         The ``mp4j.put_sharded`` span's children say what the host waited
         for: ``mp4j.stage.send`` (the hand-over to the runtime, which is
@@ -312,13 +306,28 @@ class DataParallelTrainer:
         with spans.span("mp4j.put_sharded", bytes=a.nbytes):
             a = a.reshape((self.n_shards, per) + a.shape[1:])
             if a.nbytes // self.n_shards >= self._ONE_TRANSFER_BYTES:
-                return self._put_in_row_chunks(a, each)
-            with spans.span("mp4j.stage.send", chunk=0, bytes=a.nbytes):
-                table = jax.make_array_from_callback(
-                    a.shape, self._row_sharding(), lambda idx: a[idx])
-            if each is not None:
-                each(table, 0, per)
-            return table
+                return self._put_in_row_chunks(a)
+            return self._send_whole(a)
+
+    def _send_whole(self, a: np.ndarray):
+        """``a`` [n_shards, per, ...] onto the mesh in one transfer."""
+        with spans.span("mp4j.stage.send", chunk=0, bytes=a.nbytes):
+            return jax.make_array_from_callback(
+                a.shape, self._row_sharding(), lambda idx: a[idx])
+
+    def _pieces(self, a: np.ndarray, per: int):
+        """The rows ``_put_sharded`` places, for a caller that reads each
+        once: yields what :meth:`_crossed` yields, and builds no table.
+        An array that crosses in one transfer is one piece, itself
+        ([n_shards, per, ...]); a shard of ``_ONE_TRANSFER_BYTES`` or
+        more comes in ``_array_cuts``' pieces. The spans are
+        ``_put_sharded``'s less ``place``."""
+        with spans.span("mp4j.put_sharded", bytes=a.nbytes):
+            a = a.reshape((self.n_shards, per) + a.shape[1:])
+            if a.nbytes // self.n_shards >= self._ONE_TRANSFER_BYTES:
+                yield from self._crossed(self._array_cuts(a))
+            else:
+                yield 0, self._send_whole(a), None, 0, per, []
 
     # One host-to-device transfer of 2**32 bytes or more falls off a
     # cliff in this runtime (my chip runs, PR 26, v5e host, int32
@@ -329,8 +338,8 @@ class DataParallelTrainer:
     # chunks; a smaller one is the runtime's to pace (the Higgs bins,
     # 1.23 GB, in chunks: a 2.704 s job 20 ms longer, PR 46).
     _ONE_TRANSFER_BYTES = 2 ** 32
-    # The one pace of a table that crosses in chunks, with work
-    # dispatched on every chunk (``each``) or with none. The host waits
+    # The one pace of everything that crosses in pieces (``_crossed``),
+    # whether a placer or a scoring program takes them. The host waits
     # for a chunk to have crossed before it sends the one after the
     # next: two in flight cross at 13.5 GB/s; one at a time leaves the
     # link idle between chunks (9.8 GB/s), three share it to no gain.
@@ -356,55 +365,95 @@ class DataParallelTrainer:
     _CHUNKS_CROSSING = 2
     _CHUNKS_AHEAD = 12
 
-    def _put_in_row_chunks(self, a: np.ndarray, each=None):
-        """``a`` [n_shards, per, ...] onto the mesh, rows sharded, a chunk
-        of rows at a time: a ``dynamic_update_slice`` places each chunk
-        in the donated table while the next ones are on their way, at
-        the one pace the constants above set; the table is never held
-        twice. ``each``, where given, is called for every chunk as it is
-        placed (``_put_sharded``).
+    def _crossed(self, cuts):
+        """The one pacing loop. ``cuts`` yields ``(send, bytes, shard,
+        start, stop)``: ``send()`` hands the runtime rows ``start`` to
+        ``stop`` of shard ``shard`` (None: of every shard) and returns
+        the device array (or a tuple of them). Yields ``(chunk, piece,
+        shard, start, stop, turns)`` as soon as the piece is on its way:
+        the consumer launches what reads it (a placer, a scoring
+        program), appends to ``turns`` something small that is there
+        when the device has had the piece, and keeps no reference to
+        it. Then the host waits, at the constants' pace, for the piece
+        before the last to have crossed (``link_wait``), which it lets
+        go, and for the oldest turn once more than ``_CHUNKS_AHEAD`` are
+        outstanding (``device_wait``)."""
+        crossing, turns = [], []
+        for k, (send, nbytes, shard, start, stop) in enumerate(cuts):
+            with spans.span("mp4j.stage.send", chunk=k, bytes=nbytes):
+                piece = send()
+            yield k, piece, shard, start, stop, turns
+            crossing.append(piece)
+            del piece
+            if len(crossing) >= self._CHUNKS_CROSSING:
+                with spans.span("mp4j.stage.link_wait",
+                                chunk=k + 1 - len(crossing)):
+                    jax.block_until_ready(crossing.pop(0))
+            if len(turns) > self._CHUNKS_AHEAD:
+                with spans.span("mp4j.stage.device_wait",
+                                chunk=k + 1 - len(turns)):
+                    jax.block_until_ready(turns.pop(0))
 
-        A chunk crosses as [n_shards, M, 128], which rests on the device
-        in the order the host holds it, so the host's runtime has nothing
-        to transpose and the device puts the chunk into the table's
-        layout while the next one crosses. My chip runs, PR 26, the
-        4.58 GB Bosch table: 0.425 s (0.4243-0.4253 over four) against
-        0.470 s (0.466-0.482 over ten) when each chunk crossed in its
-        own shape and the host's threads tiled it; whole jobs of 8
-        trees, six each in one process, 10.014 s with a quartile
-        distance of 0.023 against 10.081 s and 0.093. A chunk whose
-        elements do not fill rows of 128 crosses in its own shape.
+    def _rows_a_piece(self, per: int, row_bytes: int) -> int:
+        """Rows of every shard a piece of ``_array_cuts`` holds: what
+        fits ``_EACH_CHUNK_BYTES``, in whole rows of 128 lanes."""
+        rows = max(1, min(per, self._EACH_CHUNK_BYTES // row_bytes))
+        return rows - rows % 128 if rows >= 128 else rows
 
-        A tuple ``a`` of arrays of 32-bit elements, each [n_shards, per,
-        columns], is staged as ONE table of int32 words, a row the
-        arrays' rows side by side (:func:`packed_width` words, the last
-        ones zero): every array crosses on its own, a chunk of rows at a
-        time and as the host holds it, and the placer puts the chunks'
-        rows together on the device. The host copies nothing
-        (``FMTrainer.predict`` stages ids, fields and values so: packed
-        on the host, 134 MB a chunk took it 235 ms, longer than the
-        device took to score the chunk; my chip run, PR 36)."""
-        packed = isinstance(a, tuple)
-        parts = a if packed else (a,)
+    def _array_cuts(self, a):
+        """``_crossed``'s cuts of ``a`` [n_shards, per, ...] held whole
+        by the host: ``_rows_a_piece`` rows of every shard a piece, each
+        a row-sharded array that crosses as [n_shards, M, 128]. That
+        rests on the device in the order the host holds it, so the host's
+        runtime has nothing to transpose and the device puts the piece
+        into its reader's layout while the next one crosses. My chip
+        runs, PR 26, the 4.58 GB Bosch table: 0.425 s (0.4243-0.4253
+        over four) against 0.470 s (0.466-0.482 over ten) when each
+        chunk crossed in its own shape and the host's threads tiled it;
+        whole jobs of 8 trees, six each in one process, 10.014 s with a
+        quartile distance of 0.023 against 10.081 s and 0.093. A piece
+        whose elements do not fill rows of 128 crosses in its own shape.
+        The last piece is as long as the others: it starts early, over
+        rows the piece before it brought.
+
+        A tuple ``a`` of arrays is cut alike, a piece the tuple of its
+        pieces, each crossing on its own: the host copies nothing
+        (``FMTrainer.predict``'s ids, fields and values: packed on the
+        host, 134 MB a chunk took it 235 ms, longer than the device took
+        to score the chunk; my chip run, PR 36)."""
+        parts = a if isinstance(a, tuple) else (a,)
         n, per = parts[0].shape[:2]
         cols = [int(np.prod(p.shape[2:])) for p in parts]
-        row = sum(cols)                         # elements a row
-        rows = max(1, min(per, self._EACH_CHUNK_BYTES
-                          // (row * parts[0].itemsize)))
-        if rows >= 128:
-            rows -= rows % 128                  # whole rows of 128 lanes
-        shapes = [(n, rows) + p.shape[2:] for p in parts]
-        wires = [(n, rows * c // 128, 128) if rows * c % 128 == 0 else shape
-                 for c, shape in zip(cols, shapes)]
-        shape = shapes[0]
+        rows = self._rows_a_piece(per, sum(cols) * parts[0].itemsize)
+        wires = [(n, rows * c // 128, 128) if rows * c % 128 == 0
+                 else (n, rows) + p.shape[2:] for c, p in zip(cols, parts)]
         sharding = self._row_sharding()
-        if packed:
-            width = packed_width(row)
-            table_shape, dtype = (n, per, width), np.dtype(np.int32)
-            key = (table_shape, tuple(p.dtype.str for p in parts), rows)
-        else:
-            table_shape, dtype = a.shape, a.dtype
-            key = (a.shape, a.dtype.str, rows)
+        for start in range(0, per, rows):
+            start = min(start, per - rows)
+            chunks = [p[:, start:start + rows] for p in parts]
+
+            def send(chunks=chunks):
+                sent = tuple(
+                    jax.make_array_from_callback(
+                        wire, sharding,
+                        lambda idx, chunk=chunk, wire=wire: chunk[
+                            idx[0]].reshape((-1,) + wire[1:]))
+                    for chunk, wire in zip(chunks, wires))
+                return sent if isinstance(a, tuple) else sent[0]
+
+            yield (send, sum(c.nbytes for c in chunks), None, start,
+                   start + rows)
+
+    def _put_in_row_chunks(self, a: np.ndarray):
+        """``a`` [n_shards, per, ...] onto the mesh, rows sharded, a
+        piece of rows at a time (``_array_cuts``, at ``_crossed``'s
+        pace): a ``dynamic_update_slice`` places each piece in the
+        donated table while the next ones are on their way; the table is
+        never held twice."""
+        n, per = a.shape[:2]
+        rows = self._rows_a_piece(
+            per, int(np.prod(a.shape[2:])) * a.itemsize)
+        key = (a.shape, a.dtype.str, rows)
         # one program a (table, chunk) shape, kept with the trainer: a
         # job after the first builds nothing
         place = self._row_placers.get(key)
@@ -412,181 +461,133 @@ class DataParallelTrainer:
             with spans.span("mp4j.step.build", key="row_placer",
                             rows=rows):
                 place = self._row_placers[key] = self._build_row_placer(
-                    shape, width - row if packed else None)
-        table = jnp.zeros(table_shape, dtype, device=sharding)
-        placed, crossing = [], []
-        for k, start in enumerate(range(0, per, rows)):
-            # the last chunk is as long as the others: it starts early
-            # and rewrites rows the chunk before it already placed
-            start = min(start, per - rows)
-            chunks = [p[:, start:start + rows] for p in parts]
-            with spans.span("mp4j.stage.send", chunk=k,
-                            bytes=sum(c.nbytes for c in chunks)):
-                dchunks = tuple(
-                    jax.make_array_from_callback(
-                        wire, sharding,
-                        lambda idx, chunk=chunk, wire=wire: chunk[
-                            idx[0]].reshape((-1,) + wire[1:]))
-                    for chunk, wire in zip(chunks, wires))
-            dchunk = dchunks if packed else dchunks[0]
+                    (n, rows) + a.shape[2:])
+        table = jnp.zeros(a.shape, a.dtype, device=self._row_sharding())
+        for k, piece, _, start, _, turns in self._crossed(
+                self._array_cuts(a)):
             with spans.span("mp4j.stage.place", chunk=k):
-                table, done = place(table, dchunk, np.int32(start))
-            placed.append(done)
-            crossing.append(dchunk)
-            if each is not None:
-                each(table, start, start + rows)
-            if len(crossing) >= self._CHUNKS_CROSSING:
-                with spans.span("mp4j.stage.link_wait",
-                                chunk=k + 1 - len(crossing)):
-                    jax.block_until_ready(crossing.pop(0))
-            if len(placed) > self._CHUNKS_AHEAD:
-                with spans.span("mp4j.stage.device_wait",
-                                chunk=k + 1 - len(placed)):
-                    jax.block_until_ready(placed.pop(0))
+                table, done = place(table, piece, np.int32(start))
+            turns.append(done)
         return table
 
-    def _build_row_placer(self, shape, pad: int | None = None):
+    def _build_row_placer(self, shape):
         """``_put_in_row_chunks``' program: a chunk as it crossed into the
         donated table at a row it is told; also returns the chunk's first
         word, which is there when the chunk has been placed. ``shape``:
-        the chunk's in the table; ``pad``: a tuple's zero words a row."""
-        n, rows = shape[:2]
-
+        the chunk's in the table."""
         def place(table, chunk, start):
             # the device's side of ``mp4j.stage.place``, by name in a trace
             with jax.named_scope("stage.place"):
-                if pad is not None:
-                    words = [jax.lax.bitcast_convert_type(
-                        c.reshape(n, rows, -1), jnp.int32) for c in chunk]
-                    words.append(jnp.zeros((n, rows, pad), jnp.int32))
                 at = [jnp.zeros((), start.dtype)] * table.ndim
                 at[1] = start
-                piece, first = (
-                    (chunk.reshape(shape), chunk) if pad is None
-                    else (jnp.concatenate(words, axis=2), chunk[0]))
-                return (jax.lax.dynamic_update_slice(table, piece, at),
-                        first.reshape(-1)[0])
+                return (jax.lax.dynamic_update_slice(
+                    table, chunk.reshape(shape), at),
+                        chunk.reshape(-1)[0])
         return jax.jit(place, donate_argnums=0,
                        out_shardings=(self._row_sharding(), None))
 
-    def _put_row_chunks(self, chunks, n_rows: int, width: int, each=None):
-        """Rows that arrive a chunk at a time, each ``[m, width]`` f32 in
-        the order of the table, onto the mesh as ``[n_shards, rows a
-        shard, width]`` f32, rows sharded as ``_pad_rows`` and
-        ``_put_sharded`` shard them (row r is row ``r % per`` of shard
-        ``r // per``): what ``_put_in_row_chunks`` does with an array it
-        holds whole, for a caller (a file's reader) who never holds one.
-        The table is allocated once from ``n_rows``, every cell NaN, so
-        the rows that pad the last shard are empty; a chunk crosses as
-        it arrives, as ``[M, 128]`` words where its cells fill them (the
-        host tiles nothing), and a donated ``dynamic_update_slice``
-        places it, so the table is never held twice. A chunk goes to the
-        device that holds its rows, cut where it spans two shards and
-        into pieces of ``_EACH_CHUNK_BYTES`` at most, two crossing at a
-        time and up to ``_CHUNKS_AHEAD`` waiting to be placed
-        (``_put_in_row_chunks``' pace; the ledger's notes of PR 43 have
-        4.58 GB of floats at 0.339-0.341 s so). One placer a (shard,
-        piece) shape, kept with the trainer.
+    def _shard_devices(self, shape) -> dict:
+        """shard -> the device that holds it (this process's only) of a
+        row-sharded array of ``shape`` [n_shards, ...]."""
+        return {index[0].start or 0: device for device, index in
+                self._row_sharding().addressable_devices_indices_map(
+                    shape).items()}
 
-        ``each(table, start, stop)``, where given, is ``_put_sharded``'s
-        hand-over: called as soon as rows [start, stop) of EVERY shard
-        are on their way into ``table`` (the whole array; only good
-        until the next call), so that what it dispatches on them runs
-        while the next pieces cross. The rows arrive in the table's
-        order, shard after shard, so that is after every piece of the
-        last shard (of the only one, on one device: after every piece),
-        and once more at the end for the rows the last shard's data does
-        not reach (its padding). Every row of a shard is handed over
-        exactly once.
+    def _reader_cuts(self, chunks, n_rows: int, width: int):
+        """``_crossed``'s cuts of rows that arrive a chunk at a time,
+        each ``[m, width]`` f32 in the order of a table ``[n_shards,
+        rows a shard, width]`` sharded as ``_put_sharded`` shards one
+        (row r is row ``r % per`` of shard ``r // per``), for a caller (a
+        file's reader) who never holds the table. A chunk is cut where it
+        spans two shards and evenly into pieces of ``_EACH_CHUNK_BYTES``
+        at most; a piece goes to the device that holds its shard
+        (another process's rows are passed over), as ``[M, 128]`` words
+        where its cells fill them (the host tiles nothing). The rows
+        that pad the last shard are no piece.
 
         A chunk of another width, or chunks that do not add up to
         ``n_rows``, raise: nothing is padded in silence. The iterator's
-        arrays must stay as they are until the table is returned (a
-        reader that refills one buffer has to yield copies).
-
-        The spans are ``_put_sharded``'s: one ``mp4j.put_sharded``
-        (``bytes``: the table's, known from ``n_rows``) round the loop;
-        under it ``mp4j.stream.next`` (the caller's iterator),
-        ``mp4j.stage.send`` / ``place`` / ``link_wait`` /
-        ``device_wait`` a piece."""
+        arrays must stay as they are until the last piece has been
+        taken (a reader that refills one buffer has to yield copies).
+        The caller's iterator runs under ``mp4j.stream.next``."""
         from ytk_mp4j_tpu.exceptions import Mp4jError
 
+        per = max(1, -(-n_rows // self.n_shards))
+        where = self._shard_devices((self.n_shards, per, width))
+        piece_rows = max(1, self._EACH_CHUNK_BYTES // (4 * width))
+        done = 0
+        it, end = iter(chunks), object()
+        for k in itertools.count():
+            with spans.span("mp4j.stream.next", chunk=k):
+                chunk = next(it, end)
+            if chunk is end:
+                break
+            chunk = np.ascontiguousarray(chunk, np.float32)
+            if chunk.ndim != 2 or chunk.shape[1] != width:
+                raise Mp4jError(
+                    f"chunk {k} must be [rows, {width}], got "
+                    f"{chunk.shape}")
+            if done + len(chunk) > n_rows:
+                raise Mp4jError(
+                    f"chunk {k} brings the rows to "
+                    f"{done + len(chunk)}, more than n_rows={n_rows}")
+            # pieces: cut at shard ends, then evenly under the cap
+            at = 0
+            while at < len(chunk):
+                shard, start = divmod(done + at, per)
+                m = min(len(chunk) - at, per - start)
+                parts = -(-m // piece_rows)
+                m = min(m, -(-m // parts))
+                piece = chunk[at:at + m]
+                at += m
+                if shard in where:          # not another process's rows
+                    wire = ((m * width // 128, 128)
+                            if m * width % 128 == 0 else (m, width))
+                    yield (lambda piece=piece, wire=wire, shard=shard:
+                           jax.device_put(piece.reshape(wire), where[shard]),
+                           piece.nbytes, shard, start, start + m)
+            done += len(chunk)
+        if done != n_rows:
+            raise Mp4jError(
+                f"the chunks hold {done} rows, n_rows={n_rows} were "
+                f"announced")
+
+    def _reader_pieces(self, chunks, n_rows: int, width: int):
+        """``_reader_cuts``' pieces as they crossed, at ``_crossed``'s
+        pace (the ledger's notes of PR 43 have 4.58 GB of floats at
+        0.339-0.341 s so), under one ``mp4j.put_sharded`` whose
+        ``bytes`` are the announced table's, built or not; under it
+        ``mp4j.stream.next`` and ``mp4j.stage.send`` / ``link_wait`` /
+        ``device_wait`` a piece, and what the consumer adds."""
+        with spans.span("mp4j.put_sharded", bytes=4 * n_rows * width):
+            yield from self._crossed(
+                self._reader_cuts(chunks, n_rows, width))
+
+    def _put_row_chunks(self, chunks, n_rows: int, width: int):
+        """The table builder over ``_reader_pieces``: the rows onto the
+        mesh as ``[n_shards, rows a shard, width]`` f32, what
+        ``_put_in_row_chunks`` does with an array it holds whole. The
+        table is allocated once from ``n_rows``, every cell NaN, so the
+        rows that pad the last shard are empty, and a donated
+        ``dynamic_update_slice`` places each piece on the device it went
+        to (span ``mp4j.stage.place``): never held twice. One placer a
+        (shard, piece) shape, kept with the trainer."""
         n = self.n_shards
         per = max(1, -(-n_rows // n))
-        sharding = self._row_sharding()
         shape = (n, per, width)
-        # shard -> the device that holds it (this process's only)
-        where = {index[0].start or 0: device for device, index in
-                 sharding.addressable_devices_indices_map(shape).items()}
         tables = {s: jnp.full((1, per, width), jnp.nan, jnp.float32,
-                              device=d) for s, d in where.items()}
-        piece_rows = max(1, self._EACH_CHUNK_BYTES // (4 * width))
-        placed, crossing = [], []
-        done, sent = 0, 0
-        handed = 0                  # rows of every shard ``each`` has had
-
-        def whole():
-            return jax.make_array_from_single_device_arrays(
-                shape, sharding, [tables[s] for s in sorted(tables)])
-
-        it, end = iter(chunks), object()
-        with spans.span("mp4j.put_sharded", bytes=4 * n_rows * width):
-            for k in itertools.count():
-                with spans.span("mp4j.stream.next", chunk=k):
-                    chunk = next(it, end)
-                if chunk is end:
-                    break
-                chunk = np.ascontiguousarray(chunk, np.float32)
-                if chunk.ndim != 2 or chunk.shape[1] != width:
-                    raise Mp4jError(
-                        f"chunk {k} must be [rows, {width}], got "
-                        f"{chunk.shape}")
-                if done + len(chunk) > n_rows:
-                    raise Mp4jError(
-                        f"chunk {k} brings the rows to "
-                        f"{done + len(chunk)}, more than n_rows={n_rows}")
-                # pieces: cut at shard ends, then evenly under the cap
-                at = 0
-                while at < len(chunk):
-                    shard, start = divmod(done + at, per)
-                    m = min(len(chunk) - at, per - start)
-                    parts = -(-m // piece_rows)
-                    m = min(m, -(-m // parts))
-                    piece = chunk[at:at + m]
-                    at += m
-                    if shard in where:          # not another process's rows
-                        wire = ((m * width // 128, 128)
-                                if m * width % 128 == 0 else (m, width))
-                        with spans.span("mp4j.stage.send", chunk=sent,
-                                        bytes=piece.nbytes):
-                            dpiece = jax.device_put(piece.reshape(wire),
-                                                    where[shard])
-                        with spans.span("mp4j.stage.place", chunk=sent):
-                            tables[shard], marker = self._row_chunk_placer(
-                                per, width, m, wire)(
-                                    tables[shard], dpiece, np.int32(start))
-                        placed.append(marker)
-                        crossing.append(dpiece)
-                        sent += 1
-                    if each is not None and shard == n - 1:
-                        each(whole(), start, start + m)
-                        handed = start + m
-                    if len(crossing) >= self._CHUNKS_CROSSING:
-                        with spans.span("mp4j.stage.link_wait",
-                                        chunk=sent - len(crossing)):
-                            jax.block_until_ready(crossing.pop(0))
-                    if len(placed) > self._CHUNKS_AHEAD:
-                        with spans.span("mp4j.stage.device_wait",
-                                        chunk=sent - len(placed)):
-                            jax.block_until_ready(placed.pop(0))
-                done += len(chunk)
-            if done != n_rows:
-                raise Mp4jError(
-                    f"the chunks hold {done} rows, n_rows={n_rows} were "
-                    f"announced")
-            if each is not None and handed < per:
-                each(whole(), handed, per)
-            return whole()
+                              device=d)
+                  for s, d in self._shard_devices(shape).items()}
+        for k, piece, shard, start, stop, turns in self._reader_pieces(
+                chunks, n_rows, width):
+            with spans.span("mp4j.stage.place", chunk=k):
+                tables[shard], marker = self._row_chunk_placer(
+                    per, width, stop - start, piece.shape)(
+                        tables[shard], piece, np.int32(start))
+            turns.append(marker)
+        return jax.make_array_from_single_device_arrays(
+            shape, self._row_sharding(),
+            [tables[s] for s in sorted(tables)])
 
     def _row_chunk_placer(self, per: int, width: int, rows: int, wire):
         """The program that puts a piece of ``rows`` rows, crossed as

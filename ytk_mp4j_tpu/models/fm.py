@@ -75,8 +75,8 @@ Scoring a file (``FMTrainer.predict`` on a replicated table) reads the
 same table by feature, parameters only (``FMTrainer.enter_model``
 converts the public params once a model): :func:`score_rows` under
 ``shard_map``, rows sharded and no collective, a tile of rows at a time,
-over instances that ``_put_in_row_chunks`` stages a chunk of rows at a
-time and that are scored as they cross.
+over instances that cross a piece of rows at a time (``_array_cuts``)
+and are scored as they cross, each piece let go after its turn.
 """
 
 from __future__ import annotations
@@ -1050,30 +1050,29 @@ def _score_tile(rows: int) -> int:
     return min(_SCORE_TILE, rows)
 
 
-def score_rows(packed, state, out, start, rows: int, cfg: FMConfig):
-    """Score ``rows`` rows of this shard from row ``start`` on and write
-    what :func:`predict` says of them into ``out`` [N] f32 (the other
-    rows are passed on). ``packed`` [N, ``packed_width(3 * max_nnz)``] holds a
-    row's feature ids, its field ids and the bits of its f32 values as
-    32-bit words side by side (``_put_in_row_chunks`` packs them so),
-    ``state`` is the entered ``(w0, T)``.
+def score_rows(feats, fields, vals, state, out, start, cfg: FMConfig,
+               skip: int = 0):
+    """Score the rows of a piece of instances, ``feats``, ``fields``
+    [R, max_nnz] int32 and ``vals`` [R, max_nnz] f32, from row ``skip``
+    on, and write what :func:`predict` says of them into ``out`` [N] f32
+    from row ``start`` on (the other rows are passed on; ``start`` only
+    says where the results go). ``state`` is the entered ``(w0, T)``.
 
     The rows go through in tiles of :func:`_score_tile`, so that what
     the program holds beside its arguments is a tile's and not the
-    call's (a staging chunk of 286,720 rows would gather 11.5 GB of
-    blocks). The last tile is as long as the others: it starts early and
+    call's (a piece of 286,720 rows would gather 11.5 GB of blocks).
+    The last tile is as long as the others: it starts early and
     scores rows again that the one before it scored, to the same bits (a
     row's score takes nothing from its neighbours)."""
-    K = cfg.max_nnz
+    rows, K = feats.shape[0] - skip, feats.shape[1]
     tile = _score_tile(rows)
 
     def score_tile(c, out):
-        at = start + jnp.minimum(c * tile, rows - tile)
-        part = lax.dynamic_slice(packed, (at, jnp.zeros((), at.dtype)),
-                                 (tile, packed.shape[1]))
-        vals = lax.bitcast_convert_type(part[:, 2 * K:3 * K], jnp.float32)
-        p = predict(state, part[:, :K], part[:, K:2 * K], vals, cfg)
-        return lax.dynamic_update_slice(out, p, (at,))
+        at = jnp.minimum(c * tile, rows - tile)
+        part = [lax.dynamic_slice(a, (skip + at, jnp.zeros((), at.dtype)),
+                                  (tile, K)) for a in (feats, fields, vals)]
+        return lax.dynamic_update_slice(out, predict(state, *part, cfg),
+                                        (start + at,))
 
     # the loop itself is under no scope: gather, select and pairs are
     # read apart in a device trace (PERF.md section 3)
@@ -1866,28 +1865,40 @@ class FMTrainer(DataParallelTrainer):
                 widen(self._place_replicated((w0, w, V)))))
 
     def _build_score(self, shape, rows: int):
-        """The scoring program for packed instances staged as ``shape``
-        ([n_shards, rows a shard, ``packed_width(3 * max_nnz)``]), ``rows``
-        rows of every shard a call: :func:`score_rows` under ``shard_map``,
-        rows sharded, the entered model replicated, no collective. It takes
-        (instances, model, probabilities [n_shards, rows a shard], first
-        row) and returns the probabilities, donated, with those rows
-        filled in."""
+        """The scoring program for pieces of instances whose ids, fields
+        and values each cross as ``shape`` ([n_shards, M, 128] words, or
+        [n_shards, rows, max_nnz]), of which it scores the last ``rows``
+        rows of every shard (all, but for a last piece that began
+        early): :func:`score_rows` under ``shard_map``, rows sharded,
+        the entered model replicated, no collective. It takes (ids,
+        fields, values, model, probabilities [n_shards, rows a shard],
+        first row) and returns the probabilities, donated, with those
+        rows filled in, and the piece's first word, which is there when
+        the device has had the piece. The three are put into rows of
+        ``max_nnz`` inside the program, under the scope
+        ``stage.place``."""
         cfg = self._score_cfg
         axes = self.axes
+        K = cfg.max_nnz
+        held = int(np.prod(shape[1:])) // K
 
         @partial(jax.shard_map, mesh=self.mesh, check_vma=False,
-                 in_specs=(P(axes), P(), P(axes), P()), out_specs=P(axes))
-        def score(packed, model, out, start):
-            return score_rows(packed[0], model, out[0], start, rows,
-                              cfg)[None]
+                 in_specs=(P(axes),) * 3 + (P(), P(axes), P()),
+                 out_specs=(P(axes), P(axes)))
+        def score(feats, fields, vals, model, out, start):
+            # the device's side of the hand-over, by name in a trace
+            with jax.named_scope("stage.place"):
+                marker = feats.reshape(-1)[:1]
+                piece = [a.reshape(held, K) for a in (feats, fields, vals)]
+            return score_rows(*piece, model, out[0], start, cfg,
+                              skip=held - rows)[None], marker
 
         tile = _score_tile(rows)
         with spans.span("mp4j.step.build", key="ffm_score", rows=rows,
                         tile=tile, tiles=-(-rows // tile),
                         block_width=_block_width(cfg),
                         **_select_build_args(cfg)):
-            return jax.jit(score, donate_argnums=2)
+            return jax.jit(score, donate_argnums=4)
 
     def predict(self, params, feats, fields, vals):
         """What the model says of padded-sparse instances: a probability
@@ -1897,19 +1908,20 @@ class FMTrainer(DataParallelTrainer):
         replicated table; whoever scores more than one file with a model
         enters it once).
 
-        On a replicated table the instances are staged as ``fit``'s are,
-        rows padded to whole shards and sharded over the trainer's mesh,
-        and cross in row chunks as the host holds them, ids, fields and
-        values each on its own (``_put_in_row_chunks`` puts them side by
-        side on the device; arrays that are int32 / float32 and full
-        width are not copied on the host; ids are validated a chunk at a
-        time, while the chunks before are scored); one jitted
-        ``shard_map`` program (:func:`score_rows`) scores each chunk as
-        soon as it is in place, while the next ones cross, a tile of rows
-        at a time and one gather descriptor a (row, slot); the
-        probabilities are written into one donated array and fetched
-        once. The programs are kept by (staged shape, rows a call): a
-        repeated ``predict`` of the same shape builds nothing. Spans
+        On a replicated table the instances are sharded as ``fit``'s
+        are, rows padded to whole shards over the trainer's mesh, and
+        cross in pieces of rows as the host holds them, ids, fields and
+        values each on its own (``_array_cuts``: arrays that are int32 /
+        float32 and full width are not copied on the host; ids are
+        validated a piece at a time, while the pieces before are
+        scored); one jitted ``shard_map`` program (:func:`score_rows`)
+        takes the three as they crossed and scores the piece while the
+        next ones cross, a tile of rows at a time and one gather
+        descriptor a (row, slot). No table of instances is built: a
+        piece is let go when its turn is over. The probabilities are
+        written into one donated array and fetched once. The programs
+        are kept by (piece shape, rows a shard, rows a call): a repeated
+        ``predict`` of the same shape builds nothing. Spans
         ``mp4j.ffm.score.stage`` / ``dispatch`` / ``fetch``.
 
         A sharded table keeps its own program
@@ -1940,32 +1952,36 @@ class FMTrainer(DataParallelTrainer):
         job, self._score_jobs = self._score_jobs, self._score_jobs + 1
         probs = None                # the device's, as last returned
         scored = 0                  # rows of a shard scored so far
-
-        def score(table, start: int, stop: int):
-            nonlocal probs, scored
-            # the last chunk of a staging starts early, over rows that
-            # the one before it brought: those are done
-            start, scored = max(start, scored), stop
-            # on its way already, and not scored if an id is out of range
-            self._check_ids(parts[0][:, start:stop], parts[1][:, start:stop])
-            key = (table.shape, stop - start)
-            program = self._score_programs.get(key)
-            if program is None:
-                program = self._score_programs[key] = self._build_score(*key)
-            with spans.span("mp4j.ffm.score.dispatch", job=job,
-                            rows=stop - start, start=start):
-                if probs is None:
-                    probs = jnp.zeros(table.shape[:2], jnp.float32,
-                                      device=self._row_sharding())
-                probs = program(table, tuple(model), probs, np.int32(start))
-
         with spans.span("mp4j.ffm.score.stage", job=job, rows=N):
             arrays, per, _ = self._pad_rows([feats, fields, vals],
                                             weights=False)
             parts = tuple(a.reshape(self.n_shards, per, -1) for a in arrays)
             with spans.span("mp4j.put_sharded",
                             bytes=sum(a.nbytes for a in parts)):
-                self._put_in_row_chunks(parts, each=score)
+                for _, piece, _, start, stop, turns in self._crossed(
+                        self._array_cuts(parts)):
+                    # the last piece starts early, over rows that the
+                    # one before it brought: those are done
+                    start, scored = max(start, scored), stop
+                    # on its way already, and not scored if an id is
+                    # out of range
+                    self._check_ids(parts[0][:, start:stop],
+                                    parts[1][:, start:stop])
+                    key = (piece[0].shape, per, stop - start)
+                    program = self._score_programs.get(key)
+                    if program is None:
+                        program = self._score_programs[key] = \
+                            self._build_score(key[0], key[2])
+                    with spans.span("mp4j.ffm.score.dispatch", job=job,
+                                    rows=stop - start, start=start):
+                        if probs is None:
+                            probs = jnp.zeros(
+                                (self.n_shards, per), jnp.float32,
+                                device=self._row_sharding())
+                        probs, done = program(*piece, tuple(model), probs,
+                                              np.int32(start))
+                    turns.append(done)
+                    del piece
         with spans.span("mp4j.ffm.score.fetch", job=job):
             # _to_host: a collective fetch on multi-process meshes, where
             # every process calls predict together

@@ -768,6 +768,15 @@ def predict_tree(bins, tree, cfg: GBDTConfig):
 # ----------------------------------------------------------------------
 _SCORE_TREE_GROUP = 16      # trees a matmul selects for (x 2**depth rows)
 _SCORE_ROW_CHUNK = 2 ** 17  # rows a chunk: bounds the [nodes, rows] select
+# A chunk's rows rest on the lanes: one that is not whole 128-lane words
+# is scored with empty rows after it. Left as it came, XLA rests the
+# chunk's [1, rows] sums rows-major and the loop over the tree groups
+# takes as long for a fourth of the rows (my chip runs, PR 52, the
+# scoring program alone: the last piece's 8,708 new rows of the Bosch
+# table 15.17 ms, its group loop 14.65 against 14.93 for a whole piece
+# of 34,560; filled to 8,832 rows 4.44 ms; the float file's last 4,100
+# rows 7.13 -> 2.65 as 4,224; the same bits).
+_SCORE_LANES = 128
 
 
 def score_group_size(n_trees: int, n_classes: int = 1) -> int:
@@ -849,44 +858,48 @@ def _score_group(digits, group, out, cfg: GBDTConfig):
     return out
 
 
-def score_shard(bins, stacked, out, start, rows: int, cfg: GBDTConfig,
-                axis_name=None, edges=None, shift: bool = False):
-    """Score ``rows`` rows of this shard from row ``start`` on: ``bins``
-    [N, F] under the whole ensemble, their margins written into ``out``
-    ([C, N] f32, C = 1 unless softmax; the other rows are passed on).
-    ``stacked``: (feat, bin, dir, leaf), each [groups, 2**depth, G, C],
-    as ``_stack_trees`` lays them out. Per row the sum runs over the
-    trees in their order, f32, as ``predict_tree`` after
-    ``predict_tree`` would give it.
+def score_shard(rows, stacked, out, start, cfg: GBDTConfig, axis_name=None,
+                edges=None, shift: bool = False, skip: int = 0):
+    """Score ``rows`` [R, F], the rows a scoring call was handed (a
+    piece of the input, or a shard that crossed in one transfer), from
+    row ``skip`` on under the whole ensemble, and write their margins
+    into ``out`` ([C, N] f32, C = 1 unless softmax; the other columns
+    are passed on) from column ``start`` on: ``start`` only says where
+    the margins go. ``stacked``: (feat, bin, dir, leaf), each [groups,
+    2**depth, G, C], as ``_stack_trees`` lays them out. Per row the sum
+    runs over the trees in their order, f32, as ``predict_tree`` after
+    ``predict_tree`` would give it. The rows go through in
+    ``score_row_chunks``' chunks; no table is sliced.
 
-    With ``edges`` ([F, E] f32) the table is one of floats, NaN where a
-    cell is empty, and a chunk's rows are sliced out, to rest on their
-    own, and binned there (``binning._count_edges``: on a TPU the
-    ``mp4j_bin`` kernel; ``shift``: the binner's reserved missing
-    bucket) under the scope ``bin.transform``: a chunk's floats and bins
-    exist for the length of its turn, the bins as the bf16 digits the
-    select reads, and the margins are those of the bins' table."""
-    F = bins.shape[1]
+    With ``edges`` ([F, E] f32) the rows are floats, NaN where a cell is
+    empty, and a chunk is binned where it rests
+    (``binning._count_edges``: on a TPU the ``mp4j_bin`` kernel;
+    ``shift``: the binner's reserved missing bucket) under the scope
+    ``bin.transform``: its bins exist for the length of its turn, as
+    the bf16 digits the select reads."""
+    R, F = rows.shape
     C = out.shape[0]
     n_digits = _bin_digits(cfg.n_bins)
-    row_chunk, chunks = score_row_chunks(rows)
+    row_chunk, chunks = score_row_chunks(R - skip)
+    fill = -row_chunk % _SCORE_LANES     # empty rows, margins dropped
 
     def chunk_fn(out, c):
-        at = start + jnp.minimum(c * row_chunk, rows - row_chunk)
+        at = jnp.minimum(c * row_chunk, R - skip - row_chunk)
         with jax.named_scope("gbdt.score.select" if edges is None
                              else "bin.transform"):
-            part = lax.dynamic_slice(bins, (at, jnp.int32(0)),
-                                     (row_chunk, F))
+            # one chunk: the rows as they rest
+            part = rows[skip:] if chunks == 1 else lax.dynamic_slice(
+                rows, (skip + at, jnp.int32(0)), (row_chunk, F))
+            if fill:
+                part = jnp.pad(part, ((0, fill), (0, 0)))
             if edges is not None:
-                # the slice rests on its own before the kernel reads it
-                # (a custom call's operand: nothing fuses into it), and
                 # the counts come back as [F, rows] rest, so the
                 # transposition below is the kernel's own undone
                 part = _count_edges(part, edges, shift)
             part = part.T
             digits = [((part >> (8 * k)) & 255 if n_digits > 1 else part
                        ).astype(jnp.bfloat16) for k in range(n_digits)]
-        acc = jnp.zeros((C, row_chunk), jnp.float32)
+        acc = jnp.zeros((C, row_chunk + fill), jnp.float32)
         if axis_name is not None:
             acc = lax.pcast(acc, axis_name, to="varying")
         acc, _ = lax.scan(
@@ -894,7 +907,7 @@ def score_shard(bins, stacked, out, start, rows: int, cfg: GBDTConfig,
             acc, stacked)
         with jax.named_scope("gbdt.score.walk"):
             return lax.dynamic_update_slice(
-                out, acc, (jnp.int32(0), at)), None
+                out, acc[:, :row_chunk], (jnp.int32(0), start + at)), None
 
     out, _ = lax.scan(chunk_fn, out, jnp.arange(chunks, dtype=jnp.int32))
     return out
@@ -912,7 +925,7 @@ class GBDTTrainer(DataParallelTrainer):
         self._step = None
         self._jobs = 0         # train() calls so far: the spans' ``job``
         self._score_jobs = 0   # predict() calls so far, likewise
-        self._score_programs = {}   # (table shape, rows, rounds) -> program
+        self._score_programs = {}   # (piece shape, per, rows, ..) -> program
         self._margin_step = None
         self._stacked_trees = None
         self.eval_history_: list[float] = []
@@ -986,17 +999,16 @@ class GBDTTrainer(DataParallelTrainer):
         return (self._put_sharded(y, per), self._put_sharded(preds, per),
                 self._put_sharded(w, per))
 
-    def shard_bins(self, bins: np.ndarray, each=None):
+    def shard_bins(self, bins: np.ndarray):
         """Pad a binned table [N, n_features] to whole shards and place
         it on the mesh as [n_shards, N/shard, n_features], rows sharded
         (``_put_sharded``: a shard of 2**32 bytes or more crosses in
-        row chunks, and ``each(table, start, stop)`` is called for the
-        rows of every shard as they are placed). What ``train`` and
-        ``predict`` both stage; the padding rows are cut from
-        ``predict``'s margins and weigh nothing in ``train``."""
+        row chunks). What ``train`` stages; the padding rows weigh
+        nothing there. ``predict`` sends the same rows the same way and
+        keeps no table (``_pieces``)."""
         self._check_bins_width(bins)
         (bins,), per, _ = self._pad_rows([bins], weights=False)
-        return self._put_sharded(bins, per, each)
+        return self._put_sharded(bins, per)
 
     def train(self, bins: np.ndarray, y: np.ndarray,
               n_trees: int | None = None, seed: int = 0,
@@ -1273,17 +1285,20 @@ class GBDTTrainer(DataParallelTrainer):
         by default the one the raw training entry points left on
         ``self.binner_`` (or :meth:`load_model`'s caller put there).
 
-        Each chunk crosses the host link as it arrives into one float
-        table ``[n_shards, rows a shard, n_features]``
-        (``_put_row_chunks``), and its rows are scored as soon as they
-        are in place, while the next ones cross: the scoring program
-        bins a chunk of rows where it slices them (``score_shard`` with
-        the edges, replicated) and reads the bins as :meth:`predict`'s
-        reads a staged table's. The floats cross once, no binned cell
-        crosses in either direction or outlives its chunk's turn, and
-        the host holds no binned copy. Returns what :meth:`predict`
-        returns for the binner's bins of the same table, to the bit,
-        wherever the chunks were cut.
+        Each chunk crosses the host link as it arrives, to the device
+        that holds its rows' margins, in pieces of 128 MiB at most
+        (``_reader_pieces``), and a piece is scored there as soon as it
+        is on its way, while the next ones cross: the scoring program
+        takes the piece as it crossed, bins its rows (``score_shard``
+        with that device's copy of the edges) and reads the bins as
+        :meth:`predict`'s reads a piece of a binned table. No table of
+        floats is built: a piece is let go when its turn is over, so a
+        file larger than the mesh's memory is scored like any other,
+        and every shard's pieces are scored as they arrive. The floats
+        cross once, no binned cell crosses in either direction or
+        outlives its piece's turn, and the host holds no binned copy.
+        Returns what :meth:`predict` returns for the binner's bins of
+        the same table, to the bit, wherever the chunks were cut.
 
         No fitted binner, a chunk of another width or chunks that do not
         add up to ``n_rows`` raise ``Mp4jError``."""
@@ -1300,9 +1315,8 @@ class GBDTTrainer(DataParallelTrainer):
             raise Mp4jError(
                 f"the binner has edges for {binner.edges.shape[0]} "
                 f"features, cfg.n_features={F}")
-        return self._predict(
-            lambda each: self._put_row_chunks(chunks, int(n_rows), F, each),
-            int(n_rows), trees, proba, binner)
+        return self._predict(self._reader_pieces(chunks, int(n_rows), F),
+                             int(n_rows), trees, proba, binner)
 
     def predict_raw(self, X, trees, proba: bool = False):
         """Serve RAW continuous features [N, n_features] held as ONE
@@ -1367,30 +1381,48 @@ class GBDTTrainer(DataParallelTrainer):
         return float(-np.mean(logp[np.arange(len(y)), y.astype(int)]))
 
     def _build_score(self, shape, rows: int, rounds: int, binning=None):
-        """The scoring program for a staged table of ``shape``
-        ([n_shards, rows a shard, n_features]), ``rows`` rows of every
-        shard a call and ``rounds`` rounds: ``score_shard`` under
-        ``shard_map``, rows sharded and the ensemble replicated. It
-        takes (table, ensemble, margins [n_shards, C, rows a shard],
-        first row) and returns the margins, donated, with those rows
-        filled in. ``binning``: None for a table of bins; for one of
-        floats ``(edges a column, the binner's missing_bucket)``, and
-        the program takes the edges [n_features, edges] last,
-        replicated."""
+        """The scoring program for pieces that cross as ``shape``, of
+        which it scores the last ``rows`` rows (all, but for a last piece
+        that began early) under ``rounds`` rounds. It takes (piece,
+        ensemble, margins, first row[, edges]) and returns the margins,
+        donated, with those rows filled in from ``first row`` on, and
+        the piece's first word, which is there when the device has had
+        the piece. The piece is put into rows of ``n_features`` inside
+        the program, under the scope ``stage.place``, and ``score_shard``
+        scores those.
+
+        Three dimensions are a slice of every shard ([n_shards, M, 128]
+        words, or [n_shards, rows, n_features]: a table that crossed in
+        one transfer too): ``score_shard`` under ``shard_map``, the
+        ensemble replicated, margins [n_shards, C, rows a shard]. Two
+        are a piece that went to one device (``_reader_pieces``): the
+        same body as that device's own program, margins [1, C, rows a
+        shard]. ``binning``: None for bins; for floats ``(edges a
+        column, the binner's missing_bucket)``, and the program takes
+        the edges [n_features, edges] last."""
         cfg = self.cfg
-        axes = self.axes
+        mapped = len(shape) == 3
+        axes = self.axes if mapped else None
         n_edges, shift = binning or (None, False)
-        specs = (P(axes), P(), P(axes), P()) + (P(),) * (binning is not None)
+        F = cfg.n_features
+        held = int(np.prod(shape[1:] if mapped else shape)) // F
 
-        @partial(jax.shard_map, mesh=self.mesh, in_specs=specs,
-                 out_specs=P(axes))
-        def score(bins, stacked, out, start, *edges):
-            return score_shard(bins[0], stacked, out[0], start, rows, cfg,
-                               axes, *edges, shift=shift)[None]
+        def score(piece, stacked, out, start, *edges):
+            # the device's side of the hand-over, by name in a trace
+            with jax.named_scope("stage.place"):
+                marker, piece = piece.reshape(-1)[:1], piece.reshape(held, F)
+            return score_shard(piece, stacked, out[0], start, cfg, axes,
+                               *edges, shift=shift,
+                               skip=held - rows)[None], marker
 
+        if mapped:
+            score = jax.shard_map(
+                score, mesh=self.mesh, out_specs=(P(axes), P(axes)),
+                in_specs=(P(axes), P(), P(axes), P())
+                + (P(),) * (binning is not None))
         n_classes = cfg.n_classes if cfg.loss == "softmax" else 1
         row_chunk, chunks = score_row_chunks(rows)
-        # a float table's program says how many compares a cell it
+        # a float piece's program says how many compares a cell it
         # issues for that many edges, which block of a piece the TPU's
         # kernel takes at a time, and that it bins before it selects (no
         # float select)
@@ -1413,36 +1445,41 @@ class GBDTTrainer(DataParallelTrainer):
         [N, n_classes] for softmax); ``proba=True`` applies the sigmoid
         (logistic) or softmax.
 
-        The table is staged as ``train`` stages it (``shard_bins``:
-        rows padded to whole shards and sharded over the trainer's
-        mesh, a shard of 2**32 bytes or more in row chunks), the
-        ensemble is replicated, and one jitted ``shard_map`` program
-        scores it (``score_shard``): rows outermost in chunks of at
-        most 2**17, trees in groups of up to 16 inside a chunk, so the
-        table is read once a job and not once a level of every tree. A
-        table that crosses in chunks is scored a chunk at a time, as
-        soon as the chunk is in place, while the next ones cross (two
-        at a time, up to twelve ahead of the device). A
-        row's margin is the f32 sum over the trees in their order. The
-        program is kept by (table shape, rows a call, tree count): a
-        repeated ``predict`` of the same shape builds nothing.
-        :meth:`predict_raw_chunks` is the same loop over a table of
-        floats."""
+        The rows cross as ``train``'s do (``_pieces``: padded to whole
+        shards and sharded over the trainer's mesh, a shard of 2**32
+        bytes or more in 128 MiB pieces, two crossing at a time and up
+        to twelve ahead of the device; a smaller one in one transfer, as
+        one piece), the ensemble is replicated, and one jitted
+        ``shard_map`` program scores a piece as it crossed
+        (``score_shard``) while the next ones cross: rows outermost in
+        chunks of at most 2**17, trees in groups of up to 16 inside a
+        chunk, so a row is read once a job and not once a level of every
+        tree. No table is built: a piece is let go when its turn is
+        over. A row's margin is the f32 sum over the trees in their
+        order. The program is kept by (piece shape, rows a shard, rows a
+        call, tree count): a repeated ``predict`` of the same shape
+        builds nothing. :meth:`predict_raw_chunks` is the same loop over
+        pieces of floats."""
         bins = np.asarray(bins, np.int32)
         self._check_bins_width(bins)
-        return self._predict(lambda each: self.shard_bins(bins, each),
-                             bins.shape[0], trees, proba)
+        (padded,), per, _ = self._pad_rows([bins], weights=False)
+        return self._predict(self._pieces(padded, per), bins.shape[0],
+                             trees, proba)
 
-    def _predict(self, stage, N: int, trees, proba: bool, binner=None):
-        """:meth:`predict` from the point where rows are in place on the
-        mesh, whatever they hold: ``stage(each)`` places the table and
-        calls ``each(table, start, stop)`` for the rows of every shard
-        as they are placed (``shard_bins`` with a host table of bins,
-        ``_put_row_chunks`` with a reader's chunks of floats, which the
-        program bins by ``binner``'s edges). One buffer of margins, one
-        cache of programs (its key says a float table's binning too),
-        the spans of one job (``source``: what the table holds), one
-        fetch."""
+    def _predict(self, pieces, N: int, trees, proba: bool, binner=None):
+        """:meth:`predict` from the point where rows are on their way to
+        the mesh, whatever they hold: ``pieces`` yields them as
+        ``_crossed`` does (``_pieces`` of a host table of bins,
+        ``_reader_pieces`` of a reader's chunks of floats, which the
+        program bins by ``binner``'s edges), and each is scored where it
+        went and let go. A piece of every shard is scored by the
+        ``shard_map`` program into one array of margins; a piece of one
+        shard by that device's program, with its copy of the ensemble
+        and edges, into that shard's margins, which are put together
+        once at the end (a shard's padding rows are no piece: their
+        margins stay zero and are cut). One cache of programs (its key
+        says a float piece's binning too), the spans of one job
+        (``source``: what the pieces hold), one fetch."""
         trees = list(trees)
         softmax = self.cfg.loss == "softmax"
         C = self.cfg.n_classes if softmax else 1
@@ -1453,38 +1490,63 @@ class GBDTTrainer(DataParallelTrainer):
             job, self._score_jobs = self._score_jobs, self._score_jobs + 1
             said = {"job": job,
                     "source": "bins" if binner is None else "floats"}
-            stacked = self._stack_trees(trees)
-            binning, edges = (), ()
+            model = (self._stack_trees(trees),)
+            binning = ()
             if binner is not None:
                 binning = ((binner.edges.shape[1],
                             bool(binner.missing_bucket)),)
-                edges = (self._place_replicated(
+                model += (self._place_replicated(
                     np.asarray(binner.edges, np.float32)),)
-            margins = None              # the device's, as last returned
-            scored = 0                  # rows of a shard scored so far
+            n, per = self.n_shards, -(-N // self.n_shards)
+            # where a piece's margins rest: rows sharded over the mesh
+            # (shard None: a piece of every shard), or on the one device
+            # that a shard's pieces go to
+            homes = {None: self._row_sharding(),
+                     **self._shard_devices((n, C, per))}
+            held, scored = {}, {}
 
-            def score(table, start: int, stop: int):
-                nonlocal margins, scored
-                # the last chunk of a staging starts early, over rows
-                # that the one before it brought: those are done
-                start, scored = max(start, scored), stop
-                key = (table.shape, stop - start, len(trees)) + binning
-                program = self._score_programs.get(key)
-                if program is None:
-                    program = self._score_programs[key] = \
-                        self._build_score(*key)
-                with spans.span("mp4j.gbdt.score.dispatch", **said,
-                                trees=len(trees), start=start):
-                    if margins is None:
-                        margins = jnp.zeros(
-                            (table.shape[0], C, table.shape[1]), jnp.float32,
-                            device=self._row_sharding())
-                    margins = program(table, stacked, margins,
-                                      np.int32(start), *edges)
+            def home(shard):
+                """[zero margins where ``shard``'s rest, the model there]"""
+                return [jnp.zeros((n if shard is None else 1, C, per),
+                                  jnp.float32, device=homes[shard]),
+                        model if shard is None
+                        else self._replica_on(model, homes[shard])]
 
             with spans.span("mp4j.gbdt.score.stage", **said):
-                stage(score)
+                for _, piece, shard, start, stop, turns in pieces:
+                    # the last piece of a table held whole starts early,
+                    # over rows that the one before it brought: those
+                    # are done
+                    start = max(start, scored.get(shard, 0))
+                    scored[shard] = stop
+                    # ``per``: jit would trace again for other margins
+                    # and say nothing
+                    key = (piece.shape, per, stop - start,
+                           len(trees)) + binning
+                    program = self._score_programs.get(key)
+                    if program is None:
+                        program = self._score_programs[key] = \
+                            self._build_score(key[0], *key[2:])
+                    with spans.span("mp4j.gbdt.score.dispatch", **said,
+                                    trees=len(trees), start=start,
+                                    rows=stop - start):
+                        if shard not in held:
+                            held[shard] = home(shard)
+                        margins, (stacked, *edges) = held[shard]
+                        held[shard][0], done = program(
+                            piece, stacked, margins, np.int32(start), *edges)
+                    turns.append(done)
+                    del piece, margins
             with spans.span("mp4j.gbdt.score.fetch", **said):
+                if None in held:
+                    margins = held[None][0]
+                else:
+                    # a shard at a time: together now, and a shard that
+                    # no piece reached (all padding) is zero
+                    margins = jax.make_array_from_single_device_arrays(
+                        (n, C, per), homes[None],
+                        [(held.get(s) or home(s))[0]
+                         for s in sorted(set(homes) - {None})])
                 out = self._to_host(margins)
             # [n_shards, C, rows a shard] -> [N, C]
             out = out.transpose(0, 2, 1).reshape(-1, C)[:N]
