@@ -37,6 +37,18 @@ mse = float(np.mean((preds - y) ** 2))
 print(f"mse: {mse0:.4f} -> {mse:.4f} after {len(trees)} trees")
 assert mse < mse0
 
+# trees grown leaf by leaf, best first (LightGBM's policy, XGBoost's
+# lossguide): grow_policy="loss" and a budget of leaves; depth caps any
+# leaf's depth. Same entry points, same trees downstream.
+leafwise = GBDTTrainer(GBDTConfig(
+    n_features=F, n_bins=B, depth=6, grow_policy="loss", max_leaves=12,
+    n_trees=5, learning_rate=0.3, hist_mode="matmul"))
+leaf_trees, _ = leafwise.train_raw(X, y)
+leaf_mse = float(np.mean((leafwise.predict_raw(X, leaf_trees) - y) ** 2))
+print(f"leaf-wise, 12 leaves a tree: mse {leaf_mse:.4f}, "
+      f"{leafwise.grow_stats_['splits']} splits")
+assert leaf_mse < mse0 and leafwise.grow_stats_["splits"] <= 5 * 11
+
 # a table that arrives in pieces (a CSV read a block of rows at a time):
 # any iterable of (X [m, F] float32 with NaN for empty cells, y [m]) and
 # the total. The floats cross to the mesh once, the quantile sketch and

@@ -170,6 +170,88 @@ def test_wide_step_temporaries_far_below_the_table(wide_step):
     assert temp < WIDE_TABLE_BYTES // 16, temp / WIDE_TABLE_BYTES
 
 
+# ------------------------------------------------------------------------
+# ISSUE 53: the step that grows its trees leaf by leaf, at the size of
+# benchmark/configs/gbdt-bosch-968-leafwise: 70 leaves, depth at most 7.
+LEAVES, LEAF_DEPTH = 70, 7
+OPEN_LEAVES_BYTES = LEAVES * WIDE_F * B * 2 * 4     # g and h, f32
+CHIP_BYTES = 16 * 10 ** 9
+
+
+@pytest.fixture(scope="module")
+def leafwise_step(topo_devices):
+    return _compile_step(topo_devices, 1, depth=LEAF_DEPTH, n_rows=WIDE_ROWS,
+                         n_features=WIDE_F, missing_bin=True,
+                         grow_policy="loss", max_leaves=LEAVES)
+
+
+def test_leafwise_step_fits_the_chip_beside_its_table(leafwise_step):
+    """Arguments (the table, labels, margins, weights) and temporaries
+    (the open leaves' histograms, 139 MB, and a step's arrays) as XLA
+    sizes them: a third of the chip (4.598 and 0.084 GB when this was
+    written; part of the leaves' table is held in another memory space
+    and not counted)."""
+    mem = leafwise_step.memory_analysis()
+    assert mem.argument_size_in_bytes >= WIDE_TABLE_BYTES
+    assert mem.argument_size_in_bytes < WIDE_TABLE_BYTES + 4 * 4 * WIDE_ROWS
+    assert mem.temp_size_in_bytes < 2 * OPEN_LEAVES_BYTES, mem
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < CHIP_BYTES // 2
+
+
+def test_leafwise_step_loops_over_one_kernel_call(leafwise_step):
+    """The root's histogram and one call in the loop's body, not 70
+    calls; the table comes in as it rests and is the kernel's operand by
+    a bitcast, inside the loop as outside it."""
+    text = leafwise_step.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert re.search(r" while\(", text)
+    assert "s32[1,%d,%d]{1,2,0:T(8,128)} parameter(0)" % (
+        WIDE_ROWS, WIDE_F) in text
+    assert len(re.findall(r"= s32\[%d,%d\]\{1,0:T\(8,128\)\} bitcast\("
+                          % (WIDE_F, WIDE_ROWS), text)) == 2
+    assert _row_major_tables(text, WIDE_ROWS // 2, WIDE_F) == []
+    assert not re.search(
+        r"= s32\[[\d,]*\d{6,},[\d,]*\d{3,}[\d,]*\]\S* (copy|pad|transpose)\(",
+        text)
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert sorted(m for ln in calls for m in _op_names(ln)) == [
+        "jit(step)/gbdt.hist/mp4j_hist/pallas_call",
+        "jit(step)/while/body/closed_call/gbdt.hist/mp4j_hist/pallas_call"]
+    for part in ("gbdt.grow.pick", "gbdt.grow.book", "gbdt.route",
+                 "gbdt.best_splits"):
+        assert _op_names(text, r"while/body/closed_call/" + re.escape(part))
+    assert not _op_names(text, r"gbdt\.level\.")
+
+
+def test_leafwise_step_updates_the_open_leaves_where_they_rest(leafwise_step):
+    """Two slots of 2 MB are written a split. Without the barrier round
+    what is written XLA reads the parent's slot inside the fusions that
+    write the table and copies both tables (139 MB) in and out of every
+    step."""
+    text = leafwise_step.as_text()
+    table = r"f32\[%d,%d,%d\]" % (LEAVES, WIDE_F, B)
+    assert re.search(table + r"\S* (fusion|dynamic-update-slice)\(", text)
+    assert not re.findall(r"= " + table + r"\S* copy\(", text)
+
+
+def test_four_chip_leafwise_step_reduces_a_node_a_split(topo_devices):
+    """Rows sharded over four chips: the built child's histogram and the
+    children's row counts are summed over the axis inside the loop."""
+    step = _compile_step(topo_devices, 4, depth=LEAF_DEPTH,
+                         n_rows=WIDE_ROWS + 1, n_features=WIDE_F,
+                         missing_bin=True, grow_policy="loss",
+                         max_leaves=LEAVES)
+    text = step.as_text()
+    assert text.count("tpu_custom_call") == 2
+    reduced = _op_names(text, r"while/body/closed_call/.*psum")
+    assert any("gbdt.grow.book" in m for m in reduced)      # the counts
+    assert any("gbdt.grow.book" not in m for m in reduced)  # the histogram
+    mem = step.memory_analysis()
+    assert mem.argument_size_in_bytes < WIDE_TABLE_BYTES // 4 + 2 ** 24
+    assert mem.temp_size_in_bytes < 2 * OPEN_LEAVES_BYTES
+
+
 def _computations(text):
     """name -> lines of each computation of a compiled module's text."""
     found, name = {}, None

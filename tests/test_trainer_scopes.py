@@ -105,6 +105,13 @@ def _lower_score_ffm(rng):
         np.int32(0))
 
 
+def _lower_gbdt_leafwise(rng):
+    """The step that grows its trees leaf by leaf (ISSUE 53), at the
+    new cell's width and cap on depth, a small budget of leaves."""
+    return _lower_gbdt(rng, 968, missing_bin=True, grow_policy="loss",
+                       max_leaves=6)
+
+
 FFM_STEP = ["jvp(ffm.select)", "transpose(jvp(ffm.select))",
             "jvp(ffm.pairs)", "transpose(jvp(ffm.pairs))", "ffm.grad_merge"]
 LOOP = "sparse.fold_live_tiles"
@@ -129,6 +136,11 @@ PROGRAMS = {
                    "gbdt.level.5"),
     "gbdt-bosch": (partial(_lower_gbdt, n_features=968, missing_bin=True),
                    LEVELS, "gbdt.level.0"),
+    # the splits are a loop of the program, under no level's name
+    "gbdt-leafwise": (_lower_gbdt_leafwise,
+                      ["gbdt.hist", "gbdt.best_splits", "gbdt.route",
+                       "gbdt.grow.pick", "gbdt.grow.book", "gbdt.leaf"],
+                      "gbdt.grow.pick"),
     "placer": (_lower_placer, ["stage.place"], "stage.place"),
     # the scoring programs take the piece that crossed (ISSUE 52): its
     # relayout is theirs, under the placers' name
@@ -258,21 +270,27 @@ def _entered_scopes():
     return sorted(set(found))
 
 
+RESIDUALS = ("ffm_unscoped_ms_per_chunk", "gbdt_unscoped_ms_per_tree",
+             "score_unscoped_ms_per_job", "gbdt_grow_unscoped_ms_per_tree")
+
+
 def _residuals_that_run(scope: str) -> list[str]:
-    """The residual metrics whose cells run the program a scope is in."""
-    ffm, gbdt, score = ("ffm_unscoped_ms_per_chunk",
-                        "gbdt_unscoped_ms_per_tree",
-                        "score_unscoped_ms_per_job")
+    """The residual metrics whose cells run the program a scope is in.
+    The leaf-wise training cell (ISSUE 53) has a residual of its own,
+    which lists what the level-wise cells' lists and the grower's two."""
+    ffm, gbdt, score, grow = RESIDUALS
     if scope.startswith(("gbdt.score.", "ffm.score.")):
         return [score]
     if scope.startswith("stage.") or scope == "bin.transform":
-        return [gbdt, score]        # training and scoring stage and bin
+        return [gbdt, score, grow]  # training and scoring stage and bin
     if scope == "ffm.table_gather":
         return [ffm, score]         # the scoring program gathers blocks too
     if scope.startswith(("ffm.", "sparse.", "mp4j.")):
         return [ffm]
+    if scope.startswith("gbdt.grow."):
+        return [grow]
     if scope.startswith(("gbdt.", "bin.")):
-        return [gbdt]
+        return [gbdt, grow]
     raise AssertionError(
         f"{scope}: a new family of scopes; say here which cells run it and "
         f"list it in their residual's file under benchmark/layer_metrics/")
@@ -291,8 +309,8 @@ def test_the_scan_finds_the_scopes():
     assert {"ffm.select", "ffm.pairs", "sparse.fold_live_tiles",
             "stage.place", "gbdt.level.<d>", "gbdt.hist",
             "gbdt.score.select", "bin.transform", "mp4j.all_to_all",
-            "ffm.score.pairs"} <= scopes
-    assert len(scopes) >= 29
+            "ffm.score.pairs", "gbdt.grow.pick", "gbdt.grow.book"} <= scopes
+    assert len(scopes) >= 31
 
 
 @pytest.mark.parametrize("where,scope", _entered_scopes(),
@@ -300,9 +318,7 @@ def test_the_scan_finds_the_scopes():
 def test_every_scope_is_listed_where_it_runs(where, scope):
     if scope in WRAPPERS:
         # and no residual lists it: a wrapper names all it wraps
-        for metric in ("ffm_unscoped_ms_per_chunk",
-                       "gbdt_unscoped_ms_per_tree",
-                       "score_unscoped_ms_per_job"):
+        for metric in RESIDUALS:
             assert not _listed(metric).search(scope.replace("<d>", "3"))
         return
     for metric in _residuals_that_run(scope):

@@ -99,6 +99,14 @@ class GBDTConfig:
     # categorical values into [0, B-2] (and into [1, B-2] under
     # missing_bin, where 0 is the missing bucket).
     categorical_features: tuple = ()
+    # How a tree grows. "level": a complete tree of ``depth`` levels, a
+    # level at a time (``_build_tree``). "loss": leaf by leaf, best
+    # first (LightGBM's policy, XGBoost's ``lossguide``, ytk-learn's
+    # ``tree_grow_policy: "loss"``): of all open leaves the one whose
+    # best split gains most is split, until ``max_leaves`` leaves stand;
+    # ``depth`` is then the cap on any leaf's depth (``_grow_tree``).
+    grow_policy: str = "level"
+    max_leaves: int | None = None
 
     def __post_init__(self):
         # Mp4jError for ALL input validation, matching train() and the
@@ -114,6 +122,22 @@ class GBDTConfig:
         if self.loss == "softmax" and self.n_classes < 2:
             raise Mp4jError(
                 f"softmax needs n_classes >= 2, got {self.n_classes}")
+        if self.grow_policy not in ("level", "loss"):
+            raise Mp4jError(
+                f"grow_policy must be 'level' or 'loss', got "
+                f"{self.grow_policy!r}")
+        if self.grow_policy == "level":
+            if self.max_leaves is not None:
+                raise Mp4jError(
+                    f"max_leaves={self.max_leaves!r} needs "
+                    "grow_policy='loss': a level-wise tree has 2**depth "
+                    "leaves")
+        elif (isinstance(self.max_leaves, bool)
+              or not isinstance(self.max_leaves, (int, np.integer))
+              or not 2 <= self.max_leaves <= 2 ** self.depth):
+            raise Mp4jError(
+                f"grow_policy='loss' needs an int max_leaves in [2, "
+                f"2**depth = {2 ** self.depth}], got {self.max_leaves!r}")
         if not (0.0 < self.subsample <= 1.0
                 and 0.0 < self.colsample <= 1.0):
             raise Mp4jError(
@@ -634,6 +658,177 @@ def _build_tree(bins, g, h, cfg: GBDTConfig, axis_name, interpret,
     return delta, (tree_feat, tree_bin, tree_dir, leaf_val)
 
 
+def _pick_leaf(open_, gain, heap, none):
+    """The slot of the open leaf to split next: the greatest gain, ties
+    to the lowest heap index (``none``, past every heap index, where no
+    leaf is open: the pick is then slot 0 and the caller's ``open_.any()``
+    says that nothing is to be split)."""
+    best = jnp.max(jnp.where(open_, gain, -jnp.inf))
+    return jnp.argmin(jnp.where(open_ & (gain == best), heap, none))
+
+
+def _grow_tree(bins, g, h, cfg: GBDTConfig, axis_name, interpret,
+               feat_mask=None):
+    """Grow one tree leaf by leaf (``grow_policy="loss"``): of all open
+    leaves the one whose best split gains most is split, ``max_leaves -
+    1`` times over, and no leaf lies deeper than ``depth``. Takes what
+    ``_build_tree`` takes and returns its ``(delta, tree)`` with the
+    counts of the work behind them: ``(delta [N], tree, (built
+    [max_leaves] int32, splits int32))``.
+
+    The unit of work is one split, each waiting for the last, so the
+    splits are a loop of the program and the open leaves its carry: a
+    row's leaf is a slot of a table of ``max_leaves`` slots that holds
+    each leaf's heap index, depth, best (gain, feature, bin, direction)
+    and its own [F, B] histograms. A step picks the open leaf of
+    greatest gain among those above ``depth`` whose gain clears
+    ``min_split_gain`` (ties: the lowest heap index), writes its split
+    at its heap index, routes that leaf's rows by ``_route_samples``'
+    rule, builds the histogram of the child with fewer rows (summed over
+    ``axis_name``, so that every shard builds the same child; ties: the
+    left) from the rows, every other row on the sentinel id, and takes
+    its sibling's as the parent's less it, the hessians clamped as
+    ``_build_tree`` clamps them. The left child keeps the slot, the
+    right child takes slot ``step + 1``. Where no leaf is open the step
+    changes nothing (the trip count is static). ``built`` holds the
+    rows whose histogram was built from rows: the tree's rows for the
+    root, then the smaller child's for every step.
+
+    The tree is ``_build_tree``'s level-order heap of depth ``depth``:
+    a node that was never split is frozen the way ``_build_tree``
+    freezes one (bin ``B - 1``, direction 0: every row goes left), so a
+    leaf at depth k is found, by every reader of such a tree, at its
+    level-local index shifted left by ``depth - k``, where its value
+    stands."""
+    N = bins.shape[0]
+    L, depth, B = cfg.max_leaves, cfg.depth, cfg.n_bins
+    n_internal = 2 ** depth - 1
+    cat_mask = cfg._cat_mask()
+    i32 = jnp.int32
+
+    def psum(x):
+        return x if axis_name is None else lax.psum(x, axis_name)
+
+    def reduced_histogram(ids):
+        """One node's local histogram + the distributed allreduce."""
+        a, b = build_histograms(bins, g, h, ids, 1, cfg,
+                                interpret=interpret)
+        return psum(a)[0], psum(b)[0]       # THE histogram allreduce
+
+    def search(hg, hh):
+        return best_splits(hg, hh, cfg.reg_lambda, feat_mask,
+                           cfg.min_child_hessian, cat_mask,
+                           cfg.missing_bin)
+
+    slot = jnp.arange(L, dtype=i32)
+    node = jnp.arange(n_internal, dtype=i32)
+    row_leaf = jnp.zeros((N,), i32)         # every row in the root's slot
+    if axis_name is not None:
+        row_leaf = lax.pcast(row_leaf, axis_name, to="varying")
+    root_g, root_h = reduced_histogram(row_leaf)
+    feat, bin_, gain, dir_ = search(root_g[None], root_h[None])
+    with jax.named_scope("gbdt.grow.book"):
+        first = slot == 0
+        leaves = (jnp.zeros((L,), i32), jnp.zeros((L,), i32),   # heap, depth
+                  jnp.where(first, gain[0], -jnp.inf),
+                  *(jnp.where(first, v[0], 0) for v in (feat, bin_, dir_)))
+        hists = tuple(jnp.zeros((L, *root.shape), root.dtype).at[0].set(root)
+                      for root in (root_g, root_h))
+        rows = N if axis_name is None else N * lax.psum(1, axis_name)
+        built = jnp.where(first, i32(rows), 0)
+    tree = (jnp.zeros((n_internal,), i32), jnp.full((n_internal,), B - 1, i32),
+            jnp.zeros((n_internal,), i32))
+
+    def split(step, carry):
+        row_leaf, leaves, (hist_g, hist_h), tree, built, splits = carry
+        heap, level, gain, feat, bin_, dir_ = leaves
+        new = (step + 1).astype(i32)
+        with jax.named_scope("gbdt.grow.pick"):
+            # the ~(gain > thr) form of _build_tree: NaN and -inf stay shut
+            open_ = (level < depth) & (gain > cfg.min_split_gain)
+            s = _pick_leaf(open_, gain, heap, n_internal).astype(i32)
+            go = open_.any()
+            at, f, b, d = heap[s], feat[s], bin_[s], dir_[s]
+        routed = _route_samples(bins, jnp.zeros_like(row_leaf), f[None],
+                                b[None], 1, d[None], cat_mask,
+                                cfg.missing_bin, B)
+        with jax.named_scope("gbdt.grow.book"):
+            here = go & (node == at)
+            tree = tuple(jnp.where(here, v, t)
+                         for v, t in zip((f, b, d), tree))
+            in_leaf = go & (row_leaf == s)
+            right = in_leaf & (routed > 0)
+            n_in, n_right = psum(jnp.stack([in_leaf.sum(dtype=i32),
+                                            right.sum(dtype=i32)]))
+            small_right = n_right < n_in - n_right      # ties: the left
+            ids = jnp.where(in_leaf & (right == small_right), i32(0), i32(1))
+        small_g, small_h = reduced_histogram(ids)
+        with jax.named_scope("gbdt.grow.book"):
+            parent_g, parent_h = hist_g[s], hist_h[s]
+            other_g = parent_g - small_g
+            other_h = jnp.maximum(parent_h - small_h, 0.0)
+            left_g = jnp.where(small_right, other_g, small_g)
+            left_h = jnp.where(small_right, other_h, small_h)
+            right_g = jnp.where(small_right, small_g, other_g)
+            right_h = jnp.where(small_right, small_h, other_h)
+        found = search(jnp.stack([left_g, right_g]),
+                       jnp.stack([left_h, right_h]))
+        with jax.named_scope("gbdt.grow.book"):
+            is_left, is_right = go & (slot == s), go & (slot == new)
+
+            def booked(old, for_left, for_right):
+                return jnp.where(is_left, for_left,
+                                 jnp.where(is_right, for_right, old))
+
+            c_feat, c_bin, c_gain, c_dir = found
+            leaves = (booked(heap, 2 * at + 1, 2 * at + 2),
+                      booked(level, level[s] + 1, level[s] + 1),
+                      booked(gain, c_gain[0], c_gain[1]),
+                      booked(feat, c_feat[0], c_feat[1]),
+                      booked(bin_, c_bin[0], c_bin[1]),
+                      booked(dir_, c_dir[0], c_dir[1]))
+            # a step that split nothing leaves slot ``s`` as it was and
+            # slot ``new`` shut (its gain stays -inf and no row is in it).
+            # The barrier holds the children's histograms as arrays of
+            # their own: without it XLA reads the parent's slot inside
+            # the fusions that write the table, and copies the whole
+            # table (139 MB at 70 x 968 x 256) in and out of every step
+            # to keep the two apart.
+            written = lax.optimization_barrier(
+                (jnp.where(go, left_g, parent_g), right_g,
+                 jnp.where(go, left_h, parent_h), right_h))
+            hists = tuple(
+                lax.dynamic_update_index_in_dim(
+                    lax.dynamic_update_index_in_dim(hist, left, s, 0),
+                    right, new, 0)
+                for hist, left, right in ((hist_g, *written[:2]),
+                                          (hist_h, *written[2:])))
+            row_leaf = jnp.where(right, new, row_leaf)
+            built = jnp.where(slot == new, jnp.minimum(n_right,
+                                                       n_in - n_right), built)
+            splits = splits + go.astype(i32)
+        return row_leaf, leaves, hists, tree, built, splits
+
+    row_leaf, leaves, _, tree, built, splits = lax.fori_loop(
+        0, L - 1, split, (row_leaf, leaves, hists, tree, built, i32(0)))
+
+    # leaf values from (all-reduced) leaf G/H, as _build_tree ends: on
+    # each row's leaf at depth ``depth``
+    n_leaves = 2 ** depth
+    with jax.named_scope("gbdt.leaf"):
+        heap, level = leaves[:2]
+        deepest = (heap + 1 - (1 << level)) << (depth - level)
+        node_ids = _onehot_select(deepest, row_leaf, L)
+        leaf_g, leaf_h = _onehot_segment_sum2(g, h, node_ids, n_leaves)
+        if axis_name is not None:
+            leaf_g = lax.psum(leaf_g, axis_name)
+            leaf_h = lax.psum(leaf_h, axis_name)
+        leaf_val = -leaf_g / (leaf_h + cfg.reg_lambda)
+        delta = cfg.learning_rate * _onehot_select(leaf_val, node_ids,
+                                                   n_leaves)
+    return delta, (*tree, leaf_val), (built, splits)
+
+
 def _sampling_masks(rng_key, cfg: GBDTConfig, N: int, axis_name):
     """Per-tree stochastic-boosting masks (None when inactive).
 
@@ -667,7 +862,23 @@ def _sampling_masks(rng_key, cfg: GBDTConfig, N: int, axis_name):
 def train_tree_shard(bins, y, preds, cfg: GBDTConfig, axis_name=None,
                      weights=None, interpret=None, rng_key=None):
     """One boosting round on this shard's samples. Returns
-    (new_preds, tree).
+    (new_preds, tree): :func:`_train_tree_round` without the grower's
+    counts."""
+    return _train_tree_round(bins, y, preds, cfg, axis_name, weights,
+                             interpret, rng_key)[:2]
+
+
+def _train_tree_round(bins, y, preds, cfg: GBDTConfig, axis_name=None,
+                      weights=None, interpret=None, rng_key=None):
+    """One boosting round on this shard's samples. Returns
+    (new_preds, tree, grown).
+
+    ``cfg.grow_policy`` chooses the grower: ``_build_tree`` ("level")
+    or ``_grow_tree`` ("loss"), which everything below reaches alike.
+    ``grown`` is what the grower counted: nothing, ``()``, for a
+    level-wise tree, whose work follows from its shape; ``(built
+    [max_leaves], splits)`` for a leaf-wise one (``_grow_tree``), with a
+    leading class axis under softmax.
 
     ``weights`` ([N] f32, default all-ones) scales each sample's
     gradient/hessian contribution — the driver uses weight 0 to neutralize
@@ -689,12 +900,18 @@ def train_tree_shard(bins, y, preds, cfg: GBDTConfig, axis_name=None,
     if sample_scale is not None:
         weights = (sample_scale if weights is None
                    else weights * sample_scale)
+    if cfg.grow_policy == "loss":
+        grow = _grow_tree
+    else:
+        def grow(*args):
+            return (*_build_tree(*args), ())
 
     if cfg.loss == "softmax":
         C = cfg.n_classes
         p = jax.nn.softmax(preds, axis=1)          # [N, C]
         trees = []
         deltas = []
+        counts = []
         for c in range(C):                         # C static -> unrolled
             onehot_y = (y.astype(jnp.int32) == c).astype(jnp.float32)
             g = p[:, c] - onehot_y
@@ -702,12 +919,14 @@ def train_tree_shard(bins, y, preds, cfg: GBDTConfig, axis_name=None,
             if weights is not None:
                 g = g * weights
                 h = h * weights
-            delta, tree = _build_tree(bins, g, h, cfg, axis_name,
+            delta, tree, grown = grow(bins, g, h, cfg, axis_name,
                                       interpret, feat_mask)
             deltas.append(delta)
             trees.append(tree)
+            counts.append(grown)
         with jax.named_scope("gbdt.leaf"):
-            return preds + jnp.stack(deltas, axis=1), tuple(trees)
+            return (preds + jnp.stack(deltas, axis=1), tuple(trees),
+                    tuple(jnp.stack(c) for c in zip(*counts)))
 
     # gradient/hessian of the scalar objective at the current margin
     if cfg.loss == "logistic":
@@ -720,10 +939,10 @@ def train_tree_shard(bins, y, preds, cfg: GBDTConfig, axis_name=None,
     if weights is not None:
         g = g * weights
         h = h * weights
-    delta, tree = _build_tree(bins, g, h, cfg, axis_name, interpret,
+    delta, tree, grown = grow(bins, g, h, cfg, axis_name, interpret,
                               feat_mask)
     with jax.named_scope("gbdt.leaf"):
-        return preds + delta, tree
+        return preds + delta, tree, grown
 
 
 def predict_tree(bins, tree, cfg: GBDTConfig):
@@ -929,6 +1148,9 @@ class GBDTTrainer(DataParallelTrainer):
         self._margin_step = None
         self._stacked_trees = None
         self.eval_history_: list[float] = []
+        # the last job's leaf-wise work (grow_policy="loss"): ``splits``
+        # and ``rows_built``, the rows whose histogram was built from rows
+        self.grow_stats_: dict[str, int] = {}
         self.binner_ = None    # fitted by train_raw; rides save_model
 
     def _build_step(self):
@@ -943,25 +1165,31 @@ class GBDTTrainer(DataParallelTrainer):
 
         @partial(jax.shard_map, mesh=self.mesh,
                  in_specs=(spec, spec, spec, spec, P()),
-                 out_specs=(spec, P(None)))
+                 out_specs=(spec, P(None), P()))
         def step(bins, y, preds, weights, key_data):
             rng_key = (jax.random.wrap_key_data(key_data)
                        if sampling else None)
-            new_preds, tree = train_tree_shard(
+            new_preds, tree, grown = _train_tree_round(
                 bins[0], y[0], preds[0], cfg, axes, weights=weights[0],
                 interpret=interpret, rng_key=rng_key)
-            return new_preds[None], tree
+            return new_preds[None], tree, grown
 
-        # how many of a tree's levels route on sliced columns
-        grid = {"route_sliced_levels": sum(
-            route_sliced(2 ** d, cfg.n_features) for d in range(cfg.depth))}
+        if cfg.grow_policy == "loss":
+            # every pass of a leaf-wise tree builds one node
+            levels = [1]
+            grid = {"grow_policy": "loss", "max_leaves": cfg.max_leaves}
+        else:
+            levels = hist_level_nodes(cfg.depth)
+            # how many of a tree's levels route on sliced columns
+            grid = {"route_sliced_levels": sum(
+                route_sliced(2 ** d, cfg.n_features)
+                for d in range(cfg.depth))}
         if cfg.hist_mode == "pallas":
             # which grid the deepest level's kernel runs (it builds the
             # left children of the last split level), and into how many
             # high digits each level's kernel splits a bin
             from ytk_mp4j_tpu.ops.hist_kernel import (feature_blocks,
                                                       hist_radix)
-            levels = hist_level_nodes(cfg.depth)
             block, blocks = feature_blocks(
                 cfg.n_features, cfg.n_bins, max(levels, default=1))
             grid.update(hist_feature_block=block,
@@ -1069,12 +1297,14 @@ class GBDTTrainer(DataParallelTrainer):
 
         base_key = jax.random.key(seed)
         trees = []
+        grown = []      # what each leaf-wise tree counted, on the device
         for i in range(n_trees if n_trees is not None
                        else self.cfg.n_trees):
             with spans.span("mp4j.gbdt.dispatch", job=job, tree=i):
                 kd = jax.random.key_data(jax.random.fold_in(base_key, i))
-                dpreds, tree = self._step(dbins, dy, dpreds, dw, kd)
+                dpreds, tree, counts = self._step(dbins, dy, dpreds, dw, kd)
             trees.append(tree)
+            grown.append(counts)
             if va is not None:
                 va_margins = self._update_margins(va[0], tree, va_margins)
                 metric = self._eval_metric(np.asarray(va_margins), va[1])
@@ -1084,8 +1314,19 @@ class GBDTTrainer(DataParallelTrainer):
                         trees = trees[:stopper.best_round + 1]
                         dpreds = stopper.best_state
                     break
-        with spans.span("mp4j.gbdt.fetch", job=job):
+        with spans.span("mp4j.gbdt.fetch", job=job) as fetched:
             preds = self._to_host(dpreds)
+            # the counts of the trees the job grew (all of them, also
+            # where early stopping keeps fewer) come with the margins:
+            # the device has finished, so no wait is theirs
+            self.grow_stats_ = {}
+            if self.cfg.grow_policy == "loss":
+                built, splits = (np.stack(a)
+                                 for a in zip(*jax.device_get(grown)))
+                self.grow_stats_ = {
+                    "splits": int(splits.sum(dtype=np.int64)),
+                    "rows_built": int(built.sum(dtype=np.int64))}
+                fetched.args.update(self.grow_stats_)
         if self.cfg.loss == "softmax":
             return trees, preds.reshape(-1, self.cfg.n_classes)
         return trees, preds.reshape(-1)
