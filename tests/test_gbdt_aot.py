@@ -25,7 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ytk_mp4j_tpu.models.gbdt import GBDTConfig, GBDTTrainer
+from ytk_mp4j_tpu.models.gbdt import (_SLAB_ROWS, GBDTConfig, GBDTTrainer,
+                                      packed_shape)
 from ytk_mp4j_tpu.ops.hist_kernel import (_acc_bytes_a_feature,
                                           _rests_tiled, feature_blocks,
                                           hist_radix, pallas_hist_supported,
@@ -60,10 +61,14 @@ def _compile_step(devices, chips, depth=DEPTH, n_rows=ROWS, n_features=F,
     def aval(shape, dtype, sharding=rows):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
+    second = ()
+    if cfg.get("grow_policy") == "loss":    # the table's second form
+        second = (aval((chips * per, packed_shape(F, B)[2]), jnp.uint32),)
     return trainer._build_step().lower(
         aval((chips, per, F), jnp.int32), aval((chips, per), jnp.float32),
         aval((chips, per), jnp.float32), aval((chips, per), jnp.float32),
-        aval(kd.shape, kd.dtype, NamedSharding(mesh, P()))).compile()
+        aval(kd.shape, kd.dtype, NamedSharding(mesh, P())),
+        *second).compile()
 
 
 @pytest.fixture(scope="module")
@@ -173,9 +178,14 @@ def test_wide_step_temporaries_far_below_the_table(wide_step):
 # ------------------------------------------------------------------------
 # ISSUE 53: the step that grows its trees leaf by leaf, at the size of
 # benchmark/configs/gbdt-bosch-968-leafwise: 70 leaves, depth at most 7.
+# Since ISSUE 54 a split's pass reads its child's rows a slab at a time,
+# gathered from the table's second form (a row 256 words, one descriptor),
+# and the step takes that form beside the table and only reads it.
 LEAVES, LEAF_DEPTH = 70, 7
 OPEN_LEAVES_BYTES = LEAVES * WIDE_F * B * 2 * 4     # g and h, f32
 CHIP_BYTES = 16 * 10 ** 9
+WORDS = packed_shape(WIDE_F, B)[2]
+SECOND_FORM_BYTES = WIDE_ROWS * WORDS * 4
 
 
 @pytest.fixture(scope="module")
@@ -185,43 +195,109 @@ def leafwise_step(topo_devices):
                          grow_policy="loss", max_leaves=LEAVES)
 
 
+def test_a_slab_is_small_beside_the_table():
+    assert (WORDS, SECOND_FORM_BYTES) == (256, 1_212_156_928)
+    assert _SLAB_ROWS % 2048 == 0       # whole tiles of the kernel
+    assert _SLAB_ROWS * WIDE_F * 4 < WIDE_TABLE_BYTES // 64
+
+
 def test_leafwise_step_fits_the_chip_beside_its_table(leafwise_step):
-    """Arguments (the table, labels, margins, weights) and temporaries
-    (the open leaves' histograms, 139 MB, and a step's arrays) as XLA
-    sizes them: a third of the chip (4.598 and 0.084 GB when this was
-    written; part of the leaves' table is held in another memory space
-    and not counted)."""
+    """Arguments (the table, its second form, labels, margins, weights)
+    and temporaries (the open leaves' histograms, 139 MB; this tree's g
+    and h side by side, 9.5 MB; a slab's rows, gathered and unpacked,
+    0.02 GB) as XLA sizes them: under half the chip. The second form is
+    an argument like the table: nothing is donated, and the step's
+    results are the margins, the tree and the counts."""
     mem = leafwise_step.memory_analysis()
-    assert mem.argument_size_in_bytes >= WIDE_TABLE_BYTES
-    assert mem.argument_size_in_bytes < WIDE_TABLE_BYTES + 4 * 4 * WIDE_ROWS
+    held = WIDE_TABLE_BYTES + SECOND_FORM_BYTES
+    assert held <= mem.argument_size_in_bytes < held + 4 * 4 * WIDE_ROWS
+    assert mem.alias_size_in_bytes == 0
+    assert mem.output_size_in_bytes < 2 * 4 * WIDE_ROWS
     assert mem.temp_size_in_bytes < 2 * OPEN_LEAVES_BYTES, mem
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes) < CHIP_BYTES // 2
 
 
-def test_leafwise_step_loops_over_one_kernel_call(leafwise_step):
-    """The root's histogram and one call in the loop's body, not 70
-    calls; the table comes in as it rests and is the kernel's operand by
-    a bitcast, inside the loop as outside it."""
+def _moved_tables(text):
+    """Every ``copy``, ``pad`` or ``transpose`` of the compiled text,
+    inside a fusion or out, whose result has the table's rows (half of
+    them or more) by a hundred columns or more: the table, its second
+    form, or either transposed."""
+    found = []
+    for m in re.finditer(r"= \w+\[([\d,]+)\]\S* (copy|pad|transpose)\(",
+                         text):
+        dims = [int(d) for d in m.group(1).split(",")]
+        if max(dims) >= WIDE_ROWS // 2 and sorted([1] + dims)[-2] >= 100:
+            found.append(m.group(0))
+    return found
+
+
+def test_moved_tables_detector():
+    text = """
+  %copy.218 = u32[1,1183747,256]{2,1,0:T(8,128)} copy(%param_0.918)
+  %copy = s32[1,1183747,968]{2,1,0:T(8,128)} copy(%bins.1), sharding={}
+  %transpose.9 = u32[256,1183747]{1,0:T(8,128)} transpose(%p), dimensions={1,0}
+  %copy.177 = u32[1183747,2]{1,0:T(8,128)} copy(%maximum_fusion)
+  %pad.231 = bf16[1183747,4]{0,1:T(4,128)(2,1)} pad(%param_0.942, %c)
+  %copy.256 = u32[131072,248]{0,1:T(8,128)} copy(%bitcast.430)
+"""
+    assert [m.split(" ")[1] for m in _moved_tables(text)] == [
+        "u32[1,1183747,256]{2,1,0:T(8,128)}",
+        "s32[1,1183747,968]{2,1,0:T(8,128)}",
+        "u32[256,1183747]{1,0:T(8,128)}"]
+
+
+def test_leafwise_step_loops_over_one_kernel_call_on_a_slab(leafwise_step):
+    """The root's histogram from the table as it rests, and in the body
+    of the loop over a split's slabs, inside the loop over the splits,
+    one call on a slab's rows: two calls, not 70, and no ``switch``;
+    the table is the root kernel's operand by a bitcast; nothing the
+    size of the table or of its second form is copied, padded or
+    transposed anywhere."""
     text = leafwise_step.as_text()
     assert text.count("tpu_custom_call") == 2
-    assert re.search(r" while\(", text)
+    assert len(re.findall(r" while\(", text)) >= 2
+    assert not re.search(r" conditional\(", text)
     assert "s32[1,%d,%d]{1,2,0:T(8,128)} parameter(0)" % (
         WIDE_ROWS, WIDE_F) in text
+    assert re.search(r"u32\[%d,%d\]\{1,0:T\(8,128\)\} parameter\(\d\)" % (
+        WIDE_ROWS, WORDS), text)         # the second form, row-major
     assert len(re.findall(r"= s32\[%d,%d\]\{1,0:T\(8,128\)\} bitcast\("
-                          % (WIDE_F, WIDE_ROWS), text)) == 2
+                          % (WIDE_F, WIDE_ROWS), text)) == 1
     assert _row_major_tables(text, WIDE_ROWS // 2, WIDE_F) == []
-    assert not re.search(
-        r"= s32\[[\d,]*\d{6,},[\d,]*\d{3,}[\d,]*\]\S* (copy|pad|transpose)\(",
-        text)
+    assert _moved_tables(text) == []
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert sorted(m for ln in calls for m in _op_names(ln)) == [
         "jit(step)/gbdt.hist/mp4j_hist/pallas_call",
-        "jit(step)/while/body/closed_call/gbdt.hist/mp4j_hist/pallas_call"]
+        "jit(step)/while/body/closed_call/while/body/gbdt.hist/mp4j_hist/"
+        "pallas_call"]
+    # the loop's kernel reads a slab's rows: [F, slab] int32, unpacked
+    operands = sorted(int(n) for ln in calls for n in re.findall(
+        r"operand_layout_constraints=\{s32\[%d,(\d+)\]\{1,0\}" % WIDE_F, ln))
+    assert operands == [_SLAB_ROWS, WIDE_ROWS]
     for part in ("gbdt.grow.pick", "gbdt.grow.book", "gbdt.route",
-                 "gbdt.best_splits"):
+                 "gbdt.best_splits", "gbdt.grow.book/gbdt.grow.compact",
+                 "while/body/gbdt.grow.book/gbdt.grow.compact"):
         assert _op_names(text, r"while/body/closed_call/" + re.escape(part))
     assert not _op_names(text, r"gbdt\.level\.")
+    assert not _op_names(text, r"gbdt\.grow\..*gbdt\.hist")
+
+
+def test_leafwise_step_gathers_a_row_a_descriptor(leafwise_step):
+    """Every gather of the second form, and of the prefix counts that
+    find a slab's rows, is XLA's native row gather: a slab's size of
+    descriptors, each a whole row (256 words of the second form, 128
+    counts). The one gather beside them brings the slab's g and h, a
+    pair a descriptor, and no cell of either table is gathered alone."""
+    text = leafwise_step.as_text()
+    found = _table_gathers(text, r"gbdt\.grow\.compact")
+    assert sorted(g[:2] for g in found) == sorted(
+        [(_SLAB_ROWS, WORDS)] + [(_SLAB_ROWS, 128)] * 2
+        + [(_SLAB_ROWS, 2)]), found
+    # and nothing else gathers there: every gather under the scope is
+    # the body of one of those fusions
+    assert len([ln for ln in text.splitlines() if " gather(" in ln
+                and "gbdt.grow.compact" in ln]) == len(found)
 
 
 def test_leafwise_step_updates_the_open_leaves_where_they_rest(leafwise_step):
@@ -236,8 +312,10 @@ def test_leafwise_step_updates_the_open_leaves_where_they_rest(leafwise_step):
 
 
 def test_four_chip_leafwise_step_reduces_a_node_a_split(topo_devices):
-    """Rows sharded over four chips: the built child's histogram and the
-    children's row counts are summed over the axis inside the loop."""
+    """Rows sharded over four chips: the children's row counts are
+    summed over the axis inside the loop over the splits, and the built
+    child's histogram after the loop over its slabs, not inside it, so
+    that every shard may take the slabs its own rows need."""
     step = _compile_step(topo_devices, 4, depth=LEAF_DEPTH,
                          n_rows=WIDE_ROWS + 1, n_features=WIDE_F,
                          missing_bin=True, grow_policy="loss",
@@ -247,9 +325,15 @@ def test_four_chip_leafwise_step_reduces_a_node_a_split(topo_devices):
     reduced = _op_names(text, r"while/body/closed_call/.*psum")
     assert any("gbdt.grow.book" in m for m in reduced)      # the counts
     assert any("gbdt.grow.book" not in m for m in reduced)  # the histogram
+    assert not [m for m in reduced if "closed_call/while/body" in m]
+    reduces = [ln for ln in text.splitlines() if " all-reduce(" in ln
+               or " all-reduce-start(" in ln]
+    assert any(re.search(r"f32\[[\d,]*%d,%d\]" % (WIDE_F, B), ln)
+               for ln in reduces)
     mem = step.memory_analysis()
-    assert mem.argument_size_in_bytes < WIDE_TABLE_BYTES // 4 + 2 ** 24
-    assert mem.temp_size_in_bytes < 2 * OPEN_LEAVES_BYTES
+    assert mem.argument_size_in_bytes < (
+        WIDE_TABLE_BYTES + SECOND_FORM_BYTES) // 4 + 2 ** 24
+    assert mem.temp_size_in_bytes < 3 * OPEN_LEAVES_BYTES
 
 
 def _computations(text):
@@ -1325,17 +1409,17 @@ def test_sharded_step_lowers_to_the_program_it_was(sharded_programs):
 
 
 # ------------------- which form of its gather XLA emits for the table (PR 40)
-def _table_gathers(text):
+def _table_gathers(text, scope=r"ffm\.table_gather"):
     """``(rows, width, integer_config, scoped VMEM bytes, index operand)``
-    of every native gather fusion under ``ffm.table_gather``: how many
-    descriptors XLA issues at a time, what it holds of VMEM for them, and
-    the fusion that makes its index list."""
+    of every native gather fusion under ``scope``: how many descriptors
+    XLA issues at a time, what it holds of VMEM for them, and the fusion
+    that makes its index list."""
     found = []
     for line in text.splitlines():
         if not (" fusion(" in line and "kind=kCustom" in line
-                and re.search(r"ffm\.table_gather/gather", line)):
+                and re.search(scope + r"/gather", line)):
             continue
-        rows, width = re.search(r"= f32\[(\d+),(\d+)\]", line).groups()
+        rows, width = re.search(r"= \w+\[(\d+),(\d+)\]", line).groups()
         feeder = re.search(r" fusion\(%[^,)]+, %([^,)]+)\)", line).group(1)
         found.append((
             int(rows), int(width),

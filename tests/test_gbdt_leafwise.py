@@ -7,6 +7,8 @@ rules (the first candidate in (feature, bin, direction) order among
 equal gains of a node; the lowest heap index among equal gains of open
 leaves)."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -69,11 +71,13 @@ def np_goes_right(bins, feature, bin_, direction, cfg):
     return right
 
 
-def np_grow(bins, g, h, cfg, feat_mask=None):
+def np_grow(bins, g, h, cfg, feat_mask=None, built=None):
     """One leaf-wise tree. Returns (tree, order, rows_built): the heap
     tree ``(feature, bin, direction, leaf)``, the heap indices in the
     order they were split, and the rows a grower that builds the
-    smaller child from rows would have read."""
+    smaller child from rows would have read. ``built``, a list, takes
+    the rows [N] bool of every split's smaller child (ties: the left),
+    in the order of the splits."""
     depth, B_ = cfg.depth, cfg.n_bins
     n_internal = 2 ** depth - 1
     feat = np.zeros(n_internal, np.int64)
@@ -95,6 +99,8 @@ def np_grow(bins, g, h, cfg, feat_mask=None):
         right = rows & np_goes_right(bins, f, b, d, cfg)
         left = rows & ~right
         rows_built += min(left.sum(), right.sum())
+        if built is not None:
+            built.append(right if right.sum() < left.sum() else left)
         for child, held in ((2 * at + 1, left), (2 * at + 2, right)):
             leaves[child] = (held, level + 1, np_best_split(
                 bins, g, h, held, cfg, feat_mask))
@@ -133,15 +139,19 @@ def np_gradients(margins, y, loss):
     return margins - y, np.ones_like(margins)
 
 
-def np_train(bins, y, cfg, n_trees, weight=None):
-    """Leaf-wise boosting in float64: (trees, margins, rows_built)."""
+def np_train(bins, y, cfg, n_trees, weight=None, built_rows=None):
+    """Leaf-wise boosting in float64: (trees, margins, rows_built);
+    ``built_rows``, a list, takes ``np_grow``'s ``built`` of each tree."""
     margins = np.zeros(bins.shape[0])
     trees, built = [], 0
     for _ in range(n_trees):
         g, h = np_gradients(margins, y.astype(np.float64), cfg.loss)
         if weight is not None:
             g, h = g * weight, h * weight
-        tree, _, rows = np_grow(bins, g, h, cfg)
+        children = []
+        tree, _, rows = np_grow(bins, g, h, cfg, built=children)
+        if built_rows is not None:
+            built_rows.append(children)
         margins = margins + cfg.learning_rate * tree[3][
             np_leaf_of(tree, bins, cfg)]
         trees.append(tree)
@@ -283,6 +293,147 @@ def test_uneven_rows_and_a_hierarchical_mesh_grow_the_same_trees():
     for mesh in (make_mesh(4), make_hier_mesh(2, 2)):
         trees, _ = GBDTTrainer(cfg, mesh=mesh).train(bins, y, n_trees=1)
         assert_same_tree(trees[0], want[0], cfg)
+
+
+# ----------------------------------------------------------------------
+# a split reads a slab of its child's rows (ISSUE 54)
+# ----------------------------------------------------------------------
+@pytest.fixture
+def small_slabs(monkeypatch):
+    """Slabs of 32 rows, so that a table of 2,048 rows has children
+    under, at and over a slab and over many."""
+    monkeypatch.setattr(gbdt, "_SLAB_ROWS", 32)
+    return 32
+
+
+def rows_read_by_hand(trees_children, rows, n_shards, slab):
+    """The rows the passes of a job read, from the reference's children:
+    the root's pass every row the mesh holds (the rows that fill the
+    last shard too), a split's pass on every shard as many slabs as hold
+    the shard's rows of the built child: none where it has none."""
+    per = -(-rows // n_shards)
+    total = 0
+    for children in trees_children:
+        total += per * n_shards
+        for built in children:
+            child = np.zeros(per * n_shards, bool)
+            child[:rows] = built
+            total += sum(-(-mine // slab) * slab
+                         for mine in child.reshape(n_shards, per).sum(1))
+    return int(total)
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+@pytest.mark.parametrize("rows", [N, N - 3])
+def test_a_split_reads_a_slab_of_its_childs_rows(small_slabs, rows,
+                                                 n_devices):
+    """Children of under one slab and of many, on one shard and on four
+    (each takes the slabs its own rows need), with rows that do not
+    divide by the shards: the reference's trees, its count of the rows
+    built, and the slabs' rows by hand."""
+    bins, score = table(seed=53, n=rows)
+    y = labels(score, "squared")
+    cfg = config(depth=6, max_leaves=14)
+    tr = GBDTTrainer(cfg, mesh=make_mesh(n_devices))
+    trees, margins = tr.train(bins, y, n_trees=2)
+    # the rows that fill the last shard are rows of the step's (bins 0,
+    # weight 0: they follow bin 0 and count where they land), so the
+    # reference grows on the table as the mesh holds it
+    fill = -rows % n_devices
+    held = np.concatenate([bins, np.zeros((fill, F), np.int32)])
+    weight = np.concatenate([np.ones(rows), np.zeros(fill)])
+    children = []
+    want, want_margins, rows_built = np_train(
+        held, np.concatenate([y, np.zeros(fill, np.float32)]), cfg, 2,
+        weight if fill else None, built_rows=children)
+    for got, ref in zip(trees, want):
+        assert_same_tree(got, ref, cfg)
+    np.testing.assert_allclose(margins[:rows], want_margins[:rows],
+                               rtol=1e-4, atol=1e-4)
+    assert tr.grow_stats_ == {"splits": 2 * 13, "rows_built": rows_built}
+    sizes = sorted({int(c.sum()) for t in children for c in t})
+    assert sizes[0] <= 2 * 32 and sizes[-1] > 10 * 32  # few slabs and many
+    assert tr.grow_rows_read_ == rows_read_by_hand(
+        children, rows + fill, n_devices, small_slabs)
+    # far fewer than the passes over the table they replace
+    assert tr.grow_rows_read_ < 2 * (rows + 13 * rows // 2)
+
+
+def test_a_step_that_splits_nothing_reads_no_slab(small_slabs):
+    """Where no open leaf clears the threshold the built child is empty:
+    the step's loop over the slabs has no trip and it changes nothing."""
+    bins, score = table(seed=7)
+    y = labels(score, "squared")
+    cfg = config(max_leaves=16, min_split_gain=25.0)
+    tr = GBDTTrainer(cfg, n_devices=1)
+    trees, _ = tr.train(bins, y, n_trees=1)
+    children = []
+    want, order, rows_built = np_grow(bins, *np_gradients(
+        np.zeros(N), y.astype(np.float64), "squared"), cfg, built=children)
+    assert 2 <= len(order) + 1 < 16
+    assert_same_tree(trees[0], want, cfg)
+    assert tr.grow_stats_ == {"splits": len(order), "rows_built": rows_built}
+    assert tr.grow_rows_read_ == rows_read_by_hand([children], N, 1,
+                                                   small_slabs)
+
+
+@pytest.mark.parametrize("held,slab", [(31, 32), (32, 32), (33, 64),
+                                       (64, 64), (65, 96)])
+def test_a_child_of_exactly_a_slabs_size_takes_that_slab(small_slabs, held,
+                                                         slab):
+    """One split whose smaller child holds ``held`` rows: a slab's own
+    size still fits it and one row more takes a second."""
+    rng = np.random.default_rng(59)
+    bins = rng.integers(1, B, (N, F)).astype(np.int32)
+    bins[:held, 0] = 0
+    y = (5.0 * (bins[:, 0] == 0) + 0.01 * rng.standard_normal(N)).astype(
+        np.float32)
+    cfg = config(max_leaves=2)
+    tr = GBDTTrainer(cfg, n_devices=1)
+    trees, _ = tr.train(bins, y, n_trees=1)
+    want, order, rows_built = np_grow(bins, *np_gradients(
+        np.zeros(N), y.astype(np.float64), "squared"), cfg)
+    assert order == [0] and rows_built == N + held
+    assert_same_tree(trees[0], want, cfg)
+    assert tr.grow_stats_ == {"splits": 1, "rows_built": N + held}
+    assert tr.grow_rows_read_ == N + slab
+
+
+def test_the_second_form_holds_every_cell():
+    """``pack_rows`` and ``_unpack_rows`` are each other's inverse at a
+    width that fills no word evenly, for 8-bit and for 16-bit bins."""
+    rng = np.random.default_rng(61)
+    for n_bins, width in ((256, 13), (1024, 5), (16, 6)):
+        cells = rng.integers(0, n_bins, (300, width)).astype(np.int32)
+        per, bin_words, words = gbdt.packed_shape(width, n_bins)
+        assert per == (4 if n_bins <= 256 else 2)
+        assert bin_words % 8 == 0 and words % 128 == 0 \
+            and words >= bin_words
+        packed = gbdt.pack_rows(jnp.asarray(cells), n_bins)
+        assert packed.shape == (300, words) and packed.dtype == jnp.uint32
+        rows = np.array([7, 7, 299, 0])
+        back = gbdt._unpack_rows(packed[rows], width, n_bins)
+        np.testing.assert_array_equal(np.asarray(back).T, cells[rows])
+
+
+@pytest.mark.parametrize("marked", [0, 1, 129, 5000])
+def test_ranks_find_the_marked_rows_in_order(marked):
+    """``_kth_rows`` on ``_ranks``' counts gives the marked rows' numbers
+    ascending, over more than one group of blocks, and some row of the
+    table for a rank past the last."""
+    rows = 128 * 128 + 77
+    rng = np.random.default_rng(marked)
+    mask = np.zeros(rows, bool)
+    mask[rng.choice(rows, marked, replace=False)] = True
+    if marked > 1:
+        mask[[0, rows - 1]] = True
+    counts = gbdt._ranks(jnp.asarray(mask))
+    assert counts[2].shape == (2,)
+    got = np.asarray(gbdt._kth_rows(
+        counts, jnp.arange(marked + 50, dtype=jnp.int32), rows))
+    want = np.flatnonzero(mask)
+    np.testing.assert_array_equal(got[:len(want)], want)
+    assert ((0 <= got) & (got < rows)).all()
 
 
 # ----------------------------------------------------------------------
@@ -508,7 +659,12 @@ def test_the_default_policy_never_reaches_the_new_grower(monkeypatch):
     def refuse(*args, **kw):
         raise AssertionError("a level-wise step called _grow_tree")
 
+    def no_second_form(*args, **kw):
+        raise AssertionError("a level-wise job packed its table")
+
     monkeypatch.setattr(gbdt, "_grow_tree", refuse)
+    monkeypatch.setattr(gbdt, "pack_rows", no_second_form)
+    monkeypatch.setattr(GBDTTrainer, "_build_pack", no_second_form)
     bins, score = table(seed=43)
     y = labels(score, "logistic")
     cfg = GBDTConfig(n_features=F, n_bins=B, depth=3, loss="logistic")
@@ -516,9 +672,22 @@ def test_the_default_policy_never_reaches_the_new_grower(monkeypatch):
     tr = GBDTTrainer(cfg, mesh=make_mesh(2))
     trees, margins = tr.train(bins, y, n_trees=2)
     assert len(trees) == 2 and tr.grow_stats_ == {}
+    assert tr.grow_rows_read_ == 0 and tr._pack is None
+    # the step takes and returns what it did: five arguments, nothing
+    # donated, three results
+    data = tr.shard_data(bins, y)
+    lowered = tr._step.lower(*data, jax.random.key_data(jax.random.key(0)))
+    assert len(jax.tree.leaves(lowered.args_info)) == 5
+    assert not any(a.donated for a in jax.tree.leaves(lowered.args_info))
+    assert len(lowered.out_info) == 3 and lowered.out_info[2] == ()
     np.testing.assert_allclose(tr.predict(bins, trees), margins[:N],
                                rtol=1e-5, atol=1e-6)
-    # and the leaf-wise step does reach it
+    # and a leaf-wise job does reach them: the second form in its
+    # staging, the grower in its step
+    with pytest.raises(AssertionError, match="packed its table"):
+        GBDTTrainer(config(), n_devices=1).train(bins, y, n_trees=1)
+    monkeypatch.undo()
+    monkeypatch.setattr(gbdt, "_grow_tree", refuse)
     with pytest.raises(AssertionError, match="_grow_tree"):
         GBDTTrainer(config(), n_devices=1).train(bins, y, n_trees=1)
 
@@ -536,12 +705,17 @@ def test_build_and_fetch_spans_carry_the_policy_and_the_counts():
         got = spans.snapshot()
     finally:
         spans.configure(tuning.span_ring_capacity())
-    build, = [s[6] for s in got if s[0] == "mp4j.step.build"]
+    build, pack = [s[6] for s in got if s[0] == "mp4j.step.build"]
+    assert pack == {"key": "gbdt_grow_pack"}    # once, with the first job
     assert build["grow_policy"] == "loss" and build["max_leaves"] == 7
     assert build["hist_radix"] == "1"       # one node a pass, 16 bins
     fetch, = [s[6] for s in got if s[0] == "mp4j.gbdt.fetch"]
-    assert fetch == {"job": 0, **tr.grow_stats_}
+    assert fetch == {"job": 0, **tr.grow_stats_,
+                     "rows_read": tr.grow_rows_read_}
     assert fetch["splits"] == 12
+    # each tree: the table for the root, one slab a split (no child of
+    # 2,048 rows is larger)
+    assert fetch["rows_read"] == 2 * N + 12 * gbdt._SLAB_ROWS
     # each tree: its rows for the root, at most half a leaf's a split
     assert 2 * N < fetch["rows_built"] <= 2 * N + 12 * (N // 2)
 
@@ -550,12 +724,25 @@ def test_lowered_step_holds_the_growers_scopes():
     bins, score = table(seed=47, n=256)
     tr = GBDTTrainer(config(max_leaves=5), mesh=make_mesh(2))
     data = tr.shard_data(bins, labels(score, "squared"))
-    text = tr._build_step().lower(
-        *data, jax.random.key_data(jax.random.key(0))).as_text(
-            debug_info=True)
+    lowered = tr._build_step().lower(
+        *data, jax.random.key_data(jax.random.key(0)),
+        tr._build_pack()(data[0]))
+    # the second form is a sixth argument the step only reads: nothing
+    # donated, and the level-wise step's three results
+    assert len(jax.tree.leaves(lowered.args_info)) == 6
+    assert not any(a.donated for a in jax.tree.leaves(lowered.args_info))
+    assert len(lowered.out_info) == 3 and len(lowered.out_info[2]) == 3
+    text = lowered.as_text(debug_info=True)
     for scope in ("gbdt.grow.pick", "gbdt.grow.book", "gbdt.hist",
-                  "gbdt.route", "gbdt.best_splits", "gbdt.leaf"):
+                  "gbdt.route", "gbdt.best_splits", "gbdt.leaf",
+                  "gbdt.grow.book/gbdt.grow.compact"):
         assert f"{scope}" in text, scope
+    # the compaction, the gather and the unpacking stand inside the book
+    # round and the kernel's call outside both
+    stacks = re.findall(r'"([^"]*gbdt\.grow\.compact[^"]*)"', text)
+    assert stacks and all(re.search(
+        r"gbdt\.grow\.book/gbdt\.grow\.compact", s) for s in stacks)
+    assert not re.search(r'"[^"]*gbdt\.grow\.[^"]*gbdt\.hist', text)
     assert "gbdt.level." not in text
     # the splits are a loop of the program, not max_leaves - 1 copies
     assert text.count("stablehlo.while") >= 1

@@ -658,6 +658,165 @@ def _build_tree(bins, g, h, cfg: GBDTConfig, axis_name, interpret,
     return delta, (tree_feat, tree_bin, tree_dir, leaf_val)
 
 
+# ----------------------------------------------------------------------
+# a leaf-wise pass reads its child's rows, not the table (ISSUE 54)
+#
+# A split's histogram is built from the smaller child's rows: 14,000 of
+# 1,183,747 on average at 70 leaves on the Bosch table, where the
+# kernel's pass over the table as it rests ([F, N], samples on the
+# lanes) costs 48.8 ms whatever the node holds. So the grower keeps a
+# second form of the table in which a row is one descriptor
+# (``pack_rows``: the bins four to a 32-bit word, a row padded to whole
+# 128-lane words so that it rests row-major; made once a job and only
+# read after), finds the built child's row numbers from prefix counts of
+# its mask (``_ranks`` once a split over all rows, ``_kth_rows`` a slot
+# of the slab: compares and two row gathers, no sort and no scatter),
+# gathers them into a slab of one static size, as often as the child
+# needs, the tree's g and h by the same row numbers beside them, and
+# hands each slab, unpacked, to the unchanged kernel. PERF.md section 6
+# (PR 54) has the chip's prices of each piece and of the forms not
+# taken.
+# ----------------------------------------------------------------------
+# Rows a slab: a child is read in ``ceil(rows / _SLAB_ROWS)`` slabs, so an
+# empty child (a step that splits nothing, a shard that holds none of it)
+# in none. One size and a loop, not a ladder of sizes behind a ``switch``:
+# every size is one more Mosaic kernel to lower and compile in a job's
+# set-up, and in the step a kernel call costs what its rows cost (0.085
+# ms on 2,048 rows, 41 ns a row), so a ladder buys nothing a loop does
+# not. My chip runs, PR 54, the Bosch leaf-wise cell, ``trees_per_s`` and
+# warm ``setup_s`` (10.5 s before): seven sizes 2,048 to 131,072 2.86 and
+# 25.7 s; 2,048 / 8,192 / 32,768 2.82 and 15.8; 2,048 / 8,192 3.14 and
+# 12.4; one size of 2,048 3.08-3.14, 4,096 3.02-3.10, 8,192 2.98-2.99,
+# each 7.7-7.8 s. Three in five of that table's built children hold
+# under 2,048 rows, so a larger slab reads more rows that count for
+# nothing (the kernel 89.5 ms a tree at 4,096, 99.7 at 8,192); a smaller
+# one calls the kernel more often, and each call leaves two copies of
+# its output (9.5 us each) that XLA gives no name, which the residual
+# ``gbdt_grow_unscoped_ms_per_tree`` is asked to keep under 10 ms: 14.6
+# at 2,048, 9.9 at 4,096, 8.3 at 8,192. So 8,192 is not the fastest
+# size: it gives up 1-4% of ``trees_per_s`` to keep the unnamed time
+# under that threshold, and it is fitted to this one table's children
+# (2,048 is the size to take once those copies have a name).
+_SLAB_ROWS = 8192
+_RANK_LANES = 128   # rows a block of the prefix counts (a lane word)
+
+
+def packed_shape(F: int, n_bins: int) -> tuple[int, int, int]:
+    """``(bins a word, words of bins a row, words a row)`` of the
+    table's second form: a bin in 8 bits while 256 bins allow (16, 32
+    beyond), feature f in digit ``f // bin_words`` of word ``f %
+    bin_words`` with ``bin_words`` in whole sublane tiles of 8, so that
+    unpacking is ``per`` aligned blocks of rows one after the other;
+    the row filled to whole 128-lane words (968 features: 4, 248, 256:
+    1,024 B a row)."""
+    def whole(n, unit):
+        return -(-n // unit) * unit
+
+    per = 4 if n_bins <= 2 ** 8 else 2 if n_bins <= 2 ** 16 else 1
+    bin_words = whole(-(-F // per), 8)
+    return per, bin_words, whole(bin_words, 128)
+
+
+def pack_rows(bins, n_bins: int):
+    """The second form of a shard's table: ``bins`` [N, F] int32 as
+    [N, words] uint32 (``packed_shape``). A
+    row is then one contiguous run of memory and one descriptor of a
+    gather, where the table as it rests ([F, N]) has a row's cells F
+    strides apart. The words are made where the table rests, blocks of
+    its rows against one another, and transposed once: the layout
+    constraint holds them [bin_words, N] row-major, without which XLA
+    turns the whole table row-major first, 4.85 GB beside it (AOT for
+    v5e, PR 54: 80M estimated cycles and 6.06 GB of temporaries against
+    34M and 1.21). Under ``stage.place``: it is staging's work on the
+    device, once a job."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    F = bins.shape[1]
+    per, bin_words, words = packed_shape(F, n_bins)
+    with jax.named_scope("stage.place"), jax.named_scope("gbdt.grow.pack"):
+        cells, word = bins.T, None
+        for k in range(per):
+            part = cells[k * bin_words:min((k + 1) * bin_words, F)]
+            part = jnp.pad(part, ((0, bin_words - part.shape[0]), (0, 0)))
+            part = part << (k * (32 // per))
+            word = part if word is None else word | part
+        word = with_layout_constraint(
+            lax.bitcast_convert_type(word, jnp.uint32),
+            Layout(major_to_minor=(0, 1)))
+        return jnp.pad(word, ((0, words - bin_words), (0, 0))).T
+
+
+def _unpack_rows(slab, F: int, n_bins: int):
+    """The bins [F, S] int32 of gathered rows ``slab`` [S, words]: the
+    table's first form again for these rows, the samples on the lanes
+    as the kernel reads them."""
+    per, bin_words, _ = packed_shape(F, n_bins)
+    bits = 32 // per
+    word = slab.T[:bin_words]
+    # digit k holds features k * bin_words and on: whole sublane tiles,
+    # one after the other, the last digit's cut to the table's width
+    return jnp.concatenate([
+        ((word[:F - k * bin_words] >> (bits * k)) & (2 ** bits - 1)
+         if per > 1 else word[:F]).astype(jnp.int32)
+        for k in range(per) if k * bin_words < F], 0)
+
+
+def _ranks(mask):
+    """Prefix counts of ``mask`` [N] on three levels, int32: ``within``
+    [blocks, lanes], a row's count of marked rows from the start of its
+    block of ``_RANK_LANES`` rows to itself; ``upto`` [groups, lanes],
+    a block's from the table's start to its own end, a group of blocks
+    a row (the table's count past the last block); ``top`` [groups], a
+    group's. One pass over the rows a split. A row of 128 is summed
+    along itself on the MXU, against a triangle of ones (counts to 128
+    are bf16 numbers and the sums f32's, to 2**24 rows exact): as a
+    ``cumsum`` XLA makes a ``reduce_window`` of it that takes 0.23 ms
+    at 1,183,747 rows, a tenth of a split (my chip run, PR 54)."""
+    i32 = jnp.int32
+    N = mask.shape[0]
+    blocks = -(-N // _RANK_LANES)
+    groups = -(-blocks // _RANK_LANES)
+    lane = jnp.arange(_RANK_LANES)
+    upper = (lane[:, None] <= lane[None, :]).astype(jnp.bfloat16)
+
+    def along(rows):
+        return jnp.dot(rows.astype(jnp.bfloat16), upper,
+                       preferred_element_type=jnp.float32).astype(i32)
+
+    within = along(jnp.pad(mask, (0, blocks * _RANK_LANES - N)).reshape(
+        blocks, _RANK_LANES))
+    ends = along(jnp.pad(within[:, -1], (0, groups * _RANK_LANES - blocks)
+                         ).reshape(groups, _RANK_LANES))
+    top = jnp.cumsum(ends[:, -1], dtype=i32)
+    return within, ends + (top - ends[:, -1])[:, None], top
+
+
+def _kth_rows(counts, ranks, N: int):
+    """The row numbers of the marked rows of ranks ``ranks`` [S] (0 the
+    first) from ``_ranks``' ``counts``. A rank's group is the count of
+    groups that end at or under it, its block the count of its group's
+    blocks that do, its lane the count of its block's lanes at or under
+    what is left of it: compares against a short vector and against two
+    gathered rows of 512 B, and nothing is sorted, searched or
+    scattered. A rank past the last marked row gives some row under
+    N."""
+    i32 = jnp.int32
+    within, upto, top = counts
+    ranks = ranks[:, None]
+    under = top[None, :] <= ranks                       # [S, groups]
+    group = jnp.minimum(under.sum(1, dtype=i32), top.shape[0] - 1)
+    ends = upto[group]                                  # [S, lanes]
+    ended = ends <= ranks
+    block = jnp.minimum(group * _RANK_LANES + ended.sum(1, dtype=i32),
+                        within.shape[0] - 1)
+    # the marked rows before the block: the greatest count at or under
+    # the rank, which is the block's before it
+    before = jnp.maximum(jnp.where(under, top[None, :], 0).max(1),
+                         jnp.where(ended, ends, 0).max(1))
+    lane = (within[block] <= ranks - before[:, None]).sum(1, dtype=i32)
+    return jnp.minimum(block * _RANK_LANES + lane, N - 1)
+
+
 def _pick_leaf(open_, gain, heap, none):
     """The slot of the open leaf to split next: the greatest gain, ties
     to the lowest heap index (``none``, past every heap index, where no
@@ -668,13 +827,15 @@ def _pick_leaf(open_, gain, heap, none):
 
 
 def _grow_tree(bins, g, h, cfg: GBDTConfig, axis_name, interpret,
-               feat_mask=None):
+               feat_mask=None, packed=None):
     """Grow one tree leaf by leaf (``grow_policy="loss"``): of all open
     leaves the one whose best split gains most is split, ``max_leaves -
     1`` times over, and no leaf lies deeper than ``depth``. Takes what
-    ``_build_tree`` takes and returns its ``(delta, tree)`` with the
-    counts of the work behind them: ``(delta [N], tree, (built
-    [max_leaves] int32, splits int32))``.
+    ``_build_tree`` takes, and the table's second form ``packed``
+    (``pack_rows``; made here where none comes), and returns
+    ``_build_tree``'s ``(delta, tree)`` with the counts of the work
+    behind them: ``(delta [N], tree, (built [max_leaves] int32, splits
+    int32, read [max_leaves] int32))``.
 
     The unit of work is one split, each waiting for the last, so the
     splits are a loop of the program and the open leaves its carry: a
@@ -686,13 +847,22 @@ def _grow_tree(bins, g, h, cfg: GBDTConfig, axis_name, interpret,
     at its heap index, routes that leaf's rows by ``_route_samples``'
     rule, builds the histogram of the child with fewer rows (summed over
     ``axis_name``, so that every shard builds the same child; ties: the
-    left) from the rows, every other row on the sentinel id, and takes
-    its sibling's as the parent's less it, the hessians clamped as
-    ``_build_tree`` clamps them. The left child keeps the slot, the
-    right child takes slot ``step + 1``. Where no leaf is open the step
-    changes nothing (the trip count is static). ``built`` holds the
-    rows whose histogram was built from rows: the tree's rows for the
-    root, then the smaller child's for every step.
+    left) from its rows alone, and takes its sibling's as the parent's
+    less it, the hessians clamped as ``_build_tree`` clamps them. The
+    left child keeps the slot, the right child takes slot ``step + 1``.
+    Where no leaf is open the step changes nothing (the trip count is
+    static) and its child is empty. ``built`` holds the rows whose
+    histogram was built from rows: the tree's rows for the root, then
+    the smaller child's for every step.
+
+    The root's pass reads the table as it rests. A split's reads the
+    child's rows gathered from ``packed`` into slabs of ``_SLAB_ROWS``
+    rows, g and h by the same row numbers, as many slabs as hold this
+    shard's rows of the child (a shard counts its own, the sum over
+    ``axis_name`` standing after the loop), every slot past the child's
+    last row on the sentinel id with g = h = 0, and the kernel is the
+    one ``build_histograms`` calls for one node, on a slab's rows. ``read`` holds the rows the passes
+    read: the tree's for the root, the slabs' for every step.
 
     The tree is ``_build_tree``'s level-order heap of depth ``depth``:
     a node that was never split is frozen the way ``_build_tree``
@@ -701,7 +871,7 @@ def _grow_tree(bins, g, h, cfg: GBDTConfig, axis_name, interpret,
     level-local index shifted left by ``depth - k``, where its value
     stands."""
     N = bins.shape[0]
-    L, depth, B = cfg.max_leaves, cfg.depth, cfg.n_bins
+    L, depth, B, F = cfg.max_leaves, cfg.depth, cfg.n_bins, cfg.n_features
     n_internal = 2 ** depth - 1
     cat_mask = cfg._cat_mask()
     i32 = jnp.int32
@@ -709,11 +879,29 @@ def _grow_tree(bins, g, h, cfg: GBDTConfig, axis_name, interpret,
     def psum(x):
         return x if axis_name is None else lax.psum(x, axis_name)
 
-    def reduced_histogram(ids):
-        """One node's local histogram + the distributed allreduce."""
-        a, b = build_histograms(bins, g, h, ids, 1, cfg,
-                                interpret=interpret)
-        return psum(a)[0], psum(b)[0]       # THE histogram allreduce
+    def slab_histogram(counts, count):
+        """This shard's histogram of the ``count`` rows that ``counts``
+        (``_ranks``) mark, a slab at a time."""
+        def fill(i, sums):
+            with jax.named_scope("gbdt.grow.book"), \
+                    jax.named_scope("gbdt.grow.compact"):
+                ranks = i * i32(_SLAB_ROWS) + jnp.arange(_SLAB_ROWS,
+                                                         dtype=i32)
+                live = ranks < count
+                rows = _kth_rows(counts, ranks, N)
+                slab_bins = _unpack_rows(packed[rows], F, B)
+                slab_g, slab_h = jnp.where(live, gh[:, rows], 0.0)
+                ids = jnp.where(live, i32(0), i32(1))
+            (a,), (b,) = build_histograms(slab_bins.T, slab_g, slab_h, ids,
+                                          1, cfg, interpret=interpret)
+            with jax.named_scope("gbdt.grow.book"):
+                return sums[0] + a, sums[1] + b
+
+        none = jnp.zeros((F, B), jnp.float32)
+        if axis_name is not None:
+            none = lax.pcast(none, axis_name, to="varying")
+        return lax.fori_loop(i32(0), -(-count // i32(_SLAB_ROWS)), fill,
+                             (none, none))
 
     def search(hg, hh):
         return best_splits(hg, hh, cfg.reg_lambda, feat_mask,
@@ -725,7 +913,8 @@ def _grow_tree(bins, g, h, cfg: GBDTConfig, axis_name, interpret,
     row_leaf = jnp.zeros((N,), i32)         # every row in the root's slot
     if axis_name is not None:
         row_leaf = lax.pcast(row_leaf, axis_name, to="varying")
-    root_g, root_h = reduced_histogram(row_leaf)
+    root_g, root_h = (psum(a)[0] for a in build_histograms(
+        bins, g, h, row_leaf, 1, cfg, interpret=interpret))
     feat, bin_, gain, dir_ = search(root_g[None], root_h[None])
     with jax.named_scope("gbdt.grow.book"):
         first = slot == 0
@@ -736,11 +925,22 @@ def _grow_tree(bins, g, h, cfg: GBDTConfig, axis_name, interpret,
                       for root in (root_g, root_h))
         rows = N if axis_name is None else N * lax.psum(1, axis_name)
         built = jnp.where(first, i32(rows), 0)
+        with jax.named_scope("gbdt.grow.compact"):
+            if packed is None:
+                packed = pack_rows(bins, B)
+            # this tree's g and h side by side, the samples on the
+            # lanes: a slab's are gathered by its row numbers. (In the
+            # second form's spare words a row's descriptor would bring
+            # them, but writing two columns of it costs a tree what
+            # these gathers cost, 5.9 against 5.4 ms on the Bosch table,
+            # and makes the second form a donated result of every step:
+            # my chip run, PR 54, call F.)
+            gh = jnp.stack([g, h])
     tree = (jnp.zeros((n_internal,), i32), jnp.full((n_internal,), B - 1, i32),
             jnp.zeros((n_internal,), i32))
 
     def split(step, carry):
-        row_leaf, leaves, (hist_g, hist_h), tree, built, splits = carry
+        row_leaf, leaves, (hist_g, hist_h), tree, built, splits, read = carry
         heap, level, gain, feat, bin_, dir_ = leaves
         new = (step + 1).astype(i32)
         with jax.named_scope("gbdt.grow.pick"):
@@ -758,11 +958,17 @@ def _grow_tree(bins, g, h, cfg: GBDTConfig, axis_name, interpret,
                          for v, t in zip((f, b, d), tree))
             in_leaf = go & (row_leaf == s)
             right = in_leaf & (routed > 0)
-            n_in, n_right = psum(jnp.stack([in_leaf.sum(dtype=i32),
-                                            right.sum(dtype=i32)]))
+            mine = jnp.stack([in_leaf.sum(dtype=i32), right.sum(dtype=i32)])
+            n_in, n_right = psum(mine)
             small_right = n_right < n_in - n_right      # ties: the left
-            ids = jnp.where(in_leaf & (right == small_right), i32(0), i32(1))
-        small_g, small_h = reduced_histogram(ids)
+            with jax.named_scope("gbdt.grow.compact"):
+                # this shard's rows of the built child, and their slabs
+                counts = _ranks(in_leaf & (right == small_right))
+                count = jnp.where(small_right, mine[1], mine[0] - mine[1])
+                slab_rows = psum(-(-count // _SLAB_ROWS) * _SLAB_ROWS)
+        # THE histogram allreduce, after the loop: the shards' trip
+        # counts differ
+        small_g, small_h = (psum(a) for a in slab_histogram(counts, count))
         with jax.named_scope("gbdt.grow.book"):
             parent_g, parent_h = hist_g[s], hist_h[s]
             other_g = parent_g - small_g
@@ -807,10 +1013,14 @@ def _grow_tree(bins, g, h, cfg: GBDTConfig, axis_name, interpret,
             built = jnp.where(slot == new, jnp.minimum(n_right,
                                                        n_in - n_right), built)
             splits = splits + go.astype(i32)
-        return row_leaf, leaves, hists, tree, built, splits
+            read = jnp.where(slot == new, slab_rows, read)
+        return row_leaf, leaves, hists, tree, built, splits, read
 
-    row_leaf, leaves, _, tree, built, splits = lax.fori_loop(
-        0, L - 1, split, (row_leaf, leaves, hists, tree, built, i32(0)))
+    # (the root's pass read the rows it built from: ``read`` starts as
+    # ``built`` does)
+    row_leaf, leaves, _, tree, built, splits, read = lax.fori_loop(
+        0, L - 1, split,
+        (row_leaf, leaves, hists, tree, built, i32(0), built))
 
     # leaf values from (all-reduced) leaf G/H, as _build_tree ends: on
     # each row's leaf at depth ``depth``
@@ -826,7 +1036,7 @@ def _grow_tree(bins, g, h, cfg: GBDTConfig, axis_name, interpret,
         leaf_val = -leaf_g / (leaf_h + cfg.reg_lambda)
         delta = cfg.learning_rate * _onehot_select(leaf_val, node_ids,
                                                    n_leaves)
-    return delta, (*tree, leaf_val), (built, splits)
+    return delta, (*tree, leaf_val), (built, splits, read)
 
 
 def _sampling_masks(rng_key, cfg: GBDTConfig, N: int, axis_name):
@@ -869,7 +1079,8 @@ def train_tree_shard(bins, y, preds, cfg: GBDTConfig, axis_name=None,
 
 
 def _train_tree_round(bins, y, preds, cfg: GBDTConfig, axis_name=None,
-                      weights=None, interpret=None, rng_key=None):
+                      weights=None, interpret=None, rng_key=None,
+                      packed=None):
     """One boosting round on this shard's samples. Returns
     (new_preds, tree, grown).
 
@@ -877,8 +1088,10 @@ def _train_tree_round(bins, y, preds, cfg: GBDTConfig, axis_name=None,
     or ``_grow_tree`` ("loss"), which everything below reaches alike.
     ``grown`` is what the grower counted: nothing, ``()``, for a
     level-wise tree, whose work follows from its shape; ``(built
-    [max_leaves], splits)`` for a leaf-wise one (``_grow_tree``), with a
-    leading class axis under softmax.
+    [max_leaves], splits, read [max_leaves])`` for a leaf-wise one
+    (``_grow_tree``), with a leading class axis under softmax.
+    ``packed`` is the leaf-wise grower's second form of the table
+    (``pack_rows``; None for a level-wise tree, which has none).
 
     ``weights`` ([N] f32, default all-ones) scales each sample's
     gradient/hessian contribution — the driver uses weight 0 to neutralize
@@ -901,7 +1114,7 @@ def _train_tree_round(bins, y, preds, cfg: GBDTConfig, axis_name=None,
         weights = (sample_scale if weights is None
                    else weights * sample_scale)
     if cfg.grow_policy == "loss":
-        grow = _grow_tree
+        grow = partial(_grow_tree, packed=packed)
     else:
         def grow(*args):
             return (*_build_tree(*args), ())
@@ -1149,8 +1362,12 @@ class GBDTTrainer(DataParallelTrainer):
         self._stacked_trees = None
         self.eval_history_: list[float] = []
         # the last job's leaf-wise work (grow_policy="loss"): ``splits``
-        # and ``rows_built``, the rows whose histogram was built from rows
+        # and ``rows_built``, the rows whose histogram was built from
+        # rows, and beside them the rows the passes read to build them
+        # (the table's for a root, the slabs' for a split)
         self.grow_stats_: dict[str, int] = {}
+        self.grow_rows_read_ = 0
+        self._pack = None      # the leaf-wise job's packer (_build_pack)
         self.binner_ = None    # fitted by train_raw; rides save_model
 
     def _build_step(self):
@@ -1163,18 +1380,24 @@ class GBDTTrainer(DataParallelTrainer):
 
         sampling = cfg.subsample < 1.0 or cfg.colsample < 1.0
 
+        leafwise = cfg.grow_policy == "loss"
+
+        # a leaf-wise step takes the table's second form (``_build_pack``)
+        # after the key and only reads it; a level-wise step is the
+        # program it was
         @partial(jax.shard_map, mesh=self.mesh,
-                 in_specs=(spec, spec, spec, spec, P()),
+                 in_specs=(spec, spec, spec, spec, P()) + (spec,) * leafwise,
                  out_specs=(spec, P(None), P()))
-        def step(bins, y, preds, weights, key_data):
+        def step(bins, y, preds, weights, key_data, *packed):
             rng_key = (jax.random.wrap_key_data(key_data)
                        if sampling else None)
             new_preds, tree, grown = _train_tree_round(
                 bins[0], y[0], preds[0], cfg, axes, weights=weights[0],
-                interpret=interpret, rng_key=rng_key)
+                interpret=interpret, rng_key=rng_key,
+                packed=packed[0] if leafwise else None)
             return new_preds[None], tree, grown
 
-        if cfg.grow_policy == "loss":
+        if leafwise:
             # every pass of a leaf-wise tree builds one node
             levels = [1]
             grid = {"grow_policy": "loss", "max_leaves": cfg.max_leaves}
@@ -1198,6 +1421,21 @@ class GBDTTrainer(DataParallelTrainer):
                             str(hist_radix(n, cfg.n_bins)) for n in levels))
         with spans.span("mp4j.step.build", **grid):
             return jax.jit(step)
+
+    def _build_pack(self):
+        """The program that makes the table's second form on the mesh,
+        a shard from its own rows (``pack_rows``): what a leaf-wise
+        job's staging runs once the table is placed. The cells never
+        cross the link a second time."""
+        cfg, spec = self.cfg, P(self.axes)
+
+        @partial(jax.shard_map, mesh=self.mesh, in_specs=spec,
+                 out_specs=spec)
+        def pack(bins):
+            return pack_rows(bins[0], cfg.n_bins)
+
+        with spans.span("mp4j.step.build", key="gbdt_grow_pack"):
+            return jax.jit(pack)
 
     def shard_data(self, bins: np.ndarray, y: np.ndarray,
                    sample_weight: np.ndarray | None = None):
@@ -1291,6 +1529,13 @@ class GBDTTrainer(DataParallelTrainer):
         job, self._jobs = self._jobs, self._jobs + 1
         with spans.span("mp4j.gbdt.stage", job=job):
             dbins, dy, dpreds, dw, va = stage(job)
+            # a leaf-wise job's second form of the table, made on the
+            # mesh from the first and read by its steps
+            packed = ()
+            if self.cfg.grow_policy == "loss":
+                if self._pack is None:
+                    self._pack = self._build_pack()
+                packed = (self._pack(dbins),)
         va_margins = None
         stopper = EarlyStopper(early_stopping_rounds)
         self.eval_history_ = stopper.history
@@ -1302,7 +1547,8 @@ class GBDTTrainer(DataParallelTrainer):
                        else self.cfg.n_trees):
             with spans.span("mp4j.gbdt.dispatch", job=job, tree=i):
                 kd = jax.random.key_data(jax.random.fold_in(base_key, i))
-                dpreds, tree, counts = self._step(dbins, dy, dpreds, dw, kd)
+                dpreds, tree, counts = self._step(dbins, dy, dpreds, dw, kd,
+                                                  *packed)
             trees.append(tree)
             grown.append(counts)
             if va is not None:
@@ -1319,14 +1565,16 @@ class GBDTTrainer(DataParallelTrainer):
             # the counts of the trees the job grew (all of them, also
             # where early stopping keeps fewer) come with the margins:
             # the device has finished, so no wait is theirs
-            self.grow_stats_ = {}
+            self.grow_stats_, self.grow_rows_read_ = {}, 0
             if self.cfg.grow_policy == "loss":
-                built, splits = (np.stack(a)
-                                 for a in zip(*jax.device_get(grown)))
+                built, splits, read = (np.stack(a)
+                                       for a in zip(*jax.device_get(grown)))
                 self.grow_stats_ = {
                     "splits": int(splits.sum(dtype=np.int64)),
                     "rows_built": int(built.sum(dtype=np.int64))}
-                fetched.args.update(self.grow_stats_)
+                self.grow_rows_read_ = int(read.sum(dtype=np.int64))
+                fetched.args.update(self.grow_stats_,
+                                    rows_read=self.grow_rows_read_)
         if self.cfg.loss == "softmax":
             return trees, preds.reshape(-1, self.cfg.n_classes)
         return trees, preds.reshape(-1)

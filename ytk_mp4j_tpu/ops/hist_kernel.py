@@ -137,10 +137,18 @@ kernel; the second construction was not kept. On the row-major kernel that PR 25
 replaced (round-2 pricing on v5e, B=256, N=1M), not tried again: a
 bf16 arithmetic one-hot (relu(1 - |b - i|), exact for integers <= 256)
 was 9% faster standalone and ~20% slower in the fused train step. Not
-tried: the deepest level (sort or compact the samples by node so that
-the operand is dense: a 4-row operand at every level is 24 units a
-tree); int8 one-hots (twice the MXU rate, but A would need three or
-four int8 parts).
+tried on a level-wise tree: the deepest level (sort or compact the
+samples by node so that the operand is dense: a 4-row operand at every
+level is 24 units a tree). What compacting costs is priced since PR 54,
+where the leaf-wise grower does it for one node a split
+(``models/gbdt.py: _grow_tree``; PERF.md section 6, PR 54: the rows
+found from prefix counts, gathered from a second form of the table in
+which a row is one descriptor, unpacked and handed to this kernel as it
+is): a level's worth of rows pays the gather, the unpacking and this
+kernel's one-node pass on top of the pass it saves, so it is to be
+reckoned against the deepest level's 39.5 and 144.2 ms a tree and not
+against nothing. Not tried: int8 one-hots (twice the MXU rate, but A
+would need three or four int8 parts).
 
 Constraints (checked by ``pallas_hist_supported``): B must be
 lane-aligned (a multiple of 128) for the compiled path and one
