@@ -180,6 +180,12 @@ class Adapter:
         detail["bulk_sampled"] = bool(same)
         detail["bulk_sample_size"] = int(idx.size)
         detail["nonzero"] = bool(want.any())
+        detail["compared"] = {
+            "hist_messages_off": [
+                sum(not detail[f"hist_level_{i}"]
+                    for i in range(len(shapes))), 0],
+            "bulk_sample_same": [int(same), 1],
+            "bulk_sample_nonzero": [int(detail["nonzero"]), 1]}
         return bool(ok and same and want.any()), detail
 
 

@@ -187,6 +187,21 @@ class Adapter:
             "losses_finite": bool(all(np.isfinite(v).all()
                                       for v in self.losses)),
         }
+
+        def excess(got, want, atol):
+            """Largest error as a share of what ``allclose`` allows."""
+            want = np.asarray(want, np.float64)
+            return float(np.max(np.abs(np.asarray(got, np.float64) - want)
+                                / (atol + RTOL * np.abs(want)), initial=0.0))
+
+        detail["compared"] = {
+            "loss_err_over_allowed": [excess(loss, want_loss, 0.0), 1.0],
+            "row_err_over_allowed": [excess(after_rows, want_rows, ATOL),
+                                     1.0],
+            "w_err_over_allowed": [excess(after_w, want_w, ATOL), 1.0],
+            "w0_err_over_allowed": [excess(after_w0, want_w0, ATOL), 1.0],
+            "losses_finite": [int(detail["losses_finite"]), 1],
+            "steps_at_least": [len(self.losses), 1]}
         ok = (np.isclose(loss, want_loss, rtol=RTOL, atol=0)
               and np.allclose(after_rows, want_rows, rtol=RTOL, atol=ATOL)
               and np.allclose(after_w, want_w, rtol=RTOL, atol=ATOL)

@@ -287,6 +287,17 @@ class Adapter:
             "losses_finite": bool(all(np.isfinite(v).all()
                                       for v in self.losses)),
         }
+        detail["compared"] = {
+            "same_sets": [int(same_sets), 1],
+            "loss_err_over_allowed": [
+                _excess(got["loss"], want_loss, LOSS_RTOL, 0.0), 1.0],
+            **{f"{name}_err_over_allowed": [v, 1.0]
+               for name, v in excess.items()},
+            "quiet_same": [int(bool(got["quiet_same"])), 1],
+            "quiet_acc_fresh": [int(bool(got["quiet_acc_fresh"])), 1],
+            "absent_w_same": [int(bool(got["absent_w_same"])), 1],
+            "losses_finite": [int(detail["losses_finite"]), 1],
+            "steps_at_least": [len(self.losses), 1]}
         ok = (same_sets and np.isclose(got["loss"], want_loss,
                                        rtol=LOSS_RTOL, atol=0)
               and all(v <= 1.0 for v in excess.values())

@@ -263,6 +263,7 @@ class Adapter:
                   "margin_err_bound": reference.MARGIN_REL_ERR,
                   "rows_checked": int(sample.size)}
         if not shape_ok:
+            detail["compared"] = {"probs_shape_ok": [0, 1]}
             return False, detail
         inside = bool(np.isfinite(probs).all() and (probs > 0).all()
                       and (probs < 1).all())
@@ -278,5 +279,9 @@ class Adapter:
             margin_mean=float(want.mean()), margin_std=float(want.std()),
             prob_min=float(probs.min()), prob_max=float(probs.max()),
             all_inside_0_1=inside,
-            features_fetched=int(w.size))
+            features_fetched=int(w.size),
+            compared={
+                "probs_shape_ok": [1, 1],
+                "all_inside_0_1": [int(inside), 1],
+                "margin_err_over_terms": [err, reference.MARGIN_REL_ERR]})
         return bool(inside and err <= reference.MARGIN_REL_ERR), detail

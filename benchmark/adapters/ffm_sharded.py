@@ -341,6 +341,13 @@ class Adapter:
             "losses_finite": bool(all(np.isfinite(v).all()
                                       for v in self.losses)),
         }
+        detail["compared"] = {
+            **{f"{name}_err_over_allowed": [v, 1.0]
+               for name, v in excess.items()},
+            "quiet_same": [int(bool(got["quiet_same"])), 1],
+            "absent_w_same": [int(bool(got["absent_w_same"])), 1],
+            "losses_finite": [int(detail["losses_finite"]), 1],
+            "steps_at_least": [len(self.losses), 1]}
         ok = (all(v <= 1.0 for v in excess.values())
               and got["quiet_same"] and got["absent_w_same"]
               and detail["losses_finite"] and len(self.losses) > 0)

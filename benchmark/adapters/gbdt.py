@@ -19,6 +19,7 @@ import jax
 
 from benchmark import traffic as traffic_gen
 from benchmark.reference import gbdt as reference
+from benchmark.reference import gbdt_rows_needed as needed
 from ytk_mp4j_tpu.models.gbdt import GBDTConfig, GBDTTrainer
 
 CHECK_ROWS = 65_536         # rows the plain router is run on
@@ -30,6 +31,8 @@ class Adapter:
         self.config, self.traffic, self.seed = config, traffic, seed
         self.devices, self.spans = devices, spans
         self.first_job = None       # (trees, margins) kept for the check
+        self.sliced = False         # a traced run: its readers want counts
+        self.jobs_done = 0          # in the window or slice
 
     def setup(self):
         c = self.config
@@ -75,6 +78,7 @@ class Adapter:
                 break
             job_secs.append(time.perf_counter() - s)
         elapsed = time.perf_counter() - t0
+        self.jobs_done = attempted - failed
         return {"attempted": attempted, "failed": failed,
                 "metrics": {"trees_per_s": trees / elapsed},
                 "counters": {"jobs": attempted - failed, "trees": trees,
@@ -89,13 +93,42 @@ class Adapter:
 
     def slice(self) -> dict:
         """The traced slice: one whole job."""
+        self.sliced = True
         return self._jobs(lambda done, elapsed: done < 1)
+
+    def _rows_needed(self, trees) -> list[int]:
+        """Rows each tree's histograms had to be built from
+        (``reference/gbdt_rows_needed.py``): the host's table routed
+        through the returned tree, all rows for the root and the smaller
+        child's under every node whose children may split."""
+        c = self.config
+        return needed.rows_needed_a_tree(
+            trees, needed.binned(self.bins, bool(c.get("missing_bin"))),
+            self.bins.shape[0], c["depth"])
+
+    def _needed_work(self, trees) -> dict:
+        """What ``check`` adds to its detail for the readers of the
+        kernel's roofline and the step's share of the peak: the needed
+        rows of the first timed job a tree, and of all the slice's jobs
+        in all (every job trains the same trees from the same table and
+        label, so the first job's count times the jobs is theirs).
+        Counted outside the slice, and only in a traced run: nothing
+        reads it in an untraced one, and 16 trees on 11M rows take 10 s
+        to route."""
+        if not self.sliced:
+            return {}
+        per_tree = self._rows_needed(trees)
+        return {"rows_needed_a_tree": per_tree,
+                "counters": {
+                    "hist_rows_needed": int(sum(per_tree)) * self.jobs_done}}
 
     def check(self):
         """Against ``reference/gbdt.py``: the first tree's root split is
         the best (or ties the best) candidate of float64 bincount
         histograms; a plain router over the returned trees reproduces the
-        returned margins on a seeded sample; logloss fell below ln 2."""
+        returned margins on a seeded sample; logloss fell below ln 2.
+        ``detail["counters"]`` (``_needed_work``) joins the counters the
+        per-layer readers see."""
         if self.first_job is None:
             return False, {"error": "no job finished"}
         trees, margins = self.first_job
@@ -116,7 +149,11 @@ class Adapter:
                   "best_candidate": [int(v) for v in np.unravel_index(
                       np.argmax(gain), gain.shape)],
                   "margin_max_abs_err": margin_err, "logloss": loss,
-                  "trees_checked": len(trees)}
+                  "trees_checked": len(trees), **self._needed_work(trees),
+                  "compared": {
+                      "root_ok": [int(root_ok), 1],
+                      "margin_max_abs_err": [margin_err, MARGIN_ATOL],
+                      "logloss": [loss, float(np.log(2.0))]}}
         ok = (root_ok and margin_err <= MARGIN_ATOL
               and np.isfinite(loss) and loss < np.log(2.0))
         return bool(ok), detail

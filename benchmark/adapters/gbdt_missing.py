@@ -124,7 +124,17 @@ class Adapter(dense.Adapter):
                   "margin_max_abs_err": margin_err, "logloss": loss,
                   "missing_right_nodes": right,
                   "missing_share": float(hist_h[0, 0] / hist_h[0].sum()),
-                  "trees_checked": len(trees)}
+                  "trees_checked": len(trees), **self._needed_work(trees),
+                  "compared": {
+                      "root_ok": [int(root_ok), 1],
+                      "second_tree_bad_nodes": [
+                          len(later.get("second_tree_bad_nodes", [])), 0],
+                      "hist_prefix_sum_err": [
+                          later.get("hist_prefix_sum_err", 0.0),
+                          reference.HIST_REL_ERR],
+                      "margin_max_abs_err": [margin_err, dense.MARGIN_ATOL],
+                      "logloss": [loss, float(np.log(2.0))],
+                      "missing_right_nodes_at_least": [right, 1]}}
         ok = (root_ok and not later.get("second_tree_bad_nodes")
               and later.get("hist_prefix_sum_err", 0.0)
               <= reference.HIST_REL_ERR

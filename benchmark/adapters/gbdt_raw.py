@@ -39,6 +39,7 @@ from benchmark import arith_raw, raw_table
 from benchmark.adapters import gbdt as dense
 from benchmark.adapters import gbdt_missing as missing
 from benchmark.reference import gbdt_raw as reference
+from benchmark.reference import gbdt_rows_needed as needed
 from ytk_mp4j_tpu.models.gbdt import GBDTConfig, GBDTTrainer
 from ytk_mp4j_tpu.obs import spans as program_spans
 
@@ -245,6 +246,12 @@ class Adapter(missing.Adapter):
         return crossed == want, {"job_put_sharded_bytes": int(crossed),
                                  "job_put_sharded_bytes_expected": want}
 
+    def _rows_needed(self, trees) -> list[int]:
+        """The host's table is floats: ``check`` routes it through the
+        trees under the reference's own edges before it lets the floats
+        go (no binned table of the program's enters the count)."""
+        return self.rows_needed
+
     def check(self):
         """(i) to (iv) of the module docstring; (iii) is
         ``gbdt_missing``'s check (root, every split of the second tree,
@@ -265,6 +272,11 @@ class Adapter(missing.Adapter):
         link_ok, link = self._link_check()
         edges_ok, edges, want_edges = self._edges_check()
         secs = {"edges": lap()}
+        if self.sliced:             # ``_needed_work``: a traced run's
+            self.rows_needed = needed.rows_needed_a_tree(
+                self.first_job[0], needed.raw(self.X, want_edges),
+                len(self.X), self.config["depth"])
+            secs["rows_needed"] = lap()
         self.bins, largest = self._device_bins()
         secs["device_bins"] = lap()
         bins_ok, bins = self._bins_check(self.bins, largest, want_edges)
@@ -272,6 +284,16 @@ class Adapter(missing.Adapter):
         del self.X                  # the trees are checked on the bins
         trees_ok, trees_detail = super().check()
         secs["trees"] = lap()
-        return (link_ok and edges_ok and bins_ok and trees_ok,
-                {**edges, **bins, **trees_detail, **link,
-                 "check_secs": secs})
+        own = {
+            "edges_outside_their_order_statistics": 0,
+            "edges_max_rel_err": EDGE_REL, "edges_exact_mismatches": 0,
+            "bins_off_own_edges": 0, "bins_off_reference_unexplained": 0,
+            "bin0_is_not_nan_cells": 0,
+            "bins_max": self.config["n_bins"] - 1,
+            "job_put_sharded_bytes": link["job_put_sharded_bytes_expected"]}
+        detail = {**edges, **bins, **trees_detail, **link,
+                  "check_secs": secs}
+        detail["compared"] = {
+            **{name: [detail[name], limit] for name, limit in own.items()},
+            **trees_detail.get("compared", {})}
+        return link_ok and edges_ok and bins_ok and trees_ok, detail

@@ -158,6 +158,11 @@ class Adapter:
                   "margins_shape": list(margins.shape),
                   "rows_checked": int(sample.size),
                   "trees_checked": len(self.trees)}
-        ok = (shape_ok and np.isfinite(margins).all()
+        finite = bool(np.isfinite(margins).all())
+        detail["compared"] = {
+            "margins_shape_ok": [int(shape_ok), 1],
+            "margins_finite": [int(finite), 1],
+            "margin_err_over_terms": [err, reference.MARGIN_REL_ERR]}
+        ok = (shape_ok and finite
               and err <= reference.MARGIN_REL_ERR)
         return bool(ok), detail

@@ -233,6 +233,14 @@ class Adapter:
             "job_put_sharded_spans": len(crossed),
             "step_builds_in_window": int(self.builds_in_window),
             "check_secs": secs}
+        detail["compared"] = {
+            "margins_shape_ok": [int(shape_ok), 1],
+            "margins_finite": [int(detail["margins_finite"]), 1],
+            "margin_err_over_terms": [err, reference.MARGIN_REL_ERR],
+            "rows_off_predict_of_reference_bins": [off, 0],
+            "job_put_sharded_bytes": [int(sum(crossed)), floats],
+            "job_put_sharded_spans": [len(crossed), 1],
+            "step_builds_in_window": [int(self.builds_in_window), 0]}
         ok = (shape_ok and detail["margins_finite"]
               and err <= reference.MARGIN_REL_ERR and off == 0
               and crossed == [floats] and not self.builds_in_window)

@@ -1,9 +1,9 @@
 """The benchmark's arithmetic: peaks, and the operations and bytes a call
 needs, computed from shapes. Kept here so that no PR that claims a gain
-can change the yardstick. ``gbdt_hist_mxu_flops`` and
-``gbdt_hist_scanned_bytes`` are copies of ``bench.gbdt_hist_mxu_flops``
-and ``bench.scanned_bytes`` (PERF.md, Open questions: delete the
-originals)."""
+can change the yardstick. What a GBDT tree's histograms cost is in
+``arith_grow.py``, by the rows a tree needs and not by the passes of one
+implementation (until PR 57 ``gbdt_hist_mxu_flops`` here counted the
+level-wise kernel's own operand, 32 N rows a depth-6 tree)."""
 
 from __future__ import annotations
 
@@ -38,19 +38,6 @@ def hist_message_bytes(depth: int, n_features: int, n_bins: int) -> list[int]:
     """Bytes of each level's histogram allreduce: gradient and hessian
     sums, f32, per (node, feature, bin)."""
     return [n * n_features * n_bins * 2 * 4 for n in hist_level_nodes(depth)]
-
-
-def gbdt_hist_mxu_flops(n: int, f: int, b: int, depth: int) -> float:
-    """MXU flops of the fused histogram matmuls per tree: per level the
-    kernel contracts the [tile, 4*n_nodes] hi/lo-split g/h operand with
-    the per-feature [tile, B] one-hot: 2 * N * 4*n_nodes * B * F."""
-    return 2.0 * n * 4 * sum(hist_level_nodes(depth)) * b * f
-
-
-def gbdt_hist_scanned_bytes(n: int, f: int, depth: int) -> float:
-    """Least bytes the histogram passes of one tree must read: per level
-    every sample's F bin bytes (256 bins fit a byte) and its g and h."""
-    return float(depth * n * (f + 8))
 
 
 def roofline_seconds(flops: float, nbytes: float, peaks: dict) -> tuple[float, str]:

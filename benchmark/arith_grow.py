@@ -1,19 +1,22 @@
-"""The arithmetic of a tree grown leaf by leaf (``grow_policy="loss"``),
-kept with the benchmark like ``arith.py``: what the histograms of the
-nodes that *had* to be built from rows cost, whatever the program read
-to build them. The root's is built from all rows and, of every split's
-two children, the smaller one's from its own rows (its sibling's is the
-parent's less it); ``rows_built`` is their sum, counted by the program
-(``GBDTTrainer.grow_stats_``) and held to the reference's count by the
-cell's check.
+"""What a tree's histograms cost by the rows they *had* to be built
+from, whatever the program read to build them, kept with the benchmark
+like ``arith.py``. The root's histogram is built from all rows and, of
+every split's two children, the smaller one's from its own rows (its
+sibling's is the parent's less it); the needed rows are their sum,
+counted by the benchmark from the returned trees and the host's table
+(``reference/gbdt_rows_needed.py``) for a level-wise tree and a grown
+one alike. The leaf-wise trainer's own counter
+(``GBDTTrainer.grow_stats_["rows_built"]``) is held to the same count by
+its cell's check.
 
-The flops are the histogram kernel's own one-hot formulation, the one
-``arith.gbdt_hist_mxu_flops`` counts for a level-wise tree: a built row
-contracts its 4 operand rows (g and h, each a bf16 hi/lo pair) with the
-[n_bins] one-hot of every feature. A program that reads every row of the
-table for every node (70 passes a tree) does those flops for rows that
-belong to no node it builds, and they do not count here; one that reads
-only a node's rows is read against the same numbers."""
+The flops are the histogram kernel's own one-hot formulation: a needed
+row contracts its 4 operand rows (g and h, each a bf16 hi/lo pair) with
+the [n_bins] one-hot of every feature. A program that sends every row of
+the table through the kernel at every level (32 N rows' worth of
+one-node work a depth-6 tree), or reads the whole table for every node
+of a grown tree, does those flops for rows that belong to no node it has
+to build, and they do not count here; one that reads only a node's rows
+is read against the same numbers."""
 
 from __future__ import annotations
 

@@ -21,6 +21,14 @@ Rows are drawn in chunks of ``CHUNK_ROWS``, chunk k from
 ``default_rng([seed, k])``, on a few threads (numpy's generators and
 ufuncs release the GIL): the table depends on the seed alone, not on the
 number of threads.
+
+``label_draw`` gives the same table another label: the same score of the
+same bins with its noise drawn anew (chunk k of draw d from
+``default_rng([seed, k, d])``), cut at its own median. Half of the rows
+score within the noise of the cut, so a draw moves about three labels in
+eight; a traffic mix that hands successive jobs successive draws
+(``traffic/train-relabelled.json``) averages, inside one run, what a
+tree's near-ties happen to do under one draw.
 """
 
 from __future__ import annotations
@@ -87,3 +95,23 @@ def missing_binned_table(seed: int, rows: int, n_features: int, n_bins: int,
         list(pool.map(fill, range(-(-rows // CHUNK_ROWS))))
     y = (score > np.median(score)).astype(np.float32)
     return bins, y
+
+
+def label_draw(bins: np.ndarray, n_bins: int, seed: int, draw: int):
+    """y f32 [rows] in {0, 1}, half of each: the label of
+    ``missing_binned_table``'s ``bins`` with the score's noise drawn anew
+    (``draw`` >= 1; the table's own label is draw 0 and is not remade
+    here: its noise comes after the cells' in the chunk's stream)."""
+    if draw < 1:
+        raise ValueError("draw 0 is the label missing_binned_table returns")
+    rows = bins.shape[0]
+    score = np.empty(rows, np.float32)
+
+    def fill(k: int) -> None:
+        lo, hi = k * CHUNK_ROWS, min(rows, (k + 1) * CHUNK_ROWS)
+        score[lo:hi] = _score(bins[lo:hi], n_bins,
+                              np.random.default_rng([seed, k, draw]))
+
+    with ThreadPoolExecutor(_threads()) as pool:
+        list(pool.map(fill, range(-(-rows // CHUNK_ROWS))))
+    return (score > np.median(score)).astype(np.float32)

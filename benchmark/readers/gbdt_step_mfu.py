@@ -1,25 +1,26 @@
 """The whole GBDT training job's share of the chip's bf16 peak, in
-percent: the flops of the histogram matmuls of the traced trees
-(``arith.gbdt_hist_mxu_flops``: the kernel's own one-hot formulation, the
-flops ``hist_kernel_roofline`` counts; everything else a tree computes is
-a thousandth of it) over the peak times the wall time of the traced
-slice, so staging, a raw table's sketch and transform, routing, the
-split search, the gaps between launches and the fetch all count as time.
+percent: the one-hot flops of the histograms the traced jobs' trees
+needed (``hist_rows_needed``, the benchmark's own count over all the
+slice's jobs, a chip's share of it, at ``arith_grow.grow_hist_mxu_flops``
+a row: the flops ``hist_kernel_roofline`` counts; everything else a tree
+computes is a thousandth of it) over the peak times the wall time of the
+traced slice, so staging, a raw table's sketch and transform, the
+kernel's passes over rows no node needed, routing, the split search, the
+gaps between launches and the fetch all count as time.
 
 It cannot pass the kernel's share of its roofline. Unlike that share it
-needs no kernel in the trace, only the slice and the trees it finished:
-a program that builds its histograms another way is read against the
-same flops."""
+needs no kernel in the trace, only the slice and the rows its trees
+needed: a program that builds its histograms another way is read against
+the same flops."""
 
-from benchmark import arith, step_mfu
+from benchmark import arith_grow, step_mfu
 
 
 def read(spec: dict, run: dict):
-    trees = run["counters"].get("trees")
-    if not trees:
+    rows = run["counters"].get("hist_rows_needed")
+    if not rows:
         return None
     c = run["config"]
-    rows = -(-c["rows"] // run["chips"])
-    flops = trees * arith.gbdt_hist_mxu_flops(rows, c["n_features"],
-                                              c["n_bins"], c["depth"])
+    flops = arith_grow.grow_hist_mxu_flops(rows / run["chips"],
+                                           c["n_features"], c["n_bins"])
     return step_mfu.percent_of_peak(flops, run)

@@ -23,9 +23,11 @@ set-up together, and nothing in this repository can change it. Both are on
 the ``facts:`` line.
 
 Outputs are checked against the plain references outside the window. The
-last line of stdout is one JSON object with exactly the keys ``correct``,
+last line of stdout is one JSON object with the keys ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and ``breakdown`` in a
-traced run); everything else goes on earlier lines or under
+traced run) and ``compared`` last: each number the adapter's check
+compared as ``name: [number, limit]``, which are also the last lines of
+standard error; everything else goes on earlier lines or under
 ``benchmark/out/``.
 """
 
@@ -40,6 +42,7 @@ import collections              # noqa: E402
 import contextlib               # noqa: E402
 import glob                     # noqa: E402
 import json                     # noqa: E402
+import math                     # noqa: E402
 import os                       # noqa: E402
 import shutil                   # noqa: E402
 import sys                      # noqa: E402
@@ -183,6 +186,12 @@ def main(argv=None, root: str = ROOT) -> int:
         compile_s = clock.secs
         memory_after_window = machine.memory_stats(dev)
     check_ok, check_detail = adapter.check()
+    # what a check counts for the readers stands beside the window's own
+    # counters and may not take the name of one
+    taken = set(check_detail.get("counters", {})) & set(result["counters"])
+    if taken:
+        raise ValueError(f"the check's counters {sorted(taken)} are "
+                         f"already the window's")
     print("window: " + json.dumps(
         {"counters": result["counters"], "log": result.get("log"),
          "compiles_in_window": compiles_in_window,
@@ -207,7 +216,10 @@ def main(argv=None, root: str = ROOT) -> int:
         device["busy_s"] = busy / 1e9
         device["window_s"] = (t1 - t0) / 1e9
         run = {"trace": trace, "window_ns": (t0, t1), "spans": spans.seconds,
+               # what the check counted for the readers (the rows a
+               # job's trees needed) stands beside the window's counts
                "counters": {**result["counters"],
+                            **check_detail.get("counters", {}),
                             "compile_s": compile_s,
                             "compiles_in_window": compiles_in_window,
                             "memory_peak_bytes": device["memory_peak_bytes"]},
@@ -227,7 +239,20 @@ def main(argv=None, root: str = ROOT) -> int:
                                   "unit": m["unit"]}
     line["metrics"] = metrics
     line["device"] = device
+    # each number the check compared beside its limit, as the adapter
+    # names them (a check that could compare nothing says why under
+    # ``error``): last in the line and the last lines on standard error,
+    # which is what is kept of a run that is not correct
+    compared = check_detail.get(
+        "compared", {"error": [check_detail.get("error"), None]})
+    # strict JSON has no word for an infinite error: say it in letters
+    line["compared"] = compared = {
+        name: [v if not isinstance(v, float) or math.isfinite(v) else str(v)
+               for v in pair] for name, pair in compared.items()}
     print(json.dumps(line), flush=True)
+    for name, (number, limit) in compared.items():
+        print(f"compared: {name} {number} limit {limit}", file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

@@ -4,7 +4,7 @@ import math
 
 import pytest
 
-from benchmark import arith
+from benchmark import arith, arith_grow
 
 
 def test_peaks_of_the_v5e_and_no_default():
@@ -25,18 +25,24 @@ def test_histogram_messages_of_a_depth_6_tree():
     assert sum(sizes) == 1_835_008
 
 
-def test_hist_kernel_flops_and_bytes():
-    # 32 node-histograms a tree, 4 operand columns a node (g, h x hi, lo)
-    want = 2.0 * 11_000_000 * 4 * 32 * 256 * 28
-    assert arith.gbdt_hist_mxu_flops(11_000_000, 28, 256, 6) == want
-    assert arith.gbdt_hist_scanned_bytes(11_000_000, 28, 6) == \
-        6 * 11_000_000 * 36
+def test_a_needed_rows_flops_and_bytes():
+    # 4 operand rows a needed row (g, h x hi, lo) against 256 one-hot
+    # columns of each of 28 features; 28 bin bytes and g and h
+    want = 2.0 * 4 * 256 * 28 * 11_000_000
+    assert arith_grow.grow_hist_mxu_flops(11_000_000, 28, 256) == want
+    assert arith_grow.grow_hist_scanned_bytes(11_000_000, 28) == \
+        11_000_000 * 36
     peaks = arith.peaks_for("TPU v5 lite")
     secs, bound = arith.roofline_seconds(
-        want, arith.gbdt_hist_scanned_bytes(11_000_000, 28, 6), peaks)
+        want, arith_grow.grow_hist_scanned_bytes(11_000_000, 28), peaks)
     assert bound == "mxu"
     assert math.isclose(secs, want / 197e12)
+    # a needed row of the two tables at the v5e's peak: 0.291 and 10.06 ns
+    assert math.isclose(secs / 11_000_000, 0.2911e-9, rel_tol=1e-3)
+    assert math.isclose(arith_grow.grow_hist_mxu_flops(1, 968, 256) / 197e12,
+                        10.06e-9, rel_tol=1e-3)
     assert arith.roofline_seconds(1.0, 819e9, peaks) == (1.0, "hbm")
+    assert not hasattr(arith, "gbdt_hist_mxu_flops")
 
 
 def test_ffm_rows_touched_at_criteo_widths():

@@ -68,6 +68,11 @@ RETIRED = {
     "ffmscore_stage_gbps": "score_stage_gbps",
     "ffm_scatter_gather_ms_per_chunk": None,
     "gbdt_collective_ms_per_tree": None,
+    # PR 57: the leaf-wise cell's second name for the kernel's share by
+    # needed rows; and the placer's launches, which no scoring call makes
+    # since PR 52 (null in three cells)
+    "gbdt_grow_hist_roofline": "hist_kernel_roofline",
+    "score_stage_place_ms_per_job": None,
 }
 
 EVERY_CELL = {"peak_hbm_gb", "compile_s", "compiles_in_window"}
@@ -128,6 +133,10 @@ MUST = {
         "ffmscore_roofline", "ffmscore_stage_ms_per_job",
         "ffmscore_dispatch_ms_per_job", "ffmscore_fetch_wait_ms_per_job",
         "ffmscore_enter_s", "ffmscore_step_mfu"},
+    # PR 53's cell, which had no row here until PR 57
+    "gbdt-bosch-968-leafwise.train": GBDT_TRAINING | ROW_CHUNKS | {
+        "hist_kernel_roofline", "gbdt_grow_ms_per_tree",
+        "gbdt_grow_unscoped_ms_per_tree", "gbdt_grow_rows_built_share"},
     "allreduce-4rank.hist-and-bulk": EVERY_CELL | {
         "allreduce_device_idle_share", "collective_scope_us_per_tree",
         "collective_us_per_tree", "collective_ms_bulk",
@@ -221,9 +230,10 @@ def test_the_raw_scoring_cell_reports_what_its_twin_reports():
 
 
 @pytest.mark.parametrize("metric,counters,flops", [
-    # 11M x 28, 256 bins, depth 6: 2 * N * 4 * 32 nodes * B * F a tree
-    ("gbdt_step_mfu", {"trees": 16},
-     16 * 2.0 * 11_000_000 * 4 * 32 * 256 * 28),
+    # 28 columns, 256 bins: 2 * 4 * B * F a needed row, 77M rows over
+    # the slice's 2 jobs (the benchmark's count, not the program's)
+    ("gbdt_step_mfu", {"jobs": 2, "hist_rows_needed": 77_000_000},
+     2.0 * 4 * 256 * 28 * 77_000_000),
     # 1,183,748 x 968 against 64 one-hot rows a tree, 500 trees, 2 jobs
     ("score_step_mfu", {"jobs": 2}, 2 * 2.0 * 1_183_748 * 968 * 64 * 500),
     # 741 pairs of a 4-long dot product and the linear term a row

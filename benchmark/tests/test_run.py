@@ -17,7 +17,9 @@ from conftest import ROOT
 
 CELLS = ["gbdt-higgs-11m.train", "ffm-criteo.stream-zipf",
          "allreduce-4rank.hist-and-bulk"]
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+# ``compared``: each number the check compared beside its limit, last
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+             "compared"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
@@ -36,6 +38,12 @@ def test_untraced_run_reports_the_cells_end_to_end_metrics(
     assert rc == 0
     line = json.loads(lines[-1])
     assert set(line) == LINE_KEYS
+    # every adapter names what its check compared: each number beside
+    # its limit, last in the line
+    assert list(line)[-1] == "compared" and line["compared"]
+    assert all(len(pair) == 2 for pair in line["compared"].values())
+    if workload.startswith("gbdt-"):
+        assert line["compared"]["margin_max_abs_err"][1] == 1e-5
     assert set(line["device"]) == DEVICE_KEYS
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] >= 1

@@ -13,7 +13,9 @@ from benchmark import cells, run
 from conftest import ROOT
 
 CELL = "gbdt-bosch-968.train"
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+# ``compared``: each number the check compared beside its limit, last
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+             "compared"}
 # what the cell must report (it may report more: every name is one
 # quantity's, shared with the cells that have it, since PR 49)
 BOSCH = {"gbdt_hist_ms_per_tree", "hist_kernel_roofline",
@@ -85,6 +87,8 @@ def test_untraced_run(capsys, toy_root):
     assert rc == 0
     line = json.loads(lines[-1])
     assert set(line) == LINE_KEYS
+    assert list(line)[-1] == "compared" and all(
+        len(pair) == 2 for pair in line["compared"].values())
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] >= 1
     assert set(line["metrics"]) == {"trees_per_s", "setup_s"}

@@ -17,7 +17,9 @@ from benchmark.reference import ffm_score as reference
 from conftest import ROOT
 
 CELL = "ffm-criteo-score.file-zipf"
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+# ``compared``: each number the check compared beside its limit, last
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+             "compared"}
 # what ISSUE 36 names; on the CPU the device trace's are left out
 FROM_TRACE = {"rows_device_idle_share",
               "ffmscore_table_gather_ms_per_job",

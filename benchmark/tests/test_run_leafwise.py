@@ -1,11 +1,15 @@
 """The cell ``gbdt-bosch-968-leafwise.train`` end to end at a toy size
 through ``run.main`` itself, on the CPU with the platform check stubbed
 (by hand, like the rest of this directory): the contract's last line,
-``correct`` true with the replay among its checks, the cell's metrics
+``correct`` true with the replay of the first job's first two trees and
+its last and every job's counts and margins on its own label draw among
+its checks, a window that is a whole multiple of the draws, the cell's metrics
 found by name, a program without the policy refused before any table is
 drawn, and the controls that must come out ``correct: false``: a tree
-whose order is not best-first, and histograms without their lo part.
-The lists pin what the cell MUST report, not all it may."""
+whose order is not best-first, histograms without their lo part, and a
+trainer whose count of the rows it built is off in a tree the replay
+does not visit. The lists pin what the cell MUST report, not all it
+may."""
 
 import json
 import os
@@ -18,9 +22,13 @@ from benchmark import cells, run
 from conftest import ROOT
 
 CELL = "gbdt-bosch-968-leafwise.train"
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+# ``compared``: each number the check compared beside its limit, last
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+             "compared"}
 # the shared entries ``gbdt-bosch-968.train`` lists and this cell's
-# readers find something for, and the four of its own
+# readers find something for (since PR 57 the kernel's roofline and the
+# step's share of the peak among them, counted by the rows a tree
+# needs), and the three of its own
 SHARED = {"gbdt_device_idle_share", "peak_hbm_gb", "compile_s",
           "compiles_in_window", "step_builds_in_window",
           "gbdt_stage_ms_per_job", "gbdt_dispatch_ms_per_tree",
@@ -32,14 +40,16 @@ SHARED = {"gbdt_device_idle_share", "peak_hbm_gb", "compile_s",
           "stage_send_ms_per_job", "stage_sends_per_job",
           "stage_link_wait_ms_per_job", "stage_device_wait_ms_per_job",
           "stage_place_ms_per_job", "stage_place_device_ms_per_job",
-          "stage_gbps", "setup_gbdt_table_s", "setup_gbdt_warmup_s"}
+          "stage_gbps", "setup_gbdt_table_s", "setup_gbdt_warmup_s",
+          "hist_kernel_roofline", "gbdt_step_mfu"}
 OWN = {"gbdt_grow_ms_per_tree", "gbdt_grow_unscoped_ms_per_tree",
-       "gbdt_grow_rows_built_share", "gbdt_grow_hist_roofline"}
-# whose arithmetic and scope lists are the level-wise tree's
-NOT_HERE = {"hist_kernel_roofline", "gbdt_step_mfu",
-            "gbdt_hist_level0_ms_per_tree", "gbdt_hist_level5_ms_per_tree",
+       "gbdt_grow_rows_built_share"}
+# whose scope lists are the level-wise tree's
+NOT_HERE = {"gbdt_hist_level0_ms_per_tree", "gbdt_hist_level5_ms_per_tree",
             "hist_kernel_ms_per_tree", "gbdt_unscoped_ms_per_tree"}
-TOY = dict(rows=3000, n_features=200, depth=5, max_leaves=8, n_trees=2)
+RETIRED = {"gbdt_grow_hist_roofline", "score_stage_place_ms_per_job"}
+# four trees: the replay visits 0, 1 and 3 and the count all four
+TOY = dict(rows=3000, n_features=200, depth=5, max_leaves=8, n_trees=4)
 
 
 @pytest.fixture
@@ -60,7 +70,9 @@ def toy_root(tiny_root):
 def _run(capsys, root, trace, seed=5300000017):
     rc = run.main(["--workload", CELL, "--seed", str(seed), "--seconds",
                    "0.5", "--trace", str(trace)], root=root)
-    return rc, capsys.readouterr().out.strip().splitlines()
+    captured = capsys.readouterr()
+    _run.err = captured.err.strip().splitlines()   # the last run's stderr
+    return rc, captured.out.strip().splitlines()
 
 
 def _window(lines) -> dict:
@@ -73,7 +85,7 @@ def test_the_cell_reports_trees_per_s_and_its_own_layer_metrics():
     assert cell.chips == 1 and cell.adapter_name == "gbdt_leafwise"
     assert [m["name"] for m in cell.end_to_end] == ["trees_per_s", "setup_s"]
     names = {m["name"] for m in cell.per_layer}
-    assert SHARED | OWN <= names and not names & NOT_HERE
+    assert SHARED | OWN <= names and not names & (NOT_HERE | RETIRED)
     for m in cell.per_layer:
         assert m["spec"]["name"] == m["name"] and "adapters" not in m["spec"]
         for key in ("layer", "moves", "source"):
@@ -95,7 +107,7 @@ def test_the_cell_reports_trees_per_s_and_its_own_layer_metrics():
                        "deployment", "grow_policy", "max_leaves", "depth",
                        "n_trees", "guarantees", "reduced", "assumed"}
     assert (c["grow_policy"], c["max_leaves"], c["depth"], c["n_trees"]) == (
-        "loss", 70, 7, 2)
+        "loss", 70, 7, 24)
     assert c["architecture"] is None and list(c["reduced"]) == ["n_trees"]
     entry = next(e for e in bench["configs"]
                  if e["name"] == "gbdt-bosch-968-leafwise")
@@ -103,7 +115,9 @@ def test_the_cell_reports_trees_per_s_and_its_own_layer_metrics():
     assert entry is bench["configs"][-1]
     assert bench["workloads"][-1]["name"] == CELL
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 2
-    assert len(bench["workloads"]) == 11 and len(bench["per_layer"]) == 126
+    assert len(bench["workloads"]) == 11 and len(bench["per_layer"]) == 124
+    assert not RETIRED & {m["name"] for m in bench["per_layer"]}
+    assert "24 leaf-wise trees" in bench["workloads"][-1]["why"]
 
 
 def test_the_accepted_cells_report_none_of_this_cells_metrics():
@@ -111,6 +125,7 @@ def test_the_accepted_cells_report_none_of_this_cells_metrics():
                  "gbdt-bosch-968-raw.train-raw-chunks"):
         got = {m["name"] for m in cells.load_cell(ROOT, name).per_layer}
         assert not got & OWN and NOT_HERE <= got | {"hist_kernel_ms_per_tree"}
+        assert {"hist_kernel_roofline", "gbdt_step_mfu"} <= got
 
 
 def test_untraced_run(capsys, toy_root):
@@ -118,19 +133,37 @@ def test_untraced_run(capsys, toy_root):
     assert rc == 0
     line = json.loads(lines[-1])
     assert set(line) == LINE_KEYS
+    assert list(line)[-1] == "compared" and all(
+        len(pair) == 2 for pair in line["compared"].values())
     assert line["correct"] is True and line["failed"] == 0
-    assert line["attempted"] >= 1
+    window = _window(lines)
+    counters = window["counters"]
+    # a job a label draw in turn, and a window a whole multiple of the
+    # six draws however long a job takes (half a second is one job's)
+    jobs = counters["jobs"]
+    assert line["attempted"] == jobs and jobs >= 6 and jobs % 6 == 0
+    assert window["log"]["job_draws"] == [i % 6 for i in range(jobs)]
+    # what was compared, beside its limit: last on standard error too
+    compared = line["compared"]
+    assert compared["grow_rows_built"][0] == compared["grow_rows_built"][1]
+    assert compared["grow_splits"] == [28 * jobs, 28 * jobs]
+    assert compared["jobs_with_counts_off"] == [0, 0]
+    assert compared["hist_prefix_sum_err"][1] == 1.6e-5
+    assert _run.err[-len(compared):] == [
+        f"compared: {name} {number} limit {limit}"
+        for name, (number, limit) in compared.items()]
     assert set(line["metrics"]) == {"trees_per_s", "setup_s"}
     assert line["metrics"]["trees_per_s"]["value"] > 0
-    window = _window(lines)
     assert window["compiles_in_window"] == 0
     check = window["check"]
     assert check["root_ok"] and check["heap_shaped"]
     assert check["root_split"][0] == 199 and check["root_split"][2] == 1
     assert check["missing_right_nodes"] >= 1
-    # the replay: both trees, every split the best of its node, the
-    # order best-first, the budget met, no leaf under the cap
-    assert check["trees_checked"] == 2 and len(check["trees_replayed"]) == 2
+    # the replay, of the first job: its first two trees and its last,
+    # every split the best of its node, the order best-first, the budget
+    # met, no leaf under the cap
+    assert check["trees_checked"] == 4 * jobs
+    assert [t["tree"] for t in check["trees_replayed"]] == [0, 1, 3]
     for tree in check["trees_replayed"]:
         assert tree["leaves"] == 8 and tree["deepest_leaf"] <= 5
         assert tree["bad_nodes"] == [] and tree["replay_broken"] == []
@@ -139,21 +172,43 @@ def test_untraced_run(capsys, toy_root):
         # best-first is not breadth-first here: a node of the fifth
         # level was split while some of the fourth were left whole
         assert max(tree["replay_order"]) > 14
-    # the trainer's counts are the reference's
-    assert check["grow_stats_ok"] and check["grow_stats"]["splits"] == 14
-    assert check["grow_stats"]["rows_built"] == sum(
-        t["rows_built"] for t in check["trees_replayed"])
+    # EVERY job is held to its own draw's label: the trainer's counts
+    # are the reference's over all four trees, the margins those of the
+    # returned trees on every row, logloss under ln 2
+    assert check["grow_stats_ok"] and len(check["jobs_checked"]) == jobs
+    for i, job in enumerate(check["jobs_checked"]):
+        assert job["draw"] == i % 6 and job["counts_ok"]
+        assert job["grow_stats"]["splits"] == job["splits"] == 28
+        built = job["rows_built_a_tree"]
+        assert len(built) == 4 and all(3000 < n < 3000 * 4.5 for n in built)
+        assert job["grow_stats"]["rows_built"] == sum(built) == job[
+            "rows_built"]
+        # the budget of 8 leaves binds long before the cap on depth
+        assert 0 <= job["rows_built_at_the_cap"] < 0.2 * job["rows_built"]
+        assert job["margin_max_abs_err"] <= 1e-5 and job["logloss"] < 0.69
+    first = check["jobs_checked"][0]
+    assert [t["rows_built"] for t in check["trees_replayed"]] == [
+        first["rows_built_a_tree"][i] for i in (0, 1, 3)]
+    # two draws do not grow the same trees
+    assert len({tuple(job["rows_built_a_tree"])
+                for job in check["jobs_checked"][:6]}) > 1
+    # the readers' count is of all the window's jobs, each its own
+    assert check["counters"]["hist_rows_needed"] == sum(
+        job["rows_built"] for job in check["jobs_checked"]) == counters[
+            "grow_rows_built"]
+    assert set(check["check_secs"]) == {
+        "root", "replay_0", "replay_1", "replay_3", "jobs_routed",
+        "kernel_sums"}
     assert 0 < check["hist_prefix_sum_err"] <= check[
         "hist_prefix_sum_err_bound"] == 1.6e-5
     assert check["margin_max_abs_err"] <= 1e-5
-    counters = window["counters"]
-    assert counters["trees"] == 2 * counters["jobs"]
-    assert counters["grow_splits"] == 14 * counters["jobs"]
-    passes = counters["grow_splits"] + counters["trees"]
+    assert counters["trees"] == 4 * jobs
+    assert counters["grow_splits"] == 28 * jobs
+    # of the rows the trainer says its passes read (whole slabs of a
+    # child's rows), not of passes over the whole table
+    assert counters["grow_rows_read"] >= counters["grow_rows_built"]
     assert counters["grow_rows_built_share"] == pytest.approx(
-        100.0 * counters["grow_rows_built"] / (passes * 3000))
-    # a tree: its rows for the root, at most half a leaf's a split
-    assert 100.0 / 8 < counters["grow_rows_built_share"] < 100.0 * 4.5 / 8
+        100.0 * counters["grow_rows_built"] / counters["grow_rows_read"])
 
 
 def test_traced_run(capsys, toy_root):
@@ -170,9 +225,13 @@ def test_traced_run(capsys, toy_root):
     assert {"compile_s", "compiles_in_window", "step_builds_in_window",
             "peak_hbm_gb", "gbdt_stage_ms_per_job",
             "gbdt_dispatch_ms_per_tree", "gbdt_fetch_wait_ms_per_job",
-            "gbdt_grow_rows_built_share"} <= set(line["metrics"])
+            "gbdt_grow_rows_built_share", "gbdt_step_mfu"} <= set(
+                line["metrics"])
     assert line["metrics"]["step_builds_in_window"]["value"] == 0
-    assert 0 < line["metrics"]["gbdt_grow_rows_built_share"]["value"] < 100
+    assert 0 < line["metrics"]["gbdt_grow_rows_built_share"]["value"] <= 100
+    # the whole step's share needs the slice and the count, no kernel
+    assert 0 < line["metrics"]["gbdt_step_mfu"]["value"] < 100
+    assert "hist_kernel_roofline" not in line["metrics"]
 
 
 def test_a_program_without_the_policy_is_refused_at_once(toy_root,
@@ -200,14 +259,21 @@ def test_a_program_without_the_policy_is_refused_at_once(toy_root,
 
 
 @pytest.mark.parametrize("control", ["breadth_first", "lowest_gain_first",
-                                     "no_lo_part"])
+                                     "no_lo_part", "miscounted_third_tree",
+                                     "third_tree_altered",
+                                     "fourth_job_altered"])
 def test_a_weaker_grower_is_not_correct(capsys, toy_root, monkeypatch,
                                         control):
     """A grower that takes the open leaves in heap order, or the one of
     least gain first, still splits every node at its best candidate and
     returns the margins of its trees: only the replay tells. Histograms
     held in bf16 alone (the lo part dropped) fail the kernel's own sums
-    by far, whatever the splits do."""
+    by far, whatever the splits do. The third of four trees is one the
+    replay does not visit: a trainer whose count of the rows it built
+    there is off by one, and a tree altered where it is produced (a node
+    frozen after the fact), are caught by the count over all the trees
+    and by the margins; so is a tree altered in the window's fourth job
+    alone, which trains on another draw than the replayed one."""
     import jax.numpy as jnp
 
     from ytk_mp4j_tpu.models import gbdt
@@ -221,6 +287,27 @@ def test_a_weaker_grower_is_not_correct(capsys, toy_root, monkeypatch,
         monkeypatch.setattr(
             gbdt, "_pick_leaf", lambda open_, gain, heap, none:
             jnp.argmin(jnp.where(open_, gain, jnp.inf)))
+    elif control in ("miscounted_third_tree", "third_tree_altered",
+                     "fourth_job_altered"):
+        train = gbdt.GBDTTrainer.train
+        timed_jobs = []
+
+        def altered(self, *args, **kw):
+            trees, margins = train(self, *args, **kw)
+            timed_jobs.extend([1] * (len(trees) == 4))
+            if control == "fourth_job_altered" and len(timed_jobs) != 4:
+                pass
+            elif len(trees) == 4 and control == "miscounted_third_tree":
+                self.grow_stats_["rows_built"] += 1
+            elif len(trees) == 4:
+                bin_ = np.array(trees[2][1])
+                split = np.flatnonzero(bin_ != 255)
+                # the deepest split node has no split node under it
+                bin_[split[-1]] = 255
+                trees[2] = (trees[2][0], bin_, *trees[2][2:])
+            return trees, margins
+
+        monkeypatch.setattr(gbdt.GBDTTrainer, "train", altered)
     else:
         split = hist_kernel.split_bf16
 
@@ -234,8 +321,22 @@ def test_a_weaker_grower_is_not_correct(capsys, toy_root, monkeypatch,
     assert rc == 0
     assert json.loads(lines[-1])["correct"] is False
     check = _window(lines)["check"]
+    if control in ("third_tree_altered", "fourth_job_altered"):
+        assert not check["grow_stats_ok"]
+        assert check["margin_max_abs_err"] > 1e-5
+        off = [i for i, job in enumerate(check["jobs_checked"])
+               if not job["counts_ok"] or job["margin_max_abs_err"] > 1e-5]
+        assert off == ([3] if control == "fourth_job_altered"
+                       else list(range(len(check["jobs_checked"]))))
+        return
     assert check["margin_max_abs_err"] <= 1e-5      # its own trees' margins
-    if control == "no_lo_part":
+    if control == "miscounted_third_tree":
+        assert not check["grow_stats_ok"]
+        assert all(job["grow_stats"]["rows_built"] == job["rows_built"] + 1
+                   for job in check["jobs_checked"])
+        assert all(t["bad_nodes"] == [] and t["replay_broken"] == []
+                   for t in check["trees_replayed"])
+    elif control == "no_lo_part":
         assert check["hist_prefix_sum_err"] > 50 * check[
             "hist_prefix_sum_err_bound"]
     else:
@@ -245,6 +346,28 @@ def test_a_weaker_grower_is_not_correct(capsys, toy_root, monkeypatch,
         assert check["grow_stats_ok"]
         assert check["hist_prefix_sum_err"] <= check[
             "hist_prefix_sum_err_bound"]
+
+
+def test_a_label_draw_is_the_same_tables_other_label():
+    """``missing_table.label_draw``: the seed's own, another a draw,
+    balanced, and about three labels in eight away from the table's."""
+    from benchmark import missing_table
+
+    bins, y0 = missing_table.missing_binned_table(57, 40_000, 200, 256, 0.81)
+    one, again, two = (missing_table.label_draw(bins, 256, 57, d)
+                       for d in (1, 1, 2))
+    assert np.array_equal(one, again) and not np.array_equal(one, two)
+    assert not np.array_equal(
+        one, missing_table.label_draw(bins, 256, 58, 1))
+    for y in (one, two):
+        assert y.dtype == np.float32 and set(np.unique(y)) == {0.0, 1.0}
+        assert abs(y.mean() - 0.5) < 0.01
+        assert 0.55 < (y == y0).mean() < 0.75
+    with pytest.raises(ValueError, match="draw 0"):
+        missing_table.label_draw(bins, 256, 57, 0)
+    cell = cells.load_cell(ROOT, CELL)
+    assert cell.traffic["name"] == "train-relabelled"
+    assert cell.traffic["label_draws"] == 6
 
 
 def test_the_replay_on_a_tree_drawn_by_hand():

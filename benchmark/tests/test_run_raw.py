@@ -18,7 +18,9 @@ from benchmark import cells, run
 from conftest import ROOT
 
 CELL = "gbdt-bosch-968-raw.train-raw-chunks"
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+# ``compared``: each number the check compared beside its limit, last
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+             "compared"}
 # what the cell must report (it may report more)
 RAW = {"raw_stage_ms_per_job", "raw_sketch_ms_per_job",
        "raw_sketch_device_ms_per_job", "raw_transform_ms_per_job",
@@ -106,6 +108,8 @@ def test_untraced_run(capsys, toy_root):
     assert rc == 0
     line = json.loads(lines[-1])
     assert set(line) == LINE_KEYS
+    assert list(line)[-1] == "compared" and all(
+        len(pair) == 2 for pair in line["compared"].values())
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] >= 1
     assert set(line["metrics"]) == {"trees_per_s", "setup_s"}

@@ -15,7 +15,9 @@ from benchmark import arith, arith_score, cells, run
 from conftest import ROOT
 
 CELL = "gbdt-bosch-score-500.batch"
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+# ``compared``: each number the check compared beside its limit, last
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+             "compared"}
 # what the cell must report (it may report more)
 SCORE = {"rows_device_idle_share", "score_stage_ms_per_job",
          "score_dispatch_ms_per_job", "score_fetch_wait_ms_per_job",

@@ -17,7 +17,9 @@ from benchmark import cells, run
 from conftest import ROOT
 
 CELL = "gbdt-bosch-score-raw-500.raw-chunks"
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+# ``compared``: each number the check compared beside its limit, last
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+             "compared"}
 # what the cell must report (ISSUE 49: at least sixteen; it may report
 # more): its own three, and the shared loop's under the names
 # ``gbdt-bosch-score-500.batch`` reports them by

@@ -384,7 +384,7 @@ def test_traced_rehearsal_reports_the_programs_spans(capsys, tiny_root,
     line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert rc == 0 and line["correct"] is True
     assert set(line) == {"correct", "attempted", "failed", "metrics",
-                         "device", "breakdown"}
+                         "device", "breakdown", "compared"}
     for name in want:
         assert line["metrics"][name]["value"] > 0
         assert line["metrics"][name]["unit"] == "ms"

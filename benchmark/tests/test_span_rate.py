@@ -148,8 +148,7 @@ def test_new_metrics_are_declared_alike_in_both_places():
 
 
 # ------------------------------------------- the program's own staging
-@pytest.mark.parametrize("with_each", [False, True], ids=["stage", "each"])
-def test_rate_on_a_trace_of_the_programs_staging(tmp_path, with_each):
+def test_rate_on_a_trace_of_the_programs_staging(tmp_path):
     """``_put_sharded`` on the CPU under the benchmark's profiler options:
     one table in row chunks and one small array in one transfer. The rate
     is the chunked table's bytes over its span's time, whatever the small
@@ -162,15 +161,14 @@ def test_rate_on_a_trace_of_the_programs_staging(tmp_path, with_each):
     a = np.arange(4 * 4096 * 32, dtype=np.int32).reshape(4 * 4096, 32)
     t._ONE_TRANSFER_BYTES = a.nbytes // 4
     t._CHUNK_BYTES = t._EACH_CHUNK_BYTES = 2 ** 15      # 16 chunks
-    each = (lambda table, start, stop: None) if with_each else None
-    t._put_sharded(a, 4096, each)                       # builds the placer
+    t._put_sharded(a, 4096)                             # builds the placer
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     options.host_tracer_level = 2
     jax.profiler.start_trace(str(tmp_path), profiler_options=options)
     try:
         with jax.profiler.TraceAnnotation("bench.slice"):
-            jax.block_until_ready(t._put_sharded(a, 4096, each))
+            jax.block_until_ready(t._put_sharded(a, 4096))
             jax.block_until_ready(t._put_sharded(a[:64, 0], 16))
     finally:
         jax.profiler.stop_trace()
@@ -187,8 +185,8 @@ def test_rate_on_a_trace_of_the_programs_staging(tmp_path, with_each):
           for name in NEW if not name.endswith("gbps")}
     assert ms["stage_send_ms_per_job"] == ms["score_stage_send_ms_per_job"] > 0
     assert ms["stage_device_wait_ms_per_job"] > 0
-    # one pace for every caller since PR 46: the host waits for the link
-    # with or without ``each=``
+    # one pace for every caller since PR 46 (``each=`` went with PR 52:
+    # a scoring call takes its pieces from ``_pieces``, not from here)
     assert ms["score_stage_link_wait_ms_per_job"] > 0
     assert ms["score_stage_link_wait_ms_per_job"] == host_span.read(
         _spec("stage_link_wait_ms_per_job"), run)
